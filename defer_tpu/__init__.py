@@ -53,8 +53,13 @@ from .obs import (LatencyHistogram, MetricsRegistry, REGISTRY,
                   enable_tracing, export_chrome_trace, get_registry, tracer)
 from .utils.metrics import PipelineMetrics, StopwatchWindow
 from .utils.profiling import profile_pipeline, trace
+from .utils import compile_cache as _compile_cache
 
 __version__ = "0.1.0"
+
+# nothing above compiles at import; every process of the package (its
+# children too) resolves the same persistent-cache directory here
+_compile_cache.configure()
 
 __all__ = [
     "GraphBuilder", "LayerGraph", "Op", "ShapeSpec", "StageSpec",

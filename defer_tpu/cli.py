@@ -425,7 +425,8 @@ def cmd_plan(args):
         return
     print(f"{graph.name}: {plan.num_stages} stages, objective "
           f"{plan.objective}, cost model {cm.describe()['node_costs']} "
-          f"(gen {cm.gen}, link {cm.link_bw_s:.3g} B/s)")
+          f"(gen {cm.gen} [{cm.target} target], "
+          f"link {cm.link_bw_s:.3g} B/s)")
     comm = plan.hop_comm_s + [0.0]
     codecs = plan.codecs + ["-"]
     reps = getattr(plan, "replicas", None)
@@ -817,6 +818,7 @@ def _cmd_chain_dag(args, graph, params) -> None:
         "metric": f"{args.model}_{len(topo)}proc_dag_chain",
         "value": round(len(xs) * args.batch / dt, 3),
         "unit": "inferences/sec",
+        "platform": jax.default_backend(),
         "stages": len(topo),
         "labels": [v.label for v in topo.vertices],
         "forks": sum(1 for v in topo.vertices if v.fan == "broadcast"),
@@ -835,6 +837,12 @@ def _cmd_chain_dag(args, graph, params) -> None:
 
 def cmd_chain(args):
     import jax
+
+    # a local chain's stage processes run on the CPU platform
+    # (runtime/node.py LOCAL_CHAIN_ENV: a chip belongs to one process);
+    # so does this parent, stated before its first jax call, so the
+    # artifacts it exports are lowered for the platform that loads them
+    jax.config.update("jax_platforms", "cpu")
 
     from . import partition
     from .runtime.node import run_chain
@@ -910,6 +918,7 @@ def cmd_chain(args):
         "metric": f"{args.model}_{n_deployed}proc_chain",
         "value": round(len(xs) * args.batch / dt, 3),
         "unit": "inferences/sec",
+        "platform": jax.default_backend(),
         "stages": n_deployed, "codec": args.codec,
         "overlap": not args.no_overlap,
         "hop_tiers": [tier_of[k] for k in order[:-1]],
@@ -929,10 +938,7 @@ def cmd_chain(args):
     if args.emit_calibration:
         from .plan.calibrate import CalibrationError, fit_from_stats
         from .utils import hw
-        try:
-            gen = hw.identify_chip(jax.devices()[0])
-        except Exception:  # noqa: BLE001 — no backend
-            gen = "unknown"
+        gen = hw.detect_chip(jax.devices()[0])
         try:
             cal = fit_from_stats(graph,
                                  [s.output_name for s in stages[:-1]],
@@ -1067,13 +1073,15 @@ def _parse_tenant_specs(specs) -> list:
     return out
 
 
-def cmd_serve(args):
-    """The serving front door (docs/SERVING.md): accept many concurrent
-    client streams, admit under per-tenant weighted-fair queuing with
-    SLO-aware shedding, coalesce admitted samples across tenants into
-    dynamic microbatches sized by the planner's latency budget, and
-    multiplex them onto one deployed chain (tensor mode) or a
-    continuous-batching decode engine (--workload decode)."""
+def serve_deployment(args):
+    """Build the deployment ``serve`` runs, un-started: the front door
+    over one deployed chain (tensor mode) or a continuous-batching
+    decode engine (--workload decode).
+
+    Returns ``(door, disp, node_addrs, cleanup)``: ``disp`` and
+    ``node_addrs`` are the chain's dispatcher and stage-node addresses
+    (None / empty in decode mode; other processes' under --nodes), and
+    ``cleanup()`` joins what this call started, after ``door.stop()``."""
     import threading
 
     import jax
@@ -1085,13 +1093,6 @@ def cmd_serve(args):
     graph = _get_model(args.model)
     params = graph.init(jax.random.key(0))
     tenants = _parse_tenant_specs(args.tenant)
-    _start_prom(args, "serve")
-    # request-scoped tracing composes with serving (docs/SERVING.md):
-    # --trace-out enables the tracer, --trace-sample N samples whole
-    # REQUESTS 1-in-N (every frame of a sampled request traces end to
-    # end across the front door AND every stage process)
-    _obs_begin(args, process="serve")
-    ext_addrs: list[str] = []
 
     if args.workload == "decode":
         from .serve import ContinuousBatchEngine
@@ -1102,72 +1103,91 @@ def cmd_serve(args):
         engine = ContinuousBatchEngine(graph, params,
                                        num_stages=args.stages,
                                        width=width)
+        if args.stages > 1:
+            print(f"serve: --workload decode computes on ONE device "
+                  f"whatever --stages says ({args.stages} stages shape "
+                  f"the cache layout only; docs/SERVING.md)",
+                  file=sys.stderr, flush=True)
         door = ServeFrontDoor(
             engine=engine, listen=args.listen, tenants=tenants,
             decode_defaults={"max_new_tokens": args.max_new})
-        cleanup = lambda: None  # noqa: E731
-    else:
-        cuts = args.cuts.split(",") if args.cuts else None
-        stages = partition(graph, cuts, num_stages=args.stages)
-        cut_names = [s.output_name for s in stages[:-1]]
-        width = args.width
-        if args.budget_ms:
-            # dynamic-microbatch width from the planner's cost model:
-            # the largest frame batch whose slowest stage stays inside
-            # the per-stage latency budget
-            from .plan import max_batch_within_budget
-            cm = _cost_model(args, graph)
-            width = max_batch_within_budget(
-                graph, cut_names, cm, args.budget_ms,
-                cap=args.max_width)
-            print(f"serve: width {width} from --budget-ms "
-                  f"{args.budget_ms:g}", file=sys.stderr, flush=True)
-        width = width or 4
-        hop_codecs = [c for c in args.hop_codecs.split(",") if c] or None
-        if args.nodes:
-            from .runtime.node import ChainDispatcher
-            addrs = [a for a in args.nodes.split(",") if a]
-            if len(addrs) != len(stages):
-                raise SystemExit(f"{len(stages)} stages but "
-                                 f"{len(addrs)} --nodes")
-            disp = ChainDispatcher(addrs[0], codec=args.codec)
-            disp.deploy(stages, params, addrs, batch=width,
-                        codecs=hop_codecs)
-            ext_addrs = addrs
-            from .obs import tracer
-            if tracer().enabled:
-                # external stage processes: re-anchor their tracers so
-                # a sampled request's cross-process waterfall lands on
-                # one Perfetto timeline (the dispatcher edge of clock
-                # alignment, docs/OBSERVABILITY.md)
-                disp.align_clocks(addrs)
-            cleanup = lambda: None  # noqa: E731 — nodes are external
-        else:
-            # self-contained deployment: thread-per-stage nodes in this
-            # process (run `defer_tpu node` per host + --nodes for a
-            # real multi-process chain)
-            from .runtime.node import ChainDispatcher, StageNode
-            nodes = [StageNode(None, "127.0.0.1:0", None)
-                     for _ in stages]
-            addrs = [f"127.0.0.1:{n.address[1]}" for n in nodes]
-            threads = [threading.Thread(target=n.serve, daemon=True)
-                       for n in nodes]
-            for t in threads:
-                t.start()
-            disp = ChainDispatcher(addrs[0], codec=args.codec)
-            disp.deploy(stages, params, addrs, batch=width,
-                        codecs=hop_codecs)
+        return door, None, [], lambda: None
 
-            def cleanup(_threads=threads):
-                for t in _threads:
-                    t.join(timeout=10)
-        backend = ChainBackend(disp, width,
-                               tuple(stages[0].in_spec.shape),
-                               window=args.window,
-                               trace_sample_every=args.trace_sample)
-        door = ServeFrontDoor(backend=backend, listen=args.listen,
-                              tenants=tenants,
-                              gather_s=args.gather_ms / 1e3)
+    cuts = args.cuts.split(",") if args.cuts else None
+    stages = partition(graph, cuts, num_stages=args.stages)
+    cut_names = [s.output_name for s in stages[:-1]]
+    width = args.width
+    if args.budget_ms:
+        # dynamic-microbatch width from the planner's cost model:
+        # the largest frame batch whose slowest stage stays inside
+        # the per-stage latency budget
+        from .plan import max_batch_within_budget
+        cm = _cost_model(args, graph)
+        width = max_batch_within_budget(
+            graph, cut_names, cm, args.budget_ms,
+            cap=args.max_width)
+        print(f"serve: width {width} from --budget-ms "
+              f"{args.budget_ms:g}", file=sys.stderr, flush=True)
+    width = width or 4
+    hop_codecs = [c for c in args.hop_codecs.split(",") if c] or None
+    from .runtime.node import ChainDispatcher, StageNode
+    if args.nodes:
+        addrs = [a for a in args.nodes.split(",") if a]
+        if len(addrs) != len(stages):
+            raise SystemExit(f"{len(stages)} stages but "
+                             f"{len(addrs)} --nodes")
+        cleanup = lambda: None  # noqa: E731 — nodes are external
+    else:
+        # self-contained deployment: thread-per-stage nodes in this
+        # process (run `defer_tpu node` per host + --nodes for a
+        # real multi-process chain), stage k on device k of however
+        # many this process has — weights and program both
+        n_dev = len(jax.devices())
+        nodes = [StageNode(None, "127.0.0.1:0", None, device=k % n_dev)
+                 for k in range(len(stages))]
+        addrs = [f"127.0.0.1:{n.address[1]}" for n in nodes]
+        threads = [threading.Thread(target=n.serve, daemon=True)
+                   for n in nodes]
+        for t in threads:
+            t.start()
+
+        def cleanup(_threads=threads):
+            for t in _threads:
+                t.join(timeout=10)
+    disp = ChainDispatcher(addrs[0], codec=args.codec)
+    disp.deploy(stages, params, addrs, batch=width, codecs=hop_codecs)
+    backend = ChainBackend(disp, width,
+                           tuple(stages[0].in_spec.shape),
+                           window=args.window,
+                           trace_sample_every=args.trace_sample)
+    door = ServeFrontDoor(backend=backend, listen=args.listen,
+                          tenants=tenants,
+                          gather_s=args.gather_ms / 1e3)
+    return door, disp, addrs, cleanup
+
+
+def cmd_serve(args):
+    """The serving front door (docs/SERVING.md): accept many concurrent
+    client streams, admit under per-tenant weighted-fair queuing with
+    SLO-aware shedding, coalesce admitted samples across tenants into
+    dynamic microbatches sized by the planner's latency budget, and
+    multiplex them onto one deployed chain (tensor mode) or a
+    continuous-batching decode engine (--workload decode)."""
+    _start_prom(args, "serve")
+    # request-scoped tracing composes with serving (docs/SERVING.md):
+    # --trace-out enables the tracer, --trace-sample N samples whole
+    # REQUESTS 1-in-N (every frame of a sampled request traces end to
+    # end across the front door AND every stage process)
+    _obs_begin(args, process="serve")
+    door, disp, addrs, cleanup = serve_deployment(args)
+    ext_addrs = addrs if args.nodes else []
+    from .obs import tracer
+    if tracer().enabled and ext_addrs:
+        # external stage processes: re-anchor their tracers so a
+        # sampled request's cross-process waterfall lands on one
+        # Perfetto timeline (the dispatcher edge of clock alignment,
+        # docs/OBSERVABILITY.md)
+        disp.align_clocks(ext_addrs)
     door.start()
     if args.journal_dir:
         # the front door is a fleet member too: its admission/shed
@@ -1206,7 +1226,6 @@ def cmd_serve(args):
                           delay_s=0.0)
         raise
     finally:
-        from .obs import tracer
         if tracer().enabled and ext_addrs:
             # stitch the external stage processes' spans in while they
             # are still alive (in-process thread nodes already share
@@ -1759,7 +1778,9 @@ def cmd_generate(args):
     _obs_finish(args)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The CLI's argument parser (``chip_smoke.py`` builds its ``serve``
+    arguments with it, so the smoke runs what the command line runs)."""
     ap = argparse.ArgumentParser(prog="python -m defer_tpu")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -2285,8 +2306,11 @@ def main(argv=None):
     g.add_argument("--beam", type=int, default=1,
                    help="beam width (must divide --microbatch)")
     _add_obs_flags(g)
+    return ap
 
-    args = ap.parse_args(argv)
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
     {"models": cmd_models, "partition": cmd_partition, "plan": cmd_plan,
      "bench": cmd_bench, "export": cmd_export, "node": cmd_node,
      "chain": cmd_chain, "monitor": cmd_monitor, "train": cmd_train,

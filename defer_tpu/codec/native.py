@@ -9,12 +9,7 @@ wire formats.
 from __future__ import annotations
 
 import ctypes
-import os
 import threading
-
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libdefercodec.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -25,19 +20,16 @@ def load():
     """The loaded ctypes library, or None if unavailable.
 
     A rebuild-needing (missing OR stale) library that fails to build
-    yields None — the NumPy fallback — never the stale binary."""
+    yields None — the NumPy fallback, announced on stderr — never the
+    stale binary."""
     global _lib, _tried
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        from ..utils._nativebuild import ensure_built
-        if not ensure_built(os.path.join(_NATIVE_DIR, "codec.cpp"),
-                            _SO_PATH):
-            return None
-        try:
-            lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        from ..utils._nativebuild import load_library
+        lib = load_library("codec", "libdefercodec.so", "NumPy codec")
+        if lib is None:
             return None
         c_i64, c_int = ctypes.c_int64, ctypes.c_int
         u8p = ctypes.POINTER(ctypes.c_uint8)

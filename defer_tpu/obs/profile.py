@@ -306,6 +306,18 @@ class ProfileSession:
         s = h.summary()
         return int(s.get("count", 0)), float(s.get("sum", 0.0))
 
+    def _trace_failed(self, what: str, e: Exception) -> None:
+        """A host backend without a profiler still answers with the
+        phase breakdown (a note on stderr); on the chip the device
+        trace IS what was asked for, so its failure ends the session
+        and raises."""
+        import jax
+        if jax.default_backend() == "tpu":
+            self._t0 = None
+            raise e
+        print(f"profile: jax.profiler.{what} failed ({e!r})",
+              file=sys.stderr, flush=True)
+
     def start(self) -> dict:
         if self._t0 is not None:
             raise RuntimeError("profile session already started")
@@ -317,15 +329,12 @@ class ProfileSession:
                                 if self._processed else 0)
         self._t0 = time.perf_counter()
         if self._jax_trace_dir:
+            import jax
             try:
-                import jax
                 jax.profiler.start_trace(self._jax_trace_dir)
                 self._jax_tracing = True
-            except Exception as e:  # noqa: BLE001 — backend without a
-                # profiler must not fail the session; the phase
-                # breakdown still answers
-                print(f"profile: jax.profiler.trace unavailable "
-                      f"({e!r})", file=sys.stderr, flush=True)
+            except Exception as e:  # noqa: BLE001 — see _trace_failed
+                self._trace_failed("start_trace", e)
         return {"t0_unix": time.time()}
 
     def stop(self) -> dict:
@@ -333,12 +342,11 @@ class ProfileSession:
             raise RuntimeError("profile session never started")
         dt = time.perf_counter() - self._t0
         if self._jax_tracing:
+            import jax
             try:
-                import jax
                 jax.profiler.stop_trace()
             except Exception as e:  # noqa: BLE001 — symmetric guard
-                print(f"profile: stop_trace failed ({e!r})",
-                      file=sys.stderr, flush=True)
+                self._trace_failed("stop_trace", e)
         watcher = recompile_watcher()
         phases = {}
         for name, h in self._hists.items():

@@ -20,7 +20,14 @@ positions <= i + (Tk - Tq), so decode-style calls (Tq=1 against a long K/V
 prefix) attend to the whole prefix.
 
 On non-TPU backends (CPU tests) the same kernel runs in interpreter mode, so
-there is exactly one implementation of the math.
+there is exactly one implementation of the math.  On a TPU backend it is
+compiled by Mosaic and a compile error raises — there is no XLA-attention
+or interpret-mode fallback.  Established on the v5e (libtpu 0.0.34,
+``chip_smoke.py`` and its bring-up probe): Mosaic accepts the 8-row clamp
+for bf16 blocks (below the (16, 128) bf16 tile), the lane-1 ``[:, :1]``
+reads of the (block_q, 128) m/l scratch, f32 and bf16 operands, ragged
+Tq/Tk, and the kernel inside ``lax.switch`` / ``shard_map`` /
+``jax.export``.
 """
 
 from __future__ import annotations

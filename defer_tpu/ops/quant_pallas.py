@@ -11,6 +11,11 @@ multiply into the consuming stage for free.
 
 Off-TPU the identical kernel runs in interpreter mode (same math, one
 implementation) — the pattern established by ``ops/flash_attention.py``.
+On a TPU backend Mosaic compiles it and a compile error raises.
+Established on the v5e (libtpu 0.0.34, ``chip_smoke.py`` and its bring-up
+probe): the direct f32 -> int8 cast after ``jnp.round`` and the
+``(_ROWS, 1)`` f32 scale block compile, inside ``lax.scan`` /
+``shard_map`` too, and the output is bit-identical to the jnp reference.
 """
 
 from __future__ import annotations

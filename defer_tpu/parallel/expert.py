@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.ops import MoE
-from ..utils.compat import shard_map
 
 EXPERT_AXIS = "expert"
 
@@ -141,6 +140,6 @@ def expert_parallel_fn(op: MoE, mesh: Mesh, axis: str = EXPERT_AXIS,
         return expert_parallel_apply(op, p, x, axis_name=axis, ep=ep,
                                      capacity=cap)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis)),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis)),
                        out_specs=P(axis), check_vma=False)
     return jax.jit(fn)

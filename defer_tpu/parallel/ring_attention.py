@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.compat import axis_size, shard_map
 
 SEQ_AXIS = "seq"
 
@@ -60,7 +59,7 @@ def ring_attention(q, k, v, *, axis_name: str = SEQ_AXIS,
     consistent with the *global* sequence order (shard i holds positions
     [i*Tl, (i+1)*Tl)).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, tl, d = q.shape
     scale = 1.0 / math.sqrt(d)
@@ -123,7 +122,7 @@ def sequence_parallel_attention(q, k, v, mesh: Mesh, *,
     the sequence dimension sharded over ``mesh[axis_name]`` and K/V ring-
     rotated over ICI."""
     spec = P(None, None, axis_name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)

@@ -41,7 +41,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.ir import LayerGraph
-from ..utils.compat import shard_map
 from .mesh import MODEL_AXIS
 
 
@@ -93,6 +92,6 @@ def tensor_parallel_fn(graph: LayerGraph, mesh: Mesh, axis: str = MODEL_AXIS):
                                            axis_name=axis, tp=tp)
         return cache[graph.output_name]
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(P(axis), P()),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(P(axis), P()),
                        out_specs=P(), check_vma=False)
     return jax.jit(fn)

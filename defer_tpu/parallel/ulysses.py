@@ -22,7 +22,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.compat import axis_size, shard_map
 from .ring_attention import SEQ_AXIS, full_attention
 
 
@@ -33,7 +32,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = SEQ_AXIS,
     Call inside ``shard_map``; q/k/v are local shards [B, H, T/N, D].
     Returns the local output shard [B, H, T/N, D].
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[1]
     if h % n:
         raise ValueError(f"num_heads={h} not divisible by mesh size {n}")
@@ -60,7 +59,7 @@ def sequence_parallel_attention_ulysses(q, k, v, mesh: Mesh, *,
     """Convenience wrapper: global [B,H,T,D] in, attention out, sequence dim
     sharded over ``mesh[axis_name]`` with all_to_all head exchange."""
     spec = P(None, None, axis_name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=axis_name,
                           causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

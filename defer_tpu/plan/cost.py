@@ -132,6 +132,10 @@ TIER_CODECS: dict[str, CodecSpec] = {
 #: hop 2-3 decades under any TCP hop without rounding it to free).
 DEFAULT_LOCAL_BW_S = 1e10
 
+#: the generation a plan is priced for when this process has no chip
+#: and the caller names none (the chip the repo is measured on)
+DEFAULT_TARGET_GEN = "v5e"
+
 #: host-sync bandwidth: the D2H + H2D transfer pair every
 #: non-device-resident hop pays around its transport (the producing
 #: loop's ``np.asarray``, the consuming program's re-upload).  Same
@@ -257,17 +261,24 @@ class StageCostModel:
                  host_sync_bw_s: float | None = None):
         self.graph = graph
         self.batch = max(int(batch), 1)
+        #: "detected" when ``gen`` was read off this process's chip,
+        #: "assumed" when the caller named the target or no chip is
+        #: present and the plan is for ``DEFAULT_TARGET_GEN``
+        self.target = "assumed"
         if gen is None:
             gen = self._detect_gen()
+            if gen == "unknown":
+                gen = DEFAULT_TARGET_GEN
+            else:
+                self.target = "detected"
+        if hw.peak_flops(gen) <= 0:
+            raise ValueError(
+                f"no peak table for TPU generation {gen!r} "
+                f"(have {sorted(hw.PEAK_BF16_FLOPS)})")
         self.gen = gen
-        # unknown generations fall back to v5e so the analytic model
-        # still ranks nodes instead of dividing by zero; absolute
-        # seconds are then only as good as the fallback (calibrate or
-        # pass node_costs for real numbers)
-        ref = gen if hw.peak_flops(gen) > 0 else "v5e"
-        self.peak_flops_s = peak_flops_s or hw.peak_flops(ref)
-        self.hbm_bw_s = hbm_bw_s or hw.hbm_bandwidth(ref)
-        self.link_bw_s = link_bw_s or hw.ici_bandwidth(ref)
+        self.peak_flops_s = peak_flops_s or hw.peak_flops(gen)
+        self.hbm_bw_s = hbm_bw_s or hw.hbm_bandwidth(gen)
+        self.link_bw_s = link_bw_s or hw.ici_bandwidth(gen)
         self.codecs = dict(codecs) if codecs is not None \
             else dict(DEFAULT_CODECS)
         if lossless_only:
@@ -286,7 +297,7 @@ class StageCostModel:
         #: one-way ICI figure, like ``link_bw_s``; override for slower
         #: meshes the same way ``--link-bw`` overrides the wire; 0 =
         #: model the d2d wire as free, same convention as host_sync)
-        self.ici_bw_s = hw.ici_bandwidth(ref) if ici_bw_s is None \
+        self.ici_bw_s = hw.ici_bandwidth(gen) if ici_bw_s is None \
             else float(ici_bw_s)
         #: D2H/H2D bandwidth for the per-hop host_sync term every
         #: non-device-resident tier pays (0 = model the sync as free —
@@ -296,11 +307,10 @@ class StageCostModel:
 
     @staticmethod
     def _detect_gen() -> str:
-        try:
-            import jax
-            return hw.identify_chip(jax.devices()[0])
-        except Exception:  # noqa: BLE001 — no backend: analytic fallback
-            return "unknown"
+        """This process's chip generation ("unknown" off-TPU; a TPU
+        kind missing from ``utils/hw.py`` raises)."""
+        import jax
+        return hw.detect_chip(jax.devices()[0])
 
     # -- compute -----------------------------------------------------------
 
@@ -482,7 +492,7 @@ class StageCostModel:
 
     def describe(self) -> dict:
         d = {
-            "gen": self.gen, "batch": self.batch,
+            "gen": self.gen, "target": self.target, "batch": self.batch,
             "peak_flops_s": self.peak_flops_s, "hbm_bw_s": self.hbm_bw_s,
             "link_bw_s": self.link_bw_s,
             # every non-device-resident hop pays the host round-trip,

@@ -61,7 +61,6 @@ from ..graph.ir import LayerGraph
 from ..models.gpt import CausalTransformerBlock, GptEmbedding
 from ..obs import REGISTRY, tracer
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
-from ..utils.compat import shard_map
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
 
@@ -570,7 +569,7 @@ class PipelinedDecoder:
             return jax.tree.map(lambda c: c[None], local), ids[None]
 
         state = self._state_specs()
-        fn = shard_map(
+        fn = jax.shard_map(
             device_prefill, mesh=self.mesh,
             in_specs=(self._wspec_tree, P(None, None, None), P(), P(),
                       state),
@@ -657,7 +656,7 @@ class PipelinedDecoder:
         state = self._state_specs()
         out_ids = P(STAGE_AXIS, None, None, None) if beam \
             else P(STAGE_AXIS, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             device_decode, mesh=self.mesh,
             in_specs=(self._wspec_tree, P(None, None, None), P(), P(),
                       P(), P(), P(), P(None, None), P(), P(),

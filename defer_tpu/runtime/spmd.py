@@ -47,7 +47,6 @@ from ..graph.ir import ShapeSpec
 from ..obs import tracer
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, STAGE_AXIS, pipeline_mesh
 from ..partition.stage import StageSpec, buffer_footprint
-from ..utils.compat import shard_map
 from ..utils.metrics import PipelineMetrics
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
@@ -338,7 +337,7 @@ class SpmdPipeline:
         ospec = P(STAGE_AXIS, None, DATA_AXIS, None) if has_dp \
             else P(STAGE_AXIS, None, None, None)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             device_chunk, mesh=self.mesh,
             in_specs=(self._wspec, bspec, xspec),
             out_specs=(bspec, ospec),
@@ -583,7 +582,7 @@ class SpmdPipeline:
             if tp_mesh is not None:
                 w_k = jax.device_put(
                     self._w[k], NamedSharding(tp_mesh, P(MODEL_AXIS, None)))
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     lambda w, a: branch(w[0], a), mesh=tp_mesh,
                     in_specs=(P(MODEL_AXIS, None), P(None, None)),
                     out_specs=P(None, None), check_vma=False))

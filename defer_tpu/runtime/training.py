@@ -34,7 +34,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, STAGE_AXIS
-from ..utils.compat import pcast_varying, shard_map
 from .spmd import SpmdPipeline
 
 
@@ -177,7 +176,7 @@ class PipelineTrainer:
             if has_tp:
                 # the tp-rank rings produce replicated values the VMA
                 # system types as model-varying; match the carry type
-                a_init = pcast_varying(a_init, (MODEL_AXIS,))
+                a_init = lax.pcast(a_init, (MODEL_AXIS,), to="varying")
             _a_t, losses = lax.scan(body, a_init, (xs, ys, mask))
             total = jnp.where(idx == 0, losses.sum(), 0.0)
             # replicate the scalar so every shard returns the same loss;
@@ -204,7 +203,7 @@ class PipelineTrainer:
         # tracking is what makes the TRANSPOSE of the in-stage Megatron
         # psums correct — with it off, a replicated cotangent re-enters
         # psum and every tp-rank gradient double-counts
-        fn = shard_map(
+        fn = jax.shard_map(
             device_chunk, mesh=pipe.mesh,
             in_specs=(pipe._wspec, bspec, xspec, yspec, P(None)),
             out_specs=P(),

@@ -16,14 +16,9 @@ when no C++ toolchain exists.
 from __future__ import annotations
 
 import ctypes
-import os
 import threading
 
 import numpy as np
-
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libdeferstaging.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -36,13 +31,10 @@ def _load():
         if _tried:
             return _lib
         _tried = True
-        from ..utils._nativebuild import ensure_built
-        if not ensure_built(os.path.join(_NATIVE_DIR, "staging.cpp"),
-                            _SO_PATH):
-            return None
-        try:
-            lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        from ..utils._nativebuild import load_library
+        lib = load_library("staging", "libdeferstaging.so",
+                           "pure-Python staging ring")
+        if lib is None:
             return None
         i64 = ctypes.c_int64
         u8p = ctypes.POINTER(ctypes.c_uint8)

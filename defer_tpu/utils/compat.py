@@ -1,44 +1,16 @@
-"""JAX version compatibility shims.
+"""Host-platform device-count helpers.
 
-The engines target the current ``jax.shard_map(..., check_vma=...)`` API;
-older installs (<= 0.4.x) only have ``jax.experimental.shard_map`` whose
-replication-check kwarg is spelled ``check_rep``.  Every shard_map call in
-the codebase goes through this one wrapper so the version probe happens
-once, at import.
+The engines call ``jax.shard_map`` / ``lax.pcast`` / ``lax.axis_size``
+directly (jax 0.9, the one installed version); what remains here is the
+forced multi-device CPU mesh the tests and smokes run on.
 """
 
 from __future__ import annotations
 
+import os
+import re
+
 import jax
-
-_impl = getattr(jax, "shard_map", None)
-_LEGACY = _impl is None
-if _LEGACY:
-    from jax.experimental.shard_map import shard_map as _impl  # type: ignore
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions (``check_vma``/``check_rep``).
-
-    On the legacy API the replication checker is always disabled: the old
-    ``check_rep`` implementation false-positives on valid programs (e.g.
-    ``lax.cond`` branches — jax's own error suggests ``check_rep=False``
-    as the workaround), and it is purely a debugging aid.  The modern
-    ``check_vma`` checker honours the caller's flag."""
-    if _LEGACY:
-        return _impl(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-    return _impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check_vma=check_vma)
-
-
-def pcast_varying(x, axes):
-    """``lax.pcast(..., to="varying")`` where the VMA type system exists;
-    identity on legacy jax (no varying-manual-axes typing to satisfy)."""
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is None:
-        return x
-    return pc(x, axes, to="varying")
 
 
 def host_device_count_flags(flags: str | None, n: int) -> str:
@@ -46,8 +18,6 @@ def host_device_count_flags(flags: str | None, n: int) -> str:
     forced to ``n`` (any existing count flag replaced) — shared by
     :func:`force_host_device_count` and ``run_chain``'s child-env
     rewrite so the flag format lives in one place."""
-    import re
-
     flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                    flags or "").strip()
     return f"{flags} --xla_force_host_platform_device_count={n}".strip()
@@ -59,37 +29,25 @@ def force_host_device_count(n: int) -> tuple[bool, str]:
     same-mesh multi-device work (the ici transport tier, sharding
     tests) on hosts without a real accelerator mesh.
 
-    Must run BEFORE jax initializes its backends: the flag is read once
-    at backend construction.  Returns ``(ok, reason)`` — ``ok`` is True
-    when the flag took (or the backend already exposes >= n devices),
-    False with a skip-worthy ``reason`` when jax already initialized
-    with fewer devices (callers like the conftest fixture turn that
-    into a skip instead of a wrong-mesh test run).
+    The flag is read once, when jax constructs its backends, so this
+    sets it and then counts ``jax.devices()`` — which constructs them
+    if nothing has yet.  Returns ``(ok, reason)``: ``ok`` is False with
+    a skip-worthy ``reason`` when jax had already initialized with
+    fewer devices (callers like the conftest fixture turn that into a
+    skip instead of a wrong-mesh test run); the environment is left as
+    it was in that case.
     """
-    import os
-
     n = int(n)
-    backends = getattr(getattr(jax._src, "xla_bridge", None),
-                       "_backends", None)
-    if backends:
-        have = len(jax.devices())
-        if have >= n:
-            return True, f"backend already initialized with {have} devices"
-        return False, (f"jax already initialized with {have} host "
-                       f"device(s) < {n}; set XLA_FLAGS="
-                       f"--xla_force_host_platform_device_count={n} "
-                       f"before the first jax call")
-    os.environ["XLA_FLAGS"] = host_device_count_flags(
-        os.environ.get("XLA_FLAGS"), n)
-    return True, f"XLA_FLAGS set for {n} host devices"
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis (``lax.axis_size`` on current
-    jax; the ``core.axis_frame`` lookup on legacy versions, where the
-    frame resolves directly to the int size)."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return ax(axis_name)
-    from jax import core
-    return core.axis_frame(axis_name)
+    before = os.environ.get("XLA_FLAGS")
+    os.environ["XLA_FLAGS"] = host_device_count_flags(before, n)
+    have = len(jax.devices())
+    if have >= n:
+        return True, f"backend exposes {have} host devices"
+    if before is None:
+        del os.environ["XLA_FLAGS"]
+    else:
+        os.environ["XLA_FLAGS"] = before
+    return False, (f"jax already initialized with {have} host "
+                   f"device(s) < {n}; set XLA_FLAGS="
+                   f"--xla_force_host_platform_device_count={n} "
+                   f"before the first jax call")

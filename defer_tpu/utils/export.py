@@ -22,7 +22,6 @@ from typing import Any
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 from jax import export as jax_export
 
 from ..partition.stage import StageSpec
@@ -47,9 +46,10 @@ def weights_blob(leaves: list[np.ndarray]) -> bytes:
     return buf.getvalue()
 
 
-def _load_weights_blob(data: bytes, num: int) -> list:
+def _load_weights_blob(data: bytes, num: int) -> list[np.ndarray]:
+    """Host arrays; :class:`StageProgram` places them on its device."""
     with np.load(io.BytesIO(data)) as npz:
-        return [jnp.asarray(npz[f"w{i}"]) for i in range(num)]
+        return [npz[f"w{i}"] for i in range(num)]
 
 
 def export_stage_bytes(stage: StageSpec, params: dict[str, Any],
@@ -128,9 +128,12 @@ class StageProgram:
     """
 
     def __init__(self, exported, leaves: list, manifest: dict):
-        self._exported = exported
         self.manifest = manifest
         self.device = None
+        # the weights are ARGUMENTS of the jitted call: closed over,
+        # they would be baked into the HLO as dense constants (tens of
+        # MB per ResNet50 stage) and every reweight would recompile
+        self._call = jax.jit(exported.call)
         self._install(leaves)
 
     def _install(self, leaves: list):
@@ -138,33 +141,37 @@ class StageProgram:
             raise ValueError(
                 f"expected {self.manifest['num_weights']} weight arrays, "
                 f"got {len(leaves)}")
-        call = self._exported.call
-        self._leaves = leaves
-        # *xs: a join-stage artifact (manifest["num_inputs"] > 1) takes
-        # one array per merged branch path, single-input stages just one
-        base = jax.jit(lambda *xs: call(leaves, *xs))
-        if self.device is None:
-            self.fn = base
-        else:
-            # committing the inputs pins the computation: jit places the
-            # executable on its committed arguments' device.  device_put
-            # of an array already resident there is a no-op, so the
-            # device-resident (ici) hand-off path pays nothing here.
-            dev = self.device
-            self.fn = lambda *xs: base(
-                *(jax.device_put(x, dev) for x in xs))
+        # placed ONCE on the stage's device (the default device until
+        # place() pins one); committed weights also pin the executable
+        self._leaves = jax.device_put(list(leaves), self.device)
+
+    def fn(self, *xs):
+        """Run the stage: one array per merged branch path for a
+        join-stage artifact (manifest["num_inputs"] > 1), else one."""
+        if self.device is not None:
+            # device_put of an array already resident there is a no-op,
+            # so the device-resident (ici) hand-off path pays nothing
+            xs = [jax.device_put(x, self.device) for x in xs]
+        return self._call(self._leaves, *xs)
 
     def place(self, device) -> None:
-        """Pin the program to one jax device: every call runs (and its
-        output lives) there — the deployment half of the device-resident
-        ``ici`` transport tier, where the UPSTREAM hop device_puts each
-        activation onto this device and the program consumes it without
-        any host round-trip."""
+        """Pin the program to one jax device: its weights move there and
+        every call runs (and its output lives) there — the deployment
+        half of the device-resident ``ici`` transport tier, where the
+        UPSTREAM hop device_puts each activation onto this device and
+        the program consumes it without any host round-trip."""
         self.device = device
         self._install(self._leaves)
 
+    @property
+    def weight_device_ids(self) -> list[int]:
+        """Ids of the devices that hold the weights, read off the
+        arrays' shardings (what is, not what was requested)."""
+        return sorted({d.id for l in self._leaves for d in l.devices()})
+
     def reweight(self, blob: bytes):
-        """Install a weights npz blob (shapes must match the artifact's)."""
+        """Install a weights npz blob (shapes must match the artifact's);
+        same shapes and dtypes, so the compiled program is reused."""
         new = _load_weights_blob(blob, self.manifest["num_weights"])
         for i, (old, nw) in enumerate(zip(self._leaves, new)):
             if old.shape != nw.shape or old.dtype != nw.dtype:

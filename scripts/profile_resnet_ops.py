@@ -1,6 +1,6 @@
 """Per-op breakdown of ResNet50 bf16 step time on the TPU chip.
 
-VERDICT r4 weakness #4: best measured MFU was ~41% with no evidence of
+Round-4 review weakness #4: best measured MFU was ~41% with no evidence of
 where the ceiling is.  This script times every parametric op of the
 deployed graph standalone (scan-amortized, batch-128 bf16, same layouts
 as the pipeline), compares each against its FLOP lower bound at chip

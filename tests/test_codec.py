@@ -183,8 +183,9 @@ def test_lzb_expansion_worst_case_bound():
 
 def test_ensure_built_contract(tmp_path):
     """Shared native builder: builds when missing, rebuilds when the
-    source is newer, refuses to bless a stale .so when the rebuild
-    fails (callers then use their NumPy fallback, never stale code)."""
+    source is as new or newer, refuses to bless a stale .so when the
+    rebuild fails (callers then use their NumPy fallback, never stale
+    code — and say so)."""
     import os
     import shutil
     import time
@@ -200,9 +201,16 @@ def test_ensure_built_contract(tmp_path):
     assert so.exists()
     first = so.stat().st_mtime_ns
 
-    # fresh so, older-or-equal src: no rebuild
+    # fresh so, strictly older src: no rebuild
     assert ensure_built(str(src), str(so))
     assert so.stat().st_mtime_ns == first
+
+    # a source edit in the SAME clock tick as the build is stale (>=):
+    # the binary may predate the edit
+    os.utime(src, ns=(first, first))
+    assert ensure_built(str(src), str(so))
+    assert so.stat().st_mtime_ns > first
+    first = so.stat().st_mtime_ns
 
     # newer src: rebuild happens (mtime moves)
     time.sleep(0.01)
@@ -217,3 +225,18 @@ def test_ensure_built_contract(tmp_path):
     os.utime(src, ns=(time.time_ns(), time.time_ns()))
     assert not ensure_built(str(src), str(so))
     assert not [p for p in tmp_path.iterdir() if ".build." in p.name]
+
+
+def test_native_loader_says_which_path_it_took(tmp_path, monkeypatch,
+                                                capfd):
+    """A failed build is not a silent switch to NumPy: the loader names
+    the library and the path it takes instead."""
+    from defer_tpu.utils import _nativebuild
+    (tmp_path / "codec.cpp").write_text("this is not C++\n")
+    monkeypatch.setattr(_nativebuild, "NATIVE_DIR", str(tmp_path))
+    assert _nativebuild.load_library("codec", "libx.so", "NumPy codec") \
+        is None
+    err = capfd.readouterr().err
+    assert "libx.so failed" in err
+    assert "native codec library unavailable; taking the NumPy codec " \
+        "path" in err

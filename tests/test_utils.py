@@ -153,6 +153,13 @@ def test_flush_artifact_atomic_merge(tmp_path):
                        merge_key="rows",
                        row_filter=lambda k: k.startswith("spec_"))
     assert f["value"] == 2.0
+    # a failed row that carries the key with None under it is skipped,
+    # not fed to max() (xla_flag_sweep rows of a bench that died)
+    n = flush_artifact(None, {"value": 0.0,
+                              "rows": {"dead": {"tokens_per_s": None},
+                                       "ok": {"tokens_per_s": 3.0}}},
+                       merge_key="rows")
+    assert n["value"] == 3.0
     # empty prior file must not crash the flush (the touch/stray-redirect
     # scenario)
     e = str(tmp_path / "empty.json")
@@ -168,9 +175,10 @@ def test_flush_artifact_atomic_merge(tmp_path):
 
 
 def test_ring_jit_kwargs_contract(monkeypatch):
-    """Ring programs get the TPU defaults only on non-CPU meshes; env
-    options merge over (and can disable) the defaults; CPU meshes never
-    receive TPU-only flags implicitly."""
+    """Ring programs get the TPU defaults only on TPU meshes; env
+    options merge over (and can disable) the defaults; no other
+    platform's compile — CPU or anything else that merely is not the
+    CPU — receives TPU-only flags implicitly."""
     import numpy as np
     import jax
     from defer_tpu.utils.xla_opts import (RING_DEFAULTS, compiler_options,
@@ -189,6 +197,11 @@ def test_ring_jit_kwargs_contract(monkeypatch):
     class FakeTpu:
         platform = "tpu"
 
+    class FakeGpu:
+        platform = "gpu"
+
+    assert ring_jit_kwargs([FakeGpu()]) == {
+        "compiler_options": {"a": "1", "b": "two"}}
     tpu_devices = [FakeTpu()]
     opts = ring_jit_kwargs(tpu_devices)["compiler_options"]
     assert opts["a"] == "1"
