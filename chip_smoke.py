@@ -483,7 +483,7 @@ def phase_decode(run: Run) -> None:
             jnp.uint32(0), jnp.float32(0.0), caches)
         ph.note(mosaic_kernels_in_prefill_program=kernels,
                 weight_shard_device_ids=shard_device_ids(dec._w),
-                cache_shard_device_ids=shard_device_ids(caches["k"]))
+                cache_shard_device_ids=shard_device_ids(caches["k"][0]))
         if sz.expect_mosaic and kernels < 1:
             raise AssertionError(
                 "the prefill program holds no TPU custom call: flash "

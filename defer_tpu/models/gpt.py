@@ -130,6 +130,71 @@ class CausalTransformerBlock(TransformerBlock):
         q = jnp.clip(jnp.round(rowf / scale[..., None]), -127, 127)
         return q.astype(jnp.int8), scale
 
+    def decode_qkv(self, params, x, *, quant: bool = False):
+        """First half of :meth:`decode`: LN + qkv projection of ``x``
+        [b, d], and the new cache rows (the pipelined decoder writes them
+        straight into its resident buffers).  Returns ``(q, rows)`` with
+        ``rows`` keyed as the caches are: ``k``/``v`` [b, kv, 1, hd]; with
+        ``quant`` those are int8 by :meth:`quantize_row` and their
+        [b, kv, 1] f32 scales come as ``ks``/``vs``."""
+        p = _cast(params, x.dtype)
+        b, d = x.shape
+        kv = self.kv_heads
+        hd = d // self.num_heads
+
+        y = self._ln(p["ln1"], x, self.ln_eps)
+        qkv = y @ p["qkv"]["w"] + p["qkv"]["b"]
+        q, k_new, v_new = self._split_qkv(qkv)
+        rows = {"k": k_new.reshape(b, kv, 1, hd),
+                "v": v_new.reshape(b, kv, 1, hd)}
+        if quant:
+            rows["k"], rows["ks"] = self.quantize_row(rows["k"])
+            rows["v"], rows["vs"] = self.quantize_row(rows["v"])
+        return q, rows
+
+    @staticmethod
+    def write_row(cache, row, pos, lead=()):
+        """``cache`` with ``row`` written in place at position ``pos``.
+        ``cache`` is [b, kv, L(, hd)] behind ``len(lead)`` more axes, at
+        whose indices ``lead`` the row lands; the row is cast to the
+        cache's type."""
+        row = lax.expand_dims(row, range(len(lead))).astype(cache.dtype)
+        at = tuple(lead) + (0, 0, pos) + (0,) * (row.ndim - len(lead) - 3)
+        return lax.dynamic_update_slice(cache, row, at)
+
+    def decode_attend(self, params, x, q, k_cache, v_cache, pos,
+                      k_scale=None, v_scale=None):
+        """Second half of :meth:`decode`: attention of ``q`` over the
+        cache item (positions <= ``pos``, the new row already in it),
+        then proj + MLP on the residual stream ``x``.  Reads the caches
+        only; returns the block's output [b, d]."""
+        p = _cast(params, x.dtype)
+        b, d = x.shape
+        nh = self.num_heads
+        kv = self.kv_heads
+        grp = nh // kv
+        hd = d // nh
+        cache_len = k_cache.shape[2]
+        quant = k_scale is not None
+
+        qh = q.reshape(b, kv, grp, hd)
+        kh = k_cache.astype(x.dtype)
+        vh = v_cache.astype(x.dtype)
+        att = jnp.einsum("bkgd,bkld->bkgl", qh, kh) / math.sqrt(hd)
+        if quant:
+            att = att * k_scale[:, :, None, :].astype(att.dtype)
+        live = jnp.arange(cache_len)[None, None, None, :] <= pos
+        att = jnp.where(live, att, jnp.asarray(-jnp.inf, att.dtype))
+        att = jax.nn.softmax(att, axis=-1)
+        if quant:
+            att = att * v_scale[:, :, None, :].astype(att.dtype)
+        y = jnp.einsum("bkgl,bkld->bkgd", att, vh).reshape(b, d)
+        x = x + (y @ p["proj"]["w"] + p["proj"]["b"])
+
+        y = self._ln(p["ln2"], x, self.ln_eps)
+        y = jax.nn.gelu(y @ p["fc1"]["w"] + p["fc1"]["b"])
+        return x + (y @ p["fc2"]["w"] + p["fc2"]["b"])
+
     def decode(self, params, x, k_cache, v_cache, pos,
                k_scale=None, v_scale=None):
         """One-token step: ``x`` [b, d] at position ``pos``.
@@ -148,48 +213,21 @@ class CausalTransformerBlock(TransformerBlock):
         exactly (per-row constants), so ICI^W HBM reads shrink to ~1
         byte/value.  Returns ``(y, k_cache, v_cache)`` plus the updated
         scales when quantized.
+
+        The composition of :meth:`decode_qkv`, the row writes and
+        :meth:`decode_attend`, for callers that hold one cache item a
+        block (``serve/engine.py``); the pipelined decoder calls the
+        halves and writes the rows into its own buffers.
         """
-        p = _cast(params, x.dtype)
-        b, d = x.shape
-        nh = self.num_heads
-        kv = self.kv_heads
-        grp = nh // kv
-        hd = d // nh
-        cache_len = k_cache.shape[2]
         quant = k_scale is not None
-
-        y = self._ln(p["ln1"], x, self.ln_eps)
-        qkv = y @ p["qkv"]["w"] + p["qkv"]["b"]
-        q, k_new, v_new = self._split_qkv(qkv)
-        k_row = k_new.reshape(b, kv, 1, hd)
-        v_row = v_new.reshape(b, kv, 1, hd)
+        q, rows = self.decode_qkv(params, x, quant=quant)
         if quant:
-            k_row, ks_row = self.quantize_row(k_row)
-            v_row, vs_row = self.quantize_row(v_row)
-            k_scale = lax.dynamic_update_slice(k_scale, ks_row, (0, 0, pos))
-            v_scale = lax.dynamic_update_slice(v_scale, vs_row, (0, 0, pos))
-        k_cache = lax.dynamic_update_slice(
-            k_cache, k_row.astype(k_cache.dtype), (0, 0, pos, 0))
-        v_cache = lax.dynamic_update_slice(
-            v_cache, v_row.astype(v_cache.dtype), (0, 0, pos, 0))
-
-        qh = q.reshape(b, kv, grp, hd)
-        kh = k_cache.astype(x.dtype)
-        vh = v_cache.astype(x.dtype)
-        att = jnp.einsum("bkgd,bkld->bkgl", qh, kh) / math.sqrt(hd)
-        if quant:
-            att = att * k_scale[:, :, None, :].astype(att.dtype)
-        live = jnp.arange(cache_len)[None, None, None, :] <= pos
-        att = jnp.where(live, att, jnp.asarray(-jnp.inf, att.dtype))
-        att = jax.nn.softmax(att, axis=-1)
-        if quant:
-            att = att * v_scale[:, :, None, :].astype(att.dtype)
-        y = jnp.einsum("bkgl,bkld->bkgd", att, vh).reshape(b, d)
-        x = x + (y @ p["proj"]["w"] + p["proj"]["b"])
-
-        y = self._ln(p["ln2"], x, self.ln_eps)
-        y = jax.nn.gelu(y @ p["fc1"]["w"] + p["fc1"]["b"])
-        out = x + (y @ p["fc2"]["w"] + p["fc2"]["b"])
+            k_scale = self.write_row(k_scale, rows["ks"], pos)
+            v_scale = self.write_row(v_scale, rows["vs"], pos)
+        k_cache = self.write_row(k_cache, rows["k"], pos)
+        v_cache = self.write_row(v_cache, rows["v"], pos)
+        out = self.decode_attend(params, x, q, k_cache, v_cache, pos,
+                                 k_scale, v_scale)
         if quant:
             return out, k_cache, v_cache, k_scale, v_scale
         return out, k_cache, v_cache

@@ -14,12 +14,16 @@ TPU-native design, one SPMD program:
   * Weights: each device materializes only its stage's parameters from a
     stage-sharded flat buffer (same scheme as ``SpmdPipeline``), stored in
     the compute dtype.
-  * KV caches: a per-device resident array
-    ``[Lmax, N+1, mb, nh, max_len+1, hd]`` (local blocks x groups,
-    head-major so attention needs no per-step cache transpose) in compute
-    dtype; position row ``max_len`` is a scratch slot that warmup bubbles
-    write into, and group slot ``N`` absorbs prefill bubbles — so no
-    masked read-modify-write of the cache is ever needed.
+  * KV caches: per device, one resident buffer a local block,
+    ``[N+1, mb, nh, max_len+1, hd]`` (groups; head-major so attention
+    needs no per-step cache transpose) in compute dtype; position row
+    ``max_len`` is a scratch slot that warmup bubbles write into, and
+    group slot ``N`` absorbs prefill bubbles — so no masked
+    read-modify-write of the cache is ever needed.  A step writes one
+    ``[mb, nh, 1, hd]`` row a block in place and reads the group's item;
+    the blocks are never stacked into one array, because XLA:TPU wraps a
+    write into a value that large in copies of all of it
+    (docs/DECODE_CLIFF.md).
   * The ring carry is one ``[mb, d]`` float32 buffer per device: stage
     activations in flight, and — on the wrap link from the last stage back
     to stage 0 (the reference's node->dispatcher link,
@@ -85,6 +89,19 @@ def _split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
         raise ValueError(
             f"{num_blocks} blocks cannot fill {num_stages} stages")
     return out
+
+
+def _group_slice(buf, g):
+    """Group ``g``'s part ``[1, ...]`` of a ``[N+1, ...]`` cache buffer."""
+    return lax.dynamic_slice(buf, (g,) + (0,) * (buf.ndim - 1),
+                             (1,) + buf.shape[1:])
+
+
+def _with_block(caches: dict, key: str, l: int, buf) -> dict:
+    """``caches`` with local block ``l``'s buffer of entry ``key`` replaced."""
+    bufs = list(caches[key])
+    bufs[l] = buf
+    return dict(caches, **{key: tuple(bufs)})
 
 
 class PipelinedDecoder:
@@ -210,16 +227,16 @@ class PipelinedDecoder:
         self._wspec_tree = jax.tree.map(lambda _: P(STAGE_AXIS, None),
                                         self._w)
 
-        # group axis is n+1: slot n is the scratch group that pipelined
+        # one local block's buffer (the state holds l_max of each).  Group
+        # axis is n+1: slot n is the scratch group that pipelined
         # prefill's warmup/drain bubbles write into (the group-axis twin of
         # the max_len scratch row).  Head-major position axis per the
         # CausalTransformerBlock.decode cache contract; under GQA the head
         # axis is the (smaller) KV head count.
-        self._cache_shape = (self.l_max, n + 1, mb, self.num_kv_heads,
-                             max_len + 1, self.head_dim)
+        self._cache_shape = (n + 1, mb, self.num_kv_heads, max_len + 1,
+                             self.head_dim)
         #: per-row f32 scales for the int8 cache (one per head x position)
-        self._scale_shape = (self.l_max, n + 1, mb, self.num_kv_heads,
-                             max_len + 1)
+        self._scale_shape = (n + 1, mb, self.num_kv_heads, max_len + 1)
         #: ring-buffer width: beam mode adds one column carrying each
         #: row's parent-beam index around the ring alongside the token id
         self._ring_width = self.d_model + (1 if beam_width > 1 else 0)
@@ -289,16 +306,6 @@ class PipelinedDecoder:
             w_local["q"], w_local["s"], self._wmeta[s], self._smeta[s],
             self._wtreedef[s], self.compute_dtype)
 
-    def _slice_lg(self, arr, l, g):
-        """[Lmax, N+1, ...] cache entry -> the (block l, group g) item."""
-        return lax.dynamic_slice(
-            arr, (l, g) + (0,) * (arr.ndim - 2),
-            (1, 1) + arr.shape[2:])[0, 0]
-
-    def _write_lg(self, arr, item, l, g):
-        return lax.dynamic_update_slice(
-            arr, item[None, None], (l, g) + (0,) * (arr.ndim - 2))
-
     def _make_branch(self, s: int, sample: bool, top_k: int | None):
         """Stage ``s``'s step: consume the ring buffer, update caches.
 
@@ -341,16 +348,14 @@ class PipelinedDecoder:
                 applies = jnp.logical_and(valid, safe_pos >= plen)
 
                 def reparent_all(cs):
-                    def reparent(ent):
-                        # [Lmax, n+1, mb, ...] -> rows of group g gathered
-                        grp = lax.dynamic_slice(
-                            ent, (0, g) + (0,) * (ent.ndim - 2),
-                            (ent.shape[0], 1) + ent.shape[2:])
-                        grp = jnp.take(grp, parents, axis=2)
+                    def reparent(buf):
+                        # [n+1, mb, ...] -> rows of group g gathered
+                        grp = jnp.take(_group_slice(buf, g), parents, axis=1)
                         return lax.dynamic_update_slice(
-                            ent, grp, (0, g) + (0,) * (ent.ndim - 2))
+                            buf, grp, (g,) + (0,) * (buf.ndim - 1))
 
-                    return {nm: (reparent(c) if nm != "beam_cum" else c)
+                    return {nm: (jax.tree.map(reparent, c)
+                                 if nm != "beam_cum" else c)
                             for nm, c in cs.items()}
 
                 caches = lax.cond(applies, reparent_all,
@@ -374,22 +379,20 @@ class PipelinedDecoder:
 
             for l, (nm, op) in enumerate(zip(self.stage_blocks[s],
                                              block_ops)):
-                k_l = self._slice_lg(caches["k"], l, g)
-                v_l = self._slice_lg(caches["v"], l, g)
-                if int8:
-                    ks_l = self._slice_lg(caches["ks"], l, g)
-                    vs_l = self._slice_lg(caches["vs"], l, g)
-                    x, k_l, v_l, ks_l, vs_l = op.decode(
-                        p[nm], x, k_l, v_l, write_pos, ks_l, vs_l)
-                    caches = dict(
-                        caches,
-                        ks=self._write_lg(caches["ks"], ks_l, l, g),
-                        vs=self._write_lg(caches["vs"], vs_l, l, g))
-                else:
-                    x, k_l, v_l = op.decode(p[nm], x, k_l, v_l, write_pos)
-                caches = dict(caches,
-                              k=self._write_lg(caches["k"], k_l, l, g),
-                              v=self._write_lg(caches["v"], v_l, l, g))
+                # write the new rows in place (one position of one group
+                # of one block), then attend over a read-only slice of
+                # the group's item: nothing the size of an item is
+                # written back
+                q, rows = op.decode_qkv(p[nm], x, quant=int8)
+                item = {}
+                for key, row in rows.items():
+                    buf = op.write_row(caches[key][l], row, write_pos,
+                                       lead=(g,))
+                    caches = _with_block(caches, key, l, buf)
+                    item[key] = _group_slice(buf, g)[0]
+                x = op.decode_attend(p[nm], x, q, item["k"], item["v"],
+                                     write_pos, item.get("ks"),
+                                     item.get("vs"))
 
             if is_last:
                 h = nodes["final_ln"].op.apply(p["final_ln"], x)
@@ -488,25 +491,17 @@ class PipelinedDecoder:
                 # head-major relayout (one transpose per prompt, amortized)
                 k = k.reshape(mb, plen, kvh, hd).transpose(0, 2, 1, 3)
                 v = v.reshape(mb, plen, kvh, hd).transpose(0, 2, 1, 3)
+                new = {"k": k, "v": v}
                 if int8:
-                    k, ks = op.quantize_row(k)   # [mb, kv, plen] scales
-                    v, vs = op.quantize_row(v)
-                    caches = dict(
-                        caches,
-                        ks=lax.dynamic_update_slice(
-                            caches["ks"], ks[None, None],
-                            (l, write_g, 0, 0, 0)),
-                        vs=lax.dynamic_update_slice(
-                            caches["vs"], vs[None, None],
-                            (l, write_g, 0, 0, 0)))
-                caches = dict(
-                    caches,
-                    k=lax.dynamic_update_slice(
-                        caches["k"], k[None, None].astype(
-                            caches["k"].dtype), (l, write_g, 0, 0, 0, 0)),
-                    v=lax.dynamic_update_slice(
-                        caches["v"], v[None, None].astype(
-                            caches["v"].dtype), (l, write_g, 0, 0, 0, 0)))
+                    # [mb, kv, plen] scales
+                    new["k"], new["ks"] = op.quantize_row(k)
+                    new["v"], new["vs"] = op.quantize_row(v)
+                for key, rows in new.items():
+                    buf = caches[key][l]
+                    buf = lax.dynamic_update_slice(
+                        buf, rows[None].astype(buf.dtype),
+                        (write_g,) + (0,) * (buf.ndim - 1))
+                    caches = _with_block(caches, key, l, buf)
 
             if is_last:
                 h = nodes["final_ln"].op.apply(p["final_ln"], x[:, -1])
@@ -530,11 +525,13 @@ class PipelinedDecoder:
 
     def _state_specs(self):
         """shard_map spec pytree for the cache-state dict."""
-        spec7 = P(STAGE_AXIS, None, None, None, None, None, None)
-        specs = {"k": spec7, "v": spec7}
+        def per_block(rank):
+            # one buffer a local block, never one array of the whole stack
+            return (P(STAGE_AXIS, *(None,) * rank),) * self.l_max
+
+        specs = {"k": per_block(5), "v": per_block(5)}
         if self.kv_cache == "int8":
-            spec6 = P(STAGE_AXIS, None, None, None, None, None)
-            specs.update(ks=spec6, vs=spec6)
+            specs.update(ks=per_block(4), vs=per_block(4))
         if self.beam_width > 1:
             # per-group cumulative beam scores; only the LAST stage's
             # device shard is meaningful (it runs the expansion)
@@ -594,13 +591,15 @@ class PipelinedDecoder:
                 else self.compute_dtype
 
             def zeros():
-                caches = {"k": jnp.zeros((n,) + self._cache_shape, cdt),
-                          "v": jnp.zeros((n,) + self._cache_shape, cdt)}
+                def per_block(shape, dtype):
+                    return tuple(jnp.zeros((n,) + shape, dtype)
+                                 for _ in range(self.l_max))
+
+                caches = {"k": per_block(self._cache_shape, cdt),
+                          "v": per_block(self._cache_shape, cdt)}
                 if self.kv_cache == "int8":
-                    caches["ks"] = jnp.zeros((n,) + self._scale_shape,
-                                             jnp.float32)
-                    caches["vs"] = jnp.zeros((n,) + self._scale_shape,
-                                             jnp.float32)
+                    caches["ks"] = per_block(self._scale_shape, jnp.float32)
+                    caches["vs"] = per_block(self._scale_shape, jnp.float32)
                 if self.beam_width > 1:
                     caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
                 return (jnp.zeros((n, mb, self._ring_width), jnp.float32),

@@ -2,9 +2,10 @@
 
 The mb64 bf16 decode cliff (docs/DECODE_CLIFF.md, DECODE_r05.json:
 560 ms/token-step against 26 ms for the int8-KV variant of the SAME
-shapes, with a 96.8 s first call) is a compile-side pathology, so the
-guard this smoke pins down is the mechanism the cliff would have to
-break through on the host side:
+shapes, with a 96.8 s first call) was a device-side pathology (the
+compiler's whole-stack copies around the stacked cache's write-back,
+cured in PR 25), so the guard this smoke pins down is the mechanism a
+cliff would have to break through on the host side:
 
 1. ZERO STEADY-STATE RECOMPILES: after one warmup ``generate``, a
    second ``generate`` with identical arguments must reach XLA ZERO

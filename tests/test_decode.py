@@ -211,7 +211,7 @@ def test_gqa_decode_matches_references(prompt):
     dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                            max_len=MAX_LEN)
     assert dec.num_kv_heads == 1 and dec.num_heads == 2
-    assert dec._cache_shape[3] == 1          # cache halved vs MHA
+    assert dec._cache_shape[2] == 1          # cache halved vs MHA
     got = dec.generate(prompt, max_new_tokens=8)
     want = incremental_greedy(graph, params, prompt, 5 + 8, MAX_LEN)
     np.testing.assert_array_equal(got, want)
@@ -250,7 +250,7 @@ def test_int8_kv_cache(model, prompt):
                                max_len=MAX_LEN)
     q_dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                              max_len=MAX_LEN, kv_cache="int8")
-    assert q_dec._init_state()[1]["k"].dtype == jnp.int8
+    assert q_dec._init_state()[1]["k"][0].dtype == jnp.int8
     ref = ref_dec.generate(prompt, max_new_tokens=8)
     got = q_dec.generate(prompt, max_new_tokens=8)
     # tokens may differ where logits are within quant error; demand strong
@@ -716,3 +716,108 @@ def test_gpt_full_sequence_pipeline(model):
         np.asarray(graph.apply(params, jnp.asarray(m, jnp.int32)))
         for m in ids])
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+# -- the ring's cache state: a row written in place, no stacked array ---------
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (list, tuple)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of the programs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _decode_scan_body(dec, chunk_steps):
+    n, mb = dec.num_stages, dec.microbatch
+    a, caches = dec._init_state()
+    jaxpr = jax.make_jaxpr(dec._get_decode_fn(chunk_steps, False, None))(
+        dec._w, jnp.zeros((n, mb, 5), jnp.int32), jnp.int32(5),
+        jnp.int32(0), jnp.int32(chunk_steps), jnp.uint32(0),
+        jnp.float32(0.0), jnp.zeros((n, mb), jnp.int32), jnp.int32(-1),
+        jnp.int32(0), a, caches)
+    scans = [e for e in _walk(jaxpr.jaxpr) if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    return scans[0].params["jaxpr"].jaxpr
+
+
+@pytest.mark.parametrize("kv_cache,num_stages,beam", [
+    ("buffer", 1, 1), ("buffer", 4, 1), ("int8", 1, 1), ("int8", 4, 1),
+    ("buffer", 2, 2)])
+def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
+                                                        num_stages, beam):
+    """Structural guard of the decode program's scan body: every write
+    into a K/V (or scale) buffer is one position wide (beam search may
+    also re-parent one whole group), and no value has the shape of the
+    stack of all local blocks' caches — the shape whose whole-stack
+    copies were 92% of a step on the chip (docs/DECODE_CLIFF.md)."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=8 // num_stages, max_len=MAX_LEN,
+                           kv_cache=kv_cache, beam_width=beam)
+    body = _decode_scan_body(dec, 2 * num_stages)
+    buffers = {dec._cache_shape}
+    if kv_cache == "int8":
+        buffers.add(dec._scale_shape)
+    stacked = {(dec.l_max,) + sh for sh in buffers}
+    rows = groups = 0
+    for eqn in _walk(body):
+        for v in list(eqn.invars) + list(eqn.outvars):
+            assert getattr(v.aval, "shape", None) not in stacked, eqn
+        if eqn.primitive.name != "dynamic_update_slice":
+            continue
+        buf, upd = eqn.invars[0].aval.shape, eqn.invars[1].aval.shape
+        if buf not in buffers:
+            continue
+        if upd[3] == 1:
+            assert upd[0] == 1      # one group's rows, one position
+            rows += 1
+        else:
+            assert beam > 1 and upd == (1,) + buf[1:], (buf, upd)
+            groups += 1
+    blocks = sum(len(b) for b in dec.stage_blocks)
+    assert rows == blocks * len(buffers) * 2        # k and v (and scales)
+    assert groups == (dec.l_max * len(buffers) * 2 * num_stages
+                      if beam > 1 else 0)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["buffer", "int8"])
+def test_block_decode_is_its_two_halves(model, quant):
+    """``decode()`` == ``decode_qkv`` + the row writes + ``decode_attend``
+    (what the pipelined decoder runs against its own buffers)."""
+    graph, params = model
+    op: CausalTransformerBlock = graph.nodes["block_1"].op
+    p = params["block_1"]
+    rng = np.random.default_rng(5)
+    b, d, cache_len, pos = 3, 32, 9, 4
+    hd = d // op.num_heads
+    x = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
+    item = (b, op.kv_heads, cache_len, hd)
+    if quant:
+        kc = jnp.asarray(rng.integers(-127, 128, item), jnp.int8)
+        vc = jnp.asarray(rng.integers(-127, 128, item), jnp.int8)
+        scales = [jnp.asarray(rng.uniform(0.01, 0.1, item[:3]), jnp.float32)
+                  for _ in range(2)]
+    else:
+        kc = jnp.asarray(rng.standard_normal(item), jnp.float32)
+        vc = jnp.asarray(rng.standard_normal(item), jnp.float32)
+        scales = []
+    want = op.decode(p, x, kc, vc, pos, *scales)
+
+    q, rows = op.decode_qkv(p, x, quant=quant)
+    caches = {"k": kc, "v": vc, **dict(zip(("ks", "vs"), scales))}
+    assert {key: r.shape for key, r in rows.items()} == \
+        {key: c.shape[:2] + (1,) + c.shape[3:] for key, c in caches.items()}
+    got_caches = [c.at[:, :, pos: pos + 1].set(rows[key].astype(c.dtype))
+                  for key, c in caches.items()]
+    got = op.decode_attend(p, x, q, *got_caches[:2], pos, *got_caches[2:])
+    for w, g in zip(want, [got] + got_caches):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
