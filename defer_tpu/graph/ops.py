@@ -244,6 +244,26 @@ class LayerNorm(Op):
             * p["scale"] + p["bias"]
 
 
+def rms_norm(x, scale, eps):
+    """``x / rms(x) * scale`` over the last axis, statistics in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True, repr=False)
+class RMSNorm(Op):
+    eps: float = 1e-5
+
+    def init(self, key, in_specs):
+        del key
+        (spec,) = in_specs
+        return {"scale": jnp.ones((spec.shape[-1],), jnp.float32)}
+
+    def apply(self, params, x):
+        return rms_norm(x, params["scale"], self.eps)
+
+
 # ---------------------------------------------------------------------------
 # activations / pooling / structural
 # ---------------------------------------------------------------------------
@@ -706,14 +726,47 @@ class TransformerBlock(Op):
 # ---------------------------------------------------------------------------
 
 
+def route_top_k(logits, k: int):
+    """Softmax over the experts in float32, then the ``k`` largest:
+    ``(expert ids [..., k], their probabilities [..., k])``, the
+    probabilities as the softmax gave them (not renormalised)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    p, eid = lax.top_k(probs, k)
+    return eid, p
+
+
+def expert_dispatch(x, eid, gate, num_experts: int, expert_fn):
+    """Routed experts on rows grouped by expert: every (row, choice) pair
+    is computed, by its own expert only, and each expert's weights are
+    read once however many rows chose it.  No capacity, nothing dropped.
+
+    ``x`` [T, d]; ``eid``/``gate`` [T, k] (:func:`route_top_k`).
+    ``expert_fn(xs, group_sizes, es)`` maps the [T*k, d] rows sorted by
+    expert (``es`` [T*k] names each row's expert, ``group_sizes`` [E]
+    counts them: the arguments of ``lax.ragged_dot``) to [T*k, d_out].
+    Returns ``(sum_k gate * expert_k(x) [T, d_out] in float32, as it was
+    summed, group_sizes)``."""
+    t, k = eid.shape
+    flat = eid.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)       # slots, grouped by expert
+    sizes = jnp.sum(flat[:, None] == jnp.arange(num_experts)[None, :],
+                    axis=0, dtype=jnp.int32)
+    ys = expert_fn(x[order // k], sizes, flat[order])
+    ys = ys[jnp.argsort(order)].reshape(t, k, -1)       # back to row order
+    y = jnp.sum(ys.astype(jnp.float32)
+                * gate[..., None].astype(jnp.float32), axis=1)
+    return y, sizes
+
+
 @dataclasses.dataclass(frozen=True, repr=False)
 class MoE(Op):
     """Switch-style top-1 mixture-of-experts FFN (with residual).
 
-    Single-device ``apply`` evaluates every expert and masks (exact, fine
-    for the MXU at small E); the expert-parallel path — experts sharded over
-    an "expert" mesh axis with capacity-based ``all_to_all`` token dispatch —
-    lives in :mod:`defer_tpu.parallel.expert` and is numerically identical
+    Single-device ``apply`` groups rows by expert (:func:`expert_dispatch`
+    at ``k`` = 1: one expert a token is computed); the expert-parallel
+    path — experts sharded over an "expert" mesh axis with capacity-based
+    ``all_to_all`` token dispatch — lives in
+    :mod:`defer_tpu.parallel.expert` and is numerically identical
     whenever no token exceeds capacity.
     """
 
@@ -737,11 +790,8 @@ class MoE(Op):
 
     def route(self, params, x):
         """Top-1 routing: (expert_id [b,t], gate_prob [b,t])."""
-        logits = x @ params["gate"].astype(x.dtype)
-        probs = jax.nn.softmax(logits, axis=-1)
-        eid = jnp.argmax(logits, axis=-1)
-        pe = jnp.take_along_axis(probs, eid[..., None], axis=-1)[..., 0]
-        return eid, pe
+        eid, pe = route_top_k(x @ params["gate"].astype(x.dtype), 1)
+        return eid[..., 0], pe[..., 0].astype(x.dtype)
 
     def expert_fn(self, params, x, eid):
         """Run expert ``eid`` (array, broadcastable) on tokens ``x``.
@@ -759,17 +809,19 @@ class MoE(Op):
         return jnp.einsum("...h,...hd->...d", h, w2) + b2
 
     def apply(self, params, x):
+        # the top_k=1, GELU, biased case of the one dispatch
         eid, pe = self.route(params, x)
-        b, t, d = x.shape
-        e = self.num_experts
-        h1 = jax.nn.gelu(
-            jnp.einsum("btd,edh->bteh", x, params["fc1"]["w"].astype(x.dtype))
-            + params["fc1"]["b"].astype(x.dtype))
-        y = (jnp.einsum("bteh,ehd->bted", h1,
-                        params["fc2"]["w"].astype(x.dtype))
-             + params["fc2"]["b"].astype(x.dtype))
-        sel = jax.nn.one_hot(eid, e, dtype=x.dtype)
-        return x + (y * sel[..., None]).sum(axis=2) * pe[..., None]
+        fc1, fc2 = _cast((params["fc1"], params["fc2"]), x.dtype)
+
+        def experts(xs, sizes, es):
+            h = jax.nn.gelu(lax.ragged_dot(xs, fc1["w"], sizes)
+                            + fc1["b"][es])
+            return lax.ragged_dot(h, fc2["w"], sizes) + fc2["b"][es]
+
+        d = x.shape[-1]
+        y, _ = expert_dispatch(x.reshape(-1, d), eid.reshape(-1, 1),
+                               pe.reshape(-1, 1), self.num_experts, experts)
+        return x + y.reshape(x.shape).astype(x.dtype)
 
     def flops(self, in_specs, out_spec):
         (spec,) = in_specs

@@ -13,6 +13,7 @@ from .bert import BERT_BASE_12STAGE_CUTS, bert, bert_base, bert_tiny
 from .gpt import gpt, gpt2_small, gpt_small, gpt_stage_cuts, gpt_tiny
 from .moe import (moe_branched, moe_branched_tiny, moe_stage_cuts,
                   moe_tiny, moe_transformer)
+from .olmoe import olmoe, olmoe_tiny
 from .inception import (INCEPTION_6STAGE_CUTS, inception, inception_tiny,
                         inception_v3)
 from .mobilenet import (MOBILENETV2_2STAGE_CUTS, mobilenet_tiny, mobilenet_v2)
@@ -28,4 +29,5 @@ __all__ = [
     "gpt", "gpt2_small", "gpt_small", "gpt_tiny", "gpt_stage_cuts",
     "moe_transformer", "moe_tiny", "moe_stage_cuts",
     "moe_branched", "moe_branched_tiny",
+    "olmoe", "olmoe_tiny",
 ]

@@ -41,8 +41,9 @@ from .journal import (JOURNAL_VERSION, JournalSpiller, JournalWriter,
                       read_process_journals, start_journal, stop_journal)
 from .postmortem import (BUNDLE_VERSION, collect as collect_postmortem,
                          maybe_autopsy)
-from .profile import (DECODE_PHASES, DOOR_PHASES, ENGINE_LOOP_PHASES,
-                      ENGINE_PHASES, NODE_PHASES, SPAN_LAYERS,
+from .profile import (DECODE_PHASES, DECODE_STATS_PHASES, DOOR_PHASES,
+                      ENGINE_LOOP_PHASES, ENGINE_PHASES, NODE_PHASES,
+                      SPAN_LAYERS,
                       MemoryWatcher, ProfileSession, RecompileWatcher,
                       device_memory_bytes, memory_watcher,
                       recompile_watcher)
@@ -66,7 +67,7 @@ __all__ = [
     "read_journal", "read_process_journals",
     "BUNDLE_VERSION", "collect_postmortem", "maybe_autopsy",
     "NODE_PHASES", "ENGINE_PHASES", "ENGINE_LOOP_PHASES", "DECODE_PHASES",
-    "DOOR_PHASES", "SPAN_LAYERS", "ProfileSession",
+    "DECODE_STATS_PHASES", "DOOR_PHASES", "SPAN_LAYERS", "ProfileSession",
     "RecompileWatcher", "recompile_watcher",
     "MemoryWatcher", "memory_watcher", "device_memory_bytes",
 ]

@@ -76,6 +76,11 @@ ENGINE_LOOP_PHASES = ("join", "park")
 #: dispatch, scatter and emit the device has nothing queued.
 DECODE_PHASES = ("prefill", "dispatch", "sync", "scatter", "emit")
 
+#: after a generation's last chunk, where the ring's blocks sow per-step
+#: statistics (``DecoderBlock.decode_stats``; today the routed experts'
+#: ``decode.moe.*`` counters): the one fetch of their device-side sums
+DECODE_STATS_PHASES = ("moe_stats",)
+
 #: the front door's per-request phase on the client's reader thread
 #: (serve/frontdoor.py): prompt frame received -> queued or shed
 DOOR_PHASES = ("admit",)
@@ -87,7 +92,7 @@ DOOR_PHASES = ("admit",)
 #: (``serve.decode.step_s`` stays the engine's dispatch-to-sync total).
 #: A name that is not spelled here does not exist.
 SPAN_LAYERS = {
-    "decode": ("decode", "generate", DECODE_PHASES),
+    "decode": ("decode", "generate", DECODE_PHASES + DECODE_STATS_PHASES),
     "engine": ("serve.decode", "step", ENGINE_PHASES + ENGINE_LOOP_PHASES),
     "door": ("serve.door", None, DOOR_PHASES),
 }

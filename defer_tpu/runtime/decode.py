@@ -42,8 +42,12 @@ TPU-native design, one SPMD program:
 
 Scope: stage-axis-only mesh, the ``gpt()`` node-name contract
 (``embeddings`` / ``block_i`` / ``final_ln`` / ``lm_head`` —
-models/gpt.py).  Prompts are processed either at decode rate (teacher
-forcing inside the scan, the default) or by the fused full-sequence
+models/gpt.py), and blocks that meet ``models.gpt.DecoderBlock``'s
+interface (``decode_qkv(params, x, pos)`` / ``write_row`` /
+``decode_attend`` / ``apply_with_kv``; an embedding with ``embed_at``):
+the ring asks a block for nothing else, whatever its family.  Prompts
+are processed either at decode rate (teacher forcing inside the scan,
+the default) or by the fused full-sequence
 pipelined prefill (``generate(..., prefill=True)``): each group's whole
 prompt crosses each stage in one causal-attention step and bulk-seeds the
 caches, dropping prompt cost from ``plen * N`` ring steps to ``2N - 1``.
@@ -61,8 +65,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.ir import LayerGraph
-from ..models.gpt import CausalTransformerBlock, GptEmbedding
-from ..obs import span
+from ..models.gpt import DecoderBlock
+from ..obs import REGISTRY, span
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
@@ -167,9 +171,9 @@ class PipelinedDecoder:
                 raise ValueError(
                     f"decoder graphs must follow the gpt() node contract; "
                     f"missing {req!r} (models/gpt.py)")
-        self.embed_op: GptEmbedding = nodes["embeddings"].op
+        self.embed_op = nodes["embeddings"].op
         if max_len is None:
-            max_len = self.embed_op.max_len  # the positional table's reach
+            max_len = self.embed_op.max_len  # the positions' reach
         self.max_len = max_len
         if max_len > self.embed_op.max_len:
             raise ValueError(
@@ -179,13 +183,19 @@ class PipelinedDecoder:
                        if nm.startswith("block_")]
         self.block_names = block_names
         for nm in block_names:
-            if not isinstance(nodes[nm].op, CausalTransformerBlock):
-                raise TypeError(f"{nm} is not a CausalTransformerBlock")
+            if not isinstance(nodes[nm].op, DecoderBlock):
+                raise TypeError(
+                    f"{nm} ({nodes[nm].op!r}) is not a DecoderBlock "
+                    "(models/gpt.py): the ring needs its decode_qkv / "
+                    "write_row / decode_attend / apply_with_kv")
         self.d_model = nodes[block_names[0]].out_spec.shape[-1]
         self.num_heads = nodes[block_names[0]].op.num_heads
         self.num_kv_heads = nodes[block_names[0]].op.kv_heads
         self.head_dim = self.d_model // self.num_heads
         self.vocab = nodes["lm_head"].out_spec.shape[-1]
+        #: per-step scalars the blocks sow (``DecoderBlock.decode_stats``);
+        #: summed on the device over a generation, fetched once at its end
+        self._stat_names = tuple(nodes[block_names[0]].op.decode_stats)
         for nm in block_names:
             op = nodes[nm].op
             if (op.num_heads, op.kv_heads) != (self.num_heads,
@@ -195,6 +205,10 @@ class PipelinedDecoder:
                     f"!= block_0's ({self.num_heads}, "
                     f"{self.num_kv_heads}); the homogeneous cache needs "
                     "one head geometry")
+            if tuple(op.decode_stats) != self._stat_names:
+                raise ValueError(
+                    f"{nm} sows {op.decode_stats}, block_0 "
+                    f"{self._stat_names}: one ledger serves every block")
 
         assign = _split_blocks(len(block_names), n)
         self.stage_blocks = [[block_names[i] for i in idxs]
@@ -211,6 +225,15 @@ class PipelinedDecoder:
                 names += ["final_ln", "lm_head"]
             stage_param_names.append(names)
         self._stage_param_names = stage_param_names
+        #: per block, the parameter subtrees that ride beside the flat row
+        #: as stage-sharded arguments of their own
+        #: (``DecoderBlock.stage_arg_keys``): a leaf sliced out of the
+        #: 1-D row is laid out anew by the compiled program, a copy that
+        #: 0.4 GB of experts a layer cannot afford.  Under W8A16 every
+        #: leaf rides the quantized rows.
+        self._own_keys = {
+            nm: () if self.weight_quant
+            else tuple(nodes[nm].op.stage_arg_keys) for nm in block_names}
 
         # weights live in the compute dtype (the runtime/spmd.py recipe):
         # bf16 deployments read 2 bytes/param from HBM per decode step with
@@ -220,12 +243,11 @@ class PipelinedDecoder:
         self._wdt = wdt
         self._wmeta, self._wtreedef = [], []
         self._smeta: list[list[tuple[int, int]]] = []  # per-leaf scale slots
-        self._w = jax.device_put(
-            self._pack_wbuf(params, init=True),
-            NamedSharding(self.mesh, P(STAGE_AXIS, None)))
-        #: shard_map spec for the weight argument (pytree under W8A16)
-        self._wspec_tree = jax.tree.map(lambda _: P(STAGE_AXIS, None),
-                                        self._w)
+        self._w = self._place_weights(params, init=True)
+        #: shard_map spec for the weight argument (a pytree under W8A16
+        #: or with leaves of their own)
+        self._wspec_tree = jax.tree.map(
+            lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
 
         # one local block's buffer (the state holds l_max of each).  Group
         # axis is n+1: slot n is the scratch group that pipelined
@@ -246,6 +268,8 @@ class PipelinedDecoder:
         #: compiled prefill programs keyed by (prompt_len, sample, top_k)
         self._prefill_fns: dict[tuple, Any] = {}
         self._init_fn = None  # cached jitted state initializer
+        #: the newest device-side sums of the blocks' sown statistics
+        self._live_stats = None
 
     # ------------------------------------------------------------------
 
@@ -257,7 +281,9 @@ class PipelinedDecoder:
         wdt = self._wdt
         flats, qflats, sflats = [], [], []
         for s, names in enumerate(self._stage_param_names):
-            sub = {nm: params[nm] for nm in names}
+            sub = {nm: {k: v for k, v in params[nm].items()
+                        if k not in self._own_keys.get(nm, ())}
+                   for nm in names}
             leaves, treedef = jax.tree.flatten(sub)
             # meta records PRE-cast shapes/dtypes so reweight validation
             # catches dtype drift before the blind wire-dtype cast
@@ -284,6 +310,57 @@ class PipelinedDecoder:
         return {"q": flatbuf.stack_rows(qflats, np.dtype(np.int8)),
                 "s": flatbuf.stack_rows(sflats, np.dtype(np.float32))}
 
+    def _pack_own(self, params) -> tuple:
+        """The leaves kept out of the flat rows, placed: per local block
+        ``l`` a ``{key: subtree}`` whose leaves are ``[N, ...]``, stage
+        ``s``'s part being its ``l``-th block's (zeros where a stage has
+        fewer blocks).  One leaf at a time goes host -> device, so the
+        host never holds a second copy of all of them; ``()`` when no
+        block names any."""
+        if not any(self._own_keys.values()):
+            return ()
+        wdt = self._wdt
+        own = []
+        for l in range(self.l_max):
+            # stage s's l-th block, or a stand-in (zeroed) where it has
+            # fewer: every leaf is [N, ...] whatever the split
+            names = [b[min(l, len(b) - 1)] for b in self.stage_blocks]
+            real = [l < len(b) for b in self.stage_blocks]
+
+            def place(*per_stage):
+                rows = [np.asarray(a).astype(wdt, copy=False) if ok
+                        else np.zeros(np.shape(a), wdt)
+                        for ok, a in zip(real, per_stage)]
+                stacked = rows[0][None] if len(rows) == 1 \
+                    else np.stack(rows)
+                return jax.device_put(stacked, NamedSharding(
+                    self.mesh, P(STAGE_AXIS, *(None,) * (stacked.ndim - 1))))
+
+            own.append(jax.tree.map(place, *(
+                {k: params[nm][k] for k in self._own_keys[nm]}
+                for nm in names)))
+        return tuple(own)
+
+    def _place_weights(self, params, *, init: bool):
+        """``params`` on the mesh as the compiled programs take them: the
+        flat rows alone, or ``{"flat": rows, "own": leaves}`` when blocks
+        keep leaves of their own."""
+        flat = jax.device_put(self._pack_wbuf(params, init=init),
+                              NamedSharding(self.mesh, P(STAGE_AXIS, None)))
+        if not init and any(self._own_keys.values()):
+            for blocks in self.stage_blocks:
+                for l, nm in enumerate(blocks):
+                    got = jax.tree.map(np.shape, {
+                        k: params[nm][k] for k in self._own_keys[nm]})
+                    want = jax.tree.map(lambda a: a.shape[1:],
+                                        self._w["own"][l])
+                    if got != want:
+                        raise ValueError(
+                            f"reweight: {nm}'s leaves outside the flat "
+                            f"rows are {got}, deployed {want}")
+        own = self._pack_own(params)
+        return {"flat": flat, "own": own} if own else flat
+
     def reweight(self, params) -> None:
         """Install fresh weights — no recompile, caches untouched.
 
@@ -294,11 +371,15 @@ class PipelinedDecoder:
         ``generate`` rounds — an in-flight generation keeps the weights
         it started with only up to its current dispatch boundary.
         """
-        self._w = jax.device_put(
-            self._pack_wbuf(params, init=False),
-            NamedSharding(self.mesh, P(STAGE_AXIS, None)))
+        self._w = self._place_weights(params, init=False)
 
     def _stage_params(self, s: int, w_local):
+        if isinstance(w_local, dict) and "own" in w_local:
+            p = flatbuf.unpack_leaves(w_local["flat"], self._wmeta[s],
+                                      self._wtreedef[s])
+            for l, nm in enumerate(self.stage_blocks[s]):
+                p[nm] = dict(p[nm], **w_local["own"][l])
+            return p
         if not self.weight_quant:
             return flatbuf.unpack_leaves(w_local, self._wmeta[s],
                                          self._wtreedef[s])
@@ -322,6 +403,7 @@ class PipelinedDecoder:
         int8 = self.kv_cache == "int8"
         beam = self.beam_width
         mb = self.microbatch
+        stats = self._stat_names
 
         def branch(w_local, a, caches, prompt, g, pos, plen, t, seed, temp,
                    first_ids, first_pos):
@@ -355,7 +437,7 @@ class PipelinedDecoder:
                             buf, grp, (g,) + (0,) * (buf.ndim - 1))
 
                     return {nm: (jax.tree.map(reparent, c)
-                                 if nm != "beam_cum" else c)
+                                 if nm not in ("beam_cum", "stats") else c)
                             for nm, c in cs.items()}
 
                 caches = lax.cond(applies, reparent_all,
@@ -383,16 +465,21 @@ class PipelinedDecoder:
                 # of one block), then attend over a read-only slice of
                 # the group's item: nothing the size of an item is
                 # written back
-                q, rows = op.decode_qkv(p[nm], x, quant=int8)
+                q, rows = op.decode_qkv(p[nm], x, safe_pos, quant=int8)
                 item = {}
                 for key, row in rows.items():
                     buf = op.write_row(caches[key][l], row, write_pos,
                                        lead=(g,))
                     caches = _with_block(caches, key, l, buf)
                     item[key] = _group_slice(buf, g)[0]
+                sown = {} if stats else None
                 x = op.decode_attend(p[nm], x, q, item["k"], item["v"],
                                      write_pos, item.get("ks"),
-                                     item.get("vs"))
+                                     item.get("vs"), sow=sown)
+                if stats:
+                    step = jnp.stack([sown[k] for k in stats])
+                    caches = dict(caches, stats=caches["stats"] + jnp.where(
+                        valid, step.astype(jnp.int32), 0))
 
             if is_last:
                 h = nodes["final_ln"].op.apply(p["final_ln"], x)
@@ -536,6 +623,9 @@ class PipelinedDecoder:
             # per-group cumulative beam scores; only the LAST stage's
             # device shard is meaningful (it runs the expansion)
             specs["beam_cum"] = P(STAGE_AXIS, None, None)
+        if self._stat_names:
+            # each stage's sums over its own blocks' steps
+            specs["stats"] = P(STAGE_AXIS, None)
         return specs
 
     def _build_prefill_fn(self, plen: int, sample: bool, top_k: int | None):
@@ -602,6 +692,9 @@ class PipelinedDecoder:
                     caches["vs"] = per_block(self._scale_shape, jnp.float32)
                 if self.beam_width > 1:
                     caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
+                if self._stat_names:
+                    caches["stats"] = jnp.zeros(
+                        (n, len(self._stat_names)), jnp.int32)
                 return (jnp.zeros((n, mb, self._ring_width), jnp.float32),
                         caches)
 
@@ -813,10 +906,27 @@ class PipelinedDecoder:
                    "new_tokens": max_new_tokens,
                    "chunk_steps": self._schedule(
                        t_tok, plen if prefill else 0, token_chunk)[1]}):
-            return self._generate_fill(
-                prompt_ids, t_tok, temperature=temperature, top_k=top_k,
-                seed=seed, eos_id=eos_id, token_chunk=token_chunk,
-                prefill=prefill, on_tokens=on_tokens)
+            try:
+                return self._generate_fill(
+                    prompt_ids, t_tok, temperature=temperature, top_k=top_k,
+                    seed=seed, eos_id=eos_id, token_chunk=token_chunk,
+                    prefill=prefill, on_tokens=on_tokens)
+            finally:
+                # however the generation ended (a caller's on_tokens may
+                # raise to stop it): what the steps that ran have sown
+                self._post_stats()
+
+    def _post_stats(self) -> None:
+        """Add the blocks' sown sums (``DecoderBlock.decode_stats``),
+        kept on the device step by step, to the ``decode.<name>``
+        counters: one fetch a generation, no sync a step."""
+        stats, self._live_stats = self._live_stats, None
+        if stats is None or stats.is_deleted():
+            return
+        with span("decode", "moe_stats"):
+            sums = np.asarray(stats).sum(axis=0)
+        for name, total in zip(self._stat_names, sums):
+            REGISTRY.counter(f"decode.{name}").inc(int(total))
 
     def _generate_fill(self, prompt_ids: np.ndarray, t_tok: int, *,
                        temperature, top_k, seed, eos_id, token_chunk,
@@ -888,6 +998,7 @@ class PipelinedDecoder:
                                     jnp.int32(steps_run),
                                     jnp.int32(num_steps), seed_s, temp_s,
                                     fi_dev, fp_s, start_s, a, caches)
+            self._live_stats = caches.get("stats")
             if not incremental:
                 chunks.append(ids)
                 steps_run += chunk_steps

@@ -130,7 +130,12 @@ class ContinuousBatchEngine:
                        if nm.startswith("block_")]
         for nm in block_names:
             if not isinstance(nodes[nm].op, CausalTransformerBlock):
-                raise TypeError(f"{nm} is not a CausalTransformerBlock")
+                # the step adds learned positions and calls decode()
+                # with no position: another family would answer wrongly
+                raise TypeError(
+                    f"{nm} ({nodes[nm].op!r}) is not a "
+                    "CausalTransformerBlock: the decode engine serves the "
+                    "GPT family only (PipelinedDecoder runs the others)")
         assign = _split_blocks(len(block_names), num_stages)
         #: the chain-partition structure: stage s owns these blocks (and
         #: their slice of every slot's KV state)

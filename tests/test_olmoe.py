@@ -1,0 +1,458 @@
+"""OLMoE on the normal path, against the plain reference
+(``chipbench/reference/olmoe.py``) at a tiny size: seeded random float32
+weights, 2 layers, d 64, 4 heads of 16, 8 experts of width 32, 2 a token,
+vocabulary 211.
+
+Tolerances.  Both sides multiply in float32, in different orders (the
+program groups rows by expert, the reference masks and sums over all
+experts), so logits agree to about 1e-6 of their largest; ``RTOL`` 1e-4
+leaves room and stays 100x under what any change of the mathematics
+costs: a dropped QK-norm, RoPE along the wrong axis or renormalised
+top-k weights each move the logits by more than 1e-2 (asserted below by
+mutating the reference), and so does a bfloat16 product.
+"""
+
+import hashlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import defer_tpu as dt
+from chipbench.agreement import rel_err
+from chipbench.reference import olmoe as ref
+from defer_tpu.graph.ops import MoE, expert_dispatch, route_top_k
+from defer_tpu.models import gpt_tiny, olmoe, olmoe_tiny
+from defer_tpu.models.gpt import DecoderBlock
+from defer_tpu.models.olmoe import OlmoeBlock
+from defer_tpu.obs import REGISTRY
+from defer_tpu.runtime.decode import PipelinedDecoder
+from defer_tpu.serve.engine import ContinuousBatchEngine
+
+VOCAB, SEQ, PLEN, NEW = 211, 24, 8, 10
+REF = dict(n_layer=2, n_head=4, top_k=2, eps=1e-5, theta=10000.0)
+RTOL = 1e-4
+COUNTERS = ("decode.moe.assignments", "decode.moe.experts_hit",
+            "decode.moe.load_max")
+
+
+@pytest.fixture(scope="module")
+def model():
+    graph = olmoe_tiny(seq_len=SEQ, vocab=VOCAB)
+    return graph, graph.init(jax.random.key(3))
+
+
+@pytest.fixture(scope="module")
+def ids():
+    return np.random.default_rng(5).integers(
+        0, VOCAB, (4, SEQ)).astype(np.int32)
+
+
+# -- the full-sequence graph ----------------------------------------------------
+
+def test_full_sequence_logits_match_the_reference(model, ids):
+    graph, params = model
+    got = jax.jit(graph.apply)(params, jnp.asarray(ids))
+    want = ref.logits(params, ids, **REF)
+    assert got.shape == (4, SEQ, VOCAB)
+    assert rel_err(got, want) < RTOL
+
+
+_ROPE = ref._rope
+
+
+def _rope_over_the_heads(x, theta):
+    """RoPE with the position read off the head axis: the wrong axis."""
+    return _ROPE(x.transpose(0, 2, 1, 3), theta).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("mutation", ["no_qk_norm", "renormalised_top_k",
+                                      "rope_on_the_wrong_axis",
+                                      "bfloat16_products"])
+def test_the_tolerance_tells_a_changed_model_apart(model, ids, mutation,
+                                                   monkeypatch):
+    """Each departure from the equations moves the reference's logits by
+    more than 1e-2 of their largest: 100x the tolerance of the test
+    above, which would therefore fail on any of them."""
+    graph, params = model
+    want = ref.logits(params, ids, **REF)
+    if mutation == "no_qk_norm":
+        got = ref.logits(params, ids, **REF, qk_norm=False)
+    elif mutation == "renormalised_top_k":
+        got = ref.logits(params, ids, **REF, norm_topk_prob=True)
+    elif mutation == "rope_on_the_wrong_axis":
+        monkeypatch.setattr(ref, "_rope", _rope_over_the_heads)
+        with jax.disable_jit():
+            got = ref.logits(params, ids, **REF)
+    else:
+        bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+        got = jax.jit(graph.apply)(bf16, jnp.asarray(ids))
+    assert rel_err(got, want) > 1e-2
+
+
+def test_the_graph_rides_the_stage_pipeline(model, ids):
+    """``Defer.run`` cuts the full-sequence graph into two stage programs
+    (``SpmdPipeline``) and gives the single program's logits."""
+    graph, params = model
+    defer = dt.Defer(config=dt.DeferConfig(microbatch=1, chunk=2))
+    x = ids[:, None, :]                               # [4, mb=1, t]
+    out = defer.run(graph, params, x, num_stages=2)
+    want = np.asarray(ref.logits(params, ids, **REF))
+    assert rel_err(out[:, 0], want) < RTOL
+
+
+# -- the expert dispatch -----------------------------------------------------------
+
+def _mask_and_sum(x, eid, gate, w1, w2):
+    """Every expert on every row, masked: the form ``ops.MoE`` used."""
+    out = jnp.zeros((x.shape[0], w2.shape[-1]), x.dtype)
+    for e in range(w1.shape[0]):
+        weight = jnp.sum(jnp.where(eid == e, gate, 0.0), axis=-1)
+        out = out + weight[:, None] * (jnp.tanh(x @ w1[e]) @ w2[e])
+    return out
+
+
+@pytest.mark.parametrize("routing", ["one_expert_gets_every_row",
+                                     "some_experts_get_none", "random"])
+def test_dispatch_equals_mask_and_sum(routing):
+    rng = np.random.default_rng(11)
+    t, d, h, e, k = 12, 16, 8, 6, 2
+    x = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    w1 = jnp.asarray(rng.standard_normal((e, d, h)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((e, h, d)), jnp.float32)
+    gate = jnp.asarray(rng.uniform(0.1, 1.0, (t, k)), jnp.float32)
+    if routing == "one_expert_gets_every_row":
+        eid = np.stack([np.full(t, 4), np.arange(t) % 3], axis=1)
+    elif routing == "some_experts_get_none":
+        eid = np.stack([np.full(t, 1), np.full(t, 5)], axis=1)
+    else:
+        eid = np.stack([rng.permutation(e)[:k] for _ in range(t)])
+    eid = jnp.asarray(eid, jnp.int32)
+
+    def experts(xs, sizes, es):
+        assert xs.shape == (t * k, d) and es.shape == (t * k,)
+        hid = jnp.tanh(jax.lax.ragged_dot(xs, w1, sizes))
+        return jax.lax.ragged_dot(hid, w2, sizes)
+
+    got, sizes = jax.jit(
+        lambda x, eid, gate: expert_dispatch(x, eid, gate, e, experts)
+    )(x, eid, gate)
+    np.testing.assert_array_equal(
+        np.asarray(sizes), np.bincount(np.asarray(eid).ravel(), minlength=e))
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_mask_and_sum(x, eid, gate, w1, w2)),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_routing_is_a_float32_softmax_and_keeps_the_probabilities():
+    logits = jnp.asarray([[2.0, 0.0, 1.0, 3.0]], jnp.bfloat16)
+    eid, p = route_top_k(logits, 2)
+    soft = np.asarray(jax.nn.softmax(logits.astype(jnp.float32)))[0]
+    assert p.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(eid), [[3, 0]])
+    np.testing.assert_allclose(np.asarray(p)[0], soft[[3, 0]], rtol=1e-6)
+    assert float(p.sum()) < 1.0                      # not renormalised
+
+
+def test_switch_moe_is_the_top_1_case_of_the_dispatch():
+    """``ops.MoE`` (top-1, GELU, biases) through the shared dispatch
+    equals evaluating every expert and masking."""
+    op = MoE(num_experts=4, hidden=16)
+    spec = jax.ShapeDtypeStruct((6, 8), jnp.float32)
+    params = op.init(jax.random.key(0), (spec,))
+    params["fc1"]["b"] = params["fc1"]["b"] + 0.1
+    params["fc2"]["b"] = params["fc2"]["b"] - 0.2
+    x = jax.random.normal(jax.random.key(1), (3, 6, 8))
+    eid, pe = op.route(params, x)
+    every = jnp.stack([op.expert_fn(params, x, jnp.int32(e))
+                       for e in range(4)], axis=2)          # [b, t, E, d]
+    sel = jax.nn.one_hot(eid, 4)
+    want = x + (every * sel[..., None]).sum(2) * pe[..., None]
+    np.testing.assert_allclose(np.asarray(op.apply(params, x)),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# -- one token against the cache -----------------------------------------------
+
+def _step_logits(graph, params, seqs):
+    """Prefill-free decode of ``seqs`` [b, t] through the block's own two
+    halves and ``write_row``, a position a step: logits [b, t, vocab]."""
+    nodes = graph.nodes
+    blocks = [nm for nm in graph.topo_order if nm.startswith("block_")]
+    op0 = nodes[blocks[0]].op
+    b, t = seqs.shape
+    d = nodes[blocks[0]].out_spec.shape[-1]
+    item = (b, op0.kv_heads, t, d // op0.num_heads)
+    caches = {nm: {"k": jnp.zeros(item), "v": jnp.zeros(item)}
+              for nm in blocks}
+    out = []
+    for p in range(t):
+        x = nodes["embeddings"].op.embed_at(
+            params["embeddings"], jnp.asarray(seqs[:, p]), p)
+        for nm in blocks:
+            op = nodes[nm].op
+            q, rows = op.decode_qkv(params[nm], x, jnp.int32(p))
+            c = caches[nm] = {key: op.write_row(caches[nm][key], row, p)
+                              for key, row in rows.items()}
+            x = op.decode_attend(params[nm], x, q, c["k"], c["v"], p)
+        h = nodes["final_ln"].op.apply(params["final_ln"], x)
+        out.append(nodes["lm_head"].op.apply(params["lm_head"], h))
+    return jnp.stack(out, axis=1)
+
+
+def test_decode_steps_match_the_references_full_forward(model, ids):
+    """Logits, not tokens: every position decoded through the cache
+    (rotated keys cached, ``pos`` through ``decode_qkv``) against the
+    reference's full forward."""
+    graph, params = model
+    got = _step_logits(graph, params, ids[:2, :12])
+    want = ref.logits(params, ids[:2, :12], **REF)
+    assert rel_err(got, want) < RTOL
+
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+@pytest.mark.parametrize("token_chunk", [1, 3])
+def test_prefill_then_decode_through_the_ring(model, ids, num_stages,
+                                              token_chunk):
+    """Prefill, then decode through the ring's caches: every generated
+    token is the argmax of the reference's full forward over the
+    program's own sequence (teacher-forced), with the reference's margin
+    over its runner-up far above what float32 reordering moves."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=4 // num_stages, max_len=SEQ)
+    out = dec.generate(ids[:, :PLEN], NEW, prefill=True,
+                       token_chunk=token_chunk)
+    assert out.shape == (4, PLEN + NEW)
+    np.testing.assert_array_equal(out[:, :PLEN], ids[:, :PLEN])
+    lg = np.asarray(ref.logits(params, out[:, :-1], lo=PLEN - 1, **REF))
+    np.testing.assert_array_equal(out[:, PLEN:], lg.argmax(-1))
+    # and the logits themselves, by the same steps the ring runs
+    assert rel_err(_step_logits(graph, params, out[:, :-1])[:, PLEN - 1:],
+                   lg) < RTOL
+    # teacher forcing inside the scan instead of the fused prefill
+    slow = dec.generate(ids[:, :PLEN], NEW, token_chunk=token_chunk)
+    np.testing.assert_array_equal(slow, out)
+
+
+def test_the_int8_cache_serves_the_same_blocks(model, ids):
+    """Every cache type the ring has: with int8 rows the tokens stay
+    within the reference's near-ties (a share of the logit spread)."""
+    from chipbench.agreement import logit_gaps
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=SEQ, kv_cache="int8")
+    out = dec.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=2)
+    gaps = logit_gaps(params, out, PLEN, {
+        "module": "chipbench.reference.olmoe", "args": REF})
+    # int8 rows at d 64 are coarse: most tokens are the reference's
+    # argmax and none is a whole spread down, where a wrong row lands
+    assert (gaps <= 0).mean() > 0.8 and gaps.max() < 0.5
+
+
+def test_the_ring_holds_expert_leaves_as_arguments_of_their_own(model):
+    """The experts ride beside the flat rows, a leaf a local block,
+    stage-sharded; every other leaf is in the rows; ``reweight`` swaps
+    both and checks both."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=SEQ)
+    assert set(dec._w) == {"flat", "own"} and len(dec._w["own"]) == 1
+    own = dec._w["own"][0]["experts"]
+    assert {k: v.shape for k, v in own.items()} == {
+        "gate": (2, 8, 64, 32), "up": (2, 8, 64, 32),
+        "down": (2, 8, 32, 64)}
+    np.testing.assert_array_equal(np.asarray(own["up"][1]),
+                                  np.asarray(params["block_1"]["experts"]["up"]))
+    experts = sum(int(np.prod(v.shape)) for v in own.values())
+    total = sum(int(np.prod(a.shape))
+                for a in jax.tree.leaves(params))
+    assert dec._w["flat"].shape[0] == 2
+    assert dec._w["flat"].shape[1] < total - experts    # a stage's share
+    before = dec.generate(np.zeros((4, 4), np.int32), 4)
+    other = graph.init(jax.random.key(99))
+    dec.reweight(other)
+    assert not np.array_equal(dec.generate(np.zeros((4, 4), np.int32), 4),
+                              before)
+    dec.reweight(params)
+    np.testing.assert_array_equal(
+        dec.generate(np.zeros((4, 4), np.int32), 4), before)
+    bad = jax.tree.map(lambda a: a, params)
+    bad["block_0"] = dict(bad["block_0"], experts=jax.tree.map(
+        lambda a: a[:4], bad["block_0"]["experts"]))
+    with pytest.raises(ValueError, match="reweight"):
+        dec.reweight(bad)
+
+
+def test_an_uneven_split_pads_the_leaves_of_the_shorter_stage(ids):
+    """Three layers over two stages (2 + 1): the second local block's
+    leaves are [2, ...] with a zeroed stand-in on the stage that has
+    none, and the tokens are the one-stage decoder's."""
+    graph = olmoe(3, 64, 4, SEQ, vocab=VOCAB, num_experts=8,
+                  experts_per_tok=2, expert_hidden=32)
+    params = graph.init(jax.random.key(4))
+    two = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=SEQ)
+    assert [len(b) for b in two.stage_blocks] == [2, 1]
+    second = two._w["own"][1]["experts"]["down"]
+    assert second.shape == (2, 8, 32, 64) and not np.asarray(second[1]).any()
+    one = PipelinedDecoder(graph, params, num_stages=1, microbatch=4,
+                           max_len=SEQ)
+    np.testing.assert_array_equal(
+        two.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=2),
+        one.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=2))
+
+
+# -- the counters ----------------------------------------------------------------
+
+def _ring_steps(n, start, t_tok, max_len):
+    """(stage, group, position) of every live step the ring runs to
+    decode positions ``start+1 .. t_tok-1`` (``PipelinedDecoder``'s
+    schedule: stage ``s`` serves group ``(t-s) % n`` at ``start +
+    (t-s)//n``; the last fill's leading stages run one position that
+    never reaches the head)."""
+    num_steps = (n - 1) + n * (t_tok - 2 - start) + (n - 1) + 1
+    for t in range(num_steps):
+        for s in range(n):
+            rel = t - s
+            if rel >= 0 and start + rel // n < max_len:
+                yield s, rel % n, start + rel // n
+
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_moe_counters_equal_the_references_chosen_experts(model, ids,
+                                                          num_stages):
+    graph, params = model
+    mb = 4 // num_stages
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=mb, max_len=SEQ)
+    before = [REGISTRY.counter(c).n for c in COUNTERS]
+    hist = REGISTRY.histogram("decode.moe_stats_s")
+    fetches = hist.count
+    out = dec.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=3)
+    got = [REGISTRY.counter(c).n - b for c, b in zip(COUNTERS, before)]
+    assert hist.count == fetches + 1          # one fetch a generation
+    _, chosen = ref.logits(params, out, **REF, experts=True)
+    chosen = np.asarray(chosen)                       # [layer, b, t, k]
+    want = [0, 0, 0]
+    for s, g, pos in _ring_steps(num_stages, PLEN, PLEN + NEW, SEQ):
+        for nm in dec.stage_blocks[s]:
+            layer = int(nm.split("_")[1])
+            picks = chosen[layer, g * mb:(g + 1) * mb, pos].ravel()
+            sizes = np.bincount(picks, minlength=8)
+            want[0] += picks.size
+            want[1] += int((sizes > 0).sum())
+            want[2] += int(sizes.max())
+    assert got == want
+    assert got[0] == 2 * mb * sum(
+        len(dec.stage_blocks[s])
+        for s, _g, _p in _ring_steps(num_stages, PLEN, PLEN + NEW, SEQ))
+
+
+def test_counters_are_fetched_when_the_caller_stops_a_generation(model, ids):
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=4,
+                           max_len=SEQ)
+    before = REGISTRY.counter(COUNTERS[0]).n
+
+    class Stop(Exception):
+        pass
+
+    def on_tokens(lo, hi, toks, rows):
+        if hi >= PLEN + 4:
+            raise Stop
+
+    with pytest.raises(Stop):
+        dec.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=3,
+                     on_tokens=on_tokens)
+    # one chunk of three steps ran: 3 steps x 2 layers x 4 rows x 2
+    assert REGISTRY.counter(COUNTERS[0]).n - before == 3 * 2 * 4 * 2
+
+
+# -- the block interface, and who refuses it -----------------------------------------
+
+def test_blocks_of_both_families_meet_the_rings_interface(model):
+    graph, _ = model
+    for op in (graph.nodes["block_0"].op, gpt_tiny().nodes["block_0"].op):
+        assert isinstance(op, DecoderBlock)
+        for name in ("apply_with_kv", "decode_qkv", "decode_attend",
+                     "write_row", "quantize_row", "cache_attention"):
+            assert callable(getattr(op, name))
+    assert OlmoeBlock.stage_arg_keys == ("experts",)
+    assert gpt_tiny().nodes["block_0"].op.decode_stats == ()
+
+
+def test_the_ring_refuses_a_block_outside_the_interface():
+    from defer_tpu.models import bert_tiny
+    graph = gpt_tiny()
+    nodes = dict(graph.nodes)
+    enc = bert_tiny().nodes["block_0"].op
+    import dataclasses
+    nodes["block_1"] = dataclasses.replace(nodes["block_1"], op=enc)
+    broken = graph.__class__.__new__(graph.__class__)
+    broken.__dict__.update(graph.__dict__)
+    broken.nodes = nodes
+    with pytest.raises(TypeError, match="block_1.*DecoderBlock"):
+        PipelinedDecoder(broken, graph.init(jax.random.key(0)),
+                         num_stages=1)
+
+
+def test_the_serving_engine_refuses_the_block_by_name(model):
+    """The engine adds learned positions and decodes with no position:
+    it must refuse this family at construction, never answer wrongly."""
+    graph, params = model
+    with pytest.raises(TypeError, match=r"block_0 \(OlmoeBlock\)"):
+        ContinuousBatchEngine(graph, params, num_stages=1, width=2)
+
+
+# -- the GPT family through the changed interface ---------------------------------
+
+#: recorded on the parent commit (d5480a9): gpt_tiny(seq_len=32), key 0,
+#: 8 prompts ``arange(40).reshape(8, 5) % 97``, 8 greedy tokens,
+#: prefill, token_chunk 2 — and the sha256 of the ring's lowered decode
+#: program for the same decoder.  A PR that changes the ring's program
+#: on purpose records them anew and says so.
+PARENT_TOKENS_SHA = "0fef1cc65e752cd8"
+PARENT_DECODE_SHA = {1: "e8dcb192e737955e", 2: "aadd22d3adc8d6a3"}
+
+
+def _sha(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_gpt_tiny_decodes_as_on_the_parent(num_stages):
+    """``decode_qkv`` takes a position now and ``decode_attend`` a
+    ``sow``: the GPT family ignores both, its ring lowers to the parent's
+    text and gives the parent's tokens, bit for bit."""
+    graph = gpt_tiny(seq_len=32)
+    params = graph.init(jax.random.key(0))
+    n, mb = num_stages, 8 // num_stages
+    dec = PipelinedDecoder(graph, params, num_stages=n, microbatch=mb,
+                           max_len=32)
+    a, caches = dec._init_state()
+    num_steps, chunk = dec._schedule(5 + 8, 5, 2)
+    lowered = dec._get_decode_fn(chunk, False, None).lower(
+        dec._w, jnp.zeros((n, mb, 5), jnp.int32), jnp.int32(5),
+        jnp.int32(0), jnp.int32(num_steps), jnp.uint32(0), jnp.float32(0.),
+        jnp.zeros((n, mb), jnp.int32), jnp.int32(5), jnp.int32(5), a, caches)
+    assert _sha(lowered.as_text()) == PARENT_DECODE_SHA[n]
+    toks = dec.generate(np.arange(40).reshape(8, 5) % 97, 8, prefill=True,
+                        token_chunk=2)
+    assert _sha(str(toks.tolist())) == PARENT_TOKENS_SHA
+
+
+def test_the_published_model_builds_at_its_widths():
+    graph = olmoe(16, 2048, 16, 4096)
+    spec = graph.nodes["block_0"].param_spec
+    assert spec["experts"]["gate"].shape == (64, 2048, 1024)
+    assert spec["experts"]["down"].shape == (64, 1024, 2048)
+    assert spec["router"]["w"].shape == (2048, 64)
+    assert "bias" not in spec["ln1"] and set(spec["q"]) == {"w"}
+    assert set(graph.nodes["embeddings"].param_spec) == {"wte"}
+    assert set(graph.nodes["lm_head"].param_spec) == {"w"}
+    n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(
+        [node.param_spec for node in graph.nodes.values()]))
+    assert round(n / 1e9, 2) == 6.92

@@ -68,3 +68,40 @@ def test_invalid_cut_rejected():
         partition(g, ["nope"])
     with pytest.raises(ValueError, match="topological"):
         partition(g, ["add_2", "add"])
+
+
+# -- the routed-expert block: cuts by cost --------------------------------------
+
+def test_olmoe_block_flops_count_the_experts_a_token_uses():
+    """``flops()`` of the routed-expert block: q/k/v/o, attention, the
+    router and ``experts_per_tok`` experts a token — not all of them."""
+    from defer_tpu.models import olmoe
+    g = olmoe(2, 2048, 16, 1024)
+    node = g.nodes["block_0"]
+    got = node.op.flops((g.nodes["embeddings"].out_spec,), node.out_spec)
+    t, d, h = 1024, 2048, 1024
+    want = (2 * t * d * 4 * d + 4 * t * t * d + 2 * t * d * 64
+            + 8 * 2 * t * 3 * d * h)
+    assert got == want
+    all_64 = want + (64 - 8) * 2 * t * 3 * d * h
+    assert all_64 / got > 5.5
+
+
+@pytest.mark.parametrize("vocab, want", [
+    # a head that costs nothing beside a block: the even cuts of
+    # gpt_stage_cuts(16, 4), four blocks a stage
+    (64, ["block_3", "block_7", "block_11"]),
+    # the published vocabulary: the head costs 1.4 blocks (206 of 146
+    # MFLOP a token at 1024 positions), so the stage that carries it
+    # gets three blocks and the second five: cuts by cost, not by count
+    (50304, ["block_3", "block_8", "block_12"])])
+def test_olmoe_16_layers_over_4_stages_cut_by_cost(vocab, want):
+    from defer_tpu.models import gpt_stage_cuts, olmoe
+    g = olmoe(16, 2048, 16, 1024, vocab=vocab)
+    stages = partition(g, num_stages=4)
+    assert [s.output_name for s in stages[:-1]] == want
+    if vocab == 64:
+        assert want == gpt_stage_cuts(16, 4)
+    blocks = [sum(nm.startswith("block_") for nm in s.node_names)
+              for s in stages]
+    assert sum(blocks) == 16 and max(blocks) - min(blocks) <= 2

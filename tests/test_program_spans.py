@@ -19,8 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from defer_tpu.models.gpt import gpt_tiny
-from defer_tpu.obs import (DECODE_PHASES, DOOR_PHASES, ENGINE_LOOP_PHASES,
-                           ENGINE_PHASES, REGISTRY, SPAN_LAYERS, span,
+from defer_tpu.obs import (DECODE_PHASES, DECODE_STATS_PHASES, DOOR_PHASES,
+                           ENGINE_LOOP_PHASES, ENGINE_PHASES, REGISTRY, SPAN_LAYERS, span,
                            tracer)
 from defer_tpu.obs.trace import ANNOTATION_PREFIX as PREFIX
 from defer_tpu.runtime.decode import PipelinedDecoder
@@ -123,7 +123,7 @@ def test_a_span_with_the_tracer_on_links_its_parent_and_keeps_args(traced):
 @pytest.mark.parametrize("layer", sorted(SPAN_LAYERS))
 def test_names_come_from_the_phase_tables_and_nowhere_else(layer):
     prefix, root, phases = SPAN_LAYERS[layer]
-    assert phases == {"decode": DECODE_PHASES,
+    assert phases == {"decode": DECODE_PHASES + DECODE_STATS_PHASES,
                       "engine": ENGINE_PHASES + ENGINE_LOOP_PHASES,
                       "door": DOOR_PHASES}[layer]
     for phase in phases:
