@@ -256,9 +256,10 @@ class CausalTransformerBlock(DecoderBlock, TransformerBlock):
         scales when quantized.
 
         The composition of :meth:`decode_qkv`, the row writes and
-        :meth:`decode_attend`, for callers that hold one cache item a
-        block (``serve/engine.py``); the pipelined decoder calls the
-        halves and writes the rows into its own buffers.
+        :meth:`decode_attend` over one cache item: the oracle the tests
+        hold both engines to.  The pipelined decoder and the serving
+        engine call the halves and write the rows into their own
+        buffers.
         """
         quant = k_scale is not None
         q, rows = self.decode_qkv(params, x, pos, quant=quant)
@@ -302,6 +303,18 @@ class GptEmbedding(Op):
         tok = params["wte"][ids.astype(jnp.int32)]
         return tok + lax.dynamic_slice(params["wpe"], (pos, 0),
                                        (1, self.features))[0]
+
+    def embed_rows(self, params, ids, pos):
+        """Decode-path embedding of sequences at their own positions:
+        ``ids`` [b] at ``pos`` [b].  One ``dynamic_slice`` a row: for a
+        gather XLA:TPU first copies the whole table (322 MB of f32 at
+        GPT-2's vocabulary) out of the layout it is held in."""
+        def rows(table, idx):
+            return jnp.concatenate(
+                [lax.dynamic_slice(table, (idx[i], 0), (1, self.features))
+                 for i in range(idx.shape[0])])
+        return (rows(params["wte"], ids.astype(jnp.int32))
+                + rows(params["wpe"], pos))
 
     def flops(self, in_specs, out_spec):
         return out_spec.size

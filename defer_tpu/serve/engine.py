@@ -9,21 +9,28 @@ bubbles (the whole batch must drain before new prompts enter).  This
 engine is continuous batching proper:
 
 * The batch is ``width`` SLOTS.  Each slot holds one request's state —
-  its prompt, its position, and its OWN KV cache rows in every stage's
-  cache (``[blocks, width, kv_heads, max_len, head_dim]`` per stage, the
-  stage-sharded layout of ``runtime/decode.py`` with the group axis
-  replaced by a slot axis).
+  its prompt, its position, and its OWN row range of every layer's KV
+  cache: one ``[width, kv_heads, max_len, head_dim]`` f32 buffer a
+  layer and side, every one a donated argument of the step and its
+  aliased output (the per-block buffers of ``runtime/decode.py``, with
+  the group axis replaced by a slot axis).
 * Between any two decode steps, finished requests leave (slot freed,
   tokens delivered) and waiting requests join (slot claimed, position
   0); the step program itself never changes — one compiled program per
   width serves every batch composition.
 * A step is one token per active slot: teacher-forced from the prompt
   while ``pos < prompt_len`` (prefill at decode rate — a joining
-  request needs no separate prefill program), sampled past it.  Every
-  row's computation is vmapped single-row decode against its own cache
-  at its own position, so a row's output bytes are INDEPENDENT of who
-  shares the batch — per-request outputs are byte-identical to the
-  request run alone, the correctness bar continuous batching must meet.
+  request needs no separate prefill program), sampled past it.  A
+  layer is the block's own halves, as the ring calls them:
+  ``decode_qkv`` on the whole ``[width, d]`` batch, each slot's new row
+  written in place at that slot's OWN position (``ops/kv_rows.py``:
+  slots sit at different positions, and a vmapped write would be a
+  batched scatter over a re-laid-out item, docs/DECODE_CLIFF.md), then
+  ``decode_attend`` with each slot's own live mask.  Every row's
+  computation reads its own rows only, so a row's output bytes are
+  INDEPENDENT of who shares the batch — per-request outputs are
+  byte-identical to the request run alone, the correctness bar
+  continuous batching must meet.
 * Sampling keys are ``fold_in(request_seed, position)`` per row —
   deterministic per request regardless of batch composition or join
   step.
@@ -31,10 +38,11 @@ engine is continuous batching proper:
 The stage structure mirrors the deployed chain's partition (same
 ``_split_blocks`` assignment), so the planner's per-stage latency budget
 (``plan.cost.stage_ms_at_batch``) prices this engine's step the same way
-it prices a chain frame.  Execution here is in-process (one jitted step
-over the stage-structured state); carrying the per-slot caches through
-OS-process stage nodes needs stateful stage artifacts — the documented
-next step (docs/SERVING.md), not this PR.
+it prices a chain frame; it is the planner's structure only, the step
+walks the blocks in order.  Execution here is in-process (one jitted
+step); carrying the per-slot caches through OS-process stage nodes
+needs stateful stage artifacts — the documented next step
+(docs/SERVING.md), not this PR.
 """
 
 from __future__ import annotations
@@ -53,6 +61,7 @@ from ..graph.ir import LayerGraph
 from ..models.gpt import CausalTransformerBlock, GptEmbedding
 from ..obs import REGISTRY, span
 from ..obs.events import emit as emit_event
+from ..ops.kv_rows import write_kv_rows
 from ..runtime.decode import _sample_ids, _split_blocks
 from .batcher import _stamp_popped
 
@@ -137,8 +146,7 @@ class ContinuousBatchEngine:
                     "CausalTransformerBlock: the decode engine serves the "
                     "GPT family only (PipelinedDecoder runs the others)")
         assign = _split_blocks(len(block_names), num_stages)
-        #: the chain-partition structure: stage s owns these blocks (and
-        #: their slice of every slot's KV state)
+        #: the chain-partition structure: stage s owns these blocks
         self.stage_blocks = [[block_names[i] for i in idxs]
                              for idxs in assign]
         blk0 = nodes[block_names[0]].op
@@ -157,11 +165,14 @@ class ContinuousBatchEngine:
     # -- state -------------------------------------------------------------
 
     def _init_caches(self):
-        w, kv, ml, hd = (self.width, self.kv_heads, self.max_len,
-                         self.head_dim)
-        return [{"k": jnp.zeros((len(blks), w, kv, ml, hd), jnp.float32),
-                 "v": jnp.zeros((len(blks), w, kv, ml, hd), jnp.float32)}
-                for blks in self.stage_blocks]
+        """One ``[width, kv_heads, max_len, head_dim]`` f32 buffer a
+        layer and side, each a donated argument of the step and its
+        aliased output (docs/DECODE_CLIFF.md, "The engine")."""
+        shape = (self.width, self.kv_heads, self.max_len, self.head_dim)
+        n_layer = sum(len(blks) for blks in self.stage_blocks)
+        return {side: tuple(jnp.zeros(shape, jnp.float32)
+                            for _ in range(n_layer))
+                for side in ("k", "v")}
 
     def free_slots(self) -> int:
         return sum(1 for s in self._slots if s is None)
@@ -203,34 +214,26 @@ class ContinuousBatchEngine:
     def _build_step(self, sample: bool):
         nodes = self.graph.nodes
         embed = self.embed_op
-        stage_ops = [[nodes[nm].op for nm in blks]
-                     for blks in self.stage_blocks]
-        stage_names = self.stage_blocks
+        blocks = [(nodes[nm].op, nm)
+                  for blks in self.stage_blocks for nm in blks]
         final_ln = nodes["final_ln"].op
         lm_head = nodes["lm_head"].op
         top_k = self.top_k
 
         def step(params, caches, ids, pos, seeds, temps):
             safe = jnp.clip(pos, 0, self.max_len - 1)
-            x = (params["embeddings"]["wte"][ids]
-                 + params["embeddings"]["wpe"][safe]).astype(jnp.float32)
-            out_caches = []
-            # ride the stage partition: stage s applies its blocks
-            # against its slice of every slot's KV state
-            for s, (ops, names) in enumerate(zip(stage_ops, stage_names)):
-                ks, vs = caches[s]["k"], caches[s]["v"]
-                for l, (op, nm) in enumerate(zip(ops, names)):
-                    p_blk = params[nm]
-
-                    def row(x_r, k_r, v_r, pos_r, _op=op, _p=p_blk):
-                        y, k2, v2 = _op.decode(_p, x_r[None], k_r[None],
-                                               v_r[None], pos_r)
-                        return y[0], k2[0], v2[0]
-
-                    x, k_l, v_l = jax.vmap(row)(x, ks[l], vs[l], safe)
-                    ks = ks.at[l].set(k_l)
-                    vs = vs.at[l].set(v_l)
-                out_caches.append({"k": ks, "v": vs})
+            x = embed.embed_rows(params["embeddings"], ids,
+                                 safe).astype(jnp.float32)
+            # every slot attends over its own positions <= its own pos
+            live_to = safe[:, None, None, None]
+            ks, vs = list(caches["k"]), list(caches["v"])
+            for l, (op, nm) in enumerate(blocks):
+                q, rows = op.decode_qkv(params[nm], x, safe)
+                ks[l] = write_kv_rows(ks[l], rows["k"], safe)
+                vs[l] = write_kv_rows(vs[l], rows["v"], safe)
+                x = op.decode_attend(params[nm], x, q, ks[l], vs[l],
+                                     live_to)
+            out_caches = {"k": tuple(ks), "v": tuple(vs)}
             h = final_ln.apply(params["final_ln"], x)
             logits = lm_head.apply(params["lm_head"],
                                    h).astype(jnp.float32)

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from defer_tpu import partition
 from defer_tpu.models import resnet_tiny
@@ -288,6 +289,200 @@ def test_engine_cancel_reclaims_slot_others_unaffected(gpt_setup):
             out[req.request_id] = ids
     np.testing.assert_array_equal(out[1], survivor_solo)
     assert 2 in out and eng.free_slots() == 2
+
+
+def _oracle_run(g, params, tokens, max_len, new=0):
+    """The oracle: ``CausalTransformerBlock.decode`` (the composition
+    over one cache item) looped over ``tokens`` fed one a position,
+    then over its own greedy ids until ``new`` are generated, against
+    one [1, kv, max_len, hd] item a block.  Returns all the tokens, the
+    greedy next id after each fed position, and the final caches."""
+    blocks = [nm for nm in g.topo_order if nm.startswith("block_")]
+    op0 = g.nodes[blocks[0]].op
+    d = params["embeddings"]["wte"].shape[1]
+    item = (1, op0.kv_heads, max_len, d // op0.num_heads)
+    caches = {nm: (jnp.zeros(item), jnp.zeros(item)) for nm in blocks}
+
+    @jax.jit
+    def one(caches, tok, pos):
+        x = (params["embeddings"]["wte"][tok]
+             + params["embeddings"]["wpe"][pos])[None]
+        out = {}
+        for nm in blocks:
+            x, k, v = g.nodes[nm].op.decode(params[nm], x, *caches[nm], pos)
+            out[nm] = (k, v)
+        h = g.nodes["final_ln"].op.apply(params["final_ln"], x)
+        logits = g.nodes["lm_head"].op.apply(params["lm_head"], h)
+        return jnp.argmax(logits[0]), out
+
+    toks, nxt = [int(t) for t in tokens], []
+    want = len(toks) + new
+    while len(nxt) < len(toks):
+        pos = len(nxt)
+        i, caches = one(caches, jnp.int32(toks[pos]), jnp.int32(pos))
+        nxt.append(int(i))
+        if pos + 1 == len(toks) < want:
+            toks.append(int(i))
+    return np.asarray(toks, np.int64), nxt, caches
+
+
+def test_engine_slots_at_different_positions_match_solo_and_oracle(
+        gpt_setup):
+    """Slots sit at DIFFERENT positions in every step (unequal prompts,
+    joined at different steps): each slot's row lands at its own
+    position of each layer's buffer, and every answer equals the
+    request run alone and the one-item oracle."""
+    g, params = gpt_setup
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
+               for n in (3, 7, 5)]
+    join_at = {0: 0, 1: 2, 2: 5}
+
+    def make_reqs():
+        return [DecodeRequest(prompt=p, max_new_tokens=6, request_id=i)
+                for i, p in enumerate(prompts)]
+
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=3)
+    seen_pos = []
+
+    def stagger(e, queue):
+        while queue and e.steps >= join_at[queue[0].request_id]:
+            e.join(queue.pop(0))
+        seen_pos.append(tuple(s.pos for s in e._slots if s is not None))
+
+    batched = eng.run_all(make_reqs(), joiner=stagger)
+    assert any(len(set(ps)) == 3 for ps in seen_pos), \
+        "the test must hold three slots at three positions in one step"
+    for req in make_reqs():
+        solo = ContinuousBatchEngine(g, params, num_stages=2, width=3)
+        want = solo.run_all([req])[req.request_id]
+        np.testing.assert_array_equal(batched[req.request_id], want)
+        np.testing.assert_array_equal(
+            want, _oracle_run(g, params, req.prompt, eng.max_len, new=6)[0])
+
+
+@pytest.mark.parametrize("leaves_by", ["cancel", "finished"])
+def test_engine_recycled_slot_ignores_previous_tenants_rows(gpt_setup,
+                                                            leaves_by):
+    """A slot is never zeroed between tenants: the buffers still hold
+    the previous tenant's rows beyond the next tenant's positions, and
+    the next tenant's answer equals its solo run all the same."""
+    g, params = gpt_setup
+    rng = np.random.default_rng(12)
+    p_long = rng.integers(0, 97, (8,)).astype(np.int32)
+    p_next = rng.integers(0, 97, (3,)).astype(np.int32)
+    solo = ContinuousBatchEngine(g, params, num_stages=1, width=1).run_all(
+        [DecodeRequest(prompt=p_next, max_new_tokens=3, request_id=7)])[7]
+
+    eng = ContinuousBatchEngine(g, params, num_stages=1, width=1)
+    first = DecodeRequest(prompt=p_long, max_new_tokens=7, request_id=0)
+    assert eng.join(first)
+    if leaves_by == "cancel":
+        for _ in range(12):
+            eng.step()
+        assert eng.cancel(first)
+    else:
+        while eng.active():
+            eng.step()
+    assert eng.free_slots() == 1
+    got = eng.run_all(
+        [DecodeRequest(prompt=p_next, max_new_tokens=3, request_id=7)])[7]
+    np.testing.assert_array_equal(got, solo)
+    # the next tenant fed positions 0..4; the first one's rows 5..11
+    # are still there
+    stale = np.asarray(eng._caches["k"][0])[0, :, 5:12]
+    assert np.abs(stale).min(axis=(0, 2)).all()
+
+
+@pytest.mark.parametrize("shape,positions", [
+    ((3, 2, 16, 8), [0, 15, 7]),            # one window holds the item
+    ((4, 2, 192, 8), [0, 127, 128, 191]),   # 1.5 windows: the cell's L
+    ((2, 3, 300, 16), [255, 299]),
+])
+def test_write_kv_rows_touches_one_position_a_sequence(shape, positions):
+    """The engine's row-writer: sequence i's row lands at pos[i], at
+    window edges and in a partial last window too, and every other
+    element keeps its bits."""
+    from defer_tpu.ops.kv_rows import write_kv_rows
+    rng = np.random.default_rng(5)
+    w, kv, _, hd = shape
+    cache = rng.normal(size=shape).astype(np.float32)
+    rows = rng.normal(size=(w, kv, 1, hd)).astype(np.float32)
+    got = jax.jit(write_kv_rows)(cache, rows,
+                                 jnp.asarray(positions, jnp.int32))
+    want = cache.copy()
+    for i, p in enumerate(positions):
+        want[i, :, p] = rows[i, :, 0]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
+
+
+def test_engine_step_holds_a_buffer_a_layer_and_writes_rows_in_place(
+        gpt_setup):
+    """Structure of the step program: ``n_layer`` cache buffers a side,
+    every one donated and aliased to its output; no scatter (a vmapped
+    row write is one: docs/DECODE_CLIFF.md) and nothing of the stacked
+    ``[n_layer, width, ...]`` shape."""
+    g, params = gpt_setup
+    w, n_layer = 3, 4
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=w)
+    item = (w, eng.kv_heads, eng.max_len, eng.head_dim)
+    assert set(eng._caches) == {"k", "v"}
+    for side in ("k", "v"):
+        assert [b.shape for b in eng._caches[side]] == [item] * n_layer
+    vec = jnp.zeros(w, jnp.int32)
+    args = (eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
+            vec.astype(jnp.float32))
+    for sample in (False, True):
+        traced = eng._step_fn(sample).trace(*args)
+        prims = []
+        for eqn in _walk_eqns(traced.jaxpr.jaxpr):
+            prims.append(eqn.primitive.name)
+            for out in eqn.outvars:
+                shape = tuple(getattr(out.aval, "shape", ()))
+                assert shape[:2] != (n_layer, w) or len(shape) != 5, \
+                    f"{eqn.primitive.name} makes a stacked cache {shape}"
+        assert not any("scatter" in p for p in prims), set(prims)
+        # one aliased row-writer call a buffer (ops/kv_rows.py)
+        assert prims.count("pallas_call") == 2 * n_layer
+        # the CPU does not donate, so read the lowering: every cache
+        # buffer argument names the output it aliases
+        text = traced.lower().as_text()
+        assert text.count("tf.aliasing_output") == 2 * n_layer, \
+            text.count("tf.aliasing_output")
+
+
+def test_engine_writes_and_reads_the_last_position(gpt_setup):
+    """``pos == max_len - 1``: the row lands in the buffer's last
+    position and the attention reads it."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    last = eng.max_len - 1
+    tokens = np.random.default_rng(13).integers(0, 97, (eng.max_len,))
+    _, want, oracle_caches = _oracle_run(g, params, tokens, eng.max_len)
+    step = eng._step_fn(False)
+    caches = eng._caches
+    zeros = jnp.zeros(2, jnp.int32)
+    for pos, tok in enumerate(tokens):
+        # slot 1 rides along at another position, with another token
+        ids, caches = step(eng.params, caches,
+                           jnp.asarray([tok, 5], jnp.int32),
+                           jnp.asarray([pos, last - pos], jnp.int32),
+                           zeros.astype(jnp.uint32),
+                           zeros.astype(jnp.float32))
+        assert int(ids[0]) == want[pos], pos
+    for side, i in (("k", 0), ("v", 1)):
+        got = np.asarray(caches[side][0])[0]
+        # one row against two: the products round differently
+        np.testing.assert_allclose(
+            got, np.asarray(oracle_caches["block_0"][i])[0], atol=1e-5)
+        assert np.abs(got[:, last]).max() > 0
 
 
 def test_engine_validates_requests(gpt_setup):
