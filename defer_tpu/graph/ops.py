@@ -560,7 +560,7 @@ class TransformerBlock(Op):
 
         The single definition of the block forward — ``apply`` discards the
         byproducts (XLA dead-code-eliminates them); decode-cache seeding
-        (models/gpt.py prefill) consumes them.  K/V are [b, t, kv*hd]
+        (the decode ring's prefill) consumes them.  K/V are [b, t, kv*hd]
         pre-head-split columns (kv == num_heads unless a GQA subclass
         narrows them).
         """

@@ -8,15 +8,14 @@ token-by-token generation path is served by the pipelined KV-cache engine in
 :mod:`defer_tpu.runtime.decode`.
 
 Each :class:`CausalTransformerBlock` is one graph node (a natural
-single-tensor cut point) and additionally exposes :meth:`decode` — the
-single-token step against a key/value cache that the decode engine switches
-on per stage.
+single-tensor cut point) and additionally meets the decode engines' block
+interface (:class:`~defer_tpu.models.decoder.DecoderBlock`): the
+single-token step in two halves around the key/value cache.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 
 import jax
 import jax.numpy as jnp
@@ -24,116 +23,7 @@ from jax import lax
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
 from ..graph.ops import Dense, LayerNorm, TransformerBlock, _cast
-
-
-class DecoderBlock:
-    """What every decoder block shares: causal attention over a whole
-    sequence, and the cache contract (how a row is quantized, written
-    and attended over).  A block the decode ring (``runtime/decode.py``)
-    can run has these, and
-
-    * ``num_heads`` / ``kv_heads`` / ``attn_impl``;
-    * ``apply_with_kv(params, x [b, t, d]) -> (y, k, v)``: the
-      full-sequence forward, with the key and value columns [b, t, kv*hd]
-      as :meth:`decode_qkv` would have written them row by row;
-    * ``decode_qkv(params, x [b, d], pos, *, quant) -> (q, rows)``: the
-      query and the new cache rows of the token at position ``pos``;
-    * ``decode_attend(params, x, q, k_cache, v_cache, pos, k_scale,
-      v_scale, sow=None) -> y``: attention over the cache item and the
-      rest of the block.  A block that names ``decode_stats`` adds one
-      scalar under each of those names to the dict ``sow``;
-    * ``stage_arg_keys``: keys of its parameter dict whose leaves the
-      ring passes as stage-sharded arguments of their own instead of
-      slicing them out of the flat weight row.
-    """
-
-    #: per-step scalars ``decode_attend`` sows (summed over a generation)
-    decode_stats: tuple = ()
-    #: parameter subtrees kept out of the flat weight row
-    stage_arg_keys: tuple = ()
-
-    def _attend(self, q, k, v):
-        """Causal attention on [b, nh, t, hd] by ``attn_impl``: the
-        flash kernel (bottom-right aligned) on a TPU, plain XLA elsewhere."""
-        impl = self.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "xla"
-        if impl not in ("flash", "xla"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'flash' or 'xla', got {impl!r}")
-        if impl == "flash":
-            from ..ops import flash_attention
-            return flash_attention(q, k, v, causal=True)
-        hd = q.shape[-1]
-        t_q, t_k = q.shape[2], k.shape[2]
-        att = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(hd)
-        q_pos = jnp.arange(t_q)[:, None] + (t_k - t_q)
-        mask = q_pos >= jnp.arange(t_k)[None, :]
-        att = jnp.where(mask, att, jnp.asarray(-jnp.inf, att.dtype))
-        att = jax.nn.softmax(att, axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", att, v)
-
-    @staticmethod
-    def quantize_row(row):
-        """Symmetric per-(head, position)-row int8: [..., hd] float ->
-        ([..., hd] int8, [...] f32 scale).  One scale per cache row keeps
-        dequantization a scalar multiply that folds EXACTLY into the
-        attention contractions (the scale is constant over the contracted
-        head dim), so the int8 cache is read raw by the dots and no
-        dequantized copy is ever materialized."""
-        rowf = row.astype(jnp.float32)
-        amax = jnp.max(jnp.abs(rowf), axis=-1)
-        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-        q = jnp.clip(jnp.round(rowf / scale[..., None]), -127, 127)
-        return q.astype(jnp.int8), scale
-
-    @classmethod
-    def cache_rows(cls, k_new, v_new, kv: int, quant: bool) -> dict:
-        """The new rows keyed as the caches are: ``k``/``v`` [b, kv, 1,
-        hd] from [b, kv*hd] columns; with ``quant`` int8 by
-        :meth:`quantize_row`, their [b, kv, 1] f32 scales as
-        ``ks``/``vs``."""
-        b = k_new.shape[0]
-        rows = {"k": k_new.reshape(b, kv, 1, -1),
-                "v": v_new.reshape(b, kv, 1, -1)}
-        if quant:
-            rows["k"], rows["ks"] = cls.quantize_row(rows["k"])
-            rows["v"], rows["vs"] = cls.quantize_row(rows["v"])
-        return rows
-
-    @staticmethod
-    def write_row(cache, row, pos, lead=()):
-        """``cache`` with ``row`` written in place at position ``pos``.
-        ``cache`` is [b, kv, L(, hd)] behind ``len(lead)`` more axes, at
-        whose indices ``lead`` the row lands; the row is cast to the
-        cache's type."""
-        row = lax.expand_dims(row, range(len(lead))).astype(cache.dtype)
-        at = tuple(lead) + (0, 0, pos) + (0,) * (row.ndim - len(lead) - 3)
-        return lax.dynamic_update_slice(cache, row, at)
-
-    @staticmethod
-    def cache_attention(q, k_cache, v_cache, pos, k_scale=None,
-                        v_scale=None):
-        """One query a sequence over its cache item: ``q`` [b, nh*hd]
-        against head-major ``k_cache``/``v_cache`` [b, kv, L, hd],
-        positions <= ``pos`` live; returns [b, nh*hd].  With scales the
-        caches are int8 rows and the scales fold into the dots."""
-        b, d = q.shape
-        kv, cache_len, hd = k_cache.shape[1:]
-        quant = k_scale is not None
-
-        qh = q.reshape(b, kv, d // (kv * hd), hd)
-        kh = k_cache.astype(q.dtype)
-        vh = v_cache.astype(q.dtype)
-        att = jnp.einsum("bkgd,bkld->bkgl", qh, kh) / math.sqrt(hd)
-        if quant:
-            att = att * k_scale[:, :, None, :].astype(att.dtype)
-        live = jnp.arange(cache_len)[None, None, None, :] <= pos
-        att = jnp.where(live, att, jnp.asarray(-jnp.inf, att.dtype))
-        att = jax.nn.softmax(att, axis=-1)
-        if quant:
-            att = att * v_scale[:, :, None, :].astype(att.dtype)
-        return jnp.einsum("bkgl,bkld->bkgd", att, vh).reshape(b, d)
+from .decoder import DecoderBlock
 
 
 @dataclasses.dataclass(frozen=True, repr=False)
@@ -203,76 +93,32 @@ class CausalTransformerBlock(DecoderBlock, TransformerBlock):
     # apply/apply_with_kv are inherited: the base TransformerBlock forward
     # (graph/ops.py) is the single implementation, made causal here purely
     # through DecoderBlock's _attend.  apply_with_kv's K/V columns
-    # match what decode() writes row-by-row (pre-head-split qkv
+    # match what decode_qkv hands over row by row (pre-head-split qkv
     # projections), so pipelined prefill bulk-writes cache rows 0..t-1
-    # (after the head-major relayout) and decoding continues at t.
+    # and decoding continues at t.
 
-    def decode_qkv(self, params, x, pos=None, *, quant: bool = False):
-        """First half of :meth:`decode`: LN + qkv projection of ``x``
-        [b, d], and the new cache rows (the pipelined decoder writes them
-        straight into its resident buffers).  Returns ``(q, rows)`` with
-        ``rows`` as :meth:`cache_rows` keys them.  ``pos`` is part of
-        the ring's block interface and is not read: this family's
-        positions come with the embedding."""
+    def decode_qkv(self, params, x, pos=None):
+        """First half of a one-token step: LN + qkv projection of ``x``
+        [b, d].  Returns the query and the new key and value columns.
+        ``pos`` is part of the block interface and is not read: this
+        family's positions come with the embedding."""
         del pos
         p = _cast(params, x.dtype)
         y = self._ln(p["ln1"], x, self.ln_eps)
         qkv = y @ p["qkv"]["w"] + p["qkv"]["b"]
-        q, k_new, v_new = self._split_qkv(qkv)
-        return q, self.cache_rows(k_new, v_new, self.kv_heads, quant)
+        return self._split_qkv(qkv)
 
-    def decode_attend(self, params, x, q, k_cache, v_cache, pos,
-                      k_scale=None, v_scale=None, sow=None):
-        """Second half of :meth:`decode`: attention of ``q`` over the
-        cache item (positions <= ``pos``, the new row already in it),
-        then proj + MLP on the residual stream ``x``.  Reads the caches
-        only; returns the block's output [b, d]."""
+    def decode_finish(self, params, x, y, sow=None):
+        """Second half: ``y`` [b, d] the attention's output over the
+        cache (the new row already in it), then proj + MLP on the
+        residual stream ``x``.  Returns the block's output [b, d]."""
         del sow     # no ``decode_stats``
         p = _cast(params, x.dtype)
-        y = self.cache_attention(q, k_cache, v_cache, pos, k_scale, v_scale)
         x = x + (y @ p["proj"]["w"] + p["proj"]["b"])
 
         y = self._ln(p["ln2"], x, self.ln_eps)
         y = jax.nn.gelu(y @ p["fc1"]["w"] + p["fc1"]["b"])
         return x + (y @ p["fc2"]["w"] + p["fc2"]["b"])
-
-    def decode(self, params, x, k_cache, v_cache, pos,
-               k_scale=None, v_scale=None):
-        """One-token step: ``x`` [b, d] at position ``pos``.
-
-        ``k_cache``/``v_cache`` are **head-major** [b, kv, L, hd] with
-        L > max position — KV heads lead so the attention contractions are
-        plain batched dots; a position-major [b, L, d] layout would make
-        XLA materialize a transpose of the whole cache every step.  Under
-        GQA, kv < num_heads and each cache head serves its whole query
-        group without materializing repeats.  The new key/value row is
-        written at ``pos`` (callers pass a clamped scratch index for
-        bubble steps) and attention covers positions <= ``pos``.
-
-        With ``k_scale``/``v_scale`` ([b, kv, L] f32) the caches are int8
-        rows quantized by :meth:`quantize_row`; scales fold into the dots
-        exactly (per-row constants), so ICI^W HBM reads shrink to ~1
-        byte/value.  Returns ``(y, k_cache, v_cache)`` plus the updated
-        scales when quantized.
-
-        The composition of :meth:`decode_qkv`, the row writes and
-        :meth:`decode_attend` over one cache item: the oracle the tests
-        hold both engines to.  The pipelined decoder and the serving
-        engine call the halves and write the rows into their own
-        buffers.
-        """
-        quant = k_scale is not None
-        q, rows = self.decode_qkv(params, x, pos, quant=quant)
-        if quant:
-            k_scale = self.write_row(k_scale, rows["ks"], pos)
-            v_scale = self.write_row(v_scale, rows["vs"], pos)
-        k_cache = self.write_row(k_cache, rows["k"], pos)
-        v_cache = self.write_row(v_cache, rows["v"], pos)
-        out = self.decode_attend(params, x, q, k_cache, v_cache, pos,
-                                 k_scale, v_scale)
-        if quant:
-            return out, k_cache, v_cache, k_scale, v_scale
-        return out, k_cache, v_cache
 
 
 class GptEmbedding(Op):

@@ -5,10 +5,10 @@ rotate-half RoPE, and in place of the MLP a routed layer of SwiGLU
 experts — softmax over all experts, the ``experts_per_tok`` largest, their
 probabilities used as they are.
 
-The graph follows the ring's node-name contract (``embeddings`` /
-``block_i`` / ``final_ln`` / ``lm_head``, models/gpt.py) and
+The graph follows the decoder-model contract (``embeddings`` /
+``block_i`` / ``final_ln`` / ``lm_head``, models/decoder.py) and
 :class:`OlmoeBlock` meets its block interface
-(:class:`~defer_tpu.models.gpt.DecoderBlock`), so the full-sequence graph
+(:class:`~defer_tpu.models.decoder.DecoderBlock`), so the full-sequence graph
 rides ``SpmdPipeline`` and generation rides ``PipelinedDecoder`` like the
 GPT family's.  Keys are rotated *before* they are cached: a cache row is
 final when it is written.
@@ -26,7 +26,7 @@ from jax import lax
 from ..graph.ir import GraphBuilder, LayerGraph, Op
 from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch, rms_norm,
                          route_top_k)
-from .gpt import DecoderBlock
+from .decoder import DecoderBlock
 
 
 def rope(x, pos, theta: float):
@@ -142,8 +142,8 @@ class OlmoeBlock(DecoderBlock, Op):
     def apply_with_kv(self, params, x, sow=None):
         """Full-sequence forward on ``x`` [b, t, d]; also returns the key
         (normed and rotated) and value columns [b, t, nh*hd] that
-        :meth:`decode_qkv` would have written row by row.  A dict
-        ``sow`` is filled as :meth:`decode_attend` fills it, over all
+        :meth:`decode_qkv` would have handed over row by row.  A dict
+        ``sow`` is filled as :meth:`decode_finish` fills it, over all
         b*t rows, and with their chosen experts under ``moe.chosen``."""
         p = _cast(params, x.dtype)
         b, t, d = x.shape
@@ -155,22 +155,20 @@ class OlmoeBlock(DecoderBlock, Op):
 
     # -- one token against the cache --------------------------------------
 
-    def decode_qkv(self, params, x, pos, *, quant: bool = False):
-        """Query and new cache rows of ``x`` [b, d] at scalar ``pos``."""
+    def decode_qkv(self, params, x, pos):
+        """Query and new key and value columns of ``x`` [b, d] at scalar
+        ``pos``."""
         p = _cast({nm: params[nm] for nm in
                    ("ln1", "q", "q_norm", "k", "k_norm", "v")}, x.dtype)
         b, d = x.shape
         q, k, v = self._qkv(p, x[:, None], jnp.reshape(pos, (1,)))
-        return q.reshape(b, d), self.cache_rows(
-            k.reshape(b, d), v.reshape(b, d), self.kv_heads, quant)
+        return q.reshape(b, d), k.reshape(b, d), v.reshape(b, d)
 
-    def decode_attend(self, params, x, q, k_cache, v_cache, pos,
-                      k_scale=None, v_scale=None, sow=None):
-        """Attention of ``q`` over the cache item, the output projection
-        and the routed experts; sows :attr:`decode_stats` of this step."""
+    def decode_finish(self, params, x, y, sow=None):
+        """The output projection of the attention's output ``y`` and the
+        routed experts; sows :attr:`decode_stats` of this step."""
         p = _cast({nm: params[nm] for nm in
                    ("proj", "ln2", "router", "experts")}, x.dtype)
-        y = self.cache_attention(q, k_cache, v_cache, pos, k_scale, v_scale)
         return self._finish(p, x, y, sow)
 
     def flops(self, in_specs, out_spec):

@@ -14,16 +14,13 @@ TPU-native design, one SPMD program:
   * Weights: each device materializes only its stage's parameters from a
     stage-sharded flat buffer (same scheme as ``SpmdPipeline``), stored in
     the compute dtype.
-  * KV caches: per device, one resident buffer a local block,
-    ``[N+1, mb, nh, max_len+1, hd]`` (groups; head-major so attention
-    needs no per-step cache transpose) in compute dtype; position row
-    ``max_len`` is a scratch slot that warmup bubbles write into, and
-    group slot ``N`` absorbs prefill bubbles — so no masked
-    read-modify-write of the cache is ever needed.  A step writes one
-    ``[mb, nh, 1, hd]`` row a block in place and reads the group's item;
-    the blocks are never stacked into one array, because XLA:TPU wraps a
-    write into a value that large in copies of all of it
-    (docs/DECODE_CLIFF.md).
+  * KV caches: per device, one resident buffer a local block and key,
+    held and touched only through the cache's format
+    (``ops/kv_cache.py``, which describes the layout): a step writes one
+    row a block in place, every sequence of the group at one position,
+    and attends over a read-only item of the group; warmup bubbles
+    write the format's scratch row and prefill bubbles its scratch
+    group, so no masked read-modify-write of the cache is ever needed.
   * The ring carry is one ``[mb, d]`` float32 buffer per device: stage
     activations in flight, and — on the wrap link from the last stage back
     to stage 0 (the reference's node->dispatcher link,
@@ -40,12 +37,11 @@ TPU-native design, one SPMD program:
     top-k, keyed by ``fold_in(seed, step)`` so results are independent of
     the chunking.
 
-Scope: stage-axis-only mesh, the ``gpt()`` node-name contract
-(``embeddings`` / ``block_i`` / ``final_ln`` / ``lm_head`` —
-models/gpt.py), and blocks that meet ``models.gpt.DecoderBlock``'s
-interface (``decode_qkv(params, x, pos)`` / ``write_row`` /
-``decode_attend`` / ``apply_with_kv``; an embedding with ``embed_at``):
-the ring asks a block for nothing else, whatever its family.  Prompts
+Scope: stage-axis-only mesh and the decoder-model contract of
+``models/decoder.py`` (``embeddings`` / ``block_i`` / ``final_ln`` /
+``lm_head``; blocks with ``decode_qkv`` / ``decode_finish`` /
+``apply_with_kv``; an embedding with ``embed_at``): the ring asks a
+block for nothing else, whatever its family.  Prompts
 are processed either at decode rate (teacher forcing inside the scan,
 the default) or by the fused full-sequence
 pipelined prefill (``generate(..., prefill=True)``): each group's whole
@@ -65,14 +61,15 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.ir import LayerGraph
-from ..models.gpt import DecoderBlock
+from ..models.decoder import decoder_parts
 from ..obs import REGISTRY, span
+from ..ops import kv_cache as _kv_cache
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
 
 
-def _sample_ids(logits, temp, top_k, step_key):
+def sample_ids(logits, temp, top_k, step_key):
     """Temperature softmax sampling with optional top-k truncation.
 
     The single definition shared by the decode and prefill branches — both
@@ -82,30 +79,6 @@ def _sample_ids(logits, temp, top_k, step_key):
         kth = lax.top_k(lg, top_k)[0][:, -1:]
         lg = jnp.where(lg >= kth, lg, -jnp.inf)
     return jax.random.categorical(step_key, lg, axis=-1)
-
-
-def _split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
-    """Contiguous, balanced block assignment (stage i gets ~L/N blocks)."""
-    bounds = [round(num_blocks * s / num_stages)
-              for s in range(num_stages + 1)]
-    out = [list(range(bounds[s], bounds[s + 1])) for s in range(num_stages)]
-    if any(not b for b in out):
-        raise ValueError(
-            f"{num_blocks} blocks cannot fill {num_stages} stages")
-    return out
-
-
-def _group_slice(buf, g):
-    """Group ``g``'s part ``[1, ...]`` of a ``[N+1, ...]`` cache buffer."""
-    return lax.dynamic_slice(buf, (g,) + (0,) * (buf.ndim - 1),
-                             (1,) + buf.shape[1:])
-
-
-def _with_block(caches: dict, key: str, l: int, buf) -> dict:
-    """``caches`` with local block ``l``'s buffer of entry ``key`` replaced."""
-    bufs = list(caches[key])
-    bufs[l] = buf
-    return dict(caches, **{key: tuple(bufs)})
 
 
 class PipelinedDecoder:
@@ -165,55 +138,21 @@ class PipelinedDecoder:
                 "microbatch/beam_width sequences x beam_width beams)")
         self.beam_width = beam_width
 
-        nodes = graph.nodes
-        for req in ("embeddings", "final_ln", "lm_head"):
-            if req not in nodes:
-                raise ValueError(
-                    f"decoder graphs must follow the gpt() node contract; "
-                    f"missing {req!r} (models/gpt.py)")
-        self.embed_op = nodes["embeddings"].op
-        if max_len is None:
-            max_len = self.embed_op.max_len  # the positions' reach
-        self.max_len = max_len
-        if max_len > self.embed_op.max_len:
-            raise ValueError(
-                f"max_len {max_len} exceeds the model's positional table "
-                f"({self.embed_op.max_len})")
-        block_names = [nm for nm in graph.topo_order
-                       if nm.startswith("block_")]
-        self.block_names = block_names
-        for nm in block_names:
-            if not isinstance(nodes[nm].op, DecoderBlock):
-                raise TypeError(
-                    f"{nm} ({nodes[nm].op!r}) is not a DecoderBlock "
-                    "(models/gpt.py): the ring needs its decode_qkv / "
-                    "write_row / decode_attend / apply_with_kv")
-        self.d_model = nodes[block_names[0]].out_spec.shape[-1]
-        self.num_heads = nodes[block_names[0]].op.num_heads
-        self.num_kv_heads = nodes[block_names[0]].op.kv_heads
-        self.head_dim = self.d_model // self.num_heads
-        self.vocab = nodes["lm_head"].out_spec.shape[-1]
+        parts = decoder_parts(graph, n, max_len)
+        self.embed_op = parts.embed_op
+        self.max_len = max_len = parts.max_len
+        self.block_names = block_names = list(parts.block_names)
+        self.d_model = parts.d_model
+        self.num_heads = parts.num_heads
+        self.num_kv_heads = parts.kv_heads
+        self.head_dim = parts.head_dim
+        self.vocab = parts.vocab
         #: per-step scalars the blocks sow (``DecoderBlock.decode_stats``);
         #: summed on the device over a generation, fetched once at its end
-        self._stat_names = tuple(nodes[block_names[0]].op.decode_stats)
-        for nm in block_names:
-            op = nodes[nm].op
-            if (op.num_heads, op.kv_heads) != (self.num_heads,
-                                               self.num_kv_heads):
-                raise ValueError(
-                    f"{nm} has heads ({op.num_heads}, kv {op.kv_heads}) "
-                    f"!= block_0's ({self.num_heads}, "
-                    f"{self.num_kv_heads}); the homogeneous cache needs "
-                    "one head geometry")
-            if tuple(op.decode_stats) != self._stat_names:
-                raise ValueError(
-                    f"{nm} sows {op.decode_stats}, block_0 "
-                    f"{self._stat_names}: one ledger serves every block")
-
-        assign = _split_blocks(len(block_names), n)
-        self.stage_blocks = [[block_names[i] for i in idxs]
-                             for idxs in assign]
+        self._stat_names = parts.decode_stats
+        self.stage_blocks = parts.stage_blocks
         self.l_max = max(len(b) for b in self.stage_blocks)
+        nodes = graph.nodes
 
         # --- stage-sharded flat weight buffer (scheme of runtime/spmd.py)
         stage_param_names: list[list[str]] = []
@@ -249,16 +188,12 @@ class PipelinedDecoder:
         self._wspec_tree = jax.tree.map(
             lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
 
-        # one local block's buffer (the state holds l_max of each).  Group
-        # axis is n+1: slot n is the scratch group that pipelined
-        # prefill's warmup/drain bubbles write into (the group-axis twin of
-        # the max_len scratch row).  Head-major position axis per the
-        # CausalTransformerBlock.decode cache contract; under GQA the head
-        # axis is the (smaller) KV head count.
-        self._cache_shape = (n + 1, mb, self.num_kv_heads, max_len + 1,
-                             self.head_dim)
-        #: per-row f32 scales for the int8 cache (one per head x position)
-        self._scale_shape = (n + 1, mb, self.num_kv_heads, max_len + 1)
+        #: one local block's cache (the state holds l_max of each), n
+        #: groups of mb sequences; looked up on the module when the
+        #: decoder is built, so a test can put another format in its place
+        self.kv_format = _kv_cache.KVCacheFormat(
+            self.num_kv_heads, self.head_dim, max_len, self.compute_dtype,
+            quantized=kv_cache == "int8", groups=n)
         #: ring-buffer width: beam mode adds one column carrying each
         #: row's parent-beam index around the ring alongside the token id
         self._ring_width = self.d_model + (1 if beam_width > 1 else 0)
@@ -400,7 +335,7 @@ class PipelinedDecoder:
         is_first, is_last = s == 0, s == n - 1
         block_ops = [nodes[nm].op for nm in self.stage_blocks[s]]
         embed_op = self.embed_op
-        int8 = self.kv_cache == "int8"
+        fmt = self.kv_format
         beam = self.beam_width
         mb = self.microbatch
         stats = self._stat_names
@@ -414,7 +349,7 @@ class PipelinedDecoder:
             # outputs are never read (host drops them by schedule index)
             valid = jnp.logical_and(pos >= 0, pos < self.max_len)
             safe_pos = jnp.clip(pos, 0, self.max_len - 1)
-            write_pos = jnp.where(valid, safe_pos, self.max_len)
+            write_pos = jnp.where(valid, safe_pos, fmt.scratch_position)
 
             if beam > 1:
                 # re-parent this group's cache rows before appending the
@@ -428,20 +363,9 @@ class PipelinedDecoder:
                     jnp.round(a[:, self.d_model]).astype(jnp.int32),
                     0, mb - 1)
                 applies = jnp.logical_and(valid, safe_pos >= plen)
-
-                def reparent_all(cs):
-                    def reparent(buf):
-                        # [n+1, mb, ...] -> rows of group g gathered
-                        grp = jnp.take(_group_slice(buf, g), parents, axis=1)
-                        return lax.dynamic_update_slice(
-                            buf, grp, (g,) + (0,) * (buf.ndim - 1))
-
-                    return {nm: (jax.tree.map(reparent, c)
-                                 if nm not in ("beam_cum", "stats") else c)
-                            for nm, c in cs.items()}
-
-                caches = lax.cond(applies, reparent_all,
-                                  lambda cs: cs, caches)
+                caches = lax.cond(
+                    applies, lambda cs: fmt.reparent(cs, g, parents),
+                    lambda cs: cs, caches)
 
             if is_first:
                 recv_ids = jnp.round(a[:, 0]).astype(jnp.int32)
@@ -465,17 +389,14 @@ class PipelinedDecoder:
                 # of one block), then attend over a read-only slice of
                 # the group's item: nothing the size of an item is
                 # written back
-                q, rows = op.decode_qkv(p[nm], x, safe_pos, quant=int8)
-                item = {}
-                for key, row in rows.items():
-                    buf = op.write_row(caches[key][l], row, write_pos,
-                                       lead=(g,))
-                    caches = _with_block(caches, key, l, buf)
-                    item[key] = _group_slice(buf, g)[0]
+                q, k_new, v_new = op.decode_qkv(p[nm], x, safe_pos)
+                layer, item = fmt.write_position(
+                    fmt.layer(caches, l), fmt.rows(k_new, v_new),
+                    write_pos, group=g)
+                caches = fmt.with_layer(caches, l, layer)
                 sown = {} if stats else None
-                x = op.decode_attend(p[nm], x, q, item["k"], item["v"],
-                                     write_pos, item.get("ks"),
-                                     item.get("vs"), sow=sown)
+                x = op.decode_finish(
+                    p[nm], x, fmt.attend(q, item, write_pos), sow=sown)
                 if stats:
                     step = jnp.stack([sown[k] for k in stats])
                     caches = dict(caches, stats=caches["stats"] + jnp.where(
@@ -522,7 +443,7 @@ class PipelinedDecoder:
                 elif sample:
                     # keyed by the global step so results are identical
                     # under any dispatch chunking; rows draw independently
-                    ids = _sample_ids(
+                    ids = sample_ids(
                         logits, temp, top_k,
                         jax.random.fold_in(jax.random.PRNGKey(seed), t))
                 else:
@@ -548,7 +469,7 @@ class PipelinedDecoder:
         attention (``apply_with_kv``) and bulk-writes cache rows
         ``0..plen-1``; the last stage emits the first generated token
         (position ``plen``).  Bubble steps (g outside [0, n)) write the
-        scratch group ``n``.
+        format's scratch group.
         """
         n = self.num_stages
         nodes = self.graph.nodes
@@ -556,13 +477,13 @@ class PipelinedDecoder:
         mb, d = self.microbatch, self.d_model
         is_first, is_last = s == 0, s == n - 1
         embed_op = self.embed_op
-        int8 = self.kv_cache == "int8"
+        fmt = self.kv_format
 
         def branch(w_local, a, caches, prompt, g, seed, temp):
             p = self._stage_params(s, w_local)
             valid = jnp.logical_and(g >= 0, g < n)
             safe_g = jnp.clip(g, 0, n - 1)
-            write_g = jnp.where(valid, safe_g, n)  # scratch group
+            write_g = jnp.where(valid, safe_g, fmt.scratch_group)
 
             if is_first:
                 ids = lax.dynamic_slice(prompt, (safe_g, 0, 0),
@@ -571,24 +492,10 @@ class PipelinedDecoder:
             else:
                 x = a.reshape(mb, plen, d).astype(cd)
 
-            kvh, hd = self.num_kv_heads, self.head_dim
             for l, nm in enumerate(self.stage_blocks[s]):
-                op = nodes[nm].op
-                x, k, v = op.apply_with_kv(p[nm], x)
-                # head-major relayout (one transpose per prompt, amortized)
-                k = k.reshape(mb, plen, kvh, hd).transpose(0, 2, 1, 3)
-                v = v.reshape(mb, plen, kvh, hd).transpose(0, 2, 1, 3)
-                new = {"k": k, "v": v}
-                if int8:
-                    # [mb, kv, plen] scales
-                    new["k"], new["ks"] = op.quantize_row(k)
-                    new["v"], new["vs"] = op.quantize_row(v)
-                for key, rows in new.items():
-                    buf = caches[key][l]
-                    buf = lax.dynamic_update_slice(
-                        buf, rows[None].astype(buf.dtype),
-                        (write_g,) + (0,) * (buf.ndim - 1))
-                    caches = _with_block(caches, key, l, buf)
+                x, k, v = nodes[nm].op.apply_with_kv(p[nm], x)
+                caches = fmt.with_layer(caches, l, fmt.write_prefix(
+                    fmt.layer(caches, l), k, v, write_g))
 
             if is_last:
                 h = nodes["final_ln"].op.apply(p["final_ln"], x[:, -1])
@@ -596,7 +503,7 @@ class PipelinedDecoder:
                     p["lm_head"], h).astype(jnp.float32)
                 if sample:
                     # key domain disjoint from decode's per-step keys
-                    ids = _sample_ids(
+                    ids = sample_ids(
                         logits, temp, top_k,
                         jax.random.fold_in(jax.random.PRNGKey(seed),
                                            (1 << 30) + safe_g))
@@ -612,13 +519,11 @@ class PipelinedDecoder:
 
     def _state_specs(self):
         """shard_map spec pytree for the cache-state dict."""
-        def per_block(rank):
-            # one buffer a local block, never one array of the whole stack
-            return (P(STAGE_AXIS, *(None,) * rank),) * self.l_max
-
-        specs = {"k": per_block(5), "v": per_block(5)}
-        if self.kv_cache == "int8":
-            specs.update(ks=per_block(4), vs=per_block(4))
+        # one buffer a local block, never one array of the whole stack
+        specs = {key: (P(STAGE_AXIS, *(None,) * len(buf.shape)),)
+                 * self.l_max
+                 for key, buf in self.kv_format.buffers(
+                     self.microbatch).items()}
         if self.beam_width > 1:
             # per-group cumulative beam scores; only the LAST stage's
             # device shard is meaningful (it runs the expansion)
@@ -677,19 +582,9 @@ class PipelinedDecoder:
             state_sh = jax.tree.map(
                 lambda spec: NamedSharding(self.mesh, spec),
                 self._state_specs())
-            cdt = jnp.int8 if self.kv_cache == "int8" \
-                else self.compute_dtype
 
             def zeros():
-                def per_block(shape, dtype):
-                    return tuple(jnp.zeros((n,) + shape, dtype)
-                                 for _ in range(self.l_max))
-
-                caches = {"k": per_block(self._cache_shape, cdt),
-                          "v": per_block(self._cache_shape, cdt)}
-                if self.kv_cache == "int8":
-                    caches["ks"] = per_block(self._scale_shape, jnp.float32)
-                    caches["vs"] = per_block(self._scale_shape, jnp.float32)
+                caches = self.kv_format.zeros(mb, self.l_max, lead=(n,))
                 if self.beam_width > 1:
                     caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
                 if self._stat_names:

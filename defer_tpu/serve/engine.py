@@ -10,10 +10,10 @@ engine is continuous batching proper:
 
 * The batch is ``width`` SLOTS.  Each slot holds one request's state —
   its prompt, its position, and its OWN row range of every layer's KV
-  cache: one ``[width, kv_heads, max_len, head_dim]`` f32 buffer a
-  layer and side, every one a donated argument of the step and its
-  aliased output (the per-block buffers of ``runtime/decode.py``, with
-  the group axis replaced by a slot axis).
+  cache: one f32 buffer of ``width`` sequences a layer and key, in the
+  cache's format (``ops/kv_cache.py``, which describes the layout; the
+  ring's buffers without its groups), every one a donated argument of
+  the step and its aliased output.
 * Between any two decode steps, finished requests leave (slot freed,
   tokens delivered) and waiting requests join (slot claimed, position
   0); the step program itself never changes — one compiled program per
@@ -23,10 +23,11 @@ engine is continuous batching proper:
   request needs no separate prefill program), sampled past it.  A
   layer is the block's own halves, as the ring calls them:
   ``decode_qkv`` on the whole ``[width, d]`` batch, each slot's new row
-  written in place at that slot's OWN position (``ops/kv_rows.py``:
-  slots sit at different positions, and a vmapped write would be a
-  batched scatter over a re-laid-out item, docs/DECODE_CLIFF.md), then
-  ``decode_attend`` with each slot's own live mask.  Every row's
+  written in place at that slot's OWN position (the format's
+  ``write_slots``: slots sit at different positions, and a vmapped
+  write would be a batched scatter over a re-laid-out item,
+  docs/DECODE_CLIFF.md), attention with each slot's own live mask, then
+  ``decode_finish``.  Every row's
   computation reads its own rows only, so a row's output bytes are
   INDEPENDENT of who shares the batch — per-request outputs are
   byte-identical to the request run alone, the correctness bar
@@ -36,7 +37,7 @@ engine is continuous batching proper:
   step.
 
 The stage structure mirrors the deployed chain's partition (same
-``_split_blocks`` assignment), so the planner's per-stage latency budget
+``split_blocks`` assignment), so the planner's per-stage latency budget
 (``plan.cost.stage_ms_at_batch``) prices this engine's step the same way
 it prices a chain frame; it is the planner's structure only, the step
 walks the blocks in order.  Execution here is in-process (one jitted
@@ -58,11 +59,12 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import LayerGraph
+from ..models.decoder import decoder_parts
 from ..models.gpt import CausalTransformerBlock, GptEmbedding
 from ..obs import REGISTRY, span
 from ..obs.events import emit as emit_event
-from ..ops.kv_rows import write_kv_rows
-from ..runtime.decode import _sample_ids, _split_blocks
+from ..ops import kv_cache
+from ..runtime.decode import sample_ids
 from .batcher import _stamp_popped
 
 
@@ -119,60 +121,41 @@ class ContinuousBatchEngine:
                  max_len: int | None = None, top_k: int | None = None):
         if width < 1:
             raise ValueError(f"width must be >= 1, got {width}")
+        parts = decoder_parts(graph, num_stages, max_len)
         nodes = graph.nodes
-        for req in ("embeddings", "final_ln", "lm_head"):
-            if req not in nodes:
-                raise ValueError(
-                    f"decode engine needs the gpt() node contract; "
-                    f"missing {req!r} (models/gpt.py)")
-        self.graph = graph
-        self.params = jax.tree.map(jnp.asarray, params)
-        self.width = width
-        self.num_stages = num_stages
-        self.embed_op: GptEmbedding = nodes["embeddings"].op
-        self.max_len = max_len or self.embed_op.max_len
-        if self.max_len > self.embed_op.max_len:
-            raise ValueError(
-                f"max_len {self.max_len} exceeds the positional table "
-                f"({self.embed_op.max_len})")
-        block_names = [nm for nm in graph.topo_order
-                       if nm.startswith("block_")]
-        for nm in block_names:
+        for nm in parts.block_names:
             if not isinstance(nodes[nm].op, CausalTransformerBlock):
-                # the step adds learned positions and calls decode()
-                # with no position: another family would answer wrongly
+                # the step adds learned positions and hands the blocks
+                # no position: another family would answer wrongly
                 raise TypeError(
                     f"{nm} ({nodes[nm].op!r}) is not a "
                     "CausalTransformerBlock: the decode engine serves the "
                     "GPT family only (PipelinedDecoder runs the others)")
-        assign = _split_blocks(len(block_names), num_stages)
+        self.graph = graph
+        self.params = jax.tree.map(jnp.asarray, params)
+        self.width = width
+        self.num_stages = num_stages
+        self.embed_op: GptEmbedding = parts.embed_op
+        self.max_len = parts.max_len
         #: the chain-partition structure: stage s owns these blocks
-        self.stage_blocks = [[block_names[i] for i in idxs]
-                             for idxs in assign]
-        blk0 = nodes[block_names[0]].op
-        self.d_model = nodes[block_names[0]].out_spec.shape[-1]
-        self.kv_heads = blk0.kv_heads
-        self.head_dim = self.d_model // blk0.num_heads
+        self.stage_blocks = parts.stage_blocks
         self.top_k = top_k
+        #: one layer's cache: ``width`` slots, f32 (the step computes in
+        #: it); looked up on the module when the engine is built, so a
+        #: test can put another format in its place
+        self.kv_format = kv_cache.KVCacheFormat(
+            parts.kv_heads, parts.head_dim, self.max_len, jnp.float32)
 
         self._slots: list[_Slot | None] = [None] * width
-        self._caches = self._init_caches()
+        #: every layer's buffers, each a donated argument of the step and
+        #: its aliased output (docs/DECODE_CLIFF.md, "The engine")
+        self._caches = self.kv_format.zeros(width, len(parts.block_names))
         self._step_fns: dict[bool, Any] = {}
         self.steps = 0
         self._step_hist = REGISTRY.histogram("serve.decode.step_s")
         self._tok_count = REGISTRY.counter("serve.decode.tokens")
 
     # -- state -------------------------------------------------------------
-
-    def _init_caches(self):
-        """One ``[width, kv_heads, max_len, head_dim]`` f32 buffer a
-        layer and side, each a donated argument of the step and its
-        aliased output (docs/DECODE_CLIFF.md, "The engine")."""
-        shape = (self.width, self.kv_heads, self.max_len, self.head_dim)
-        n_layer = sum(len(blks) for blks in self.stage_blocks)
-        return {side: tuple(jnp.zeros(shape, jnp.float32)
-                            for _ in range(n_layer))
-                for side in ("k", "v")}
 
     def free_slots(self) -> int:
         return sum(1 for s in self._slots if s is None)
@@ -219,21 +202,21 @@ class ContinuousBatchEngine:
         final_ln = nodes["final_ln"].op
         lm_head = nodes["lm_head"].op
         top_k = self.top_k
+        fmt = self.kv_format
 
         def step(params, caches, ids, pos, seeds, temps):
             safe = jnp.clip(pos, 0, self.max_len - 1)
             x = embed.embed_rows(params["embeddings"], ids,
                                  safe).astype(jnp.float32)
             # every slot attends over its own positions <= its own pos
-            live_to = safe[:, None, None, None]
-            ks, vs = list(caches["k"]), list(caches["v"])
+            live_to = fmt.live_to(safe)
             for l, (op, nm) in enumerate(blocks):
-                q, rows = op.decode_qkv(params[nm], x, safe)
-                ks[l] = write_kv_rows(ks[l], rows["k"], safe)
-                vs[l] = write_kv_rows(vs[l], rows["v"], safe)
-                x = op.decode_attend(params[nm], x, q, ks[l], vs[l],
-                                     live_to)
-            out_caches = {"k": tuple(ks), "v": tuple(vs)}
+                q, k_new, v_new = op.decode_qkv(params[nm], x, safe)
+                layer = fmt.write_slots(fmt.layer(caches, l),
+                                        fmt.rows(k_new, v_new), safe)
+                caches = fmt.with_layer(caches, l, layer)
+                x = op.decode_finish(params[nm], x,
+                                     fmt.attend(q, layer, live_to))
             h = final_ln.apply(params["final_ln"], x)
             logits = lm_head.apply(params["lm_head"],
                                    h).astype(jnp.float32)
@@ -241,13 +224,13 @@ class ContinuousBatchEngine:
                 def row_sample(lg, seed_r, pos_r, temp_r):
                     key = jax.random.fold_in(
                         jax.random.PRNGKey(seed_r), pos_r)
-                    return _sample_ids(lg[None], temp_r, top_k, key)[0]
+                    return sample_ids(lg[None], temp_r, top_k, key)[0]
                 sampled = jax.vmap(row_sample)(logits, seeds, safe, temps)
                 ids_out = jnp.where(temps > 0, sampled,
                                     jnp.argmax(logits, axis=-1))
             else:
                 ids_out = jnp.argmax(logits, axis=-1)
-            return ids_out.astype(jnp.int32), out_caches
+            return ids_out.astype(jnp.int32), caches
 
         return jax.jit(step, donate_argnums=(1,))
 

@@ -58,9 +58,11 @@ def main() -> int:
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    cache = arg((1,) + dec._cache_shape, jnp.bfloat16,
-                P(STAGE_AXIS, None, None, None, None, None))
-    caches = {"k": (cache,) * dec.l_max, "v": (cache,) * dec.l_max}
+    # the format's buffers behind the ring's own stage axis
+    caches = {key: (arg((1,) + buf.shape, buf.dtype,
+                        P(STAGE_AXIS, *(None,) * len(buf.shape))),)
+              * dec.l_max
+              for key, buf in dec.kv_format.buffers(mb).items()}
     i32 = arg((), jnp.int32)
     _, chunk_steps = dec._schedule(plen + 128, 0, 32)
     compiled = dec._build_decode_fn(chunk_steps, False, None).lower(

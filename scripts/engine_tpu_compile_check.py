@@ -11,7 +11,7 @@ the stack, copied it to a scatter's layout and back and wrote it into
 the stack again: 23 ms of a 38 ms step (ledger, PR 26).  All of that
 shows in the compiled text as operations that *produce* an array of a
 cache buffer's size.  With one buffer a layer and each slot's row
-written in place (``ops/kv_rows.py``) there is none.  Run it before
+written in place (``ops/kv_cache.py``) there is none.  Run it before
 spending chip time on a change to how the engine holds or writes its
 caches:
 
@@ -43,7 +43,7 @@ from jax.sharding import SingleDeviceSharding
 from defer_tpu.models import gpt
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
-WIDTH, MAX_LEN = 16, 192
+N_LAYER, WIDTH, MAX_LEN = 48, 16, 192
 
 #: ``%name = f32[16,25,192,64]{...} opcode(operands), attrs``
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
@@ -130,7 +130,7 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    graph = gpt(48, 1600, 25, 1024, vocab=50257)
+    graph = gpt(N_LAYER, 1600, 25, 1024, vocab=50257)
     # the step reads its weights as an argument: the engine itself holds
     # none here, and the program gets their shapes
     eng = ContinuousBatchEngine(graph, {}, num_stages=1, width=WIDTH,
@@ -141,8 +141,10 @@ def main() -> int:
 
     params = jax.tree.map(shaped,
                           jax.eval_shape(graph.init, jax.random.key(0)))
-    caches = jax.tree.map(shaped, eng._caches)
-    item = (WIDTH, eng.kv_heads, MAX_LEN, eng.head_dim)
+    buffers = eng.kv_format.buffers(WIDTH)
+    caches = {key: (shaped(buf),) * N_LAYER
+              for key, buf in buffers.items()}
+    item = buffers["k"].shape
 
     def vec(dtype):
         return jax.ShapeDtypeStruct((WIDTH,), dtype, sharding=chip)

@@ -1,0 +1,97 @@
+"""sha256 of the lowered text of both decode engines' programs, to show
+that a refactor compiles what its parent compiled — no chip needed, not
+part of the tests.
+
+The ring's decode and prefill programs (``gpt_tiny`` and ``olmoe_tiny``;
+``kv_cache`` buffer and int8; ``beam_width`` 1 and 2; 1 stage and
+several; ``olmoe_tiny``'s widths at four layers for four stages) and
+the engine's step (greedy and sampling), lowered on the CPU
+mesh at toy sizes.  Run it in two trees and compare the lines:
+
+    env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        python scripts/lowered_text_hashes.py [DIR]
+
+One ``name sha256 bytes`` line a program; with ``DIR`` each text is also
+written to ``DIR/<name>.txt`` for ``diff``.  It reads only what both
+engines have always had: ``_init_state``, ``_get_decode_fn``,
+``_build_prefill_fn``, ``_step_fn``, ``_caches``.
+"""
+
+import hashlib
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from defer_tpu.models import gpt_tiny, olmoe, olmoe_tiny
+from defer_tpu.runtime.decode import PipelinedDecoder
+from defer_tpu.serve.engine import ContinuousBatchEngine
+
+PLEN, CHUNK = 5, 2
+
+
+def ring_programs(name, graph, stages):
+    params = graph.init(jax.random.key(0))
+    for n in stages:
+        for kv_cache in ("buffer", "int8"):
+            for beam in (1, 2):
+                dec = PipelinedDecoder(
+                    graph, params, num_stages=n, microbatch=2, max_len=16,
+                    kv_cache=kv_cache, beam_width=beam)
+                tag = f"ring.{name}.{kv_cache}.beam{beam}.stages{n}"
+                mb = dec.microbatch
+                prompt = jnp.zeros((n, mb, PLEN), jnp.int32)
+                a, caches = dec._init_state()
+                i32 = jnp.int32(0)
+                for sample in (False, True) if beam == 1 else (False,):
+                    mode = "sample" if sample else "greedy"
+                    top_k = 3 if sample else None
+                    yield f"{tag}.decode.{mode}", dec._get_decode_fn(
+                        n * CHUNK, sample, top_k).lower(
+                        dec._w, prompt, i32, i32, i32, jnp.uint32(0),
+                        jnp.float32(0), jnp.zeros((n, mb), jnp.int32), i32,
+                        i32, a, caches)
+                    if beam == 1:   # beam search has no fused prefill
+                        yield f"{tag}.prefill.{mode}", \
+                            dec._build_prefill_fn(PLEN, sample, top_k).lower(
+                                dec._w, prompt, jnp.uint32(0),
+                                jnp.float32(0), caches)
+
+
+def engine_programs():
+    graph = gpt_tiny()
+    eng = ContinuousBatchEngine(graph, graph.init(jax.random.key(0)),
+                                num_stages=2, width=3, top_k=3)
+    vec = jnp.zeros(3, jnp.int32)
+    for sample in (False, True):
+        yield f"engine.step.{'sample' if sample else 'greedy'}", \
+            eng._step_fn(sample).lower(
+                eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
+                vec.astype(jnp.float32))
+
+
+def main() -> int:
+    out_dir = sys.argv[1] if len(sys.argv) > 1 else None
+    if out_dir:
+        os.makedirs(out_dir, exist_ok=True)
+    programs = [*ring_programs("gpt_tiny", gpt_tiny(), (1, 4)),
+                *ring_programs("olmoe_tiny", olmoe_tiny(), (1, 2)),
+                # olmoe_tiny's widths at four layers, for four stages
+                *ring_programs("olmoe_4l", olmoe(
+                    4, 64, 4, 16, vocab=211, num_experts=8,
+                    experts_per_tok=2, expert_hidden=32), (4,)),
+                *engine_programs()]
+    for name, lowered in programs:
+        text = lowered.as_text()
+        if out_dir:
+            with open(os.path.join(out_dir, name + ".txt"), "w") as f:
+                f.write(text)
+        print(name, hashlib.sha256(text.encode()).hexdigest(), len(text))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

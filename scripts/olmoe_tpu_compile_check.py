@@ -67,11 +67,13 @@ def main() -> int:
         return arg(a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1)))
 
     w = jax.tree.map(staged, dec._w)
-    cache = arg((1,) + dec._cache_shape, jnp.bfloat16,
-                P(STAGE_AXIS, None, None, None, None, None))
-    caches = {"k": (cache,) * dec.l_max, "v": (cache,) * dec.l_max,
-              "stats": arg((1, len(dec._stat_names)), jnp.int32,
-                           P(STAGE_AXIS, None))}
+    # the format's buffers behind the ring's own stage axis
+    caches = {key: (arg((1,) + buf.shape, buf.dtype,
+                        P(STAGE_AXIS, *(None,) * len(buf.shape))),)
+              * dec.l_max
+              for key, buf in dec.kv_format.buffers(MB).items()}
+    caches["stats"] = arg((1, len(dec._stat_names)), jnp.int32,
+                          P(STAGE_AXIS, None))
     i32, u32, f32 = (arg((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     prompt = arg((1, MB, PLEN), jnp.int32, P(None, None, None))
 

@@ -15,8 +15,10 @@ import jax
 import jax.numpy as jnp
 
 from defer_tpu.models import gpt_stage_cuts, gpt_tiny
+from defer_tpu.models.decoder import split_blocks
 from defer_tpu.models.gpt import CausalTransformerBlock
-from defer_tpu.runtime.decode import PipelinedDecoder, _split_blocks
+from defer_tpu.ops.kv_cache import KVCacheFormat
+from defer_tpu.runtime.decode import PipelinedDecoder
 
 VOCAB = 97
 MAX_LEN = 24
@@ -29,18 +31,18 @@ def incremental_greedy(graph, params, prompt, t_tok, max_len):
     b, plen = prompt.shape
     op0 = nodes[blocks[0]].op
     d = nodes[blocks[0]].out_spec.shape[-1]
-    # head-major KV-head cache contract (kv < num_heads under GQA)
-    shape = (b, op0.kv_heads, max_len + 1, d // op0.num_heads)
-    kc = {nm: jnp.zeros(shape) for nm in blocks}
-    vc = {nm: jnp.zeros(shape) for nm in blocks}
+    # one layer's buffers a block (kv < num_heads under GQA)
+    fmt = KVCacheFormat(op0.kv_heads, d // op0.num_heads, max_len,
+                        jnp.float32)
+    cache = {nm: fmt.layer(fmt.zeros(b, 1), 0) for nm in blocks}
     out = np.zeros((b, t_tok), np.int64)
     out[:, :plen] = prompt
     for p in range(t_tok - 1):
         tok = jnp.asarray(out[:, p], jnp.int32)
         x = nodes["embeddings"].op.embed_at(params["embeddings"], tok, p)
         for nm in blocks:
-            x, kc[nm], vc[nm] = nodes[nm].op.decode(
-                params[nm], x, kc[nm], vc[nm], p)
+            x, cache[nm] = nodes[nm].op.decode(
+                params[nm], x, cache[nm], p, fmt)
         h = nodes["final_ln"].op.apply(params["final_ln"], x)
         logits = nodes["lm_head"].op.apply(params["lm_head"], h)
         nxt = np.asarray(jnp.argmax(logits.astype(jnp.float32), -1))
@@ -211,7 +213,7 @@ def test_gqa_decode_matches_references(prompt):
     dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                            max_len=MAX_LEN)
     assert dec.num_kv_heads == 1 and dec.num_heads == 2
-    assert dec._cache_shape[2] == 1          # cache halved vs MHA
+    assert dec.kv_format.kv_heads == 1       # cache halved vs MHA
     got = dec.generate(prompt, max_new_tokens=8)
     want = incremental_greedy(graph, params, prompt, 5 + 8, MAX_LEN)
     np.testing.assert_array_equal(got, want)
@@ -467,17 +469,17 @@ def reference_beam(graph, params, prompt, max_new, beam, max_len):
     outs = []
     for s in range(b):
         seqs = np.tile(prompt[s], (beam, 1)).astype(np.int64)
-        shape = (beam, op0.kv_heads, max_len + 1, d // op0.num_heads)
-        kc = {nm: jnp.zeros(shape) for nm in blocks}
-        vc = {nm: jnp.zeros(shape) for nm in blocks}
+        fmt = KVCacheFormat(op0.kv_heads, d // op0.num_heads, max_len,
+                            jnp.float32)
+        cache = {nm: fmt.layer(fmt.zeros(beam, 1), 0) for nm in blocks}
         cum = jnp.zeros(beam)
         for p in range(t_tok - 1):
             tok = jnp.asarray(seqs[:, p], jnp.int32)
             x = nodes["embeddings"].op.embed_at(params["embeddings"],
                                                 tok, p)
             for nm in blocks:
-                x, kc[nm], vc[nm] = nodes[nm].op.decode(
-                    params[nm], x, kc[nm], vc[nm], p)
+                x, cache[nm] = nodes[nm].op.decode(
+                    params[nm], x, cache[nm], p, fmt)
             if p < plen - 1:
                 continue  # forced prompt token; no expansion
             h = nodes["final_ln"].op.apply(params["final_ln"], x)
@@ -492,10 +494,9 @@ def reference_beam(graph, params, prompt, max_new, beam, max_len):
             cum = best[0]
             seqs = np.concatenate([seqs[parent],
                                    new_tok[:, None]], axis=1)
-            kc = {nm: jnp.take(kc[nm], jnp.asarray(parent), axis=0)
-                  for nm in blocks}
-            vc = {nm: jnp.take(vc[nm], jnp.asarray(parent), axis=0)
-                  for nm in blocks}
+            cache = {nm: {key: jnp.take(buf, jnp.asarray(parent), axis=0)
+                          for key, buf in cache[nm].items()}
+                     for nm in blocks}
         outs.append(seqs[int(np.argmax(np.asarray(cum)))])
     return np.stack(outs)
 
@@ -621,18 +622,6 @@ def test_beam_validation(model, prompt):
         dec.generate(prompt[:4], 4, temperature=0.5)
 
 
-def test_quantize_row_roundtrip():
-    from defer_tpu.models.gpt import CausalTransformerBlock
-    rng = np.random.default_rng(0)
-    row = jnp.asarray(rng.standard_normal((3, 2, 7, 16)) * 5)
-    q, s = CausalTransformerBlock.quantize_row(row)
-    assert q.dtype == jnp.int8 and s.shape == (3, 2, 7)
-    dq = np.asarray(q, np.float32) * np.asarray(s)[..., None]
-    err = np.abs(dq - np.asarray(row))
-    bound = np.abs(np.asarray(row)).max(-1) / 127.0 * 0.5 + 1e-7
-    assert (err <= bound[..., None] + 1e-5).all()
-
-
 def test_gqa_param_shapes():
     from defer_tpu.models.gpt import CausalTransformerBlock
     from defer_tpu.graph.ir import ShapeSpec
@@ -675,12 +664,12 @@ def test_validation_errors(model, prompt):
 
 
 def test_split_blocks():
-    assert _split_blocks(4, 4) == [[0], [1], [2], [3]]
-    assert _split_blocks(12, 4) == [[0, 1, 2], [3, 4, 5], [6, 7, 8],
-                                    [9, 10, 11]]
-    assert _split_blocks(5, 2) == [[0, 1], [2, 3, 4]]
+    assert split_blocks(4, 4) == [[0], [1], [2], [3]]
+    assert split_blocks(12, 4) == [[0, 1, 2], [3, 4, 5], [6, 7, 8],
+                                   [9, 10, 11]]
+    assert split_blocks(5, 2) == [[0, 1], [2, 3, 4]]
     with pytest.raises(ValueError):
-        _split_blocks(2, 4)
+        split_blocks(2, 4)
 
 
 def test_causal_block_full_vs_decode(model):
@@ -692,11 +681,11 @@ def test_causal_block_full_vs_decode(model):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((2, 6, 32)), jnp.float32)
     full = np.asarray(op.apply(p, x))
-    d = x.shape[-1]
-    kc = jnp.zeros((2, op.num_heads, 8, d // op.num_heads))
-    vc = jnp.zeros((2, op.num_heads, 8, d // op.num_heads))
+    fmt = KVCacheFormat(op.num_heads, x.shape[-1] // op.num_heads, 8,
+                        jnp.float32)
+    cache = fmt.layer(fmt.zeros(2, 1), 0)
     for t in range(6):
-        y, kc, vc = op.decode(p, x[:, t], kc, vc, t)
+        y, cache = op.decode(p, x[:, t], cache, t, fmt)
         np.testing.assert_allclose(np.asarray(y), full[:, t],
                                    rtol=2e-5, atol=2e-5)
 
@@ -764,9 +753,9 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
                            microbatch=8 // num_stages, max_len=MAX_LEN,
                            kv_cache=kv_cache, beam_width=beam)
     body = _decode_scan_body(dec, 2 * num_stages)
-    buffers = {dec._cache_shape}
-    if kv_cache == "int8":
-        buffers.add(dec._scale_shape)
+    buffers = {buf.shape for buf in
+               dec.kv_format.buffers(dec.microbatch).values()}
+    assert len(buffers) == (2 if kv_cache == "int8" else 1)
     stacked = {(dec.l_max,) + sh for sh in buffers}
     rows = groups = 0
     for eqn in _walk(body):
@@ -791,8 +780,11 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
 
 @pytest.mark.parametrize("quant", [False, True], ids=["buffer", "int8"])
 def test_block_decode_is_its_two_halves(model, quant):
-    """``decode()`` == ``decode_qkv`` + the row writes + ``decode_attend``
-    (what the pipelined decoder runs against its own buffers)."""
+    """``decode()`` == ``decode_qkv`` + the format's write and attention
+    + ``decode_finish`` (what both engines run against their own
+    buffers), and the halves name no cache: key and value columns come
+    out, the attention's output goes in.  The format's half of the step
+    is held in tests/test_kv_cache.py."""
     graph, params = model
     op: CausalTransformerBlock = graph.nodes["block_1"].op
     p = params["block_1"]
@@ -800,24 +792,22 @@ def test_block_decode_is_its_two_halves(model, quant):
     b, d, cache_len, pos = 3, 32, 9, 4
     hd = d // op.num_heads
     x = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
-    item = (b, op.kv_heads, cache_len, hd)
-    if quant:
-        kc = jnp.asarray(rng.integers(-127, 128, item), jnp.int8)
-        vc = jnp.asarray(rng.integers(-127, 128, item), jnp.int8)
-        scales = [jnp.asarray(rng.uniform(0.01, 0.1, item[:3]), jnp.float32)
-                  for _ in range(2)]
-    else:
-        kc = jnp.asarray(rng.standard_normal(item), jnp.float32)
-        vc = jnp.asarray(rng.standard_normal(item), jnp.float32)
-        scales = []
-    want = op.decode(p, x, kc, vc, pos, *scales)
+    fmt = KVCacheFormat(op.kv_heads, hd, cache_len, jnp.float32,
+                        quantized=quant)
+    cache = {key: jnp.asarray(
+        rng.integers(-127, 128, s.shape) if s.dtype == jnp.int8
+        else rng.uniform(0.01, 0.1, s.shape), s.dtype)
+        for key, s in fmt.buffers(b).items()}
+    want, want_cache = op.decode(p, x, cache, pos, fmt)
 
-    q, rows = op.decode_qkv(p, x, quant=quant)
-    caches = {"k": kc, "v": vc, **dict(zip(("ks", "vs"), scales))}
-    assert {key: r.shape for key, r in rows.items()} == \
-        {key: c.shape[:2] + (1,) + c.shape[3:] for key, c in caches.items()}
-    got_caches = [c.at[:, :, pos: pos + 1].set(rows[key].astype(c.dtype))
-                  for key, c in caches.items()]
-    got = op.decode_attend(p, x, q, *got_caches[:2], pos, *got_caches[2:])
-    for w, g in zip(want, [got] + got_caches):
-        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+    q, k_new, v_new = op.decode_qkv(p, x)
+    assert q.shape == (b, d)
+    assert k_new.shape == v_new.shape == (b, op.kv_heads * hd)
+    got_cache, item = fmt.write_position(cache, fmt.rows(k_new, v_new), pos)
+    got = op.decode_finish(p, x, fmt.attend(q, item, pos))
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    assert set(want_cache) == set(got_cache) == set(cache)
+    for key, buf in got_cache.items():
+        np.testing.assert_array_equal(np.asarray(want_cache[key]),
+                                      np.asarray(buf))
+        assert (np.asarray(buf) != np.asarray(cache[key])).any()

@@ -25,9 +25,10 @@ from chipbench.agreement import rel_err
 from chipbench.reference import olmoe as ref
 from defer_tpu.graph.ops import MoE, expert_dispatch, route_top_k
 from defer_tpu.models import gpt_tiny, olmoe, olmoe_tiny
-from defer_tpu.models.gpt import DecoderBlock
+from defer_tpu.models.decoder import DecoderBlock, decoder_parts
 from defer_tpu.models.olmoe import OlmoeBlock
 from defer_tpu.obs import REGISTRY
+from defer_tpu.ops.kv_cache import KVCacheFormat
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -178,25 +179,22 @@ def test_switch_moe_is_the_top_1_case_of_the_dispatch():
 
 def _step_logits(graph, params, seqs):
     """Prefill-free decode of ``seqs`` [b, t] through the block's own two
-    halves and ``write_row``, a position a step: logits [b, t, vocab]."""
+    halves around the cache's format (``DecoderBlock.decode``), a
+    position a step: logits [b, t, vocab]."""
     nodes = graph.nodes
     blocks = [nm for nm in graph.topo_order if nm.startswith("block_")]
     op0 = nodes[blocks[0]].op
     b, t = seqs.shape
     d = nodes[blocks[0]].out_spec.shape[-1]
-    item = (b, op0.kv_heads, t, d // op0.num_heads)
-    caches = {nm: {"k": jnp.zeros(item), "v": jnp.zeros(item)}
-              for nm in blocks}
+    fmt = KVCacheFormat(op0.kv_heads, d // op0.num_heads, t, jnp.float32)
+    caches = {nm: fmt.layer(fmt.zeros(b, 1), 0) for nm in blocks}
     out = []
     for p in range(t):
         x = nodes["embeddings"].op.embed_at(
             params["embeddings"], jnp.asarray(seqs[:, p]), p)
         for nm in blocks:
-            op = nodes[nm].op
-            q, rows = op.decode_qkv(params[nm], x, jnp.int32(p))
-            c = caches[nm] = {key: op.write_row(caches[nm][key], row, p)
-                              for key, row in rows.items()}
-            x = op.decode_attend(params[nm], x, q, c["k"], c["v"], p)
+            x, caches[nm] = nodes[nm].op.decode(
+                params[nm], x, caches[nm], jnp.int32(p), fmt)
         h = nodes["final_ln"].op.apply(params["final_ln"], x)
         out.append(nodes["lm_head"].op.apply(params["lm_head"], h))
     return jnp.stack(out, axis=1)
@@ -374,29 +372,92 @@ def test_counters_are_fetched_when_the_caller_stops_a_generation(model, ids):
 # -- the block interface, and who refuses it -----------------------------------------
 
 def test_blocks_of_both_families_meet_the_rings_interface(model):
+    """The interface lives in ``models/decoder.py`` (neither family's
+    file), the cache's half of a step in ``ops/kv_cache.py``: a block
+    has the first and nothing of the second."""
+    import inspect
+    import defer_tpu.models.olmoe as olmoe_module
     graph, _ = model
     for op in (graph.nodes["block_0"].op, gpt_tiny().nodes["block_0"].op):
         assert isinstance(op, DecoderBlock)
-        for name in ("apply_with_kv", "decode_qkv", "decode_attend",
-                     "write_row", "quantize_row", "cache_attention"):
+        for name in ("apply_with_kv", "decode_qkv", "decode_finish",
+                     "decode"):
             assert callable(getattr(op, name))
+        for name in ("write_row", "quantize_row", "cache_rows",
+                     "cache_attention", "decode_attend"):
+            assert not hasattr(op, name)
+    for name in ("rows", "write_position", "write_slots", "write_prefix",
+                 "reparent", "attend"):
+        assert callable(getattr(KVCacheFormat, name))
     assert OlmoeBlock.stage_arg_keys == ("experts",)
     assert gpt_tiny().nodes["block_0"].op.decode_stats == ()
+    assert DecoderBlock.__module__ == "defer_tpu.models.decoder"
+    assert ".gpt" not in inspect.getsource(olmoe_module)    # no sibling
 
 
-def test_the_ring_refuses_a_block_outside_the_interface():
-    from defer_tpu.models import bert_tiny
-    graph = gpt_tiny()
-    nodes = dict(graph.nodes)
-    enc = bert_tiny().nodes["block_0"].op
+def _graph_with(graph, **ops):
+    """``graph`` with the ops of the named nodes replaced (None: the
+    node removed)."""
     import dataclasses
-    nodes["block_1"] = dataclasses.replace(nodes["block_1"], op=enc)
+    nodes = dict(graph.nodes)
+    for nm, op in ops.items():
+        if op is None:
+            del nodes[nm]
+        else:
+            nodes[nm] = dataclasses.replace(nodes[nm], op=op)
     broken = graph.__class__.__new__(graph.__class__)
     broken.__dict__.update(graph.__dict__)
     broken.nodes = nodes
-    with pytest.raises(TypeError, match="block_1.*DecoderBlock"):
-        PipelinedDecoder(broken, graph.init(jax.random.key(0)),
-                         num_stages=1)
+    return broken
+
+
+def _broken_graphs():
+    import dataclasses
+    from defer_tpu.models import bert_tiny
+    graph = gpt_tiny()
+    op = graph.nodes["block_2"].op
+    return {
+        "missing": (_graph_with(graph, final_ln=None), ValueError,
+                    "missing 'final_ln'"),
+        "foreign": (_graph_with(graph,
+                                block_1=bert_tiny().nodes["block_0"].op),
+                    TypeError, "block_1.*not a DecoderBlock"),
+        "mixed": (_graph_with(graph, block_2=dataclasses.replace(
+            op, num_kv_heads=1)), ValueError,
+            "block_2 has heads.*one head geometry"),
+    }
+
+
+@pytest.mark.parametrize("fault", ["missing", "foreign", "mixed"])
+@pytest.mark.parametrize("build", [
+    lambda g, p: PipelinedDecoder(g, p, num_stages=2),
+    lambda g, p: ContinuousBatchEngine(g, p, num_stages=2, width=2),
+    lambda g, p: decoder_parts(g, 2),
+], ids=["ring", "engine", "contract"])
+def test_both_engines_refuse_a_graph_outside_the_contract(build, fault):
+    """One function checks a graph for both constructors: a missing
+    node, a foreign block and mixed head geometry are refused by each
+    in the same words, before anything is placed on a device."""
+    broken, error, words = _broken_graphs()[fault]
+    params = gpt_tiny().init(jax.random.key(0))
+    with pytest.raises(error, match=words):
+        build(broken, params)
+
+
+def test_the_contract_hands_back_a_graphs_parts(model):
+    graph, _ = model
+    parts = decoder_parts(graph, 2, max_len=16)
+    assert parts.block_names == ("block_0", "block_1")
+    assert parts.stage_blocks == [["block_0"], ["block_1"]]
+    assert (parts.d_model, parts.num_heads, parts.kv_heads, parts.head_dim,
+            parts.vocab, parts.max_len) == (64, 4, 4, 16, VOCAB, 16)
+    assert parts.decode_stats == OlmoeBlock.decode_stats
+    assert parts.embed_op is graph.nodes["embeddings"].op
+    assert decoder_parts(graph, 1).max_len == SEQ
+    with pytest.raises(ValueError, match="max_len"):
+        decoder_parts(graph, 1, max_len=SEQ + 1)
+    with pytest.raises(ValueError, match="cannot fill"):
+        decoder_parts(graph, 3)
 
 
 def test_the_serving_engine_refuses_the_block_by_name(model):
@@ -424,9 +485,10 @@ def _sha(text: str) -> str:
 
 @pytest.mark.parametrize("num_stages", [1, 2])
 def test_gpt_tiny_decodes_as_on_the_parent(num_stages):
-    """``decode_qkv`` takes a position now and ``decode_attend`` a
-    ``sow``: the GPT family ignores both, its ring lowers to the parent's
-    text and gives the parent's tokens, bit for bit."""
+    """``decode_qkv`` takes a position and ``decode_finish`` a ``sow``,
+    and the cache's half of a step lives in ``ops/kv_cache.py``: the
+    GPT family ignores the first two, and its ring still lowers to the
+    text recorded at d5480a9 and gives its tokens, bit for bit."""
     graph = gpt_tiny(seq_len=32)
     params = graph.init(jax.random.key(0))
     n, mb = num_stages, 8 // num_stages

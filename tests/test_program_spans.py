@@ -411,7 +411,7 @@ def test_the_engine_step_program_keeps_its_name(door):
     eng = door.engine
     w = eng.width
     lowered = eng._step_fn(False).lower(
-        eng.params, eng._init_caches(), jnp.zeros(w, jnp.int32),
+        eng.params, eng._caches, jnp.zeros(w, jnp.int32),
         jnp.zeros(w, jnp.int32), jnp.zeros(w, jnp.uint32),
         jnp.zeros(w, jnp.float32))
     assert _module_name(lowered) == "jit_step"

@@ -295,13 +295,15 @@ def _oracle_run(g, params, tokens, max_len, new=0):
     """The oracle: ``CausalTransformerBlock.decode`` (the composition
     over one cache item) looped over ``tokens`` fed one a position,
     then over its own greedy ids until ``new`` are generated, against
-    one [1, kv, max_len, hd] item a block.  Returns all the tokens, the
+    one sequence's buffers a block.  Returns all the tokens, the
     greedy next id after each fed position, and the final caches."""
+    from defer_tpu.ops.kv_cache import KVCacheFormat
     blocks = [nm for nm in g.topo_order if nm.startswith("block_")]
     op0 = g.nodes[blocks[0]].op
     d = params["embeddings"]["wte"].shape[1]
-    item = (1, op0.kv_heads, max_len, d // op0.num_heads)
-    caches = {nm: (jnp.zeros(item), jnp.zeros(item)) for nm in blocks}
+    fmt = KVCacheFormat(op0.kv_heads, d // op0.num_heads, max_len,
+                        jnp.float32)
+    caches = {nm: fmt.layer(fmt.zeros(1, 1), 0) for nm in blocks}
 
     @jax.jit
     def one(caches, tok, pos):
@@ -309,8 +311,8 @@ def _oracle_run(g, params, tokens, max_len, new=0):
              + params["embeddings"]["wpe"][pos])[None]
         out = {}
         for nm in blocks:
-            x, k, v = g.nodes[nm].op.decode(params[nm], x, *caches[nm], pos)
-            out[nm] = (k, v)
+            x, out[nm] = g.nodes[nm].op.decode(params[nm], x, caches[nm],
+                                               pos, fmt)
         h = g.nodes["final_ln"].op.apply(params["final_ln"], x)
         logits = g.nodes["lm_head"].op.apply(params["lm_head"], h)
         return jnp.argmax(logits[0]), out
@@ -394,28 +396,6 @@ def test_engine_recycled_slot_ignores_previous_tenants_rows(gpt_setup,
     assert np.abs(stale).min(axis=(0, 2)).all()
 
 
-@pytest.mark.parametrize("shape,positions", [
-    ((3, 2, 16, 8), [0, 15, 7]),            # one window holds the item
-    ((4, 2, 192, 8), [0, 127, 128, 191]),   # 1.5 windows: the cell's L
-    ((2, 3, 300, 16), [255, 299]),
-])
-def test_write_kv_rows_touches_one_position_a_sequence(shape, positions):
-    """The engine's row-writer: sequence i's row lands at pos[i], at
-    window edges and in a partial last window too, and every other
-    element keeps its bits."""
-    from defer_tpu.ops.kv_rows import write_kv_rows
-    rng = np.random.default_rng(5)
-    w, kv, _, hd = shape
-    cache = rng.normal(size=shape).astype(np.float32)
-    rows = rng.normal(size=(w, kv, 1, hd)).astype(np.float32)
-    got = jax.jit(write_kv_rows)(cache, rows,
-                                 jnp.asarray(positions, jnp.int32))
-    want = cache.copy()
-    for i, p in enumerate(positions):
-        want[i, :, p] = rows[i, :, 0]
-    np.testing.assert_array_equal(np.asarray(got), want)
-
-
 def _walk_eqns(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn
@@ -432,10 +412,10 @@ def test_engine_step_holds_a_buffer_a_layer_and_writes_rows_in_place(
     g, params = gpt_setup
     w, n_layer = 3, 4
     eng = ContinuousBatchEngine(g, params, num_stages=2, width=w)
-    item = (w, eng.kv_heads, eng.max_len, eng.head_dim)
-    assert set(eng._caches) == {"k", "v"}
-    for side in ("k", "v"):
-        assert [b.shape for b in eng._caches[side]] == [item] * n_layer
+    buffers = eng.kv_format.buffers(w)
+    assert set(eng._caches) == set(buffers) == {"k", "v"}
+    for side, buf in buffers.items():
+        assert [b.shape for b in eng._caches[side]] == [buf.shape] * n_layer
     vec = jnp.zeros(w, jnp.int32)
     args = (eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
             vec.astype(jnp.float32))
@@ -449,7 +429,7 @@ def test_engine_step_holds_a_buffer_a_layer_and_writes_rows_in_place(
                 assert shape[:2] != (n_layer, w) or len(shape) != 5, \
                     f"{eqn.primitive.name} makes a stacked cache {shape}"
         assert not any("scatter" in p for p in prims), set(prims)
-        # one aliased row-writer call a buffer (ops/kv_rows.py)
+        # one aliased row-writer call a buffer (ops/kv_cache.py)
         assert prims.count("pallas_call") == 2 * n_layer
         # the CPU does not donate, so read the lowering: every cache
         # buffer argument names the output it aliases
@@ -477,11 +457,11 @@ def test_engine_writes_and_reads_the_last_position(gpt_setup):
                            zeros.astype(jnp.uint32),
                            zeros.astype(jnp.float32))
         assert int(ids[0]) == want[pos], pos
-    for side, i in (("k", 0), ("v", 1)):
+    for side in ("k", "v"):
         got = np.asarray(caches[side][0])[0]
         # one row against two: the products round differently
         np.testing.assert_allclose(
-            got, np.asarray(oracle_caches["block_0"][i])[0], atol=1e-5)
+            got, np.asarray(oracle_caches["block_0"][side])[0], atol=1e-5)
         assert np.abs(got[:, last]).max() > 0
 
 
