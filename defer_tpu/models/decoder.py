@@ -92,8 +92,8 @@ class DecoderBlock:
         call the halves and write into their own buffers.
         """
         q, k_new, v_new = self.decode_qkv(params, x, pos)
-        cache, item = fmt.write_position(cache, fmt.rows(k_new, v_new), pos)
-        return self.decode_finish(params, x, fmt.attend(q, item, pos)), cache
+        cache = fmt.write_position(cache, fmt.rows(k_new, v_new), pos)
+        return self.decode_finish(params, x, fmt.attend(q, cache, pos)), cache
 
 
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:

@@ -40,13 +40,23 @@ cheapest (docs/DECODE_CLIFF.md):
   aliased Pallas call :func:`write_kv_rows`;
 * :meth:`KVCacheFormat.write_prefix` — a whole prompt for one group:
   one relayout to head-major a prompt, then one bulk write.
+
+**The attention**, :meth:`KVCacheFormat.attend`: one query a sequence
+over a layer's buffers *where they lie* — the Pallas kernel
+:func:`kv_attend`, which takes the group as an index and reads the
+position blocks that hold live rows and no other.  Only the int8 rows
+stay on the plain einsum (:func:`attend_einsum`), which is also the
+oracle the tests hold the kernel to.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -54,40 +64,50 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: positions a window of the row-writer holds: one lane row
-_WINDOW = 128
+#: what a lane row holds: the positions of a window of the row-writer,
+#: and the step by which a block of the attention grows
+_LANES = 128
+#: the most one of the attention's blocks (keys or values) may hold
+_BLOCK_BYTES = 1 << 20
 
 
-def _write_kernel(pos_ref, rows_ref, win_ref, out_ref):
-    # rows_ref [1, hd, kv]; win_ref / out_ref [1, kv, hd, window]
-    kv, hd, window = win_ref.shape[1:]
+def _write_kernel(group_ref, pos_ref, rows_ref, win_ref, out_ref):
+    # rows_ref [1, hd, kv] f32; win_ref / out_ref [1, 1, kv, hd, window]
+    del group_ref                       # the index maps read it
+    kv, hd, window = win_ref.shape[2:]
     at = pos_ref[pl.program_id(0)] % window
     hit = lax.broadcasted_iota(jnp.int32, (hd, window), 1) == at
     for k in range(kv):
-        out_ref[0, k] = jnp.where(hit, rows_ref[0, :, k:k + 1], win_ref[0, k])
+        out_ref[0, 0, k] = jnp.where(
+            hit, rows_ref[0, :, k:k + 1],
+            win_ref[0, 0, k].astype(jnp.float32)).astype(out_ref.dtype)
 
 
 @jax.jit
-def write_kv_rows(cache, rows, pos):
+def write_kv_rows(cache, rows, pos, group=None):
     """``cache`` [b, kv, L, hd] with ``rows[i]`` ([b, kv, 1, hd], cast
     to the cache's type) written at position ``pos[i]`` of sequence
     ``i``; nothing else of the buffer is touched.  ``pos`` [b] int32
-    in ``[0, L)``.  The result aliases ``cache``: donate it.
+    in ``[0, L)``.  With ``group`` ([1] int32) the cache is a ring's
+    ``[groups, b, kv, L, hd]`` and the rows are that group's.  The
+    result aliases ``cache``: donate it.
 
-    XLA:TPU keeps such an f32 array with the positions on the lanes
-    when ``hd`` is under a lane row (128): ``hd`` 64 would otherwise be
+    XLA:TPU keeps such an array with the positions on the lanes when
+    ``hd`` is under a lane row (128): ``hd`` 64 would otherwise be
     padded to twice its size.  What the obvious forms of the write cost
-    in that layout, at gpt2-xl, 16 sequences, 192 positions
-    (docs/DECODE_CLIFF.md, "The engine"):
+    in that layout (docs/DECODE_CLIFF.md, "The engine" and "The
+    attention"):
 
     * ``jax.vmap`` of a ``dynamic_update_slice`` over the positions is
       a batched scatter: the compiler copies the whole buffer into the
       scatter's layout and back.
-    * one scalar-indexed ``dynamic_update_slice`` a sequence touches
-      one lane of every tile, which XLA runs as a read-modify-write of
-      the sequence's item: 5.6 us a row, 8.4 ms a step for 1,536 rows.
+    * a scalar-indexed ``dynamic_update_slice`` touches one lane of
+      every tile, which XLA runs as a read-modify-write of the whole
+      item: 5.6 us a row in the engine (8.4 ms a step for 1,536 rows),
+      75 us a buffer in the ring (3.6 ms a step at gpt2-xl, 24 layers,
+      8 sequences).
 
-    Here the buffer is viewed as ``[b, kv, hd, L]`` — the same bytes,
+    Here the buffer is viewed as ``[.., kv, hd, L]`` — the same bytes,
     so both ``swapaxes`` compile to bitcasts — and aliased to the
     output.  Each grid step moves the one 128-position window that
     holds its sequence's position through VMEM and replaces one lane of
@@ -97,30 +117,36 @@ def write_kv_rows(cache, rows, pos):
     Jitted so that a step program that calls it once a buffer traces
     and lowers the kernel once: 96 separate ``pallas_call`` sites added
     6 s to the serving cell's set-up."""
-    b, kv, cache_len, hd = cache.shape
-    window = min(_WINDOW, cache_len)
+    if group is None:
+        return write_kv_rows(cache.reshape((1,) + cache.shape), rows, pos,
+                             jnp.zeros(1, jnp.int32)).reshape(cache.shape)
+    groups, b, kv, cache_len, hd = cache.shape
+    window = min(_LANES, cache_len)
+    pos = jnp.clip(pos.astype(jnp.int32), 0, cache_len - 1)
+    group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
 
-    def at_window(i, pos_ref):
-        return (i, 0, 0, pos_ref[i] // window)
+    def at_window(i, group_ref, pos_ref):
+        return (group_ref[0], i, 0, 0, pos_ref[i] // window)
 
     # the rows go in as [b, hd, kv]: a head's row is then a column the
     # kernel spreads over the lanes, and the array is 0.4 MB where
     # [b, kv, hd, 1] would be padded to 128 lanes, 13 MB
-    rows = jnp.swapaxes(rows[:, :, 0, :], 1, 2).astype(cache.dtype)
+    rows = jnp.swapaxes(rows[:, :, 0, :], 1, 2).astype(jnp.float32)
     out = pl.pallas_call(
         _write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b,),
+            num_scalar_prefetch=2, grid=(b,),
             in_specs=[pl.BlockSpec((1, hd, kv),
-                                   lambda i, pos_ref: (i, 0, 0)),
-                      pl.BlockSpec((1, kv, hd, window), at_window)],
-            out_specs=pl.BlockSpec((1, kv, hd, window), at_window)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, hd, cache_len), cache.dtype),
-        input_output_aliases={2: 0},
+                                   lambda i, group_ref, pos_ref: (i, 0, 0)),
+                      pl.BlockSpec((1, 1, kv, hd, window), at_window)],
+            out_specs=pl.BlockSpec((1, 1, kv, hd, window), at_window)),
+        out_shape=jax.ShapeDtypeStruct((groups, b, kv, hd, cache_len),
+                                       cache.dtype),
+        input_output_aliases={3: 0},
         interpret=jax.default_backend() != "tpu",
         name="kv_write_rows",
-    )(pos.astype(jnp.int32), rows, jnp.swapaxes(cache, 2, 3))
-    return jnp.swapaxes(out, 2, 3)
+    )(group, pos, rows, jnp.swapaxes(cache, 3, 4))
+    return jnp.swapaxes(out, 3, 4)
 
 
 def quantize_rows(rows):
@@ -137,6 +163,231 @@ def _group_slice(buf, g):
     """Group ``g``'s part ``[1, ...]`` of a ``[groups + 1, ...]`` buffer."""
     return lax.dynamic_slice(buf, (g,) + (0,) * (buf.ndim - 1),
                              (1,) + buf.shape[1:])
+
+
+def _on_lanes(hd: int) -> bool:
+    """Whether XLA:TPU holds a ``[.., kv, L, hd]`` float buffer with the
+    positions on the lanes: it does when ``hd`` is under a lane row
+    (``hd`` 64 would otherwise be padded to twice its size); from 128
+    on, a position's ``kv x hd`` rows lie together."""
+    return hd < _LANES
+
+
+@functools.lru_cache(maxsize=None)
+def attend_blocks(kv: int, hd: int, length: int, itemsize: int):
+    """``(kv heads, positions)`` of one block of :func:`kv_attend` over
+    buffers of ``length`` positions: as many heads and then as many
+    lane rows of positions as :data:`_BLOCK_BYTES` hold (a block under
+    ~0.5 MB leaves the chip waiting on each grid step, a larger one
+    reads further past the last live position)."""
+    tl = min(_LANES, length)
+    kvb = max(d for d in range(1, kv + 1)
+              if kv % d == 0 and (d == 1 or d * hd * tl * itemsize
+                                  <= _BLOCK_BYTES))
+    while kvb == kv and tl + _LANES <= length \
+            and kv * hd * (tl + _LANES) * itemsize <= _BLOCK_BYTES:
+        tl += _LANES
+    return kvb, tl
+
+
+def _attend_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                   qs_ref, m_ref, l_ref, acc_ref, *, tl, on_lanes, scale):
+    """One position block of one sequence's KV heads: online softmax on
+    the vector unit, in f32.  A block is ``[kvb, hd, tl]`` with the
+    positions on the lanes (``on_lanes``) or ``[tl, kvb, hd]``; one body
+    serves both, told which axis holds the positions (``pa``) and which
+    the head's dimension (``da``).  With one query a KV head (or a few:
+    the group) there is no matrix for the matrix unit: a score is a
+    multiply and a reduce over ``da``, the output a multiply and a
+    reduce over ``pa``.
+
+    q_ref / o_ref ``[1, kvb, g, hd]`` (``[1, g, kvb, hd]`` off the
+    lanes); qs_ref / acc_ref ``[g, kvb, hd, 1]`` (``[g, 1, kvb, hd]``):
+    a query in the shape that multiplies a block; m_ref / l_ref the
+    same with ``hd`` reduced away."""
+    del group_ref                       # the index maps read it
+    pa, da = (2, 1) if on_lanes else (0, 2)
+    g, hd = qs_ref.shape[0], qs_ref.shape[2 if on_lanes else 3]
+    t = pl.program_id(2)
+    pos = pos_ref[pl.program_id(0)]
+    if on_lanes:
+        # a head's row [1, hd] and its column [hd, 1] are each the
+        # other spread over this diagonal and reduced
+        eye = (lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+               == lax.broadcasted_iota(jnp.int32, (hd, hd), 1))[None]
+
+    @pl.when(t == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for j in range(g):
+            if on_lanes:
+                row = q_ref[0, :, j:j + 1, :].astype(jnp.float32) * scale
+                qs_ref[j] = jnp.sum(jnp.where(eye, row, 0.0), axis=2,
+                                    keepdims=True)
+            else:
+                qs_ref[j, 0] = q_ref[0, j].astype(jnp.float32) * scale
+
+    def accumulate(ragged):
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+
+        def live(shape):
+            return t * tl + lax.broadcasted_iota(jnp.int32, shape, pa) <= pos
+
+        if ragged:
+            # a dead position's row may hold anything (the scratch row,
+            # a block's overhang): 0 x NaN must not reach a sum
+            v = jnp.where(live(v.shape), v, 0.0)
+        for j in range(g):
+            s = jnp.sum(k * qs_ref[j], axis=da, keepdims=True)
+            if ragged:
+                s = jnp.where(live(s.shape), s, -jnp.inf)
+            # block 0 always holds a live position: from the first
+            # block on the running max is finite
+            m_new = jnp.maximum(m_ref[j], jnp.max(s, axis=pa, keepdims=True))
+            alpha = jnp.exp(m_ref[j] - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[j] = l_ref[j] * alpha + jnp.sum(p, axis=pa, keepdims=True)
+            acc_ref[j] = acc_ref[j] * alpha + jnp.sum(
+                p * v, axis=pa, keepdims=True)
+            m_ref[j] = m_new
+
+    # a block wholly past ``pos`` is not computed (nor fetched: its
+    # index map names a block that is wanted next); only the block that
+    # holds ``pos`` pays for masks
+    pl.when((t + 1) * tl - 1 <= pos)(lambda: accumulate(False))
+    pl.when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - 1 > pos))(
+        lambda: accumulate(True))
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finish():
+        for j in range(g):
+            out = acc_ref[j] / l_ref[j]
+            if on_lanes:
+                o_ref[0, :, j:j + 1, :] = jnp.sum(
+                    jnp.where(eye, out, 0.0), axis=1,
+                    keepdims=True).astype(o_ref.dtype)
+            else:
+                o_ref[0, j] = out[0].astype(o_ref.dtype)
+
+
+@jax.jit
+def kv_attend(q, k_buf, v_buf, pos, group):
+    """One query a sequence over its live rows: ``q`` [b, heads * hd]
+    against ``k_buf`` / ``v_buf`` [groups, b, kv, L, hd] as they are
+    stored, sequence ``i`` of group ``group`` [1] over its positions
+    ``<= pos[i]`` ([b], int32, in ``[0, L)``).  Exact softmax in f32,
+    online over position blocks as ``ops/flash_attention.py`` does;
+    returns [b, heads * hd] in ``q``'s type.
+
+    What the two einsums this replaces cost on the chip
+    (docs/DECODE_CLIFF.md, "The attention"): XLA:TPU lowers them to an
+    f32 multiply-and-reduce over *every* position of an item it first
+    slices out of the buffer, in a layout of its own that pads the rows
+    and that the compiled loop converts every buffer to and from, every
+    dispatch.  Here the group is an index, the grid runs (sequence,
+    block of KV heads, position block), a block past ``pos[i]`` is
+    neither fetched nor computed, and — a Pallas call's operands having
+    one fixed layout — the buffer a loop carries is the buffer its
+    caller holds.
+
+    Two block shapes, by ``hd`` alone, each a view of the bytes as
+    XLA:TPU holds such a buffer (it tiles the two dimensions that pad
+    least).  Under a lane row (gpt2's 64) that is the positions on the
+    lanes (:func:`write_kv_rows`): ``[.., kv, hd, L]``, a block ``[kvb,
+    hd, positions]``.  From 128 on (OLMoE) it is a position's ``kv x
+    hd`` rows together: ``[.., L, kv, hd]``, a block ``[positions, kvb,
+    hd]``.  Where the bytes lie otherwise, the view is a transpose and
+    the compile checks (``scripts/*_tpu_compile_check.py``) say so.
+    The query group of a KV head rides along in the block.  Jitted for
+    the reason :func:`write_kv_rows` is."""
+    b, d = q.shape
+    groups, _, kv, length, hd = k_buf.shape
+    g = d // (kv * hd)
+    on_lanes = _on_lanes(hd)
+    kvb, tl = attend_blocks(kv, hd, length, k_buf.dtype.itemsize)
+    pos = jnp.clip(pos.astype(jnp.int32), 0, length - 1)
+    group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
+    q = q.reshape(b, kv, g, hd)
+
+    def head_block(i, h, t, group_ref, pos_ref):
+        return (i, h, 0, 0) if on_lanes else (i, 0, h, 0)
+
+    def cache_block(i, h, t, group_ref, pos_ref):
+        # past the last live block, name the first block of the grid's
+        # next (sequence, heads): it is fetched while the last live one
+        # is computed, and being named again until its turn comes it is
+        # fetched once.  Naming the last live block again would leave
+        # the next sequence's first fetch with nothing to hide behind.
+        dead = t > pos_ref[i] // tl
+        wrap = h + 1 == kv // kvb
+        more = jnp.logical_and(dead, jnp.logical_or(i + 1 < b,
+                                                    jnp.logical_not(wrap)))
+        i = jnp.where(jnp.logical_and(more, wrap), i + 1, i)
+        h = jnp.where(more, jnp.where(wrap, 0, h + 1), h)
+        t = jnp.where(more, 0, jnp.minimum(t, pos_ref[i] // tl))
+        return (group_ref[0], i, h, 0, t) if on_lanes \
+            else (group_ref[0], i, t, h, 0)
+
+    if on_lanes:
+        k_buf, v_buf = jnp.swapaxes(k_buf, 3, 4), jnp.swapaxes(v_buf, 3, 4)
+        heads, block = (1, kvb, g, hd), (1, 1, kvb, hd, tl)
+        state, reduced = (g, kvb, hd, 1), (g, kvb, 1, 1)
+    else:
+        q = jnp.swapaxes(q, 1, 2)
+        k_buf, v_buf = jnp.swapaxes(k_buf, 2, 3), jnp.swapaxes(v_buf, 2, 3)
+        heads, block = (1, g, kvb, hd), (1, 1, tl, kvb, hd)
+        state, reduced = (g, 1, kvb, hd), (g, 1, kvb, 1)
+    out = pl.pallas_call(
+        functools.partial(_attend_kernel, tl=tl, on_lanes=on_lanes,
+                          scale=1.0 / math.sqrt(hd)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, kv // kvb, pl.cdiv(length, tl)),
+            in_specs=[pl.BlockSpec(heads, head_block),
+                      pl.BlockSpec(block, cache_block),
+                      pl.BlockSpec(block, cache_block)],
+            out_specs=pl.BlockSpec(heads, head_block),
+            scratch_shapes=[pltpu.VMEM(state, jnp.float32),
+                            pltpu.VMEM(reduced, jnp.float32),
+                            pltpu.VMEM(reduced, jnp.float32),
+                            pltpu.VMEM(state, jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=jax.default_backend() != "tpu",
+        name="kv_attend",
+    )(group, pos, q, k_buf, v_buf)
+    if not on_lanes:
+        out = jnp.swapaxes(out, 1, 2)
+    return out.reshape(b, d)
+
+
+def attend_einsum(q, item: dict, pos):
+    """:meth:`KVCacheFormat.attend` as two plain einsums over one item
+    (buffers ``[b, kv, L, hd]``, no group axis): what the int8 rows run
+    on — their scales fold into the dots — and the oracle the tests
+    hold :func:`kv_attend` to.  ``pos`` a scalar or one a sequence."""
+    k_cache, v_cache = item["k"], item["v"]
+    k_scale, v_scale = item.get("ks"), item.get("vs")
+    b, d = q.shape
+    kv, cache_len, hd = k_cache.shape[1:]
+    quant = k_scale is not None
+
+    qh = q.reshape(b, kv, d // (kv * hd), hd)
+    kh = k_cache.astype(q.dtype)
+    vh = v_cache.astype(q.dtype)
+    att = jnp.einsum("bkgd,bkld->bkgl", qh, kh) / math.sqrt(hd)
+    if quant:
+        att = att * k_scale[:, :, None, :].astype(att.dtype)
+    live = jnp.arange(cache_len)[None, None, None, :] \
+        <= jnp.reshape(pos, (-1, 1, 1, 1))
+    att = jnp.where(live, att, jnp.asarray(-jnp.inf, att.dtype))
+    att = jax.nn.softmax(att, axis=-1)
+    if quant:
+        att = att * v_scale[:, :, None, :].astype(att.dtype)
+    return jnp.einsum("bkgl,bkld->bkgd", att, vh).reshape(b, d)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,22 +474,31 @@ class KVCacheFormat:
     def write_position(self, layer: dict, rows: dict, pos, group=None):
         """``rows`` written in place at the one position ``pos`` of
         every sequence (of group ``group``, where the format has
-        groups).  Returns the layer and the read-only item
-        :meth:`attend` reads: nothing the size of an item is written
-        back."""
+        groups).  Returns the layer: :meth:`attend` reads it where it
+        lies, so nothing the size of an item is cut out or written
+        back.  One ``lax.dynamic_update_slice`` a buffer where a
+        position's rows lie together; where the positions lie on the
+        lanes (float rows under a lane row) that would rewrite the
+        group's whole item, and the row-writer does it."""
+        if not self.quantized and _on_lanes(self.head_dim):
+            b = rows["k"].shape[0]
+            pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+            if group is not None:
+                group = jnp.asarray(group, jnp.int32).reshape(1)
+            return {key: write_kv_rows(layer[key], rows[key], pos, group)
+                    for key in layer}
         lead = () if group is None else (group,)
-        out, item = dict(layer), {}
+        out = dict(layer)
         for key, row in rows.items():
             buf = layer[key]
             row = lax.expand_dims(row, range(len(lead))).astype(buf.dtype)
             at = lead + (0, 0, pos) + (0,) * (row.ndim - len(lead) - 3)
-            out[key] = buf = lax.dynamic_update_slice(buf, row, at)
-            item[key] = buf if group is None else _group_slice(buf, group)[0]
-        return out, item
+            out[key] = lax.dynamic_update_slice(buf, row, at)
+        return out
 
     def write_slots(self, layer: dict, rows: dict, pos) -> dict:
         """``rows`` written in place, sequence ``i``'s at its own
-        position ``pos[i]``: the layer, which is its own item."""
+        position ``pos[i]``: the layer."""
         if self.quantized or self.groups is not None:
             raise NotImplementedError(
                 "a position a sequence is written into unquantized "
@@ -280,33 +540,34 @@ class KVCacheFormat:
 
     # -- attention -------------------------------------------------------
 
-    @staticmethod
-    def live_to(pos):
-        """Per-sequence positions ``pos`` [b] as :meth:`attend` takes
-        them: computed once a step, not once a layer."""
-        return pos[:, None, None, None]
+    def attend(self, q, layer: dict, pos, group=None):
+        """One query a sequence over ``layer``'s buffers (group
+        ``group``'s sequences, where the format has groups): ``q`` [b,
+        heads * head_dim], positions ``<= pos`` live — ``pos`` a scalar
+        (every sequence at one position) or [b], one a sequence;
+        returns [b, heads * head_dim].  :func:`kv_attend`, or the
+        einsums for int8 rows."""
+        if self.quantized:
+            item = layer if group is None else {
+                key: _group_slice(buf, group)[0]
+                for key, buf in layer.items()}
+            return attend_einsum(q, item, pos)
+        k_buf, v_buf = layer["k"], layer["v"]
+        if group is None:
+            k_buf, v_buf, group = k_buf[None], v_buf[None], 0
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), q.shape[:1])
+        return kv_attend(q, k_buf, v_buf, pos,
+                         jnp.asarray(group, jnp.int32).reshape(1))
 
-    @staticmethod
-    def attend(q, item: dict, pos):
-        """One query a sequence over its item: ``q`` [b, heads *
-        head_dim], positions ``<= pos`` live — ``pos`` a scalar (every
-        sequence at one position) or :meth:`live_to` of each sequence's
-        own; returns [b, heads * head_dim]."""
-        k_cache, v_cache = item["k"], item["v"]
-        k_scale, v_scale = item.get("ks"), item.get("vs")
-        b, d = q.shape
-        kv, cache_len, hd = k_cache.shape[1:]
-        quant = k_scale is not None
-
-        qh = q.reshape(b, kv, d // (kv * hd), hd)
-        kh = k_cache.astype(q.dtype)
-        vh = v_cache.astype(q.dtype)
-        att = jnp.einsum("bkgd,bkld->bkgl", qh, kh) / math.sqrt(hd)
-        if quant:
-            att = att * k_scale[:, :, None, :].astype(att.dtype)
-        live = jnp.arange(cache_len)[None, None, None, :] <= pos
-        att = jnp.where(live, att, jnp.asarray(-jnp.inf, att.dtype))
-        att = jax.nn.softmax(att, axis=-1)
-        if quant:
-            att = att * v_scale[:, :, None, :].astype(att.dtype)
-        return jnp.einsum("bkgl,bkld->bkgd", att, vh).reshape(b, d)
+    def live_block_share(self, pos) -> tuple[int, int]:
+        """Position blocks :meth:`attend` reads for sequences at the
+        positions ``pos`` (host integers, any shape), and the blocks
+        their items hold: reckoned from the block size alone, no device
+        asked."""
+        length = self.positions + (self.groups is not None)
+        _, tl = attend_blocks(self.kv_heads, self.head_dim, length,
+                              jnp.dtype(self.dtype).itemsize)
+        pos = np.clip(np.asarray(pos), 0, length - 1)
+        held = pos.size * -(-length // tl)
+        # the einsums of the int8 rows read every position
+        return (held if self.quantized else int((pos // tl + 1).sum())), held

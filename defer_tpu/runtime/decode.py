@@ -18,7 +18,7 @@ TPU-native design, one SPMD program:
     held and touched only through the cache's format
     (``ops/kv_cache.py``, which describes the layout): a step writes one
     row a block in place, every sequence of the group at one position,
-    and attends over a read-only item of the group; warmup bubbles
+    and attends over the group's live rows where they lie; warmup bubbles
     write the format's scratch row and prefill bubbles its scratch
     group, so no masked read-modify-write of the cache is ever needed.
   * The ring carry is one ``[mb, d]`` float32 buffer per device: stage
@@ -205,6 +205,9 @@ class PipelinedDecoder:
         self._init_fn = None  # cached jitted state initializer
         #: the newest device-side sums of the blocks' sown statistics
         self._live_stats = None
+        #: position blocks the attention read / the blocks the items it
+        #: read from hold, over this decoder's dispatches
+        self._attend_blocks = [0, 0]
 
     # ------------------------------------------------------------------
 
@@ -386,17 +389,18 @@ class PipelinedDecoder:
             for l, (nm, op) in enumerate(zip(self.stage_blocks[s],
                                              block_ops)):
                 # write the new rows in place (one position of one group
-                # of one block), then attend over a read-only slice of
-                # the group's item: nothing the size of an item is
-                # written back
+                # of one block), then attend over the group's live rows
+                # where they lie: nothing the size of an item is cut
+                # out of a buffer or written back
                 q, k_new, v_new = op.decode_qkv(p[nm], x, safe_pos)
-                layer, item = fmt.write_position(
+                layer = fmt.write_position(
                     fmt.layer(caches, l), fmt.rows(k_new, v_new),
                     write_pos, group=g)
                 caches = fmt.with_layer(caches, l, layer)
                 sown = {} if stats else None
                 x = op.decode_finish(
-                    p[nm], x, fmt.attend(q, item, write_pos), sow=sown)
+                    p[nm], x, fmt.attend(q, layer, write_pos, group=g),
+                    sow=sown)
                 if stats:
                     step = jnp.stack([sown[k] for k in stats])
                     caches = dict(caches, stats=caches["stats"] + jnp.where(
@@ -670,6 +674,25 @@ class PipelinedDecoder:
             else max(n, n * int(token_chunk))
         return num_steps, chunk_steps
 
+    def _count_attend_blocks(self, t0: int, chunk_steps: int,
+                             num_steps: int, start: int) -> None:
+        """``decode.attend.live_block_share`` for one dispatch: where
+        each stage's group stands at each of its steps is the schedule's
+        own arithmetic (``device_decode``), so the host reckons what
+        the kernel will read before the device has run any of it."""
+        n = self.num_stages
+        t = t0 + np.arange(chunk_steps)[:, None]
+        rel = t - np.arange(n)[None, :]
+        pos = start + rel // n
+        real = (rel >= 0) & (t < num_steps) & (pos >= 0) \
+            & (pos < self.max_len)
+        read, held = self.kv_format.live_block_share(
+            np.where(real, pos, self.kv_format.scratch_position))
+        self._attend_blocks[0] += read
+        self._attend_blocks[1] += held
+        REGISTRY.gauge("decode.attend.live_block_share").set(
+            self._attend_blocks[0] / self._attend_blocks[1])
+
     def _get_decode_fn(self, chunk_steps: int, sample: bool,
                        top_k: int | None):
         key = (chunk_steps, sample, top_k)
@@ -893,6 +916,8 @@ class PipelinedDecoder:
                                     jnp.int32(steps_run),
                                     jnp.int32(num_steps), seed_s, temp_s,
                                     fi_dev, fp_s, start_s, a, caches)
+            self._count_attend_blocks(steps_run, chunk_steps, num_steps,
+                                      start)
             self._live_stats = caches.get("stats")
             if not incremental:
                 chunks.append(ids)
@@ -991,6 +1016,7 @@ class PipelinedDecoder:
                                 jnp.int32(steps_run), jnp.int32(num_steps),
                                 jnp.uint32(0), jnp.float32(0.0), fi_dev,
                                 jnp.int32(-1), zero, a, caches)
+            self._count_attend_blocks(steps_run, chunk_steps, num_steps, 0)
             chunks.append(ids)
             steps_run += chunk_steps
         arr = np.concatenate([np.asarray(c[0]) for c in chunks], axis=0)

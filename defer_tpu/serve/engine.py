@@ -154,6 +154,10 @@ class ContinuousBatchEngine:
         self.steps = 0
         self._step_hist = REGISTRY.histogram("serve.decode.step_s")
         self._tok_count = REGISTRY.counter("serve.decode.tokens")
+        #: position blocks the attention read / the blocks the slots it
+        #: read from hold, over this engine's steps
+        self._attend_blocks = [0, 0]
+        self._attend_share = REGISTRY.gauge("engine.attend.live_block_share")
 
     # -- state -------------------------------------------------------------
 
@@ -208,15 +212,14 @@ class ContinuousBatchEngine:
             safe = jnp.clip(pos, 0, self.max_len - 1)
             x = embed.embed_rows(params["embeddings"], ids,
                                  safe).astype(jnp.float32)
-            # every slot attends over its own positions <= its own pos
-            live_to = fmt.live_to(safe)
             for l, (op, nm) in enumerate(blocks):
                 q, k_new, v_new = op.decode_qkv(params[nm], x, safe)
                 layer = fmt.write_slots(fmt.layer(caches, l),
                                         fmt.rows(k_new, v_new), safe)
                 caches = fmt.with_layer(caches, l, layer)
+                # every slot attends over its own positions <= its own
                 x = op.decode_finish(params[nm], x,
-                                     fmt.attend(q, layer, live_to))
+                                     fmt.attend(q, layer, safe))
             h = final_ln.apply(params["final_ln"], x)
             logits = lm_head.apply(params["lm_head"],
                                    h).astype(jnp.float32)
@@ -277,6 +280,13 @@ class ContinuousBatchEngine:
                 seeds[i] = s.req.seed & 0xFFFFFFFF
                 temps[i] = s.req.temperature
                 sample = sample or s.req.temperature > 0
+            # what the attention will read: every slot's blocks up to
+            # its own position (an idle slot's first)
+            read, held = self.kv_format.live_block_share(pos)
+            self._attend_blocks[0] += read
+            self._attend_blocks[1] += held
+            self._attend_share.set(
+                self._attend_blocks[0] / self._attend_blocks[1])
         with span("engine", "dispatch") as dispatched:
             next_ids, self._caches = self._step_fn(sample)(
                 self.params, self._caches, jnp.asarray(ids),

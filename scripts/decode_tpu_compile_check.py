@@ -7,25 +7,39 @@ on this host.  r05's cliff row (gpt 12L/768, 64 sequences, 512 positions:
 its blocks' caches in one stacked array: XLA:TPU wrapped every cache
 write in a ``remat_uncompressed``/``remat_compressed`` pair of copies of
 the whole stack, which shows in the compiled text (214 of them at the
-batch cell's size on PR 24's tree).  With per-block buffers there is none.  Run it before
+batch cell's size on PR 24's tree).  With per-block buffers there is
+none.  Until PR 29 a step also cut a group's item out of every buffer
+(``slice``), and the compiled loop converted every buffer to a padded
+layout of its own and back, every dispatch (``copy``, and a temporary of
+all of them): with the attention a kernel over the buffers as they lie
+(``ops/kv_cache.py::kv_attend``) there is neither.  Run it before
 spending chip time on a change to how the ring holds its caches:
 
-    env JAX_PLATFORMS=cpu python scripts/decode_tpu_compile_check.py
+    env JAX_PLATFORMS=cpu python scripts/decode_tpu_compile_check.py \\
+        [layers d_model heads sequences max_len token_chunk]
 
-~30 s; exit 0 and one JSON line when no such copy is in the compiled
-text, 1 when there is.  A process of its own on purpose: loading the
-TPU's library takes a machine-wide lock (``/tmp/libtpu_lockfile``) that
-is held until the process ends, so this must not live in a long test run.
+(default: the cliff row, ``12 768 12 64 512 32``; the benchmark's batch
+cell is ``24 1600 25 8 768 4``, gpt2-xl at full depth ``48 1600 25 8 768
+4``).  ~30 s at the default; one JSON line; exit 0 when the compiled
+text writes rows in place and holds no whole-cache copy, no item-sized
+slice or copy inside a step and no whole-buffer conversion around the
+loop, 1 otherwise (2 when the program does not fit the chip).  A process
+of its own on purpose: loading the TPU's library takes a machine-wide
+lock (``/tmp/libtpu_lockfile``) that is held until the process ends, so
+this must not live in a long test run.
 """
 
 import json
 import os
 import re
 import sys
+from unittest import mock
 
 import numpy as np
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
@@ -35,20 +49,22 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from defer_tpu.models import gpt
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
+from hlo_cache_ops import computations, count_cache_ops
 
 
-def main() -> int:
+def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
+         chunk=32) -> int:
     # a program compiled for a described chip cannot be read back from
     # the persistent cache without the chip: keep it out
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    mb, plen = 64, 32
-    graph = gpt(12, 768, 12, 512, vocab=50257)
+    plen = 32
+    graph = gpt(layers, d_model, heads, max(max_len, 512), vocab=50257)
     params = jax.tree.map(lambda s: np.zeros(s.shape, np.float32),
                           jax.eval_shape(graph.init, jax.random.key(0)))
     dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=mb,
-                           max_len=512, compute_dtype=jnp.bfloat16)
+                           max_len=max_len, compute_dtype=jnp.bfloat16)
     # the program as the chip would get it: same function, the mesh made
     # of the described device, shapes in place of arrays
     dec.mesh = Mesh(np.array(topo.devices[:1]).reshape(dec.mesh.devices.shape),
@@ -59,30 +75,49 @@ def main() -> int:
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
     # the format's buffers behind the ring's own stage axis
+    buffers = dec.kv_format.buffers(mb)
     caches = {key: (arg((1,) + buf.shape, buf.dtype,
                         P(STAGE_AXIS, *(None,) * len(buf.shape))),)
               * dec.l_max
-              for key, buf in dec.kv_format.buffers(mb).items()}
+              for key, buf in buffers.items()}
     i32 = arg((), jnp.int32)
-    _, chunk_steps = dec._schedule(plen + 128, 0, 32)
-    compiled = dec._build_decode_fn(chunk_steps, False, None).lower(
-        arg(dec._w.shape, dec._w.dtype, P(STAGE_AXIS, None)),
-        arg((1, mb, plen), jnp.int32, P(None, None, None)),
-        i32, i32, i32, arg((), jnp.uint32), arg((), jnp.float32),
-        arg((1, mb), jnp.int32, P(None, None)), i32, i32,
-        arg((1, mb, dec.d_model), jnp.float32,
-            P(STAGE_AXIS, None, None)), caches).compile()
+    _, chunk_steps = dec._schedule(plen + 4 * chunk, 0, chunk)
+    # the attention runs its kernel in the interpreter wherever
+    # ``jax.default_backend()`` is not the TPU; this host's is the CPU
+    # and the program is the chip's
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = dec._build_decode_fn(chunk_steps, False, None).lower(
+            arg(dec._w.shape, dec._w.dtype, P(STAGE_AXIS, None)),
+            arg((1, mb, plen), jnp.int32, P(None, None, None)),
+            i32, i32, i32, arg((), jnp.uint32), arg((), jnp.float32),
+            arg((1, mb), jnp.int32, P(None, None)), i32, i32,
+            arg((1, mb, dec.d_model), jnp.float32,
+                P(STAGE_AXIS, None, None)), caches)
+    try:
+        compiled = lowered.compile()
+    except Exception as e:  # noqa: BLE001 — the compiler's own refusal
+        print(json.dumps({"device_kind": topo.devices[0].device_kind,
+                          "refused": str(e).splitlines()[0][:400]}))
+        return 2
     text = compiled.as_text()
     mem = compiled.memory_analysis()
+    shape = buffers["k"].shape
     row = {"device_kind": topo.devices[0].device_kind,
-           "row_writes": text.count("dynamic-update-slice"),
            "whole_cache_copies": len(re.findall(
                r"remat_(?:un)?compressed[\w.]* = ", text)),
+           **count_cache_ops(computations(text), shape[1:], shape),
+           "kernels": text.count('custom_call_target="tpu_custom_call"'),
            "argument_bytes": mem.argument_size_in_bytes,
            "temp_bytes": mem.temp_size_in_bytes}
+    dump = os.environ.get("DECODE_CHECK_DUMP")
+    if dump:
+        with open(dump, "w") as f:
+            f.write(text)
     print(json.dumps(row))
-    return 0 if row["row_writes"] and not row["whole_cache_copies"] else 1
+    return 0 if row["row_writes"] and not (
+        row["whole_cache_copies"] or row["item_copies"]
+        or row["buffer_copies"]) else 1
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(*(int(a) for a in sys.argv[1:])))

@@ -1,0 +1,106 @@
+"""What the three ``*_tpu_compile_check.py`` scripts count in a compiled
+program's text: the operations that touch a KV cache buffer.
+
+* a **row write** runs in place on its operand's buffer: the row-writer
+  kernel (a custom call whose output aliases an operand) or a
+  ``dynamic-update-slice``, bare or as a fusion's root;
+* a **prefetch** is an asynchronous move the compiler's memory-space
+  assignment adds on its own, into fast memory and back;
+* an **item copy** is anything else that *produces* an array of one
+  sequence group's size inside a step: a slice out of a buffer, a layout
+  copy, a transpose, a scatter;
+* a **buffer copy** is anything that produces an array of a whole
+  buffer's size: the layout conversion a compiled loop puts around
+  itself when it wants a buffer otherwise than its caller holds it.
+
+A size is matched by its dimensions in any order, unit dimensions
+aside, so a re-laid-out copy counts.
+"""
+
+import re
+
+#: ``%name = f32[16,25,192,64]{...} opcode(operands), attrs``
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\((.*)$")
+#: opcodes that name or alias an array and move nothing
+_FREE = {"parameter", "bitcast", "get-tuple-element", "tuple", "while",
+         "conditional", "call", "optimization-barrier"}
+#: the two ends of an asynchronous move; a sliced prefetch joins its
+#: parts with a ``ConcatBitcast`` custom call
+_ASYNC = {"copy-start", "copy-done", "slice-start", "slice-done"}
+
+
+def computations(text: str) -> dict[str, list[str]]:
+    """HLO text -> {computation name: its instruction lines}."""
+    comps: dict[str, list[str]] = {}
+    cur = None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(
+                "ENTRY" if head.group(1) else head.group(2), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _root_opcode(lines: list[str]) -> str:
+    for line in lines:
+        if line.lstrip().startswith("ROOT "):
+            m = _INSTR.match(line)
+            return m.group(4) if m else ""
+    return ""
+
+
+def _dims(shape) -> list[int]:
+    return sorted(int(d) for d in shape if int(d) != 1)
+
+
+def count_cache_ops(comps: dict[str, list[str]], item_dims, buffer_dims=None,
+                    within=None) -> dict:
+    """Counts over the computations ``within`` (default: all but the
+    bodies of fusions, whose values live in registers): see the module
+    docstring.  ``item_dims`` one group's ``[b, kv, L, hd]``;
+    ``buffer_dims`` the whole buffer's where it has groups."""
+    item = _dims(item_dims)
+    whole = _dims(buffer_dims) if buffer_dims is not None else None
+    fused = {m.group(1) for lines in comps.values() for line in lines
+             for m in [re.search(r"fusion\(.*calls=%?([\w.\-]+)", line)] if m}
+    row_writes, prefetches, items, buffers = 0, 0, [], []
+    for name, lines in comps.items():
+        if name in fused or (within is not None and name not in within):
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            op_name, _dtype, dims, opcode, rest = m.groups()
+            if opcode in _FREE or not dims:
+                continue
+            got = _dims(dims.split(","))
+            if got != item and got != whole:
+                continue
+            if opcode == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", rest)
+                opcode = _root_opcode(comps.get(called.group(1), [])) \
+                    if called else opcode
+            in_place = opcode == "dynamic-update-slice" or (
+                opcode == "custom-call"
+                and "output_to_operand_aliasing" in rest)
+            if in_place:
+                row_writes += 1
+            elif opcode in _ASYNC or "ConcatBitcast" in rest:
+                prefetches += 1
+            else:
+                (buffers if got == whole else items).append(op_name)
+
+    def kinds(names):
+        return sorted({re.sub(r"[.\d]+$", "", n) for n in names})
+
+    return {"row_writes": row_writes, "item_copies": len(items),
+            "item_copy_kinds": kinds(items),
+            "buffer_copies": len(buffers),
+            "buffer_copy_kinds": kinds(buffers),
+            "item_prefetches": prefetches}

@@ -12,12 +12,16 @@ What it answers before any chip time is spent (PERF.md, PR 26):
 * is an expert-sized array copied anywhere in the decode program (a
   ``copy`` or a ``fusion`` whose result has an expert leaf's shape): the
   expert leaves are stage-sharded arguments of their own because a slice
-  of the flat weight row would be one.
+  of the flat weight row would be one;
+* does a step cut a group's item out of a cache buffer, or the compiled
+  loop convert a whole buffer to a layout of its own (both did until
+  PR 29: the attention is now a kernel over the buffers as they lie,
+  ``ops/kv_cache.py::kv_attend``; ``scripts/hlo_cache_ops.py`` counts).
 
     env JAX_PLATFORMS=cpu python scripts/olmoe_tpu_compile_check.py
 
 A minute or two and ~8 GB of host memory (the weights are zeros); one
-JSON line; exit 0 when all three hold.  A process of its own, like
+JSON line; exit 0 when all four hold.  A process of its own, like
 ``decode_tpu_compile_check.py``: the TPU's library is locked machine-wide
 while it runs.
 """
@@ -26,9 +30,11 @@ import json
 import os
 import re
 import sys
+from unittest import mock
 
 import numpy as np
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -41,6 +47,7 @@ from chipbench.roofline_moe import olmoe_prefill_needs
 from defer_tpu.models import olmoe
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
+from hlo_cache_ops import computations, count_cache_ops
 
 ARGS = dict(num_layers=8, hidden=2048, heads=16, seq_len=4096, vocab=50304,
             num_experts=64, experts_per_tok=8, expert_hidden=1024)
@@ -77,14 +84,19 @@ def main() -> int:
     i32, u32, f32 = (arg((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     prompt = arg((1, MB, PLEN), jnp.int32, P(None, None, None))
 
-    prefill = dec._build_prefill_fn(PLEN, False, None).lower(
-        w, prompt, u32, f32, caches).compile()
     _, chunk_steps = dec._schedule(MAX_LEN, PLEN, CHUNK)
-    decode = dec._build_decode_fn(chunk_steps, False, None).lower(
-        w, prompt, i32, i32, i32, u32, f32,
-        arg((1, MB), jnp.int32, P(None, None)), i32, i32,
-        arg((1, MB, dec.d_model), jnp.float32, P(STAGE_AXIS, None, None)),
-        caches).compile()
+    # the kernels run in the interpreter wherever
+    # ``jax.default_backend()`` is not the TPU; this host's is the CPU
+    # and the programs are the chip's
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        prefill = dec._build_prefill_fn(PLEN, False, None).lower(
+            w, prompt, u32, f32, caches)
+        decode = dec._build_decode_fn(chunk_steps, False, None).lower(
+            w, prompt, i32, i32, i32, u32, f32,
+            arg((1, MB), jnp.int32, P(None, None)), i32, i32,
+            arg((1, MB, dec.d_model), jnp.float32,
+                P(STAGE_AXIS, None, None)), caches)
+    prefill, decode = prefill.compile(), decode.compile()
 
     e, d, h = ARGS["num_experts"], ARGS["hidden"], ARGS["expert_hidden"]
     expert_shapes = (f"bf16[{e},{d},{h}]", f"bf16[{e},{h},{d}]",
@@ -116,15 +128,19 @@ def main() -> int:
                 "output_gb": m.output_size_in_bytes / 1e9,
                 "alias_gb": m.alias_size_in_bytes / 1e9}
 
+    shape = dec.kv_format.buffers(MB)["k"].shape
+    cache_ops = count_cache_ops(computations(text), shape[1:], shape)
     row = {"device_kind": topo.devices[0].device_kind,
            "prefill": mem(prefill), "decode": mem(decode),
+           "decode_cache_ops": cache_ops,
            "prefill_flops": flops, "prefill_needs_flops": needs_flops,
            "prefill_flops_over_needs": flops / needs_flops,
            "expert_sized_values_produced_in_decode": produced[:8],
            "ragged_dot_calls_in_decode": text.count("ragged"),
            }
     print(json.dumps(row))
-    ok = not produced and flops / needs_flops <= 1.5
+    ok = not produced and flops / needs_flops <= 1.5 and not (
+        cache_ops["item_copies"] or cache_ops["buffer_copies"])
     return 0 if ok else 1
 
 

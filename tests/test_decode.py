@@ -744,10 +744,13 @@ def _decode_scan_body(dec, chunk_steps):
 def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
                                                         num_stages, beam):
     """Structural guard of the decode program's scan body: every write
-    into a K/V (or scale) buffer is one position wide (beam search may
-    also re-parent one whole group), and no value has the shape of the
-    stack of all local blocks' caches — the shape whose whole-stack
-    copies were 92% of a step on the chip (docs/DECODE_CLIFF.md)."""
+    into a K/V (or scale) buffer is one position wide — a
+    ``dynamic_update_slice``, or the row-writer kernel where the
+    positions lie on the lanes (float rows, ``head_dim`` under 128:
+    ``ops/kv_cache.py``) — beam search may also re-parent one whole
+    group, and no value has the shape of the stack of all local blocks'
+    caches — the shape whose whole-stack copies were 92% of a step on
+    the chip (docs/DECODE_CLIFF.md)."""
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=num_stages,
                            microbatch=8 // num_stages, max_len=MAX_LEN,
@@ -761,6 +764,10 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
     for eqn in _walk(body):
         for v in list(eqn.invars) + list(eqn.outvars):
             assert getattr(v.aval, "shape", None) not in stacked, eqn
+        if eqn.primitive.name == "pallas_call" \
+                and eqn.params["name"] == "kv_write_rows":
+            assert kv_cache == "buffer"
+            rows += 1
         if eqn.primitive.name != "dynamic_update_slice":
             continue
         buf, upd = eqn.invars[0].aval.shape, eqn.invars[1].aval.shape
@@ -803,8 +810,8 @@ def test_block_decode_is_its_two_halves(model, quant):
     q, k_new, v_new = op.decode_qkv(p, x)
     assert q.shape == (b, d)
     assert k_new.shape == v_new.shape == (b, op.kv_heads * hd)
-    got_cache, item = fmt.write_position(cache, fmt.rows(k_new, v_new), pos)
-    got = op.decode_finish(p, x, fmt.attend(q, item, pos))
+    got_cache = fmt.write_position(cache, fmt.rows(k_new, v_new), pos)
+    got = op.decode_finish(p, x, fmt.attend(q, got_cache, pos))
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
     assert set(want_cache) == set(got_cache) == set(cache)
     for key, buf in got_cache.items():

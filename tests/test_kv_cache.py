@@ -14,7 +14,9 @@ import jax.numpy as jnp
 
 from defer_tpu.models import gpt_tiny
 from defer_tpu.ops import kv_cache
-from defer_tpu.ops.kv_cache import KVCacheFormat, quantize_rows, write_kv_rows
+from defer_tpu.ops.kv_cache import (KVCacheFormat, attend_blocks,
+                                    attend_einsum, quantize_rows,
+                                    write_kv_rows)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine, DecodeRequest
 
@@ -102,7 +104,8 @@ def test_write_position_and_attend_are_the_cache_half_of_a_step(quantized):
     """The format half of a block's one-token step: the rows of the new
     columns fit one position of every buffer, land at ``pos`` and touch
     nothing else, and attention over the item is plain softmax attention
-    over the (dequantized) live positions."""
+    over the (dequantized) live positions (the kernel for float rows,
+    the einsums for int8: the format's own field chooses)."""
     fmt, rng = _fmt(quantized), np.random.default_rng(5)
     b, pos, heads = 3, 4, 4             # two query heads a KV head
     layer = _random_layer(fmt, b, rng)
@@ -111,19 +114,18 @@ def test_write_position_and_attend_are_the_cache_half_of_a_step(quantized):
     rows = fmt.rows(k_new, v_new)
     assert {key: r.shape for key, r in rows.items()} == \
         {key: c.shape[:2] + (1,) + c.shape[3:] for key, c in layer.items()}
-    got, item = fmt.write_position(layer, rows, pos)
+    got = fmt.write_position(layer, rows, pos)
     for key, c in layer.items():
         want = c.at[:, :, pos: pos + 1].set(rows[key].astype(c.dtype))
         np.testing.assert_array_equal(np.asarray(got[key]), np.asarray(want))
-        assert item[key] is got[key]    # without groups: its own item
     if not quantized:
         np.testing.assert_array_equal(
             np.asarray(got["k"][:, :, pos]).reshape(b, KV * HD),
             np.asarray(k_new))
 
     q = jnp.asarray(rng.standard_normal((b, heads * HD)), jnp.float32)
-    y = fmt.attend(q, item, pos)
-    k, v = _dequantized(item)
+    y = fmt.attend(q, got, pos)
+    k, v = _dequantized(got)
     qh = np.asarray(q).reshape(b, KV, heads // KV, HD)
     att = np.einsum("bkgd,bkld->bkgl", qh, k[:, :, : pos + 1]) / math.sqrt(HD)
     att = np.exp(att - att.max(-1, keepdims=True))
@@ -133,18 +135,160 @@ def test_write_position_and_attend_are_the_cache_half_of_a_step(quantized):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_attend_takes_each_sequences_own_position():
-    fmt, rng = _fmt(), np.random.default_rng(6)
+@pytest.mark.parametrize("quantized", [False, True], ids=["buffer", "int8"])
+def test_attend_takes_each_sequences_own_position(quantized):
+    fmt, rng = _fmt(quantized), np.random.default_rng(6)
     layer = _random_layer(fmt, 3, rng)
     q = jnp.asarray(rng.standard_normal((3, 2 * KV * HD)), jnp.float32)
     pos = jnp.asarray([0, 5, L - 1], jnp.int32)
-    got = fmt.attend(q, layer, fmt.live_to(pos))
+    got = fmt.attend(q, layer, pos)
     for i, p in enumerate(pos):
         want = fmt.attend(q[i: i + 1],
                           {key: c[i: i + 1] for key, c in layer.items()},
                           int(p))
         np.testing.assert_allclose(np.asarray(got[i: i + 1]),
                                    np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
+# -- the attention kernel against the einsums it replaces -----------------------
+
+def _attend_case(hd, dtype, g, groups, length, batch=4, kv=2, seed=0):
+    """A format, one layer of noise (NaN wherever nothing may read: the
+    scratch group and the scratch row) and a query."""
+    rng = np.random.default_rng(seed)
+    fmt = KVCacheFormat(kv, hd, length, dtype, groups=groups)
+    layer = {key: jnp.asarray(rng.standard_normal(s.shape), dtype)
+             for key, s in fmt.buffers(batch).items()}
+    if groups is not None:
+        layer = {key: buf.at[fmt.scratch_group].set(jnp.nan)
+                 .at[:, :, :, fmt.scratch_position].set(jnp.nan)
+                 for key, buf in layer.items()}
+    q = jnp.asarray(rng.standard_normal((batch, kv * g * hd)), dtype)
+    return fmt, layer, q
+
+
+def _oracle(q, layer, pos, group=None):
+    """The kept einsums in f32 over the group's clean item."""
+    item = {key: jnp.nan_to_num((buf if group is None else buf[group])
+                                .astype(jnp.float32))
+            for key, buf in layer.items()}
+    return np.asarray(attend_einsum(q.astype(jnp.float32), item, pos))
+
+
+@pytest.mark.parametrize("groups", [None, 2], ids=["slots", "groups"])
+@pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_kv_attend_is_the_einsums_over_live_rows(hd, dtype, g, groups):
+    """Both block shapes, both row types, one query a KV head and a
+    group of them; 300 (301) positions are two blocks, the second
+    ragged.  With groups: the ring's call, a scalar position and the
+    group an index, and neither the scratch group nor the scratch row
+    (NaN) reaches the output."""
+    fmt, layer, q = _attend_case(hd, dtype, g, groups, 300)
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    if groups is None:
+        pos = jnp.asarray([0, 130, 255, 299], jnp.int32)
+        got = fmt.attend(q, layer, pos)
+        want = _oracle(q, layer, pos)
+    else:
+        got = fmt.attend(q, layer, jnp.int32(200), group=jnp.int32(1))
+        want = _oracle(q, layer, jnp.int32(200), 1)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("positions", [
+    [0, 0, 0], [127, 128, 129], [255, 256, 383], [60, 200, 419]],
+    ids=["first", "block-edges", "later-edges", "mixed-and-last"])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_kv_attend_at_each_sequences_own_position(monkeypatch, request, hd,
+                                                  positions):
+    """Position 0, mid-block, a block's last and first, the buffer's
+    last; 420 positions in blocks of 128 are four blocks, the last
+    ragged (a block is as many lane rows as ``_BLOCK_BYTES`` hold)."""
+    monkeypatch.setattr(kv_cache, "_BLOCK_BYTES", 2 * hd * 128 * 4)
+    attend_blocks.cache_clear()         # sizes are reckoned once a shape
+    request.addfinalizer(attend_blocks.cache_clear)
+    assert attend_blocks(2, hd, 420, 4) == (2, 128)
+    fmt, layer, q = _attend_case(hd, jnp.float32, 2, None, 420, batch=3)
+    pos = jnp.asarray(positions, jnp.int32)
+    # a fresh trace: the block size is read when the kernel is built
+    got = kv_cache.kv_attend.__wrapped__(
+        q, layer["k"][None], layer["v"][None], pos, jnp.zeros(1, jnp.int32))
+    np.testing.assert_allclose(np.asarray(got), _oracle(q, layer, pos),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_kv_attend_of_a_bubble_step_is_finite():
+    """A bubble writes the scratch row and attends at it: numbers
+    nobody reads, but numbers."""
+    fmt, layer, q = _attend_case(64, jnp.bfloat16, 1, 2, 300)
+    layer = {key: jnp.nan_to_num(buf) for key, buf in layer.items()}
+    got = fmt.attend(q, layer, jnp.int32(fmt.scratch_position),
+                     group=jnp.int32(0))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("kv,hd,length,itemsize,want", [
+    (25, 64, 769, 2, (25, 256)),     # gpt2-xl's ring: 0.8 MB a block
+    (25, 64, 192, 4, (25, 128)),     # the engine's f32 slots
+    (16, 128, 1281, 2, (16, 256)),   # OLMoE's ring: 1 MiB
+    (2, 8, 9, 4, (2, 9)),            # under a lane row: the whole item
+    (64, 128, 4096, 4, (16, 128)),   # heads split before positions grow
+])
+def test_attend_blocks_hold_a_megabyte(kv, hd, length, itemsize, want):
+    assert attend_blocks(kv, hd, length, itemsize) == want
+    fmt = KVCacheFormat(kv, hd, length, jnp.dtype(f"float{8 * itemsize}"))
+    read, held = fmt.live_block_share(np.array([0, want[1] - 1, want[1]]))
+    assert (read, held) == (4 if length > want[1] else 3,
+                            3 * -(-length // want[1]))
+
+
+def _cache_slices(jaxpr, item):
+    """Names of the equations, at any depth, that cut an array with an
+    item's dimensions out of another."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("slice", "dynamic_slice", "gather"):
+            for out in eqn.outvars:
+                if sorted(d for d in out.aval.shape if d != 1) == item:
+                    found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _cache_slices(sub, item)
+    return found
+
+
+@pytest.mark.parametrize("engine", ["ring", "slots"])
+def test_a_step_cuts_no_item_out_of_a_cache_buffer(model, engine):
+    """Neither engine's step holds a ``slice`` / ``dynamic_slice`` that
+    produces an array of one group's (one batch of slots') size: the
+    attention reads the buffers where they lie, once a layer."""
+    graph, params = model
+    if engine == "ring":
+        dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=3,
+                               max_len=24)
+        a, caches = dec._init_state()
+        i32 = jnp.int32(0)
+        jaxpr = jax.make_jaxpr(dec._get_decode_fn(4, False, None))(
+            dec._w, jnp.zeros((2, 3, 5), jnp.int32), i32, i32, i32,
+            jnp.uint32(0), jnp.float32(0), jnp.zeros((2, 3), jnp.int32),
+            i32, i32, a, caches)
+        shape = dec.kv_format.buffers(3)["k"].shape[1:]
+        layers = dec.l_max * 2          # a call a block and a stage
+    else:
+        eng = ContinuousBatchEngine(graph, params, num_stages=2, width=3)
+        vec = jnp.zeros(3, jnp.int32)
+        jaxpr = jax.make_jaxpr(eng._step_fn(False))(
+            eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
+            vec.astype(jnp.float32))
+        shape = eng.kv_format.buffers(3)["k"].shape
+        layers = len(eng._caches["k"])
+    assert _cache_slices(jaxpr.jaxpr, sorted(d for d in shape if d != 1)) == []
+    assert str(jaxpr).count("name=kv_attend") >= 1
+    assert str(jaxpr).count("kv_attend") >= layers
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["buffer", "int8"])
@@ -154,13 +298,12 @@ def test_write_position_of_a_group_touches_that_group_only(quantized):
     rows = fmt.rows(*(jnp.asarray(rng.standard_normal((2, KV * HD)),
                                   jnp.float32) for _ in range(2)))
     for g, pos in ((1, 4), (fmt.scratch_group, fmt.scratch_position)):
-        got, item = fmt.write_position(layer, rows, jnp.int32(pos),
-                                       group=jnp.int32(g))
+        got = fmt.write_position(layer, rows, jnp.int32(pos),
+                                 group=jnp.int32(g))
         for key, c in layer.items():
             want = np.asarray(c).copy()
             want[g, :, :, pos] = np.asarray(rows[key].astype(c.dtype))[:, :, 0]
             np.testing.assert_array_equal(np.asarray(got[key]), want)
-            np.testing.assert_array_equal(np.asarray(item[key]), want[g])
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["buffer", "int8"])
@@ -175,8 +318,8 @@ def test_write_prefix_is_the_rows_written_one_by_one(quantized):
     got = fmt.write_prefix(layer, k, v, jnp.int32(g))
     want = layer
     for p in range(t):
-        want, _ = fmt.write_position(want, fmt.rows(k[:, p], v[:, p]), p,
-                                     group=g)
+        want = fmt.write_position(want, fmt.rows(k[:, p], v[:, p]), p,
+                                  group=g)
     for key in fmt.keys:
         np.testing.assert_array_equal(np.asarray(got[key]),
                                       np.asarray(want[key]))
@@ -234,6 +377,32 @@ def test_reparent_moves_one_groups_rows_in_every_layer():
             np.testing.assert_array_equal(np.asarray(new), want)
 
 
+def test_live_block_share_is_reckoned_when_a_step_is_dispatched(model):
+    """``decode.attend.live_block_share`` / ``engine.attend...``: blocks
+    read over blocks held, from positions the host already has.  At toy
+    sizes an item is one block, so every step reads all it holds; the
+    tally grows by a block a sequence, a layer-less count a step."""
+    from defer_tpu.obs import REGISTRY
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=24)
+    dec.generate(_prompts(4, 5), 4, prefill=True, token_chunk=2)
+    read, held = dec._attend_blocks
+    assert read == held > 0 and held % 2 == 0   # both stages, every step
+    assert REGISTRY.gauge("decode.attend.live_block_share").value == 1.0
+    eng = ContinuousBatchEngine(graph, params, num_stages=2, width=3)
+    eng.run_all([DecodeRequest(prompt=_prompts(1, 3)[0], max_new_tokens=2,
+                               request_id=0)])
+    assert eng._attend_blocks == [3 * eng.steps, 3 * eng.steps]
+    assert REGISTRY.gauge("engine.attend.live_block_share").value == 1.0
+    # where an item is several blocks, a sequence reads up to its own
+    fmt = KVCacheFormat(25, 64, 768, jnp.bfloat16, groups=1)
+    assert fmt.live_block_share(np.array([0, 255, 256, 767, 768])) == (
+        1 + 1 + 2 + 3 + 4, 5 * 4)
+    assert KVCacheFormat(25, 64, 768, jnp.bfloat16, quantized=True,
+                         groups=1).live_block_share(np.array([0])) == (4, 4)
+
+
 # -- another format in its place ------------------------------------------------
 
 class PositionsLeading(KVCacheFormat):
@@ -245,9 +414,8 @@ class PositionsLeading(KVCacheFormat):
     def _axis(self):            # where the real format keeps positions
         return 2 if self.groups is None else 3
 
-    def _out(self, layer, axis=None):
-        axis = self._axis if axis is None else axis
-        return {key: jnp.moveaxis(buf, axis, 0)
+    def _out(self, layer):
+        return {key: jnp.moveaxis(buf, self._axis, 0)
                 for key, buf in layer.items()}
 
     def _in(self, layer):
@@ -262,9 +430,8 @@ class PositionsLeading(KVCacheFormat):
         return {key: first(s) for key, s in super().buffers(batch).items()}
 
     def write_position(self, layer, rows, pos, group=None):
-        layer, item = super().write_position(self._in(layer), rows, pos,
-                                             group)
-        return self._out(layer), self._out(item, 2)
+        return self._out(super().write_position(self._in(layer), rows, pos,
+                                                group))
 
     def write_slots(self, layer, rows, pos):
         return self._out(super().write_slots(self._in(layer), rows, pos))
@@ -279,11 +446,8 @@ class PositionsLeading(KVCacheFormat):
         return each(self._out,
                     super().reparent(each(self._in, state), group, parents))
 
-    @staticmethod
-    def attend(q, item, pos):
-        return KVCacheFormat.attend(
-            q, {key: jnp.moveaxis(buf, 0, 2) for key, buf in item.items()},
-            pos)
+    def attend(self, q, layer, pos, group=None):
+        return super().attend(q, self._in(layer), pos, group)
 
 
 @pytest.fixture(scope="module")

@@ -474,9 +474,12 @@ def test_the_serving_engine_refuses_the_block_by_name(model):
 #: 8 prompts ``arange(40).reshape(8, 5) % 97``, 8 greedy tokens,
 #: prefill, token_chunk 2 — and the sha256 of the ring's lowered decode
 #: program for the same decoder.  A PR that changes the ring's program
-#: on purpose records them anew and says so.
+#: on purpose records them anew and says so.  PR 29 did: the attention
+#: became the kernel ``kv_attend`` and the ring's row write, under a
+#: lane row, the kernel ``kv_write_rows`` (``e8dcb192e737955e`` /
+#: ``aadd22d3adc8d6a3`` before it); the tokens are PR 26's still.
 PARENT_TOKENS_SHA = "0fef1cc65e752cd8"
-PARENT_DECODE_SHA = {1: "e8dcb192e737955e", 2: "aadd22d3adc8d6a3"}
+PARENT_DECODE_SHA = {1: "843764f96d5a7620", 2: "081b76872aa207eb"}
 
 
 def _sha(text: str) -> str:
@@ -488,7 +491,7 @@ def test_gpt_tiny_decodes_as_on_the_parent(num_stages):
     """``decode_qkv`` takes a position and ``decode_finish`` a ``sow``,
     and the cache's half of a step lives in ``ops/kv_cache.py``: the
     GPT family ignores the first two, and its ring still lowers to the
-    text recorded at d5480a9 and gives its tokens, bit for bit."""
+    text recorded (PR 29's) and gives d5480a9's tokens, bit for bit."""
     graph = gpt_tiny(seq_len=32)
     params = graph.init(jax.random.key(0))
     n, mb = num_stages, 8 // num_stages
