@@ -5,13 +5,19 @@ it, whatever its family.
 * **Nodes**, by name: ``embeddings`` (an op with ``max_len``, the
   positions the model declares, and ``embed_at(params, ids, pos)``),
   ``block_0..`` in topological order (each a :class:`DecoderBlock`, all
-  of one head geometry and sowing the same statistics), ``final_ln``,
-  ``lm_head``.  :func:`decoder_parts` checks a graph against this and
-  hands back its parts; both engines' constructors call it.
-* **Blocks**: :class:`DecoderBlock`.  A block hands key and value
-  *columns* to the cache's format (``ops/kv_cache.py``) and takes the
-  attention's output back: it knows no axis order, key or type of the
-  cache.
+  keeping one kind of memory in one head geometry and sowing the same
+  statistics), ``final_ln``, ``lm_head``.  Any of them may name
+  ``stage_arg_keys``.  :func:`decoder_parts` checks a graph against
+  this and hands back its parts; both engines' constructors call it.
+* **Blocks**: :class:`DecoderBlock`, whose per-sequence memory is a KV
+  cache: it hands key and value *columns* to the cache's format
+  (``ops/kv_cache.py``) and takes the attention's output back.  Its
+  sibling :class:`RetentionBlock` keeps a recurrent state of fixed size
+  instead: it hands ``q, k, v`` and a log-decay to the state's format
+  (``ops/retention.py``) and takes the layer's output back.  Neither
+  knows an axis order, key or type of what the format holds; which kind
+  a block keeps is the class it is (``memory``), and the holder asks
+  the block for the format (:meth:`DecoderBlock.memory_format`).
 """
 
 from __future__ import annotations
@@ -31,7 +37,8 @@ class DecoderBlock:
     sequence, and one token's step in two halves around the cache.  A
     block the decode engines can run has
 
-    * ``num_heads`` / ``kv_heads`` / ``attn_impl``;
+    * ``num_heads`` / ``kv_heads`` / ``attn_impl``, and ``head_dim``
+      where a head's width is not the stream's over ``num_heads``;
     * ``apply_with_kv(params, x [b, t, d]) -> (y, k, v)``: the
       full-sequence forward, with the key and value columns [b, t, kv*hd]
       as :meth:`decode_qkv` would have handed them over row by row;
@@ -50,7 +57,8 @@ class DecoderBlock:
 
     Between the halves the caller writes the columns into its cache and
     attends over it, through ``ops/kv_cache.py``; :meth:`decode` is that
-    composition over one layer's buffers.
+    composition over one layer's buffers, :meth:`prefill` a whole
+    prompt's.
     """
 
     #: per-step scalars ``decode_finish`` sows (summed over a generation)
@@ -79,21 +87,108 @@ class DecoderBlock:
         att = jax.nn.softmax(att, axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", att, v)
 
-    def decode(self, params, x, cache, pos, fmt):
+    #: the kind of per-sequence memory the block keeps
+    memory = "kv_cache"
+
+    def memory_format(self, head_dim: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """The format of one layer of this block's memory, for
+        ``positions`` positions of rows of type ``dtype``: what a
+        holder builds its buffers from and hands back to :meth:`decode`
+        and :meth:`prefill`."""
+        from ..ops import kv_cache   # the class as the module names it now
+        return kv_cache.KVCacheFormat(self.kv_heads, head_dim, positions,
+                                      dtype, quantized=quantized,
+                                      groups=groups)
+
+    def decode(self, params, x, cache, pos, fmt, slot=None, group=None,
+               sow=None):
         """One-token step: ``x`` [b, d] at position ``pos`` against
         ``cache``, one layer's buffers in the format ``fmt``
-        (``ops/kv_cache.py::KVCacheFormat``, without groups).  The new
-        row is written at ``pos`` and attention covers positions
-        ``<= pos``.  Returns ``(out, cache)``.
+        (:meth:`memory_format`'s; of group ``group`` where it has
+        groups).  The new row is written at ``pos`` and attention covers
+        positions ``<= pos``.  ``slot`` is the format's own word for
+        where a step's memory goes, made by ``fmt.decode_slot(valid,
+        pos)`` and read by the format alone: a holder with bubbles
+        passes it, and to a block it is opaque (here None stands for
+        "at ``pos``").  Returns ``(out, cache)``.
 
         The composition of :meth:`decode_qkv`, the format's write and
-        attention and :meth:`decode_finish`: the oracle the tests hold
-        both engines to.  The pipelined decoder and the serving engine
-        call the halves and write into their own buffers.
+        attention and :meth:`decode_finish`: what the ring runs a layer
+        a step, and the oracle the tests hold both engines to (the
+        serving engine calls the halves and writes a position a slot).
         """
+        slot = pos if slot is None else slot
         q, k_new, v_new = self.decode_qkv(params, x, pos)
-        cache = fmt.write_position(cache, fmt.rows(k_new, v_new), pos)
-        return self.decode_finish(params, x, fmt.attend(q, cache, pos)), cache
+        cache = fmt.write_position(cache, fmt.rows(k_new, v_new), slot,
+                                   group=group)
+        return self.decode_finish(
+            params, x, fmt.attend(q, cache, slot, group=group),
+            sow=sow), cache
+
+    def prefill(self, params, x, cache, fmt, slot):
+        """A whole prompt ``x`` [b, t, d] through the layer, its rows
+        bulk-written where ``slot`` says (``fmt.prefill_slot(valid,
+        group)``'s, opaque to the block like :meth:`decode`'s):
+        ``(out, cache)``."""
+        x, k, v = self.apply_with_kv(params, x)
+        return x, fmt.write_prefix(cache, k, v, slot)
+
+
+class RetentionBlock(DecoderBlock):
+    """A decoder block whose per-sequence memory is a retention state
+    (``ops/retention.py``): of fixed size, read *and rewritten whole*
+    every step, where a KV cache gains a row.  In place of
+    ``apply_with_kv`` / ``decode_qkv`` such a block has
+
+    * ``qkvg(params, x [..., t, d], pos [t]) -> (q, k, v, lg)``: the
+      queries [..., t, nh*hd], the keys and values [..., t, kv*hd]
+      (final when handed over) and the log-decay [..., t, kv] of the
+      tokens at positions ``pos``;
+    * ``decode_finish(params, x [T, d], y [T, nh*hd], sow=None)``: the
+      rest of the block after the retention's output ``y``.
+
+    The head geometry, ``decode_stats`` and ``stage_arg_keys`` are
+    :class:`DecoderBlock`'s.  No serving engine takes such a block yet
+    (``serve/engine.py`` refuses every block but GPT's).
+    """
+
+    memory = "retention"
+
+    def memory_format(self, head_dim: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """A state's size does not depend on ``positions``, and it is
+        float32 whatever ``dtype`` the block computes in."""
+        del positions, dtype
+        if quantized:
+            raise ValueError(
+                "kv_cache='int8' quantizes cached key and value rows; "
+                "these blocks keep a retention state, which has none")
+        from ..ops import retention
+        return retention.RetentionFormat(self.kv_heads, head_dim,
+                                         groups=groups)
+
+    def decode(self, params, x, state, pos, fmt, slot=True, group=None,
+               sow=None):
+        """One-token step: ``x`` [b, d] at position ``pos`` against one
+        layer's ``state``; ``slot`` is ``fmt.decode_slot``'s, handed on
+        to the format unread (for a state it says whether the step is
+        real: a bubble leaves the state as it is)."""
+        q, k, v, lg = self.qkvg(params, x[:, None], jnp.reshape(pos, (1,)))
+        y, state = fmt.step(q[:, 0], k[:, 0], v[:, 0], lg[:, 0], state,
+                            group=group, valid=slot)
+        return self.decode_finish(params, x, y, sow=sow), state
+
+    def prefill(self, params, x, state, fmt, slot=(None, True)):
+        """A whole prompt ``x`` [b, t, d] through the layer from an
+        empty memory; the state after its last position is left where
+        ``slot`` (``fmt.prefill_slot``'s, handed on unread) says."""
+        b, t, d = x.shape
+        q, k, v, lg = self.qkvg(params, x, jnp.arange(t))
+        y, state = fmt.prefill(q, k, v, lg, state, slot)
+        out = self.decode_finish(params, x.reshape(b * t, d),
+                                 y.reshape(b * t, -1))
+        return out.reshape(b, t, d), state
 
 
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
@@ -121,6 +216,7 @@ class DecoderParts:
     max_len: int                #: positions a cache is to hold
     stage_blocks: list          #: per stage, its blocks' names, balanced
     decode_stats: tuple         #: what every block sows each step
+    memory: str                 #: the kind of memory every block keeps
 
 
 def decoder_parts(graph: LayerGraph, num_stages: int,
@@ -148,16 +244,26 @@ def decoder_parts(graph: LayerGraph, num_stages: int,
             raise TypeError(
                 f"{nm} ({nodes[nm].op!r}) is not a DecoderBlock "
                 "(models/decoder.py): the decode engines need its "
-                "decode_qkv / decode_finish / apply_with_kv")
+                "decode / prefill and the halves they are made of")
     # an empty block list is refused with split_blocks' message
     stage_blocks = [[block_names[i] for i in idxs]
                     for idxs in split_blocks(len(block_names), num_stages)]
     first = nodes[block_names[0]]
-    heads = (first.op.num_heads, first.op.kv_heads)
+    d_model = first.out_spec.shape[-1]
+
+    def geometry(op):
+        return (op.num_heads, op.kv_heads,
+                getattr(op, "head_dim", None) or d_model // op.num_heads)
+
+    heads = geometry(first.op)
     stats = tuple(first.op.decode_stats)
     for nm in block_names:
         op = nodes[nm].op
-        if (op.num_heads, op.kv_heads) != heads:
+        if op.memory != first.op.memory:
+            raise ValueError(
+                f"{nm} keeps a {op.memory}, block_0 a {first.op.memory}: "
+                "one format serves every block of a graph")
+        if geometry(op) != heads:
             raise ValueError(
                 f"{nm} has heads ({op.num_heads}, kv {op.kv_heads}) "
                 f"!= block_0's ({heads[0]}, {heads[1]}); the "
@@ -166,9 +272,9 @@ def decoder_parts(graph: LayerGraph, num_stages: int,
             raise ValueError(
                 f"{nm} sows {op.decode_stats}, block_0 {stats}: one "
                 "ledger serves every block")
-    d_model = first.out_spec.shape[-1]
     return DecoderParts(
-        embed_op=embed_op, block_names=block_names, d_model=d_model, num_heads=heads[0], kv_heads=heads[1],
-        head_dim=d_model // heads[0],
+        embed_op=embed_op, block_names=block_names, d_model=d_model,
+        num_heads=heads[0], kv_heads=heads[1], head_dim=heads[2],
         vocab=nodes["lm_head"].out_spec.shape[-1], max_len=max_len,
-        stage_blocks=stage_blocks, decode_stats=stats)
+        stage_blocks=stage_blocks, decode_stats=stats,
+        memory=first.op.memory)

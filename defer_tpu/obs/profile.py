@@ -78,7 +78,8 @@ DECODE_PHASES = ("prefill", "dispatch", "sync", "scatter", "emit")
 
 #: after a generation's last chunk, where the ring's blocks sow per-step
 #: statistics (``DecoderBlock.decode_stats``; today the routed experts'
-#: ``decode.moe.*`` counters): the one fetch of their device-side sums
+#: ``decode.moe.*`` counters and the retention blocks'
+#: ``decode.retention.updates``): the one fetch of their device-side sums
 DECODE_STATS_PHASES = ("moe_stats",)
 
 #: the front door's per-request phase on the client's reader thread

@@ -26,10 +26,8 @@ leading axis of ``groups + 1`` (the ring's round-robin groups and one
 scratch group) and one more position, the scratch row.
 
 The *state* of several layers is a dict of tuples, one buffer a layer
-under each key: the layers are never stacked into one array, because
-XLA:TPU wraps a write into a value that large in copies of all of it
-(docs/DECODE_CLIFF.md).  A holder may keep entries of its own beside
-the format's in the same dict; the format passes them through.
+under each key, never stacked (``ops/layered.py``, which the retention
+state's format shares).
 
 **The three writes**, each the operation its caller's positions make
 cheapest (docs/DECODE_CLIFF.md):
@@ -63,6 +61,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .layered import LayeredState
 
 #: what a lane row holds: the positions of a window of the row-writer,
 #: and the step by which a block of the attention grows
@@ -391,9 +391,10 @@ def attend_einsum(q, item: dict, pos):
 
 
 @dataclasses.dataclass(frozen=True)
-class KVCacheFormat:
+class KVCacheFormat(LayeredState):
     """One layer's cache, described: what both decode engines build
-    their buffers from and write and read them through."""
+    their buffers from and write and read them through (``zeros``,
+    ``layer`` and ``with_layer`` are ``ops/layered.py``'s)."""
 
     kv_heads: int
     head_dim: int
@@ -417,6 +418,16 @@ class KVCacheFormat:
         """Where a prefill's bubble writes: a group nothing reads."""
         return self.groups
 
+    def decode_slot(self, valid, pos):
+        """Where a ring step's row goes: position ``pos``, or for a
+        bubble (``valid`` false) the scratch row."""
+        return jnp.where(valid, pos, self.scratch_position)
+
+    def prefill_slot(self, valid, group):
+        """Where a ring prefill's rows go: group ``group``, or for a
+        bubble the scratch group."""
+        return jnp.where(valid, group, self.scratch_group)
+
     # -- buffers ---------------------------------------------------------
 
     def buffers(self, batch: int) -> dict[str, jax.ShapeDtypeStruct]:
@@ -433,28 +444,10 @@ class KVCacheFormat:
             out["ks"] = out["vs"] = jax.ShapeDtypeStruct(scales, jnp.float32)
         return out
 
-    def zeros(self, batch: int, layers: int, lead: tuple = ()) -> dict:
-        """The empty state of ``layers`` layers: a tuple of buffers under
-        each key, each behind the holder's own axes ``lead``."""
-        return {key: tuple(jnp.zeros(lead + s.shape, s.dtype)
-                           for _ in range(layers))
-                for key, s in self.buffers(batch).items()}
-
     @property
     def keys(self) -> tuple:
         """The format's entries of a state (any other is its holder's)."""
         return ("k", "v", "ks", "vs") if self.quantized else ("k", "v")
-
-    def layer(self, state: dict, l: int) -> dict:
-        """Layer ``l``'s buffers out of a state."""
-        return {key: state[key][l] for key in self.keys}
-
-    @staticmethod
-    def with_layer(state: dict, l: int, layer: dict) -> dict:
-        """``state`` with layer ``l``'s buffers replaced."""
-        return dict(state, **{
-            key: state[key][:l] + (buf,) + state[key][l + 1:]
-            for key, buf in layer.items()})
 
     # -- rows ------------------------------------------------------------
 

@@ -1,4 +1,4 @@
-"""Pipelined autoregressive decoding with per-stage KV caches.
+"""Pipelined autoregressive decoding with per-stage sequence memory.
 
 The inference engine (:mod:`defer_tpu.runtime.spmd`) streams independent
 inputs through the stage ring; generation is harder — token t+1 of a
@@ -14,13 +14,17 @@ TPU-native design, one SPMD program:
   * Weights: each device materializes only its stage's parameters from a
     stage-sharded flat buffer (same scheme as ``SpmdPipeline``), stored in
     the compute dtype.
-  * KV caches: per device, one resident buffer a local block and key,
-    held and touched only through the cache's format
-    (``ops/kv_cache.py``, which describes the layout): a step writes one
-    row a block in place, every sequence of the group at one position,
-    and attends over the group's live rows where they lie; warmup bubbles
-    write the format's scratch row and prefill bubbles its scratch
-    group, so no masked read-modify-write of the cache is ever needed.
+  * Sequence memory: per device, one resident buffer a local block and
+    key, held and touched only through the format the blocks name
+    (``DecoderBlock.memory_format``).  A KV cache (``ops/kv_cache.py``,
+    which describes the layout): a step writes one row a block in
+    place, every sequence of the group at one position, and attends
+    over the group's live rows where they lie; warmup bubbles write the
+    format's scratch row and prefill bubbles its scratch group, so no
+    masked read-modify-write of the cache is ever needed.  A retention
+    state (``ops/retention.py``): of fixed size, read and rewritten
+    whole each step; a bubble is its identity update, so it has neither
+    scratch.
   * The ring carry is one ``[mb, d]`` float32 buffer per device: stage
     activations in flight, and — on the wrap link from the last stage back
     to stage 0 (the reference's node->dispatcher link,
@@ -39,9 +43,9 @@ TPU-native design, one SPMD program:
 
 Scope: stage-axis-only mesh and the decoder-model contract of
 ``models/decoder.py`` (``embeddings`` / ``block_i`` / ``final_ln`` /
-``lm_head``; blocks with ``decode_qkv`` / ``decode_finish`` /
-``apply_with_kv``; an embedding with ``embed_at``): the ring asks a
-block for nothing else, whatever its family.  Prompts
+``lm_head``; blocks with ``memory_format`` / ``decode`` / ``prefill``;
+an embedding with ``embed_at``): the ring asks a block for nothing
+else, whatever its family and whatever memory it keeps.  Prompts
 are processed either at decode rate (teacher forcing inside the scan,
 the default) or by the fused full-sequence
 pipelined prefill (``generate(..., prefill=True)``): each group's whole
@@ -63,7 +67,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
 from ..obs import REGISTRY, span
-from ..ops import kv_cache as _kv_cache
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
@@ -164,15 +167,21 @@ class PipelinedDecoder:
                 names += ["final_ln", "lm_head"]
             stage_param_names.append(names)
         self._stage_param_names = stage_param_names
-        #: per block, the parameter subtrees that ride beside the flat row
-        #: as stage-sharded arguments of their own
-        #: (``DecoderBlock.stage_arg_keys``): a leaf sliced out of the
-        #: 1-D row is laid out anew by the compiled program, a copy that
-        #: 0.4 GB of experts a layer cannot afford.  Under W8A16 every
-        #: leaf rides the quantized rows.
+        #: per node, the parameter subtrees that ride beside the flat row
+        #: as stage-sharded arguments of their own (the op's
+        #: ``stage_arg_keys``): a leaf sliced out of the 1-D row is laid
+        #: out anew by the compiled program, a copy that 0.4 GB of
+        #: experts a layer, or a 1.6 GB embedding, cannot afford.  Under
+        #: W8A16 every leaf rides the quantized rows.
         self._own_keys = {
             nm: () if self.weight_quant
-            else tuple(nodes[nm].op.stage_arg_keys) for nm in block_names}
+            else tuple(getattr(nodes[nm].op, "stage_arg_keys", ()))
+            for names in stage_param_names for nm in names}
+        #: the nodes outside the blocks that keep such leaves, with the
+        #: one stage that holds each
+        self._own_ends = {nm: s for s, names in enumerate(stage_param_names)
+                          for nm in names
+                          if nm not in block_names and self._own_keys[nm]}
 
         # weights live in the compute dtype (the runtime/spmd.py recipe):
         # bf16 deployments read 2 bytes/param from HBM per decode step with
@@ -188,12 +197,25 @@ class PipelinedDecoder:
         self._wspec_tree = jax.tree.map(
             lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
 
-        #: one local block's cache (the state holds l_max of each), n
-        #: groups of mb sequences; looked up on the module when the
-        #: decoder is built, so a test can put another format in its place
-        self.kv_format = _kv_cache.KVCacheFormat(
-            self.num_kv_heads, self.head_dim, max_len, self.compute_dtype,
+        #: one local block's memory (the state holds l_max of each), n
+        #: groups of mb sequences, in the format the blocks name
+        self.state_format = nodes[block_names[0]].op.memory_format(
+            self.head_dim, max_len, self.compute_dtype,
             quantized=kv_cache == "int8", groups=n)
+        #: the kind of memory that is (``DecoderBlock.memory``)
+        self.memory = parts.memory
+        if beam_width > 1 and self.memory != "kv_cache":
+            raise ValueError(
+                f"beam_width={beam_width}: beam search re-parents a "
+                f"sequence's memory at every expansion, and these blocks "
+                f"keep a {self.memory} "
+                f"({type(self.state_format).__name__}), which cannot "
+                "hand one sequence's memory to another")
+        REGISTRY.gauge(f"decode.{self.memory}.state_bytes").set(
+            n * self.state_format.state_bytes(mb, self.l_max))
+        #: the newest generation's state as it left it (device buffers;
+        #: dropped when the next generation begins)
+        self.state = None
         #: ring-buffer width: beam mode adds one column carrying each
         #: row's parent-beam index around the ring alongside the token id
         self._ring_width = self.d_model + (1 if beam_width > 1 else 0)
@@ -249,22 +271,19 @@ class PipelinedDecoder:
                 "s": flatbuf.stack_rows(sflats, np.dtype(np.float32))}
 
     def _pack_own(self, params) -> tuple:
-        """The leaves kept out of the flat rows, placed: per local block
-        ``l`` a ``{key: subtree}`` whose leaves are ``[N, ...]``, stage
-        ``s``'s part being its ``l``-th block's (zeros where a stage has
-        fewer blocks).  One leaf at a time goes host -> device, so the
-        host never holds a second copy of all of them; ``()`` when no
-        block names any."""
-        if not any(self._own_keys.values()):
-            return ()
+        """The leaves kept out of the flat rows, placed: ``(own, ends)``.
+        ``own`` is per local block ``l`` a ``{key: subtree}`` whose
+        leaves are ``[N, ...]``, stage ``s``'s part being its ``l``-th
+        block's (zeros where a stage has fewer blocks), ``()`` when no
+        block names any; ``ends`` the same by name for the nodes outside
+        the blocks (zeros on every stage but the node's own).  One leaf
+        at a time goes host -> device, so the host never holds a second
+        copy of all of them."""
         wdt = self._wdt
-        own = []
-        for l in range(self.l_max):
-            # stage s's l-th block, or a stand-in (zeroed) where it has
-            # fewer: every leaf is [N, ...] whatever the split
-            names = [b[min(l, len(b) - 1)] for b in self.stage_blocks]
-            real = [l < len(b) for b in self.stage_blocks]
 
+        def placed(names, real):
+            """The own leaves of ``names`` (one a stage), stacked
+            ``[N, ...]``; a stage whose ``real`` is false gets zeros."""
             def place(*per_stage):
                 rows = [np.asarray(a).astype(wdt, copy=False) if ok
                         else np.zeros(np.shape(a), wdt)
@@ -274,30 +293,48 @@ class PipelinedDecoder:
                 return jax.device_put(stacked, NamedSharding(
                     self.mesh, P(STAGE_AXIS, *(None,) * (stacked.ndim - 1))))
 
-            own.append(jax.tree.map(place, *(
+            return jax.tree.map(place, *(
                 {k: params[nm][k] for k in self._own_keys[nm]}
-                for nm in names)))
-        return tuple(own)
+                for nm in names))
+
+        own = []
+        if any(self._own_keys[nm] for nm in self.block_names):
+            for l in range(self.l_max):
+                # stage s's l-th block, or a stand-in (zeroed) where it
+                # has fewer: every leaf is [N, ...] whatever the split
+                own.append(placed(
+                    [b[min(l, len(b) - 1)] for b in self.stage_blocks],
+                    [l < len(b) for b in self.stage_blocks]))
+        ends = {nm: placed([nm] * self.num_stages,
+                           [s == at for s in range(self.num_stages)])
+                for nm, at in self._own_ends.items()}
+        return tuple(own), ends
 
     def _place_weights(self, params, *, init: bool):
         """``params`` on the mesh as the compiled programs take them: the
         flat rows alone, or ``{"flat": rows, "own": leaves}`` when blocks
-        keep leaves of their own."""
+        keep leaves of their own (and ``"ends"`` when other nodes do)."""
         flat = jax.device_put(self._pack_wbuf(params, init=init),
                               NamedSharding(self.mesh, P(STAGE_AXIS, None)))
         if not init and any(self._own_keys.values()):
-            for blocks in self.stage_blocks:
-                for l, nm in enumerate(blocks):
-                    got = jax.tree.map(np.shape, {
-                        k: params[nm][k] for k in self._own_keys[nm]})
-                    want = jax.tree.map(lambda a: a.shape[1:],
-                                        self._w["own"][l])
-                    if got != want:
-                        raise ValueError(
-                            f"reweight: {nm}'s leaves outside the flat "
-                            f"rows are {got}, deployed {want}")
-        own = self._pack_own(params)
-        return {"flat": flat, "own": own} if own else flat
+            deployed = {nm: self._w["own"][l]
+                        for blocks in self.stage_blocks
+                        for l, nm in enumerate(blocks)
+                        if self._own_keys[nm]}
+            deployed.update(self._w.get("ends", {}))
+            for nm, leaves in deployed.items():
+                got = jax.tree.map(np.shape, {
+                    k: params[nm][k] for k in self._own_keys[nm]})
+                want = jax.tree.map(lambda a: a.shape[1:], leaves)
+                if got != want:
+                    raise ValueError(
+                        f"reweight: {nm}'s leaves outside the flat "
+                        f"rows are {got}, deployed {want}")
+        own, ends = self._pack_own(params)
+        if not own and not ends:
+            return flat
+        w = {"flat": flat, "own": own}
+        return dict(w, ends=ends) if ends else w
 
     def reweight(self, params) -> None:
         """Install fresh weights — no recompile, caches untouched.
@@ -315,8 +352,12 @@ class PipelinedDecoder:
         if isinstance(w_local, dict) and "own" in w_local:
             p = flatbuf.unpack_leaves(w_local["flat"], self._wmeta[s],
                                       self._wtreedef[s])
-            for l, nm in enumerate(self.stage_blocks[s]):
-                p[nm] = dict(p[nm], **w_local["own"][l])
+            if w_local["own"]:
+                for l, nm in enumerate(self.stage_blocks[s]):
+                    p[nm] = dict(p[nm], **w_local["own"][l])
+            for nm, leaves in w_local.get("ends", {}).items():
+                if self._own_ends[nm] == s:
+                    p[nm] = dict(p[nm], **leaves)
             return p
         if not self.weight_quant:
             return flatbuf.unpack_leaves(w_local, self._wmeta[s],
@@ -338,7 +379,7 @@ class PipelinedDecoder:
         is_first, is_last = s == 0, s == n - 1
         block_ops = [nodes[nm].op for nm in self.stage_blocks[s]]
         embed_op = self.embed_op
-        fmt = self.kv_format
+        fmt = self.state_format
         beam = self.beam_width
         mb = self.microbatch
         stats = self._stat_names
@@ -347,12 +388,13 @@ class PipelinedDecoder:
                    first_ids, first_pos):
             p = self._stage_params(s, w_local)
             # bubble steps (pos < 0 during warmup skew, or pos >= max_len
-            # on chunk-overshoot steps past the requested generation) write
-            # the cache scratch row and attend over nothing real; their
-            # outputs are never read (host drops them by schedule index)
+            # on chunk-overshoot steps past the requested generation) go
+            # where the format sends a bubble (a cache's scratch row; a
+            # state's identity update); their outputs are never read
+            # (host drops them by schedule index)
             valid = jnp.logical_and(pos >= 0, pos < self.max_len)
             safe_pos = jnp.clip(pos, 0, self.max_len - 1)
-            write_pos = jnp.where(valid, safe_pos, fmt.scratch_position)
+            slot = fmt.decode_slot(valid, safe_pos)
 
             if beam > 1:
                 # re-parent this group's cache rows before appending the
@@ -388,19 +430,15 @@ class PipelinedDecoder:
 
             for l, (nm, op) in enumerate(zip(self.stage_blocks[s],
                                              block_ops)):
-                # write the new rows in place (one position of one group
-                # of one block), then attend over the group's live rows
-                # where they lie: nothing the size of an item is cut
-                # out of a buffer or written back
-                q, k_new, v_new = op.decode_qkv(p[nm], x, safe_pos)
-                layer = fmt.write_position(
-                    fmt.layer(caches, l), fmt.rows(k_new, v_new),
-                    write_pos, group=g)
-                caches = fmt.with_layer(caches, l, layer)
+                # the block's step against its layer's buffers where
+                # they lie (a cache: one row written in place, then the
+                # group's live rows attended over; a state: updated and
+                # read in place): nothing the size of an item is cut out
+                # of a buffer or written back
                 sown = {} if stats else None
-                x = op.decode_finish(
-                    p[nm], x, fmt.attend(q, layer, write_pos, group=g),
-                    sow=sown)
+                x, layer = op.decode(p[nm], x, fmt.layer(caches, l),
+                                     safe_pos, fmt, slot, g, sown)
+                caches = fmt.with_layer(caches, l, layer)
                 if stats:
                     step = jnp.stack([sown[k] for k in stats])
                     caches = dict(caches, stats=caches["stats"] + jnp.where(
@@ -470,10 +508,11 @@ class PipelinedDecoder:
 
         The group's full [mb, plen] prompt flows through the stages like
         one inference microbatch; each block runs full-sequence causal
-        attention (``apply_with_kv``) and bulk-writes cache rows
-        ``0..plen-1``; the last stage emits the first generated token
-        (position ``plen``).  Bubble steps (g outside [0, n)) write the
-        format's scratch group.
+        attention (``DecoderBlock.prefill``) and bulk-writes cache rows
+        ``0..plen-1`` (or leaves the state after them); the last stage
+        emits the first generated token (position ``plen``).  Bubble
+        steps (g outside [0, n)) go where the format sends them: a
+        cache's scratch group, a state's identity update.
         """
         n = self.num_stages
         nodes = self.graph.nodes
@@ -481,13 +520,13 @@ class PipelinedDecoder:
         mb, d = self.microbatch, self.d_model
         is_first, is_last = s == 0, s == n - 1
         embed_op = self.embed_op
-        fmt = self.kv_format
+        fmt = self.state_format
 
         def branch(w_local, a, caches, prompt, g, seed, temp):
             p = self._stage_params(s, w_local)
             valid = jnp.logical_and(g >= 0, g < n)
             safe_g = jnp.clip(g, 0, n - 1)
-            write_g = jnp.where(valid, safe_g, fmt.scratch_group)
+            slot = fmt.prefill_slot(valid, safe_g)
 
             if is_first:
                 ids = lax.dynamic_slice(prompt, (safe_g, 0, 0),
@@ -497,9 +536,9 @@ class PipelinedDecoder:
                 x = a.reshape(mb, plen, d).astype(cd)
 
             for l, nm in enumerate(self.stage_blocks[s]):
-                x, k, v = nodes[nm].op.apply_with_kv(p[nm], x)
-                caches = fmt.with_layer(caches, l, fmt.write_prefix(
-                    fmt.layer(caches, l), k, v, write_g))
+                x, layer = nodes[nm].op.prefill(
+                    p[nm], x, fmt.layer(caches, l), fmt, slot)
+                caches = fmt.with_layer(caches, l, layer)
 
             if is_last:
                 h = nodes["final_ln"].op.apply(p["final_ln"], x[:, -1])
@@ -526,7 +565,7 @@ class PipelinedDecoder:
         # one buffer a local block, never one array of the whole stack
         specs = {key: (P(STAGE_AXIS, *(None,) * len(buf.shape)),)
                  * self.l_max
-                 for key, buf in self.kv_format.buffers(
+                 for key, buf in self.state_format.buffers(
                      self.microbatch).items()}
         if self.beam_width > 1:
             # per-group cumulative beam scores; only the LAST stage's
@@ -575,7 +614,7 @@ class PipelinedDecoder:
                        **ring_jit_kwargs(self.mesh.devices))
 
     def _init_state(self):
-        """Fresh sharded pipeline state: ring carry + empty KV caches.
+        """Fresh sharded pipeline state: ring carry + empty memory.
 
         The zero-fill programs are jitted ONCE and cached — a fresh lambda
         per call would recompile (~0.4 s each) on every ``generate``.
@@ -588,7 +627,7 @@ class PipelinedDecoder:
                 self._state_specs())
 
             def zeros():
-                caches = self.kv_format.zeros(mb, self.l_max, lead=(n,))
+                caches = self.state_format.zeros(mb, self.l_max, lead=(n,))
                 if self.beam_width > 1:
                     caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
                 if self._stat_names:
@@ -679,15 +718,19 @@ class PipelinedDecoder:
         """``decode.attend.live_block_share`` for one dispatch: where
         each stage's group stands at each of its steps is the schedule's
         own arithmetic (``device_decode``), so the host reckons what
-        the kernel will read before the device has run any of it."""
+        the kernel will read before the device has run any of it.  Only
+        a cache has positions to skip, and the gauge."""
+        fmt = self.state_format
+        if self.memory != "kv_cache":
+            return
         n = self.num_stages
         t = t0 + np.arange(chunk_steps)[:, None]
         rel = t - np.arange(n)[None, :]
         pos = start + rel // n
         real = (rel >= 0) & (t < num_steps) & (pos >= 0) \
             & (pos < self.max_len)
-        read, held = self.kv_format.live_block_share(
-            np.where(real, pos, self.kv_format.scratch_position))
+        read, held = fmt.live_block_share(
+            np.where(real, pos, fmt.scratch_position))
         self._attend_blocks[0] += read
         self._attend_blocks[1] += held
         REGISTRY.gauge("decode.attend.live_block_share").set(
@@ -867,6 +910,7 @@ class PipelinedDecoder:
         plen_s = jnp.int32(plen)
         seed_s = jnp.uint32(seed)
         temp_s = jnp.float32(temperature)
+        self.state = None       # let the last generation's buffers go
         a, caches = self._init_state()
 
         if prefill:
@@ -954,6 +998,7 @@ class PipelinedDecoder:
             with span("decode", "scatter"):
                 self._gather_into(out3, ids_np, i * chunk_steps,
                                   t_tok, start, p0)
+        self.state = caches
         out = out3.reshape(n * mb, t_tok)[:b]
         if eos_id is not None:
             # freeze everything after each sequence's first generated EOS

@@ -75,7 +75,7 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
     # the format's buffers behind the ring's own stage axis
-    buffers = dec.kv_format.buffers(mb)
+    buffers = dec.state_format.buffers(mb)
     caches = {key: (arg((1,) + buf.shape, buf.dtype,
                         P(STAGE_AXIS, *(None,) * len(buf.shape))),)
               * dec.l_max

@@ -78,7 +78,7 @@ def main() -> int:
     caches = {key: (arg((1,) + buf.shape, buf.dtype,
                         P(STAGE_AXIS, *(None,) * len(buf.shape))),)
               * dec.l_max
-              for key, buf in dec.kv_format.buffers(MB).items()}
+              for key, buf in dec.state_format.buffers(MB).items()}
     caches["stats"] = arg((1, len(dec._stat_names)), jnp.int32,
                           P(STAGE_AXIS, None))
     i32, u32, f32 = (arg((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
@@ -128,7 +128,7 @@ def main() -> int:
                 "output_gb": m.output_size_in_bytes / 1e9,
                 "alias_gb": m.alias_size_in_bytes / 1e9}
 
-    shape = dec.kv_format.buffers(MB)["k"].shape
+    shape = dec.state_format.buffers(MB)["k"].shape
     cache_ops = count_cache_ops(computations(text), shape[1:], shape)
     row = {"device_kind": topo.devices[0].device_kind,
            "prefill": mem(prefill), "decode": mem(decode),

@@ -213,7 +213,7 @@ def test_gqa_decode_matches_references(prompt):
     dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                            max_len=MAX_LEN)
     assert dec.num_kv_heads == 1 and dec.num_heads == 2
-    assert dec.kv_format.kv_heads == 1       # cache halved vs MHA
+    assert dec.state_format.kv_heads == 1       # cache halved vs MHA
     got = dec.generate(prompt, max_new_tokens=8)
     want = incremental_greedy(graph, params, prompt, 5 + 8, MAX_LEN)
     np.testing.assert_array_equal(got, want)
@@ -757,7 +757,7 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
                            kv_cache=kv_cache, beam_width=beam)
     body = _decode_scan_body(dec, 2 * num_stages)
     buffers = {buf.shape for buf in
-               dec.kv_format.buffers(dec.microbatch).values()}
+               dec.state_format.buffers(dec.microbatch).values()}
     assert len(buffers) == (2 if kv_cache == "int8" else 1)
     stacked = {(dec.l_max,) + sh for sh in buffers}
     rows = groups = 0

@@ -276,7 +276,7 @@ def test_a_step_cuts_no_item_out_of_a_cache_buffer(model, engine):
             dec._w, jnp.zeros((2, 3, 5), jnp.int32), i32, i32, i32,
             jnp.uint32(0), jnp.float32(0), jnp.zeros((2, 3), jnp.int32),
             i32, i32, a, caches)
-        shape = dec.kv_format.buffers(3)["k"].shape[1:]
+        shape = dec.state_format.buffers(3)["k"].shape[1:]
         layers = dec.l_max * 2          # a call a block and a stage
     else:
         eng = ContinuousBatchEngine(graph, params, num_stages=2, width=3)
@@ -482,7 +482,7 @@ def test_the_ring_runs_on_a_format_with_positions_leading(
     _, want = tokens()
     monkeypatch.setattr(kv_cache, "KVCacheFormat", PositionsLeading)
     dec, got = tokens()
-    assert isinstance(dec.kv_format, PositionsLeading)
+    assert isinstance(dec.state_format, PositionsLeading)
     _, caches = dec._init_state()
     assert caches["k"][0].shape[1] == 24 + 1     # [stage, positions, ...]
     for w, g in zip(want, got):
