@@ -481,14 +481,18 @@ def phase_decode(run: Run) -> None:
         kernels = lowered_kernel_count(
             pfn, dec._w, jnp.zeros((n, sz.lm_microbatch, plen), jnp.int32),
             jnp.uint32(0), jnp.float32(0.0), caches)
+        # the weights are a tree since the GPT family's nodes name their
+        # leaves stage-sharded arguments of their own: every leaf is
+        # sharded alike, so one stands for all
+        w_ids = shard_device_ids(jax.tree.leaves(dec._w)[0])
         ph.note(mosaic_kernels_in_prefill_program=kernels,
-                weight_shard_device_ids=shard_device_ids(dec._w),
+                weight_shard_device_ids=w_ids,
                 cache_shard_device_ids=shard_device_ids(caches["k"][0]))
         if sz.expect_mosaic and kernels < 1:
             raise AssertionError(
                 "the prefill program holds no TPU custom call: flash "
                 "attention was not selected")
-        if len(set(shard_device_ids(dec._w))) != n:
+        if len(set(w_ids)) != n:
             raise AssertionError("decoder weights not on n devices")
 
         # single-program greedy reference: the whole-graph forward on a

@@ -11,9 +11,12 @@ on different in-flight inputs" (SURVEY.md §0) transposed to token time.
 
 TPU-native design, one SPMD program:
 
-  * Weights: each device materializes only its stage's parameters from a
-    stage-sharded flat buffer (same scheme as ``SpmdPipeline``), stored in
-    the compute dtype.
+  * Weights: each device holds only its stage's parameters, stored in
+    the compute dtype: the leaves a node names in ``stage_arg_keys`` as
+    stage-sharded arguments of their own, in their own shapes (a leaf
+    cut out of a flat row is laid out anew by the compiled program,
+    every step), the rest in a stage-sharded flat buffer (the scheme of
+    ``SpmdPipeline``).
   * Sequence memory: per device, one resident buffer a local block and
     key, held and touched only through the format the blocks name
     (``DecoderBlock.memory_format``).  A KV cache (``ops/kv_cache.py``,
@@ -192,6 +195,17 @@ class PipelinedDecoder:
         self._wmeta, self._wtreedef = [], []
         self._smeta: list[list[tuple[int, int]]] = []  # per-leaf scale slots
         self._w = self._place_weights(params, init=True)
+        # how much of the deployment rides the flat rows, and how much
+        # beside them: leaves as packed, before the device's tiling
+        # pads them, stand-in blocks and other stages' ends not counted
+        held = sum(m[1] for meta in self._wmeta for m in meta)
+        REGISTRY.gauge("decode.weights.row_bytes").set(
+            held + 4 * sum(size for sm in self._smeta for _, size in sm)
+            if self.weight_quant else held * np.dtype(wdt).itemsize)
+        REGISTRY.gauge("decode.weights.own_bytes").set(
+            np.dtype(wdt).itemsize * sum(
+                np.size(leaf) for nm, keys in self._own_keys.items()
+                for leaf in jax.tree.leaves([params[nm][k] for k in keys])))
         #: shard_map spec for the weight argument (a pytree under W8A16
         #: or with leaves of their own)
         self._wspec_tree = jax.tree.map(
@@ -316,20 +330,21 @@ class PipelinedDecoder:
         keep leaves of their own (and ``"ends"`` when other nodes do)."""
         flat = jax.device_put(self._pack_wbuf(params, init=init),
                               NamedSharding(self.mesh, P(STAGE_AXIS, None)))
-        if not init and any(self._own_keys.values()):
-            deployed = {nm: self._w["own"][l]
-                        for blocks in self.stage_blocks
-                        for l, nm in enumerate(blocks)
-                        if self._own_keys[nm]}
-            deployed.update(self._w.get("ends", {}))
-            for nm, leaves in deployed.items():
-                got = jax.tree.map(np.shape, {
-                    k: params[nm][k] for k in self._own_keys[nm]})
-                want = jax.tree.map(lambda a: a.shape[1:], leaves)
-                if got != want:
-                    raise ValueError(
-                        f"reweight: {nm}'s leaves outside the flat "
-                        f"rows are {got}, deployed {want}")
+        # the leaves outside the rows are held to what was deployed as
+        # the rows' are (``flatbuf.check_layout``): shapes, and the
+        # types they had before the cast to the compute type
+        layout = {nm: jax.tree.map(
+            lambda a: (np.shape(a), np.dtype(
+                a.dtype if hasattr(a, "dtype") else np.asarray(a).dtype)),
+            {k: params[nm][k] for k in keys})
+            for nm, keys in self._own_keys.items() if keys}
+        if init:
+            self._own_layout = layout
+        for nm, got in layout.items():
+            if got != self._own_layout[nm]:
+                raise ValueError(
+                    f"reweight: {nm}'s leaves outside the flat rows are "
+                    f"{got}, deployed {self._own_layout[nm]}")
         own, ends = self._pack_own(params)
         if not own and not ends:
             return flat

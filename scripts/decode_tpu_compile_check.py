@@ -16,14 +16,19 @@ all of them): with the attention a kernel over the buffers as they lie
 spending chip time on a change to how the ring holds its caches:
 
     env JAX_PLATFORMS=cpu python scripts/decode_tpu_compile_check.py \\
-        [layers d_model heads sequences max_len token_chunk]
+        [layers d_model heads sequences max_len token_chunk stages]
 
 (default: the cliff row, ``12 768 12 64 512 32``; the benchmark's batch
 cell is ``24 1600 25 8 768 4``, gpt2-xl at full depth ``48 1600 25 8 768
-4``).  ~30 s at the default; one JSON line; exit 0 when the compiled
-text writes rows in place and holds no whole-cache copy, no item-sized
-slice or copy inside a step and no whole-buffer conversion around the
-loop, 1 otherwise (2 when the program does not fit the chip).  A process
+4``; the four-chip cell is ``48 1600 25 2 768 4 4``, ``sequences`` being
+a group's, under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
+~30 s at the default; one JSON line; exit 0 when the compiled text
+writes rows in place and holds no whole-cache copy, no item-sized slice
+or copy inside a step, no whole-buffer conversion around the loop and,
+inside the loop, no copy of a weight matrix (a leaf cut out of the flat
+weight row and laid out anew every step: the GPT family's nodes name
+every leaf an argument of its own since PR 32), 1 otherwise (2 when the
+program does not fit the chip).  A process
 of its own on purpose: loading the TPU's library takes a machine-wide
 lock (``/tmp/libtpu_lockfile``) that is held until the process ends, so
 this must not live in a long test run.
@@ -49,11 +54,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from defer_tpu.models import gpt
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
-from hlo_cache_ops import computations, count_cache_ops
+from hlo_cache_ops import computations, count_cache_ops, weight_copies
 
 
 def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
-         chunk=32) -> int:
+         chunk=32, stages=1) -> int:
     # a program compiled for a described chip cannot be read back from
     # the persistent cache without the chip: keep it out
     jax.config.update("jax_enable_compilation_cache", False)
@@ -63,20 +68,24 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
     graph = gpt(layers, d_model, heads, max(max_len, 512), vocab=50257)
     params = jax.tree.map(lambda s: np.zeros(s.shape, np.float32),
                           jax.eval_shape(graph.init, jax.random.key(0)))
-    dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=mb,
+    dec = PipelinedDecoder(graph, params, num_stages=stages, microbatch=mb,
                            max_len=max_len, compute_dtype=jnp.bfloat16)
     # the program as the chip would get it: same function, the mesh made
-    # of the described device, shapes in place of arrays
-    dec.mesh = Mesh(np.array(topo.devices[:1]).reshape(dec.mesh.devices.shape),
-                    dec.mesh.axis_names)
+    # of the described devices, shapes in place of arrays
+    dec.mesh = Mesh(np.array(topo.devices[:stages]).reshape(
+        dec.mesh.devices.shape), dec.mesh.axis_names)
 
     def arg(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
+    # the weights as the ring placed them: the flat row and the leaves
+    # beside it, each behind the stage axis
+    w = jax.tree.map(lambda a: arg(
+        a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1))), dec._w)
     # the format's buffers behind the ring's own stage axis
     buffers = dec.state_format.buffers(mb)
-    caches = {key: (arg((1,) + buf.shape, buf.dtype,
+    caches = {key: (arg((stages,) + buf.shape, buf.dtype,
                         P(STAGE_AXIS, *(None,) * len(buf.shape))),)
               * dec.l_max
               for key, buf in buffers.items()}
@@ -87,11 +96,10 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
     # and the program is the chip's
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         lowered = dec._build_decode_fn(chunk_steps, False, None).lower(
-            arg(dec._w.shape, dec._w.dtype, P(STAGE_AXIS, None)),
-            arg((1, mb, plen), jnp.int32, P(None, None, None)),
+            w, arg((stages, mb, plen), jnp.int32, P(None, None, None)),
             i32, i32, i32, arg((), jnp.uint32), arg((), jnp.float32),
-            arg((1, mb), jnp.int32, P(None, None)), i32, i32,
-            arg((1, mb, dec.d_model), jnp.float32,
+            arg((stages, mb), jnp.int32, P(None, None)), i32, i32,
+            arg((stages, mb, dec.d_model), jnp.float32,
                 P(STAGE_AXIS, None, None)), caches)
     try:
         compiled = lowered.compile()
@@ -102,10 +110,13 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     shape = buffers["k"].shape
+    comps = computations(text)
     row = {"device_kind": topo.devices[0].device_kind,
+           **weight_copies(comps, [leaf.shape for leaf in
+                                   jax.tree.leaves(params) if leaf.ndim > 1]),
            "whole_cache_copies": len(re.findall(
                r"remat_(?:un)?compressed[\w.]* = ", text)),
-           **count_cache_ops(computations(text), shape[1:], shape),
+           **count_cache_ops(comps, shape[1:], shape),
            "kernels": text.count('custom_call_target="tpu_custom_call"'),
            "argument_bytes": mem.argument_size_in_bytes,
            "temp_bytes": mem.temp_size_in_bytes}
@@ -116,7 +127,7 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
     print(json.dumps(row))
     return 0 if row["row_writes"] and not (
         row["whole_cache_copies"] or row["item_copies"]
-        or row["buffer_copies"]) else 1
+        or row["buffer_copies"] or row["weight_copies_in_loop"]) else 1
 
 
 if __name__ == "__main__":

@@ -1,5 +1,6 @@
-"""What the three ``*_tpu_compile_check.py`` scripts count in a compiled
-program's text: the operations that touch a KV cache buffer.
+"""What the ``*_tpu_compile_check.py`` scripts count in a compiled
+program's text: the operations that touch a KV cache buffer (and, at
+the end, those that copy a weight matrix).
 
 * a **row write** runs in place on its operand's buffer: the row-writer
   kernel (a custom call whose output aliases an operand) or a
@@ -58,6 +59,17 @@ def _dims(shape) -> list[int]:
     return sorted(int(d) for d in shape if int(d) != 1)
 
 
+def _fusion_bodies(comps: dict[str, list[str]]) -> set[str]:
+    """The computations that are fusions' bodies: their values live in
+    registers."""
+    return {m.group(1) for lines in comps.values() for line in lines
+            for m in [re.search(r"fusion\(.*calls=%?([\w.\-]+)", line)] if m}
+
+
+def _kind(op_name: str) -> str:
+    return re.sub(r"[.\d]+$", "", op_name)
+
+
 def count_cache_ops(comps: dict[str, list[str]], item_dims, buffer_dims=None,
                     within=None) -> dict:
     """Counts over the computations ``within`` (default: all but the
@@ -66,8 +78,7 @@ def count_cache_ops(comps: dict[str, list[str]], item_dims, buffer_dims=None,
     ``buffer_dims`` the whole buffer's where it has groups."""
     item = _dims(item_dims)
     whole = _dims(buffer_dims) if buffer_dims is not None else None
-    fused = {m.group(1) for lines in comps.values() for line in lines
-             for m in [re.search(r"fusion\(.*calls=%?([\w.\-]+)", line)] if m}
+    fused = _fusion_bodies(comps)
     row_writes, prefetches, items, buffers = 0, 0, [], []
     for name, lines in comps.items():
         if name in fused or (within is not None and name not in within):
@@ -97,10 +108,36 @@ def count_cache_ops(comps: dict[str, list[str]], item_dims, buffer_dims=None,
                 (buffers if got == whole else items).append(op_name)
 
     def kinds(names):
-        return sorted({re.sub(r"[.\d]+$", "", n) for n in names})
+        return sorted({_kind(n) for n in names})
 
     return {"row_writes": row_writes, "item_copies": len(items),
             "item_copy_kinds": kinds(items),
             "buffer_copies": len(buffers),
             "buffer_copy_kinds": kinds(buffers),
             "item_prefetches": prefetches}
+
+
+def weight_copies(comps: dict[str, list[str]], matrices) -> dict:
+    """The instructions, fusions' bodies and prefetches aside, that
+    *produce* an array of the size of one of ``matrices`` (shapes): a
+    leaf that rides the ring's flat weight row is cut out of it and laid
+    out anew inside the loop, every step; one handed over as an argument
+    of its own is only read.  ``in_loop`` counts those of the loop's
+    computations, ``per_dispatch`` the entry computation's (a layout
+    the loop wants otherwise than the caller holds it, converted once)."""
+    sizes = {tuple(_dims(shape)) for shape in matrices}
+    fused = _fusion_bodies(comps)
+    made = {"ENTRY": [], "loop": []}
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for m in filter(None, map(_INSTR.match, lines)):
+            op_name, _dtype, dims, opcode, rest = m.groups()
+            if opcode in _FREE or opcode in _ASYNC or "ConcatBitcast" in rest \
+                    or not dims or tuple(_dims(dims.split(","))) not in sizes:
+                continue
+            made["ENTRY" if name == "ENTRY" else "loop"].append(
+                _kind(op_name))
+    return {"weight_copies_in_loop": len(made["loop"]),
+            "weight_copy_kinds_in_loop": sorted(set(made["loop"])),
+            "weight_copies_per_dispatch": len(made["ENTRY"])}

@@ -61,6 +61,13 @@ def full_recompute_greedy(graph, params, prompt, t_tok):
     return cur
 
 
+def _weight_gauges():
+    """(bytes on the flat rows, bytes beside them) of the newest decoder."""
+    from defer_tpu.obs import REGISTRY
+    return (REGISTRY.gauge("decode.weights.row_bytes").value,
+            REGISTRY.gauge("decode.weights.own_bytes").value)
+
+
 @pytest.fixture(scope="module")
 def model():
     graph = gpt_tiny(seq_len=MAX_LEN, vocab=VOCAB)
@@ -74,7 +81,11 @@ def prompt():
     return rng.integers(0, VOCAB, size=(8, 5)).astype(np.int32)
 
 
-@pytest.mark.parametrize("num_stages,microbatch", [(4, 2), (2, 4), (1, 8)])
+# 3 stages: gpt_tiny's 4 layers split 1 / 2 / 1, so the stages with one
+# block get the zeroed stand-in for their second (and 8 prompts take two
+# pipeline fills of 6)
+@pytest.mark.parametrize("num_stages,microbatch", [(4, 2), (2, 4), (1, 8),
+                                                   (3, 2)])
 def test_pipelined_matches_incremental(model, prompt, num_stages, microbatch):
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=num_stages,
@@ -84,10 +95,11 @@ def test_pipelined_matches_incremental(model, prompt, num_stages, microbatch):
     np.testing.assert_array_equal(got, want)
 
 
-def test_pipelined_matches_full_recompute(model, prompt):
+@pytest.mark.parametrize("num_stages", [4, 3])
+def test_pipelined_matches_full_recompute(model, prompt, num_stages):
     graph, params = model
-    dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
-                           max_len=MAX_LEN)
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN)
     got = dec.generate(prompt, max_new_tokens=8)
     want = full_recompute_greedy(graph, params, prompt, 5 + 8)
     np.testing.assert_array_equal(got, want)
@@ -166,7 +178,7 @@ def test_eos_early_stop(model, prompt):
                                   ref[0, 5: 5 + stop + 1])
 
 
-@pytest.mark.parametrize("num_stages,microbatch", [(4, 2), (1, 8)])
+@pytest.mark.parametrize("num_stages,microbatch", [(4, 2), (1, 8), (3, 2)])
 def test_fused_prefill_matches_decode_rate(model, prompt, num_stages,
                                            microbatch):
     """prefill=True seeds the caches with the pipelined full-sequence pass;
@@ -299,12 +311,25 @@ def test_w8a16_weight_quant_decode(model, prompt):
     graph, params = model
     ref = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                            max_len=MAX_LEN)
+    ref_placed = sum(_weight_gauges())
+    assert ref_placed == sum(
+        leaf.nbytes for leaf in jax.tree.leaves(params))
     q = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                          max_len=MAX_LEN, weight_dtype="int8")
-    assert q._w["q"].dtype == jnp.int8
+    # the blocks' declarations are not read under W8A16: every leaf rides
+    # the quantized rows
+    assert set(q._w) == {"q", "s"} and q._w["q"].dtype == jnp.int8
+    assert _weight_gauges()[1] == 0
     # the weight stream is 1 byte/elem vs 4 (scales only matter for the
-    # tiny 1-D leaves; on real geometries they are ~1/last_dim overhead)
-    assert q._w["q"].nbytes == ref._w.nbytes // 4
+    # tiny 1-D leaves; on real geometries they are ~1/last_dim overhead):
+    # the int8 rows hold every leaf the f32 engine placed, on the row or
+    # beside it (zeroed stand-ins and padding aside)
+    held = sum(m[1] for meta in q._wmeta for m in meta)
+    assert held == ref_placed // 4
+    assert q._w["q"].nbytes == 4 * max(
+        sum(m[1] for m in meta) for meta in q._wmeta)
+    assert _weight_gauges()[0] == held + 4 * sum(
+        size for sm in q._smeta for _, size in sm)
     a = ref.generate(prompt, 8)
     b = q.generate(prompt, 8)
     assert (b[:, :5] == prompt).all()          # exact prompt echo
@@ -322,13 +347,15 @@ def test_w8a16_weight_quant_decode(model, prompt):
     assert len(q._decode_fns) + len(q._prefill_fns) == compiled
 
 
-def test_decoder_reweight_no_recompile(model, prompt):
+@pytest.mark.parametrize("num_stages", [4, 3])
+def test_decoder_reweight_no_recompile(model, prompt, num_stages):
     """Weights-only re-push on the decode engine: fresh params install
-    into the live flat buffer, compiled decode programs are reused, and
-    generations match the single-device oracle under the new weights."""
+    into the live weight arguments, compiled decode programs are reused,
+    and generations match the single-device oracle under the new
+    weights."""
     graph, params = model
-    dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
-                           max_len=MAX_LEN)
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN)
     a = dec.generate(prompt, 6)
     compiled_before = len(dec._decode_fns) + len(dec._prefill_fns)
 
@@ -345,15 +372,87 @@ def test_decoder_reweight_no_recompile(model, prompt):
     bad = dict(params2)
     bad["lm_head"] = {"w": np.zeros((2, 2), np.float32),
                       "b": np.zeros((2,), np.float32)}
-    with pytest.raises(ValueError, match="reweight"):
+    with pytest.raises(ValueError, match="reweight: lm_head"):
         dec.reweight(bad)
     # dtype drift with matching shapes must also be refused: the buffer
     # would otherwise blind-cast the values
     drift = dict(params)
     drift["lm_head"] = jax.tree.map(
         lambda a: np.asarray(a).astype(np.int32), params["lm_head"])
-    with pytest.raises(ValueError, match="reweight"):
+    with pytest.raises(ValueError, match="reweight: lm_head"):
         dec.reweight(drift)
+    # a refused reweight leaves the deployed weights where they were
+    np.testing.assert_array_equal(dec.generate(prompt, 6), a)
+
+
+@pytest.mark.parametrize("node,key", [("block_2", "qkv"), ("block_0", "fc1"),
+                                      ("embeddings", "wte")])
+def test_reweight_changed_matrices_and_wrong_shapes(model, prompt, node, key):
+    """The leaves a node keeps beside the flat rows (``stage_arg_keys``):
+    ``reweight`` with one changed gives what a fresh decoder on those
+    weights gives, through the programs already compiled, and one of
+    another shape is refused by its node's name."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=4,
+                           max_len=MAX_LEN)
+    a = dec.generate(prompt, 6, prefill=True)
+    compiled = len(dec._decode_fns) + len(dec._prefill_fns)
+    rng = np.random.default_rng(17)
+    changed = dict(params)
+    changed[node] = dict(params[node], **{key: jax.tree.map(
+        lambda x: x + 0.5 * rng.standard_normal(x.shape).astype(x.dtype),
+        params[node][key])})
+    dec.reweight(changed)
+    b = dec.generate(prompt, 6, prefill=True)
+    assert len(dec._decode_fns) + len(dec._prefill_fns) == compiled
+    fresh = PipelinedDecoder(graph, changed, num_stages=2, microbatch=4,
+                             max_len=MAX_LEN)
+    np.testing.assert_array_equal(b, fresh.generate(prompt, 6, prefill=True))
+    assert not np.array_equal(a, b)
+
+    wrong = dict(params)
+    wrong[node] = dict(params[node], **{key: jax.tree.map(
+        lambda x: np.zeros(x.shape + (2,), np.float32), params[node][key])})
+    with pytest.raises(ValueError, match=f"reweight: {node}'s leaves"):
+        dec.reweight(wrong)
+    np.testing.assert_array_equal(dec.generate(prompt, 6, prefill=True), b)
+
+
+@pytest.mark.parametrize("num_stages", [1, 3, 4])
+def test_gpt_weights_ride_beside_the_flat_row(model, num_stages):
+    """What GPT-2's nodes declare (``stage_arg_keys``) the ring keeps out
+    of the flat row, as stage-sharded arguments of their own: only
+    ``final_ln`` is left on it, and the two gauges say so."""
+    from defer_tpu.models.gpt import GptEmbedding, GptHead
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN)
+    assert set(dec._w) == {"flat", "own", "ends"}
+    assert len(dec._w["own"]) == dec.l_max
+    for leaves in dec._w["own"]:
+        assert set(leaves) == set(CausalTransformerBlock.stage_arg_keys) \
+            == set(params["block_0"])
+    assert set(dec._w["ends"]) == {"embeddings", "lm_head"}
+    assert set(dec._w["ends"]["embeddings"]) \
+        == set(GptEmbedding.stage_arg_keys) == set(params["embeddings"])
+    assert set(dec._w["ends"]["lm_head"]) \
+        == set(GptHead.stage_arg_keys) == set(params["lm_head"])
+    for leaf in jax.tree.leaves((dec._w["own"], dec._w["ends"])):
+        assert leaf.shape[0] == num_stages
+    # a stage with fewer blocks than the fullest holds a zeroed stand-in
+    for s, blocks in enumerate(dec.stage_blocks):
+        for l in range(dec.l_max):
+            w = np.asarray(dec._w["own"][l]["qkv"]["w"][s])
+            if l < len(blocks):
+                np.testing.assert_array_equal(
+                    w, np.asarray(params[blocks[l]]["qkv"]["w"]))
+            else:
+                assert not w.any()
+    row, own = _weight_gauges()
+    assert row == sum(leaf.nbytes
+                      for leaf in jax.tree.leaves(params["final_ln"]))
+    assert row + own == sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    assert dec._w["flat"].shape == (num_stages, row // 4)
 
 
 def test_defer_score_bucketed_short_sequence(model):
@@ -502,7 +601,7 @@ def reference_beam(graph, params, prompt, max_new, beam, max_len):
 
 
 @pytest.mark.parametrize("num_stages,microbatch,beam", [(4, 4, 2), (2, 6, 3),
-                                                        (1, 4, 4)])
+                                                        (1, 4, 4), (3, 4, 2)])
 def test_pipelined_beam_matches_reference(model, prompt, num_stages,
                                           microbatch, beam):
     graph, params = model
@@ -783,6 +882,31 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
     assert rows == blocks * len(buffers) * 2        # k and v (and scales)
     assert groups == (dec.l_max * len(buffers) * 2 * num_stages
                       if beam > 1 else 0)
+
+
+@pytest.mark.parametrize("num_stages,beam", [(1, 1), (4, 1), (3, 1), (2, 2)])
+def test_decode_step_cuts_no_weight_out_of_the_flat_row(model, num_stages,
+                                                        beam):
+    """Structural guard of the decode program's scan body: no ``slice``
+    or ``reshape`` produces an array of a weight matrix's size.  A leaf
+    that rode the flat row was cut out of it and laid out anew inside
+    the loop, every step (12 of 21 ms a step at GPT-2 XL's widths on the
+    chip); the leaves the nodes name in ``stage_arg_keys`` arrive as
+    arguments in their own shapes."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=4, max_len=MAX_LEN, beam_width=beam)
+    sizes = {np.size(params[nm][key]["w"] if sub else params[nm][key])
+             for nm, key, sub in (("block_0", "qkv", True),
+                                  ("block_0", "fc1", True),
+                                  ("block_0", "proj", True),
+                                  ("embeddings", "wte", False),
+                                  ("embeddings", "wpe", False),
+                                  ("lm_head", "w", False))}
+    cut = [eqn for eqn in _walk(_decode_scan_body(dec, 2 * num_stages))
+           if eqn.primitive.name in ("slice", "reshape")
+           and eqn.outvars[0].aval.size in sizes]
+    assert not cut, cut
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["buffer", "int8"])

@@ -477,9 +477,13 @@ def test_the_serving_engine_refuses_the_block_by_name(model):
 #: on purpose records them anew and says so.  PR 29 did: the attention
 #: became the kernel ``kv_attend`` and the ring's row write, under a
 #: lane row, the kernel ``kv_write_rows`` (``e8dcb192e737955e`` /
-#: ``aadd22d3adc8d6a3`` before it); the tokens are PR 26's still.
+#: ``aadd22d3adc8d6a3`` before it).  PR 32 did: the family's nodes name
+#: every leaf a stage-sharded argument of its own (``stage_arg_keys``),
+#: so the program takes a tree where it cut leaves out of the flat row
+#: (``843764f96d5a7620`` / ``081b76872aa207eb`` before it); the tokens
+#: are PR 26's still.
 PARENT_TOKENS_SHA = "0fef1cc65e752cd8"
-PARENT_DECODE_SHA = {1: "843764f96d5a7620", 2: "081b76872aa207eb"}
+PARENT_DECODE_SHA = {1: "4c8596d40d6c1428", 2: "7ddd12dc4515bd7c"}
 
 
 def _sha(text: str) -> str:
