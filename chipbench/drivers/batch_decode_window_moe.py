@@ -1,0 +1,386 @@
+"""Driver ``batch_decode_window_moe``: ``batch_decode``'s offline batch
+through ``PipelinedDecoder``, for the family whose layers keep two
+lengths of memory — window layers a ring buffer, full layers every
+position — and hold a share of their routed experts
+(``models.cohere_moe``).
+
+The window, the readings, ``tokens_per_s`` and the token check are
+``chipbench/drivers/batch_decode.py``'s own functions, called from here,
+as ``batch_decode_moe`` calls them; the weights are made where and how
+``batch_decode_retention`` makes them (drawn on the chip a node at a
+time, kept on the *host*: 9.7 GB of bf16 weights and 3.2 GB of cache
+buffers leave no room for a second tree on the chip), and the head is
+the embedding's table.  This file has the set-up, what the layers add
+to ``counters`` and the second half of ``check``.
+
+``check`` holds the program to the plain reference three times:
+
+* the generated tokens, by ``batch_decode``'s measure at this file's
+  limit, on ``check_sequences`` sequences over the first
+  ``check_tokens`` generated tokens;
+* **the router**, as ``batch_decode_moe`` holds OLMoE's: the share of
+  the reference's 8 choices a token a layer (over all 128 experts,
+  held or not) that the program's own blocks make on the same tokens,
+  in the layer where they agree least;
+* **the window** (:func:`window_probe`): seeded random keys give
+  near-flat attention, and a window off by one, or a ring buffer read
+  one row off after a wrap, would hide inside the token limit.  So the
+  check drives the program's own format and kernels at the cell's
+  geometry (8 KV heads of 128, 16 queries a KV head, a window of 4096):
+  the banded flash kernel over a prompt of nearly three windows,
+  ``write_prefix`` of that prompt into a ring buffer, then decode
+  writes and ``kv_attend`` across the next wrap — on seeded inputs
+  where a few planted keys carry most of a query's weight, one of them
+  leaving the window inside the decode steps — against the reference's
+  masked softmax; and the same through a format without a window.
+
+Counters added: the program's ``decode.moe.*`` sums over the window
+(``assignments``: rows x 8 x layers x steps; ``held_assignments``: those
+that fell to held experts; ``experts_hit``: distinct held experts a
+layer a step; ``load_max``), ``experts_hit_share`` (held experts hit a
+layer a step over held experts), ``held_share`` (held over all
+assignments: 1/8 expected), the gauges ``decode.cache.window_bytes`` /
+``.full_bytes`` / ``.window_positions`` as ``cache_*`` and
+``prefill_tokens``.
+
+Traffic file keys: as ``batch_decode``, and ``check_tokens``.
+Configuration file keys: ``model_args`` (for
+``defer_tpu.models.cohere_moe``), ``reference``, and optionally
+``init_gain``.
+"""
+
+from __future__ import annotations
+
+import gc
+import importlib
+
+import numpy as np
+
+from chipbench.agreement import rel_err
+from chipbench.drivers import batch_decode as base
+
+#: this configuration's limit on the worst logit gap share (the measure is
+#: ``batch_decode``'s: how far the reference's logit of the program's token
+#: sits under the reference's best, over the position's spread; a token
+#: no better than a random one reads ~1, the worst of 1024 random ones
+#: ~2).  Set between two readings on the v5e (PR 34, PERF.md section 6;
+#: a reading is the worst of 2 x 512 tokens, as a run judges them).  The
+#: configuration's ``init_gain`` makes attention sharp (scores spread
+#: by ~4 over a window), so a bfloat16 rounding can turn a near-tie
+#: between two cached positions, a fifth of the tokens are not the
+#: reference's own argmax, and the measure's worst token has a long
+#: tail: over 18 readings of 18 seeds the program gave 0.101-0.483
+#: (median 0.22; 0.13-0.37 at neighbouring gains).  The reference itself
+#: with every product's operands rounded to float8_e4m3, the nearest
+#: precision below the stated one: 1.61 at the least over 3 seeds (2.09
+#: at the most): not correct.  0.9 is near their geometric mean: 1.9x
+#: over the one and 1.8x under the other.
+GAP_TOL = 0.9
+#: the least share of the reference's expert choices (8 a token, over
+#: all 128 experts) that the program's own blocks must make on the same
+#: tokens, in the layer where they agree least (as
+#: ``batch_decode_moe.ROUTER_TOL``).  Set the same way (pairs of
+#: 8703-token sequences): the program's least 0.9484 (by layer 0.998,
+#: 0.990, 0.974, 0.949: the streams part as the sharp attention of each
+#: layer turns its near-ties), the float8_e4m3-input reference's most
+#: 0.126 (by layer 0.85, 0.27, 0.15, 0.12): not correct.  0.8 leaves a
+#: disagreement of 0.2: 3.9x the one's 0.052, 4.4x under the other's
+#: 0.874.  A router that takes the wrong experts shares about 8 / 128.
+ROUTER_TOL = 0.8
+#: the most any part of the window probe may differ from the reference's
+#: masked softmax, as ``rel_err`` (largest difference over largest
+#: entry).  Set between two readings on the v5e (PR 34, PERF.md section
+#: 6; a reading is the worst of the probe's four parts: 2 sequences x
+#: 128 heads x 12186 prompt rows and 204 decode steps).  The largest the
+#: program gave over the builder's 26 readings (bfloat16 rows and
+#: queries, f32 accumulation): 0.0104.  The same kernels handed inputs
+#: rounded to float8_e4m3, the nearest precision below the stated one:
+#: 0.0785 at the least (0.141 at the most): not correct.  0.025 is 2.4x
+#: over the one and 3.1x under the other.  It also fails, by every part they
+#: touch, a window off by one (held to a reference of 4095 or 4097:
+#: 0.26-0.42 in the flash kernel, 0.43-0.67 in the decode steps) and a
+#: decode row written one row off (0.54-0.67 over the ring buffer,
+#: 0.30-0.34 over the full layer's rows).
+PROBE_TOL = 0.025
+MOE_COUNTERS = ("decode.moe.assignments", "decode.moe.held_assignments",
+                "decode.moe.experts_hit", "decode.moe.load_max")
+CACHE_GAUGES = ("decode.cache.window_bytes", "decode.cache.full_bytes",
+                "decode.cache.window_positions")
+
+
+def probe_plan(window: int) -> tuple[int, int, int, int]:
+    """``(prompt length, decode steps, planted every, planted at)`` of
+    the window probe: the prompt ends a fortieth of a window under three
+    windows and twice as many decode steps follow, so that they cross
+    the wrap at three windows; a key is planted every quarter window,
+    half of that fortieth past a multiple, so that one of them leaves
+    the window in the middle of the steps behind the wrap (at 4096: a
+    prompt of 12186, 204 steps, the wrap at step 102, and position 2 x
+    4096 + 51 leaves the window at step 153)."""
+    short = max(4, window // 40)
+    return 3 * window - short, 2 * short, max(2, window // 4), short // 2
+
+
+def make_weights(graph, seed: int, dtype, gains: dict) -> dict:
+    """``batch_decode_retention.make_weights`` (the program's initialiser
+    from the seed, drawn on the chip a node at a time and fetched to
+    the host: the float32 draw of one layer, 4.6 GB, is the most the
+    chip holds; scaled by ``gains``, cast to ``dtype``), then the head
+    tied to the embedding, as a tied checkpoint loads."""
+    from chipbench.drivers.batch_decode_retention import make_weights as draw
+    from defer_tpu.models.cohere_moe import tie_head
+    return tie_head(draw(graph, seed, dtype, gains))
+
+
+def setup(ctx):
+    import jax.numpy as jnp
+
+    from defer_tpu import PipelinedDecoder, models
+
+    tr, cfg = ctx.cell.traffic, ctx.cell.config
+    graph = models.cohere_moe(**cfg["model_args"])
+    dtype = jnp.dtype(tr["compute_dtype"])
+    with ctx.span("weights"):
+        params = make_weights(graph, ctx.seed, dtype,
+                              cfg.get("init_gain", {}))
+    with ctx.span("build"):
+        dec = PipelinedDecoder(
+            graph, params, num_stages=ctx.cell.chips,
+            microbatch=tr["batch"] // ctx.cell.chips, max_len=tr["max_len"],
+            compute_dtype=dtype, kv_cache=tr["kv_cache"])
+    rng = np.random.default_rng(ctx.seed)
+    # ids over the held rows of the vocabulary
+    prompts = rng.integers(0, cfg["model_args"]["vocab"],
+                           (tr["batch"], tr["prompt_len"])).astype(np.int32)
+    state = {"params": params, "dec": dec, "prompts": prompts,
+             "graph": graph, "traffic": tr, "config": cfg}
+    with ctx.span("warmup"):
+        # the prefill is keyed by the prompt length and the decode
+        # program by token_chunk: two chunks compile all a window runs
+        dec.generate(prompts, 2 * tr["token_chunk"] + 1, prefill=True,
+                     token_chunk=tr["token_chunk"],
+                     on_tokens=lambda *a, **k: None)
+    return state
+
+
+def _moe_counts() -> dict:
+    from defer_tpu.obs import REGISTRY
+    return {name: REGISTRY.counter(name).n for name in MOE_COUNTERS}
+
+
+def measure(state, seconds, ctx):
+    from defer_tpu.obs import REGISTRY
+
+    tr, args = state["traffic"], state["config"]["model_args"]
+    before = _moe_counts()
+    out = base.measure(state, seconds, ctx)
+    moe = {name: n - before[name] for name, n in _moe_counts().items()}
+    counters = out["counters"]
+    counters.update(moe, prefill_tokens=tr["batch"] * tr["prompt_len"],
+                    max_len=tr["max_len"])
+    if "dec" in state:
+        # sequences a piece of the prefill holds (the banded kernel's
+        # calls are a piece's)
+        counters["prefill_piece_rows"] = state["dec"]._prefill_rows(
+            tr["prompt_len"])
+    counters.update({"cache_" + name.rsplit(".", 1)[1]:
+                     float(REGISTRY.gauge(name).value)
+                     for name in CACHE_GAUGES})
+    lo, hi = args["experts_held"] or (0, args["num_experts"])
+    # one (layer, step) routes rows x experts_per_tok choices
+    layer_steps = moe["decode.moe.assignments"] / (
+        tr["batch"] * args["experts_per_tok"])
+    if layer_steps:
+        hit = moe["decode.moe.experts_hit"] / layer_steps
+        counters["experts_hit_share"] = hit / (hi - lo)
+        counters["held_share"] = (moe["decode.moe.held_assignments"]
+                                  / moe["decode.moe.assignments"])
+        out["notes"].append(
+            f"held experts hit a layer a step {hit:.2f} of {hi - lo}; "
+            f"{counters['held_share']:.4f} of the assignments fell to "
+            f"them ({(hi - lo) / args['num_experts']:.4f} expected); "
+            f"largest group "
+            f"{moe['decode.moe.load_max'] / layer_steps:.2f} rows "
+            f"({layer_steps:.0f} layer-steps)")
+    return out
+
+
+def router_agreement(graph, params, seqs, ref_cfg: dict) -> list:
+    """For each layer, the share of the plain reference's expert choices
+    on ``seqs`` [n, t] that the program's blocks make too: the program's
+    own full-sequence forward (``apply_with_kv``, what its prefill runs)
+    in the type of ``params``, a layer's weights on the device at a
+    time, against the reference's float32 forward of the same tokens
+    (choices over all the experts the router names, held or not)."""
+    import jax
+
+    ref = importlib.import_module(ref_cfg["module"])
+    _, want = ref.logits(params, seqs, lo=seqs.shape[1] - 1, experts=True,
+                         **ref_cfg["args"])
+    want = np.asarray(want)                            # [L, n, t, k]
+    nodes = graph.nodes
+    n_experts = nodes["block_0"].op.num_experts
+    forward = {}
+
+    def layer(name, p, x):
+        op = nodes[name].op
+        if op not in forward:       # one program a kind of layer
+
+            @jax.jit
+            def fn(p, x, op=op):
+                sown: dict = {}
+                y, _k, _v = op.apply_with_kv(p, x, sow=sown)
+                return y, sown["moe.chosen"].reshape(x.shape[:2] + (-1,))
+
+            forward[op] = fn
+        return forward[op](p, x)
+
+    def chose(ids):                                   # -> [n, t, E] bool
+        hot = np.zeros(ids.shape[:2] + (n_experts,), bool)
+        np.put_along_axis(hot, ids, True, -1)
+        return hot
+
+    x = jax.jit(nodes["embeddings"].op.apply)(params["embeddings"], seqs)
+    shares = []
+    for i in range(want.shape[0]):
+        x, got = layer(f"block_{i}", params[f"block_{i}"], x)
+        both = chose(np.asarray(got)) & chose(want[i])
+        shares.append(float(both.sum() / want[i].size))
+    return shares
+
+
+def probe_inputs(seed: int, heads: int, kv: int, hd: int, length: int,
+                 sequences: int, every: int, at: int):
+    """Seeded float32 ``q`` [b, heads, length, hd], ``k`` / ``v`` [b, kv,
+    length, hd].  Every query of a KV head's group leans one way (a
+    unit direction a KV head, plus noise), and so does one key every
+    ``every`` positions (from ``at`` on): a planted key scores ~sqrt(hd)
+    = 11 where the others score ~N(0, 1.4), so the few planted keys a
+    window holds carry most of a query's weight, and a key that enters
+    or leaves a window a position early moves the output by a third."""
+    rng = np.random.default_rng(seed)
+    b, g = sequences, heads // kv
+
+    def normal(*shape):
+        return rng.standard_normal(shape, dtype=np.float32)
+
+    u = normal(b, kv, 1, hd)
+    u /= np.sqrt((u * u).mean(-1, keepdims=True))       # entries ~1
+    q = normal(b, kv, g, length, hd) + u[:, :, None]
+    k = normal(b, kv, length, hd)
+    planted = np.arange(at, length, every)
+    k[:, :, planted] = u + 0.1 * normal(b, kv, planted.size, hd)
+    return q.reshape(b, heads, length, hd), k, normal(b, kv, length, hd)
+
+
+def window_probe(seed: int, *, heads: int, kv: int, hd: int, window: int,
+                 dtype, ref, sequences: int = 2, inputs=None,
+                 ref_window: int | None = None, slot_shift: int = 0) -> dict:
+    """The program's formats and kernels at one layer's geometry against
+    the reference's masked softmax (the module docstring): ``rel_err``
+    of the banded flash kernel over the prompt (``flash_window``), of
+    the decode steps' attention over a ring buffer behind
+    ``write_prefix`` (``decode_window``), and of both through a format
+    and a kernel call without a window (``flash_full``,
+    ``decode_full``).  Cache rows and queries are of type ``dtype``,
+    the cell's.
+
+    The last three arguments are the controls', never the check's:
+    ``inputs`` rounds what the program is given to a narrower float;
+    ``ref_window`` holds the program to a reference of another window
+    (a program whose window is off by one, seen from the other side);
+    ``slot_shift`` writes each decode step's row that many rows off."""
+    import jax
+    import jax.numpy as jnp
+
+    from defer_tpu.ops.flash_attention import flash_attention
+    from defer_tpu.ops.kv_cache import KVCacheFormat
+
+    plen, steps, every, at = probe_plan(window)
+    total = plen + steps
+    b = sequences
+    q, k, v = (jnp.asarray(a) for a in probe_inputs(
+        seed, heads, kv, hd, total, b, every, at))
+    given = (q, k, v)               # what the program is handed
+    if inputs is not None:
+        kind = jnp.finfo(inputs)
+        given = tuple(jax.lax.reduce_precision(a, kind.nexp, kind.nmant)
+                      for a in given)
+    out = {}
+    for name, w in (("window", window), ("full", None)):
+        rw = w if w is None or ref_window is None else ref_window
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda q, k, v, rw=rw: ref.attention(
+                q, k, v, rw))(q, k, v)
+        want = np.asarray(want)                      # [b, heads, total, hd]
+        qd, kd, vd = (a.astype(dtype) for a in given)
+        got = flash_attention(qd[:, :, :plen], kd[:, :, :plen],
+                              vd[:, :, :plen], causal=True, window=w)
+        out["flash_" + name] = rel_err(got, want[:, :, :plen])
+
+        fmt = KVCacheFormat(kv, hd, total, dtype, groups=1, window=w,
+                            query_group=heads // kv)
+        cols = [a.transpose(0, 2, 1, 3).reshape(b, total, -1)
+                for a in (qd, kd, vd)]
+
+        def run(cols, fmt=fmt):
+            qc, kc, vc = cols
+            layer = fmt.layer(fmt.zeros(b, 1), 0)
+            layer = fmt.write_prefix(layer, kc[:, :plen], vc[:, :plen],
+                                     fmt.prefill_slot(True, 0))
+
+            def step(layer, xs):
+                pos, qt, kt, vt = xs
+                layer = fmt.write_position(
+                    layer, fmt.rows(kt, vt),
+                    fmt.decode_slot(True, pos + slot_shift), group=0)
+                return layer, fmt.attend(qt, layer,
+                                         fmt.decode_slot(True, pos), group=0)
+
+            _, ys = jax.lax.scan(step, layer, (
+                plen + jnp.arange(steps),
+                *(a[:, plen:].swapaxes(0, 1) for a in (qc, kc, vc))))
+            return ys                                  # [steps, b, heads*hd]
+
+        ys = np.asarray(jax.jit(run)(cols)).reshape(steps, b, heads, hd)
+        out["decode_" + name] = rel_err(ys.transpose(1, 2, 0, 3),
+                                        want[:, :, plen:])
+    return out
+
+
+def check(state, ctx):
+    import jax.numpy as jnp
+
+    tr, cfg = state["traffic"], state["config"]
+    # the reference upcasts a layer at a time beside whatever the chip
+    # still holds: let the decoder's weights and caches go first
+    state.pop("dec", None)
+    gc.collect()
+    plen, n = tr["prompt_len"], tr["check_sequences"]
+    # the first ``check_tokens`` generated tokens are judged: the
+    # reference runs every position of every judged sequence in float32
+    state["sample"] = state["sample"][:, :plen + tr["check_tokens"]]
+    ok, detail = base.check(state, ctx)
+    if "worst_logit_gap_share" not in detail:
+        return ok, detail
+    detail["tolerance"] = GAP_TOL               # judged at this file's limits
+    shares = router_agreement(state["graph"], state["params"],
+                              state["sample"][:n, :-1], cfg["reference"])
+    args = cfg["model_args"]
+    probe = window_probe(
+        ctx.seed, heads=args["heads"], kv=args["kv_heads"],
+        hd=args["head_dim"], window=args["window"],
+        dtype=jnp.dtype(tr["compute_dtype"]),
+        ref=importlib.import_module(cfg["reference"]["module"]))
+    detail.update(router_agreement_share=min(shares),
+                  router_agreement_by_layer=[round(s, 5) for s in shares],
+                  router_tolerance=ROUTER_TOL,
+                  window_probe_rel_err=max(probe.values()),
+                  window_probe_rel_err_by_part=probe,
+                  window_probe_tolerance=PROBE_TOL)
+    return (detail["worst_logit_gap_share"] <= GAP_TOL
+            and min(shares) >= ROUTER_TOL
+            and max(probe.values()) <= PROBE_TOL), detail
+
+
+close = base.close

@@ -726,13 +726,22 @@ class TransformerBlock(Op):
 # ---------------------------------------------------------------------------
 
 
-def route_top_k(logits, k: int):
-    """Softmax over the experts in float32, then the ``k`` largest:
-    ``(expert ids [..., k], their probabilities [..., k])``, the
-    probabilities as the softmax gave them (not renormalised)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    p, eid = lax.top_k(probs, k)
-    return eid, p
+def route_top_k(logits, k: int, scoring: str = "softmax"):
+    """Scores over the experts in float32, then the ``k`` largest:
+    ``(expert ids [..., k], their weights [..., k])``.  ``scoring`` is
+    the family's rule, named by the block that calls (never a user's
+    flag): ``"softmax"`` — probabilities over all experts, used as the
+    softmax gave them (not renormalised); ``"sigmoid"`` — an
+    independent score an expert, renormalised over the chosen ``k``."""
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        p, eid = lax.top_k(probs, k)
+        return eid, p
+    if scoring != "sigmoid":
+        raise ValueError(
+            f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
+    p, eid = lax.top_k(jax.nn.sigmoid(logits.astype(jnp.float32)), k)
+    return eid, p / jnp.sum(p, axis=-1, keepdims=True)
 
 
 def expert_dispatch(x, eid, gate, num_experts: int, expert_fn):
@@ -756,6 +765,77 @@ def expert_dispatch(x, eid, gate, num_experts: int, expert_fn):
     y = jnp.sum(ys.astype(jnp.float32)
                 * gate[..., None].astype(jnp.float32), axis=1)
     return y, sizes
+
+
+#: the most (row, choice) pairs one grouped product of
+#: :func:`expert_dispatch_held` takes: a prompt's pairs beyond it are
+#: worked off run by run, as many runs as hold the pairs that fell to
+#: held experts
+_HELD_RUN = 4096
+
+
+def expert_dispatch_held(x, eid, gate, held: tuple[int, int], expert_fn):
+    """:func:`expert_dispatch` for a layer that holds experts
+    ``held[0] .. held[1] - 1`` of those its router chooses among (one
+    chip's share of a layer under expert parallelism): the pairs that
+    fell to a held expert are computed, by that expert; the pairs that
+    fell elsewhere are another chip's, and are **not computed** — they
+    are sorted behind the held ones and no product sees them, not even
+    as zeros.  No capacity, nothing held is dropped.
+
+    ``x`` [T, d]; ``eid``/``gate`` [T, k] over *all* experts
+    (:func:`route_top_k`: the weights stay those of the full choice).
+    ``expert_fn(xs, group_sizes)`` maps rows sorted by held expert
+    (``group_sizes`` [held experts]; they may sum to less than the
+    rows: the rest are no expert's) to [rows, d_out].  Returns ``(the
+    held pairs' weighted sum [T, d_out] in float32, group_sizes)``.
+
+    Up to :data:`_HELD_RUN` pairs are one grouped product.  A prompt
+    has more; its sorted pairs are taken a run at a time, in a loop
+    whose trip count is the number of runs that hold held pairs — with
+    1/8 of the experts held, 1/8 of the runs."""
+    lo, hi = held
+    n_held = hi - lo
+    t, k = eid.shape
+    pairs = t * k
+    flat = eid.reshape(pairs) - lo
+    mine = jnp.logical_and(flat >= 0, flat < n_held)
+    key = jnp.where(mine, flat, n_held)          # absent: sorted last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                    axis=0, dtype=jnp.int32)
+    weight = jnp.where(mine, gate.reshape(pairs).astype(jnp.float32), 0.0)
+    run = min(pairs, _HELD_RUN)
+
+    def one_run(y, start, slots):
+        """The sorted pairs ``start .. start + run - 1`` added to ``y``."""
+        ends = jnp.cumsum(sizes)
+        part = jnp.clip(ends - start, 0, run) \
+            - jnp.clip(ends - sizes - start, 0, run)
+        rows = slots // k
+        ys = expert_fn(x[rows], part).astype(jnp.float32)
+        # rows behind the last group are no expert's: whatever the
+        # product left there must not reach the sum
+        live = (start + jnp.arange(run) < ends[-1])[:, None]
+        return y.at[rows].add(
+            jnp.where(live, ys * weight[slots][:, None], 0.0))
+
+    d_out = jax.eval_shape(
+        expert_fn, jax.ShapeDtypeStruct((run,) + x.shape[1:], x.dtype),
+        jax.ShapeDtypeStruct(sizes.shape, sizes.dtype)).shape[-1]
+    y = jnp.zeros((t, d_out), jnp.float32)
+    if run == pairs:
+        return one_run(y, 0, order), sizes
+    # whole runs only: the tail of the order is padded with pair 0,
+    # which ``live`` masks
+    padded = jnp.concatenate(
+        [order, jnp.zeros((-pairs % run,), order.dtype)])
+
+    def body(i, y):
+        start = i * run
+        return one_run(y, start, lax.dynamic_slice(padded, (start,), (run,)))
+
+    return lax.fori_loop(0, (jnp.sum(sizes) + run - 1) // run, body, y), sizes
 
 
 @dataclasses.dataclass(frozen=True, repr=False)

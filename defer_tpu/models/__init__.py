@@ -10,6 +10,7 @@ Each family ships a ``*_tiny`` variant for fast CPU-mesh tests.
 """
 
 from .brumby import brumby, brumby_tiny
+from .cohere_moe import cohere_moe, cohere_moe_tiny
 from .bert import BERT_BASE_12STAGE_CUTS, bert, bert_base, bert_tiny
 from .gpt import gpt, gpt2_small, gpt_small, gpt_stage_cuts, gpt_tiny
 from .moe import (moe_branched, moe_branched_tiny, moe_stage_cuts,
@@ -32,4 +33,5 @@ __all__ = [
     "moe_branched", "moe_branched_tiny",
     "olmoe", "olmoe_tiny",
     "brumby", "brumby_tiny",
+    "cohere_moe", "cohere_moe_tiny",
 ]

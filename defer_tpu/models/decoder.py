@@ -6,7 +6,10 @@ it, whatever its family.
   positions the model declares, and ``embed_at(params, ids, pos)``),
   ``block_0..`` in topological order (each a :class:`DecoderBlock`, all
   keeping one kind of memory in one head geometry and sowing the same
-  statistics), ``final_ln``, ``lm_head``.  Any of them may name
+  statistics; how *much* of it a layer keeps is the layer's own: a
+  block whose attention has a ``window`` keeps a ring buffer of that
+  many rows beside a neighbour that keeps every position),
+  ``final_ln``, ``lm_head``.  Any of them may name
   ``stage_arg_keys``.  :func:`decoder_parts` checks a graph against
   this and hands back its parts; both engines' constructors call it.
 * **Blocks**: :class:`DecoderBlock`, whose per-sequence memory is a KV
@@ -66,9 +69,12 @@ class DecoderBlock:
     #: parameter subtrees kept out of the flat weight row
     stage_arg_keys: tuple = ()
 
-    def _attend(self, q, k, v):
+    def _attend(self, q, k, v, window: int | None = None):
         """Causal attention on [b, nh, t, hd] by ``attn_impl``: the
-        flash kernel (bottom-right aligned) on a TPU, plain XLA elsewhere."""
+        flash kernel (bottom-right aligned) on a TPU, plain XLA
+        elsewhere.  ``k`` / ``v`` may have fewer heads, each serving a
+        group of queries; with ``window`` a row attends its ``window``
+        newest keys, itself counted."""
         impl = self.attn_impl
         if impl == "auto":
             impl = "flash" if jax.default_backend() == "tpu" else "xla"
@@ -77,29 +83,46 @@ class DecoderBlock:
                 f"attn_impl must be 'auto', 'flash' or 'xla', got {impl!r}")
         if impl == "flash":
             from ..ops import flash_attention
-            return flash_attention(q, k, v, causal=True)
+            return flash_attention(q, k, v, causal=True, window=window)
         hd = q.shape[-1]
         t_q, t_k = q.shape[2], k.shape[2]
+        if k.shape[1] != q.shape[1]:
+            k, v = (jnp.repeat(a, q.shape[1] // k.shape[1], axis=1)
+                    for a in (k, v))
         att = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(hd)
         q_pos = jnp.arange(t_q)[:, None] + (t_k - t_q)
         mask = q_pos >= jnp.arange(t_k)[None, :]
+        if window is not None:
+            mask = jnp.logical_and(
+                mask, q_pos - jnp.arange(t_k)[None, :] < window)
         att = jnp.where(mask, att, jnp.asarray(-jnp.inf, att.dtype))
         att = jax.nn.softmax(att, axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", att, v)
 
     #: the kind of per-sequence memory the block keeps
     memory = "kv_cache"
+    #: how far back the block's attention reaches, its own token
+    #: counted; None: every position (a dataclass block may make it a
+    #: field)
+    window = None
 
     def memory_format(self, head_dim: int, positions: int, dtype, *,
                       quantized: bool = False, groups: int | None = None):
         """The format of one layer of this block's memory, for
         ``positions`` positions of rows of type ``dtype``: what a
         holder builds its buffers from and hands back to :meth:`decode`
-        and :meth:`prefill`."""
+        and :meth:`prefill`.  The format is *this layer's*: a block
+        with a ``window`` shorter than ``positions`` keeps a ring buffer
+        of ``window`` rows, its neighbour without one a row a position,
+        and a holder asks every block."""
         from ..ops import kv_cache   # the class as the module names it now
-        return kv_cache.KVCacheFormat(self.kv_heads, head_dim, positions,
-                                      dtype, quantized=quantized,
-                                      groups=groups)
+        window = self.window
+        if window is not None and window >= positions:
+            window = None       # never wraps: a row a position
+        return kv_cache.KVCacheFormat(
+            self.kv_heads, head_dim, positions, dtype, quantized=quantized,
+            groups=groups, window=window,
+            query_group=self.num_heads // self.kv_heads)
 
     def decode(self, params, x, cache, pos, fmt, slot=None, group=None,
                sow=None):
@@ -262,7 +285,7 @@ def decoder_parts(graph: LayerGraph, num_stages: int,
         if op.memory != first.op.memory:
             raise ValueError(
                 f"{nm} keeps a {op.memory}, block_0 a {first.op.memory}: "
-                "one format serves every block of a graph")
+                "one kind of format serves every block of a graph")
         if geometry(op) != heads:
             raise ValueError(
                 f"{nm} has heads ({op.num_heads}, kv {op.kv_heads}) "

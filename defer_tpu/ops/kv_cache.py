@@ -25,6 +25,16 @@ needs a masked read-modify-write: with ``groups`` the buffers carry a
 leading axis of ``groups + 1`` (the ring's round-robin groups and one
 scratch group) and one more position, the scratch row.
 
+**A ring buffer** (``window=W``): a layer whose attention reaches back
+``W`` positions and no further keeps ``W`` rows a sequence, not one a
+position.  Row ``p`` lives at ``p % W``; until the first wrap rows
+``0..p`` are live, from then on every row is, and their order does not
+matter to a softmax (a family with rotary positions rotates its keys
+before they are cached).  So attention over the buffer is attention
+over rows ``<= min(p, W - 1)``: the kernels need no second rule.  A
+format is a layer's, and a holder of several layers holds one a layer
+(a window layer's beside a full layer's).
+
 The *state* of several layers is a dict of tuples, one buffer a layer
 under each key, never stacked (``ops/layered.py``, which the retention
 state's format shares).
@@ -60,6 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 from .layered import LayeredState
@@ -69,6 +80,14 @@ from .layered import LayeredState
 _LANES = 128
 #: the most one of the attention's blocks (keys or values) may hold
 _BLOCK_BYTES = 1 << 20
+#: joined buffers hold a multiple of this many positions (a sublane
+#: tile of 16-bit rows)
+_JOINED_ROWS = 16
+#: from this many queries a KV head on, the group is the rows of a
+#: matrix product a position block (:func:`kv_attend_joined`); under it
+#: the vector unit's multiply-and-reduce a query keeps up with the DMA
+#: (8 rows fill a sublane tile of f32)
+_MXU_GROUP = 8
 
 
 def _write_kernel(group_ref, pos_ref, rows_ref, win_ref, out_ref):
@@ -273,6 +292,125 @@ def _attend_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                 o_ref[0, j] = out[0].astype(o_ref.dtype)
 
 
+def _attend_joined_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                          m_ref, l_ref, acc_ref, *, tl, kv, scale):
+    """One position block of one sequence, every KV head's whole query
+    group at once: ``q_ref`` / ``o_ref`` ``[1, kv * g, hd]``, ``k_ref``
+    / ``v_ref`` ``[1, 1, tl, kv * hd]`` as a joined buffer lies.  A
+    head's keys are a lane-aligned slice ``[tl, hd]`` of the block, its
+    scores ``[g, hd] x [hd, tl]`` and its output ``[g, tl] x [tl, hd]``
+    on the matrix unit, accumulated in f32 (16 queries over 512 bytes a
+    position are 8192 f32 operations for every 512 bytes: more than the
+    vector unit has at the memory's pace); the online softmax between
+    them runs on ``[g, tl]``.  m_ref / l_ref ``[kv * g, 128]`` (a row's
+    scalar on every lane), acc_ref ``[kv * g, hd]``."""
+    del group_ref                       # the index maps read it
+    t = pl.program_id(1)
+    pos = pos_ref[pl.program_id(0)]
+    g, hd = q_ref.shape[1] // kv, q_ref.shape[2]
+
+    @pl.when(t == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(ragged):
+        for h in range(kv):
+            rows, cols = pl.ds(h * g, g), pl.ds(h * hd, hd)
+            q, k, v = q_ref[0, rows, :], k_ref[0, 0, :, cols], \
+                v_ref[0, 0, :, cols]
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if ragged:
+                # a dead position's row may hold anything (the scratch
+                # row, a block's overhang): 0 x NaN must not reach a sum
+                s = jnp.where(t * tl + lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1) <= pos, s, -jnp.inf)
+                v = jnp.where(t * tl + lax.broadcasted_iota(
+                    jnp.int32, v.shape, 0) <= pos, v, jnp.zeros_like(v))
+            # block 0 always holds a live position: from the first
+            # block on the running max is finite
+            m_prev = m_ref[rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[rows, :] = jnp.broadcast_to(
+                l_ref[rows, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+                (g, l_ref.shape[1]))
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[rows, :] = jnp.broadcast_to(m_new, (g, m_ref.shape[1]))
+
+    pl.when((t + 1) * tl - 1 <= pos)(lambda: accumulate(False))
+    pl.when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - 1 > pos))(
+        lambda: accumulate(True))
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def joined_block_rows(kv: int, hd: int, length: int, itemsize: int) -> int:
+    """Positions of one block of :func:`kv_attend_joined`: as many lane
+    rows as :data:`_BLOCK_BYTES` hold of every KV head's keys."""
+    return min(length, max(_LANES, _BLOCK_BYTES // (kv * hd * itemsize)
+                           // _LANES * _LANES))
+
+
+@functools.partial(jax.jit, static_argnames=("kv",))
+def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int):
+    """:func:`kv_attend` over *joined* buffers ``[groups, b, L, kv *
+    hd]`` (:attr:`KVCacheFormat.joined`: a position's rows of all KV
+    heads side by side on the lanes), for a query group that fills the
+    matrix unit's rows: ``q`` [b, heads * hd], sequence ``i`` over its
+    rows ``<= pos[i]``.  The grid runs (sequence, position block); a
+    block is whole rows as they lie, so its DMA is one contiguous run,
+    a block past ``pos[i]`` is neither fetched nor computed, and each
+    KV head's ``[positions, hd]`` is a lane-aligned slice of it — the
+    operand the products want.  The same name in a device trace as
+    :func:`kv_attend`."""
+    b, d = q.shape
+    groups, _, length, width = k_buf.shape
+    hd = width // kv
+    heads = d // hd
+    tl = joined_block_rows(kv, hd, length, k_buf.dtype.itemsize)
+    pos = jnp.clip(pos.astype(jnp.int32), 0, length - 1)
+    group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
+
+    def head_block(i, t, group_ref, pos_ref):
+        return (i, 0, 0)
+
+    def cache_block(i, t, group_ref, pos_ref):
+        # as in kv_attend: past the last live block, name the first
+        # block of the grid's next sequence
+        more = jnp.logical_and(t > pos_ref[i] // tl, i + 1 < b)
+        i = jnp.where(more, i + 1, i)
+        t = jnp.where(more, 0, jnp.minimum(t, pos_ref[i] // tl))
+        return (group_ref[0], i, t, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_attend_joined_kernel, tl=tl, kv=kv,
+                          scale=1.0 / math.sqrt(hd)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, pl.cdiv(length, tl)),
+            in_specs=[pl.BlockSpec((1, heads, hd), head_block),
+                      pl.BlockSpec((1, 1, tl, width), cache_block),
+                      pl.BlockSpec((1, 1, tl, width), cache_block)],
+            out_specs=pl.BlockSpec((1, heads, hd), head_block),
+            scratch_shapes=[pltpu.VMEM((heads, _LANES), jnp.float32),
+                            pltpu.VMEM((heads, _LANES), jnp.float32),
+                            pltpu.VMEM((heads, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, heads, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=jax.default_backend() != "tpu",
+        name="kv_attend",
+    )(group, pos, q.reshape(b, heads, hd), k_buf, v_buf)
+    return out.reshape(b, d)
+
+
 @jax.jit
 def kv_attend(q, k_buf, v_buf, pos, group):
     """One query a sequence over its live rows: ``q`` [b, heads * hd]
@@ -301,8 +439,11 @@ def kv_attend(q, k_buf, v_buf, pos, group):
     hd`` rows together: ``[.., L, kv, hd]``, a block ``[positions, kvb,
     hd]``.  Where the bytes lie otherwise, the view is a transpose and
     the compile checks (``scripts/*_tpu_compile_check.py``) say so.
-    The query group of a KV head rides along in the block.  Jitted for
-    the reason :func:`write_kv_rows` is."""
+    The query group of a KV head rides along in the block — a few
+    queries; a group of :data:`_MXU_GROUP` or more is a matrix's rows,
+    and a format for such a group holds its buffers joined for
+    :func:`kv_attend_joined` (the same name in a device trace).
+    Jitted for the reason :func:`write_kv_rows` is."""
     b, d = q.shape
     groups, _, kv, length, hd = k_buf.shape
     g = d // (kv * hd)
@@ -364,11 +505,16 @@ def kv_attend(q, k_buf, v_buf, pos, group):
     return out.reshape(b, d)
 
 
-def attend_einsum(q, item: dict, pos):
+def attend_einsum(q, item: dict, pos, window: int | None = None):
     """:meth:`KVCacheFormat.attend` as two plain einsums over one item
     (buffers ``[b, kv, L, hd]``, no group axis): what the int8 rows run
     on — their scales fold into the dots — and the oracle the tests
-    hold :func:`kv_attend` to.  ``pos`` a scalar or one a sequence."""
+    hold :func:`kv_attend` to.  ``pos`` a scalar or one a sequence.
+    With ``window`` the item is a ring buffer of that many rows and
+    ``pos`` the sequence's true position: rows ``<= min(pos, window -
+    1)`` are live."""
+    if window is not None:
+        pos = jnp.minimum(pos, window - 1)
     k_cache, v_cache = item["k"], item["v"]
     k_scale, v_scale = item.get("ks"), item.get("vs")
     b, d = q.shape
@@ -406,35 +552,88 @@ class KVCacheFormat(LayeredState):
     #: the ring's round-robin groups (a leading axis, with the scratch
     #: group and the scratch row); None for slots alone
     groups: int | None = None
+    #: a ring buffer: the rows a sequence keeps, fewer than
+    #: ``positions`` (the module docstring); None: a row a position
+    window: int | None = None
+    #: queries that read one KV head (what decides :attr:`joined`)
+    query_group: int = 1
+
+    @property
+    def joined(self) -> bool:
+        """Whether the buffers are ``[batch, positions, kv_heads *
+        head_dim]`` (the module docstring): for float rows of whole
+        lane rows read by a group the matrix unit is for."""
+        return (self.query_group >= _MXU_GROUP and not self.quantized
+                and self.head_dim % _LANES == 0)
+
+    def __post_init__(self):
+        if self.window is not None and not 0 < self.window < self.positions:
+            raise ValueError(
+                f"a ring buffer of {self.window} rows for {self.positions} "
+                "positions: a window that never wraps is no window "
+                "(window=None holds a row a position)")
+
+    @property
+    def rows_held(self) -> int:
+        """Rows a sequence keeps (the scratch row not counted)."""
+        return self.positions if self.window is None else self.window
 
     @property
     def scratch_position(self) -> int:
         """Where a bubble step writes: a row nothing reads (with
         ``groups`` only)."""
-        return self.positions
+        return self.rows_held
 
     @property
     def scratch_group(self) -> int:
         """Where a prefill's bubble writes: a group nothing reads."""
         return self.groups
 
+    @property
+    def bubble_slot(self) -> int:
+        """What :meth:`decode_slot` says for a bubble: the scratch row
+        itself, or in a ring buffer — where a position names its row
+        only through ``% window`` — no position at all."""
+        return self.scratch_position if self.window is None else -1
+
     def decode_slot(self, valid, pos):
         """Where a ring step's row goes: position ``pos``, or for a
         bubble (``valid`` false) the scratch row."""
-        return jnp.where(valid, pos, self.scratch_position)
+        return jnp.where(valid, pos, self.bubble_slot)
 
-    def prefill_slot(self, valid, group):
+    def prefill_slot(self, valid, group, row=None):
         """Where a ring prefill's rows go: group ``group``, or for a
-        bubble the scratch group."""
-        return jnp.where(valid, group, self.scratch_group)
+        bubble the scratch group; with ``row``, a piece of the group
+        from that sequence on."""
+        group = jnp.where(valid, group, self.scratch_group)
+        return group if row is None else (group, row)
+
+    def _row(self, slot):
+        """The ring buffer's row for :meth:`decode_slot`'s ``slot``."""
+        return jnp.where(slot < 0, self.scratch_position,
+                         slot % self.window)
+
+    def _last_live(self, slot):
+        """The ring buffer's last live row at ``slot``: rows ``0..slot``
+        until the first wrap, every row from then on."""
+        return jnp.clip(slot, 0, self.window - 1)
 
     # -- buffers ---------------------------------------------------------
 
     def buffers(self, batch: int) -> dict[str, jax.ShapeDtypeStruct]:
         """One layer's buffers for ``batch`` sequences (a group), by key."""
-        lead, length = (), self.positions
+        lead, length = (), self.rows_held
         if self.groups is not None:
-            lead, length = (self.groups + 1,), self.positions + 1
+            lead, length = (self.groups + 1,), self.rows_held + 1
+        if self.joined:
+            # whole sublane tiles of positions: at 4097 rows XLA:TPU
+            # would tile another dimension with the lanes (the sequences:
+            # 16 fit a tile exactly) and convert every buffer to the
+            # kernel's layout and back around each dispatch
+            rows = jax.ShapeDtypeStruct(
+                lead + (batch, -(-length // _JOINED_ROWS) * _JOINED_ROWS,
+                        self.kv_heads * self.head_dim), self.dtype)
+            return {"k": rows, "v": rows}
         scales = lead + (batch, self.kv_heads, length)
         rows = jax.ShapeDtypeStruct(
             scales + (self.head_dim,),
@@ -455,6 +654,8 @@ class KVCacheFormat(LayeredState):
         """A block's new key and value columns [b, kv_heads * head_dim],
         one position a sequence, as the writes take them."""
         b = k_new.shape[0]
+        if self.joined:
+            return {"k": k_new[:, None], "v": v_new[:, None]}
         rows = {"k": k_new.reshape(b, self.kv_heads, 1, -1),
                 "v": v_new.reshape(b, self.kv_heads, 1, -1)}
         if self.quantized:
@@ -472,7 +673,10 @@ class KVCacheFormat(LayeredState):
         back.  One ``lax.dynamic_update_slice`` a buffer where a
         position's rows lie together; where the positions lie on the
         lanes (float rows under a lane row) that would rewrite the
-        group's whole item, and the row-writer does it."""
+        group's whole item, and the row-writer does it.  In a ring
+        buffer ``pos`` lands at ``pos % window``."""
+        if self.window is not None:
+            pos = self._row(pos)
         if not self.quantized and _on_lanes(self.head_dim):
             b = rows["k"].shape[0]
             pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
@@ -485,29 +689,50 @@ class KVCacheFormat(LayeredState):
         for key, row in rows.items():
             buf = layer[key]
             row = lax.expand_dims(row, range(len(lead))).astype(buf.dtype)
-            at = lead + (0, 0, pos) + (0,) * (row.ndim - len(lead) - 3)
+            at = lead + (0, pos, 0) if self.joined else \
+                lead + (0, 0, pos) + (0,) * (row.ndim - len(lead) - 3)
             out[key] = lax.dynamic_update_slice(buf, row, at)
         return out
 
     def write_slots(self, layer: dict, rows: dict, pos) -> dict:
         """``rows`` written in place, sequence ``i``'s at its own
         position ``pos[i]``: the layer."""
-        if self.quantized or self.groups is not None:
+        if self.quantized or self.groups is not None \
+                or self.window is not None or self.joined:
             raise NotImplementedError(
                 "a position a sequence is written into unquantized "
-                "slots only (ROADMAP.md A5)")
+                "slots of a row a position only (ROADMAP.md A5, B2)")
         return {key: write_kv_rows(layer[key], rows[key], pos)
                 for key in layer}
 
-    def write_prefix(self, layer: dict, k, v, group) -> dict:
+    def write_prefix(self, layer: dict, k, v, slot) -> dict:
         """A whole prompt's key and value columns [b, t, kv_heads *
-        head_dim] written at positions ``0..t-1`` of group ``group``:
-        one head-major relayout a prompt (amortized), one bulk write a
-        buffer."""
+        head_dim] written at positions ``0..t-1`` where ``slot``
+        (:meth:`prefill_slot`'s) says — a group, or a group and the
+        sequence of it the ``b`` prompts start at: one head-major
+        relayout a prompt (amortized), one bulk write a buffer.  A ring
+        buffer shorter than the prompt keeps the prompt's newest rows,
+        each where a decode step will look for it (``p % window``)."""
+        group, row = slot if isinstance(slot, tuple) else (slot, 0)
         b, t = k.shape[:2]
-        shape = (b, t, self.kv_heads, self.head_dim)
-        k = k.reshape(shape).transpose(0, 2, 1, 3)
-        v = v.reshape(shape).transpose(0, 2, 1, 3)
+        w, shift = self.window, 0
+        if w is not None and t > w:
+            # position t - w + i lies at row (t - w + i) % w
+            k, v, shift, t = k[:, t - w:], v[:, t - w:], (t - w) % w, w
+        if self.joined:
+            # rows as the buffer holds them: left to itself the
+            # compiler may produce a prompt's keys positions-minor (what
+            # the product before them liked) and, the write needing one
+            # layout on both sides, convert the *buffer* there and back
+            rows_major = Layout(major_to_minor=(0, 1, 2))
+            k, v = (with_layout_constraint(a, rows_major) for a in (k, v))
+        else:
+            shape = (b, t, self.kv_heads, self.head_dim)
+            k = k.reshape(shape).transpose(0, 2, 1, 3)
+            v = v.reshape(shape).transpose(0, 2, 1, 3)
+        if shift:
+            k, v = (jnp.roll(a, shift, axis=1 if self.joined else 2)
+                    for a in (k, v))
         new = {"k": k, "v": v}
         if self.quantized:
             new["k"], new["ks"] = quantize_rows(k)
@@ -517,8 +742,17 @@ class KVCacheFormat(LayeredState):
             buf = layer[key]
             out[key] = lax.dynamic_update_slice(
                 buf, rows[None].astype(buf.dtype),
-                (group,) + (0,) * (buf.ndim - 1))
+                (group, row) + (0,) * (buf.ndim - 2))
         return out
+
+    def head_major(self, item: dict) -> dict:
+        """One group's buffers (no group axis) as ``[b, kv, L, hd]``,
+        whichever way the format holds them: what
+        :func:`attend_einsum` reads."""
+        if not self.joined:
+            return item
+        return {key: buf.reshape(buf.shape[:2] + (self.kv_heads, -1))
+                .swapaxes(1, 2) for key, buf in item.items()}
 
     def reparent(self, state: dict, group, parents) -> dict:
         """Beam search: sequence ``i`` of group ``group`` takes over
@@ -539,7 +773,10 @@ class KVCacheFormat(LayeredState):
         heads * head_dim], positions ``<= pos`` live — ``pos`` a scalar
         (every sequence at one position) or [b], one a sequence;
         returns [b, heads * head_dim].  :func:`kv_attend`, or the
-        einsums for int8 rows."""
+        einsums for int8 rows.  Over a ring buffer ``pos`` is
+        :meth:`decode_slot`'s, the sequences' true position."""
+        if self.window is not None:
+            pos = self._last_live(pos)
         if self.quantized:
             item = layer if group is None else {
                 key: _group_slice(buf, group)[0]
@@ -549,18 +786,24 @@ class KVCacheFormat(LayeredState):
         if group is None:
             k_buf, v_buf, group = k_buf[None], v_buf[None], 0
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), q.shape[:1])
-        return kv_attend(q, k_buf, v_buf, pos,
-                         jnp.asarray(group, jnp.int32).reshape(1))
+        group = jnp.asarray(group, jnp.int32).reshape(1)
+        if self.joined:
+            return kv_attend_joined(q, k_buf, v_buf, pos, group,
+                                    kv=self.kv_heads)
+        return kv_attend(q, k_buf, v_buf, pos, group)
 
     def live_block_share(self, pos) -> tuple[int, int]:
         """Position blocks :meth:`attend` reads for sequences at the
         positions ``pos`` (host integers, any shape), and the blocks
         their items hold: reckoned from the block size alone, no device
-        asked."""
-        length = self.positions + (self.groups is not None)
-        _, tl = attend_blocks(self.kv_heads, self.head_dim, length,
-                              jnp.dtype(self.dtype).itemsize)
-        pos = np.clip(np.asarray(pos), 0, length - 1)
+        asked.  (A bubble is :attr:`bubble_slot`.)"""
+        length = self.buffers(1)["k"].shape[-2]     # scratch and padding too
+        itemsize = jnp.dtype(self.dtype).itemsize
+        tl = joined_block_rows(self.kv_heads, self.head_dim, length,
+                               itemsize) if self.joined else attend_blocks(
+            self.kv_heads, self.head_dim, length, itemsize)[1]
+        # a ring buffer's last live row is the window's last at most
+        pos = np.clip(np.asarray(pos), 0, (self.window or length) - 1)
         held = pos.size * -(-length // tl)
         # the einsums of the int8 rows read every position
         return (held if self.quantized else int((pos // tl + 1).sum())), held

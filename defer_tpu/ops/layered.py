@@ -45,3 +45,14 @@ class LayeredState:
         """Bytes of ``layers`` layers' buffers for ``batch`` sequences."""
         return layers * sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
                             for s in self.buffers(batch).values())
+
+
+def zeros_by_layer(formats, batch: int, lead: tuple = ()) -> dict:
+    """The empty state of a holder whose layers each have a format of
+    their own (``formats[l]`` layer ``l``'s; all of one kind, so of the
+    same keys): what :meth:`LayeredState.zeros` gives where they are
+    all alike."""
+    shapes = [fmt.buffers(batch) for fmt in formats]
+    return {key: tuple(jnp.zeros(lead + s[key].shape, s[key].dtype)
+                       for s in shapes)
+            for key in shapes[0]}

@@ -338,10 +338,11 @@ class RetentionFormat(LayeredState):
         return valid
 
     @staticmethod
-    def prefill_slot(valid, group):
+    def prefill_slot(valid, group, row=None):
         """What :meth:`prefill` takes as ``slot``: the group and whether
-        the call is real."""
-        return group, valid
+        the call is real; with ``row``, also the sequence of the group
+        from which a piece's prompts lie."""
+        return (group, valid) if row is None else (group, valid, row)
 
     def _heads(self, q, k, v, lg):
         """The block's columns split into heads: ``q`` [.., kv, g, d],
@@ -411,17 +412,18 @@ class RetentionFormat(LayeredState):
         and the chunk's ``phi(k) v^T`` are added, a block of positions
         a product.  ``slot`` is :meth:`prefill_slot`'s: where it says
         the call is a bubble, the update is the identity."""
-        group, valid = slot
+        group, valid, row = slot if len(slot) == 3 else (*slot, 0)
         b, t = q.shape[:2]
         d = self.head_dim
         qh, kh, vh, lg = self._heads(q, k, v, lg)
         kh = jnp.where(valid, kh, jnp.zeros((), kh.dtype))
         lg = jnp.where(valid, lg, 0.0)
         bufs, group = self._group(layer, group)
-        at = (group[0],) + (0,) * (bufs["S"].ndim - 1)
-        s = lax.dynamic_slice(bufs["S"], at, (1,) + bufs["S"].shape[1:])[0]
+        at = (group[0], row) + (0,) * (bufs["S"].ndim - 2)
+        s = lax.dynamic_slice(bufs["S"], at,
+                              (1, b) + bufs["S"].shape[2:])[0]
         z = lax.dynamic_slice(bufs["z"], at[:-1],
-                              (1,) + bufs["z"].shape[1:])[0]
+                              (1, b) + bufs["z"].shape[2:])[0]
         ys = []
         for lo in range(0, t, CHUNK):
             part = slice(lo, min(t, lo + CHUNK))

@@ -18,8 +18,12 @@ TPU-native design, one SPMD program:
     every step), the rest in a stage-sharded flat buffer (the scheme of
     ``SpmdPipeline``).
   * Sequence memory: per device, one resident buffer a local block and
-    key, held and touched only through the format the blocks name
-    (``DecoderBlock.memory_format``).  A KV cache (``ops/kv_cache.py``,
+    key, held and touched only through the format *that block* names
+    (``DecoderBlock.memory_format``: a layer whose attention has a
+    window keeps a ring buffer of the window's rows beside a layer that
+    keeps every position; a stage's layers must name the same formats
+    in the same order on every stage, since a stage-sharded buffer has
+    one shape).  A KV cache (``ops/kv_cache.py``,
     which describes the layout): a step writes one row a block in
     place, every sequence of the group at one position, and attends
     over the group's live rows where they lie; warmup bubbles write the
@@ -70,9 +74,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
 from ..obs import REGISTRY, span
+from ..ops.layered import zeros_by_layer
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
+
+
+#: the most a piece of a group's prefill may hold in its widest
+#: activation (a piece's rows x prompt x the wider of the stream and the
+#: merged heads, in the compute type): a group whose whole prompt
+#: passes it crosses a stage a few sequences at a time
+_PREFILL_PIECE_BYTES = 1 << 28
 
 
 def sample_ids(logits, temp, top_k, step_key):
@@ -211,11 +223,12 @@ class PipelinedDecoder:
         self._wspec_tree = jax.tree.map(
             lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
 
-        #: one local block's memory (the state holds l_max of each), n
-        #: groups of mb sequences, in the format the blocks name
-        self.state_format = nodes[block_names[0]].op.memory_format(
-            self.head_dim, max_len, self.compute_dtype,
-            quantized=kv_cache == "int8", groups=n)
+        #: each local block's memory (n groups of mb sequences), in the
+        #: format that block names: one a local layer, the same on
+        #: every stage
+        self.state_formats = self._layer_formats()
+        #: the first local layer's (every layer's, where they are alike)
+        self.state_format = self.state_formats[0]
         #: the kind of memory that is (``DecoderBlock.memory``)
         self.memory = parts.memory
         if beam_width > 1 and self.memory != "kv_cache":
@@ -225,8 +238,20 @@ class PipelinedDecoder:
                 f"keep a {self.memory} "
                 f"({type(self.state_format).__name__}), which cannot "
                 "hand one sequence's memory to another")
+        by_layer = [n * fmt.state_bytes(mb, 1) for fmt in self.state_formats]
         REGISTRY.gauge(f"decode.{self.memory}.state_bytes").set(
-            n * self.state_format.state_bytes(mb, self.l_max))
+            sum(by_layer))
+        if self.memory == "kv_cache":
+            # the buffers by kind, scratch row and group included: ring
+            # buffers of a window's rows, and a row a position
+            ring = [fmt.window is not None for fmt in self.state_formats]
+            REGISTRY.gauge("decode.cache.window_bytes").set(
+                sum(b for b, w in zip(by_layer, ring) if w))
+            REGISTRY.gauge("decode.cache.full_bytes").set(
+                sum(b for b, w in zip(by_layer, ring) if not w))
+            REGISTRY.gauge("decode.cache.window_positions").set(max(
+                (fmt.window for fmt in self.state_formats if fmt.window),
+                default=0))
         #: the newest generation's state as it left it (device buffers;
         #: dropped when the next generation begins)
         self.state = None
@@ -246,6 +271,48 @@ class PipelinedDecoder:
         self._attend_blocks = [0, 0]
 
     # ------------------------------------------------------------------
+
+    def _layer_formats(self) -> tuple:
+        """The memory format of each local layer, asked of the blocks:
+        the longest stage's, which every other stage's blocks must
+        repeat in order — a buffer is sharded over the stages, so local
+        layer ``l`` has one shape on all of them."""
+        nodes = self.graph.nodes
+
+        def fmt(nm):
+            return nodes[nm].op.memory_format(
+                self.head_dim, self.max_len, self.compute_dtype,
+                quantized=self.kv_cache == "int8", groups=self.num_stages)
+
+        longest = max(self.stage_blocks, key=len)
+        formats = tuple(fmt(nm) for nm in longest)
+        for s, names in enumerate(self.stage_blocks):
+            for l, nm in enumerate(names):
+                if fmt(nm) != formats[l]:
+                    raise ValueError(
+                        f"stage {s}'s layer {l} ({nm}) keeps "
+                        f"{fmt(nm)}, {longest[l]} at the same place of "
+                        f"its stage {formats[l]}: the ring shards one "
+                        "buffer a local layer over the stages, so every "
+                        "stage's layers must repeat the same kinds of "
+                        "memory in the same order (cut the graph at a "
+                        "whole period of its layer pattern)")
+        return formats
+
+    def _prefill_rows(self, plen: int) -> int:
+        """Sequences of a group that cross a stage's prefill at once:
+        all of them where their widest activation fits
+        :data:`_PREFILL_PIECE_BYTES`, else the largest divisor of the
+        group that does (at least one).  From shapes alone."""
+        nodes = self.graph.nodes
+        widest = max([self.d_model] + [
+            nodes[nm].op.num_heads * self.head_dim
+            for nm in self.block_names])
+        row = plen * widest * self.compute_dtype.itemsize
+        mb = self.microbatch
+        return max(r for r in range(1, mb + 1)
+                   if mb % r == 0 and (r == 1 or r * row
+                                       <= _PREFILL_PIECE_BYTES))
 
     def _pack_wbuf(self, params, *, init: bool = False) -> np.ndarray:
         """Pack ``params`` into the [N, Pmax] flat weight buffer; with
@@ -394,7 +461,7 @@ class PipelinedDecoder:
         is_first, is_last = s == 0, s == n - 1
         block_ops = [nodes[nm].op for nm in self.stage_blocks[s]]
         embed_op = self.embed_op
-        fmt = self.state_format
+        fmts = self.state_formats
         beam = self.beam_width
         mb = self.microbatch
         stats = self._stat_names
@@ -409,7 +476,9 @@ class PipelinedDecoder:
             # (host drops them by schedule index)
             valid = jnp.logical_and(pos >= 0, pos < self.max_len)
             safe_pos = jnp.clip(pos, 0, self.max_len - 1)
-            slot = fmt.decode_slot(valid, safe_pos)
+            # each format's own word for where this step's memory goes
+            slots = {fmt: fmt.decode_slot(valid, safe_pos)
+                     for fmt in dict.fromkeys(fmts)}
 
             if beam > 1:
                 # re-parent this group's cache rows before appending the
@@ -424,7 +493,7 @@ class PipelinedDecoder:
                     0, mb - 1)
                 applies = jnp.logical_and(valid, safe_pos >= plen)
                 caches = lax.cond(
-                    applies, lambda cs: fmt.reparent(cs, g, parents),
+                    applies, lambda cs: fmts[0].reparent(cs, g, parents),
                     lambda cs: cs, caches)
 
             if is_first:
@@ -451,8 +520,9 @@ class PipelinedDecoder:
                 # read in place): nothing the size of an item is cut out
                 # of a buffer or written back
                 sown = {} if stats else None
+                fmt = fmts[l]
                 x, layer = op.decode(p[nm], x, fmt.layer(caches, l),
-                                     safe_pos, fmt, slot, g, sown)
+                                     safe_pos, fmt, slots[fmt], g, sown)
                 caches = fmt.with_layer(caches, l, layer)
                 if stats:
                     step = jnp.stack([sown[k] for k in stats])
@@ -535,49 +605,99 @@ class PipelinedDecoder:
         mb, d = self.microbatch, self.d_model
         is_first, is_last = s == 0, s == n - 1
         embed_op = self.embed_op
-        fmt = self.state_format
+        fmts = self.state_formats
+        rows = self._prefill_rows(plen)
+        width = self._prefill_carry_width(plen)
+
+        def slots(valid, group, row=None):
+            """Each format's own word for where the prompts' memory goes."""
+            return {fmt: fmt.prefill_slot(valid, group, row)
+                    for fmt in dict.fromkeys(fmts)}
+
+        def layers(p, x, caches, slot):
+            for l, nm in enumerate(self.stage_blocks[s]):
+                fmt = fmts[l]
+                x, layer = nodes[nm].op.prefill(
+                    p[nm], x, fmt.layer(caches, l), fmt, slot[fmt])
+                caches = fmt.with_layer(caches, l, layer)
+            return x, caches
+
+        def last_logits(p, x):
+            h = nodes["final_ln"].op.apply(p["final_ln"], x[:, -1])
+            return nodes["lm_head"].op.apply(
+                p["lm_head"], h).astype(jnp.float32)
 
         def branch(w_local, a, caches, prompt, g, seed, temp):
             p = self._stage_params(s, w_local)
             valid = jnp.logical_and(g >= 0, g < n)
             safe_g = jnp.clip(g, 0, n - 1)
-            slot = fmt.prefill_slot(valid, safe_g)
 
-            if is_first:
-                ids = lax.dynamic_slice(prompt, (safe_g, 0, 0),
-                                        (1, mb, plen))[0]
-                x = embed_op.apply(p["embeddings"], ids).astype(cd)
+            if rows == mb:
+                slot = slots(valid, safe_g)
+                if is_first:
+                    ids = lax.dynamic_slice(prompt, (safe_g, 0, 0),
+                                            (1, mb, plen))[0]
+                    x = embed_op.apply(p["embeddings"], ids).astype(cd)
+                else:
+                    x = a.reshape(mb, plen, d).astype(cd)
+                x, caches = layers(p, x, caches, slot)
+                out = last_logits(p, x) if is_last else x
             else:
-                x = a.reshape(mb, plen, d).astype(cd)
+                # the group crosses the stage ``rows`` sequences at a
+                # time: a piece is whole prompts, so each layer's
+                # memory is written once a sequence, and the stage's
+                # weights are read once a piece
+                def piece(caches, i):
+                    row = i * rows
+                    if is_first:
+                        ids = lax.dynamic_slice(prompt, (safe_g, row, 0),
+                                                (1, rows, plen))[0]
+                        x = embed_op.apply(p["embeddings"], ids).astype(cd)
+                    else:
+                        x = lax.dynamic_slice(
+                            a.reshape(mb, plen, d), (row, 0, 0),
+                            (rows, plen, d)).astype(cd)
+                    x, caches = layers(p, x, caches,
+                                       slots(valid, safe_g, row))
+                    return caches, last_logits(p, x) if is_last \
+                        else x.reshape(rows, plen * d).astype(jnp.float32)
 
-            for l, nm in enumerate(self.stage_blocks[s]):
-                x, layer = nodes[nm].op.prefill(
-                    p[nm], x, fmt.layer(caches, l), fmt, slot)
-                caches = fmt.with_layer(caches, l, layer)
+                caches, out = lax.scan(piece, caches,
+                                       jnp.arange(mb // rows))
+                out = out.reshape((mb,) + out.shape[2:])
 
-            if is_last:
-                h = nodes["final_ln"].op.apply(p["final_ln"], x[:, -1])
-                logits = nodes["lm_head"].op.apply(
-                    p["lm_head"], h).astype(jnp.float32)
+            if is_last:         # ``out`` the last position's logits
                 if sample:
                     # key domain disjoint from decode's per-step keys
                     ids = sample_ids(
-                        logits, temp, top_k,
+                        out, temp, top_k,
                         jax.random.fold_in(jax.random.PRNGKey(seed),
                                            (1 << 30) + safe_g))
                 else:
-                    ids = jnp.argmax(logits, axis=-1)
-                a_out = jnp.zeros((mb, plen * d), jnp.float32)
+                    ids = jnp.argmax(out, axis=-1)
+                a_out = jnp.zeros((mb, width), jnp.float32)
                 a_out = a_out.at[:, 0].set(ids.astype(jnp.float32))
             else:
-                a_out = x.reshape(mb, plen * d).astype(jnp.float32)
+                a_out = out.reshape(mb, plen * d).astype(jnp.float32)
             return a_out, caches
 
         return branch
 
+    def _prefill_carry_width(self, plen: int) -> int:
+        """Columns of the prefill's ring carry: a group's activations
+        hop from stage to stage, ``plen * d`` a sequence.  One stage
+        sends nothing but the first ids over its wrap link; where its
+        prefill runs in pieces (the carry would be the size that
+        forced them) it carries one column."""
+        if self.num_stages == 1 and self._prefill_rows(plen) \
+                < self.microbatch:
+            return 1
+        return plen * self.d_model
+
     def _state_specs(self):
         """shard_map spec pytree for the cache-state dict."""
         # one buffer a local block, never one array of the whole stack
+        # (layers' buffers may differ in length, not in rank)
         specs = {key: (P(STAGE_AXIS, *(None,) * len(buf.shape)),)
                  * self.l_max
                  for key, buf in self.state_format.buffers(
@@ -596,13 +716,14 @@ class PipelinedDecoder:
         perm = [(k, (k + 1) % n) for k in range(n)]
         branches = [self._make_prefill_branch(s, plen, sample, top_k)
                     for s in range(n)]
-        mb, d = self.microbatch, self.d_model
+        mb = self.microbatch
+        width = self._prefill_carry_width(plen)
         num_steps = 2 * n - 1  # n groups through n stages, pipelined
 
         def device_prefill(w, prompt, seed, temp, caches):
             w_l = jax.tree.map(lambda x: x[0], w)
             idx = lax.axis_index(STAGE_AXIS)
-            a0 = jnp.zeros((mb, plen * d), jnp.float32)
+            a0 = jnp.zeros((mb, width), jnp.float32)
             local = jax.tree.map(lambda c: c[0], caches)
 
             def body(carry, t):
@@ -642,7 +763,7 @@ class PipelinedDecoder:
                 self._state_specs())
 
             def zeros():
-                caches = self.state_format.zeros(mb, self.l_max, lead=(n,))
+                caches = zeros_by_layer(self.state_formats, mb, lead=(n,))
                 if self.beam_width > 1:
                     caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
                 if self._stat_names:
@@ -735,7 +856,6 @@ class PipelinedDecoder:
         own arithmetic (``device_decode``), so the host reckons what
         the kernel will read before the device has run any of it.  Only
         a cache has positions to skip, and the gauge."""
-        fmt = self.state_format
         if self.memory != "kv_cache":
             return
         n = self.num_stages
@@ -744,10 +864,11 @@ class PipelinedDecoder:
         pos = start + rel // n
         real = (rel >= 0) & (t < num_steps) & (pos >= 0) \
             & (pos < self.max_len)
-        read, held = fmt.live_block_share(
-            np.where(real, pos, fmt.scratch_position))
-        self._attend_blocks[0] += read
-        self._attend_blocks[1] += held
+        for fmt in self.state_formats:
+            read, held = fmt.live_block_share(
+                np.where(real, pos, fmt.bubble_slot))
+            self._attend_blocks[0] += read
+            self._attend_blocks[1] += held
         REGISTRY.gauge("decode.attend.live_block_share").set(
             self._attend_blocks[0] / self._attend_blocks[1])
 

@@ -4,7 +4,9 @@ part of the tests.
 
 The ring's decode and prefill programs (``gpt_tiny`` and ``olmoe_tiny``;
 ``kv_cache`` buffer and int8; ``beam_width`` 1 and 2; 1 stage and
-several; ``olmoe_tiny``'s widths at four layers for four stages) and
+several; ``olmoe_tiny``'s widths at four layers for four stages;
+``brumby_tiny``, whose state has neither int8 rows nor beams;
+``cohere_moe_tiny``, a format a layer) and
 the engine's step (greedy and sampling), lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -26,18 +28,20 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from defer_tpu.models import gpt_tiny, olmoe, olmoe_tiny
+from defer_tpu.models import (brumby_tiny, cohere_moe_tiny, gpt_tiny, olmoe,
+                              olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
 PLEN, CHUNK = 5, 2
 
 
-def ring_programs(name, graph, stages):
+def ring_programs(name, graph, stages, kv_caches=("buffer", "int8"),
+                  beams=(1, 2)):
     params = graph.init(jax.random.key(0))
     for n in stages:
-        for kv_cache in ("buffer", "int8"):
-            for beam in (1, 2):
+        for kv_cache in kv_caches:
+            for beam in beams:
                 dec = PipelinedDecoder(
                     graph, params, num_stages=n, microbatch=2, max_len=16,
                     kv_cache=kv_cache, beam_width=beam)
@@ -83,6 +87,12 @@ def main() -> int:
                 *ring_programs("olmoe_4l", olmoe(
                     4, 64, 4, 16, vocab=211, num_experts=8,
                     experts_per_tok=2, expert_hidden=32), (4,)),
+                # a retention state has neither int8 rows nor beams
+                *ring_programs("brumby_tiny", brumby_tiny(), (1, 2),
+                               kv_caches=("buffer",), beams=(1,)),
+                # window layers' ring buffers beside full layers' caches:
+                # one period a stage
+                *ring_programs("cohere_moe_tiny", cohere_moe_tiny(), (1, 2)),
                 *engine_programs()]
     for name, lowered in programs:
         text = lowered.as_text()

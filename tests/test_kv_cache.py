@@ -518,3 +518,160 @@ def test_the_engine_runs_on_a_format_with_positions_leading(
     assert eng._caches["k"][0].shape == (24, 3, 2, 16)
     for rid, ids in want.items():
         np.testing.assert_array_equal(got[rid], ids)
+
+
+# -- ring buffers and joined rows ----------------------------------------------
+
+def _dense_attention(q, k, v, pos, window=None):
+    """softmax(q k / sqrt hd) v over positions ``<= pos`` (and, with a
+    window, the ``window`` newest): q [b, nh, hd], k / v [b, kv, T, hd]."""
+    b, nh, hd = q.shape
+    g = nh // k.shape[1]
+    k, v = np.repeat(k, g, axis=1), np.repeat(v, g, axis=1)
+    s = np.einsum("bhd,bhtd->bht", q, k) / math.sqrt(hd)
+    t = np.arange(k.shape[2])[None, None, :]
+    seen = t <= pos
+    if window is not None:
+        seen &= pos - t < window
+    s = np.where(seen, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bht,bhtd->bhd", p, v).reshape(b, nh * hd)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["buffer", "int8"])
+@pytest.mark.parametrize("groups", [None, 2], ids=["slots", "groups"])
+def test_a_ring_buffer_holds_window_rows_and_the_scratch_row(quantized,
+                                                             groups):
+    fmt = KVCacheFormat(KV, HD, 20, jnp.float32, quantized=quantized,
+                        groups=groups, window=6)
+    lead = () if groups is None else (groups + 1,)
+    assert fmt.buffers(3)["k"].shape == lead + (3, KV, 6 + (groups
+                                                             is not None), HD)
+    assert fmt.rows_held == 6 and fmt.scratch_position == 6
+    assert fmt.bubble_slot == -1
+    assert KVCacheFormat(KV, HD, 20, jnp.float32).rows_held == 20
+    with pytest.raises(ValueError, match="never wraps"):
+        KVCacheFormat(KV, HD, 6, jnp.float32, window=6)
+    with pytest.raises(NotImplementedError):
+        fmt.write_slots({}, {}, jnp.zeros(3, jnp.int32))
+
+
+@pytest.mark.parametrize("g,hd,dtype", [
+    (1, 8, jnp.float32), (4, 8, jnp.float32), (4, 64, jnp.bfloat16),
+    (16, 128, jnp.float32), (16, 128, jnp.bfloat16), (8, 128, jnp.float32)],
+    ids=["g1", "g4", "g4-hd64-bf16", "g16-joined", "g16-joined-bf16",
+         "g8-joined"])
+@pytest.mark.parametrize("plen", [3, 6, 7, 17], ids=[
+    "under", "at", "over", "wrapped-twice"])
+def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
+    """``write_prefix`` of a prompt under, at and over the window, then
+    decode steps across the next wraps: every step's attention is the
+    dense attention over the ``window`` newest positions — through the
+    vector kernel (groups 1 and 4) and through joined rows on the matrix
+    unit (8 and 16 queries a KV head of 128)."""
+    kv, w, total, b = 2, 6, 30, 2
+    fmt = KVCacheFormat(kv, hd, total, dtype, groups=1, window=w,
+                        query_group=g)
+    assert fmt.joined == (g >= 8 and hd == 128)
+    if fmt.joined:
+        # whole sublane tiles of positions: the window, the scratch
+        # row, and padding
+        assert fmt.buffers(b)["k"].shape == (2, b, 16, kv * hd)
+    rng = np.random.default_rng(plen)
+    q = rng.standard_normal((b, total, kv * g * hd)).astype(np.float32)
+    k = rng.standard_normal((b, total, kv * hd)).astype(np.float32)
+    v = rng.standard_normal((b, total, kv * hd)).astype(np.float32)
+    qd, kd, vd = (jnp.asarray(a, dtype) for a in (q, k, v))
+
+    @jax.jit
+    def run(qd, kd, vd):
+        layer = fmt.layer(fmt.zeros(b, 1), 0)
+        layer = fmt.write_prefix(layer, kd[:, :plen], vd[:, :plen],
+                                 fmt.prefill_slot(True, 0))
+        ys = []
+        for pos in range(plen, plen + 9):
+            slot = fmt.decode_slot(True, jnp.int32(pos))
+            layer = fmt.write_position(
+                layer, fmt.rows(kd[:, pos], vd[:, pos]), slot, group=0)
+            ys.append(fmt.attend(qd[:, pos], layer, slot, group=0))
+        # a bubble writes the scratch row and reads something finite
+        bubble = fmt.decode_slot(False, jnp.int32(3))
+        after = fmt.write_position(
+            layer, fmt.rows(kd[:, 0] * 0 + 7, vd[:, 0]), bubble, group=0)
+        return jnp.stack(ys), fmt.attend(qd[:, 0], after, bubble, group=0), \
+            layer, after
+
+    ys, y_bubble, layer, after = run(qd, kd, vd)
+    f32 = [np.asarray(a.astype(jnp.float32)) for a in (qd, kd, vd)]
+    heads = [a.reshape(b, total, -1, hd).transpose(0, 2, 1, 3) for a in f32]
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for i, pos in enumerate(range(plen, plen + 9)):
+        want = _dense_attention(heads[0][:, :, pos], heads[1], heads[2],
+                                pos, window=w)
+        np.testing.assert_allclose(np.asarray(ys[i], np.float32), want,
+                                   atol=tol, err_msg=f"pos {pos}")
+    assert np.isfinite(np.asarray(y_bubble, np.float32)).all()
+    # the bubble touched the scratch row alone
+    for key in ("k", "v"):
+        a, c = np.asarray(layer[key][0], np.float32), \
+            np.asarray(after[key][0], np.float32)
+        rows = (slice(None), slice(0, w)) if fmt.joined \
+            else (slice(None), slice(None), slice(0, w))
+        np.testing.assert_array_equal(a[rows], c[rows])
+    # and the oracle learns the same window
+    item = fmt.head_major({key: buf[0] for key, buf in layer.items()})
+    last = plen + 8
+    got = attend_einsum(qd[:, last], item, last, window=w)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ys[-1], np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("positions", [[5, 300], [511, 512], [1029, 0]],
+                         ids=["block0", "edge", "three-blocks"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_joined_attention_at_each_sequences_own_position(positions, dtype):
+    """16 queries a KV head over joined rows, each sequence at its own
+    position, over several position blocks and a length that is no
+    multiple of the block: the einsums over the live rows."""
+    kv, g, hd, length, b = 2, 16, 128, 1100, 2
+    fmt = KVCacheFormat(kv, hd, length, dtype, query_group=g)
+    assert fmt.joined
+    rng = np.random.default_rng(7)
+    layer = {key: jnp.asarray(rng.standard_normal(s.shape), dtype)
+             for key, s in fmt.buffers(b).items()}
+    q = jnp.asarray(rng.standard_normal((b, kv * g * hd)), dtype)
+    pos = jnp.asarray(positions, jnp.int32)
+    got = jax.jit(fmt.attend)(q, layer, pos)
+    want = attend_einsum(q.astype(jnp.float32), {
+        key: buf.astype(jnp.float32)
+        for key, buf in fmt.head_major(layer).items()}, pos)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want),
+        atol=2e-5 if dtype == jnp.float32 else 3e-2)
+    read, held = fmt.live_block_share(np.asarray(positions))
+    tl = kv_cache.joined_block_rows(kv, hd, 1104, jnp.dtype(dtype).itemsize)
+    assert held == 2 * -(-1104 // tl)        # 1100 rows in tiles of 16
+    assert read == sum(p // tl + 1 for p in positions)
+
+
+def test_a_joined_prefix_of_a_piece_lands_at_its_sequences():
+    """``prefill_slot`` with a row: a piece of a group, written from
+    that sequence on, the rest of the group untouched."""
+    for g in (1, 16):
+        fmt = KVCacheFormat(2, 128, 12, jnp.float32, groups=2,
+                            query_group=g)
+        rng = np.random.default_rng(g)
+        k = jnp.asarray(rng.standard_normal((2, 5, 256)), jnp.float32)
+        layer = fmt.layer(fmt.zeros(4, 1), 0)
+        slot = fmt.prefill_slot(True, 1, 2)
+        out = jax.jit(lambda layer: fmt.write_prefix(layer, k, k + 1, slot))(
+            layer)
+        item = fmt.head_major({key: buf[1] for key, buf in out.items()})
+        want = np.asarray(k).reshape(2, 5, 2, 128).transpose(0, 2, 1, 3)
+        np.testing.assert_array_equal(np.asarray(item["k"])[2:, :, :5], want)
+        np.testing.assert_array_equal(np.asarray(item["v"])[2:, :, :5],
+                                      want + 1)
+        assert not np.asarray(item["k"])[:2].any()
+        assert not np.asarray(out["k"][0]).any()
