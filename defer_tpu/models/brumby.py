@@ -29,10 +29,10 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import Dense, RMSNorm, _cast, rms_norm
+from ..graph.ops import RMSNorm, _cast, rms_norm
 from ..ops import retention
 from .decoder import RetentionBlock
-from .olmoe import OlmoeEmbedding, rope
+from .olmoe import OlmoeEmbedding, OlmoeHead, rope
 
 
 @dataclasses.dataclass(frozen=True, repr=False)
@@ -170,21 +170,6 @@ class BrumbyBlock(RetentionBlock, Op):
                 + 2 * t * rows * hd * (self.num_kv_heads + self.num_heads))
 
 
-class BrumbyEmbedding(OlmoeEmbedding):
-    """The token embedding (positions enter through RoPE), its table an
-    argument of its own on the ring."""
-
-    stage_arg_keys = ("wte",)
-
-
-@dataclasses.dataclass(frozen=True, repr=False)
-class BrumbyHead(Dense):
-    """The untied, bias-free output head, its matrix an argument of its
-    own on the ring."""
-
-    stage_arg_keys = ("w",)
-
-
 def brumby(num_layers: int, hidden: int, heads: int, kv_heads: int,
            mlp_hidden: int, seq_len: int, vocab: int = 151936,
            rope_theta: float = 1e6, rms_eps: float = 1e-6,
@@ -197,14 +182,14 @@ def brumby(num_layers: int, hidden: int, heads: int, kv_heads: int,
     bias-free head; RMSNorm ``final_ln``."""
     b = GraphBuilder(name)
     x = b.input((seq_len,), jnp.int32)
-    x = b.add(BrumbyEmbedding(vocab, hidden, seq_len), x, name="embeddings")
+    x = b.add(OlmoeEmbedding(vocab, hidden, seq_len), x, name="embeddings")
     for i in range(num_layers):
         x = b.add(BrumbyBlock(heads, kv_heads, mlp_hidden,
                               rope_theta=rope_theta, rms_eps=rms_eps,
                               head_dim=head_dim),
                   x, name=f"block_{i}")
     x = b.add(RMSNorm(eps=rms_eps), x, name="final_ln")
-    x = b.add(BrumbyHead(vocab, use_bias=False), x, name="lm_head")
+    x = b.add(OlmoeHead(vocab, use_bias=False), x, name="lm_head")
     return b.build()
 
 

@@ -256,13 +256,6 @@ class CohereMoeBlock(DecoderBlock, Op):
                 * 2 * t * 3 * d * self.expert_hidden)
 
 
-class CohereEmbedding(OlmoeEmbedding):
-    """The token embedding (positions enter through the window layers'
-    RoPE, or not at all), its table an argument of its own on the ring."""
-
-    stage_arg_keys = ("wte",)
-
-
 @dataclasses.dataclass(frozen=True, repr=False)
 class ScaleLayerNorm(Op):
     """LayerNorm with a scale and no bias (:func:`layer_norm`)."""
@@ -336,7 +329,7 @@ def cohere_moe(num_layers: int, hidden: int, heads: int, kv_heads: int,
         experts_held = tuple(experts_held)
     b = GraphBuilder(name)
     x = b.input((seq_len,), jnp.int32)
-    x = b.add(CohereEmbedding(vocab, hidden, seq_len), x, name="embeddings")
+    x = b.add(OlmoeEmbedding(vocab, hidden, seq_len), x, name="embeddings")
     for i in range(num_layers):
         kind = layer_types[i % len(layer_types)]
         x = b.add(CohereMoeBlock(

@@ -9,10 +9,14 @@ What it answers before any chip time is spent (PERF.md, PR 26):
 * does the prefill compute 8 experts a token and not 64 (``cost_analysis``
   flops against ``chipbench.roofline_moe.olmoe_prefill_needs``: within
   1.5x);
-* is an expert-sized array copied anywhere in the decode program (a
-  ``copy`` or a ``fusion`` whose result has an expert leaf's shape): the
-  expert leaves are stage-sharded arguments of their own because a slice
-  of the flat weight row would be one;
+* is a weight matrix copied anywhere in the decode program's loop (an
+  instruction that produces an array of a matrix's size): every matrix
+  — the experts', ``q`` / ``k`` / ``v`` / ``proj`` / ``router``, the
+  embedding's, the head's — is a stage-sharded argument of its own
+  (PR 35; the experts' since PR 26) because a leaf cut out of the flat
+  weight row is one, every step (``weight_copies_in_loop``;
+  ``weight_copies_per_dispatch`` counts layout conversions around the
+  loop, once a call);
 * does a step cut a group's item out of a cache buffer, or the compiled
   loop convert a whole buffer to a layout of its own (both did until
   PR 29: the attention is now a kernel over the buffers as they lie,
@@ -21,7 +25,8 @@ What it answers before any chip time is spent (PERF.md, PR 26):
     env JAX_PLATFORMS=cpu python scripts/olmoe_tpu_compile_check.py
 
 A minute or two and ~8 GB of host memory (the weights are zeros); one
-JSON line; exit 0 when all four hold.  A process of its own, like
+JSON line; exit 0 when all four hold (per-dispatch copies are reported,
+not refused).  A process of its own, like
 ``decode_tpu_compile_check.py``: the TPU's library is locked machine-wide
 while it runs.
 """
@@ -47,7 +52,7 @@ from chipbench.roofline_moe import olmoe_prefill_needs
 from defer_tpu.models import olmoe
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
-from hlo_cache_ops import computations, count_cache_ops
+from hlo_cache_ops import computations, count_cache_ops, weight_copies
 
 ARGS = dict(num_layers=8, hidden=2048, heads=16, seq_len=4096, vocab=50304,
             num_experts=64, experts_per_tok=8, expert_hidden=1024)
@@ -129,8 +134,11 @@ def main() -> int:
                 "alias_gb": m.alias_size_in_bytes / 1e9}
 
     shape = dec.state_format.buffers(MB)["k"].shape
-    cache_ops = count_cache_ops(computations(text), shape[1:], shape)
-    row = {"device_kind": topo.devices[0].device_kind,
+    comps = computations(text)
+    cache_ops = count_cache_ops(comps, shape[1:], shape)
+    copies = weight_copies(comps, [leaf.shape for leaf in
+                                   jax.tree.leaves(params) if leaf.ndim > 1])
+    row = {"device_kind": topo.devices[0].device_kind, **copies,
            "prefill": mem(prefill), "decode": mem(decode),
            "decode_cache_ops": cache_ops,
            "prefill_flops": flops, "prefill_needs_flops": needs_flops,
@@ -140,7 +148,8 @@ def main() -> int:
            }
     print(json.dumps(row))
     ok = not produced and flops / needs_flops <= 1.5 and not (
-        cache_ops["item_copies"] or cache_ops["buffer_copies"])
+        cache_ops["item_copies"] or cache_ops["buffer_copies"]
+        or copies["weight_copies_in_loop"])
     return 0 if ok else 1
 
 

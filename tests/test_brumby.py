@@ -333,13 +333,11 @@ def test_every_matrix_rides_beside_the_flat_row(model):
     wrong = dict(params, lm_head={"w": params["lm_head"]["w"][:, :-1]})
     with pytest.raises(ValueError, match="lm_head's leaves outside"):
         dec.reweight(wrong)
-    # GPT-2's ends are named too (PR 32); OLMoE names nothing outside
-    # its blocks
-    for other, ends in ((gpt_tiny(), {"embeddings", "lm_head"}),
-                        (olmoe_tiny(), set())):
+    # GPT-2's ends are named too (PR 32), and OLMoE's (PR 35)
+    for other in (gpt_tiny(), olmoe_tiny()):
         d2 = PipelinedDecoder(other, other.init(jax.random.key(0)),
                               num_stages=1, max_len=8)
-        assert set(d2._own_ends) == ends
+        assert set(d2._own_ends) == {"embeddings", "lm_head"}
 
 
 def test_the_published_model_builds_at_its_widths():

@@ -385,14 +385,16 @@ def test_decoder_reweight_no_recompile(model, prompt, num_stages):
     np.testing.assert_array_equal(dec.generate(prompt, 6), a)
 
 
-@pytest.mark.parametrize("node,key", [("block_2", "qkv"), ("block_0", "fc1"),
-                                      ("embeddings", "wte")])
-def test_reweight_changed_matrices_and_wrong_shapes(model, prompt, node, key):
+@pytest.mark.parametrize("name,node,key", [
+    ("gpt_tiny", "block_2", "qkv"), ("gpt_tiny", "block_0", "fc1"),
+    ("gpt_tiny", "embeddings", "wte"), ("olmoe_tiny", "block_1", "q"),
+    ("olmoe_tiny", "block_0", "router"), ("olmoe_tiny", "embeddings", "wte")])
+def test_reweight_changed_matrices_and_wrong_shapes(prompt, name, node, key):
     """The leaves a node keeps beside the flat rows (``stage_arg_keys``):
     ``reweight`` with one changed gives what a fresh decoder on those
     weights gives, through the programs already compiled, and one of
     another shape is refused by its node's name."""
-    graph, params = model
+    graph, params = _family(name, seq_len=MAX_LEN, vocab=VOCAB)
     dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=4,
                            max_len=MAX_LEN)
     a = dec.generate(prompt, 6, prefill=True)
@@ -884,28 +886,28 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
                       if beam > 1 else 0)
 
 
-@pytest.mark.parametrize("num_stages,beam", [(1, 1), (4, 1), (3, 1), (2, 2)])
-def test_decode_step_cuts_no_weight_out_of_the_flat_row(model, num_stages,
+@pytest.mark.parametrize("name,num_stages,beam", [
+    ("gpt_tiny", 1, 1), ("gpt_tiny", 4, 1), ("gpt_tiny", 3, 1),
+    ("gpt_tiny", 2, 2), ("olmoe_tiny", 1, 1), ("olmoe_tiny", 2, 1)])
+def test_decode_step_cuts_no_weight_out_of_the_flat_row(name, num_stages,
                                                         beam):
     """Structural guard of the decode program's scan body: no ``slice``
-    or ``reshape`` produces an array of a weight matrix's size.  A leaf
+    or ``reshape`` produces a weight matrix, flat or in its shape.  A leaf
     that rode the flat row was cut out of it and laid out anew inside
     the loop, every step (12 of 21 ms a step at GPT-2 XL's widths on the
-    chip); the leaves the nodes name in ``stage_arg_keys`` arrive as
-    arguments in their own shapes."""
-    graph, params = model
+    chip, 7 of 21 at OLMoE's with the experts alone beside the row); the
+    leaves the nodes name in ``stage_arg_keys`` arrive as arguments in
+    their own shapes."""
+    graph, params = _family(name, seq_len=MAX_LEN, vocab=VOCAB)
     dec = PipelinedDecoder(graph, params, num_stages=num_stages,
                            microbatch=4, max_len=MAX_LEN, beam_width=beam)
-    sizes = {np.size(params[nm][key]["w"] if sub else params[nm][key])
-             for nm, key, sub in (("block_0", "qkv", True),
-                                  ("block_0", "fc1", True),
-                                  ("block_0", "proj", True),
-                                  ("embeddings", "wte", False),
-                                  ("embeddings", "wpe", False),
-                                  ("lm_head", "w", False))}
+    # the row's cut (``flatbuf.unpack_leaves``): a 1-D slice of the
+    # leaf's size, reshaped to the leaf's shape
+    shapes = {shape for leaf in jax.tree.leaves(params) if leaf.ndim > 1
+              for shape in ((leaf.size,), leaf.shape)}
     cut = [eqn for eqn in _walk(_decode_scan_body(dec, 2 * num_stages))
            if eqn.primitive.name in ("slice", "reshape")
-           and eqn.outvars[0].aval.size in sizes]
+           and eqn.outvars[0].aval.shape in shapes]
     assert not cut, cut
 
 
@@ -946,10 +948,10 @@ def test_block_decode_is_its_two_halves(model, quant):
 
 # -- a format a layer, and a prefill in pieces ---------------------------------
 
-def _family(name):
+def _family(name, **size):
     from defer_tpu import models
     from defer_tpu.models.cohere_moe import tie_head
-    graph = getattr(models, name)()
+    graph = getattr(models, name)(**size)
     params = graph.init(jax.random.key(3))
     return graph, tie_head(params) if name == "cohere_moe_tiny" else params
 

@@ -252,23 +252,23 @@ def test_the_int8_cache_serves_the_same_blocks(model, ids):
 
 def test_the_ring_holds_expert_leaves_as_arguments_of_their_own(model):
     """The experts ride beside the flat rows, a leaf a local block,
-    stage-sharded; every other leaf is in the rows; ``reweight`` swaps
-    both and checks both."""
+    stage-sharded — and since PR 35 every other matrix with them: only
+    the norms' scales are in the rows; ``reweight`` swaps both and
+    checks both."""
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
                            max_len=SEQ)
-    assert set(dec._w) == {"flat", "own"} and len(dec._w["own"]) == 1
+    assert set(dec._w) == {"flat", "own", "ends"}
+    assert len(dec._w["own"]) == 1
     own = dec._w["own"][0]["experts"]
     assert {k: v.shape for k, v in own.items()} == {
         "gate": (2, 8, 64, 32), "up": (2, 8, 64, 32),
         "down": (2, 8, 32, 64)}
     np.testing.assert_array_equal(np.asarray(own["up"][1]),
                                   np.asarray(params["block_1"]["experts"]["up"]))
-    experts = sum(int(np.prod(v.shape)) for v in own.values())
-    total = sum(int(np.prod(a.shape))
-                for a in jax.tree.leaves(params))
-    assert dec._w["flat"].shape[0] == 2
-    assert dec._w["flat"].shape[1] < total - experts    # a stage's share
+    # a stage's share of the row: its block's four scales, and the
+    # last stage's ``final_ln``
+    assert dec._w["flat"].shape == (2, 5 * 64)
     before = dec.generate(np.zeros((4, 4), np.int32), 4)
     other = graph.init(jax.random.key(99))
     dec.reweight(other)
@@ -282,6 +282,63 @@ def test_the_ring_holds_expert_leaves_as_arguments_of_their_own(model):
         lambda a: a[:4], bad["block_0"]["experts"]))
     with pytest.raises(ValueError, match="reweight"):
         dec.reweight(bad)
+
+
+def _nbytes(tree):
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("layers,num_stages", [(2, 1), (2, 2), (3, 2)],
+                         ids=["stages1", "stages2", "uneven"])
+def test_olmoe_weights_ride_beside_the_flat_row(layers, num_stages):
+    """What OLMoE's nodes declare (``stage_arg_keys``) the ring keeps out
+    of the flat row, as stage-sharded arguments of their own, each end
+    on the stage that holds it: only the norms' scales are left on the
+    row, and the two gauges say so."""
+    from defer_tpu.models.olmoe import OlmoeEmbedding, OlmoeHead
+    graph = olmoe(layers, 64, 4, SEQ, vocab=VOCAB, num_experts=8,
+                  experts_per_tok=2, expert_hidden=32)
+    params = graph.init(jax.random.key(4))
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=SEQ)
+    n, last = num_stages, num_stages - 1
+    assert set(dec._w) == {"flat", "own", "ends"}
+    assert len(dec._w["own"]) == dec.l_max == -(-layers // n)
+    scales = {"ln1", "q_norm", "k_norm", "ln2"}
+    for leaves in dec._w["own"]:
+        assert set(leaves) == set(OlmoeBlock.stage_arg_keys) \
+            == set(params["block_0"]) - scales
+    assert OlmoeEmbedding.stage_arg_keys == ("wte",)
+    assert OlmoeHead.stage_arg_keys == ("w",)
+    assert set(dec._w["ends"]) == {"embeddings", "lm_head"}
+    for leaf in jax.tree.leaves((dec._w["own"], dec._w["ends"])):
+        assert leaf.shape[0] == n
+    # each end on the stage that holds it, zeros elsewhere
+    wte = np.asarray(dec._w["ends"]["embeddings"]["wte"])
+    head = np.asarray(dec._w["ends"]["lm_head"]["w"])
+    np.testing.assert_array_equal(wte[0], params["embeddings"]["wte"])
+    np.testing.assert_array_equal(head[last], params["lm_head"]["w"])
+    assert not wte[1:].any() and not head[:last].any()
+    # a stage with fewer blocks than the fullest holds a zeroed stand-in
+    for s, blocks in enumerate(dec.stage_blocks):
+        for l in range(dec.l_max):
+            for key in ("q", "router"):
+                w = np.asarray(dec._w["own"][l][key]["w"][s])
+                if l < len(blocks):
+                    np.testing.assert_array_equal(
+                        w, np.asarray(params[blocks[l]][key]["w"]))
+                else:
+                    assert not w.any()
+    row = REGISTRY.gauge("decode.weights.row_bytes").value
+    own = REGISTRY.gauge("decode.weights.own_bytes").value
+    assert row == _nbytes(params["final_ln"]) + sum(
+        _nbytes(params[f"block_{i}"][k])
+        for i in range(layers) for k in scales) == (4 * layers + 1) * 64 * 4
+    assert row + own == _nbytes(params)
+    # the fullest stage's share, the last one's ``final_ln`` with it
+    assert dec._w["flat"].shape == (
+        n, 64 * max(4 * len(b) + (s == last)
+                    for s, b in enumerate(dec.stage_blocks)))
 
 
 def test_an_uneven_split_pads_the_leaves_of_the_shorter_stage(ids):
@@ -301,6 +358,30 @@ def test_an_uneven_split_pads_the_leaves_of_the_shorter_stage(ids):
     np.testing.assert_array_equal(
         two.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=2),
         one.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=2))
+
+
+#: recorded on the parent commit (169e9f5, where every matrix but the
+#: experts' rode the flat row): ``olmoe_tiny(seq_len=24, vocab=211)``, key
+#: 3, the four seeded prompts' first 8 tokens, 16 greedy tokens, prefill,
+#: token_chunk 2; the same on 1 and 2 stages.
+PARENT_OLMOE_TOKENS_SHA = {"float32": "95572485a40a23a2",
+                           "bfloat16": "e2ea0d15ef1dbda0"}
+
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_olmoe_tiny_decodes_as_on_the_parent(model, ids, dtype, num_stages):
+    """A leaf handed over as an argument of its own is cast as the row's
+    leaves are and multiplied as before: the tokens are the parent
+    commit's, bit for bit, in both compute types."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=4 // num_stages, max_len=SEQ,
+                           compute_dtype=jnp.dtype(dtype))
+    toks = dec.generate(ids[:, :PLEN], SEQ - PLEN, prefill=True,
+                        token_chunk=2)
+    assert hashlib.sha256(str(toks.tolist()).encode()).hexdigest()[:16] \
+        == PARENT_OLMOE_TOKENS_SHA[dtype]
 
 
 # -- the counters ----------------------------------------------------------------
@@ -389,7 +470,8 @@ def test_blocks_of_both_families_meet_the_rings_interface(model):
     for name in ("rows", "write_position", "write_slots", "write_prefix",
                  "reparent", "attend"):
         assert callable(getattr(KVCacheFormat, name))
-    assert OlmoeBlock.stage_arg_keys == ("experts",)
+    assert OlmoeBlock.stage_arg_keys == ("q", "k", "v", "proj", "router",
+                                         "experts")
     assert gpt_tiny().nodes["block_0"].op.decode_stats == ()
     assert DecoderBlock.__module__ == "defer_tpu.models.decoder"
     assert ".gpt" not in inspect.getsource(olmoe_module)    # no sibling
