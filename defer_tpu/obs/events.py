@@ -77,6 +77,11 @@ EVENT_KINDS = {
                  "episode (count, via, label, shapes)",
     "mem_pressure": "live device-array bytes crossed the configured "
                     "threshold (bytes, threshold, live_arrays)",
+    "host_pause": "a program phase took far longer than it usually does "
+                  "(layer, phase, round, wall_ms, typical_ms, cpu_ms, "
+                  "proc_cpu_ms; since the thread's baseline: since_ms, "
+                  "vol_switches, invol_switches, major_faults, "
+                  "runq_wait_ms, steal_ms, gc_collections)",
     "journal": "the black-box journal spiller started or stopped "
                "(action, dir)",
     "postmortem": "a postmortem bundle was assembled "

@@ -1,6 +1,6 @@
 """Stage-interior profiling plane (docs/OBSERVABILITY.md §Profiling).
 
-Three instruments that compose with the live observability plane
+Four instruments that compose with the live observability plane
 instead of replacing it:
 
 * **Phase decomposition** — the compute loops split each frame's
@@ -21,6 +21,11 @@ instead of replacing it:
   process that never used it; :class:`MemoryWatcher` turns it into the
   ``device.mem_bytes`` gauge plus a thresholded ``mem_pressure`` event
   (hysteresis re-arm at 90% of the threshold).
+* **Pause telemetry** — :class:`PauseWatcher` is fed by the program
+  spans themselves (``obs/trace.py::span``): an occurrence of a phase
+  that took far longer than the phase usually does is a *pause*, and
+  leaves a counter, a histogram, one ``host_pause`` event that says
+  what the thread was doing, and a line on stderr.
 
 :class:`ProfileSession` is the on-demand half: a node's
 ``profile_start``/``profile_stop`` control commands bracket a window
@@ -35,13 +40,16 @@ for.
 
 from __future__ import annotations
 
+import gc
 import os
+import resource
 import sys
 import threading
 import time
 
 from .events import emit as emit_event
 from .registry import REGISTRY
+from .trace import ANNOTATION_PREFIX, ROUND_ARGS
 
 #: the named phases of one frame through a stage node's compute loop,
 #: in wall order.  ``dispatch`` + ``queue`` + ``device`` + ``host_sync``
@@ -61,6 +69,11 @@ NODE_PHASES = ("dispatch", "queue", "device", "host_sync")
 #: profile CLI's --jax-trace), not host timers.
 ENGINE_PHASES = ("gather", "dispatch", "device", "sync", "delivery")
 
+#: inside the engine's ``dispatch``, in wall order: ``upload`` is the
+#: four ``jnp.asarray`` of the per-slot rows (host to device), ``launch``
+#: the jitted step's call alone
+ENGINE_DISPATCH_PHASES = ("upload", "launch")
+
 #: what the engine's scheduling thread does between two steps
 #: (serve/engine.py ``EngineLoop.run``): ``join`` is the sweep that
 #: applies cancellations and moves queued requests into free slots —
@@ -68,13 +81,21 @@ ENGINE_PHASES = ("gather", "dispatch", "device", "sync", "delivery")
 #: taken with no active slot — the engine has nothing to do
 ENGINE_LOOP_PHASES = ("join", "park")
 
-#: one chunk of ``PipelinedDecoder.generate`` (runtime/decode.py), in
-#: wall order after the one ``prefill``: ``dispatch`` is the chunk
+#: ``PipelinedDecoder.generate`` (runtime/decode.py), in wall order:
+#: ``init`` is what a generation sets up on the host before its first
+#: chunk, in two parts around the one ``prefill`` (the prompt's upload
+#: and the zero state; then the first tokens' upload, the schedule and
+#: the result buffer).  Then once a chunk: ``dispatch`` is the chunk
 #: program's call returning (the enqueue; no sync), ``sync`` the
 #: ``np.asarray`` that waits for its ids, ``scatter`` the host copy
-#: into the result, ``emit`` the caller's ``on_tokens``.  During
+#: into the result, ``emit`` the caller's ``on_tokens``.  During init,
 #: dispatch, scatter and emit the device has nothing queued.
-DECODE_PHASES = ("prefill", "dispatch", "sync", "scatter", "emit")
+DECODE_PHASES = ("init", "prefill", "dispatch", "sync", "scatter", "emit")
+
+#: inside the ring's ``dispatch``, in wall order: ``upload`` is the
+#: chunk's host-to-device scalars (where the chunk starts and stops),
+#: ``launch`` the jitted chunk program's call alone
+DECODE_DISPATCH_PHASES = ("upload", "launch")
 
 #: after a generation's last chunk, where the ring's blocks sow per-step
 #: statistics (``DecoderBlock.decode_stats``; today the routed experts'
@@ -93,10 +114,42 @@ DOOR_PHASES = ("admit",)
 #: (``serve.decode.step_s`` stays the engine's dispatch-to-sync total).
 #: A name that is not spelled here does not exist.
 SPAN_LAYERS = {
-    "decode": ("decode", "generate", DECODE_PHASES + DECODE_STATS_PHASES),
-    "engine": ("serve.decode", "step", ENGINE_PHASES + ENGINE_LOOP_PHASES),
+    "decode": ("decode", "generate", DECODE_PHASES + DECODE_DISPATCH_PHASES
+               + DECODE_STATS_PHASES),
+    "engine": ("serve.decode", "step", ENGINE_PHASES
+               + ENGINE_DISPATCH_PHASES + ENGINE_LOOP_PHASES),
     "door": ("serve.door", None, DOOR_PHASES),
 }
+
+#: phases the pause watch never judges: ``dispatch`` is tiled by its two
+#: children, which are judged (a pause would count twice), and ``park``
+#: is a blocking pop by design.  A root encloses a whole round and is
+#: not judged either.
+PAUSE_UNJUDGED = {"decode": ("dispatch",), "engine": ("dispatch", "park")}
+
+#: where a layer's pause baseline is taken (:meth:`PauseWatcher.rebase`):
+#: a generation begins, the engine leaves ``park`` — so that nothing is
+#: read per round
+PAUSE_BASELINE_AT = {"decode": ("generate", "enter"),
+                     "engine": ("park", "exit")}
+
+#: the key of a span's ``args`` that numbers the layer's rounds: a
+#: ``host_pause`` event names the newest one its thread has seen
+PAUSE_ROUND_KEY = {"decode": "steps_run", "engine": "step"}
+
+#: ``chipbench:<layer>.pause``: the marker a pause leaves at its phase's
+#: end while a profiler session is live (the one annotation name that is
+#: no phase)
+PAUSE_MARKER = "pause"
+
+#: an occurrence is a pause when its wall time is at least this much
+#: over the phase's typical time AND at least PAUSE_TIMES times it
+PAUSE_OVER_S = 0.010
+PAUSE_TIMES = 3.0
+#: occurrences of a phase that only found its typical time: the first
+#: one judged is the one after (a warm-up's compiling dispatch is the
+#: phase's first, and the median of these leaves it out)
+PAUSE_UNJUDGED_FIRST = 7
 
 #: the jax.monitoring duration event that fires once per XLA backend
 #: compilation (and never on a program-cache hit)
@@ -314,6 +367,184 @@ class MemoryWatcher:
         return n
 
 
+class _PhaseWatch:
+    """One phase's typical wall time, kept by its :class:`PauseWatcher`
+    and fed by every span of the phase (``obs/trace.py``)."""
+
+    __slots__ = ("layer", "phase", "typ", "_first", "_streak", "_owner")
+
+    def __init__(self, owner: "PauseWatcher", layer: str, phase: str):
+        self._owner = owner
+        self.layer, self.phase = layer, phase
+        #: seconds; ``None`` while the phase's first occurrences, which
+        #: are never judged, are still being collected
+        self.typ: float | None = None
+        self._first: list[float] = []
+        self._streak = 0        # occurrences over PAUSE_TIMES x, in a row
+
+    def feed(self, sp, dur: float) -> None:
+        """One occurrence's wall time, from the span ``sp`` at its exit.
+        The estimate follows the recent occurrences (an eighth of the
+        way each); one counts for at most PAUSE_TIMES x typical in it,
+        so a pause barely moves it.  Three such in a row are no pause:
+        the phase has become longer (another ``token_chunk``, say) and
+        finds its typical time anew."""
+        typ = self.typ
+        if typ is None:
+            self._first.append(dur)
+            if len(self._first) >= PAUSE_UNJUDGED_FIRST:
+                self.typ = sorted(self._first)[len(self._first) // 2]
+                self._first = []
+        elif dur < PAUSE_TIMES * typ:
+            self.typ = typ + 0.125 * (dur - typ)
+            if self._streak:
+                self._streak = 0
+        elif self._streak >= 2:
+            self.typ, self._first, self._streak = None, [dur], 0
+        else:
+            self._streak += 1
+            self.typ = max(typ + 0.125 * (PAUSE_TIMES - 1.0) * typ, 1e-9)
+            if dur - typ >= PAUSE_OVER_S:
+                self._owner.pause(self, sp, dur, typ)
+
+
+class PauseWatcher:
+    """Says which phase of which round froze, and what its thread was
+    doing meanwhile.
+
+    ``span`` feeds every judged phase's wall time to its
+    :class:`_PhaseWatch`; an occurrence at least ``PAUSE_OVER_S`` over
+    and ``PAUSE_TIMES`` times the phase's typical time is a *pause*:
+
+    * counter ``<prefix>.pauses`` and histogram ``<prefix>.pause_s``
+      (the time over typical);
+    * one ``host_pause`` flight-recorder event: the span's own numbers
+      (wall, typical, this thread's and the process's CPU time inside
+      the phase) and, where the layer has a baseline on this thread
+      (:meth:`rebase`), how far :meth:`_thread_counts` moved since it —
+      over ``since_ms``, not inside the phase alone: nothing is read per
+      round;
+    * one line on stderr, at most one a second (the event is never
+      dropped for it);
+    * while a profiler session is live, a marker
+      ``chipbench:<layer>.pause`` right behind the phase's end, which
+      puts the pause on the device trace's clock.
+    """
+
+    def __init__(self):
+        self._local = threading.local()
+        self._lock = threading.Lock()
+        self._watches: dict = {}
+        self._last_line = float("-inf")
+        self._lacks: set = set()    # count sources this platform lacks
+
+    def phase(self, layer: str, phase: str) -> _PhaseWatch | None:
+        """The watch of ``<layer>.<phase>``; ``None`` for a phase that
+        is never judged (``PAUSE_UNJUDGED``, and every root)."""
+        root = SPAN_LAYERS[layer][1]
+        if phase == root or phase in PAUSE_UNJUDGED.get(layer, ()):
+            return None
+        with self._lock:
+            w = self._watches.get((layer, phase))
+            if w is None:
+                w = self._watches[layer, phase] = \
+                    _PhaseWatch(self, layer, phase)
+            return w
+
+    def _thread_counts(self) -> dict:
+        """What the kernel and the interpreter have counted for the
+        calling thread so far, under the names a ``host_pause`` event
+        gives their differences.  A source this platform lacks (no
+        ``/proc``; a sandbox whose files are missing or read all zero,
+        as the TPU host's do) leaves its names out, and is not asked
+        again."""
+        out, lacks = {}, self._lacks
+        if "rusage" not in lacks:
+            try:
+                ru = resource.getrusage(resource.RUSAGE_THREAD)
+                out["vol_switches"] = ru.ru_nvcsw
+                out["invol_switches"] = ru.ru_nivcsw
+                out["major_faults"] = ru.ru_majflt
+            except (AttributeError, ValueError, OSError):
+                lacks.add("rusage")     # RUSAGE_THREAD is Linux's
+        if "schedstat" not in lacks:
+            try:
+                with open("/proc/thread-self/schedstat") as f:
+                    fields = [int(x) for x in f.read().split()]
+                if len(fields) < 2 or not any(fields):
+                    raise ValueError(fields)
+                out["runq_wait_ms"] = fields[1] * 1e-6  # runnable, not run
+            except (OSError, ValueError):
+                lacks.add("schedstat")
+        if "stat" not in lacks:
+            try:
+                with open("/proc/stat") as f:
+                    cpu = f.readline().split()
+                ticks = [int(x) for x in cpu[1:]]
+                if cpu[0] != "cpu" or len(ticks) < 8 or not any(ticks):
+                    raise ValueError(cpu)
+                out["steal_ms"] = ticks[7] * 1e3 / os.sysconf("SC_CLK_TCK")
+            except (OSError, ValueError, IndexError):
+                lacks.add("stat")
+        out["gc_collections"] = sum(
+            g["collections"] for g in gc.get_stats())
+        return out
+
+    def _bases(self) -> dict:
+        """The calling thread's baselines: layer -> (when, counts)."""
+        try:
+            return self._local.bases
+        except AttributeError:
+            bases = self._local.bases = {}
+            return bases
+
+    def rebase(self, layer: str) -> None:
+        """Take the calling thread's baseline for ``layer`` anew."""
+        self._bases()[layer] = (time.perf_counter(), self._thread_counts())
+
+    def pause(self, watch: _PhaseWatch, sp, dur: float, typ: float) -> None:
+        layer, phase = watch.layer, watch.phase
+        jax = sys.modules.get("jax")
+        if jax is not None:     # first: the marker belongs at sp's end
+            with jax.profiler.TraceAnnotation(
+                    f"{ANNOTATION_PREFIX}{layer}.{PAUSE_MARKER}"):
+                pass
+        prefix = SPAN_LAYERS[layer][0]
+        count = REGISTRY.counter(f"{prefix}.pauses")
+        over = REGISTRY.histogram(f"{prefix}.pause_s")
+        count.inc()
+        over.record(dur - typ)
+        data = {"layer": layer, "phase": phase,
+                "wall_ms": round(dur * 1e3, 3),
+                "typical_ms": round(typ * 1e3, 3),
+                "cpu_ms": round(sp.cpu_s * 1e3, 3),
+                "proc_cpu_ms": round(sp.proc_cpu_s * 1e3, 3)}
+        rnd = ROUND_ARGS.get(layer, {}).get(PAUSE_ROUND_KEY.get(layer))
+        if rnd is not None:
+            data["round"] = rnd
+        base = self._bases().get(layer)
+        if base is not None:
+            t_base, then = base
+            now = self._thread_counts()
+            data["since_ms"] = round((sp.t1 - t_base) * 1e3, 3)
+            for key, v in now.items():
+                if key in then:
+                    data[key] = round(v - then[key], 3)
+        emit_event("host_pause", **data)
+        with self._lock:
+            say = sp.t1 - self._last_line >= 1.0
+            if say:
+                self._last_line = sp.t1
+        if say:
+            # the layer's totals so far ride the line, so a log that the
+            # limit thinned still accounts for every pause
+            print("defer_tpu: host_pause " + " ".join(
+                f"{k}={v}" for k, v in data.items())
+                + f" pauses={count.value} pause_s_sum={over.sum:.6f}",
+                file=sys.stderr, flush=True)
+        self.rebase(layer)
+
+
 class ProfileSession:
     """One ``profile_start`` .. ``profile_stop`` window on a node: a
     baseline snapshot of the phase histograms at start, a delta
@@ -414,6 +645,7 @@ class ProfileSession:
 
 _WATCHER: RecompileWatcher | None = None
 _MEM: MemoryWatcher | None = None
+_PAUSES: PauseWatcher | None = None
 _LOCK = threading.Lock()
 
 
@@ -433,3 +665,12 @@ def memory_watcher() -> MemoryWatcher:
         if _MEM is None:
             _MEM = MemoryWatcher()
         return _MEM
+
+
+def pause_watcher() -> PauseWatcher:
+    """This process's pause watch (the one ``span`` feeds)."""
+    global _PAUSES
+    with _LOCK:
+        if _PAUSES is None:
+            _PAUSES = PauseWatcher()
+        return _PAUSES

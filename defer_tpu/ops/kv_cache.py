@@ -64,8 +64,6 @@ import functools
 import math
 from typing import Any
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -791,19 +789,3 @@ class KVCacheFormat(LayeredState):
             return kv_attend_joined(q, k_buf, v_buf, pos, group,
                                     kv=self.kv_heads)
         return kv_attend(q, k_buf, v_buf, pos, group)
-
-    def live_block_share(self, pos) -> tuple[int, int]:
-        """Position blocks :meth:`attend` reads for sequences at the
-        positions ``pos`` (host integers, any shape), and the blocks
-        their items hold: reckoned from the block size alone, no device
-        asked.  (A bubble is :attr:`bubble_slot`.)"""
-        length = self.buffers(1)["k"].shape[-2]     # scratch and padding too
-        itemsize = jnp.dtype(self.dtype).itemsize
-        tl = joined_block_rows(self.kv_heads, self.head_dim, length,
-                               itemsize) if self.joined else attend_blocks(
-            self.kv_heads, self.head_dim, length, itemsize)[1]
-        # a ring buffer's last live row is the window's last at most
-        pos = np.clip(np.asarray(pos), 0, (self.window or length) - 1)
-        held = pos.size * -(-length // tl)
-        # the einsums of the int8 rows read every position
-        return (held if self.quantized else int((pos // tl + 1).sum())), held

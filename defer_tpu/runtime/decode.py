@@ -266,9 +266,6 @@ class PipelinedDecoder:
         self._init_fn = None  # cached jitted state initializer
         #: the newest device-side sums of the blocks' sown statistics
         self._live_stats = None
-        #: position blocks the attention read / the blocks the items it
-        #: read from hold, over this decoder's dispatches
-        self._attend_blocks = [0, 0]
 
     # ------------------------------------------------------------------
 
@@ -849,29 +846,6 @@ class PipelinedDecoder:
             else max(n, n * int(token_chunk))
         return num_steps, chunk_steps
 
-    def _count_attend_blocks(self, t0: int, chunk_steps: int,
-                             num_steps: int, start: int) -> None:
-        """``decode.attend.live_block_share`` for one dispatch: where
-        each stage's group stands at each of its steps is the schedule's
-        own arithmetic (``device_decode``), so the host reckons what
-        the kernel will read before the device has run any of it.  Only
-        a cache has positions to skip, and the gauge."""
-        if self.memory != "kv_cache":
-            return
-        n = self.num_stages
-        t = t0 + np.arange(chunk_steps)[:, None]
-        rel = t - np.arange(n)[None, :]
-        pos = start + rel // n
-        real = (rel >= 0) & (t < num_steps) & (pos >= 0) \
-            & (pos < self.max_len)
-        for fmt in self.state_formats:
-            read, held = fmt.live_block_share(
-                np.where(real, pos, fmt.bubble_slot))
-            self._attend_blocks[0] += read
-            self._attend_blocks[1] += held
-        REGISTRY.gauge("decode.attend.live_block_share").set(
-            self._attend_blocks[0] / self._attend_blocks[1])
-
     def _get_decode_fn(self, chunk_steps: int, sample: bool,
                        top_k: int | None):
         key = (chunk_steps, sample, top_k)
@@ -1042,12 +1016,13 @@ class PipelinedDecoder:
         if not sample:
             top_k = None  # unused by argmax; keep the program caches keyed
             # identically so greedy calls never recompile over it
-        prompt_dev = jnp.asarray(prompt)
-        plen_s = jnp.int32(plen)
-        seed_s = jnp.uint32(seed)
-        temp_s = jnp.float32(temperature)
-        self.state = None       # let the last generation's buffers go
-        a, caches = self._init_state()
+        with span("decode", "init"):
+            prompt_dev = jnp.asarray(prompt)
+            plen_s = jnp.int32(plen)
+            seed_s = jnp.uint32(seed)
+            temp_s = jnp.float32(temperature)
+            self.state = None       # let the last generation's buffers go
+            a, caches = self._init_state()
 
         if prefill:
             pkey = (plen, sample, top_k)
@@ -1059,26 +1034,27 @@ class PipelinedDecoder:
                 caches, pre_ids = pfn(self._w, prompt_dev, seed_s, temp_s,
                                       caches)
                 pre_np = np.asarray(pre_ids[0])
+            start = plen
+        else:
+            start = 0
+
+        with span("decode", "init"):
             # group g's first generated token exits the wrap link at
             # prefill step g + (n-1)
             first_ids_np = np.stack(
-                [pre_np[g + n - 1] for g in range(n)]).astype(np.int32)
-            start = plen
-        else:
-            first_ids_np = None
-            start = 0
-
-        # with prefill, position `start` is already known (first_ids)
-        num_steps, chunk_steps = self._schedule(t_tok, start, token_chunk)
-        fn = self._get_decode_fn(chunk_steps, sample, top_k)
-
-        fi_dev = jnp.asarray(first_ids_np if first_ids_np is not None
-                             else np.zeros((n, mb), np.int32))
-        fp_s = jnp.int32(plen if prefill else -1)
-        start_s = jnp.int32(start)
+                [pre_np[g + n - 1] for g in range(n)]).astype(np.int32) \
+                if prefill else None
+            # with prefill, position `start` is already known (first_ids)
+            num_steps, chunk_steps = self._schedule(t_tok, start,
+                                                    token_chunk)
+            fn = self._get_decode_fn(chunk_steps, sample, top_k)
+            fi_dev = jnp.asarray(first_ids_np if first_ids_np is not None
+                                 else np.zeros((n, mb), np.int32))
+            fp_s = jnp.int32(plen if prefill else -1)
+            start_s = jnp.int32(start)
+            out3, p0 = self._gather_init(prompt, plen, t_tok, start,
+                                         first_ids_np)
         chunks: list = []  # device chunks (batch path), drained at the end
-        out3, p0 = self._gather_init(prompt, plen, t_tok, start,
-                                     first_ids_np)
         incremental = eos_id is not None or on_tokens is not None
         p_done = plen - 1  # last position already delivered to on_tokens
         if on_tokens is not None and prefill and t_tok > plen:
@@ -1092,12 +1068,15 @@ class PipelinedDecoder:
         while steps_run < num_steps:
             with span("decode", "dispatch",
                       {"steps_run": steps_run, "chunk_steps": chunk_steps}):
-                a, caches, ids = fn(self._w, prompt_dev, plen_s,
-                                    jnp.int32(steps_run),
-                                    jnp.int32(num_steps), seed_s, temp_s,
-                                    fi_dev, fp_s, start_s, a, caches)
-            self._count_attend_blocks(steps_run, chunk_steps, num_steps,
-                                      start)
+                with span("decode", "upload"):
+                    at = (jnp.int32(steps_run), jnp.int32(num_steps))
+                with span("decode", "launch"):
+                    a, caches, ids = fn(self._w, prompt_dev, plen_s, *at,
+                                        seed_s, temp_s, fi_dev, fp_s,
+                                        start_s, a, caches)
+                # dropped while the device runs the chunk, as the call's
+                # own temporaries were: not when the next chunk waits
+                del at
             self._live_stats = caches.get("stats")
             if not incremental:
                 chunks.append(ids)
@@ -1197,7 +1176,6 @@ class PipelinedDecoder:
                                 jnp.int32(steps_run), jnp.int32(num_steps),
                                 jnp.uint32(0), jnp.float32(0.0), fi_dev,
                                 jnp.int32(-1), zero, a, caches)
-            self._count_attend_blocks(steps_run, chunk_steps, num_steps, 0)
             chunks.append(ids)
             steps_run += chunk_steps
         arr = np.concatenate([np.asarray(c[0]) for c in chunks], axis=0)

@@ -154,10 +154,6 @@ class ContinuousBatchEngine:
         self.steps = 0
         self._step_hist = REGISTRY.histogram("serve.decode.step_s")
         self._tok_count = REGISTRY.counter("serve.decode.tokens")
-        #: position blocks the attention read / the blocks the slots it
-        #: read from hold, over this engine's steps
-        self._attend_blocks = [0, 0]
-        self._attend_share = REGISTRY.gauge("engine.attend.live_block_share")
 
     # -- state -------------------------------------------------------------
 
@@ -260,7 +256,8 @@ class ContinuousBatchEngine:
     def _step(self, live) -> list[tuple[DecodeRequest, np.ndarray]]:
         """One step in the phases of ``obs/profile.py::ENGINE_PHASES``:
         gather (host build of the per-slot rows / teacher-forcing),
-        dispatch (the jit step call returning), device
+        dispatch (``upload`` of those rows, then ``launch``: the jit step
+        call returning; ``ENGINE_DISPATCH_PHASES``), device
         (block_until_ready — the fused step program: blocks, lm_head,
         sampling AND the KV write all live here; splitting those needs
         jax.profiler), sync (np.asarray of the sampled ids), delivery
@@ -280,17 +277,14 @@ class ContinuousBatchEngine:
                 seeds[i] = s.req.seed & 0xFFFFFFFF
                 temps[i] = s.req.temperature
                 sample = sample or s.req.temperature > 0
-            # what the attention will read: every slot's blocks up to
-            # its own position (an idle slot's first)
-            read, held = self.kv_format.live_block_share(pos)
-            self._attend_blocks[0] += read
-            self._attend_blocks[1] += held
-            self._attend_share.set(
-                self._attend_blocks[0] / self._attend_blocks[1])
         with span("engine", "dispatch") as dispatched:
-            next_ids, self._caches = self._step_fn(sample)(
-                self.params, self._caches, jnp.asarray(ids),
-                jnp.asarray(pos), jnp.asarray(seeds), jnp.asarray(temps))
+            with span("engine", "upload"):
+                rows = (jnp.asarray(ids), jnp.asarray(pos),
+                        jnp.asarray(seeds), jnp.asarray(temps))
+            with span("engine", "launch"):
+                next_ids, self._caches = self._step_fn(sample)(
+                    self.params, self._caches, *rows)
+            del rows    # while the device runs the step, not at the next
         with span("engine", "device"):
             sync = getattr(next_ids, "block_until_ready", None)
             if sync is not None:

@@ -241,10 +241,6 @@ def test_kv_attend_of_a_bubble_step_is_finite():
 ])
 def test_attend_blocks_hold_a_megabyte(kv, hd, length, itemsize, want):
     assert attend_blocks(kv, hd, length, itemsize) == want
-    fmt = KVCacheFormat(kv, hd, length, jnp.dtype(f"float{8 * itemsize}"))
-    read, held = fmt.live_block_share(np.array([0, want[1] - 1, want[1]]))
-    assert (read, held) == (4 if length > want[1] else 3,
-                            3 * -(-length // want[1]))
 
 
 def _cache_slices(jaxpr, item):
@@ -375,32 +371,6 @@ def test_reparent_moves_one_groups_rows_in_every_layer():
             want = np.asarray(old).copy()
             want[1] = want[1][np.asarray(parents)]
             np.testing.assert_array_equal(np.asarray(new), want)
-
-
-def test_live_block_share_is_reckoned_when_a_step_is_dispatched(model):
-    """``decode.attend.live_block_share`` / ``engine.attend...``: blocks
-    read over blocks held, from positions the host already has.  At toy
-    sizes an item is one block, so every step reads all it holds; the
-    tally grows by a block a sequence, a layer-less count a step."""
-    from defer_tpu.obs import REGISTRY
-    graph, params = model
-    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
-                           max_len=24)
-    dec.generate(_prompts(4, 5), 4, prefill=True, token_chunk=2)
-    read, held = dec._attend_blocks
-    assert read == held > 0 and held % 2 == 0   # both stages, every step
-    assert REGISTRY.gauge("decode.attend.live_block_share").value == 1.0
-    eng = ContinuousBatchEngine(graph, params, num_stages=2, width=3)
-    eng.run_all([DecodeRequest(prompt=_prompts(1, 3)[0], max_new_tokens=2,
-                               request_id=0)])
-    assert eng._attend_blocks == [3 * eng.steps, 3 * eng.steps]
-    assert REGISTRY.gauge("engine.attend.live_block_share").value == 1.0
-    # where an item is several blocks, a sequence reads up to its own
-    fmt = KVCacheFormat(25, 64, 768, jnp.bfloat16, groups=1)
-    assert fmt.live_block_share(np.array([0, 255, 256, 767, 768])) == (
-        1 + 1 + 2 + 3 + 4, 5 * 4)
-    assert KVCacheFormat(25, 64, 768, jnp.bfloat16, quantized=True,
-                         groups=1).live_block_share(np.array([0])) == (4, 4)
 
 
 # -- another format in its place ------------------------------------------------
@@ -650,10 +620,6 @@ def test_joined_attention_at_each_sequences_own_position(positions, dtype):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want),
         atol=2e-5 if dtype == jnp.float32 else 3e-2)
-    read, held = fmt.live_block_share(np.asarray(positions))
-    tl = kv_cache.joined_block_rows(kv, hd, 1104, jnp.dtype(dtype).itemsize)
-    assert held == 2 * -(-1104 // tl)        # 1100 rows in tiles of 16
-    assert read == sum(p // tl + 1 for p in positions)
 
 
 def test_a_joined_prefix_of_a_piece_lands_at_its_sequences():

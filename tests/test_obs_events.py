@@ -134,3 +134,26 @@ def test_admission_emits_shed_and_admit_events():
     shed = next(e for e in evs if e["kind"] == "shed")
     assert shed["data"]["reason"] == "deadline"
     assert shed["data"]["predicted_ms"] > 100.0
+
+
+# ---------------------------------------------------------------------------
+# host_pause: the pause watch's event (obs/profile.py PauseWatcher)
+# ---------------------------------------------------------------------------
+
+def test_a_host_pause_event_validates_and_survives_the_wire():
+    assert "host_pause" in EVENT_KINDS
+    rec = FlightRecorder(process="p")
+    full = dict(layer="decode", phase="sync", round=128, wall_ms=512.25,
+                typical_ms=18.6, cpu_ms=0.1, proc_cpu_ms=0.4,
+                since_ms=900.0, vol_switches=31, invol_switches=2,
+                major_faults=0, runq_wait_ms=488.0, steal_ms=0.0,
+                gc_collections=1)
+    # a platform without /proc leaves those fields out: still one event
+    bare = {k: v for k, v in full.items()
+            if k not in ("runq_wait_ms", "steal_ms")}
+    for data in (full, bare):
+        ev = rec.emit("host_pause", **data)
+        back = validate_event(json.loads(json.dumps(ev)))
+        assert back["kind"] == "host_pause" and back["data"] == data
+    merged = merge_events(rec.snapshot(), rec.snapshot())
+    assert [e["seq"] for e in merged] == [0, 1]

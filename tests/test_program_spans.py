@@ -19,9 +19,14 @@ import jax
 import jax.numpy as jnp
 
 from defer_tpu.models.gpt import gpt_tiny
-from defer_tpu.obs import (DECODE_PHASES, DECODE_STATS_PHASES, DOOR_PHASES,
-                           ENGINE_LOOP_PHASES, ENGINE_PHASES, REGISTRY, SPAN_LAYERS, span,
-                           tracer)
+from defer_tpu.obs import (DECODE_DISPATCH_PHASES, DECODE_PHASES,
+                           DECODE_STATS_PHASES, DOOR_PHASES,
+                           ENGINE_DISPATCH_PHASES, ENGINE_LOOP_PHASES,
+                           ENGINE_PHASES, REGISTRY, SPAN_LAYERS,
+                           pause_watcher, recorder, span, tracer)
+from defer_tpu.obs.events import validate_event
+from defer_tpu.obs.profile import (PAUSE_MARKER, PAUSE_OVER_S,
+                                   PAUSE_UNJUDGED, PAUSE_UNJUDGED_FIRST)
 from defer_tpu.obs.trace import ANNOTATION_PREFIX as PREFIX
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve import ContinuousBatchEngine, ServeClient
@@ -123,9 +128,12 @@ def test_a_span_with_the_tracer_on_links_its_parent_and_keeps_args(traced):
 @pytest.mark.parametrize("layer", sorted(SPAN_LAYERS))
 def test_names_come_from_the_phase_tables_and_nowhere_else(layer):
     prefix, root, phases = SPAN_LAYERS[layer]
-    assert phases == {"decode": DECODE_PHASES + DECODE_STATS_PHASES,
-                      "engine": ENGINE_PHASES + ENGINE_LOOP_PHASES,
-                      "door": DOOR_PHASES}[layer]
+    assert phases == {
+        "decode": DECODE_PHASES + DECODE_DISPATCH_PHASES
+        + DECODE_STATS_PHASES,
+        "engine": ENGINE_PHASES + ENGINE_DISPATCH_PHASES
+        + ENGINE_LOOP_PHASES,
+        "door": DOOR_PHASES}[layer]
     for phase in phases:
         hist = REGISTRY.histogram(f"{prefix}.{phase}_s")
         n0 = hist.count
@@ -142,16 +150,279 @@ def test_names_come_from_the_phase_tables_and_nowhere_else(layer):
         span("no_such_layer", "dispatch")
 
 
+# -- the CPU clocks and the pause watch ----------------------------------------------
+
+@pytest.mark.parametrize("busy", [False, True])
+def test_a_span_knows_whether_its_thread_worked_or_waited(busy):
+    with span("decode", "emit") as sp:
+        t_end = time.perf_counter() + 0.05
+        if busy:
+            while time.perf_counter() < t_end:
+                pass
+        else:
+            time.sleep(0.05)
+    wall = sp.t1 - sp.t0
+    assert wall >= 0.05
+    if busy:    # worked: the thread's CPU clock ran with the wall clock
+        assert sp.cpu_s >= 0.6 * wall and sp.proc_cpu_s >= sp.cpu_s - 1e-3
+    else:       # waited
+        assert sp.cpu_s <= 0.2 * wall
+    # a reading up to 5 ms old serves as the span's first
+    assert sp.cpu_s <= wall + 6e-3
+    # a short phase reads no CPU clock at all: both ends share a reading
+    with span("decode", "emit") as a:
+        pass
+    with span("decode", "emit") as b:
+        pass
+    assert a.cpu_s == b.cpu_s == 0.0
+
+
+def _fresh_watch(layer, phase):
+    """The phase's watch as a new process has it, and an unlimited
+    stderr line."""
+    pw = pause_watcher()
+    w = pw.phase(layer, phase)
+    if w is not None:
+        w.typ, w._first, w._streak = None, [], 0
+    pw._last_line = float("-inf")
+    return pw, w
+
+
+def _pauses(prefix):
+    return (REGISTRY.counter(f"{prefix}.pauses").value,
+            REGISTRY.histogram(f"{prefix}.pause_s").count,
+            REGISTRY.histogram(f"{prefix}.pause_s").sum,
+            len([e for e in recorder().snapshot()
+                 if e["kind"] == "host_pause"]))
+
+
+def _occur(layer, phase, seconds, args=None):
+    with span(layer, phase, args) as sp:
+        time.sleep(seconds)
+    return sp
+
+
+def test_no_occurrence_is_judged_before_the_phases_eighth(capfd):
+    _pw, w = _fresh_watch("decode", "scatter")
+    before = _pauses("decode")
+    for i in range(PAUSE_UNJUDGED_FIRST):
+        # the second is as long as a pause: still finding what is typical
+        _occur("decode", "scatter", 0.03 if i == 1 else 0.001)
+        assert (w.typ is None) == (i < PAUSE_UNJUDGED_FIRST - 1)
+    assert _pauses("decode") == before
+    assert 0.001 <= w.typ < 0.003      # the median left the long one out
+    assert "host_pause" not in capfd.readouterr().err
+
+
+def test_a_pause_fires_once_with_every_field_the_platform_has(capfd):
+    pw, w = _fresh_watch("decode", "sync")
+    with span("decode", "generate", {"rows": 1}):      # the baseline
+        for i in range(PAUSE_UNJUDGED_FIRST + 2):
+            with span("decode", "dispatch", {"steps_run": 4 * i}):
+                pass
+            _occur("decode", "sync", 0.002)
+        typ, before = w.typ, _pauses("decode")
+        # over 10 ms but under 3x, and 3x but under 10 ms over: no pause
+        w.typ = 0.02
+        _occur("decode", "sync", 0.035)
+        w.typ = typ
+        _occur("decode", "sync", 0.009)
+        assert _pauses("decode") == before
+        typ = w.typ
+        with span("decode", "dispatch", {"steps_run": 444}):
+            pass
+        sp = _occur("decode", "sync", 0.04)
+    n, hn, hsum, ne = _pauses("decode")
+    assert (n, hn, ne) == (before[0] + 1, before[1] + 1, before[3] + 1)
+    wall = sp.t1 - sp.t0
+    assert hsum - before[2] == pytest.approx(wall - typ, abs=1e-6)
+    ev = validate_event([e for e in recorder().snapshot()
+                         if e["kind"] == "host_pause"][-1])
+    d = ev["data"]
+    assert (d["layer"], d["phase"], d["round"]) == ("decode", "sync", 444)
+    assert d["wall_ms"] == pytest.approx(1e3 * wall, abs=1e-3)
+    assert d["typical_ms"] == pytest.approx(1e3 * typ, abs=1e-3)
+    assert d["cpu_ms"] <= 0.2 * d["wall_ms"] and d["proc_cpu_ms"] >= 0
+    assert d["since_ms"] >= d["wall_ms"]
+    # the thread slept: it gave the core up of its own accord, at least
+    # once an occurrence since the generation began
+    assert d["vol_switches"] >= PAUSE_UNJUDGED_FIRST
+    assert d["invol_switches"] >= 0 and d["major_faults"] >= 0
+    assert d["gc_collections"] >= 0
+    for key, path in (("runq_wait_ms", "/proc/thread-self/schedstat"),
+                      ("steal_ms", "/proc/stat")):
+        if key in d:        # a field the platform lacks is absent
+            assert d[key] >= 0 and os.path.exists(path)
+    line = [ln for ln in capfd.readouterr().err.splitlines()
+            if "host_pause" in ln]
+    assert len(line) == 1
+    assert "phase=sync" in line[0] and "round=444" in line[0] \
+        and f"wall_ms={d['wall_ms']}" in line[0]
+    # the layer's totals ride the line: a thinned log still adds up
+    assert f"pauses={n} pause_s_sum={hsum:.6f}" in line[0]
+    # the estimate took the pause for 3x typical at most
+    assert w.typ == pytest.approx(1.25 * typ)
+
+
+@pytest.mark.parametrize("layer, phase", [
+    ("engine", "park"), ("engine", "dispatch"), ("decode", "dispatch"),
+    ("decode", "generate"), ("engine", "step")])
+def test_park_the_tiled_parents_and_the_roots_are_never_judged(layer, phase):
+    pw, w = _fresh_watch(layer, phase)
+    assert w is None
+    assert phase == SPAN_LAYERS[layer][1] or phase in PAUSE_UNJUDGED[layer]
+    before = _pauses(SPAN_LAYERS[layer][0])
+    for i in range(PAUSE_UNJUDGED_FIRST + 2):
+        _occur(layer, phase, 0.0005)
+    _occur(layer, phase, 0.03)
+    assert _pauses(SPAN_LAYERS[layer][0]) == before
+
+
+def test_the_warm_ups_compiling_first_dispatch_is_no_pause():
+    _fresh_watch("decode", "launch")
+    _fresh_watch("decode", "prefill")
+    seen = recorder().cursor()
+    g = gpt_tiny(seq_len=32)
+    dec = PipelinedDecoder(g, g.init(jax.random.key(1)), num_stages=1,
+                           microbatch=2, max_len=32)
+    prompts = np.zeros((2, PLEN), np.int32)
+    # both programs compile inside their phases' first occurrences
+    dec.generate(prompts, 2 * CHUNK + 1, prefill=True, token_chunk=CHUNK,
+                 on_tokens=lambda *a, **k: None)
+    assert REGISTRY.histogram("decode.launch_s").max > 10 * PAUSE_OVER_S
+    assert not [e for e in recorder().events_since(seen)[1]
+                if e["kind"] == "host_pause"
+                and e["data"]["phase"] in ("launch", "prefill")]
+    w = pause_watcher().phase("decode", "launch")
+    for _ in range(3):
+        dec.generate(prompts, 2 * CHUNK + 1, prefill=True,
+                     token_chunk=CHUNK, on_tokens=lambda *a, **k: None)
+    # what is typical is a dispatch, not the compile
+    assert w.typ is not None and w.typ < PAUSE_OVER_S
+
+
+def test_the_stderr_line_is_rate_limited_and_the_event_is_not(capfd):
+    pw, w = _fresh_watch("engine", "delivery")
+    for _ in range(PAUSE_UNJUDGED_FIRST):
+        _occur("engine", "delivery", 0.001)
+    before = _pauses("serve.decode")
+    for _ in range(3):
+        _occur("engine", "delivery", 0.03)
+        w.typ, w._streak = 0.001, 0
+    assert _pauses("serve.decode")[0] == before[0] + 3
+    assert _pauses("serve.decode")[3] == before[3] + 3
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if "host_pause" in ln]
+    assert len(lines) == 1 and "layer=engine" in lines[0]
+    pw._last_line -= 1.0               # a second later the next is said
+    w._streak = 0
+    _occur("engine", "delivery", 0.03)
+    assert len([ln for ln in capfd.readouterr().err.splitlines()
+                if "host_pause" in ln]) == 1
+
+
+def test_a_phase_that_became_longer_is_typical_after_a_few_occurrences():
+    _pw, w = _fresh_watch("decode", "scatter")
+    for _ in range(PAUSE_UNJUDGED_FIRST):
+        _occur("decode", "scatter", 0.001)
+    before = _pauses("decode")[0]
+    for _ in range(3 + PAUSE_UNJUDGED_FIRST):  # say, a longer token_chunk
+        _occur("decode", "scatter", 0.02)
+    # two were taken for pauses; the third in a row began the estimate anew
+    assert _pauses("decode")[0] == before + 2
+    assert 0.02 <= w.typ < 0.03
+    _occur("decode", "scatter", 0.02)
+    assert _pauses("decode")[0] == before + 2
+
+
+def test_the_baseline_is_the_generations_begin_or_the_last_park():
+    pw = pause_watcher()
+    bases = pw._bases()
+    bases.clear()
+    with span("decode", "generate"):
+        t_gen = bases["decode"][0]             # a generation begins
+        assert "engine" not in bases
+    assert bases["decode"][0] == t_gen
+    with span("engine", "park"):
+        assert "engine" not in bases
+    t_left = bases["engine"][0]                # the engine left park
+    with span("engine", "park") as sp:
+        pass
+    assert bases["engine"][0] >= sp.t1 > t_left
+    with span("engine", "join"):
+        pass
+    with span("engine", "step", {"step": 3, "rows": 1}):
+        pass
+    left = bases["engine"][0]
+    _fresh_watch("engine", "join")
+    for _ in range(PAUSE_UNJUDGED_FIRST):
+        _occur("engine", "join", 0.001)
+    assert bases["engine"][0] == left          # nothing is read per round
+    _occur("engine", "join", 0.03)
+    ev = [e for e in recorder().snapshot() if e["kind"] == "host_pause"][-1]
+    assert ev["data"]["round"] == 3 and ev["data"]["phase"] == "join"
+    assert bases["engine"][0] > left           # taken anew after a pause
+    # a thread without a baseline says what the span alone knows
+    bases.clear()
+    _occur("engine", "join", 0.03)
+    ev = [e for e in recorder().snapshot() if e["kind"] == "host_pause"][-1]
+    assert "since_ms" not in ev["data"] and "vol_switches" not in ev["data"]
+    assert ev["data"]["wall_ms"] >= 30
+
+
+def test_a_pause_leaves_a_marker_behind_its_phase_in_a_profiler_trace(
+        tmp_path):
+    from chipbench import trace as cb_trace
+    _pw, w = _fresh_watch("decode", "emit")
+    for _ in range(PAUSE_UNJUDGED_FIRST):
+        _occur("decode", "emit", 0.001)
+    _occur("decode", "emit", 0.03)     # no session: the marker is inert
+    w.typ = 0.001
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _occur("decode", "emit", 0.001)
+        sp = _occur("decode", "emit", 0.03)
+    finally:
+        jax.profiler.stop_trace()
+    assert PREFIX + "decode." + PAUSE_MARKER in _host_event_names(
+        str(tmp_path))
+    # as the benchmark's reduction reads it: one marker, right behind
+    # the end of the occurrence that was the pause
+    red = cb_trace.load(cb_trace.find_xplane(str(tmp_path)),
+                        host_ops_as_device=True)
+    marks = [(s, e) for n, s, e in red.spans if n == "decode.pause"]
+    emits = [(s, e) for n, s, e in red.spans if n == "decode.emit"]
+    assert len(marks) == 1 and len(emits) == 2
+    long = max(emits, key=lambda se: se[1] - se[0])
+    assert long[1] - long[0] == pytest.approx(sp.t1 - sp.t0, abs=2e-4)
+    assert 0 <= marks[0][0] - long[1] < 2e-3
+
+
 # -- the decode ring ------------------------------------------------------------
 
 def _decode_spans(tr):
     spans = [s for s in tr.spans if s["name"].startswith("decode.")]
     roots = [s for s in spans if s["name"] == "decode.generate"]
     assert len(roots) == 1
-    kids = [s for s in spans if s is not roots[0]]
+    inner = {f"decode.{p}" for p in DECODE_DISPATCH_PHASES}
+    kids = [s for s in spans if s is not roots[0]
+            and s["name"] not in inner]
     assert all(s["parent"] == roots[0]["span"] for s in kids)
     assert {s["name"].split(".", 1)[1] for s in kids} <= set(DECODE_PHASES)
     assert _in_order(kids)
+    # the launch's two phases lie inside their dispatch, in order, once
+    for d in (s for s in kids if s["name"] == "decode.dispatch"):
+        two = sorted((s for s in spans if s["parent"] == d["span"]),
+                     key=lambda s: s["ts_us"])
+        assert [s["name"] for s in two] \
+            == [f"decode.{p}" for p in DECODE_DISPATCH_PHASES]
+        assert _in_order(two) and d["ts_us"] <= two[0]["ts_us"]
+        assert _ends(d) >= _ends(two[-1]) - 2
+    assert len([s for s in spans if s["name"] in inner]) \
+        == 2 * len([s for s in kids if s["name"] == "decode.dispatch"])
     assert roots[0]["ts_us"] <= min(s["ts_us"] for s in kids)
     assert _ends(roots[0]) >= max(_ends(s) for s in kids) - 2
     return roots[0], sorted(kids, key=lambda s: s["ts_us"])
@@ -170,12 +441,14 @@ def test_generate_names_its_phases_once_a_chunk_in_order(decoder, traced):
     chunks = -(-num_steps // chunk_steps)
     assert root["args"] == {"rows": 2, "prompt_len": PLEN,
                             "new_tokens": NEW, "chunk_steps": chunk_steps}
-    assert names.count("prefill") == 1 and names[0] == "prefill"
+    # what a generation sets up, in two parts around the one prefill
+    assert names.count("prefill") == 1 and names.count("init") == 2
+    assert names[:3] == ["init", "prefill", "init"]
     assert [names.count(p) for p in ("dispatch", "sync", "scatter")] \
         == [chunks] * 3
     assert names.count("emit") == len(calls) >= chunks
-    assert names[1] == "emit"            # the prefill's own first token
-    body = names[2:]
+    assert names[3] == "emit"            # the prefill's own first token
+    body = names[4:]
     # each chunk: dispatch, sync, scatter, then emit where tokens came
     assert [p for p in body if p != "emit"] \
         == ["dispatch", "sync", "scatter"] * chunks
@@ -215,14 +488,20 @@ def test_generate_without_a_callback_syncs_after_the_last_dispatch(
         decoder, traced):
     dec, prompts = decoder
     n0 = REGISTRY.histogram("decode.dispatch_s").count
+    u0 = [REGISTRY.histogram(f"decode.{p}_s").count
+          for p in ("launch", "upload")]
     dec.generate(prompts, NEW, token_chunk=CHUNK)
     _root, kids = _decode_spans(traced)
     names = [s["name"].split(".", 1)[1] for s in kids]
     num_steps, chunk_steps = dec._schedule(PLEN + NEW, 0, CHUNK)
     chunks = -(-num_steps // chunk_steps)
-    assert names == ["dispatch"] * chunks + ["sync", "scatter"] * chunks
-    # the histogram's count is the dispatch count (the old counter)
+    assert names == ["init"] * 2 + ["dispatch"] * chunks \
+        + ["sync", "scatter"] * chunks
+    # the histogram's count is the dispatch count (the old counter): the
+    # children feed their own and leave the parent's as it was
     assert REGISTRY.histogram("decode.dispatch_s").count == n0 + chunks
+    assert REGISTRY.histogram("decode.launch_s").count == u0[0] + chunks
+    assert REGISTRY.histogram("decode.upload_s").count == u0[1] + chunks
 
 
 def test_many_rounds_open_a_generate_span_each(decoder, traced):
@@ -267,6 +546,13 @@ def test_the_engine_loop_names_steps_joins_and_parks(door, traced):
         assert _in_order(kids)
         assert root["ts_us"] <= kids[0]["ts_us"]
         assert _ends(root) >= _ends(kids[-1]) - 2
+        disp = kids[ENGINE_PHASES.index("dispatch")]
+        two = sorted((s for s in spans if s["parent"] == disp["span"]),
+                     key=lambda s: s["ts_us"])
+        assert [s["name"] for s in two] \
+            == [f"engine.{p}" for p in ENGINE_DISPATCH_PHASES]
+        assert _in_order(two) and disp["ts_us"] <= two[0]["ts_us"]
+        assert _ends(disp) >= _ends(two[-1]) - 2
     loop = sorted((s for s in spans if s["parent"] is None),
                   key=lambda s: s["ts_us"])
     assert _in_order(loop)
@@ -280,7 +566,8 @@ def test_the_engine_loop_names_steps_joins_and_parks(door, traced):
     parks = [s for s in loop if s["name"] == "engine.park"]
     assert any(s["dur_us"] >= 40_000 for s in parks)    # a whole timeout
     # the phase histograms are what the spans fed
-    for phase in ENGINE_PHASES + ENGINE_LOOP_PHASES:
+    for phase in ENGINE_PHASES + ENGINE_DISPATCH_PHASES \
+            + ENGINE_LOOP_PHASES:
         assert REGISTRY.histogram(
             f"serve.decode.{phase}_s").count >= steps
 
@@ -343,15 +630,19 @@ def test_a_profiler_session_records_the_spans_as_host_events(
         pass
     named = {n for n in _host_event_names(str(tmp_path))
              if n.startswith(PREFIX)}
-    want = {f"{PREFIX}decode.{p}" for p in DECODE_PHASES + ("generate",)} \
+    want = {f"{PREFIX}decode.{p}" for p in
+            DECODE_PHASES + DECODE_DISPATCH_PHASES + ("generate",)} \
         | {f"{PREFIX}engine.{p}" for p in
-           ENGINE_PHASES + ENGINE_LOOP_PHASES + ("step",)} \
+           ENGINE_PHASES + ENGINE_DISPATCH_PHASES + ENGINE_LOOP_PHASES
+           + ("step",)} \
         | {PREFIX + "door.admit"}
-    assert named == want
+    # a host that stalled meanwhile may have left a pause's marker too
+    assert named - {f"{PREFIX}{layer}.{PAUSE_MARKER}"
+                    for layer in SPAN_LAYERS} == want
     # every name in the trace is spelled in the tables
     table = {f"{PREFIX}{layer}.{p}"
              for layer, (_pre, root, phases) in SPAN_LAYERS.items()
-             for p in phases + ((root,) if root else ())}
+             for p in phases + (PAUSE_MARKER,) + ((root,) if root else ())}
     assert named <= table
     # the namespace is the one the benchmark's reduction collects
     from chipbench.trace import SPAN_PREFIX
