@@ -88,19 +88,24 @@ ENGINE_LOOP_PHASES = ("join", "park")
 #: the result buffer).  Then once a chunk: ``dispatch`` is the chunk
 #: program's call returning (the enqueue; no sync), ``sync`` the
 #: ``np.asarray`` that waits for its ids, ``scatter`` the host copy
-#: into the result, ``emit`` the caller's ``on_tokens``.  During init,
-#: dispatch, scatter and emit the device has nothing queued.
+#: into the result, ``emit`` the caller's ``on_tokens``.  A steady round
+#: is ``dispatch(n+1)``, ``sync(n)``, ``scatter(n)``, ``emit(n)``: the
+#: loop keeps one chunk launched ahead of the one it reads, so only
+#: during init and a generation's first dispatch has the device nothing
+#: queued.
 DECODE_PHASES = ("init", "prefill", "dispatch", "sync", "scatter", "emit")
 
 #: inside the ring's ``dispatch``, in wall order: ``upload`` is the
-#: chunk's host-to-device scalars (where the chunk starts and stops),
-#: ``launch`` the jitted chunk program's call alone
+#: chunk's host-to-device scalars (where the chunk starts and stops,
+#: to every stage's device), ``launch`` the jitted chunk program's call
+#: alone
 DECODE_DISPATCH_PHASES = ("upload", "launch")
 
 #: after a generation's last chunk, where the ring's blocks sow per-step
 #: statistics (``DecoderBlock.decode_stats``; today the routed experts'
 #: ``decode.moe.*`` counters and the retention blocks'
-#: ``decode.retention.updates``): the one fetch of their device-side sums
+#: ``decode.retention.updates``): their sums, which came to the host a
+#: chunk at a time with the chunk's ids, go to the counters
 DECODE_STATS_PHASES = ("moe_stats",)
 
 #: the front door's per-request phase on the client's reader thread

@@ -42,8 +42,10 @@ TPU-native design, one SPMD program:
     generation in ONE dispatch by default); prompt teacher-forcing happens
     inside the scan (stage 0 substitutes the known prompt token while
     ``pos < prompt_len``), and the ring carry + caches flow between
-    dispatches as donated device-resident shards — zero host round trips
-    except the optional EOS check.
+    dispatches as donated device-resident shards — a chunk needs nothing
+    of the host but where it starts, so the loop launches chunk n+1
+    before it waits for chunk n's ids (streaming, the EOS check) and the
+    device always has a program queued.
   * Sampling: greedy argmax, or temperature softmax sampling with optional
     top-k, keyed by ``fold_in(seed, step)`` so results are independent of
     the chunking.
@@ -62,6 +64,7 @@ caches, dropping prompt cost from ``plen * N`` ring steps to ``2N - 1``.
 
 from __future__ import annotations
 
+import collections
 from typing import Any
 
 import numpy as np
@@ -166,7 +169,7 @@ class PipelinedDecoder:
         self.head_dim = parts.head_dim
         self.vocab = parts.vocab
         #: per-step scalars the blocks sow (``DecoderBlock.decode_stats``);
-        #: summed on the device over a generation, fetched once at its end
+        #: summed on the device over a chunk, fetched with the chunk's ids
         self._stat_names = parts.decode_stats
         self.stage_blocks = parts.stage_blocks
         self.l_max = max(len(b) for b in self.stage_blocks)
@@ -264,8 +267,15 @@ class PipelinedDecoder:
         #: compiled prefill programs keyed by (prompt_len, sample, top_k)
         self._prefill_fns: dict[tuple, Any] = {}
         self._init_fn = None  # cached jitted state initializer
-        #: the newest device-side sums of the blocks' sown statistics
-        self._live_stats = None
+        #: where a generation's host inputs go (prompt, scalars, where a
+        #: chunk starts): whole on every stage's device, straight from
+        #: the host.  Left on the default device they are copied from
+        #: there to the other stages at every call, and that copy queues
+        #: behind the chunk stage 0 is running
+        self._everywhere = NamedSharding(self.mesh, P())
+        #: the ring carry out of the newest chunk launched: a generation
+        #: that was stopped may have left that chunk running
+        self._tail = None
 
     # ------------------------------------------------------------------
 
@@ -425,7 +435,17 @@ class PipelinedDecoder:
         ``generate`` rounds — an in-flight generation keeps the weights
         it started with only up to its current dispatch boundary.
         """
+        self._settle()
         self._w = self._place_weights(params, init=False)
+
+    def _settle(self) -> None:
+        """Wait for the chunk a stopped generation may have left running
+        (it was launched before the chunk that showed the stop was read,
+        and holds that generation's memory until it ends): whoever
+        allocates next calls this first."""
+        tail, self._tail = self._tail, None
+        if tail is not None and not tail.is_deleted():
+            tail.block_until_ready()
 
     def _stage_params(self, s: int, w_local):
         if isinstance(w_local, dict) and "own" in w_local:
@@ -703,9 +723,6 @@ class PipelinedDecoder:
             # per-group cumulative beam scores; only the LAST stage's
             # device shard is meaningful (it runs the expansion)
             specs["beam_cum"] = P(STAGE_AXIS, None, None)
-        if self._stat_names:
-            # each stage's sums over its own blocks' steps
-            specs["stats"] = P(STAGE_AXIS, None)
         return specs
 
     def _build_prefill_fn(self, plen: int, sample: bool, top_k: int | None):
@@ -763,9 +780,6 @@ class PipelinedDecoder:
                 caches = zeros_by_layer(self.state_formats, mb, lead=(n,))
                 if self.beam_width > 1:
                     caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
-                if self._stat_names:
-                    caches["stats"] = jnp.zeros(
-                        (n, len(self._stat_names)), jnp.int32)
                 return (jnp.zeros((n, mb, self._ring_width), jnp.float32),
                         caches)
 
@@ -780,12 +794,18 @@ class PipelinedDecoder:
         branches = [self._make_branch(s, sample, top_k) for s in range(n)]
         beam = self.beam_width > 1
         d = self.d_model
+        stats = self._stat_names
 
         def device_decode(w, prompt, plen, t0, t_stop, seed, temp,
                           first_ids, first_pos, start, a, caches):
             w_l = jax.tree.map(lambda x: x[0], w)
             idx = lax.axis_index(STAGE_AXIS)
             local = jax.tree.map(lambda c: c[0], caches)
+            if stats:
+                # what this stage's blocks sow is summed over the chunk
+                # and leaves as an output of its own: the host can read
+                # a chunk's sums while the next chunk holds the state
+                local = dict(local, stats=jnp.zeros(len(stats), jnp.int32))
 
             def body(carry, t):
                 a, caches = carry
@@ -812,8 +832,9 @@ class PipelinedDecoder:
             (a, local), ids = lax.scan(
                 body, (a[0], local),
                 t0 + jnp.arange(chunk_steps, dtype=jnp.int32))
+            sown = (local.pop("stats")[None],) if stats else ()
             return (a[None], jax.tree.map(lambda c: c[None], local),
-                    ids[None])
+                    ids[None]) + sown
 
         state = self._state_specs()
         out_ids = P(STAGE_AXIS, None, None, None) if beam \
@@ -823,7 +844,8 @@ class PipelinedDecoder:
             in_specs=(self._wspec_tree, P(None, None, None), P(), P(),
                       P(), P(), P(), P(None, None), P(), P(),
                       P(STAGE_AXIS, None, None), state),
-            out_specs=(P(STAGE_AXIS, None, None), state, out_ids),
+            out_specs=(P(STAGE_AXIS, None, None), state, out_ids)
+            + ((P(STAGE_AXIS, None),) if stats else ()),
             check_vma=False,
         )
         # donate the carried state so chunked dispatches update in place
@@ -907,7 +929,11 @@ class PipelinedDecoder:
         per group (one compiled program serves every generation length);
         the default is the whole generation in one dispatch.  ``eos_id``
         stops early once every sequence has emitted it and fills the tail
-        with ``eos_id``.
+        with ``eos_id``.  The stop is seen one chunk late: the loop
+        launches chunk n+1 before it reads chunk n, so one chunk past
+        the one that showed the stop has been launched by then.  It is
+        discarded (counter ``decode.ahead.discarded``): its tokens reach
+        neither ``on_tokens`` nor the result.
 
         ``prefill=True`` seeds the KV caches with a fused full-sequence
         pipelined pass (each group's whole prompt crosses each stage in
@@ -923,7 +949,19 @@ class PipelinedDecoder:
         batch spans several pipeline-fill rounds) — pair with
         ``token_chunk`` for incremental delivery.  With ``eos_id``,
         streamed tokens past a sequence's EOS are garbage the final
-        result replaces with ``eos_id``.
+        result replaces with ``eos_id``.  A chunk's tokens are handed
+        over while the next chunk (where there is one) already runs.
+        ``on_tokens`` may raise to stop the generation: the exception
+        passes through, and ``generate`` returns without waiting for
+        the chunk it had launched ahead, which runs to its end on the
+        device (the counters of what the blocks sowed hold the chunks
+        handed over, not that one).
+
+        After a generation ``self.state`` is the memory as its last
+        chunk left it; after a stop (EOS, a raising callback) it is at
+        the stop or one chunk past it.  The next ``generate`` (and
+        ``reweight``) first waits for a chunk left running, then lets
+        that memory go, then allocates.
         """
         prompt_ids = np.asarray(prompt_ids)
         if prompt_ids.ndim != 2:
@@ -977,27 +1015,22 @@ class PipelinedDecoder:
                    "new_tokens": max_new_tokens,
                    "chunk_steps": self._schedule(
                        t_tok, plen if prefill else 0, token_chunk)[1]}):
-            try:
-                return self._generate_fill(
-                    prompt_ids, t_tok, temperature=temperature, top_k=top_k,
-                    seed=seed, eos_id=eos_id, token_chunk=token_chunk,
-                    prefill=prefill, on_tokens=on_tokens)
-            finally:
-                # however the generation ended (a caller's on_tokens may
-                # raise to stop it): what the steps that ran have sown
-                self._post_stats()
+            return self._generate_fill(
+                prompt_ids, t_tok, temperature=temperature, top_k=top_k,
+                seed=seed, eos_id=eos_id, token_chunk=token_chunk,
+                prefill=prefill, on_tokens=on_tokens)
 
-    def _post_stats(self) -> None:
-        """Add the blocks' sown sums (``DecoderBlock.decode_stats``),
-        kept on the device step by step, to the ``decode.<name>``
-        counters: one fetch a generation, no sync a step."""
-        stats, self._live_stats = self._live_stats, None
-        if stats is None or stats.is_deleted():
+    def _post_stats(self, sums: np.ndarray) -> None:
+        """Add ``sums``, what the blocks sowed
+        (``DecoderBlock.decode_stats``) in the chunks a generation read,
+        to the ``decode.<name>`` counters.  Host numbers (each chunk's
+        came with its ids): nothing here waits for the device, so a
+        chunk still running behind a stop is not waited for."""
+        if not self._stat_names:
             return
         with span("decode", "moe_stats"):
-            sums = np.asarray(stats).sum(axis=0)
-        for name, total in zip(self._stat_names, sums):
-            REGISTRY.counter(f"decode.{name}").inc(int(total))
+            for name, total in zip(self._stat_names, sums):
+                REGISTRY.counter(f"decode.{name}").inc(int(total))
 
     def _generate_fill(self, prompt_ids: np.ndarray, t_tok: int, *,
                        temperature, top_k, seed, eos_id, token_chunk,
@@ -1017,11 +1050,13 @@ class PipelinedDecoder:
             top_k = None  # unused by argmax; keep the program caches keyed
             # identically so greedy calls never recompile over it
         with span("decode", "init"):
-            prompt_dev = jnp.asarray(prompt)
-            plen_s = jnp.int32(plen)
-            seed_s = jnp.uint32(seed)
-            temp_s = jnp.float32(temperature)
-            self.state = None       # let the last generation's buffers go
+            # a chunk the last generation launched ahead of its stop may
+            # still run and hold its memory: wait, then let that go
+            self._settle()
+            self.state = None
+            prompt_dev, plen_s, seed_s, temp_s = jax.device_put(
+                (prompt, np.int32(plen), np.uint32(seed),
+                 np.float32(temperature)), self._everywhere)
             a, caches = self._init_state()
 
         if prefill:
@@ -1048,81 +1083,109 @@ class PipelinedDecoder:
             num_steps, chunk_steps = self._schedule(t_tok, start,
                                                     token_chunk)
             fn = self._get_decode_fn(chunk_steps, sample, top_k)
-            fi_dev = jnp.asarray(first_ids_np if first_ids_np is not None
-                                 else np.zeros((n, mb), np.int32))
-            fp_s = jnp.int32(plen if prefill else -1)
-            start_s = jnp.int32(start)
+            fi_dev, fp_s, start_s = jax.device_put(
+                (first_ids_np if first_ids_np is not None
+                 else np.zeros((n, mb), np.int32),
+                 np.int32(plen if prefill else -1), np.int32(start)),
+                self._everywhere)
             out3, p0 = self._gather_init(prompt, plen, t_tok, start,
                                          first_ids_np)
-        chunks: list = []  # device chunks (batch path), drained at the end
-        incremental = eos_id is not None or on_tokens is not None
+        flat = out3.reshape(n * mb, t_tok)[:b]
         p_done = plen - 1  # last position already delivered to on_tokens
         if on_tokens is not None and prefill and t_tok > plen:
             # the prefill already produced position plen (first_ids)
-            flat = out3.reshape(n * mb, t_tok)[:b]
             with span("decode", "emit"):
                 on_tokens(plen, plen + 1, flat[:, plen: plen + 1].copy(),
                           rows=(0, b))
             p_done = plen
-        steps_run = 0
-        while steps_run < num_steps:
-            with span("decode", "dispatch",
-                      {"steps_run": steps_run, "chunk_steps": chunk_steps}):
-                with span("decode", "upload"):
-                    at = (jnp.int32(steps_run), jnp.int32(num_steps))
-                with span("decode", "launch"):
-                    a, caches, ids = fn(self._w, prompt_dev, plen_s, *at,
-                                        seed_s, temp_s, fi_dev, fp_s,
-                                        start_s, a, caches)
-                # dropped while the device runs the chunk, as the call's
-                # own temporaries were: not when the next chunk waits
-                del at
-            self._live_stats = caches.get("stats")
-            if not incremental:
-                chunks.append(ids)
-                steps_run += chunk_steps
-                continue
-            with span("decode", "sync"):
-                ids_np = np.asarray(ids[0])
-            with span("decode", "scatter"):
-                # incremental scatter of just this chunk: linear host work
-                self._gather_into(out3, ids_np, steps_run, t_tok, start, p0)
-                steps_run += chunk_steps
-                # positions already decodable for EVERY group this far
-                p_avail = start + min(
-                    (steps_run - 1 - (n - 1) - g) // n + 1
-                    for g in range(n))
-                p_avail = min(p_avail, t_tok - 1)
-                flat = out3.reshape(n * mb, t_tok)[:b]
-                new = None
-                if on_tokens is not None and p_avail > p_done \
-                        and p_avail >= plen:
-                    lo = max(p_done + 1, plen)
-                    new = flat[:, lo: p_avail + 1].copy()
-                all_eos = eos_id is not None and p_avail >= plen and np.all(
-                    (flat[:, plen: p_avail + 1] == eos_id).any(axis=1))
-            if new is not None:
-                with span("decode", "emit"):
-                    on_tokens(lo, p_avail + 1, new, rows=(0, b))
-                p_done = p_avail
-            if all_eos:
-                break
-        for i, c in enumerate(chunks):  # non-incremental: one pass at the end
-            with span("decode", "sync"):
-                ids_np = np.asarray(c[0])
-            with span("decode", "scatter"):
-                self._gather_into(out3, ids_np, i * chunk_steps,
-                                  t_tok, start, p0)
-        self.state = caches
-        out = out3.reshape(n * mb, t_tok)[:b]
+        # Chunk n+1 needs nothing of chunk n that the host reads (its
+        # inputs are n's device outputs and where it starts), so a chunk
+        # is launched before the one ahead of it is read: the device has
+        # its next program while the host wakes, scatters and emits.
+        # Tokens wanted as they come (streaming, the EOS check) keep one
+        # chunk unread behind the newest; otherwise every chunk is
+        # launched first and all are read at the end.
+        unread = 1 if eos_id is not None or on_tokens is not None \
+            else num_steps
+        launched: collections.deque = collections.deque()
+        ahead = REGISTRY.counter("decode.ahead.launched")
+        steps_run = 0           # the next chunk to launch starts here
+        all_eos = False
+        # what the blocks sowed in the chunks read so far
+        sums = np.zeros(len(self._stat_names), np.int64)
+        try:
+            while not all_eos and (steps_run < num_steps or launched):
+                if steps_run < num_steps:
+                    with span("decode", "dispatch",
+                              {"steps_run": steps_run,
+                               "chunk_steps": chunk_steps}):
+                        with span("decode", "upload"):
+                            at = jax.device_put(
+                                (np.int32(steps_run), np.int32(num_steps)),
+                                self._everywhere)
+                        with span("decode", "launch"):
+                            a, caches, ids, *sown = fn(
+                                self._w, prompt_dev, plen_s, *at, seed_s,
+                                temp_s, fi_dev, fp_s, start_s, a, caches)
+                        # stage 0's shard, where the wrap link's ids
+                        # arrive, and each stage's sums: buffers of this
+                        # chunk, whose transfer waits for it alone
+                        # (``ids[0]`` is a program of its own, queued
+                        # behind the next chunk)
+                        out = [ids.addressable_data(0), *sown]
+                        # dropped while the device runs the chunk, as the
+                        # call's own temporaries were: not when the next
+                        # chunk waits
+                        del at, ids, sown
+                    if launched:
+                        ahead.inc()
+                    launched.append((steps_run, out))
+                    steps_run += chunk_steps
+                    if steps_run < num_steps and len(launched) <= unread:
+                        continue
+                t0, out = launched.popleft()
+                with span("decode", "sync", {"ahead": int(bool(launched))}):
+                    ids_np, *sown = [np.asarray(o) for o in out]
+                    del out
+                with span("decode", "scatter"):
+                    # incremental scatter of just this chunk: linear host
+                    # work
+                    self._gather_into(out3, ids_np[0], t0, t_tok, start, p0)
+                    if sown:
+                        sums += sown[0].sum(axis=0)
+                    # positions already decodable for EVERY group this far
+                    p_avail = start + min(
+                        (t0 + chunk_steps - 1 - (n - 1) - g) // n + 1
+                        for g in range(n))
+                    p_avail = min(p_avail, t_tok - 1)
+                    new = None
+                    if on_tokens is not None and p_avail > p_done \
+                            and p_avail >= plen:
+                        lo = max(p_done + 1, plen)
+                        new = flat[:, lo: p_avail + 1].copy()
+                    all_eos = eos_id is not None and p_avail >= plen \
+                        and np.all((flat[:, plen: p_avail + 1]
+                                    == eos_id).any(axis=1))
+                if new is not None:
+                    with span("decode", "emit"):
+                        on_tokens(lo, p_avail + 1, new, rows=(0, b))
+                    p_done = p_avail
+        finally:
+            # a stop (every sequence at its EOS, a callback that raised)
+            # leaves the chunk launched ahead of it unread: its tokens
+            # reach no one, and the next allocation waits for it
+            REGISTRY.counter("decode.ahead.discarded").inc(len(launched))
+            self.state, self._tail = caches, a
+            # however the generation ended: what the chunks it read sowed
+            self._post_stats(sums)
         if eos_id is not None:
             # freeze everything after each sequence's first generated EOS
-            gen = out[:, plen:]
+            gen = flat[:, plen:]
             hit = gen == eos_id
             first = np.where(hit.any(1), hit.argmax(1), gen.shape[1])
             mask = np.arange(gen.shape[1])[None, :] > first[:, None]
             gen[mask] = eos_id
-        return out
+        return flat
 
     def _generate_beam(self, prompt_ids: np.ndarray, max_new_tokens: int,
                        *, token_chunk: int | None) -> np.ndarray:
@@ -1172,10 +1235,12 @@ class PipelinedDecoder:
         chunks = []
         steps_run = 0
         while steps_run < num_steps:
+            # (blocks that sow hand their sums out last; no one reads
+            # them here)
             a, caches, ids = fn(self._w, prompt_dev, jnp.int32(plen),
                                 jnp.int32(steps_run), jnp.int32(num_steps),
                                 jnp.uint32(0), jnp.float32(0.0), fi_dev,
-                                jnp.int32(-1), zero, a, caches)
+                                jnp.int32(-1), zero, a, caches)[:3]
             chunks.append(ids)
             steps_run += chunk_steps
         arr = np.concatenate([np.asarray(c[0]) for c in chunks], axis=0)

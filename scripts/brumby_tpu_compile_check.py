@@ -76,8 +76,6 @@ def main() -> int:
     state = {key: (arg((1,) + buf.shape, buf.dtype,
                        P(STAGE_AXIS, *(None,) * len(buf.shape))),)
              * dec.l_max for key, buf in buffers.items()}
-    state["stats"] = arg((1, len(dec._stat_names)), jnp.int32,
-                         P(STAGE_AXIS, None))
     i32, u32, f32 = (arg((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     prompt = arg((1, MB, PLEN), jnp.int32, P(None, None, None))
 

@@ -86,8 +86,6 @@ def main() -> int:
     caches = {key: tuple(arg((1,) + s[key].shape, s[key].dtype,
                              P(STAGE_AXIS, *(None,) * len(s[key].shape)))
                          for s in shapes) for key in shapes[0]}
-    caches["stats"] = arg((1, len(dec._stat_names)), jnp.int32,
-                          P(STAGE_AXIS, None))
     i32, u32, f32 = (arg((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     prompt = arg((1, mb, plen), jnp.int32, P(None, None, None))
 

@@ -695,6 +695,150 @@ def test_streaming_with_eos(model, prompt):
     assert hits.size and (gen[hits[0]:] == eos).all()
 
 
+def _chunk_counts():
+    """(chunks launched, chunks read, launched ahead, discarded) so far."""
+    from defer_tpu.obs import REGISTRY
+    return (REGISTRY.histogram("decode.dispatch_s").count,
+            REGISTRY.histogram("decode.sync_s").count,
+            REGISTRY.counter("decode.ahead.launched").n,
+            REGISTRY.counter("decode.ahead.discarded").n)
+
+
+@pytest.mark.parametrize("prefill", [False, True],
+                         ids=["teacher_forced", "prefill"])
+@pytest.mark.parametrize("num_stages", [1, 2, 3, 4])
+def test_streaming_keeps_one_chunk_ahead_and_changes_no_token(
+        model, prompt, num_stages, prefill):
+    """The loop launches chunk n+1 before it reads chunk n: the tokens
+    streamed and returned are the ones a generation with nothing
+    streamed returns, and ``on_tokens`` never holds a chunk's tokens
+    before the next chunk (where there is one) was launched.  Four new
+    positions a chunk do not divide the eleven generated."""
+    graph, params = model
+    rows = num_stages * 2
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN)
+    want = dec.generate(prompt[:rows], 11, prefill=prefill)
+    num_steps, chunk_steps = dec._schedule(16, 5 if prefill else 0, 4)
+    chunks = -(-num_steps // chunk_steps)
+    assert chunks >= 3 and num_steps % chunk_steps
+    before = _chunk_counts()
+    spans, seen = [], []
+
+    def on_tokens(lo, hi, toks, rows):
+        spans.append((lo, hi, toks))
+        seen.append([now - was for now, was in
+                     zip(_chunk_counts(), before)])
+
+    got = dec.generate(prompt[:rows], 11, prefill=prefill, token_chunk=4,
+                       on_tokens=on_tokens)
+    np.testing.assert_array_equal(got, want)
+    assert spans[0][0] == 5 and spans[-1][1] == 16
+    assert all(a[1] == b[0] for a, b in zip(spans, spans[1:]))
+    np.testing.assert_array_equal(
+        np.concatenate([t for _lo, _hi, t in spans], axis=1), want[:, 5:])
+    for launched, read, _ahead, discarded in seen:
+        assert not discarded
+        if read:        # (a prefill's own first token comes before any)
+            assert launched == min(read + 1, chunks)
+    launched, read, ahead, discarded = [
+        now - was for now, was in zip(_chunk_counts(), before)]
+    assert (launched, read, ahead, discarded) \
+        == (chunks, chunks, chunks - 1, 0)
+
+
+@pytest.mark.parametrize("prefill", [False, True],
+                         ids=["teacher_forced", "prefill"])
+@pytest.mark.parametrize("num_stages", [1, 2, 4])
+def test_an_eos_stop_discards_the_one_chunk_launched_past_it(
+        model, prompt, num_stages, prefill):
+    """Every sequence the same, so all reach the EOS together: the stop
+    is seen when the chunk that holds it is read, one chunk is running
+    by then, and its tokens reach neither the callback nor the result
+    (which is what a whole generation gives, frozen at the EOS)."""
+    graph, params = model
+    same = np.repeat(prompt[:1], num_stages * 2, axis=0)
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN)
+    ref = dec.generate(same, 16, prefill=prefill)
+    eos = int(ref[0, 5 + 3])
+    at = 5 + int(np.where(ref[0, 5:] == eos)[0][0])    # first EOS position
+    want = ref.copy()
+    want[:, at + 1:] = eos
+    num_steps, chunk_steps = dec._schedule(21, 5 if prefill else 0, 2)
+    chunks = -(-num_steps // chunk_steps)
+    before = _chunk_counts()
+    his = []
+    got = dec.generate(same, 16, prefill=prefill, token_chunk=2, eos_id=eos,
+                       on_tokens=lambda lo, hi, t, rows: his.append(hi))
+    np.testing.assert_array_equal(got, want)
+    launched, read, _ahead, discarded = [
+        now - was for now, was in zip(_chunk_counts(), before)]
+    assert launched == read + 1 < chunks and discarded == 1
+    # the chunk that showed the stop was the last one handed over: two
+    # positions a chunk, so nothing at or past ``at + 2`` was
+    assert at < his[-1] <= at + 2
+    assert dec.state is not None        # at or one chunk past the stop
+    # and the decoder goes on: the next generation waits for that chunk
+    np.testing.assert_array_equal(dec.generate(same, 16, prefill=prefill),
+                                  ref)
+
+
+@pytest.mark.parametrize("then", ["generate", "reweight"])
+@pytest.mark.parametrize("num_stages", [1, 2, 4])
+def test_a_callback_that_raises_leaves_one_chunk_running_and_the_decoder_whole(
+        model, prompt, num_stages, then):
+    graph, params = model
+    rows = num_stages * 2
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN)
+    want = dec.generate(prompt[:rows], 12, prefill=True, token_chunk=3)
+
+    class Stop(Exception):
+        pass
+
+    def on_tokens(lo, hi, toks, rows):
+        np.testing.assert_array_equal(toks, want[:, lo:hi])
+        if hi >= 5 + 4:
+            raise Stop
+
+    before = _chunk_counts()
+    with pytest.raises(Stop):
+        dec.generate(prompt[:rows], 12, prefill=True, token_chunk=3,
+                     on_tokens=on_tokens)
+    launched, read, ahead, discarded = [
+        now - was for now, was in zip(_chunk_counts(), before)]
+    assert read >= 1 and (launched, ahead, discarded) == (read + 1, read, 1)
+    # the chunk launched ahead is what the next allocation waits for
+    assert dec._tail is not None and dec.state is not None
+    if then == "reweight":
+        dec.reweight(params)
+        assert dec._tail is None
+    got = dec.generate(prompt[:rows], 12, prefill=True, token_chunk=3)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_chunks_ids_are_read_from_stage_zeros_own_buffer(model, prompt):
+    """``ids[0]`` of the stage-sharded output is a program of its own,
+    queued behind whatever was launched last; the loop reads the shard
+    that holds the same numbers, a transfer of the chunk's own buffer."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
+                           max_len=MAX_LEN)
+    dec.generate(prompt, 4, token_chunk=2)
+    fn = dec._get_decode_fn(8, False, None)
+    a, caches = dec._init_state()
+    z = jnp.int32(0)
+    _a, _c, ids = fn(dec._w, jnp.zeros((4, 2, 5), jnp.int32), jnp.int32(5),
+                     z, jnp.int32(8), jnp.uint32(0), jnp.float32(0),
+                     jnp.zeros((4, 2), jnp.int32), jnp.int32(-1), z, a,
+                     caches)
+    own = ids.addressable_data(0)
+    assert own.shape == (1,) + ids.shape[1:]
+    assert own.devices() == {dec.mesh.devices.flat[0]}
+    np.testing.assert_array_equal(np.asarray(own)[0], np.asarray(ids[0]))
+
+
 def test_beam_with_int8_cache(model, prompt):
     """Beam re-parenting gathers the int8 cache AND its scale entries."""
     graph, params = model

@@ -430,11 +430,20 @@ def test_moe_counters_equal_the_references_chosen_experts(model, ids,
         for s, _g, _p in _ring_steps(num_stages, PLEN, PLEN + NEW, SEQ))
 
 
-def test_counters_are_fetched_when_the_caller_stops_a_generation(model, ids):
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_counters_are_fetched_when_the_caller_stops_a_generation(model, ids,
+                                                                 num_stages):
+    """The counters hold what was sown up to the last chunk handed over,
+    and nothing of the chunk that ran ahead of the stop: a chunk's sums
+    come to the host with its ids, so the stop waits for nothing."""
     graph, params = model
-    dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=4,
-                           max_len=SEQ)
+    mb = 4 // num_stages
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=mb, max_len=SEQ)
+    want = dec.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=3)
     before = REGISTRY.counter(COUNTERS[0]).n
+    discarded = REGISTRY.counter("decode.ahead.discarded").n
+    syncs = REGISTRY.histogram("decode.sync_s").count
 
     class Stop(Exception):
         pass
@@ -443,11 +452,28 @@ def test_counters_are_fetched_when_the_caller_stops_a_generation(model, ids):
         if hi >= PLEN + 4:
             raise Stop
 
+    posted, post = [], dec._post_stats
+    dec._post_stats = lambda sums: (posted.append(sums), post(sums))
     with pytest.raises(Stop):
         dec.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=3,
                      on_tokens=on_tokens)
-    # one chunk of three steps ran: 3 steps x 2 layers x 4 rows x 2
-    assert REGISTRY.counter(COUNTERS[0]).n - before == 3 * 2 * 4 * 2
+    del dec._post_stats
+    # summed on the host as the chunks were read: no device array is
+    # left for the generation's end to wait for
+    assert len(posted) == 1 and type(posted[0]) is np.ndarray
+    assert REGISTRY.counter("decode.ahead.discarded").n == discarded + 1
+    read = REGISTRY.histogram("decode.sync_s").count - syncs
+    # the live (stage, step)s of the chunks that were read, of 3 x
+    # num_stages steps each: x the stage's layers x mb rows x 2 choices
+    steps = read * 3 * num_stages
+    live = sum(len(dec.stage_blocks[s])
+               for t in range(steps) for s in range(num_stages) if t >= s)
+    assert read == (1 if num_stages == 1 else 2)
+    assert REGISTRY.counter(COUNTERS[0]).n - before == live * mb * 2
+    # the decoder is whole: the next generation waits for the chunk that
+    # was left running, and gives the tokens it gave before
+    np.testing.assert_array_equal(
+        dec.generate(ids[:, :PLEN], NEW, prefill=True, token_chunk=3), want)
 
 
 # -- the block interface, and who refuses it -----------------------------------------

@@ -449,14 +449,20 @@ def test_generate_names_its_phases_once_a_chunk_in_order(decoder, traced):
     assert names.count("emit") == len(calls) >= chunks
     assert names[3] == "emit"            # the prefill's own first token
     body = names[4:]
-    # each chunk: dispatch, sync, scatter, then emit where tokens came
-    assert [p for p in body if p != "emit"] \
-        == ["dispatch", "sync", "scatter"] * chunks
+    # a round: the next chunk's dispatch, then the sync and the scatter
+    # of the one before it, then emit where tokens came.  The same
+    # counts as ever: the first round only launches, the last only reads
+    assert [p for p in body if p != "emit"] == ["dispatch"] \
+        + ["dispatch", "sync", "scatter"] * (chunks - 1) \
+        + ["sync", "scatter"]
     assert all(body[i - 1] == "scatter"
                for i, p in enumerate(body) if p == "emit")
     assert [s["args"]["steps_run"] for s in kids
             if s["name"] == "decode.dispatch"] \
         == [i * chunk_steps for i in range(chunks)]
+    # every wait but the last had a later chunk launched behind it
+    assert [s["args"] for s in kids if s["name"] == "decode.sync"] \
+        == [{"ahead": 1}] * (chunks - 1) + [{"ahead": 0}]
 
 
 def test_an_exception_in_on_tokens_passes_through_emit(decoder, traced):
@@ -477,6 +483,9 @@ def test_an_exception_in_on_tokens_passes_through_emit(decoder, traced):
     assert kids[-1]["name"] == "decode.emit"
     assert kids[-1]["args"] == {"error": "Stop"}
     assert all("error" not in s["args"] for s in kids[:-1])
+    # the chunk launched ahead of the stop was never waited for
+    names = [s["name"] for s in kids]
+    assert names.count("decode.dispatch") == names.count("decode.sync") + 1
     # the thread's span stack is clean again: the next span is a root
     with span("decode", "scatter") as sp:
         pass
