@@ -16,6 +16,7 @@ from .gpt import gpt, gpt2_small, gpt_small, gpt_stage_cuts, gpt_tiny
 from .moe import (moe_branched, moe_branched_tiny, moe_stage_cuts,
                   moe_tiny, moe_transformer)
 from .olmoe import olmoe, olmoe_tiny
+from .jamba import jamba, jamba_tiny
 from .inception import (INCEPTION_6STAGE_CUTS, inception, inception_tiny,
                         inception_v3)
 from .mobilenet import (MOBILENETV2_2STAGE_CUTS, mobilenet_tiny, mobilenet_v2)
@@ -34,4 +35,5 @@ __all__ = [
     "olmoe", "olmoe_tiny",
     "brumby", "brumby_tiny",
     "cohere_moe", "cohere_moe_tiny",
+    "jamba", "jamba_tiny",
 ]

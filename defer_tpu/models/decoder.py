@@ -5,11 +5,13 @@ it, whatever its family.
 * **Nodes**, by name: ``embeddings`` (an op with ``max_len``, the
   positions the model declares, and ``embed_at(params, ids, pos)``),
   ``block_0..`` in topological order (each a :class:`DecoderBlock`, all
-  keeping one kind of memory in one head geometry and sowing the same
-  statistics; how *much* of it a layer keeps is the layer's own: a
-  block whose attention has a ``window`` keeps a ring buffer of that
-  many rows beside a neighbour that keeps every position),
-  ``final_ln``, ``lm_head``.  Any of them may name
+  of one stream width and sowing the same statistics; *what* a layer
+  keeps a sequence, in which geometry and how much of it, is the
+  layer's own: a block whose attention has a ``window`` keeps a ring
+  buffer of that many rows beside a neighbour that keeps every
+  position, and a state-space block a state of fixed size beside a
+  block that keeps a KV cache), ``final_ln``, ``lm_head``.  Any of
+  them may name
   ``stage_arg_keys``.  :func:`decoder_parts` checks a graph against
   this and hands back its parts; both engines' constructors call it.
 * **Blocks**: :class:`DecoderBlock`, whose per-sequence memory is a KV
@@ -17,10 +19,12 @@ it, whatever its family.
   (``ops/kv_cache.py``) and takes the attention's output back.  Its
   sibling :class:`RetentionBlock` keeps a recurrent state of fixed size
   instead: it hands ``q, k, v`` and a log-decay to the state's format
-  (``ops/retention.py``) and takes the layer's output back.  Neither
+  (``ops/retention.py``) and takes the layer's output back.  Its
+  sibling :class:`StateSpaceBlock` keeps a convolution window and a
+  state-space state (``ops/ssm.py``) and has no heads at all.  None
   knows an axis order, key or type of what the format holds; which kind
   a block keeps is the class it is (``memory``), and the holder asks
-  the block for the format (:meth:`DecoderBlock.memory_format`).
+  every block for its format (:meth:`DecoderBlock.memory_format`).
 """
 
 from __future__ import annotations
@@ -106,23 +110,38 @@ class DecoderBlock:
     #: field)
     window = None
 
-    def memory_format(self, head_dim: int, positions: int, dtype, *,
+    def geometry(self, d_model: int):
+        """``(query heads, KV heads, a head's width)`` of this layer on
+        a stream of width ``d_model`` (a block without a ``head_dim`` of
+        its own splits the stream among its heads); None for a block
+        that has no heads."""
+        return (self.num_heads, self.kv_heads,
+                getattr(self, "head_dim", None) or d_model // self.num_heads)
+
+    def widest(self, d_model: int) -> int:
+        """Columns of the widest activation a token has inside this
+        layer: what a holder sizes a prefill's pieces by."""
+        heads, _, head_dim = self.geometry(d_model)
+        return max(d_model, heads * head_dim)
+
+    def memory_format(self, d_model: int, positions: int, dtype, *,
                       quantized: bool = False, groups: int | None = None):
-        """The format of one layer of this block's memory, for
-        ``positions`` positions of rows of type ``dtype``: what a
-        holder builds its buffers from and hands back to :meth:`decode`
-        and :meth:`prefill`.  The format is *this layer's*: a block
-        with a ``window`` shorter than ``positions`` keeps a ring buffer
-        of ``window`` rows, its neighbour without one a row a position,
+        """The format of one layer of this block's memory on a stream
+        of width ``d_model``, for ``positions`` positions of rows of
+        type ``dtype``: what a holder builds its buffers from and hands
+        back to :meth:`decode` and :meth:`prefill`.  The format is
+        *this layer's*, kind and geometry and length: a block with a
+        ``window`` shorter than ``positions`` keeps a ring buffer of
+        ``window`` rows, its neighbour without one a row a position,
         and a holder asks every block."""
         from ..ops import kv_cache   # the class as the module names it now
         window = self.window
         if window is not None and window >= positions:
             window = None       # never wraps: a row a position
+        heads, kv_heads, head_dim = self.geometry(d_model)
         return kv_cache.KVCacheFormat(
-            self.kv_heads, head_dim, positions, dtype, quantized=quantized,
-            groups=groups, window=window,
-            query_group=self.num_heads // self.kv_heads)
+            kv_heads, head_dim, positions, dtype, quantized=quantized,
+            groups=groups, window=window, query_group=heads // kv_heads)
 
     def decode(self, params, x, cache, pos, fmt, slot=None, group=None,
                sow=None):
@@ -178,7 +197,7 @@ class RetentionBlock(DecoderBlock):
 
     memory = "retention"
 
-    def memory_format(self, head_dim: int, positions: int, dtype, *,
+    def memory_format(self, d_model: int, positions: int, dtype, *,
                       quantized: bool = False, groups: int | None = None):
         """A state's size does not depend on ``positions``, and it is
         float32 whatever ``dtype`` the block computes in."""
@@ -188,8 +207,8 @@ class RetentionBlock(DecoderBlock):
                 "kv_cache='int8' quantizes cached key and value rows; "
                 "these blocks keep a retention state, which has none")
         from ..ops import retention
-        return retention.RetentionFormat(self.kv_heads, head_dim,
-                                         groups=groups)
+        _, kv_heads, head_dim = self.geometry(d_model)
+        return retention.RetentionFormat(kv_heads, head_dim, groups=groups)
 
     def decode(self, params, x, state, pos, fmt, slot=True, group=None,
                sow=None):
@@ -214,6 +233,87 @@ class RetentionBlock(DecoderBlock):
         return out.reshape(b, t, d), state
 
 
+class StateSpaceBlock(DecoderBlock):
+    """A decoder block whose per-sequence memory is a selective
+    state-space mixer's (``ops/ssm.py``): the last inputs of a causal
+    convolution and a recurrent state ``H``, both of fixed size, the
+    state read *and rewritten whole* every step.  It has no heads.  In
+    place of ``apply_with_kv`` / ``decode_qkv`` such a block has
+    ``channels``, ``states`` and ``d_conv`` (the state's sizes) and
+
+    * ``mixer_inputs(params, x [..., d]) -> (u, z)``: the convolution's
+      input and the gate, each [..., E];
+    * ``mixer_conv(params, taps) -> c``: the convolution over its
+      ``d_conv`` taps (``ops/ssm.py::causal_conv`` under the block's
+      weights), [..., E];
+    * ``mixer_selection(params, c [..., E]) -> (dt, b, c_read, a)``: the
+      step [..., E] and the two projections [..., N] of the selection,
+      and the matrix ``A`` [N, E];
+    * ``decode_finish(params, x [T, d], y [T, E], c, z, sow=None)``: the
+      rest of the block after the recurrence's output ``y`` (the skip
+      term, the gate, the output projection, the MLP).
+
+    ``decode_stats`` and ``stage_arg_keys`` are :class:`DecoderBlock`'s.
+    No serving engine takes such a block yet (``serve/engine.py``
+    refuses every block but GPT's).
+    """
+
+    memory = "ssm"
+
+    def geometry(self, d_model: int):
+        del d_model
+        return None
+
+    def widest(self, d_model: int) -> int:
+        """The input projection's ``[u, z]``."""
+        return max(d_model, 2 * self.channels)
+
+    def memory_format(self, d_model: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """A state's size depends neither on the stream's width nor on
+        ``positions``; the window is of type ``dtype``, ``H`` float32."""
+        del d_model, positions
+        if quantized:
+            raise ValueError(
+                "kv_cache='int8' quantizes cached key and value rows; "
+                "these blocks keep a state-space state, which has none")
+        from ..ops import ssm
+        return ssm.SsmFormat(self.channels, self.states, self.d_conv, dtype,
+                             groups=groups)
+
+    def decode(self, params, x, state, pos, fmt, slot=True, group=None,
+               sow=None):
+        """One-token step: ``x`` [b, d] against one layer's ``state``;
+        ``slot`` is ``fmt.decode_slot``'s, handed on to the format
+        unread (it says whether the step is real: a bubble leaves the
+        window and ``H`` as they are).  The position is not read."""
+        del pos
+        u, z = self.mixer_inputs(params, x)
+        taps, state = fmt.shift(u, state, group=group, valid=slot)
+        c = self.mixer_conv(params, taps)
+        dt, b, c_read, a = self.mixer_selection(params, c)
+        y, state = fmt.step(dt, c, b, c_read, a, state, group=group,
+                            valid=slot)
+        return self.decode_finish(params, x, y, c, z, sow=sow), state
+
+    def prefill(self, params, x, state, fmt, slot=(None, True)):
+        """A whole prompt ``x`` [b, t, d] through the layer from an
+        empty memory; the window and the state after its last position
+        are left where ``slot`` (``fmt.prefill_slot``'s, handed on
+        unread) says."""
+        b, t, d = x.shape
+        u, z = self.mixer_inputs(params, x)
+        taps, state = fmt.prefill_shift(u, state, slot)
+        c = self.mixer_conv(params, taps)
+        dt, b_in, c_read, a = self.mixer_selection(params, c)
+        y, state = fmt.prefill(dt, c, b_in, c_read, a, state, slot)
+        e = y.shape[-1]
+        out = self.decode_finish(
+            params, x.reshape(b * t, d), y.reshape(b * t, e),
+            c.reshape(b * t, e), z.reshape(b * t, e))
+        return out.reshape(b, t, d), state
+
+
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
     """Contiguous, balanced block assignment (stage i gets ~L/N blocks)."""
     bounds = [round(num_blocks * s / num_stages)
@@ -231,15 +331,16 @@ class DecoderParts:
 
     embed_op: Any
     block_names: tuple          #: ``block_*`` in topological order
-    d_model: int
-    num_heads: int
-    kv_heads: int
-    head_dim: int
+    d_model: int                #: the stream's width, every block's
     vocab: int
     max_len: int                #: positions a cache is to hold
     stage_blocks: list          #: per stage, its blocks' names, balanced
     decode_stats: tuple         #: what every block sows each step
-    memory: str                 #: the kind of memory every block keeps
+    #: per block, the kind of memory it keeps (``DecoderBlock.memory``)
+    memory: tuple
+    #: per block, ``(query heads, KV heads, a head's width)``, or None
+    #: for a block without heads (``DecoderBlock.geometry``)
+    geometry: tuple
 
 
 def decoder_parts(graph: LayerGraph, num_stages: int,
@@ -273,31 +374,24 @@ def decoder_parts(graph: LayerGraph, num_stages: int,
                     for idxs in split_blocks(len(block_names), num_stages)]
     first = nodes[block_names[0]]
     d_model = first.out_spec.shape[-1]
-
-    def geometry(op):
-        return (op.num_heads, op.kv_heads,
-                getattr(op, "head_dim", None) or d_model // op.num_heads)
-
-    heads = geometry(first.op)
     stats = tuple(first.op.decode_stats)
     for nm in block_names:
         op = nodes[nm].op
-        if op.memory != first.op.memory:
+        # what the ring needs alike of every block: the stream it hands
+        # from one to the next, and the ledger their sums share.  Kind,
+        # geometry and length of a layer's memory are the layer's own.
+        if nodes[nm].out_spec.shape[-1] != d_model:
             raise ValueError(
-                f"{nm} keeps a {op.memory}, block_0 a {first.op.memory}: "
-                "one kind of format serves every block of a graph")
-        if geometry(op) != heads:
-            raise ValueError(
-                f"{nm} has heads ({op.num_heads}, kv {op.kv_heads}) "
-                f"!= block_0's ({heads[0]}, {heads[1]}); the "
-                "homogeneous cache needs one head geometry")
+                f"{nm}'s stream is {nodes[nm].out_spec.shape[-1]} wide, "
+                f"block_0's {d_model}: one activation crosses every block")
         if tuple(op.decode_stats) != stats:
             raise ValueError(
                 f"{nm} sows {op.decode_stats}, block_0 {stats}: one "
                 "ledger serves every block")
+    ops = [nodes[nm].op for nm in block_names]
     return DecoderParts(
         embed_op=embed_op, block_names=block_names, d_model=d_model,
-        num_heads=heads[0], kv_heads=heads[1], head_dim=heads[2],
         vocab=nodes["lm_head"].out_spec.shape[-1], max_len=max_len,
         stage_blocks=stage_blocks, decode_stats=stats,
-        memory=first.op.memory)
+        memory=tuple(op.memory for op in ops),
+        geometry=tuple(op.geometry(d_model) for op in ops))

@@ -103,8 +103,9 @@ DECODE_DISPATCH_PHASES = ("upload", "launch")
 
 #: after a generation's last chunk, where the ring's blocks sow per-step
 #: statistics (``DecoderBlock.decode_stats``; today the routed experts'
-#: ``decode.moe.*`` counters and the retention blocks'
-#: ``decode.retention.updates``): their sums, which came to the host a
+#: ``decode.moe.*`` counters, the retention blocks'
+#: ``decode.retention.updates`` and the state-space blocks'
+#: ``decode.ssm.updates``): their sums, which came to the host a
 #: chunk at a time with the chunk's ids, go to the counters
 DECODE_STATS_PHASES = ("moe_stats",)
 

@@ -1,21 +1,25 @@
 """What every per-sequence memory of the decode engines shares, whatever
 it holds: a *layer* is a dict of device buffers by key, and the *state*
-of several layers a dict of tuples, one buffer a layer under each key.
-The layers are never stacked into one array: XLA:TPU wraps a write into
-a value that large in copies of all of it (docs/DECODE_CLIFF.md).  A
-holder may keep entries of its own beside the format's in the same
-dict; the format passes them through.
+of several layers a dict of tuples, one entry a layer under each key: a
+buffer, or None where that layer's format has no such key (a
+state-space layer's ``conv`` and ``h`` beside an attention layer's ``k``
+and ``v``: layers of unlike formats lie side by side, each reached
+through its own format alone).  The layers are never stacked into one
+array: XLA:TPU wraps a write into a value that large in copies of all
+of it (docs/DECODE_CLIFF.md).  A holder may keep entries of its own
+beside the formats' in the same dict; a format passes them through.
 
 A format (``ops/kv_cache.py::KVCacheFormat``,
-``ops/retention.py::RetentionFormat``) says what the buffers are
-(``buffers(batch)``, ``keys``) and is the one place that writes and
-reads them.
+``ops/retention.py::RetentionFormat``, ``ops/ssm.py::SsmFormat``) says
+what the buffers are (``buffers(batch)``, ``keys``) and is the one
+place that writes and reads them.
 """
 
 from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 
@@ -41,18 +45,41 @@ class LayeredState:
             key: state[key][:l] + (buf,) + state[key][l + 1:]
             for key, buf in layer.items()})
 
+    # -- for a format whose buffers lie behind a ``groups`` axis or none
+
+    def _group(self, layer: dict, group):
+        """``layer``'s buffers behind a group axis (added where the
+        format has no ``groups``), and the group as kernels and slices
+        take it: [1] int32."""
+        if self.groups is None:
+            return {key: buf[None] for key, buf in layer.items()}, \
+                jnp.zeros(1, jnp.int32)
+        return layer, jnp.asarray(group, jnp.int32).reshape(1)
+
+    def _ungroup(self, layer: dict) -> dict:
+        return layer if self.groups is not None else {
+            key: buf[0] for key, buf in layer.items()}
+
     def state_bytes(self, batch: int, layers: int) -> int:
         """Bytes of ``layers`` layers' buffers for ``batch`` sequences."""
         return layers * sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
                             for s in self.buffers(batch).values())
 
 
-def zeros_by_layer(formats, batch: int, lead: tuple = ()) -> dict:
-    """The empty state of a holder whose layers each have a format of
-    their own (``formats[l]`` layer ``l``'s; all of one kind, so of the
-    same keys): what :meth:`LayeredState.zeros` gives where they are
-    all alike."""
+def shapes_by_layer(formats, batch: int) -> dict:
+    """What a holder's state looks like whose layers each have a format
+    of their own (``formats[l]`` layer ``l``'s, of any kinds): under
+    each key any of them names, a tuple with a layer's
+    ``ShapeDtypeStruct``, or None where the layer keeps nothing under
+    that key."""
     shapes = [fmt.buffers(batch) for fmt in formats]
-    return {key: tuple(jnp.zeros(lead + s[key].shape, s[key].dtype)
-                       for s in shapes)
-            for key in shapes[0]}
+    keys = dict.fromkeys(key for s in shapes for key in s)
+    return {key: tuple(s.get(key) for s in shapes) for key in keys}
+
+
+def zeros_by_layer(formats, batch: int, lead: tuple = ()) -> dict:
+    """The empty state of :func:`shapes_by_layer`'s shape, each buffer
+    behind the holder's own axes ``lead``: what
+    :meth:`LayeredState.zeros` gives where the layers are all alike."""
+    return jax.tree.map(lambda s: jnp.zeros(lead + s.shape, s.dtype),
+                        shapes_by_layer(formats, batch))

@@ -352,18 +352,6 @@ class RetentionFormat(LayeredState):
         return (q.reshape(lead + (kv, -1, d)), k.reshape(lead + (kv, d)),
                 v.reshape(lead + (kv, d)), lg.astype(jnp.float32))
 
-    def _group(self, layer: dict, group):
-        """``layer``'s buffers behind a group axis, and the group as the
-        kernel and the slices take it."""
-        if self.groups is None:
-            return {key: buf[None] for key, buf in layer.items()}, \
-                jnp.zeros(1, jnp.int32)
-        return layer, jnp.asarray(group, jnp.int32).reshape(1)
-
-    def _ungroup(self, layer: dict) -> dict:
-        return layer if self.groups is not None else {
-            key: buf[0] for key, buf in layer.items()}
-
     # -- one token a sequence ------------------------------------------------
 
     def step(self, q, k, v, lg, layer: dict, group=None, valid=True):

@@ -1,0 +1,409 @@
+"""The state-space state's format: the one module that knows how a
+Mamba-1 layer's per-sequence memory is kept on the device, updated and
+read.
+
+A selective state-space mixer (Gu & Dao, arXiv:2312.00752) of ``E``
+channels and ``N`` states keeps two things a sequence, whatever its
+length: the last ``d_conv - 1`` inputs of its depthwise causal
+convolution, and the recurrent state ``H [E, N]``:
+
+    c(t) = silu(b + sum_j w[j] * u(t - d_conv + 1 + j))          (the window)
+    H(t) = exp(dt(t) (x) A) * H(t-1) + (dt(t) * c(t)) (x) B(t)
+    y(t) = H(t) C(t)
+
+``dt [E]``, ``B [N]``, ``C [N]`` are functions of ``c(t)`` (the
+*selection*), ``A [E, N]`` a parameter.  The blocks
+(``models/decoder.py::StateSpaceBlock``) hand over ``u``, then ``dt``,
+``c``, ``B``, ``C`` and ``A``, and take the window's taps and ``y``
+back; they know nothing of what follows.
+
+**The format.**  One layer is a dict of two buffers, behind a leading
+``groups`` axis for the ring:
+
+* ``h [batch, N, E]`` float32 — the states on the sublanes, the
+  channels on the lanes: 16 x 5120 are whole (8, 128) tiles, where ``[E,
+  N]`` would pad 16 values to 128 lanes (8x).  Float32 because the sum
+  runs over hundreds of positions under a decay near 1, as the
+  retention state's does;
+* ``conv [d_conv - 1, batch, E]`` in the compute type — the taps lead,
+  so a tap is ``[batch, E]`` of whole tiles (``[batch, 3, E]`` would pad
+  3 sublanes to 16).  ``conv[j]`` is the input ``d_conv - 1 - j``
+  positions back; before a sequence's start it is zero.
+
+Like a retention state and unlike a KV cache it has **no scratch group
+and no scratch row**: a pipeline's bubble is the identity update (``dt =
+0`` leaves ``H`` bit for bit, and the window is kept), which
+:meth:`SsmFormat.shift`, :meth:`SsmFormat.step` and the two prefill
+calls make of a call whose ``valid`` is false.  The state of several
+layers is a tuple of buffers a key, never stacked (``ops/layered.py``).
+
+* :meth:`SsmFormat.step` — one token a sequence: the aliased Pallas
+  kernel :func:`ssm_step` streams a block of sequences' ``H`` through
+  VMEM once (decay, add, read out, write back in place).
+* :meth:`SsmFormat.prefill` — a whole prompt from an empty memory: the
+  Pallas kernel :func:`ssm_scan` runs the recurrence position by
+  position over a block of channels whose ``H`` stays in registers,
+  carried between blocks of positions in VMEM; the ``[t, E, N]`` tensor
+  of a prompt is never made.
+* :func:`step_reference` / :func:`prefill_reference` — the same in plain
+  ``jnp``, the tests' oracle.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
+from jax.experimental.pallas import tpu as pltpu
+
+from .layered import LayeredState
+
+#: channels a kernel works on at a time: 16 states x 512 channels of
+#: float32 are 8 vector registers, so a block's ``H`` stays in them
+_CHANNELS = 512
+#: sequences of one grid step of :func:`ssm_step` (a sublane tile)
+_SEQUENCES = 8
+#: the most positions of one grid step of :func:`ssm_scan`
+_POSITIONS = 256
+#: positions the scan unrolls: one sublane tile of ``dt`` and ``y``
+_TILE = 8
+
+
+def _channel_block(e: int) -> int:
+    """Channels of a kernel's inner block: the largest of 512, 256, 128
+    that divides ``e``, else all of them."""
+    return next((c for c in (_CHANNELS, 256, 128) if e % c == 0), e)
+
+
+# -- the step kernel ----------------------------------------------------------
+
+def _step_kernel(group_ref, dt_ref, dx_ref, b_ref, c_ref, a_ref, h_ref,
+                 y_ref, out_ref, *, block: int):
+    """A block of sequences: ``h_ref`` / ``out_ref`` ``[1, bs, N, E]``,
+    ``dt_ref`` / ``dx_ref`` / ``y_ref`` ``[bs, E]``, ``b_ref`` /
+    ``c_ref`` ``[bs, N, 1]`` (a column a sequence, spread over the lanes
+    here), ``a_ref`` ``[N, E]``.  A block of channels at a time, each
+    sequence's ``[N, block]`` tile once through the registers."""
+    del group_ref                       # the index map reads it
+    bs, e = dt_ref.shape
+    for lo in range(0, e, block):
+        cols = slice(lo, lo + block)
+        a = a_ref[:, cols]
+        ys = []
+        for i in range(bs):
+            h = jnp.exp(dt_ref[i:i + 1, cols] * a) * h_ref[0, i, :, cols] \
+                + dx_ref[i:i + 1, cols] * b_ref[i]
+            out_ref[0, i, :, cols] = h
+            ys.append(jnp.sum(h * c_ref[i], axis=0, keepdims=True))
+        y_ref[:, cols] = jnp.concatenate(ys, axis=0)
+
+
+@jax.jit
+def ssm_step(dt, dx, b, c, a, state, group):
+    """``H <- exp(dt (x) A) * H + dx (x) B`` in place and ``y = H C`` of
+    the new state.  ``state`` [groups, batch, N, E] f32, of which group
+    ``group`` [1] int32; ``dt`` / ``dx`` [batch, E] f32 (the step and
+    the step times the input); ``b`` / ``c`` [batch, N] f32; ``a`` [N,
+    E] f32.  Returns ``(y [batch, E] f32, state)``; the state aliases
+    its argument: donate it.
+
+    One grid step a block of sequences, whose states cross VMEM once
+    (327 KB a sequence in and as much out at 16 x 5120).  Every
+    operation is on the vector unit in float32: there is no matrix in a
+    diagonal recurrence.  Off-TPU the identical kernel runs in
+    interpreter mode, as the package's others do.  Jitted so that a
+    step program that calls it once a layer traces and lowers it
+    once."""
+    groups, batch, n, e = state.shape
+    bs = _SEQUENCES if batch % _SEQUENCES == 0 else batch
+    group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
+    rows = pl.BlockSpec((bs, e), lambda i, group_ref: (i, 0))
+    cols = pl.BlockSpec((bs, n, 1), lambda i, group_ref: (i, 0, 0))
+    big = pl.BlockSpec((1, bs, n, e),
+                       lambda i, group_ref: (group_ref[0], i, 0, 0))
+    y, out = pl.pallas_call(
+        functools.partial(_step_kernel, block=_channel_block(e)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(batch // bs,),
+            in_specs=[rows, rows, cols, cols,
+                      pl.BlockSpec((n, e), lambda i, group_ref: (0, 0)),
+                      big],
+            out_specs=[rows, big]),
+        out_shape=[jax.ShapeDtypeStruct((batch, e), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the states' block in and out, each double-buffered, the
+            # columns (a lane row a value) and the rows
+            vmem_limit_bytes=4 * bs * n * e * 4 + 4 * bs * n * 512
+            + 8 * bs * e * 4 + (8 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="ssm_step",
+    )(group, dt, dx, b[..., None], c[..., None], a, state)
+    return y, out
+
+
+# -- the scan kernel ----------------------------------------------------------
+
+def _scan_kernel(dt_ref, dx_ref, b_ref, c_ref, a_ref, y_ref, last_ref,
+                 h_ref):
+    """One sequence, one block of channels, one block of positions:
+    ``dt_ref`` / ``dx_ref`` / ``y_ref`` ``[1, tb, block]``, ``b_ref`` /
+    ``c_ref`` ``[1, tb, N, 1]``, ``a_ref`` ``[N, block]``; ``h_ref``
+    ``[N, block]`` carries the state from one block of positions to the
+    next, ``last_ref`` ``[1, N, block]`` takes it after the last."""
+    at_t = pl.program_id(2)
+    tb = dt_ref.shape[1]
+
+    @pl.when(at_t == 0)
+    def _empty():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+    a = a_ref[...]
+
+    def tile(k, h):
+        lo = pl.multiple_of(k * _TILE, _TILE)
+        dts = dt_ref[0, pl.ds(lo, _TILE), :]
+        dxs = dx_ref[0, pl.ds(lo, _TILE), :]
+        ys = []
+        for j in range(_TILE):
+            h = jnp.exp(dts[j:j + 1] * a) * h + dxs[j:j + 1] * b_ref[0, lo + j]
+            ys.append(jnp.sum(h * c_ref[0, lo + j], axis=0, keepdims=True))
+        y_ref[0, pl.ds(lo, _TILE), :] = jnp.concatenate(ys, axis=0)
+        return h
+
+    h = lax.fori_loop(0, tb // _TILE, tile, h_ref[...])
+    h_ref[...] = h
+
+    @pl.when(at_t == pl.num_programs(2) - 1)
+    def _last():
+        last_ref[0] = h
+
+
+@jax.jit
+def ssm_scan(dt, dx, b, c, a):
+    """The recurrence of :func:`ssm_step` over whole prompts from an
+    empty memory: ``dt`` / ``dx`` [batch, t, E] f32, ``b`` / ``c``
+    [batch, t, N] f32, ``a`` [N, E] f32 -> ``(y [batch, t, E] f32, H
+    [batch, N, E] f32 after the last position)``.
+
+    The grid is (sequence, block of channels, block of positions), the
+    positions innermost and in order: a block of channels' ``[N, 512]``
+    state is 8 vector registers, updated position by position and kept
+    in VMEM between two blocks of positions; a position's ``[E, N]``
+    outer products exist a block of channels at a time, in registers.
+    A prompt whose length is no multiple of 8 is padded at its end with
+    identity steps (``dt = 0``)."""
+    batch, t, e = dt.shape
+    n = a.shape[0]
+    pad = -t % _TILE
+    if pad:
+        dt, dx, b, c = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (dt, dx, b, c))
+    tp = t + pad
+    tb = next(k for k in range(min(tp, _POSITIONS), 0, -_TILE)
+              if tp % k == 0)
+    block = _channel_block(e)
+    rows = pl.BlockSpec((1, tb, block), lambda i, j, k: (i, k, j))
+    cols = pl.BlockSpec((1, tb, n, 1), lambda i, j, k: (i, k, 0, 0))
+    y, last = pl.pallas_call(
+        _scan_kernel,
+        grid=(batch, e // block, tp // tb),
+        in_specs=[rows, rows, cols, cols,
+                  pl.BlockSpec((n, block), lambda i, j, k: (0, j))],
+        out_specs=[rows, pl.BlockSpec((1, n, block),
+                                      lambda i, j, k: (i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((batch, tp, e), jnp.float32),
+                   jax.ShapeDtypeStruct((batch, n, e), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, block), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            # the columns (a lane row a value), double-buffered, are most
+            # of it: 2 x 2 x tb x N x 512 B
+            vmem_limit_bytes=4 * tb * n * 512 + 6 * tb * block * 4
+            + (8 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="ssm_scan",
+    )(dt, dx, b[..., None], c[..., None], a)
+    return (y[:, :t] if pad else y), last
+
+
+# -- the convolution ----------------------------------------------------------
+
+def causal_conv(taps, w, bias):
+    """``silu(bias + sum_j w[j] * taps[j])`` in float32, rounded to the
+    taps' type: ``taps`` a sequence of ``d_conv`` arrays [..., E], oldest
+    first, the newest the position's own input; ``w`` [d_conv, E],
+    ``bias`` [E]."""
+    acc = bias.astype(jnp.float32)
+    for j, tap in enumerate(taps):
+        acc = acc + w[j].astype(jnp.float32) * tap.astype(jnp.float32)
+    return jax.nn.silu(acc).astype(taps[-1].dtype)
+
+
+# -- the format --------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class SsmFormat(LayeredState):
+    """One layer's state-space memory, described: what the ring builds
+    its buffers from and updates and reads them through (``zeros``,
+    ``layer`` and ``with_layer`` are ``ops/layered.py``'s)."""
+
+    keys = ("conv", "h")
+
+    channels: int           #: ``E``
+    states: int             #: ``N``
+    d_conv: int
+    #: the window's type, the block's compute type (``h`` is float32)
+    dtype: Any
+    #: the ring's round-robin groups (a leading axis); None for one batch
+    groups: int | None = None
+
+    def buffers(self, batch: int) -> dict[str, jax.ShapeDtypeStruct]:
+        """One layer's buffers for ``batch`` sequences (a group), by key."""
+        lead = () if self.groups is None else (self.groups,)
+        return {
+            "conv": jax.ShapeDtypeStruct(
+                lead + (self.d_conv - 1, batch, self.channels), self.dtype),
+            "h": jax.ShapeDtypeStruct(
+                lead + (batch, self.states, self.channels), jnp.float32)}
+
+    # -- where a ring step's memory goes: a bubble is an identity update
+
+    @staticmethod
+    def decode_slot(valid, pos):
+        """What :meth:`shift` and :meth:`step` take as ``valid``; the
+        position is not part of a state's address."""
+        del pos
+        return valid
+
+    @staticmethod
+    def prefill_slot(valid, group, row=None):
+        """What the prefill calls take as ``slot``: the group and
+        whether the call is real; with ``row``, also the sequence of
+        the group from which a piece's prompts lie."""
+        return (group, valid) if row is None else (group, valid, row)
+
+    # -- one token a sequence ------------------------------------------------
+
+    def shift(self, u, layer: dict, group=None, valid=True):
+        """The convolution's taps for one token of every sequence (of
+        group ``group``), and the window moved on by it: ``u`` [b, E]
+        the position's input -> ``(taps, layer)``, ``taps`` the
+        ``d_conv`` inputs ``[b, E]`` the convolution reads, oldest
+        first, ``u`` itself the last.  With ``valid`` false the window
+        is kept as it is."""
+        bufs, group = self._group(layer, group)
+        at = (group[0], 0, 0, 0)
+        win = lax.dynamic_slice(bufs["conv"], at,
+                                (1,) + bufs["conv"].shape[1:])[0]
+        u = u.astype(win.dtype)
+        new = jnp.concatenate([win[1:], u[None]], axis=0)
+        new = jnp.where(valid, new, win)
+        conv = lax.dynamic_update_slice(bufs["conv"], new[None], at)
+        return [win[j] for j in range(self.d_conv - 1)] + [u], \
+            self._ungroup(dict(bufs, conv=conv))
+
+    def step(self, dt, x, b, c, a, layer: dict, group=None, valid=True):
+        """One token of every sequence (of group ``group``): ``dt`` [b,
+        E] the step (float32), ``x`` [b, E] the convolution's output,
+        ``b`` / ``c`` [b, N], ``a`` [N, E].  The state is decayed, ``(dt
+        x) (x) b`` is added and ``c`` reads the *new* state: returns
+        ``(y [b, E] float32, the layer)``.  With ``valid`` false (a
+        pipeline's bubble) the update is the identity (``dt = 0``) and
+        ``y`` means nothing."""
+        f32 = jnp.float32
+        dt = jnp.where(valid, dt.astype(f32), 0.0)
+        bufs, group = self._group(layer, group)
+        y, h = ssm_step(dt, dt * x.astype(f32), b.astype(f32),
+                        c.astype(f32), a.astype(f32), bufs["h"], group)
+        return y, self._ungroup(dict(bufs, h=h))
+
+    # -- a whole prompt ---------------------------------------------------------
+
+    def prefill_shift(self, u, layer: dict, slot=(None, True)):
+        """The taps of a whole prompt ``u`` [b, t, E] from an empty
+        window, and the window after its last position left where
+        ``slot`` says: ``(taps, layer)``, ``taps`` the ``d_conv`` arrays
+        ``[b, t, E]``, the input ``d_conv - 1 - j`` positions back under
+        ``j`` (zero before the prompt's start)."""
+        group, valid, row = slot if len(slot) == 3 else (*slot, 0)
+        k = self.d_conv - 1
+        b, t, e = u.shape
+        bufs, group = self._group(layer, group)
+        u = u.astype(bufs["conv"].dtype)
+        padded = jnp.pad(u, ((0, 0), (k, 0), (0, 0)))
+        taps = [lax.slice_in_dim(padded, j, j + t, axis=1)
+                for j in range(k + 1)]
+        at = (group[0], 0, row, 0)
+        old = lax.dynamic_slice(bufs["conv"], at, (1, k, b, e))
+        # taps-major as the buffer holds them: left to itself the
+        # compiler keeps the prompt's last inputs sequence-major (what
+        # the slice before them liked) and, the write needing one layout
+        # on both sides, converts the *buffer* there and back
+        last = with_layout_constraint(padded[:, t:].swapaxes(0, 1),
+                                      Layout(major_to_minor=(0, 1, 2)))
+        new = jnp.where(valid, last[None], old)
+        conv = lax.dynamic_update_slice(bufs["conv"], new, at)
+        return taps, self._ungroup(dict(bufs, conv=conv))
+
+    def prefill(self, dt, x, b, c, a, layer: dict, slot=(None, True)):
+        """A whole prompt of every sequence (of the group ``slot``
+        names) into an *empty* memory: ``dt`` / ``x`` [b, t, E], ``b`` /
+        ``c`` [b, t, N], ``a`` [N, E] -> ``(y [b, t, E] float32, the
+        layer)``, the layer holding the state after the last position.
+        Where ``slot`` says the call is a bubble, the state is kept."""
+        group, valid, row = slot if len(slot) == 3 else (*slot, 0)
+        f32 = jnp.float32
+        dt = dt.astype(f32)
+        y, last = ssm_scan(dt, dt * x.astype(f32), b.astype(f32),
+                           c.astype(f32), a.astype(f32))
+        bufs, group = self._group(layer, group)
+        at = (group[0], row, 0, 0)
+        old = lax.dynamic_slice(bufs["h"], at, (1,) + last.shape)
+        h = lax.dynamic_update_slice(
+            bufs["h"], jnp.where(valid, last[None], old), at)
+        return y, self._ungroup(dict(bufs, h=h))
+
+
+def dense(h, conv) -> tuple[np.ndarray, np.ndarray]:
+    """A layer's buffers of one group on the host in the form that
+    knows no layout: ``h`` [b, N, E] -> ``H [b, E, N]``, ``conv``
+    [d_conv - 1, b, E] -> the window ``[b, d_conv - 1, E]``, oldest
+    input first."""
+    return (np.swapaxes(np.asarray(h), -1, -2),
+            np.swapaxes(np.asarray(conv), 0, 1))
+
+
+# -- the oracle -----------------------------------------------------------------
+
+def step_reference(dt, x, b, c, a, h):
+    """:func:`ssm_step` in plain ``jnp`` over one item (``h`` [batch,
+    N, E], no group axis), all float32: ``(y [batch, E], h)``."""
+    h = jnp.exp(dt[:, None, :] * a[None]) * h \
+        + (dt * x)[:, None, :] * b[:, :, None]
+    return jnp.sum(h * c[:, :, None], axis=1), h
+
+
+def prefill_reference(dt, x, b, c, a):
+    """The recurrence position by position from an empty memory: ``dt``
+    / ``x`` [batch, t, E], ``b`` / ``c`` [batch, t, N], ``a`` [N, E],
+    all float32 -> ``(y [batch, t, E], h [batch, N, E])``."""
+    def step(h, xs):
+        y, h = step_reference(*xs, a, h)
+        return h, y
+
+    start = jnp.zeros((dt.shape[0], a.shape[0], a.shape[1]), jnp.float32)
+    h, ys = lax.scan(step, start, tuple(
+        jnp.swapaxes(v, 0, 1) for v in (dt, x, b, c)))
+    return jnp.swapaxes(ys, 0, 1), h

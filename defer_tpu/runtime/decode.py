@@ -19,19 +19,23 @@ TPU-native design, one SPMD program:
     ``SpmdPipeline``).
   * Sequence memory: per device, one resident buffer a local block and
     key, held and touched only through the format *that block* names
-    (``DecoderBlock.memory_format``: a layer whose attention has a
-    window keeps a ring buffer of the window's rows beside a layer that
-    keeps every position; a stage's layers must name the same formats
-    in the same order on every stage, since a stage-sharded buffer has
-    one shape).  A KV cache (``ops/kv_cache.py``,
+    (``DecoderBlock.memory_format``: kind, geometry and length are the
+    layer's — a layer whose attention has a window keeps a ring buffer
+    of the window's rows beside a layer that keeps every position, a
+    state-space layer its window and state beside a layer that keeps a
+    KV cache; a stage's layers must name the same formats in the same
+    order on every stage, since a stage-sharded buffer has one shape).
+    The state is a dict of tuples, an entry a local layer under each
+    key, None where the layer's format has no such key
+    (``ops/layered.py``).  A KV cache (``ops/kv_cache.py``,
     which describes the layout): a step writes one row a block in
     place, every sequence of the group at one position, and attends
     over the group's live rows where they lie; warmup bubbles write the
     format's scratch row and prefill bubbles its scratch group, so no
     masked read-modify-write of the cache is ever needed.  A retention
-    state (``ops/retention.py``): of fixed size, read and rewritten
-    whole each step; a bubble is its identity update, so it has neither
-    scratch.
+    state (``ops/retention.py``) or a state-space state
+    (``ops/ssm.py``): of fixed size, read and rewritten whole each
+    step; a bubble is its identity update, so it has neither scratch.
   * The ring carry is one ``[mb, d]`` float32 buffer per device: stage
     activations in flight, and — on the wrap link from the last stage back
     to stage 0 (the reference's node->dispatcher link,
@@ -65,6 +69,7 @@ caches, dropping prompt cost from ``plen * N`` ring steps to ``2N - 1``.
 from __future__ import annotations
 
 import collections
+import math
 from typing import Any
 
 import numpy as np
@@ -77,15 +82,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
 from ..obs import REGISTRY, span
-from ..ops.layered import zeros_by_layer
+from ..ops.layered import shapes_by_layer, zeros_by_layer
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
 from . import flatbuf
 
 
 #: the most a piece of a group's prefill may hold in its widest
-#: activation (a piece's rows x prompt x the wider of the stream and the
-#: merged heads, in the compute type): a group whose whole prompt
+#: activation (a piece's rows x prompt x the widest any block names,
+#: ``DecoderBlock.widest``, in the compute type): a group whose whole prompt
 #: passes it crosses a stage a few sequences at a time
 _PREFILL_PIECE_BYTES = 1 << 28
 
@@ -164,9 +169,6 @@ class PipelinedDecoder:
         self.max_len = max_len = parts.max_len
         self.block_names = block_names = list(parts.block_names)
         self.d_model = parts.d_model
-        self.num_heads = parts.num_heads
-        self.num_kv_heads = parts.kv_heads
-        self.head_dim = parts.head_dim
         self.vocab = parts.vocab
         #: per-step scalars the blocks sow (``DecoderBlock.decode_stats``);
         #: summed on the device over a chunk, fetched with the chunk's ids
@@ -174,6 +176,12 @@ class PipelinedDecoder:
         self.stage_blocks = parts.stage_blocks
         self.l_max = max(len(b) for b in self.stage_blocks)
         nodes = graph.nodes
+        #: each local block's memory (n groups of mb sequences), in the
+        #: format that block names: one a local layer, the same on
+        #: every stage (asked first: a cut the formats refuse would
+        #: fail later, less clearly, where the stages' leaves are laid
+        #: side by side)
+        self.state_formats = self._layer_formats()
 
         # --- stage-sharded flat weight buffer (scheme of runtime/spmd.py)
         stage_param_names: list[list[str]] = []
@@ -226,35 +234,53 @@ class PipelinedDecoder:
         self._wspec_tree = jax.tree.map(
             lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
 
-        #: each local block's memory (n groups of mb sequences), in the
-        #: format that block names: one a local layer, the same on
-        #: every stage
-        self.state_formats = self._layer_formats()
         #: the first local layer's (every layer's, where they are alike)
         self.state_format = self.state_formats[0]
-        #: the kind of memory that is (``DecoderBlock.memory``)
-        self.memory = parts.memory
-        if beam_width > 1 and self.memory != "kv_cache":
+        #: the kind of memory each local layer keeps
+        #: (``DecoderBlock.memory``)
+        self.memory = tuple(nodes[nm].op.memory
+                            for nm in max(self.stage_blocks, key=len))
+        kinds = dict.fromkeys(self.memory)
+        states = [(kind, fmt) for kind, fmt in zip(self.memory,
+                                                    self.state_formats)
+                  if kind != "kv_cache"]
+        if beam_width > 1 and states:
+            kind, fmt = states[0]
             raise ValueError(
                 f"beam_width={beam_width}: beam search re-parents a "
                 f"sequence's memory at every expansion, and these blocks "
-                f"keep a {self.memory} "
-                f"({type(self.state_format).__name__}), which cannot "
+                f"keep a {kind} ({type(fmt).__name__}), which cannot "
                 "hand one sequence's memory to another")
-        by_layer = [n * fmt.state_bytes(mb, 1) for fmt in self.state_formats]
-        REGISTRY.gauge(f"decode.{self.memory}.state_bytes").set(
-            sum(by_layer))
-        if self.memory == "kv_cache":
-            # the buffers by kind, scratch row and group included: ring
-            # buffers of a window's rows, and a row a position
-            ring = [fmt.window is not None for fmt in self.state_formats]
-            REGISTRY.gauge("decode.cache.window_bytes").set(
-                sum(b for b, w in zip(by_layer, ring) if w))
-            REGISTRY.gauge("decode.cache.full_bytes").set(
-                sum(b for b, w in zip(by_layer, ring) if not w))
+
+        # what the ring holds over its n stages (scratch row and group
+        # included): (kind, format, key, bytes) a buffer a local layer
+        sizes = [(kind, fmt, key, n * math.prod(buf.shape)
+                  * jnp.dtype(buf.dtype).itemsize)
+                 for kind, fmt in zip(self.memory, self.state_formats)
+                 for key, buf in fmt.buffers(mb).items()]
+
+        def held(pick) -> int:
+            return sum(size for kind, fmt, key, size in sizes
+                       if pick(kind, fmt, key))
+
+        # a gauge a kind of memory, and each kind's own parts
+        for kind in kinds:
+            REGISTRY.gauge(f"decode.{kind}.state_bytes").set(
+                held(lambda k, fmt, key: k == kind))
+        if "kv_cache" in kinds:
+            # ring buffers of a window's rows, and a row a position
+            REGISTRY.gauge("decode.cache.window_bytes").set(held(
+                lambda k, fmt, key: k == "kv_cache"
+                and fmt.window is not None))
+            REGISTRY.gauge("decode.cache.full_bytes").set(held(
+                lambda k, fmt, key: k == "kv_cache" and fmt.window is None))
             REGISTRY.gauge("decode.cache.window_positions").set(max(
-                (fmt.window for fmt in self.state_formats if fmt.window),
-                default=0))
+                (fmt.window or 0 for k, fmt, _key, _size in sizes
+                 if k == "kv_cache"), default=0))
+        if "ssm" in kinds:
+            # the convolutions' windows, of the state-space layers' all
+            REGISTRY.gauge("decode.ssm.conv_bytes").set(held(
+                lambda k, fmt, key: k == "ssm" and key == "conv"))
         #: the newest generation's state as it left it (device buffers;
         #: dropped when the next generation begins)
         self.state = None
@@ -288,7 +314,7 @@ class PipelinedDecoder:
 
         def fmt(nm):
             return nodes[nm].op.memory_format(
-                self.head_dim, self.max_len, self.compute_dtype,
+                self.d_model, self.max_len, self.compute_dtype,
                 quantized=self.kv_cache == "int8", groups=self.num_stages)
 
         longest = max(self.stage_blocks, key=len)
@@ -312,9 +338,8 @@ class PipelinedDecoder:
         :data:`_PREFILL_PIECE_BYTES`, else the largest divisor of the
         group that does (at least one).  From shapes alone."""
         nodes = self.graph.nodes
-        widest = max([self.d_model] + [
-            nodes[nm].op.num_heads * self.head_dim
-            for nm in self.block_names])
+        widest = max(nodes[nm].op.widest(self.d_model)
+                     for nm in self.block_names)
         row = plen * widest * self.compute_dtype.itemsize
         mb = self.microbatch
         return max(r for r in range(1, mb + 1)
@@ -713,12 +738,12 @@ class PipelinedDecoder:
 
     def _state_specs(self):
         """shard_map spec pytree for the cache-state dict."""
-        # one buffer a local block, never one array of the whole stack
-        # (layers' buffers may differ in length, not in rank)
-        specs = {key: (P(STAGE_AXIS, *(None,) * len(buf.shape)),)
-                 * self.l_max
-                 for key, buf in self.state_format.buffers(
-                     self.microbatch).items()}
+        # one buffer a local block and key, never one array of the whole
+        # stack: each of its own layer's shape, and None where the
+        # layer's format has no such key
+        specs = jax.tree.map(
+            lambda buf: P(STAGE_AXIS, *(None,) * len(buf.shape)),
+            shapes_by_layer(self.state_formats, self.microbatch))
         if self.beam_width > 1:
             # per-group cumulative beam scores; only the LAST stage's
             # device shard is meaningful (it runs the expansion)

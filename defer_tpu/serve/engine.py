@@ -143,8 +143,14 @@ class ContinuousBatchEngine:
         #: one layer's cache: ``width`` slots, f32 (the step computes in
         #: it); looked up on the module when the engine is built, so a
         #: test can put another format in its place
+        if len(set(parts.geometry)) != 1:
+            raise ValueError(
+                f"the blocks' heads are {sorted(set(parts.geometry))} "
+                "(query heads, KV heads, width): the engine's homogeneous "
+                "cache needs one head geometry")
+        _, kv_heads, head_dim = parts.geometry[0]
         self.kv_format = kv_cache.KVCacheFormat(
-            parts.kv_heads, parts.head_dim, self.max_len, jnp.float32)
+            kv_heads, head_dim, self.max_len, jnp.float32)
 
         self._slots: list[_Slot | None] = [None] * width
         #: every layer's buffers, each a donated argument of the step and

@@ -6,7 +6,8 @@ The ring's decode and prefill programs (``gpt_tiny`` and ``olmoe_tiny``;
 ``kv_cache`` buffer and int8; ``beam_width`` 1 and 2; 1 stage and
 several; ``olmoe_tiny``'s widths at four layers for four stages;
 ``brumby_tiny``, whose state has neither int8 rows nor beams;
-``cohere_moe_tiny``, a format a layer) and
+``cohere_moe_tiny``, a format a layer; ``jamba_tiny``, two kinds of
+memory in one graph, a period a stage) and
 the engine's step (greedy and sampling), lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -28,8 +29,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from defer_tpu.models import (brumby_tiny, cohere_moe_tiny, gpt_tiny, olmoe,
-                              olmoe_tiny)
+from defer_tpu.models import (brumby_tiny, cohere_moe_tiny, gpt_tiny,
+                              jamba_tiny, olmoe, olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -93,6 +94,10 @@ def main() -> int:
                 # window layers' ring buffers beside full layers' caches:
                 # one period a stage
                 *ring_programs("cohere_moe_tiny", cohere_moe_tiny(), (1, 2)),
+                # state-space layers' windows and states beside attention
+                # layers' caches: a state has neither int8 rows nor beams
+                *ring_programs("jamba_tiny", jamba_tiny(), (1, 2),
+                               kv_caches=("buffer",), beams=(1,)),
                 *engine_programs()]
     for name, lowered in programs:
         text = lowered.as_text()

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from defer_tpu.models import gpt_stage_cuts, gpt_tiny
-from defer_tpu.models.decoder import split_blocks
+from defer_tpu.models.decoder import decoder_parts, split_blocks
 from defer_tpu.models.gpt import CausalTransformerBlock
 from defer_tpu.ops.kv_cache import KVCacheFormat
 from defer_tpu.runtime.decode import PipelinedDecoder
@@ -224,7 +224,7 @@ def test_gqa_decode_matches_references(prompt):
     params = graph.init(jax.random.key(9))
     dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                            max_len=MAX_LEN)
-    assert dec.num_kv_heads == 1 and dec.num_heads == 2
+    assert decoder_parts(graph, 4).geometry == ((2, 1, 16),) * 4
     assert dec.state_format.kv_heads == 1       # cache halved vs MHA
     got = dec.generate(prompt, max_new_tokens=8)
     want = incremental_greedy(graph, params, prompt, 5 + 8, MAX_LEN)
@@ -1140,11 +1140,14 @@ def test_piece_rows_come_from_shapes_alone(model):
     from defer_tpu.runtime.decode import PipelinedDecoder as PD
 
     def rows(mb, plen, d, heads, hd, itemsize=2):
-        op = types.SimpleNamespace(num_heads=heads)
+        # a block names its own widest activation (the merged heads
+        # here; a state-space block its input projection's 2 E)
+        op = types.SimpleNamespace(widest=lambda d_model: max(d_model,
+                                                              heads * hd))
         me = types.SimpleNamespace(
             graph=types.SimpleNamespace(
                 nodes={"block_0": types.SimpleNamespace(op=op)}),
-            block_names=["block_0"], d_model=d, head_dim=hd, microbatch=mb,
+            block_names=["block_0"], d_model=d, microbatch=mb,
             compute_dtype=np.dtype(np.float16 if itemsize == 2
                                    else np.float32))
         return PD._prefill_rows(me, plen)
@@ -1155,6 +1158,10 @@ def test_piece_rows_come_from_shapes_alone(model):
     assert rows(16, 8192, 4096, 128, 128) == 1
     assert rows(16, 2048, 4096, 128, 128) == 4
     assert rows(6, 8192, 4096, 128, 128, 4) == 1
+    # 256 prompts of 256 tokens on a stream of 2560 whose widest
+    # activation is 10240 columns: 32 sequences a piece, not all 256
+    assert rows(256, 256, 2560, 80, 128) == 32
+    assert rows(256, 256, 2560, 20, 128) == 128
 
 
 @pytest.mark.parametrize("stages", [1, 2])

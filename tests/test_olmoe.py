@@ -530,9 +530,12 @@ def _broken_graphs():
         "foreign": (_graph_with(graph,
                                 block_1=bert_tiny().nodes["block_0"].op),
                     TypeError, "block_1.*not a DecoderBlock"),
-        "mixed": (_graph_with(graph, block_2=dataclasses.replace(
-            op, num_kv_heads=1)), ValueError,
-            "block_2 has heads.*one head geometry"),
+        # head geometry is a layer's own; the sown statistics are not
+        "mixed": (_graph_with(graph, block_2=type(
+            "Sowing", (type(op),), {"decode_stats": ("odd",)})(**{
+                f.name: getattr(op, f.name)
+                for f in dataclasses.fields(op)})), ValueError,
+            "block_2 sows.*one ledger"),
     }
 
 
@@ -544,7 +547,7 @@ def _broken_graphs():
 ], ids=["ring", "engine", "contract"])
 def test_both_engines_refuse_a_graph_outside_the_contract(build, fault):
     """One function checks a graph for both constructors: a missing
-    node, a foreign block and mixed head geometry are refused by each
+    node, a foreign block and mixed sown statistics are refused by each
     in the same words, before anything is placed on a device."""
     broken, error, words = _broken_graphs()[fault]
     params = gpt_tiny().init(jax.random.key(0))
@@ -557,8 +560,9 @@ def test_the_contract_hands_back_a_graphs_parts(model):
     parts = decoder_parts(graph, 2, max_len=16)
     assert parts.block_names == ("block_0", "block_1")
     assert parts.stage_blocks == [["block_0"], ["block_1"]]
-    assert (parts.d_model, parts.num_heads, parts.kv_heads, parts.head_dim,
-            parts.vocab, parts.max_len) == (64, 4, 4, 16, VOCAB, 16)
+    assert (parts.d_model, parts.vocab, parts.max_len) == (64, VOCAB, 16)
+    assert parts.geometry == ((4, 4, 16),) * 2
+    assert parts.memory == ("kv_cache",) * 2
     assert parts.decode_stats == OlmoeBlock.decode_stats
     assert parts.embed_op is graph.nodes["embeddings"].op
     assert decoder_parts(graph, 1).max_len == SEQ

@@ -141,21 +141,23 @@ def test_admission_shed_then_retry_lifecycle():
     """The satellite lifecycle: admit while the prediction fits, shed
     with a retry hint when the backlog blows the deadline, admit again
     once completions drain the backlog."""
+    # (a tenant of this test's own: the per-tenant counters live in the
+    # process's registry, and another file's front door serves "t")
     ctl = AdmissionController(service_s=lambda: 0.1)
-    ctl.configure(TenantConfig("t", deadline_ms=250.0))
-    d1 = ctl.admit("t", "u1")
-    d2 = ctl.admit("t", "u2")
+    ctl.configure(TenantConfig("lifecycle_t", deadline_ms=250.0))
+    d1 = ctl.admit("lifecycle_t", "u1")
+    d2 = ctl.admit("lifecycle_t", "u2")
     assert d1.admitted and d2.admitted
-    d3 = ctl.admit("t", "u3")  # predicted (2+1)*0.1 = 0.3 > 0.25
+    d3 = ctl.admit("lifecycle_t", "u3")  # predicted (2+1)*0.1 = 0.3 > 0.25
     assert not d3.admitted and d3.reason == "deadline"
     assert d3.retry_after_s > 0 and d3.predicted_s > 0.25
-    ctl.complete("t", queued_at=time.monotonic())
-    d4 = ctl.admit("t", "u3-retry")  # backlog drained below the SLO
+    ctl.complete("lifecycle_t", queued_at=time.monotonic())
+    d4 = ctl.admit("lifecycle_t", "u3-retry")  # backlog drained below the SLO
     assert d4.admitted
     stats = ctl.stats()
-    assert stats["tenants"]["t"]["admitted"] == 3
-    assert stats["tenants"]["t"]["shed"] == 1
-    assert stats["tenants"]["t"]["completed"] == 1
+    assert stats["tenants"]["lifecycle_t"]["admitted"] == 3
+    assert stats["tenants"]["lifecycle_t"]["shed"] == 1
+    assert stats["tenants"]["lifecycle_t"]["completed"] == 1
 
 
 def test_admission_backlog_cap_sheds_without_deadline():
