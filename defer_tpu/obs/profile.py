@@ -78,8 +78,11 @@ ENGINE_DISPATCH_PHASES = ("upload", "launch")
 #: (serve/engine.py ``EngineLoop.run``): ``join`` is the sweep that
 #: applies cancellations and moves queued requests into free slots —
 #: host work the device waits for; ``park`` is the one blocking pop
-#: taken with no active slot — the engine has nothing to do
-ENGINE_LOOP_PHASES = ("join", "park")
+#: taken with no active slot — the engine has nothing to do; ``prefill``
+#: is one joined slot's prompt through the prefill programs, waited for
+#: (``ContinuousBatchEngine.step`` runs it in front of the step that
+#: follows a join: a sibling of ``join`` and outside ``step``)
+ENGINE_LOOP_PHASES = ("join", "park", "prefill")
 
 #: ``PipelinedDecoder.generate`` (runtime/decode.py), in wall order:
 #: ``init`` is what a generation sets up on the host before its first

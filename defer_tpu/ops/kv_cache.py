@@ -707,11 +707,15 @@ class KVCacheFormat(LayeredState):
         """A whole prompt's key and value columns [b, t, kv_heads *
         head_dim] written at positions ``0..t-1`` where ``slot``
         (:meth:`prefill_slot`'s) says — a group, or a group and the
-        sequence of it the ``b`` prompts start at: one head-major
-        relayout a prompt (amortized), one bulk write a buffer.  A ring
-        buffer shorter than the prompt keeps the prompt's newest rows,
-        each where a decode step will look for it (``p % window``)."""
-        group, row = slot if isinstance(slot, tuple) else (slot, 0)
+        sequence of it the ``b`` prompts start at; in a format without
+        groups, the sequence alone: one head-major relayout a prompt
+        (amortized), one bulk write a buffer.  A ring buffer shorter
+        than the prompt keeps the prompt's newest rows, each where a
+        decode step will look for it (``p % window``)."""
+        if self.groups is None:
+            at = (slot,)
+        else:
+            at = slot if isinstance(slot, tuple) else (slot, 0)
         b, t = k.shape[:2]
         w, shift = self.window, 0
         if w is not None and t > w:
@@ -738,9 +742,10 @@ class KVCacheFormat(LayeredState):
         out = dict(layer)
         for key, rows in new.items():
             buf = layer[key]
+            if self.groups is not None:
+                rows = rows[None]
             out[key] = lax.dynamic_update_slice(
-                buf, rows[None].astype(buf.dtype),
-                (group, row) + (0,) * (buf.ndim - 2))
+                buf, rows.astype(buf.dtype), at + (0,) * (buf.ndim - len(at)))
         return out
 
     def head_major(self, item: dict) -> dict:

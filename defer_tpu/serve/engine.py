@@ -15,12 +15,25 @@ engine is continuous batching proper:
   ring's buffers without its groups), every one a donated argument of
   the step and its aliased output.
 * Between any two decode steps, finished requests leave (slot freed,
-  tokens delivered) and waiting requests join (slot claimed, position
-  0); the step program itself never changes — one compiled program per
-  width serves every batch composition.
+  tokens delivered) and waiting requests join (slot claimed); the step
+  program itself never changes — one compiled program per width serves
+  every batch composition.
+* A joining request's prompt is prefilled in ONE pass, between two
+  steps: positions ``0 .. plen - 2`` (the first
+  :data:`PREFILL_POSITIONS` of them; one compiled length, the prompt
+  padded to it) through every block's ``prefill`` — one program a few
+  blocks long, called once a group of them — their key and value rows
+  written into the slot's own rows of every layer's buffer.  The slot
+  enters the step loop at the position behind them, so its first step
+  feeds the last prompt token and produces the first generated one.
+  Fed a token a step instead, a prompt costs a step a token that nobody
+  is paid for: half of a short chat's steps.  Causal attention hides
+  the padding from the prompt's own rows, and the rows the padding
+  writes are each rewritten by a decode step before any query can see
+  them.
 * A step is one token per active slot: teacher-forced from the prompt
-  while ``pos < prompt_len`` (prefill at decode rate — a joining
-  request needs no separate prefill program), sampled past it.  A
+  while ``pos < prompt_len`` (the last prompt token, and the tail of a
+  prompt longer than one prefill takes), sampled past it.  A
   layer is the block's own halves, as the ring calls them:
   ``decode_qkv`` on the whole ``[width, d]`` batch, each slot's new row
   written in place at that slot's OWN position (the format's
@@ -67,6 +80,18 @@ from ..ops import kv_cache
 from ..runtime.decode import sample_ids
 from .batcher import _stamp_popped
 
+#: the positions one prefill takes: the one length its programs are
+#: compiled for (an engine's is ``min(this, max_len - 1)``).  A shorter
+#: prompt is padded to it, a longer one has its tail teacher-forced by
+#: the step.  One length and no buckets: every length is more programs
+#: in the door's set-up, and at 128 positions a prefill is still bound
+#: by the weights it reads, as a step is.
+PREFILL_POSITIONS = 128
+#: blocks one prefill program walks.  The engine calls it once a group
+#: of as many: long enough that a prompt's few launches run ahead of the
+#: device, short enough that a door's set-up traces and loads little
+PREFILL_LAYERS = 8
+
 
 @dataclasses.dataclass(eq=False)
 class DecodeRequest:
@@ -97,11 +122,14 @@ class DecodeRequest:
 
 
 class _Slot:
-    __slots__ = ("req", "pos", "out", "last_id", "cancelled")
+    __slots__ = ("req", "pos", "prefill", "out", "last_id", "cancelled")
 
-    def __init__(self, req: DecodeRequest):
+    def __init__(self, req: DecodeRequest, prefill: int = 0):
         self.req = req
-        self.pos = 0               #: next position to feed
+        self.pos = prefill         #: next position to feed to a step
+        #: prompt positions ``0 .. prefill - 1`` still to go through the
+        #: prefill, which the next ``step()`` runs first
+        self.prefill = prefill
         self.out: list[int] = []   #: generated ids
         self.last_id = 0           #: last sampled id (input past prompt)
         self.cancelled = False
@@ -139,6 +167,8 @@ class ContinuousBatchEngine:
         self.max_len = parts.max_len
         #: the chain-partition structure: stage s owns these blocks
         self.stage_blocks = parts.stage_blocks
+        #: every block in order, as the programs walk them: (op, name)
+        self._blocks = [(nodes[nm].op, nm) for nm in parts.block_names]
         self.top_k = top_k
         #: one layer's cache: ``width`` slots, f32 (the step computes in
         #: it); looked up on the module when the engine is built, so a
@@ -157,9 +187,18 @@ class ContinuousBatchEngine:
         #: its aliased output (docs/DECODE_CLIFF.md, "The engine")
         self._caches = self.kv_format.zeros(width, len(parts.block_names))
         self._step_fns: dict[bool, Any] = {}
+        #: positions a prefill takes (0: a model of one position)
+        self.prefill_len = min(PREFILL_POSITIONS, self.max_len - 1)
+        self._prefill_fns = self._build_prefill()
         self.steps = 0
         self._step_hist = REGISTRY.histogram("serve.decode.step_s")
         self._tok_count = REGISTRY.counter("serve.decode.tokens")
+        #: how often the prefill engages: prompt tokens it took, and
+        #: prompt tokens a step was fed (a request's last, a long tail)
+        self._prefilled_count = REGISTRY.counter(
+            "serve.decode.prompt_tokens_prefilled")
+        self._forced_count = REGISTRY.counter(
+            "serve.decode.prompt_tokens_forced")
 
     # -- state -------------------------------------------------------------
 
@@ -173,14 +212,18 @@ class ContinuousBatchEngine:
         """Claim a free slot for ``req``; False when the batch is full.
         The request's KV rows start clean by construction: position p's
         cache row is written before any later position reads it, so a
-        recycled slot needs no cache zeroing."""
+        recycled slot needs no cache zeroing.  The slot is marked for
+        its prompt's prefill, which the next :meth:`step` runs before
+        the step itself (a slot cancelled before then has run nothing);
+        a prompt of one token has nothing to prefill."""
         if req.prompt.size + req.max_new_tokens > self.max_len:
             raise ValueError(
                 f"prompt {req.prompt.size} + {req.max_new_tokens} new "
                 f"tokens exceeds max_len={self.max_len}")
         for i, s in enumerate(self._slots):
             if s is None:
-                self._slots[i] = _Slot(req)
+                self._slots[i] = _Slot(
+                    req, min(req.prompt.size - 1, self.prefill_len))
                 return True
         return False
 
@@ -203,8 +246,7 @@ class ContinuousBatchEngine:
     def _build_step(self, sample: bool):
         nodes = self.graph.nodes
         embed = self.embed_op
-        blocks = [(nodes[nm].op, nm)
-                  for blks in self.stage_blocks for nm in blks]
+        blocks = self._blocks
         final_ln = nodes["final_ln"].op
         lm_head = nodes["lm_head"].op
         top_k = self.top_k
@@ -245,16 +287,81 @@ class ContinuousBatchEngine:
             fn = self._step_fns[sample] = self._build_step(sample)
         return fn
 
+    # -- the prefill programs ----------------------------------------------
+
+    def _build_prefill(self):
+        """``(embed, blocks)``: a prompt's rows ``ids [prefill_len] ->
+        x [1, prefill_len, d]``, and a few blocks' prefill ``(ops, their
+        params, x, their layers of the caches, slot) -> (x, the
+        layers)``, the layers donated and aliased like the step's
+        caches.  In float32 like the step, whose rows these stand in
+        for; no head: the last prompt token goes through the step.
+
+        A program :data:`PREFILL_LAYERS` blocks long, called once a
+        group of them, not one program over every block: alike blocks
+        share it, so the door's set-up traces, lowers and loads a sixth
+        of gpt2-xl's 48 layers (all of them in one program cost what the
+        step program does, 4.7 s of a 26 s set-up; a program a block
+        0.17 ms a call, 8 ms a prompt: PERF.md section 6, PR 39)."""
+        embed, fmt = self.embed_op, self.kv_format
+
+        def engine_prefill_embed(params, ids):
+            # rows cut out one by one, as the step's: a gather would
+            # first copy the whole token table out of its layout
+            return embed.embed_rows(
+                params, ids,
+                np.arange(ids.shape[0]))[None].astype(jnp.float32)
+
+        def engine_prefill(ops, params, x, layers, slot):
+            out = []
+            for op, p, layer in zip(ops, params, layers):
+                x, layer = op.prefill(p, x, layer, fmt, slot)
+                out.append(layer)
+            return x, out
+
+        return jax.jit(engine_prefill_embed), \
+            jax.jit(engine_prefill, static_argnums=(0,), donate_argnums=(3,))
+
+    def _prefill(self, i: int, s: _Slot) -> None:
+        """Slot ``i``'s prompt positions ``0 .. s.prefill - 1`` through
+        the prefill programs, waited for: the step behind them then
+        starts on an empty queue, and ``step_s`` stays a step's own
+        time."""
+        n, s.prefill = s.prefill, 0
+        fmt = self.kv_format
+        embed, blocks_prefill = self._prefill_fns
+        with span("engine", "prefill", {"step": self.steps, "slot": i,
+                                        "positions": n}):
+            ids = np.zeros(self.prefill_len, np.int32)
+            ids[:n] = s.req.prompt[:n]
+            slot = jnp.int32(i)
+            x = embed(self.params["embeddings"], jnp.asarray(ids))
+            for l0 in range(0, len(self._blocks), PREFILL_LAYERS):
+                ops, names = zip(*self._blocks[l0:l0 + PREFILL_LAYERS])
+                x, layers = blocks_prefill(
+                    ops, [self.params[nm] for nm in names], x,
+                    [fmt.layer(self._caches, l0 + j)
+                     for j in range(len(ops))], slot)
+                for l, layer in enumerate(layers, l0):
+                    self._caches = fmt.with_layer(self._caches, l, layer)
+            jax.block_until_ready(x)
+        self._prefilled_count.n += n
+
     # -- one decode step ---------------------------------------------------
 
     def step(self) -> list[tuple[DecodeRequest, np.ndarray]]:
         """Advance every active slot one token; returns requests that
         FINISHED this step as ``(request, [plen + new] ids)`` (their
-        slots are already free).  No-op (empty list) with no active
-        slots."""
+        slots are already free).  A slot that joined since the last
+        step first has its prompt prefilled (``engine.prefill``, a phase
+        of its own in front of the step's).  No-op (empty list) with no
+        active slots."""
         live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         if not live:
             return []
+        for i, s in live:
+            if s.prefill:       # joined since the last step
+                self._prefill(i, s)
         with span("engine", "step", {"step": self.steps,
                                      "rows": len(live)}):
             return self._step(live)
@@ -278,7 +385,11 @@ class ContinuousBatchEngine:
             sample = False
             for i, s in live:
                 plen = s.req.prompt.size
-                ids[i] = s.req.prompt[s.pos] if s.pos < plen else s.last_id
+                if s.pos < plen:
+                    ids[i] = s.req.prompt[s.pos]
+                    self._forced_count.n += 1
+                else:
+                    ids[i] = s.last_id
                 pos[i] = s.pos
                 seeds[i] = s.req.seed & 0xFFFFFFFF
                 temps[i] = s.req.temperature
@@ -363,7 +474,9 @@ class EngineLoop(threading.Thread):
         self._halt = threading.Event()
         self.error: BaseException | None = None
         #: called with (per-unit seconds, units) after each step — feeds
-        #: the admission controller's live service EWMA
+        #: the admission controller's live service EWMA.  A joined
+        #: slot's prefill runs inside that ``step()`` and is timed with
+        #: it: over a request's life its steps carry one prefill each
         self._on_service = on_service
         #: cancellations queued from OTHER threads (client reader saw a
         #: disconnect); applied between steps on THIS thread — the slot

@@ -862,8 +862,9 @@ class ServeFrontDoor:
                 "active": self.engine.active(),
                 "free_slots": self.engine.free_slots(),
                 "steps": self.engine.steps,
-                "tokens": REGISTRY.counter(
-                    "serve.decode.tokens").value,
+                **{name: REGISTRY.counter(f"serve.decode.{name}").value
+                   for name in ("tokens", "prompt_tokens_prefilled",
+                                "prompt_tokens_forced")},
                 **{f"{name}_s": REGISTRY.histogram(
                     f"serve.decode.{name}_s").summary()
                    for name in ("step",) + ENGINE_LOOP_PHASES},

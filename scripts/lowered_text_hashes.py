@@ -8,7 +8,7 @@ several; ``olmoe_tiny``'s widths at four layers for four stages;
 ``brumby_tiny``, whose state has neither int8 rows nor beams;
 ``cohere_moe_tiny``, a format a layer; ``jamba_tiny``, two kinds of
 memory in one graph, a period a stage) and
-the engine's step (greedy and sampling), lowered on the CPU
+the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
     env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
@@ -17,7 +17,8 @@ mesh at toy sizes.  Run it in two trees and compare the lines:
 One ``name sha256 bytes`` line a program; with ``DIR`` each text is also
 written to ``DIR/<name>.txt`` for ``diff``.  It reads only what both
 engines have always had: ``_init_state``, ``_get_decode_fn``,
-``_build_prefill_fn``, ``_step_fn``, ``_caches``.
+``_build_prefill_fn``, ``_step_fn``, ``_caches`` (and the engine's
+``_prefill_fns`` and ``_blocks``, which it has had since PR 39).
 """
 
 import hashlib
@@ -76,6 +77,15 @@ def engine_programs():
             eng._step_fn(sample).lower(
                 eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
                 vec.astype(jnp.float32))
+    embed, blocks_prefill = eng._prefill_fns
+    ids = jnp.zeros(eng.prefill_len, jnp.int32)
+    yield "engine.prefill.embed", embed.lower(eng.params["embeddings"], ids)
+    ops, names = zip(*eng._blocks)
+    yield "engine.prefill.blocks", blocks_prefill.lower(
+        ops, [eng.params[nm] for nm in names],
+        embed(eng.params["embeddings"], ids),
+        [eng.kv_format.layer(eng._caches, l) for l in range(len(ops))],
+        jnp.int32(0))
 
 
 def main() -> int:

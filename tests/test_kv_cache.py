@@ -302,23 +302,39 @@ def test_write_position_of_a_group_touches_that_group_only(quantized):
             np.testing.assert_array_equal(np.asarray(got[key]), want)
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["buffer", "int8"])
-def test_write_prefix_is_the_rows_written_one_by_one(quantized):
+@pytest.mark.parametrize("quantized,groups", [
+    (False, 2), (True, 2), (False, None), (True, None)],
+    ids=["buffer", "int8", "slots", "slots-int8"])
+def test_write_prefix_is_the_rows_written_one_by_one(quantized, groups):
     """A prompt's columns written at once equal ``rows`` +
-    ``write_position`` a position, int8 scales included."""
-    fmt, rng = _fmt(quantized, groups=2), np.random.default_rng(8)
-    b, t, g = 2, 5, 1
-    layer = _random_layer(fmt, b, rng)
+    ``write_position`` a position, int8 scales included.  In a format
+    without groups (the serving engine's) the slot is a sequence alone:
+    that sequence's rows are the ones written one by one, and every
+    other sequence keeps its bits."""
+    fmt, rng = _fmt(quantized, groups=groups), np.random.default_rng(8)
+    t, at = 5, 1
+    batch, b, group = (2, 2, at) if groups else (3, 1, None)
+
+    def cut(layer):
+        """The sequences a position's rows are written to."""
+        return layer if groups else {key: buf[at:at + b]
+                                     for key, buf in layer.items()}
+
+    layer = _random_layer(fmt, batch, rng)
     k, v = (jnp.asarray(rng.standard_normal((b, t, KV * HD)), jnp.float32)
             for _ in range(2))
-    got = fmt.write_prefix(layer, k, v, jnp.int32(g))
-    want = layer
+    got = fmt.write_prefix(layer, k, v, jnp.int32(at))
+    want = cut(layer)
     for p in range(t):
         want = fmt.write_position(want, fmt.rows(k[:, p], v[:, p]), p,
-                                  group=g)
+                                  group=group)
     for key in fmt.keys:
-        np.testing.assert_array_equal(np.asarray(got[key]),
+        np.testing.assert_array_equal(np.asarray(cut(got)[key]),
                                       np.asarray(want[key]))
+        if groups is None:
+            others = np.arange(batch) != at
+            np.testing.assert_array_equal(np.asarray(got[key])[others],
+                                          np.asarray(layer[key])[others])
 
 
 @pytest.mark.parametrize("shape,positions", [

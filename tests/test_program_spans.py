@@ -566,19 +566,27 @@ def test_the_engine_loop_names_steps_joins_and_parks(door, traced):
                   key=lambda s: s["ts_us"])
     assert _in_order(loop)
     assert {s["name"] for s in loop} \
-        == {"engine.step", "engine.join", "engine.park"}
+        == {"engine.step", "engine.join", "engine.park", "engine.prefill"}
     names = [s["name"] for s in loop]
-    # a join sweep before every step; parks only with nothing active
-    assert all(names[i - 1] == "engine.join"
-               for i, nm in enumerate(names) if nm == "engine.step")
+    # a join sweep before every step, a joined slot's prefill between
+    # them: a sibling of both; parks only with nothing active
+    between = [nm for nm in names if nm != "engine.prefill"]
+    assert all(between[i - 1] == "engine.join"
+               for i, nm in enumerate(between) if nm == "engine.step")
+    assert all(names[i - 1] in ("engine.join", "engine.prefill")
+               and "engine.step" in names[i:]
+               for i, nm in enumerate(names) if nm == "engine.prefill")
+    # one call a request, its prompt but for the last token
+    fills = [s for s in loop if s["name"] == "engine.prefill"]
+    assert sorted(s["args"]["positions"] for s in fills) == [2, 3]
     assert names[-1] in ("engine.park", "engine.join")
     parks = [s for s in loop if s["name"] == "engine.park"]
     assert any(s["dur_us"] >= 40_000 for s in parks)    # a whole timeout
     # the phase histograms are what the spans fed
     for phase in ENGINE_PHASES + ENGINE_DISPATCH_PHASES \
             + ENGINE_LOOP_PHASES:
-        assert REGISTRY.histogram(
-            f"serve.decode.{phase}_s").count >= steps
+        assert REGISTRY.histogram(f"serve.decode.{phase}_s").count \
+            >= (len(fills) if phase == "prefill" else steps)
 
 
 def test_the_door_admits_each_request_under_a_span_with_its_rid(
@@ -596,7 +604,7 @@ def test_the_door_admits_each_request_under_a_span_with_its_rid(
     assert len(set(rids)) == 3 and rids == sorted(rids)
     assert REGISTRY.histogram("serve.door.admit_s").count == n0 + 3
     stats = fetch_stats(host, port)["decode"]
-    for key in ("step_s", "join_s", "park_s"):
+    for key in ("step_s", "join_s", "park_s", "prefill_s"):
         assert stats[key]["count"] > 0
 
 

@@ -17,12 +17,14 @@ import jax.numpy as jnp
 from defer_tpu import partition
 from defer_tpu.models import resnet_tiny
 from defer_tpu.models.gpt import gpt_tiny
+from defer_tpu.obs import REGISTRY
 from defer_tpu.plan import StageCostModel, max_batch_within_budget, \
     stage_ms_at_batch
 from defer_tpu.runtime.node import ChainDispatcher, StageNode
 from defer_tpu.serve import (AdmissionController, ContinuousBatchEngine,
                              DecodeRequest, ServeClient, TenantConfig,
                              WeightedFairQueue, poisson_trace)
+from defer_tpu.serve import engine as engine_mod
 from defer_tpu.serve.client import fetch_stats
 from defer_tpu.serve.frontdoor import ChainBackend, ServeFrontDoor
 
@@ -226,10 +228,23 @@ def _prompts(n, rng):
             for p in rng.integers(2, 6, n)]
 
 
-def test_engine_byte_identity_solo_vs_continuous(gpt_setup):
+def _prefill_positions(monkeypatch, positions):
+    """Engines built from here on prefill ``positions`` of a prompt in
+    one call (0: none, every prompt token is fed to a step)."""
+    monkeypatch.setattr(engine_mod, "PREFILL_POSITIONS", positions)
+
+
+@pytest.mark.parametrize("positions", [128, 2, 0],
+                         ids=["prefill", "prefill-and-tail", "forced"])
+def test_engine_byte_identity_solo_vs_continuous(gpt_setup, monkeypatch,
+                                                 positions):
     """The correctness bar: per-request outputs byte-identical to the
     request run alone, with requests JOINING AT DIFFERENT STEPS (true
-    continuous batching, not lockstep), greedy and sampled rows mixed."""
+    continuous batching, not lockstep), greedy and sampled rows mixed.
+    A joining request's prefill call lands between the other slots'
+    steps and touches its own rows only; so does a prompt whose tail is
+    teacher-forced behind a short prefill."""
+    _prefill_positions(monkeypatch, positions)
     g, params = gpt_setup
     rng = np.random.default_rng(3)
     prompts = _prompts(3, rng)
@@ -252,9 +267,13 @@ def test_engine_byte_identity_solo_vs_continuous(gpt_setup):
                 and e.steps >= 3 * queue[0].request_id:
             e.join(queue.pop(0))
 
+    fills0 = REGISTRY.histogram("serve.decode.prefill_s").count
     batched = eng.run_all(make_reqs(), joiner=stagger)
     for rid, ids in solo.items():
         np.testing.assert_array_equal(batched[rid], ids)
+    # every prompt here has two tokens or more: one call a request
+    assert REGISTRY.histogram("serve.decode.prefill_s").count \
+        - fills0 == (3 if positions else 0)
 
 
 def test_engine_cancel_reclaims_slot_others_unaffected(gpt_setup):
@@ -365,37 +384,153 @@ def test_engine_slots_at_different_positions_match_solo_and_oracle(
             want, _oracle_run(g, params, req.prompt, eng.max_len, new=6)[0])
 
 
+@pytest.mark.parametrize("positions", [128, 4],
+                         ids=["padded-over", "padded-and-stale"])
 @pytest.mark.parametrize("leaves_by", ["cancel", "finished"])
-def test_engine_recycled_slot_ignores_previous_tenants_rows(gpt_setup,
-                                                            leaves_by):
-    """A slot is never zeroed between tenants: the buffers still hold
-    the previous tenant's rows beyond the next tenant's positions, and
-    the next tenant's answer equals its solo run all the same."""
+def test_engine_recycled_slot_ignores_previous_tenants_rows(
+        gpt_setup, monkeypatch, leaves_by, positions):
+    """A slot is never zeroed between tenants, and the next tenant's
+    prefill writes rows for its padding: behind the next tenant's
+    positions the buffers hold rows that are not its own — the padding's
+    up to the prefill's length, the longer previous tenant's from there
+    on — and its answer equals its solo run and the one-item oracle,
+    which has neither."""
+    _prefill_positions(monkeypatch, positions)
     g, params = gpt_setup
     rng = np.random.default_rng(12)
     p_long = rng.integers(0, 97, (8,)).astype(np.int32)
     p_next = rng.integers(0, 97, (3,)).astype(np.int32)
     solo = ContinuousBatchEngine(g, params, num_stages=1, width=1).run_all(
         [DecodeRequest(prompt=p_next, max_new_tokens=3, request_id=7)])[7]
+    np.testing.assert_array_equal(
+        solo, _oracle_run(g, params, p_next, 16, new=3)[0])
 
     eng = ContinuousBatchEngine(g, params, num_stages=1, width=1)
     first = DecodeRequest(prompt=p_long, max_new_tokens=7, request_id=0)
     assert eng.join(first)
+    # the first tenant feeds positions 0..13, all but the prefilled to
+    # a step each
+    to_go = 14 - eng._slots[0].prefill
     if leaves_by == "cancel":
-        for _ in range(12):
+        for _ in range(to_go - 2):
             eng.step()
         assert eng.cancel(first)
     else:
         while eng.active():
             eng.step()
+        assert eng.steps == to_go
     assert eng.free_slots() == 1
     got = eng.run_all(
         [DecodeRequest(prompt=p_next, max_new_tokens=3, request_id=7)])[7]
     np.testing.assert_array_equal(got, solo)
-    # the next tenant fed positions 0..4; the first one's rows 5..11
-    # are still there
+    # the next tenant fed positions 0..4; rows 5..11 are still there,
+    # the first tenant's or the padding's
     stale = np.asarray(eng._caches["k"][0])[0, :, 5:12]
     assert np.abs(stale).min(axis=(0, 2)).all()
+
+
+@pytest.mark.parametrize("layers", [8, 3], ids=["one-group", "groups-3+1"])
+@pytest.mark.parametrize("plen", [1, 2, 4, 5, 11],
+                         ids=["1", "2", "L", "L+1", "L+7"])
+def test_engine_prefilled_rows_are_the_teacher_forced_rows(
+        gpt_setup, monkeypatch, plen, layers):
+    """One prefill of L = 4 positions against the token-a-step path:
+    the slot's rows agree within float32 rounding (a whole-prompt
+    product against ``plen`` one-row products), the generated tokens are
+    the same, and a prompt longer than L + 1 is served by prefill plus a
+    forced tail.  Another slot rides along at its own positions.  The
+    four blocks go through one program, or through one of three blocks
+    and one of the last."""
+    monkeypatch.setattr(engine_mod, "PREFILL_LAYERS", layers)
+    g, params = gpt_setup
+    rng = np.random.default_rng(21)
+    prompt = rng.integers(1, 97, (plen,)).astype(np.int32)
+    other = rng.integers(1, 97, (3,)).astype(np.int32)
+
+    def run(eng):
+        out = eng.run_all([
+            DecodeRequest(prompt=other, max_new_tokens=2, request_id=0),
+            DecodeRequest(prompt=prompt, max_new_tokens=4, request_id=1)])
+        return out[1], eng
+
+    _prefill_positions(monkeypatch, 0)      # every token to a step
+    want, forced = run(ContinuousBatchEngine(g, params, num_stages=2,
+                                             width=2))
+    assert forced.prefill_len == 0
+    _prefill_positions(monkeypatch, 4)
+    fills0 = REGISTRY.histogram("serve.decode.prefill_s").count
+    got, eng = run(ContinuousBatchEngine(g, params, num_stages=2, width=2))
+    assert eng.prefill_len == 4
+    np.testing.assert_array_equal(got, want)
+    assert eng.steps == 4 + plen - 1 - min(plen - 1, 4)
+    assert forced.steps == 4 + plen - 1
+    # the other request's call, and this one's unless it has one token
+    assert REGISTRY.histogram("serve.decode.prefill_s").count \
+        - fills0 == 1 + (plen > 1)
+    live = plen + 4 - 1         # positions 0..live-1 were fed
+    for side in ("k", "v"):
+        for a, b in zip(eng._caches[side], forced._caches[side]):
+            a, b = np.asarray(a)[1, :, :live], np.asarray(b)[1, :, :live]
+            np.testing.assert_allclose(a, b, atol=1e-5)
+            assert np.abs(a).max() > 0
+
+
+def test_engine_cancel_between_join_and_first_step_frees_the_slot(
+        gpt_setup):
+    """A request cancelled before the step that follows its join has
+    run nothing: no prefill call, its slot free, ``on_done(None)``; the
+    slot's next tenant is served as if it had never been there."""
+    g, params = gpt_setup
+    rng = np.random.default_rng(22)
+    p_gone, p_next = (rng.integers(0, 97, (n,)).astype(np.int32)
+                      for n in (6, 4))
+    solo = ContinuousBatchEngine(g, params, num_stages=2, width=1).run_all(
+        [DecodeRequest(prompt=p_next, max_new_tokens=3, request_id=1)])[1]
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=1)
+    seen = []
+    gone = DecodeRequest(prompt=p_gone, max_new_tokens=3, on_done=seen.append)
+    fills = REGISTRY.histogram("serve.decode.prefill_s")
+    fills0 = fills.count
+    assert eng.join(gone) and eng._slots[0].prefill == 5
+    assert eng.cancel(gone)
+    assert seen == [None] and eng.free_slots() == 1
+    assert eng.step() == [] and eng.steps == 0
+    assert fills.count == fills0
+    assert not np.asarray(eng._caches["k"][0]).any()
+    got = eng.run_all(
+        [DecodeRequest(prompt=p_next, max_new_tokens=3, request_id=1)])[1]
+    np.testing.assert_array_equal(got, solo)
+    assert fills.count == fills0 + 1
+
+
+@pytest.mark.parametrize("positions", [128, 4, 0],
+                         ids=["prefill", "prefill-and-tail", "forced"])
+def test_engine_counts_prompt_tokens_prefilled_and_forced(
+        gpt_setup, monkeypatch, positions):
+    """``serve.decode.prompt_tokens_prefilled`` + ``_forced`` = every
+    prompt token served; with a prefill as long as the prompts, one
+    forced a request: its last prompt token, which the first step
+    takes."""
+    _prefill_positions(monkeypatch, positions)
+    g, params = gpt_setup
+    rng = np.random.default_rng(23)
+    plens = [1, 2, 5, 9, 6]
+    reqs = [DecodeRequest(prompt=rng.integers(0, 97, (n,)),
+                          max_new_tokens=3, request_id=i)
+            for i, n in enumerate(plens)]
+    counters = [REGISTRY.counter(f"serve.decode.{name}")
+                for name in ("prompt_tokens_prefilled",
+                             "prompt_tokens_forced", "tokens")]
+    before = [c.value for c in counters]
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    assert len(eng.run_all(reqs)) == len(plens)
+    prefilled, forced, tokens = (c.value - b
+                                 for c, b in zip(counters, before))
+    assert prefilled + forced == sum(plens)
+    assert prefilled == sum(min(n - 1, positions) for n in plens)
+    if positions == 128:
+        assert forced == len(plens)
+    assert tokens == 3 * len(plens)
 
 
 def _walk_eqns(jaxpr):
