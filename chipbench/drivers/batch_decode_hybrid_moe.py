@@ -1,0 +1,550 @@
+"""Driver ``batch_decode_hybrid_moe``: ``batch_decode``'s offline batch
+through ``PipelinedDecoder``, for the family whose layers keep two
+*kinds* of memory — Mamba-2 layers a convolution window and a state of
+heads, attention layers a KV cache — and each hold a share of their
+routed experts (``models.granite_hybrid``).
+
+The window, the readings, ``tokens_per_s`` and the token check are
+``chipbench/drivers/batch_decode.py``'s own functions, called from
+here, as the four newer drivers call them; the weights are drawn on the
+chip a node's own ``init`` at a time, one program a kind of node, and
+kept on the *host*, as ``batch_decode_hybrid_ssm`` draws them (here a
+leaf may be scaled by the configuration's ``init_gain`` on the way),
+and the head is the embedding's table.  This file has the set-up, what
+the layers add to ``counters`` and the rest of ``check``.
+
+``check`` holds the program to the plain reference four times:
+
+* the generated tokens, by ``batch_decode``'s measure at this file's
+  limit, on ``check_sequences`` sequences over the first
+  ``check_tokens`` generated tokens;
+* **the router**, as ``batch_decode_window_moe`` holds command-a-plus's:
+  the share of the reference's 10 choices a token a layer (over all 72
+  experts, held or not) that the program's own blocks make on the same
+  tokens, in the layer where they agree least; and **the weights** of
+  those choices, which tell this family's rule (a softmax over the
+  chosen) from one over all 72 (any monotone rule chooses the same
+  ten): the program's expert half of every layer, fed the reference's
+  stream, against the reference's (:func:`router_agreement`);
+* **the state the decode steps left**: one more generation outside the
+  window, the prefill and ``STATE_STEPS`` decode steps.
+  ``check_sequences`` sequences' ``H`` and window of every Mamba layer
+  are fetched, brought to the layout-free ``[heads, head_dim, N]`` /
+  ``[d_conv - 1, E + 2 N]`` form (``ops/ssm.py::dense``) and compared
+  with the reference's own recurrence over the prompt and the tokens
+  the program fed back (``chipbench/reference/granite_hybrid.py::
+  states``) by ``rel_err``: the first layer, whose inputs are one norm
+  and one product away from the reference's, at a limit of its own.  A
+  window read one position off fails it
+  (``scripts/hybrid_moe_controls.py``);
+* **the long memory** (:func:`long_memory_error`): the model's seeded
+  steps and decays give most heads a memory of tens of positions, under
+  which a state kept below float32 costs little a comparison could see.
+  What the configuration's float32 is for is a sum over hundreds of
+  positions under a decay near 1, so the check drives the program's own
+  format (its buffers, ``ssd_scan``, ``ssd_step``) at the cell's
+  geometry through a prefill of ``PROBE_STEPS`` positions (eight
+  chunks: the state carried between them is what it tests) and as many
+  decode steps of float32 inputs with ``dt A`` in ``[-PROBE_DECAY, 0)``
+  and holds its outputs to the reference's recurrence and its last
+  ``H`` to the explicit sum.
+
+Counters added: the program's ``decode.moe.*`` sums over the window
+(``assignments``: rows x 10 x layers x steps; ``held_assignments``:
+those that fell to held experts; ``experts_hit``: distinct held experts
+a layer a step; ``load_max``), ``experts_hit_share`` (held experts hit a
+layer a step over held experts), ``held_share`` (held over all
+assignments: 1/2 expected), ``decode.ssm.updates`` (sequences x Mamba
+layers of every valid decode step), the program's gauges
+``decode.ssm.state_bytes`` / ``decode.ssm.conv_bytes`` /
+``decode.cache.full_bytes`` / ``decode.weights.row_bytes`` /
+``decode.weights.own_bytes`` (as ``ssm_state_bytes`` ...),
+``mamba2_layers``, ``prefill_tokens``, ``prefill_piece_rows`` and
+``max_len``.
+
+Traffic file keys: as ``batch_decode``, and ``check_tokens``.
+Configuration file keys: ``model_args`` (for
+``defer_tpu.models.granite_hybrid``), ``reference``, and optionally
+``init_gain``.
+"""
+
+from __future__ import annotations
+
+import functools
+import gc
+import importlib
+
+import numpy as np
+
+from chipbench.agreement import rel_err
+from chipbench.drivers import batch_decode as base
+
+#: this configuration's limit on the worst logit gap share (the measure
+#: is ``batch_decode``'s: how far the reference's logit of the program's
+#: token sits under the reference's best, over the position's spread; a
+#: token no better than a random one reads ~1).  Set between two
+#: readings on the v5e (PR 41, PERF.md section 6; a reading is the worst
+#: of 2 x 512 tokens, as a run judges them).  The largest the program
+#: gave over the builder's 21 readings of 21 seeds: 0.0675 (0.0207 at
+#: the least; 92-95% of its tokens are the reference's own argmax).  The
+#: reference itself with every product's operands rounded to
+#: float8_e4m3, the nearest precision below the stated one: 1.589 at the
+#: least over 3 seeds (1.739 at the most; none of its tokens the float32
+#: run's argmax): not correct.  0.3 is 4.4x over the one and 5.3x under
+#: the other, near their geometric mean.
+GAP_TOL = 0.3
+#: the least share of the reference's expert choices (10 a token, over
+#: all 72 experts) that the program's own blocks must make on the same
+#: tokens, in the layer where they agree least (as
+#: ``batch_decode_window_moe.ROUTER_TOL``).  Set the same way (pairs of
+#: 1535-token sequences): the program's least 0.9739 (by layer 0.996
+#: falling to 0.975: the streams part as bfloat16 turns a tenth choice
+#: at a near-tie), the float8_e4m3-input reference's most 0.419 in its
+#: best layer and 0.19 in its worst: not correct.  A router that
+#: renormalises over all 72 chooses the same ten in the first layer and,
+#: its stream drifting, 0.849-0.853 of them in the last: not correct.
+#: 0.935 leaves a disagreement of 0.065: 2.5x the program's 0.026, 2.3x
+#: under that router's 0.147.
+ROUTER_TOL = 0.935
+#: the most the program's expert half of a layer (router, held routed
+#: experts under the full choice's weights, shared expert) may differ
+#: from the reference's on the reference's own stream into the layer,
+#: as :func:`rms_err`, in the layer where it differs most: what tells
+#: this family's weights — a softmax over the 10 chosen — from a softmax
+#: over all 72 used as it comes, which chooses the same experts.  The
+#: program's largest over the same readings (and three of 2 x 320
+#: tokens) 0.0101 (0.0053 at the least); the program held to a reference
+#: under that other rule (``scripts/hybrid_moe_controls.py``) 0.1309 at
+#: the least over 3 seeds x 10 layers (0.1440 at the most): not correct.
+#: 0.036 is 3.6x over the one and 3.6x under the other.
+WEIGHTS_TOL = 0.036
+#: decode steps behind the prefill before the state is read back
+STATE_STEPS = 64
+#: the most a Mamba layer's state after those steps (``H`` or the
+#: window, in the layout-free form) may differ from the reference's, as
+#: ``rel_err`` (largest difference over largest entry), in the layer
+#: where it differs most.  Set the same way (a reading is the worst of 9
+#: layers, 2 sequences of 1024 + 64 tokens): the program's largest over
+#: the same readings 0.1031 (0.0330 at the least; it grows with depth:
+#: bfloat16 activations a layer further from the float32 stream), the
+#: float8_e4m3-input reference's least 0.579 (2.95 at the most): not
+#: correct.  0.24 is 2.3x over the one and 2.4x under the other.  A
+#: window read one position off reads 1.26-1.32 in every layer: not
+#: correct.  What this limit cannot see is a state kept in bfloat16: the
+#: reference with its own ``H`` rounded to bfloat16 after every position
+#: reads 0.0036-0.040, *under* what the program's bfloat16 activations
+#: cost; that is the probe's to fail, below.
+STATE_TOL = 0.24
+#: the same in the first layer alone, whose inputs are one norm and one
+#: bfloat16 product away from the reference's: the program's largest
+#: 0.0102 (0.0033 at the least), the float8_e4m3-input reference's least
+#: 0.579.  0.06 is 5.9x over the one and 9.6x under the other.
+STATE_TOL_FIRST = 0.06
+#: the long-memory probe: positions of its prefill and as many decode
+#: steps, and the range of ``dt A`` (a memory of ~2 / PROBE_DECAY = 500
+#: positions)
+PROBE_STEPS = 2048
+PROBE_DECAY = 0.004
+#: the most the probe's outputs and its last ``H`` may differ from the
+#: reference's, as ``rel_err``, each.  Set from two readings on the v5e
+#: (PR 41, PERF.md section 6; 24 seeds, the control 3): the program's
+#: largest 1.57e-3 (``H`` against the explicit sum; its outputs
+#: 0.86e-3-1.27e-3: the chunked form's products and the recurrence's
+#: order of sums differ from the reference's position by position, both
+#: in float32); the same kernels with ``H`` rounded to bfloat16 after
+#: the prefill and after each step, the nearest below the float32 the
+#: configuration states, at the least 0.0599 (``y`` of the decode steps)
+#: and 0.1562 (``H``): not correct, by both parts.  0.01 is 6.4x over
+#: the program's largest and 6.0x under the control's least.
+MEMORY_TOL = 0.01
+MOE_COUNTERS = ("decode.moe.assignments", "decode.moe.held_assignments",
+                "decode.moe.experts_hit", "decode.moe.load_max")
+UPDATES = "decode.ssm.updates"
+GAUGES = ("decode.ssm.state_bytes", "decode.ssm.conv_bytes",
+          "decode.cache.full_bytes", "decode.weights.row_bytes",
+          "decode.weights.own_bytes")
+
+
+def make_weights(graph, seed: int, dtype, gains: dict) -> dict:
+    """The program's initialiser from the seed, a node at a time on the
+    chip, each fetched to the host as it is made, scaled where its path
+    ends with a key of ``gains`` (``q/w``) and cast to ``dtype`` in the
+    same program, then the head tied to the embedding, as a tied
+    checkpoint loads.  A node's draw is *that node's own* ``init``
+    under the key ``graph.init`` would hand it (its split of the seed's
+    key by the node's place), jitted once a kind of node: nine Mamba
+    layers share one program
+    (``batch_decode_hybrid_ssm.make_weights``, with gains).  The tree is
+    ``graph.init``'s own, leaf for leaf but for the gains
+    (``chipbench/tests`` hold it to that)."""
+    import jax
+    import jax.numpy as jnp
+
+    from defer_tpu.models.cohere_moe import tie_head
+
+    # seeds run to a little over 2**31: fold into the key's 32-bit range
+    key = jax.random.key(int(seed) % (2 ** 31 - 1))
+    keys = jax.random.split(key, max(len(graph.nodes), 1))
+    programs: dict = {}
+
+    def leaf(name, path, a):
+        full = "/".join([name] + [str(k.key) for k in path])
+        for ending, gain in gains.items():
+            if full.endswith(ending):
+                a = a * gain
+        return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) \
+            else a
+
+    params = {}
+    for k, node in zip(keys, graph.nodes.values()):
+        if node.param_spec is None:
+            continue
+        in_specs = tuple(graph.out_spec(i) for i in node.inputs)
+        # like layers are equal ops on equal inputs
+        kind = (node.op, tuple((s.shape, s.dtype) for s in in_specs))
+        if kind not in programs:
+            # (a kind's nodes differ in their number alone, which no
+            # ending of a gain's path holds)
+            programs[kind] = jax.jit(
+                lambda k, op=node.op, in_specs=in_specs, name=node.name:
+                jax.tree_util.tree_map_with_path(
+                    functools.partial(leaf, name), op.init(k, in_specs)))
+        params[node.name] = jax.device_get(programs[kind](k))
+    return tie_head(params)
+
+
+def setup(ctx):
+    import jax.numpy as jnp
+
+    from defer_tpu import PipelinedDecoder, models
+
+    tr, cfg = ctx.cell.traffic, ctx.cell.config
+    if ctx.cell.chips != 1:
+        raise ValueError("batch_decode_hybrid_moe's state check reads one "
+                         "chip's buffers; give the cell one chip")
+    graph = models.granite_hybrid(**cfg["model_args"])
+    dtype = jnp.dtype(tr["compute_dtype"])
+    with ctx.span("weights"):
+        params = make_weights(graph, ctx.seed, dtype,
+                              cfg.get("init_gain", {}))
+    with ctx.span("build"):
+        dec = PipelinedDecoder(
+            graph, params, num_stages=ctx.cell.chips,
+            microbatch=tr["batch"] // ctx.cell.chips, max_len=tr["max_len"],
+            compute_dtype=dtype, kv_cache=tr["kv_cache"])
+    rng = np.random.default_rng(ctx.seed)
+    # ids over the held rows of the vocabulary
+    prompts = rng.integers(0, cfg["model_args"]["vocab"],
+                           (tr["batch"], tr["prompt_len"])).astype(np.int32)
+    state = {"params": params, "dec": dec, "prompts": prompts,
+             "graph": graph, "traffic": tr, "config": cfg}
+    with ctx.span("warmup"):
+        # the prefill is keyed by the prompt length and the decode
+        # program by token_chunk: two chunks compile all a window runs
+        dec.generate(prompts, 2 * tr["token_chunk"] + 1, prefill=True,
+                     token_chunk=tr["token_chunk"],
+                     on_tokens=lambda *a, **k: None)
+    return state
+
+
+def _counts() -> dict:
+    from defer_tpu.obs import REGISTRY
+    return {name: REGISTRY.counter(name).n
+            for name in MOE_COUNTERS + (UPDATES,)}
+
+
+def measure(state, seconds, ctx):
+    from chipbench.roofline_hybrid_moe import layer_kinds
+    from defer_tpu.obs import REGISTRY
+
+    tr, args = state["traffic"], state["config"]["model_args"]
+    before = _counts()
+    out = base.measure(state, seconds, ctx)
+    done = {name: n - before[name] for name, n in _counts().items()}
+    mamba, _ = layer_kinds(args)
+    counters = out["counters"]
+    counters.update(done, mamba2_layers=mamba,
+                    prefill_tokens=tr["batch"] * tr["prompt_len"],
+                    max_len=tr["max_len"])
+    counters.update({name.split(".", 1)[1].replace(".", "_"):
+                     float(REGISTRY.gauge(name).value) for name in GAUGES})
+    if "dec" in state:
+        # sequences a piece of the prefill holds (a scan's call is a
+        # piece's)
+        counters["prefill_piece_rows"] = state["dec"]._prefill_rows(
+            tr["prompt_len"])
+    lo, hi = args["experts_held"] or (0, args["num_experts"])
+    # one (layer, step) routes rows x experts_per_tok choices
+    layer_steps = done["decode.moe.assignments"] / (
+        tr["batch"] * args["experts_per_tok"])
+    if layer_steps:
+        hit = done["decode.moe.experts_hit"] / layer_steps
+        counters["experts_hit_share"] = hit / (hi - lo)
+        counters["held_share"] = (done["decode.moe.held_assignments"]
+                                  / done["decode.moe.assignments"])
+        out["notes"].append(
+            f"held experts hit a layer a step {hit:.2f} of {hi - lo}; "
+            f"{counters['held_share']:.4f} of the assignments fell to "
+            f"them ({(hi - lo) / args['num_experts']:.4f} expected); "
+            f"largest group "
+            f"{done['decode.moe.load_max'] / layer_steps:.2f} rows "
+            f"({layer_steps:.0f} layer-steps); {UPDATES} {done[UPDATES]} = "
+            f"{tr['batch'] * mamba} (sequences x Mamba layers) x "
+            f"{done[UPDATES] / (tr['batch'] * mamba):.2f} valid decode "
+            "steps")
+    return out
+
+
+#: the expert half is probed on the reference's stream brought to this
+#: root mean square: its norm undoes the scale, and the half's output,
+#: 0.22 of a few tenths, is then not lost in the rounding of a stream
+#: of size 1 to bfloat16 on the way out
+HALF_RMS = 1.0 / 64
+
+
+def rms_err(got, want) -> float:
+    """Root mean square of ``got - want`` over that of ``want``: where a
+    few tokens' tenth choice turns at a near-tie (a whole expert's
+    output off for that token, which ``rel_err``'s largest difference
+    would read), this reads what all tokens share."""
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.sqrt(np.square(got - want).mean()
+                         / max(float(np.square(want).mean()), 1e-30)))
+
+
+def router_agreement(graph, params, seqs, ref_cfg: dict, **control) -> tuple:
+    """``(shares, errors)``, an entry a layer.  ``shares``: the share of
+    the plain reference's expert choices on ``seqs`` [n, t] that the
+    program's blocks make too — the program's own full-sequence forward
+    (a block's ``apply``, what its prefill runs) in the type of
+    ``params``, a layer's weights on the device at a time, against the
+    reference's float32 forward of the same tokens (choices over all
+    the experts the router names, held or not).  ``errors``: ``rel_err``
+    of the program's expert half of that layer (``expert_half``: the
+    router, the held experts under the full choice's weights, the
+    shared expert) against the reference's, both fed the reference's
+    stream into the layer (scaled to :data:`HALF_RMS`), as :func:`rms_err` — the choices
+    being the same under any monotone rule, this is what sees the
+    *weights*.  ``control`` is the controls' (``renormalise_over_all``:
+    the reference's weights a softmax over all experts)."""
+    import jax
+    import jax.numpy as jnp
+
+    ref = importlib.import_module(ref_cfg["module"])
+    args = ref._args(ref_cfg["args"])
+    args.pop("logits_scaling")
+    types = args.pop("layer_types")
+    multiplier = args.pop("embedding_multiplier")
+    nodes = graph.nodes
+    n_experts = nodes["block_0"].op.num_experts
+    forward, halves = {}, {}
+
+    def layer(name, p, x):
+        op = nodes[name].op
+        if op not in forward:       # one program a kind of layer
+
+            @jax.jit
+            def fn(p, x, op=op):
+                sown: dict = {}
+                y = op.apply(p, x, sow=sown)
+                return y, sown["moe.chosen"].reshape(x.shape[:2] + (-1,))
+
+            forward[op] = fn
+        return forward[op](p, x)
+
+    def half(name, p, x32):
+        """What the program's expert half adds to the stream ``x32``,
+        before the residual multiplier."""
+        op = nodes[name].op
+        if op not in halves:
+
+            @jax.jit
+            def fn(p, x32, op=op):
+                dtype = p["router"]["w"].dtype
+                flat = x32.reshape(-1, x32.shape[-1])
+                out = op.expert_half(p, flat, dtype).astype(jnp.float32)
+                return ((out - flat) / op.residual_multiplier
+                        ).reshape(x32.shape)
+
+            halves[op] = fn
+        return halves[op](p, x32)
+
+    def chose(ids):                                   # -> [n, t, E] bool
+        hot = np.zeros(ids.shape[:2] + (n_experts,), bool)
+        np.put_along_axis(hot, ids, True, -1)
+        return hot
+
+    x = jax.jit(nodes["embeddings"].op.apply)(params["embeddings"], seqs)
+    want_x = ref._embed(params["embeddings"], jnp.asarray(seqs, jnp.int32),
+                        multiplier=multiplier)
+    shares, errors = [], []
+    for i, kind in enumerate(types):
+        name = f"block_{i}"
+        probe = want_x * (HALF_RMS / jnp.sqrt(jnp.square(want_x).mean()))
+        want_half, _ = ref.expert_half(
+            params[name], probe, top_k=args["top_k"], held=args["held"],
+            eps=args["eps"], **control)
+        errors.append(rms_err(half(name, params[name], probe), want_half))
+        want_x, _, want = ref.block(params[name], want_x, kind=kind, **args,
+                                    **control)
+        x, got = layer(name, params[name], x)
+        want = np.asarray(want)
+        both = chose(np.asarray(got)) & chose(want)
+        shares.append(float(both.sum() / want.size))
+    return shares, errors
+
+
+def decoded_states(dec, prompts, n: int, tr: dict, heads: int) -> tuple:
+    """One generation outside the window, the prefill and
+    ``STATE_STEPS`` decode steps (fewer where the traffic's generations
+    are shorter): ``(ids, states)``, the first ``n`` sequences' prompt
+    and the tokens fed back (all the state has absorbed: the last token
+    handed out was never an input), and what the ring was left with for
+    them, a layer an entry: ``(H [n, heads, head_dim, N], window [n,
+    d_conv - 1, E + 2 N])`` on the host in the layout-free form, None
+    for a layer that keeps no state-space state."""
+    from defer_tpu.ops.ssm import dense
+
+    out = dec.generate(prompts, min(STATE_STEPS + 1, tr["new_tokens"]),
+                       prefill=True, token_chunk=tr["token_chunk"])
+    states = []
+    for l, kind in enumerate(dec.memory):
+        # [stage, group, ...]: one chip's one group holds every
+        # sequence, and its blocks are the model's
+        states.append(dense(np.asarray(dec.state["h"][l][0, 0, :n]),
+                            np.asarray(dec.state["conv"][l][0, 0, :, :n]),
+                            heads)
+                      if kind == "ssm" else None)
+    dec.state = None
+    return np.asarray(out)[:n, :-1], states
+
+
+def state_errors(got: list, params, ids, ref_cfg: dict, **control) -> dict:
+    """For each Mamba layer (by its index), how far the program's state
+    is from the plain reference's over the same tokens: the larger of
+    ``H``'s and the window's ``rel_err``.  ``control`` is the controls':
+    ``state_dtype`` rounds the reference's own state to that type after
+    every position, ``window_shift`` hands back the window that many
+    positions earlier."""
+    ref = importlib.import_module(ref_cfg["module"])
+    want = ref.states(params, ids, **ref_cfg["args"], **control)
+    return {l: max(rel_err(g[0], np.asarray(w[0])),
+                   rel_err(g[1], np.asarray(w[1])))
+            for l, (g, w) in enumerate(zip(got, want)) if g is not None}
+
+
+def long_memory_error(fmt, seed: int, ref, *, held=None,
+                      steps: int = PROBE_STEPS, sequences: int = 2) -> dict:
+    """The program's format ``fmt`` (its buffers, its two kernels)
+    through a prefill of ``steps`` positions and ``steps`` decode steps
+    of ``sequences`` seeded float32 sequences whose ``dt A`` lies in
+    ``[-PROBE_DECAY, 0)``, from an empty memory, against the
+    reference's recurrence (the outputs: ``y_prefill``, ``y_decode``)
+    and explicit sum (the last state: ``H``), as ``rel_err``.  ``B``
+    and ``C`` have unit mean square.  ``held`` is the control: a type
+    the state is rounded to after the prefill and after every step (by
+    ``reduce_precision``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from defer_tpu.ops.ssm import dense
+
+    nh, p, n = fmt.heads, fmt.head_dim, fmt.states
+    e, b, t = fmt.channels, sequences, 2 * steps
+    rng = np.random.default_rng(seed)
+
+    def normed(*shape):
+        a = rng.standard_normal(shape, dtype=np.float32)
+        return a / np.sqrt((a * a).mean(-1, keepdims=True))
+
+    a_vec = -rng.uniform(0.25, 1.0, (nh,)).astype(np.float32)
+    dt = rng.uniform(0.0, PROBE_DECAY, (b, t, nh)).astype(np.float32)
+    x = rng.standard_normal((b, t, e), dtype=np.float32)
+    dt, x, bm, cm, a_vec = (jnp.asarray(v) for v in (
+        dt, x, normed(b, t, n), normed(b, t, n), a_vec))
+
+    def rounded(layer):
+        if held is None:
+            return layer
+        kind = jnp.finfo(held)
+        return dict(layer, h=jax.lax.reduce_precision(
+            layer["h"], kind.nexp, kind.nmant))
+
+    def run(dt, x, bm, cm, a_vec):
+        layer = fmt.layer(fmt.zeros(b, 1), 0)
+        y0, layer = fmt.prefill(dt[:, :steps], x[:, :steps], bm[:, :steps],
+                                cm[:, :steps], a_vec, layer,
+                                fmt.prefill_slot(True, 0))
+
+        def step(layer, xs):
+            y, layer = fmt.step(*xs, a_vec, layer, group=0)
+            return rounded(layer), y
+
+        layer, ys = jax.lax.scan(step, rounded(layer), tuple(
+            v[:, steps:].swapaxes(0, 1) for v in (dt, x, bm, cm)))
+        return y0, ys.swapaxes(0, 1), layer["h"]
+
+    y0, y1, h = jax.jit(run)(dt, x, bm, cm, a_vec)
+    heads = x.reshape(b, t, nh, p)
+    with jax.default_matmul_precision("highest"):
+        want_y, _ = jax.jit(ref.selective_scan)(dt, heads, bm, cm, a_vec)
+        want_h = jax.jit(ref.explicit_state)(dt, heads, bm, a_vec)
+    # behind the ring's group axis, where the format has one
+    h = np.asarray(h if fmt.groups is None else h[0])
+    got_h, _ = dense(h, np.zeros((fmt.d_conv - 1, b, e), np.float32), nh)
+    want_y = np.asarray(want_y).reshape(b, t, e)
+    return {"y_prefill": rel_err(y0, want_y[:, :steps]),
+            "y_decode": rel_err(y1, want_y[:, steps:]),
+            "H": rel_err(got_h, np.asarray(want_h))}
+
+
+def check(state, ctx):
+    tr, cfg = state["traffic"], state["config"]
+    plen, n = tr["prompt_len"], tr["check_sequences"]
+    dec = state.pop("dec", None)
+    fmt = next(f for f, kind in zip(dec.state_formats, dec.memory)
+               if kind == "ssm")
+    ids, got = decoded_states(dec, state["prompts"], n, tr, fmt.heads)
+    # the reference upcasts a layer at a time beside whatever the chip
+    # still holds: let the decoder's weights and state go first
+    del dec
+    gc.collect()
+    # the first ``check_tokens`` generated tokens are judged: the
+    # reference runs every position of every judged sequence in float32
+    state["sample"] = state["sample"][:, :plen + tr["check_tokens"]]
+    ok, detail = base.check(state, ctx)
+    if "worst_logit_gap_share" not in detail:
+        return ok, detail
+    detail["tolerance"] = GAP_TOL               # judged at this file's limits
+    shares, weights = router_agreement(
+        state["graph"], state["params"], state["sample"][:n, :-1],
+        cfg["reference"])
+    errors = state_errors(got, state["params"], ids, cfg["reference"])
+    memory = long_memory_error(
+        fmt, ctx.seed, importlib.import_module(cfg["reference"]["module"]))
+    first = errors[min(errors)]
+    detail.update(router_agreement_share=min(shares),
+                  router_agreement_by_layer=[round(s, 5) for s in shares],
+                  router_tolerance=ROUTER_TOL,
+                  expert_half_rms_err=max(weights),
+                  expert_half_rms_err_by_layer=[
+                      round(e, 5) for e in weights],
+                  expert_half_tolerance=WEIGHTS_TOL,
+                  state_rel_err=max(errors.values()),
+                  state_rel_err_by_layer={
+                      l: round(e, 5) for l, e in errors.items()},
+                  state_tolerance=STATE_TOL,
+                  first_layer_state_tolerance=STATE_TOL_FIRST,
+                  long_memory_rel_err=max(memory.values()),
+                  long_memory_rel_err_by_part=memory,
+                  long_memory_tolerance=MEMORY_TOL)
+    return (detail["worst_logit_gap_share"] <= GAP_TOL
+            and min(shares) >= ROUTER_TOL
+            and max(weights) <= WEIGHTS_TOL
+            and max(errors.values()) <= STATE_TOL
+            and first <= STATE_TOL_FIRST
+            and max(memory.values()) <= MEMORY_TOL), detail
+
+
+close = base.close

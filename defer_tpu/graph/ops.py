@@ -731,16 +731,25 @@ def route_top_k(logits, k: int, scoring: str = "softmax"):
     ``(expert ids [..., k], their weights [..., k])``.  ``scoring`` is
     the family's rule, named by the block that calls (never a user's
     flag): ``"softmax"`` — probabilities over all experts, used as the
-    softmax gave them (not renormalised); ``"sigmoid"`` — an
-    independent score an expert, renormalised over the chosen ``k``."""
+    softmax gave them, not renormalised (OLMoE, ``models/olmoe.py``);
+    ``"sigmoid"`` — an independent score an expert, renormalised over
+    the chosen ``k`` (command-a-plus, ``models/cohere_moe.py``);
+    ``"softmax_of_chosen"`` — the ``k`` largest logits, then a softmax
+    over those ``k`` values alone (Granite 4.0-H,
+    ``models/granite_hybrid.py``)."""
+    logits = logits.astype(jnp.float32)
     if scoring == "softmax":
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        probs = jax.nn.softmax(logits, axis=-1)
         p, eid = lax.top_k(probs, k)
         return eid, p
+    if scoring == "softmax_of_chosen":
+        top, eid = lax.top_k(logits, k)
+        return eid, jax.nn.softmax(top, axis=-1)
     if scoring != "sigmoid":
         raise ValueError(
-            f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
-    p, eid = lax.top_k(jax.nn.sigmoid(logits.astype(jnp.float32)), k)
+            "scoring must be 'softmax', 'sigmoid' or 'softmax_of_chosen', "
+            f"got {scoring!r}")
+    p, eid = lax.top_k(jax.nn.sigmoid(logits), k)
     return eid, p / jnp.sum(p, axis=-1, keepdims=True)
 
 

@@ -21,7 +21,8 @@ it, whatever its family.
   instead: it hands ``q, k, v`` and a log-decay to the state's format
   (``ops/retention.py``) and takes the layer's output back.  Its
   sibling :class:`StateSpaceBlock` keeps a convolution window and a
-  state-space state (``ops/ssm.py``) and has no heads at all.  None
+  state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's) and has no
+  attention heads at all.  None
   knows an axis order, key or type of what the format holds; which kind
   a block keeps is the class it is (``memory``), and the holder asks
   every block for its format (:meth:`DecoderBlock.memory_format`).
@@ -234,24 +235,41 @@ class RetentionBlock(DecoderBlock):
 
 
 class StateSpaceBlock(DecoderBlock):
-    """A decoder block whose per-sequence memory is a selective
-    state-space mixer's (``ops/ssm.py``): the last inputs of a causal
-    convolution and a recurrent state ``H``, both of fixed size, the
-    state read *and rewritten whole* every step.  It has no heads.  In
-    place of ``apply_with_kv`` / ``decode_qkv`` such a block has
-    ``channels``, ``states`` and ``d_conv`` (the state's sizes) and
+    """A decoder block whose per-sequence memory is a state-space
+    mixer's (``ops/ssm.py``): the last inputs of a causal convolution
+    and a recurrent state ``H``, both of fixed size, the state read
+    *and rewritten whole* every step.  One contract serves both mixers
+    the package has — Mamba-1, a decay a channel and a state, and
+    Mamba-2, heads of channels under one decay each, whose convolution
+    also runs over ``B`` and ``C`` — and assumes neither's shapes: the
+    block's fields say which it is, and what passes between the block
+    and the format has whatever shape that format takes.  In place of
+    ``apply_with_kv`` / ``decode_qkv`` such a block has
 
-    * ``mixer_inputs(params, x [..., d]) -> (u, z)``: the convolution's
-      input and the gate, each [..., E];
+    * ``channels`` (``E``), ``states`` (``N``) and ``d_conv``, the
+      state's sizes; ``heads``: absent or None where every channel and
+      state has a decay of its own (``ops/ssm.py::SsmFormat``), else the number
+      of heads the channels form, a scalar decay each, over a window
+      of ``E + 2 N`` columns (``SsdFormat``; such a block also names
+      ``chunk``, the positions of one chunk of its prefill);
+      ``mixer_width``, the columns of the input projection, the widest
+      activation a token has in the layer;
+    * ``mixer_inputs(params, x [..., d]) -> (u, rest)``: the
+      convolution's input [..., window's width] and whatever else the
+      input projection gives (the gate; Mamba-2's raw step), which the
+      block gets back untouched;
     * ``mixer_conv(params, taps) -> c``: the convolution over its
       ``d_conv`` taps (``ops/ssm.py::causal_conv`` under the block's
-      weights), [..., E];
-    * ``mixer_selection(params, c [..., E]) -> (dt, b, c_read, a)``: the
-      step [..., E] and the two projections [..., N] of the selection,
-      and the matrix ``A`` [N, E];
-    * ``decode_finish(params, x [T, d], y [T, E], c, z, sow=None)``: the
-      rest of the block after the recurrence's output ``y`` (the skip
-      term, the gate, the output projection, the MLP).
+      weights), as wide as the window;
+    * ``mixer_selection(params, c, rest) -> (dt, xs, b, c_read, a)``:
+      what the recurrence takes, in the format's shapes — the step
+      (``[..., E]``, or ``[..., heads]``), the channels ``xs [..., E]``
+      it is fed, the two projections ``[..., N]``, and ``A`` (``[N,
+      E]``, or ``[heads]``);
+    * ``decode_finish(params, x [T, d], y [T, E], xs, rest,
+      sow=None)``: the rest of the block after the recurrence's output
+      ``y`` (the skip term, the gate, the output projection, the
+      second half).
 
     ``decode_stats`` and ``stage_arg_keys`` are :class:`DecoderBlock`'s.
     No serving engine takes such a block yet (``serve/engine.py``
@@ -265,20 +283,26 @@ class StateSpaceBlock(DecoderBlock):
         return None
 
     def widest(self, d_model: int) -> int:
-        """The input projection's ``[u, z]``."""
-        return max(d_model, 2 * self.channels)
+        """The input projection's columns."""
+        return max(d_model, self.mixer_width)
 
     def memory_format(self, d_model: int, positions: int, dtype, *,
                       quantized: bool = False, groups: int | None = None):
         """A state's size depends neither on the stream's width nor on
-        ``positions``; the window is of type ``dtype``, ``H`` float32."""
+        ``positions``; the window is of type ``dtype``, ``H`` float32.
+        Which of ``ops/ssm.py``'s two shapes it has is the block's
+        ``heads``."""
         del d_model, positions
         if quantized:
             raise ValueError(
                 "kv_cache='int8' quantizes cached key and value rows; "
                 "these blocks keep a state-space state, which has none")
         from ..ops import ssm
-        return ssm.SsmFormat(self.channels, self.states, self.d_conv, dtype,
+        heads = getattr(self, "heads", None)
+        if heads is None:
+            return ssm.SsmFormat(self.channels, self.states, self.d_conv,
+                                 dtype, groups=groups)
+        return ssm.SsdFormat(heads, self.channels // heads, self.states, self.d_conv, self.chunk, dtype,
                              groups=groups)
 
     def decode(self, params, x, state, pos, fmt, slot=True, group=None,
@@ -288,29 +312,32 @@ class StateSpaceBlock(DecoderBlock):
         unread (it says whether the step is real: a bubble leaves the
         window and ``H`` as they are).  The position is not read."""
         del pos
-        u, z = self.mixer_inputs(params, x)
+        u, rest = self.mixer_inputs(params, x)
         taps, state = fmt.shift(u, state, group=group, valid=slot)
         c = self.mixer_conv(params, taps)
-        dt, b, c_read, a = self.mixer_selection(params, c)
-        y, state = fmt.step(dt, c, b, c_read, a, state, group=group,
+        dt, xs, b, c_read, a = self.mixer_selection(params, c, rest)
+        y, state = fmt.step(dt, xs, b, c_read, a, state, group=group,
                             valid=slot)
-        return self.decode_finish(params, x, y, c, z, sow=sow), state
+        return self.decode_finish(params, x, y, xs, rest, sow=sow), state
 
-    def prefill(self, params, x, state, fmt, slot=(None, True)):
+    def prefill(self, params, x, state, fmt, slot=(None, True), sow=None):
         """A whole prompt ``x`` [b, t, d] through the layer from an
         empty memory; the window and the state after its last position
         are left where ``slot`` (``fmt.prefill_slot``'s, handed on
-        unread) says."""
+        unread) says.  A dict ``sow`` is filled as :meth:`decode`
+        fills it, over all ``b * t`` rows."""
         b, t, d = x.shape
-        u, z = self.mixer_inputs(params, x)
+        u, rest = self.mixer_inputs(params, x)
         taps, state = fmt.prefill_shift(u, state, slot)
         c = self.mixer_conv(params, taps)
-        dt, b_in, c_read, a = self.mixer_selection(params, c)
-        y, state = fmt.prefill(dt, c, b_in, c_read, a, state, slot)
-        e = y.shape[-1]
-        out = self.decode_finish(
-            params, x.reshape(b * t, d), y.reshape(b * t, e),
-            c.reshape(b * t, e), z.reshape(b * t, e))
+        dt, xs, b_in, c_read, a = self.mixer_selection(params, c, rest)
+        y, state = fmt.prefill(dt, xs, b_in, c_read, a, state, slot)
+
+        def rows(v):
+            return v.reshape((b * t,) + v.shape[2:])
+
+        out = self.decode_finish(params, rows(x), rows(y), rows(xs),
+                                 jax.tree.map(rows, rest), sow=sow)
         return out.reshape(b, t, d), state
 
 

@@ -127,8 +127,14 @@ class JambaMambaBlock(StateSpaceBlock, Op):
 
     # -- the mixer's pieces, around the state's format -----------------------
 
+    @property
+    def mixer_width(self) -> int:
+        """The input projection's ``[u, z]``."""
+        return 2 * self.channels
+
     def mixer_inputs(self, params, x):
-        """``u`` and ``z`` [..., E] of the stream ``x`` [..., d]."""
+        """``u`` and the gate ``z`` [..., E] of the stream ``x`` [...,
+        d]."""
         p = _cast({nm: params[nm] for nm in ("ln1", "in_proj")}, x.dtype)
         uz = rms_norm(x, p["ln1"]["scale"], self.rms_eps) @ p["in_proj"]["w"]
         return uz[..., :self.channels], uz[..., self.channels:]
@@ -137,11 +143,13 @@ class JambaMambaBlock(StateSpaceBlock, Op):
         return ssm.causal_conv(taps, params["conv"]["w"],
                                params["conv"]["b"])
 
-    def mixer_selection(self, params, c):
+    def mixer_selection(self, params, c, z):
         """The step ``dt`` [..., E] and the projections ``B``, ``C``
-        [..., N] of ``c`` [..., E], float32, and ``A`` [N, E]: the
-        products leave the matrix unit in float32, the three norms and
-        the softplus run in it."""
+        [..., N] of ``c`` [..., E], float32, ``c`` itself as what the
+        recurrence is fed, and ``A`` [N, E]: the products leave the
+        matrix unit in float32, the three norms and the softplus run
+        in it.  The gate is not read."""
+        del z
         f32, p = jnp.float32, params
         r, n = self.dt_rank, self.states
         sel = jnp.dot(c, p["x_proj"]["w"].astype(c.dtype),
@@ -154,7 +162,7 @@ class JambaMambaBlock(StateSpaceBlock, Op):
             jnp.dot(low.astype(c.dtype), p["dt_proj"]["w"].astype(c.dtype),
                     preferred_element_type=f32)
             + p["dt_proj"]["b"].astype(f32))
-        return dt, b, c_read, -jnp.exp(p["ssm"]["a_log"].astype(f32))
+        return dt, c, b, c_read, -jnp.exp(p["ssm"]["a_log"].astype(f32))
 
     def decode_finish(self, params, x, y, c, z, sow=None):
         """The rest of a layer after the recurrence: ``x`` [T, d] the

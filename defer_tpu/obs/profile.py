@@ -108,8 +108,10 @@ DECODE_DISPATCH_PHASES = ("upload", "launch")
 #: statistics (``DecoderBlock.decode_stats``; today the routed experts'
 #: ``decode.moe.*`` counters, the retention blocks'
 #: ``decode.retention.updates`` and the state-space blocks'
-#: ``decode.ssm.updates``): their sums, which came to the host a
-#: chunk at a time with the chunk's ids, go to the counters
+#: ``decode.ssm.updates``; a graph whose blocks both route and keep a
+#: state, ``models/granite_hybrid.py``'s, sows all five): their sums,
+#: which came to the host a chunk at a time with the chunk's ids, go to
+#: the counters
 DECODE_STATS_PHASES = ("moe_stats",)
 
 #: the front door's per-request phase on the client's reader thread

@@ -717,6 +717,9 @@ class KVCacheFormat(LayeredState):
         else:
             at = slot if isinstance(slot, tuple) else (slot, 0)
         b, t = k.shape[:2]
+        # plain rows of a piece of a group (the joined rows' constraint
+        # below serves whole groups and pieces alike)
+        pieces = not self.joined and isinstance(slot, tuple)
         w, shift = self.window, 0
         if w is not None and t > w:
             # position t - w + i lies at row (t - w + i) % w
@@ -732,6 +735,17 @@ class KVCacheFormat(LayeredState):
             shape = (b, t, self.kv_heads, self.head_dim)
             k = k.reshape(shape).transpose(0, 2, 1, 3)
             v = v.reshape(shape).transpose(0, 2, 1, 3)
+            if pieces:
+                # a piece of a group, written inside the prefill's loop
+                # over pieces: positions-major, as the product made the
+                # rows and as the chip keeps a buffer of few heads (8
+                # heads of 128 are one tile, so its own layout of
+                # ``[.., 8, rows, 128]`` has the rows outside the
+                # heads).  Head-major rows make the loop hold the
+                # *buffer* head-major and convert all of it on the way
+                # in and out: two copies of every buffer a prefill
+                as_made = Layout(major_to_minor=(0, 2, 1, 3))
+                k, v = (with_layout_constraint(a, as_made) for a in (k, v))
         if shift:
             k, v = (jnp.roll(a, shift, axis=1 if self.joined else 2)
                     for a in (k, v))

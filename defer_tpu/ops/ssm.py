@@ -1,6 +1,7 @@
 """The state-space state's format: the one module that knows how a
-Mamba-1 layer's per-sequence memory is kept on the device, updated and
-read.
+state-space layer's per-sequence memory is kept on the device, updated
+and read — of two shapes, Mamba-1's (:class:`SsmFormat`) and Mamba-2's
+(:class:`SsdFormat`, below), which share the window and the layout.
 
 A selective state-space mixer (Gu & Dao, arXiv:2312.00752) of ``E``
 channels and ``N`` states keeps two things a sequence, whatever its
@@ -47,6 +48,37 @@ layers is a tuple of buffers a key, never stacked (``ops/layered.py``).
   of a prompt is never made.
 * :func:`step_reference` / :func:`prefill_reference` — the same in plain
   ``jnp``, the tests' oracle.
+
+**The second shape: a state of heads** (Mamba-2, Dao & Gu,
+arXiv:2405.21060; :class:`SsdFormat`).  The ``E`` channels are ``heads``
+heads of ``head_dim``; the decay is **one scalar a head** (``dt [heads]``
+a position, ``A [heads]``), ``B`` and ``C [N]`` are shared by every head
+(one group), and the convolution runs over the channels, ``B`` and ``C``
+together, so its window is ``E + 2 N`` wide, wider than the state:
+
+    [x(t), B(t), C(t)] = c(t)                       (the window's output)
+    H(t)[h] = exp(dt(t)[h] A[h]) * H(t-1)[h] + dt(t)[h] * x(t)[h] (x) B(t)
+    y(t)[h] = H(t)[h] C(t)
+
+The buffers are the first shape's — ``h [batch, N, E]`` float32, the
+states on the sublanes and every head's channels side by side on the
+lanes (128 x 8192: whole tiles; ``B`` and ``C`` being shared, a product
+over the states serves all heads at once), ``conv [d_conv - 1, batch, E
++ 2 N]`` — and so are the window's three calls, the scratch-free
+bubble and the layered state.  What differs is the recurrence:
+
+* :meth:`SsdFormat.step` — the aliased Pallas kernel :func:`ssd_step`:
+  a grid over blocks of sequences *and* blocks of channels (a
+  sequence's 4.2 MB does not cross VMEM whole), the decay a row a
+  sequence (one exponential a head, taken before the call), ``y`` a sum
+  over the sublanes.
+* :meth:`SsdFormat.prefill` — the Pallas kernel :func:`ssd_scan`: the
+  chunked matrix form.  Within a chunk of ``chunk`` positions ``Y =
+  (L o C B^T) (dt x)`` with ``L[i, j] = exp(sum_{j<k<=i} dt_k A)`` a
+  head, on the matrix unit; between chunks the ``[N, E]`` state is
+  carried in VMEM; the ``[t, E, N]`` tensor is never made.
+* :func:`ssd_step_reference` / :func:`ssd_prefill_reference` — plain
+  ``jnp``, position by position.
 """
 
 from __future__ import annotations
@@ -238,6 +270,220 @@ def ssm_scan(dt, dx, b, c, a):
     return (y[:, :t] if pad else y), last
 
 
+# -- the second shape's step kernel -------------------------------------------
+
+#: the most bytes of ``H`` one grid step of :func:`ssd_step` takes in
+#: (and as many out): 8 sequences x 128 states x 1024 channels
+_SSD_STEP_BYTES = 4 << 20
+#: channels whose ``[N, .]`` tile a sequence's update holds at a time
+_SSD_LANES = 256
+
+
+def _ssd_step_kernel(group_ref, decay_ref, dx_ref, b_ref, c_ref, h_ref,
+                     y_ref, out_ref, *, block: int):
+    """A block of sequences and of channels: ``h_ref`` / ``out_ref``
+    ``[1, bs, N, cb]``, ``decay_ref`` / ``dx_ref`` / ``y_ref`` ``[bs,
+    cb]`` (a head's decay on each of its channels), ``b_ref`` /
+    ``c_ref`` ``[bs, N, 1]`` (a column a sequence, spread over the
+    lanes here).  ``block`` channels at a time, each sequence's ``[N,
+    block]`` tile once through the registers."""
+    del group_ref                       # the index map reads it
+    bs, cb = decay_ref.shape
+    for lo in range(0, cb, block):
+        cols = slice(lo, lo + block)
+        ys = []
+        for i in range(bs):
+            h = decay_ref[i:i + 1, cols] * h_ref[0, i, :, cols] \
+                + dx_ref[i:i + 1, cols] * b_ref[i]
+            out_ref[0, i, :, cols] = h
+            ys.append(jnp.sum(h * c_ref[i], axis=0, keepdims=True))
+        y_ref[:, cols] = jnp.concatenate(ys, axis=0)
+
+
+@jax.jit
+def ssd_step(decay, dx, b, c, state, group):
+    """``H <- decay * H + dx (x) B`` in place and ``y = H C`` of the new
+    state, for a state of heads laid ``[N, E]``.  ``state`` [groups,
+    batch, N, E] f32, of which group ``group`` [1] int32; ``decay`` /
+    ``dx`` [batch, E] f32 (a head's ``exp(dt A)`` on each of its
+    channels, and the step times the input); ``b`` / ``c`` [batch, N]
+    f32.  Returns ``(y [batch, E] f32, state)``; the state aliases its
+    argument: donate it.
+
+    The grid runs over blocks of sequences and, inside, blocks of
+    channels: at 128 states x 8192 channels a sequence is 4.2 MB, so a
+    grid step takes 8 sequences' ``[128, 1024]`` (4 MB in, 4 MB out)
+    and every value of ``H`` crosses VMEM once.  There is no
+    exponential in here: a head has one, taken before the call."""
+    groups, batch, n, e = state.shape
+    bs = _SEQUENCES if batch % _SEQUENCES == 0 else batch
+    fit = max(128, _SSD_STEP_BYTES // (4 * bs * n))
+    cb = next((k for k in range(min(e, fit), 0, -128)
+               if k % 128 == 0 and e % k == 0), e)
+    block = next((k for k in (_SSD_LANES, 128) if cb % k == 0), cb)
+    group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
+    rows = pl.BlockSpec((bs, cb), lambda i, j, group_ref: (i, j))
+    cols = pl.BlockSpec((bs, n, 1), lambda i, j, group_ref: (i, 0, 0))
+    big = pl.BlockSpec((1, bs, n, cb),
+                       lambda i, j, group_ref: (group_ref[0], i, 0, j))
+    y, out = pl.pallas_call(
+        functools.partial(_ssd_step_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(batch // bs, e // cb),
+            in_specs=[rows, rows, cols, cols, big],
+            out_specs=[rows, big]),
+        out_shape=[jax.ShapeDtypeStruct((batch, e), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the states' block in and out, each double-buffered, the
+            # columns (a lane row a value) and the rows
+            vmem_limit_bytes=4 * bs * n * cb * 4 + 4 * bs * n * 512
+            + 6 * bs * cb * 4 + (8 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="ssd_step",
+    )(group, decay, dx, b[..., None], c[..., None], state)
+    return y, out
+
+
+# -- the second shape's scan kernel ---------------------------------------------
+
+#: channels of one grid step of :func:`ssd_scan` (16 heads of 64)
+_SSD_SCAN_CHANNELS = 1024
+
+
+def _ssd_scan_kernel(cum_ref, row_ref, col_ref, dx_ref, b_ref, bt_ref,
+                     c_ref, y_ref, last_ref, h_ref, *, head_dim: int,
+                     tile: int):
+    """One sequence, one block of channels, one chunk of ``L``
+    positions.  ``cum_ref`` ``[1, L, heads]`` is the running sum of ``dt
+    A`` inside the chunk, every head's; ``row_ref`` ``[1, 1, hb, L]``
+    and ``col_ref`` ``[1, 1, hb, L, 1]`` the same for this block's heads
+    as rows and as columns; ``dx_ref`` / ``y_ref`` ``[1, L, eb]``;
+    ``b_ref`` / ``c_ref`` ``[1, L, N]``, ``bt_ref`` ``[1, 1, N, L]``;
+    ``h_ref`` ``[N, eb]`` carries the state from chunk to chunk,
+    ``last_ref`` ``[1, N, eb]`` takes it after the last."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    at_e, at_t = pl.program_id(1), pl.program_id(2)
+    length, eb = dx_ref.shape[1:]
+    heads = cum_ref.shape[2]
+
+    @pl.when(at_t == 0)
+    def _empty():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+    def dot(a, b):
+        return jnp.dot(a, b, precision=hi, preferred_element_type=f32)
+
+    cum = cum_ref[0]                                   # [L, heads]
+    # a head's value on each of its channels: a product with 0 / 1
+    # (exact at this precision) spreads [L, heads] over [L, eb]
+    spread = (lax.broadcasted_iota(jnp.int32, (heads, eb), 0)
+              == (lax.broadcasted_iota(jnp.int32, (heads, eb), 1)
+                  + at_e * eb) // head_dim).astype(f32)
+    since = dot(jnp.exp(cum), spread)          # decay since the chunk began
+    until = dot(jnp.exp(cum[length - 1:length] - cum), spread)  # to its end
+    x, b, c = dx_ref[0], b_ref[0], c_ref[0]
+    h = h_ref[...]
+    off = dot(c, h) * since                            # [L, eb]
+    scores = dot(c, bt_ref[0, 0])                      # C B^T [L, L]
+    causal = lax.broadcasted_iota(jnp.int32, (length, length), 0) \
+        >= lax.broadcasted_iota(jnp.int32, (length, length), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (length, tile), 1)
+    per_tile = tile // head_dim
+    for k in range(eb // tile):
+        cols = slice(k * tile, (k + 1) * tile)
+        y = None
+        for j in range(per_tile):
+            head = k * per_tile + j
+            decay = jnp.exp(jnp.where(
+                causal, col_ref[0, 0, head] - row_ref[0, 0, head:head + 1],
+                -jnp.inf))
+            part = dot(scores * decay, x[:, cols])
+            y = part if y is None else jnp.where(
+                lane < j * head_dim, y, part)
+        y_ref[0, :, cols] = y + off[:, cols]
+    h = since[length - 1:length] * h + dot(bt_ref[0, 0], until * x)
+    h_ref[...] = h
+
+    @pl.when(at_t == pl.num_programs(2) - 1)
+    def _last():
+        last_ref[0] = h
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan(dt, dx, b, c, a, *, chunk: int):
+    """The recurrence of :func:`ssd_step` over whole prompts from an
+    empty memory, in the chunked matrix form: ``dt`` [batch, t, heads]
+    f32, ``dx`` [batch, t, E] f32 (the step times the input, a head's
+    step on each of its channels), ``b`` / ``c`` [batch, t, N] f32,
+    ``a`` [heads] f32 -> ``(y [batch, t, E] f32, H [batch, N, E] f32
+    after the last position)``.
+
+    The grid is (sequence, block of channels, chunk), the chunks
+    innermost and in order.  Inside a chunk of ``L`` positions a head's
+    outputs are ``(decay o C B^T) dx`` with ``decay[i, j] = exp(sum_{j <
+    k <= i} dt_k A)`` for ``j <= i`` — ``[L, L]`` products on the matrix
+    unit, ``C B^T`` shared by the heads — plus what the state carried
+    in gives, ``(C H) * decay since the chunk began``; the state moves
+    on by ``B^T (decay to the chunk's end * dx)``.  Every product takes
+    float32 operands whole (``Precision.HIGHEST``).  The running sums
+    of ``dt A`` are taken here, outside the kernel, a chunk at a time.
+    A prompt whose length is no multiple of ``chunk`` is padded at its
+    end with identity steps (``dt = 0``).  Heads narrower than a lane
+    tile share one: each takes the whole tile's product and keeps its
+    own lanes."""
+    batch, t, heads = dt.shape
+    e, n = dx.shape[-1], b.shape[-1]
+    head_dim = e // heads
+    length = min(chunk, -(-t // _TILE) * _TILE)
+    pad = -t % length
+    if pad:
+        dt, dx, b, c = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                        for x in (dt, dx, b, c))
+    tp = t + pad
+    chunks = tp // length
+    cum = jnp.cumsum((dt * a).reshape(batch, chunks, length, heads), axis=2)
+    rows = cum.swapaxes(2, 3)                   # [batch, chunks, heads, L]
+    eb = next((k for k in (_SSD_SCAN_CHANNELS, 512, 256, 128)
+               if e % k == 0 and k % head_dim == 0), e)
+    tile = next((k for k in (128, head_dim) if eb % k == 0
+                 and k % head_dim == 0), eb)
+    hb = eb // head_dim
+    wide = pl.BlockSpec((1, length, eb), lambda i, j, k: (i, k, j))
+    thin = pl.BlockSpec((1, length, n), lambda i, j, k: (i, k, 0))
+    y, last = pl.pallas_call(
+        functools.partial(_ssd_scan_kernel, head_dim=head_dim, tile=tile),
+        grid=(batch, e // eb, chunks),
+        in_specs=[
+            pl.BlockSpec((1, length, heads), lambda i, j, k: (i, k, 0)),
+            pl.BlockSpec((1, 1, hb, length), lambda i, j, k: (i, k, j, 0)),
+            pl.BlockSpec((1, 1, hb, length, 1),
+                         lambda i, j, k: (i, k, j, 0, 0)),
+            wide, thin,
+            pl.BlockSpec((1, 1, n, length), lambda i, j, k: (i, k, 0, 0)),
+            thin],
+        out_specs=[wide, pl.BlockSpec((1, n, eb), lambda i, j, k: (i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((batch, tp, e), jnp.float32),
+                   jax.ShapeDtypeStruct((batch, n, e), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, eb), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            # the inputs' and the output's blocks double-buffered (the
+            # columns a lane row a value), a handful of [L, eb] and
+            # [L, L] temporaries
+            vmem_limit_bytes=4 * (4 * length * eb + 2 * hb * length * 128
+                                  + 8 * length * max(n, heads, 128)
+                                  + 6 * length * eb + 8 * length * length)
+            + (8 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="ssd_scan",
+    )(cum.reshape(batch, tp, heads), rows, rows[..., None], dx, b,
+      b.reshape(batch, chunks, length, n).swapaxes(2, 3), c)
+    return (y[:, :t] if pad else y), last
+
+
 # -- the convolution ----------------------------------------------------------
 
 def causal_conv(taps, w, bias):
@@ -253,28 +499,19 @@ def causal_conv(taps, w, bias):
 
 # -- the format --------------------------------------------------------------------
 
-@dataclasses.dataclass(frozen=True)
-class SsmFormat(LayeredState):
-    """One layer's state-space memory, described: what the ring builds
-    its buffers from and updates and reads them through (``zeros``,
-    ``layer`` and ``with_layer`` are ``ops/layered.py``'s)."""
+class _WindowedState(LayeredState):
+    """What both shapes of state-space memory share: the buffers' keys,
+    the bubble as an identity update, the convolution's window (only
+    its width differs) and where a prefill leaves its last state."""
 
     keys = ("conv", "h")
-
-    channels: int           #: ``E``
-    states: int             #: ``N``
-    d_conv: int
-    #: the window's type, the block's compute type (``h`` is float32)
-    dtype: Any
-    #: the ring's round-robin groups (a leading axis); None for one batch
-    groups: int | None = None
 
     def buffers(self, batch: int) -> dict[str, jax.ShapeDtypeStruct]:
         """One layer's buffers for ``batch`` sequences (a group), by key."""
         lead = () if self.groups is None else (self.groups,)
         return {
             "conv": jax.ShapeDtypeStruct(
-                lead + (self.d_conv - 1, batch, self.channels), self.dtype),
+                lead + (self.d_conv - 1, batch, self.conv_width), self.dtype),
             "h": jax.ShapeDtypeStruct(
                 lead + (batch, self.states, self.channels), jnp.float32)}
 
@@ -314,21 +551,6 @@ class SsmFormat(LayeredState):
         return [win[j] for j in range(self.d_conv - 1)] + [u], \
             self._ungroup(dict(bufs, conv=conv))
 
-    def step(self, dt, x, b, c, a, layer: dict, group=None, valid=True):
-        """One token of every sequence (of group ``group``): ``dt`` [b,
-        E] the step (float32), ``x`` [b, E] the convolution's output,
-        ``b`` / ``c`` [b, N], ``a`` [N, E].  The state is decayed, ``(dt
-        x) (x) b`` is added and ``c`` reads the *new* state: returns
-        ``(y [b, E] float32, the layer)``.  With ``valid`` false (a
-        pipeline's bubble) the update is the identity (``dt = 0``) and
-        ``y`` means nothing."""
-        f32 = jnp.float32
-        dt = jnp.where(valid, dt.astype(f32), 0.0)
-        bufs, group = self._group(layer, group)
-        y, h = ssm_step(dt, dt * x.astype(f32), b.astype(f32),
-                        c.astype(f32), a.astype(f32), bufs["h"], group)
-        return y, self._ungroup(dict(bufs, h=h))
-
     # -- a whole prompt ---------------------------------------------------------
 
     def prefill_shift(self, u, layer: dict, slot=(None, True)):
@@ -357,32 +579,135 @@ class SsmFormat(LayeredState):
         conv = lax.dynamic_update_slice(bufs["conv"], new, at)
         return taps, self._ungroup(dict(bufs, conv=conv))
 
+    def _leave(self, last, layer: dict, slot) -> dict:
+        """``layer`` with ``last`` [b, N, E], the state after a prompt's
+        last position, left where ``slot`` says (kept where the call is
+        a bubble)."""
+        group, valid, row = slot if len(slot) == 3 else (*slot, 0)
+        bufs, group = self._group(layer, group)
+        at = (group[0], row, 0, 0)
+        old = lax.dynamic_slice(bufs["h"], at, (1,) + last.shape)
+        h = lax.dynamic_update_slice(
+            bufs["h"], jnp.where(valid, last[None], old), at)
+        return self._ungroup(dict(bufs, h=h))
+
+
+@dataclasses.dataclass(frozen=True)
+class SsmFormat(_WindowedState):
+    """One layer's Mamba-1 memory, described: what the ring builds its
+    buffers from and updates and reads them through (``zeros``,
+    ``layer`` and ``with_layer`` are ``ops/layered.py``'s)."""
+
+    channels: int           #: ``E``
+    states: int             #: ``N``
+    d_conv: int
+    #: the window's type, the block's compute type (``h`` is float32)
+    dtype: Any
+    #: the ring's round-robin groups (a leading axis); None for one batch
+    groups: int | None = None
+
+    @property
+    def conv_width(self) -> int:
+        return self.channels
+
+    def step(self, dt, x, b, c, a, layer: dict, group=None, valid=True):
+        """One token of every sequence (of group ``group``): ``dt`` [b,
+        E] the step (float32), ``x`` [b, E] the convolution's output,
+        ``b`` / ``c`` [b, N], ``a`` [N, E].  The state is decayed, ``(dt
+        x) (x) b`` is added and ``c`` reads the *new* state: returns
+        ``(y [b, E] float32, the layer)``.  With ``valid`` false (a
+        pipeline's bubble) the update is the identity (``dt = 0``) and
+        ``y`` means nothing."""
+        f32 = jnp.float32
+        dt = jnp.where(valid, dt.astype(f32), 0.0)
+        bufs, group = self._group(layer, group)
+        y, h = ssm_step(dt, dt * x.astype(f32), b.astype(f32),
+                        c.astype(f32), a.astype(f32), bufs["h"], group)
+        return y, self._ungroup(dict(bufs, h=h))
+
     def prefill(self, dt, x, b, c, a, layer: dict, slot=(None, True)):
         """A whole prompt of every sequence (of the group ``slot``
         names) into an *empty* memory: ``dt`` / ``x`` [b, t, E], ``b`` /
         ``c`` [b, t, N], ``a`` [N, E] -> ``(y [b, t, E] float32, the
         layer)``, the layer holding the state after the last position.
         Where ``slot`` says the call is a bubble, the state is kept."""
-        group, valid, row = slot if len(slot) == 3 else (*slot, 0)
         f32 = jnp.float32
         dt = dt.astype(f32)
         y, last = ssm_scan(dt, dt * x.astype(f32), b.astype(f32),
                            c.astype(f32), a.astype(f32))
+        return y, self._leave(last, layer, slot)
+
+
+@dataclasses.dataclass(frozen=True)
+class SsdFormat(_WindowedState):
+    """One layer's Mamba-2 memory, described: a state of ``heads`` heads
+    of ``head_dim`` channels under one decay a head, ``B`` and ``C``
+    shared by all heads and convolved with the channels (the module
+    docstring's second shape)."""
+
+    heads: int
+    head_dim: int
+    states: int             #: ``N``
+    d_conv: int
+    #: positions of one chunk of the prefill's matrix form
+    chunk: int
+    #: the window's type, the block's compute type (``h`` is float32)
+    dtype: Any
+    #: the ring's round-robin groups (a leading axis); None for one batch
+    groups: int | None = None
+
+    @property
+    def channels(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """The channels, ``B`` and ``C``."""
+        return self.channels + 2 * self.states
+
+    def step(self, dt, x, b, c, a, layer: dict, group=None, valid=True):
+        """One token of every sequence (of group ``group``): ``dt`` [b,
+        heads] the step (float32), ``x`` [b, E] the channels of the
+        convolution's output, ``b`` / ``c`` [b, N], ``a`` [heads].
+        Every head's state is decayed by its ``exp(dt a)``, ``(dt x)
+        (x) b`` is added and ``c`` reads the *new* state: returns ``(y
+        [b, E] float32, the layer)``.  With ``valid`` false (a
+        pipeline's bubble) the update is the identity (``dt = 0``) and
+        ``y`` means nothing."""
+        f32 = jnp.float32
+        dt = jnp.where(valid, dt.astype(f32), 0.0)
         bufs, group = self._group(layer, group)
-        at = (group[0], row, 0, 0)
-        old = lax.dynamic_slice(bufs["h"], at, (1,) + last.shape)
-        h = lax.dynamic_update_slice(
-            bufs["h"], jnp.where(valid, last[None], old), at)
+        y, h = ssd_step(
+            jnp.repeat(jnp.exp(dt * a.astype(f32)), self.head_dim, axis=-1),
+            jnp.repeat(dt, self.head_dim, axis=-1) * x.astype(f32),
+            b.astype(f32), c.astype(f32), bufs["h"], group)
         return y, self._ungroup(dict(bufs, h=h))
 
+    def prefill(self, dt, x, b, c, a, layer: dict, slot=(None, True)):
+        """A whole prompt of every sequence (of the group ``slot``
+        names) into an *empty* memory: ``dt`` [b, t, heads], ``x`` [b,
+        t, E], ``b`` / ``c`` [b, t, N], ``a`` [heads] -> ``(y [b, t, E]
+        float32, the layer)``, the layer holding the state after the
+        last position.  Where ``slot`` says the call is a bubble, the
+        state is kept."""
+        f32 = jnp.float32
+        dt = dt.astype(f32)
+        y, last = ssd_scan(
+            dt, jnp.repeat(dt, self.head_dim, axis=-1) * x.astype(f32),
+            b.astype(f32), c.astype(f32), a.astype(f32), chunk=self.chunk)
+        return y, self._leave(last, layer, slot)
 
-def dense(h, conv) -> tuple[np.ndarray, np.ndarray]:
+
+def dense(h, conv, heads: int | None = None
+          ) -> tuple[np.ndarray, np.ndarray]:
     """A layer's buffers of one group on the host in the form that
-    knows no layout: ``h`` [b, N, E] -> ``H [b, E, N]``, ``conv``
-    [d_conv - 1, b, E] -> the window ``[b, d_conv - 1, E]``, oldest
-    input first."""
-    return (np.swapaxes(np.asarray(h), -1, -2),
-            np.swapaxes(np.asarray(conv), 0, 1))
+    knows no layout: ``h`` [b, N, E] -> ``H [b, E, N]``, or with
+    ``heads`` ``[b, heads, head_dim, N]``; ``conv`` [d_conv - 1, b, W]
+    -> the window ``[b, d_conv - 1, W]``, oldest input first."""
+    h = np.swapaxes(np.asarray(h), -1, -2)
+    if heads is not None:
+        h = h.reshape(h.shape[:-2] + (heads, -1, h.shape[-1]))
+    return h, np.swapaxes(np.asarray(conv), 0, 1)
 
 
 # -- the oracle -----------------------------------------------------------------
@@ -404,6 +729,32 @@ def prefill_reference(dt, x, b, c, a):
         return h, y
 
     start = jnp.zeros((dt.shape[0], a.shape[0], a.shape[1]), jnp.float32)
+    h, ys = lax.scan(step, start, tuple(
+        jnp.swapaxes(v, 0, 1) for v in (dt, x, b, c)))
+    return jnp.swapaxes(ys, 0, 1), h
+
+
+def ssd_step_reference(dt, x, b, c, a, h):
+    """:func:`ssd_step` (behind :meth:`SsdFormat.step`'s spreading of a
+    head's step) in plain ``jnp`` over one item: ``dt`` [batch, heads],
+    ``x`` [batch, E], ``b`` / ``c`` [batch, N], ``a`` [heads], ``h``
+    [batch, N, E], all float32: ``(y [batch, E], h)``."""
+    p = x.shape[-1] // dt.shape[-1]
+    h = jnp.repeat(jnp.exp(dt * a), p, axis=-1)[:, None, :] * h \
+        + (jnp.repeat(dt, p, axis=-1) * x)[:, None, :] * b[:, :, None]
+    return jnp.sum(h * c[:, :, None], axis=1), h
+
+
+def ssd_prefill_reference(dt, x, b, c, a):
+    """The second shape's recurrence position by position from an empty
+    memory: ``dt`` [batch, t, heads], ``x`` [batch, t, E], ``b`` / ``c``
+    [batch, t, N], ``a`` [heads], all float32 -> ``(y [batch, t, E], h
+    [batch, N, E])``."""
+    def step(h, xs):
+        y, h = ssd_step_reference(*xs, a, h)
+        return h, y
+
+    start = jnp.zeros((dt.shape[0], b.shape[-1], x.shape[-1]), jnp.float32)
     h, ys = lax.scan(step, start, tuple(
         jnp.swapaxes(v, 0, 1) for v in (dt, x, b, c)))
     return jnp.swapaxes(ys, 0, 1), h

@@ -7,7 +7,8 @@ The ring's decode and prefill programs (``gpt_tiny`` and ``olmoe_tiny``;
 several; ``olmoe_tiny``'s widths at four layers for four stages;
 ``brumby_tiny``, whose state has neither int8 rows nor beams;
 ``cohere_moe_tiny``, a format a layer; ``jamba_tiny``, two kinds of
-memory in one graph, a period a stage) and
+memory in one graph, a period a stage; ``granite_hybrid_tiny``, the
+same with a state of heads and routed experts in every layer) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -30,8 +31,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from defer_tpu.models import (brumby_tiny, cohere_moe_tiny, gpt_tiny,
-                              jamba_tiny, olmoe, olmoe_tiny)
+from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
+                              granite_hybrid_tiny, gpt_tiny, jamba_tiny,
+                              olmoe, olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -108,6 +110,10 @@ def main() -> int:
                 # layers' caches: a state has neither int8 rows nor beams
                 *ring_programs("jamba_tiny", jamba_tiny(), (1, 2),
                                kv_caches=("buffer",), beams=(1,)),
+                # the state-space state's second shape, and every layer
+                # routing
+                *ring_programs("granite_hybrid_tiny", granite_hybrid_tiny(),
+                               (1, 2), kv_caches=("buffer",), beams=(1,)),
                 *engine_programs()]
     for name, lowered in programs:
         text = lowered.as_text()

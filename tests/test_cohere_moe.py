@@ -166,6 +166,30 @@ def test_sigmoid_routing_renormalises_over_the_chosen():
         route_top_k(logits, 4, scoring="tanh")
 
 
+def test_the_third_rule_is_a_softmax_over_the_chosen_logits():
+    """``softmax_of_chosen`` (Granite 4.0-H): the ``k`` largest logits,
+    then a softmax over those alone — the other two rules' experts under
+    other weights, and the plain reference's own rule."""
+    granite_ref = importlib.import_module(
+        "chipbench.reference.granite_hybrid")
+    logits = jax.random.normal(jax.random.key(0), (64, 16)) * 3
+    eid, w = route_top_k(logits, 4, scoring="softmax_of_chosen")
+    order = np.argsort(-np.asarray(logits), axis=-1)[:, :4]
+    np.testing.assert_array_equal(np.asarray(eid), order)
+    picked = np.take_along_axis(np.asarray(logits), order, -1)
+    np.testing.assert_allclose(
+        np.asarray(w), np.asarray(jax.nn.softmax(picked, -1)), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
+    # the other two choose the same experts and weigh them otherwise
+    for scoring in ("softmax", "sigmoid"):
+        other_eid, other_w = route_top_k(logits, 4, scoring=scoring)
+        np.testing.assert_array_equal(np.asarray(other_eid), order)
+        assert float(jnp.abs(other_w - w).max()) > 0.05
+    want_ids, want_w = granite_ref.route(logits, 4)
+    np.testing.assert_array_equal(np.asarray(want_ids), order)
+    np.testing.assert_allclose(np.asarray(w), np.asarray(want_w), rtol=1e-6)
+
+
 def test_the_programs_blocks_make_the_references_choices(tiny):
     graph, params = tiny
     ids = np.random.default_rng(8).integers(0, VOCAB, (2, 24)).astype(

@@ -26,8 +26,9 @@ import jax.numpy as jnp
 
 from chipbench.agreement import logit_gaps, rel_err
 from chipbench.reference import jamba as ref
-from defer_tpu.models import (brumby_tiny, cohere_moe_tiny, gpt_tiny,
-                              jamba_tiny, olmoe_tiny)
+from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
+                              granite_hybrid_tiny, gpt_tiny, jamba_tiny,
+                              olmoe_tiny)
 from defer_tpu.models.cohere_moe import tie_head
 from defer_tpu.models.decoder import (DecoderBlock, StateSpaceBlock,
                                       decoder_parts)
@@ -377,7 +378,17 @@ def test_the_blocks_declare_their_memory(model):
         and not isinstance(attn, StateSpaceBlock)
     assert (mamba.memory, attn.memory) == ("ssm", "kv_cache")
     assert mamba.geometry(64) is None and attn.geometry(64) == (4, 1, 16)
-    assert mamba.widest(64) == 256 and attn.widest(64) == 64
+    # the contract's words: the input projection's [u, z] is the widest
+    # activation, no heads (a decay a channel and a state), and the
+    # selection hands the recurrence its input beside dt, B, C and A
+    assert mamba.mixer_width == 256 == mamba.widest(64)
+    assert attn.widest(64) == 64
+    assert getattr(mamba, "heads", None) is None
+    params0 = graph.init(jax.random.key(0))["block_0"]
+    c = jnp.ones((2, 128), jnp.float32)
+    dt, xs, b, c_read, a = mamba.mixer_selection(params0, c, None)
+    assert xs is c and dt.shape == (2, 128) and a.shape == (8, 128)
+    assert b.shape == c_read.shape == (2, 8)
     assert mamba.memory_format(64, SEQ, jnp.bfloat16, groups=2) == \
         ssm.SsmFormat(128, 8, 4, jnp.bfloat16, groups=2)
     assert mamba.decode_stats == attn.decode_stats == ("ssm.updates",)
@@ -394,7 +405,8 @@ def test_the_blocks_declare_their_memory(model):
     (brumby_tiny, ("retention",), (4, 2, 16)),
     (cohere_moe_tiny, ("kv_cache",), (8, 2, 8)),
     (jamba_tiny, ("ssm", "ssm", "kv_cache", "ssm"), (4, 1, 16)),
-], ids=["gpt", "olmoe", "brumby", "cohere_moe", "jamba"])
+    (granite_hybrid_tiny, ("ssm", "ssm", "kv_cache", "ssm"), (4, 2, 16)),
+], ids=["gpt", "olmoe", "brumby", "cohere_moe", "jamba", "granite_hybrid"])
 def test_the_contract_reports_kinds_and_geometries_by_layer(family, kinds,
                                                             heads):
     graph = family()
