@@ -61,27 +61,39 @@ from .trace import ANNOTATION_PREFIX, ROUND_ARGS
 #: on a fast stage is overlap working, not time lost).
 NODE_PHASES = ("dispatch", "queue", "device", "host_sync")
 
-#: the decode engine's per-step phases (serve/engine.py): host-side
-#: gather of the per-slot rows, jit dispatch, device wait, host sync of
-#: the sampled ids, and per-slot delivery/bookkeeping.  Sampling and
-#: the KV write happen INSIDE the fused step program, so they are part
-#: of ``device`` here; splitting them needs ``jax.profiler`` (the
-#: profile CLI's --jax-trace), not host timers.
+#: the decode engine's per-step phases (serve/engine.py), each once a
+#: step: host-side gather of the per-slot rows, jit dispatch, device
+#: wait, host sync of the sampled ids, and per-slot delivery/bookkeeping.
+#: Sampling and the KV write happen INSIDE the fused step program, so
+#: they are part of ``device`` here; splitting them needs
+#: ``jax.profiler`` (the profile CLI's --jax-trace), not host timers.
+#: The engine keeps one step launched ahead of the one it reads, so one
+#: call of ``ContinuousBatchEngine.step`` holds the first two phases of
+#: step n+1 and then the last three of step n — in this order, under
+#: one root ``step``, which opens once a step around the call that
+#: launches it.  A busy period's first call holds the first two alone
+#: (nothing was in flight), its last the last three with no root (nothing
+#: is left to launch).  ``sync`` carries ``{"ahead": 0|1}``: whether a
+#: later step was running on the device under the wait.
 ENGINE_PHASES = ("gather", "dispatch", "device", "sync", "delivery")
 
 #: inside the engine's ``dispatch``, in wall order: ``upload`` is the
-#: four ``jnp.asarray`` of the per-slot rows (host to device), ``launch``
-#: the jitted step's call alone
+#: one ``jax.device_put`` of the step's five per-slot rows (host to
+#: device; the ids a slot sampled the step before stay on the device),
+#: ``launch`` the jitted step's call alone
 ENGINE_DISPATCH_PHASES = ("upload", "launch")
 
 #: what the engine's scheduling thread does between two steps
 #: (serve/engine.py ``EngineLoop.run``): ``join`` is the sweep that
 #: applies cancellations and moves queued requests into free slots —
-#: host work the device waits for; ``park`` is the one blocking pop
+#: host work beside the step in flight; ``park`` is the one blocking pop
 #: taken with no active slot — the engine has nothing to do; ``prefill``
-#: is one joined slot's prompt through the prefill programs, waited for
-#: (``ContinuousBatchEngine.step`` runs it in front of the step that
-#: follows a join: a sibling of ``join`` and outside ``step``)
+#: is one joined slot's prompt through the prefill programs, LAUNCHED
+#: and not waited for: it closes when its launches have returned, and
+#: the pass's device time is the ``jit_engine_prefill`` runs of a
+#: profiler trace (``ContinuousBatchEngine.step`` runs it in front of
+#: the launch that takes the slot in, behind the step in flight: a
+#: sibling of ``join`` and outside ``step``)
 ENGINE_LOOP_PHASES = ("join", "park", "prefill")
 
 #: ``PipelinedDecoder.generate`` (runtime/decode.py), in wall order:
@@ -122,7 +134,9 @@ DOOR_PHASES = ("admit",)
 #: prefix, root, phases).  ``span(layer, phase)`` is named
 #: ``<layer>.<phase>`` and feeds the histogram ``<prefix>.<phase>_s``;
 #: the root encloses one round of the first phases and feeds none
-#: (``serve.decode.step_s`` stays the engine's dispatch-to-sync total).
+#: (``serve.decode.step_s`` is the engine's round: from one step's ids
+#: reaching the host to the next step's; launch to ids for a step
+#: launched onto an empty queue).
 #: A name that is not spelled here does not exist.
 SPAN_LAYERS = {
     "decode": ("decode", "generate", DECODE_PHASES + DECODE_DISPATCH_PHASES

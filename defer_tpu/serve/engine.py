@@ -33,7 +33,9 @@ engine is continuous batching proper:
   them.
 * A step is one token per active slot: teacher-forced from the prompt
   while ``pos < prompt_len`` (the last prompt token, and the tail of a
-  prompt longer than one prefill takes), sampled past it.  A
+  prompt longer than one prefill takes), sampled past it — and past it
+  the step reads the id it feeds from the DEVICE array the step before
+  it returned, never from the host.  A
   layer is the block's own halves, as the ring calls them:
   ``decode_qkv`` on the whole ``[width, d]`` batch, each slot's new row
   written in place at that slot's OWN position (the format's
@@ -48,6 +50,16 @@ engine is continuous batching proper:
 * Sampling keys are ``fold_in(request_seed, position)`` per row —
   deterministic per request regardless of batch composition or join
   step.
+* The engine keeps ONE step launched ahead of the one whose tokens it
+  reads: a steady round is ``launch(n+1)``, ``sync(n)``,
+  ``delivery(n)``, the loop's join sweep, ``gather(n+2)`` — as the
+  ring's is ``dispatch(n+1)``, ``sync(n)``, ``scatter(n)``, ``emit(n)``
+  (``runtime/decode.py``).  Nothing step n+1 needs waits for step n's
+  tokens on the host: its ids are step n's device output, positions
+  advance by one, seeds and temperatures are the request's, and a slot
+  finishes by ``max_new_tokens`` alone, so which slots step n+1 holds
+  is known before a token of step n is.  The chip has its next program
+  while the host wakes, delivers, joins, gathers, uploads and launches.
 
 The stage structure mirrors the deployed chain's partition (same
 ``split_blocks`` assignment), so the planner's per-stage latency budget
@@ -122,17 +134,38 @@ class DecodeRequest:
 
 
 class _Slot:
-    __slots__ = ("req", "pos", "prefill", "out", "last_id", "cancelled")
+    __slots__ = ("req", "pos", "prefill", "out", "cancelled")
 
     def __init__(self, req: DecodeRequest, prefill: int = 0):
         self.req = req
-        self.pos = prefill         #: next position to feed to a step
+        #: next position to feed to a step: it advances when a step is
+        #: LAUNCHED, a step before that step's token is read
+        self.pos = prefill
         #: prompt positions ``0 .. prefill - 1`` still to go through the
         #: prefill, which the next ``step()`` runs first
         self.prefill = prefill
-        self.out: list[int] = []   #: generated ids
-        self.last_id = 0           #: last sampled id (input past prompt)
+        self.out: list[int] = []   #: generated ids, as they are read
         self.cancelled = False
+
+    def steps_left(self) -> bool:
+        """Whether a step is still to be launched for this slot: the
+        last one feeds position ``plen + max_new_tokens - 2``.  Known
+        without any token."""
+        return self.pos < self.req.prompt.size + self.req.max_new_tokens - 1
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A launched step whose ids the host has not read."""
+
+    ids: Any                    #: the step's ``[width]`` ids, on the device
+    #: ``(slot index, slot, position fed)`` a row: the slot object, not
+    #: only its index, which may belong to a later tenant by delivery
+    rows: list
+    #: where the round ``step_s`` records began: the launch, for a step
+    #: launched onto an empty queue; the step before's ids reaching the
+    #: host, for one launched ahead (set when they do)
+    since: float
 
 
 class ContinuousBatchEngine:
@@ -142,7 +175,9 @@ class ContinuousBatchEngine:
     test) drive it with :meth:`join` / :meth:`cancel` between calls to
     :meth:`step`.  All three must be called from one scheduling thread
     (the slot table is not locked against concurrent mutation; the
-    front door owns that thread)."""
+    front door owns that thread).  Between two calls one step may be
+    running on the device, launched and not yet read (depth exactly
+    one, always: no option)."""
 
     def __init__(self, graph: LayerGraph, params: dict[str, Any], *,
                  num_stages: int, width: int,
@@ -187,12 +222,22 @@ class ContinuousBatchEngine:
         #: its aliased output (docs/DECODE_CLIFF.md, "The engine")
         self._caches = self.kv_format.zeros(width, len(parts.block_names))
         self._step_fns: dict[bool, Any] = {}
+        #: the ids the last launched step returned, on the device: what
+        #: the next step feeds every slot that is past its prompt
+        self._prev_ids = jnp.zeros(width, jnp.int32)
+        #: the step launched and not yet read, if any
+        self._flight: _Flight | None = None
         #: positions a prefill takes (0: a model of one position)
         self.prefill_len = min(PREFILL_POSITIONS, self.max_len - 1)
         self._prefill_fns = self._build_prefill()
-        self.steps = 0
+        self.steps = 0              #: steps whose ids the host has read
         self._step_hist = REGISTRY.histogram("serve.decode.step_s")
         self._tok_count = REGISTRY.counter("serve.decode.tokens")
+        #: steps launched while an earlier one was still unread: over
+        #: ``step_s``'s count, the share of steps the chip never waited
+        #: for.  None is ever discarded: a finish is known before the
+        #: launch
+        self._ahead_count = REGISTRY.counter("serve.decode.ahead.launched")
         #: how often the prefill engages: prompt tokens it took, and
         #: prompt tokens a step was fed (a request's last, a long tail)
         self._prefilled_count = REGISTRY.counter(
@@ -212,10 +257,15 @@ class ContinuousBatchEngine:
         """Claim a free slot for ``req``; False when the batch is full.
         The request's KV rows start clean by construction: position p's
         cache row is written before any later position reads it, so a
-        recycled slot needs no cache zeroing.  The slot is marked for
-        its prompt's prefill, which the next :meth:`step` runs before
-        the step itself (a slot cancelled before then has run nothing);
-        a prompt of one token has nothing to prefill."""
+        recycled slot needs no cache zeroing — with a step in flight
+        too: it may still carry the slot's last tenant's row (cancelled
+        since; a finished one is in no step launched ahead), and the
+        newcomer's prefill and first step are queued behind it, where
+        they rewrite every row the newcomer will read.  The slot is
+        marked for its prompt's prefill, which the next :meth:`step`
+        runs before it launches the step that takes the slot in (a slot
+        cancelled before then has run nothing); a prompt of one token
+        has nothing to prefill."""
         if req.prompt.size + req.max_new_tokens > self.max_len:
             raise ValueError(
                 f"prompt {req.prompt.size} + {req.max_new_tokens} new "
@@ -231,13 +281,20 @@ class ContinuousBatchEngine:
         """Free ``req``'s slot immediately (client disconnected).  The
         slot is reusable at the next join; other slots' rows are
         untouched (row-independent step), so a mid-decode cancellation
-        cannot perturb anyone else's output."""
+        cannot perturb anyone else's output.  A step in flight still
+        carries the slot's row: its token is dropped at delivery.  Where
+        this was the last live slot that step is read here and now, so
+        that an engine with no request has nothing in flight (a caller
+        that parks would otherwise read it a park later, as one long
+        ``step_s``)."""
         for i, s in enumerate(self._slots):
             if s is not None and s.req is req:
                 s.cancelled = True
                 self._slots[i] = None
                 if req.on_done is not None:
                     req.on_done(None)
+                if not self.active():
+                    self.drain()
                 return True
         return False
 
@@ -252,7 +309,12 @@ class ContinuousBatchEngine:
         top_k = self.top_k
         fmt = self.kv_format
 
-        def step(params, caches, ids, pos, seeds, temps):
+        def step(params, caches, prev_ids, host_ids, from_host, pos, seeds,
+                 temps):
+            # the id a slot feeds: the one the step before sampled for
+            # it, still on the device — or the host's, where the host
+            # owns it (a prompt token, a slot with no request)
+            ids = jnp.where(from_host, host_ids, prev_ids)
             safe = jnp.clip(pos, 0, self.max_len - 1)
             x = embed.embed_rows(params["embeddings"], ids,
                                  safe).astype(jnp.float32)
@@ -324,9 +386,11 @@ class ContinuousBatchEngine:
 
     def _prefill(self, i: int, s: _Slot) -> None:
         """Slot ``i``'s prompt positions ``0 .. s.prefill - 1`` through
-        the prefill programs, waited for: the step behind them then
-        starts on an empty queue, and ``step_s`` stays a step's own
-        time."""
+        the prefill programs, launched and NOT waited for: the donated
+        cache buffers order the pass behind the step in flight and in
+        front of the step launched next.  The span closes when the
+        launches have returned; the pass's device time is the
+        ``jit_engine_prefill`` runs of a profiler trace."""
         n, s.prefill = s.prefill, 0
         fmt = self.kv_format
         embed, blocks_prefill = self._prefill_fns
@@ -344,84 +408,124 @@ class ContinuousBatchEngine:
                      for j in range(len(ops))], slot)
                 for l, layer in enumerate(layers, l0):
                     self._caches = fmt.with_layer(self._caches, l, layer)
-            jax.block_until_ready(x)
         self._prefilled_count.n += n
 
     # -- one decode step ---------------------------------------------------
 
     def step(self) -> list[tuple[DecodeRequest, np.ndarray]]:
-        """Advance every active slot one token; returns requests that
-        FINISHED this step as ``(request, [plen + new] ids)`` (their
-        slots are already free).  A slot that joined since the last
-        step first has its prompt prefilled (``engine.prefill``, a phase
-        of its own in front of the step's).  No-op (empty list) with no
-        active slots."""
-        live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
-        if not live:
-            return []
-        for i, s in live:
-            if s.prefill:       # joined since the last step
-                self._prefill(i, s)
-        with span("engine", "step", {"step": self.steps,
-                                     "rows": len(live)}):
-            return self._step(live)
+        """Launch the next step, then read the one launched by the call
+        before; returns the requests that read FINISHED as ``(request,
+        [plen + new] ids)`` (their slots are already free).
 
-    def _step(self, live) -> list[tuple[DecodeRequest, np.ndarray]]:
-        """One step in the phases of ``obs/profile.py::ENGINE_PHASES``:
-        gather (host build of the per-slot rows / teacher-forcing),
-        dispatch (``upload`` of those rows, then ``launch``: the jit step
-        call returning; ``ENGINE_DISPATCH_PHASES``), device
-        (block_until_ready — the fused step program: blocks, lm_head,
-        sampling AND the KV write all live here; splitting those needs
-        jax.profiler), sync (np.asarray of the sampled ids), delivery
-        (per-slot bookkeeping + on_done).  ``step_s`` stays the
-        dispatch→materialize total the serve stats already report."""
+        In order: a slot that joined since the last call has its prompt
+        prefilled (``engine.prefill``, a phase of its own in front of
+        the step's; launched, not waited for); the next step is gathered
+        and launched for every slot that has a step left — which is
+        known without the tokens of the step in flight — and only then
+        the step in flight is waited for and its ids delivered: the
+        device runs the step just launched meanwhile.  ``on_done`` fires
+        from the delivery of a request's last token.  With nothing in
+        flight (the first call after a join into an idle engine) the
+        launch is all, and the list is empty; with nothing to launch
+        (every live slot's last step is the one in flight) the read is
+        all.  No-op (empty list) with no active slot."""
+        flight = self._flight
+        live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
+        for i, s in live:
+            if s.prefill:       # joined since the last call
+                self._prefill(i, s)
+        rows = [(i, s) for i, s in live if s.steps_left()]
+        if not rows:
+            return self.drain()
+        # one root a step, around the call that launches it
+        with span("engine", "step", {"step": self.steps + (flight is not None),
+                                     "rows": len(rows)}):
+            self._launch(rows)
+            return self._deliver(flight) if flight is not None else []
+
+    def drain(self) -> list[tuple[DecodeRequest, np.ndarray]]:
+        """Read the step in flight, if any, and launch nothing: what a
+        busy period's last call comes to, a cancellation that empties
+        the engine, a loop that stops."""
+        flight, self._flight = self._flight, None
+        return self._deliver(flight) if flight is not None else []
+
+    def _blank_rows(self) -> tuple:
+        """A step's host rows ``(host_ids, from_host, pos, seeds,
+        temps)`` with no request in any slot: id 0 at position 0, the
+        host's."""
+        w = self.width
+        return (np.zeros(w, np.int32), np.ones(w, np.bool_),
+                np.zeros(w, np.int32), np.zeros(w, np.uint32),
+                np.zeros(w, np.float32))
+
+    def _launch(self, rows) -> None:
+        """The first two phases of ``obs/profile.py::ENGINE_PHASES`` for
+        the step that takes ``rows``: gather (host build of the per-slot
+        rows / teacher-forcing) and dispatch (``upload`` of those rows,
+        then ``launch``: the jit step call returning;
+        ``ENGINE_DISPATCH_PHASES``).  The step becomes the one in
+        flight."""
         with span("engine", "gather"):
-            w = self.width
-            ids = np.zeros(w, np.int32)
-            pos = np.zeros(w, np.int32)
-            seeds = np.zeros(w, np.uint32)
-            temps = np.zeros(w, np.float32)
+            host_ids, from_host, pos, seeds, temps = self._blank_rows()
             sample = False
-            for i, s in live:
-                plen = s.req.prompt.size
-                if s.pos < plen:
-                    ids[i] = s.req.prompt[s.pos]
+            fed = []
+            for i, s in rows:
+                if s.pos < s.req.prompt.size:
+                    host_ids[i] = s.req.prompt[s.pos]
                     self._forced_count.n += 1
                 else:
-                    ids[i] = s.last_id
+                    # the step before this one sampled it: every launch
+                    # since the slot's first has held the slot
+                    from_host[i] = False
                 pos[i] = s.pos
                 seeds[i] = s.req.seed & 0xFFFFFFFF
                 temps[i] = s.req.temperature
                 sample = sample or s.req.temperature > 0
+                fed.append((i, s, s.pos))
+                s.pos += 1
+        if self._flight is not None:    # launched ahead of its read
+            self._ahead_count.n += 1
         with span("engine", "dispatch") as dispatched:
             with span("engine", "upload"):
-                rows = (jnp.asarray(ids), jnp.asarray(pos),
-                        jnp.asarray(seeds), jnp.asarray(temps))
+                up = jax.device_put((host_ids, from_host, pos, seeds, temps))
             with span("engine", "launch"):
-                next_ids, self._caches = self._step_fn(sample)(
-                    self.params, self._caches, *rows)
-            del rows    # while the device runs the step, not at the next
+                self._prev_ids, self._caches = self._step_fn(sample)(
+                    self.params, self._caches, self._prev_ids, *up)
+            del up      # while the device runs the step, not at the next
+        self._flight = _Flight(self._prev_ids, fed, dispatched.t0)
+
+    def _deliver(self, flight: _Flight
+                 ) -> list[tuple[DecodeRequest, np.ndarray]]:
+        """The last three phases for the step ``flight``: device
+        (block_until_ready — the fused step program: blocks, lm_head,
+        sampling AND the KV write all live here; splitting those needs
+        jax.profiler), sync (np.asarray of the sampled ids; ``ahead``
+        says whether a later step was running under the wait), delivery
+        (per-slot bookkeeping + on_done).  ``step_s`` records the round:
+        from the step before's ids reaching the host to this step's —
+        what a token costs a live slot — and launch to ids for a step
+        launched onto an empty queue."""
+        later = self._flight    # the step launched since, if any
         with span("engine", "device"):
-            sync = getattr(next_ids, "block_until_ready", None)
-            if sync is not None:
-                sync()
-        with span("engine", "sync") as synced:
-            next_ids = np.asarray(next_ids)
-        self._step_hist.record(synced.t1 - dispatched.t0)
+            flight.ids.block_until_ready()
+        with span("engine", "sync", {"ahead": int(later is not None)}) \
+                as synced:
+            next_ids = np.asarray(flight.ids)
+        self._step_hist.record(synced.t1 - flight.since)
+        if later is not None:
+            later.since = synced.t1
         self.steps += 1
         done: list[tuple[DecodeRequest, np.ndarray]] = []
         with span("engine", "delivery"):
-            for i, s in live:
-                plen = s.req.prompt.size
-                tok = int(next_ids[i])
-                # the step consumed position s.pos; the token it produced
-                # sits at position s.pos + 1, generated iff past the prompt
-                if s.pos + 1 >= plen:
-                    s.out.append(tok)
-                    s.last_id = tok
+            for i, s, fed in flight.rows:
+                if s.cancelled:     # left while the step ran
+                    continue
+                # the step consumed position ``fed``; the token it
+                # produced sits behind it, generated iff past the prompt
+                if fed + 1 >= s.req.prompt.size:
+                    s.out.append(int(next_ids[i]))
                     self._tok_count.n += 1
-                s.pos += 1
                 if len(s.out) >= s.req.max_new_tokens:
                     result = np.concatenate(
                         [s.req.prompt.astype(np.int64),
@@ -473,10 +577,11 @@ class EngineLoop(threading.Thread):
         self.former = former
         self._halt = threading.Event()
         self.error: BaseException | None = None
-        #: called with (per-unit seconds, units) after each step — feeds
-        #: the admission controller's live service EWMA.  A joined
-        #: slot's prefill runs inside that ``step()`` and is timed with
-        #: it: over a request's life its steps carry one prefill each
+        #: called with (per-unit seconds, units) after each call of
+        #: ``step()`` that read a step — feeds the admission controller's
+        #: live service EWMA.  The call's time is a round (it launched
+        #: the next step first, then waited for the one it read); a
+        #: busy period's first call only launches and is not a sample
         self._on_service = on_service
         #: cancellations queued from OTHER threads (client reader saw a
         #: disconnect); applied between steps on THIS thread — the slot
@@ -535,9 +640,12 @@ class EngineLoop(threading.Thread):
                 if eng.active() == 0:
                     continue
                 t0 = time.perf_counter()
-                n = eng.active()
+                n, read = eng.active(), eng.steps
                 eng.step()
-                if self._on_service is not None and n > 0:
+                if self._on_service is not None and eng.steps > read:
                     self._on_service((time.perf_counter() - t0) / n, n)
+            # a step launched ahead is read, not left: a request whose
+            # last token it holds still gets its answer
+            eng.drain()
         except BaseException as e:  # noqa: BLE001 — surfaced by the door
             self.error = e

@@ -74,8 +74,9 @@ def main() -> int:
               for key, buf in buffers.items()}
     item = buffers["k"].shape
 
-    def vec(dtype):
-        return jax.ShapeDtypeStruct((WIDTH,), dtype, sharding=chip)
+    # the step's per-slot rows: the ids the step before returned and
+    # the host's five
+    rows = [shaped(x) for x in (eng._prev_ids, *eng._blank_rows())]
 
     def report(lowered) -> dict:
         compiled = lowered.compile()
@@ -101,9 +102,7 @@ def main() -> int:
     # ``jax.default_backend()`` is not the TPU; this host's is the CPU
     # and the program is the chip's
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        step = eng._step_fn(False).lower(
-            params, caches, vec(jnp.int32), vec(jnp.int32),
-            vec(jnp.uint32), vec(jnp.float32))
+        step = eng._step_fn(False).lower(params, caches, *rows)
         # the first group of blocks' prefill: the engine calls the
         # program once a group
         ops, names = zip(*eng._blocks[:PREFILL_LAYERS])

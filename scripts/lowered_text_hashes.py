@@ -19,7 +19,8 @@ One ``name sha256 bytes`` line a program; with ``DIR`` each text is also
 written to ``DIR/<name>.txt`` for ``diff``.  It reads only what both
 engines have always had: ``_init_state``, ``_get_decode_fn``,
 ``_build_prefill_fn``, ``_step_fn``, ``_caches`` (and the engine's
-``_prefill_fns`` and ``_blocks``, which it has had since PR 39).
+``_prefill_fns`` and ``_blocks``, which it has had since PR 39, and its
+``_prev_ids`` and ``_blank_rows``, the step's inputs since PR 42).
 """
 
 import hashlib
@@ -73,12 +74,10 @@ def engine_programs():
     graph = gpt_tiny()
     eng = ContinuousBatchEngine(graph, graph.init(jax.random.key(0)),
                                 num_stages=2, width=3, top_k=3)
-    vec = jnp.zeros(3, jnp.int32)
     for sample in (False, True):
         yield f"engine.step.{'sample' if sample else 'greedy'}", \
             eng._step_fn(sample).lower(
-                eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
-                vec.astype(jnp.float32))
+                eng.params, eng._caches, eng._prev_ids, *eng._blank_rows())
     embed, blocks_prefill = eng._prefill_fns
     ids = jnp.zeros(eng.prefill_len, jnp.int32)
     yield "engine.prefill.embed", embed.lower(eng.params["embeddings"], ids)

@@ -105,3 +105,16 @@ def pytest_runtest_call(item):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
+
+
+@pytest.fixture
+def traced():
+    """The process tracer, on and empty for one test."""
+    from defer_tpu.obs import tracer
+    tr = tracer()
+    was = tr.enabled
+    tr.clear()
+    tr.enabled = True
+    yield tr
+    tr.enabled = was
+    tr.clear()

@@ -276,10 +276,8 @@ def test_a_step_cuts_no_item_out_of_a_cache_buffer(model, engine):
         layers = dec.l_max * 2          # a call a block and a stage
     else:
         eng = ContinuousBatchEngine(graph, params, num_stages=2, width=3)
-        vec = jnp.zeros(3, jnp.int32)
         jaxpr = jax.make_jaxpr(eng._step_fn(False))(
-            eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
-            vec.astype(jnp.float32))
+            eng.params, eng._caches, eng._prev_ids, *eng._blank_rows())
         shape = eng.kv_format.buffers(3)["k"].shape
         layers = len(eng._caches["k"])
     assert _cache_slices(jaxpr.jaxpr, sorted(d for d in shape if d != 1)) == []

@@ -36,18 +36,6 @@ from defer_tpu.serve.frontdoor import ServeFrontDoor
 PLEN, NEW, CHUNK = 4, 9, 2
 
 
-@pytest.fixture
-def traced():
-    """The process tracer, on and empty for one test."""
-    tr = tracer()
-    was = tr.enabled
-    tr.clear()
-    tr.enabled = True
-    yield tr
-    tr.enabled = was
-    tr.clear()
-
-
 @pytest.fixture(scope="module")
 def decoder():
     g = gpt_tiny(seq_len=32)
@@ -217,20 +205,27 @@ def test_no_occurrence_is_judged_before_the_phases_eighth(capfd):
 def test_a_pause_fires_once_with_every_field_the_platform_has(capfd):
     pw, w = _fresh_watch("decode", "sync")
     with span("decode", "generate", {"rows": 1}):      # the baseline
+        # what is typical, and the two occurrences that are no pause,
+        # are TOLD to the watch: slept, a loaded machine makes 9 ms into
+        # 13 and a pause of them.  Only the pause itself is slept
         for i in range(PAUSE_UNJUDGED_FIRST + 2):
             with span("decode", "dispatch", {"steps_run": 4 * i}):
                 pass
-            _occur("decode", "sync", 0.002)
+            w.feed(None, 0.002)
         typ, before = w.typ, _pauses("decode")
+        assert typ == pytest.approx(0.002)
         # over 10 ms but under 3x, and 3x but under 10 ms over: no pause
         w.typ = 0.02
-        _occur("decode", "sync", 0.035)
+        w.feed(None, 0.035)
         w.typ = typ
-        _occur("decode", "sync", 0.009)
+        w.feed(None, 0.009)
         assert _pauses("decode") == before
         typ = w.typ
         with span("decode", "dispatch", {"steps_run": 444}):
             pass
+        # the thread's last CPU reading is over 5 ms old by then, so the
+        # span takes its own and reads none of the work above
+        time.sleep(0.006)
         sp = _occur("decode", "sync", 0.04)
     n, hn, hsum, ne = _pauses("decode")
     assert (n, hn, ne) == (before[0] + 1, before[1] + 1, before[3] + 1)
@@ -245,8 +240,8 @@ def test_a_pause_fires_once_with_every_field_the_platform_has(capfd):
     assert d["cpu_ms"] <= 0.2 * d["wall_ms"] and d["proc_cpu_ms"] >= 0
     assert d["since_ms"] >= d["wall_ms"]
     # the thread slept: it gave the core up of its own accord, at least
-    # once an occurrence since the generation began
-    assert d["vol_switches"] >= PAUSE_UNJUDGED_FIRST
+    # once since the generation began
+    assert d["vol_switches"] >= 1
     assert d["invol_switches"] >= 0 and d["major_faults"] >= 0
     assert d["gc_collections"] >= 0
     for key, path in (("runq_wait_ms", "/proc/thread-self/schedstat"),
@@ -547,14 +542,23 @@ def test_the_engine_loop_names_steps_joins_and_parks(door, traced):
     assert [s["args"]["step"] for s in roots] \
         == list(range(steps0, steps0 + steps))
     assert all(1 <= s["args"]["rows"] <= 3 for s in roots)
+    # one root a step, around the call that launches it.  That call goes
+    # on to read the step before — launch(n+1), then device(n), sync(n),
+    # delivery(n): all five phases under one root, in the table's order
+    # — unless nothing was in flight (a busy period's first launch)
+    launch_only = 0
     for root in roots:
         kids = sorted((s for s in spans if s["parent"] == root["span"]),
                       key=lambda s: s["ts_us"])
-        assert [s["name"] for s in kids] \
-            == [f"engine.{p}" for p in ENGINE_PHASES]
+        names = [s["name"] for s in kids]
+        assert names in ([f"engine.{p}" for p in ENGINE_PHASES],
+                         [f"engine.{p}" for p in ENGINE_PHASES[:2]])
+        launch_only += len(names) == 2
         assert _in_order(kids)
         assert root["ts_us"] <= kids[0]["ts_us"]
         assert _ends(root) >= _ends(kids[-1]) - 2
+        if len(names) == 5:     # the step launched first runs meanwhile
+            assert kids[3]["args"] == {"ahead": 1}
         disp = kids[ENGINE_PHASES.index("dispatch")]
         two = sorted((s for s in spans if s["parent"] == disp["span"]),
                      key=lambda s: s["ts_us"])
@@ -565,14 +569,27 @@ def test_the_engine_loop_names_steps_joins_and_parks(door, traced):
     loop = sorted((s for s in spans if s["parent"] is None),
                   key=lambda s: s["ts_us"])
     assert _in_order(loop)
+    # a busy period's last call launches nothing and has no root: its
+    # three phases read the last step, with nothing running behind it
+    tail = ["engine.device", "engine.sync", "engine.delivery"]
     assert {s["name"] for s in loop} \
-        == {"engine.step", "engine.join", "engine.park", "engine.prefill"}
+        == {"engine.step", "engine.join", "engine.park", "engine.prefill",
+            *tail}
     names = [s["name"] for s in loop]
-    # a join sweep before every step, a joined slot's prefill between
+    drains = [i for i, nm in enumerate(names) if nm == tail[0]]
+    assert len(drains) == launch_only > 0
+    assert all(names[i:i + 3] == tail
+               and loop[i + 1]["args"] == {"ahead": 0} for i in drains)
+    # every phase once a step, wherever its call put it
+    for phase in ENGINE_PHASES:
+        assert len([s for s in spans
+                    if s["name"] == f"engine.{phase}"]) == steps
+    # a join sweep before every call, a joined slot's prefill between
     # them: a sibling of both; parks only with nothing active
     between = [nm for nm in names if nm != "engine.prefill"]
     assert all(between[i - 1] == "engine.join"
-               for i, nm in enumerate(between) if nm == "engine.step")
+               for i, nm in enumerate(between)
+               if nm in ("engine.step", tail[0]))
     assert all(names[i - 1] in ("engine.join", "engine.prefill")
                and "engine.step" in names[i:]
                for i, nm in enumerate(names) if nm == "engine.prefill")
@@ -717,9 +734,6 @@ def test_the_decode_ring_programs_keep_their_names(decoder, program, want):
 
 def test_the_engine_step_program_keeps_its_name(door):
     eng = door.engine
-    w = eng.width
     lowered = eng._step_fn(False).lower(
-        eng.params, eng._caches, jnp.zeros(w, jnp.int32),
-        jnp.zeros(w, jnp.int32), jnp.zeros(w, jnp.uint32),
-        jnp.zeros(w, jnp.float32))
+        eng.params, eng._caches, eng._prev_ids, *eng._blank_rows())
     assert _module_name(lowered) == "jit_step"

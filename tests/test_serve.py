@@ -553,9 +553,7 @@ def test_engine_step_holds_a_buffer_a_layer_and_writes_rows_in_place(
     assert set(eng._caches) == set(buffers) == {"k", "v"}
     for side, buf in buffers.items():
         assert [b.shape for b in eng._caches[side]] == [buf.shape] * n_layer
-    vec = jnp.zeros(w, jnp.int32)
-    args = (eng.params, eng._caches, vec, vec, vec.astype(jnp.uint32),
-            vec.astype(jnp.float32))
+    args = (eng.params, eng._caches, eng._prev_ids, *eng._blank_rows())
     for sample in (False, True):
         traced = eng._step_fn(sample).trace(*args)
         prims = []
@@ -586,14 +584,13 @@ def test_engine_writes_and_reads_the_last_position(gpt_setup):
     _, want, oracle_caches = _oracle_run(g, params, tokens, eng.max_len)
     step = eng._step_fn(False)
     caches = eng._caches
-    zeros = jnp.zeros(2, jnp.int32)
+    _, from_host, _, seeds, temps = eng._blank_rows()
     for pos, tok in enumerate(tokens):
         # slot 1 rides along at another position, with another token
-        ids, caches = step(eng.params, caches,
-                           jnp.asarray([tok, 5], jnp.int32),
-                           jnp.asarray([pos, last - pos], jnp.int32),
-                           zeros.astype(jnp.uint32),
-                           zeros.astype(jnp.float32))
+        ids, caches = step(eng.params, caches, eng._prev_ids,
+                           np.asarray([tok, 5], np.int32), from_host,
+                           np.asarray([pos, last - pos], np.int32),
+                           seeds, temps)
         assert int(ids[0]) == want[pos], pos
     for side in ("k", "v"):
         got = np.asarray(caches[side][0])[0]
@@ -614,6 +611,306 @@ def test_engine_validates_requests(gpt_setup):
     assert not eng.join(
         DecodeRequest(prompt=np.arange(3), max_new_tokens=1)), \
         "a full batch refuses joins until a slot frees"
+
+
+# -- one step launched ahead of the one whose tokens are read --------------
+
+_STEP_PHASES = ("gather", "dispatch", "upload", "launch", "device", "sync",
+                "delivery")
+
+
+def _decode_counts():
+    """(steps read, launched ahead, tokens, then one count a phase)."""
+    return [REGISTRY.histogram("serve.decode.step_s").count,
+            REGISTRY.counter("serve.decode.ahead.launched").value,
+            REGISTRY.counter("serve.decode.tokens").value,
+            *(REGISTRY.histogram(f"serve.decode.{p}_s").count
+              for p in _STEP_PHASES)]
+
+
+def _since(before):
+    return [a - b for a, b in zip(_decode_counts(), before)]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("plen", [1, 2, 4, 5, 11],
+                         ids=["1", "2", "L", "L+1", "L+7"])
+def test_engine_launched_ahead_answers_equal_the_request_run_alone(
+        gpt_setup, monkeypatch, plen, temperature):
+    """With a step always in flight, a request that joins beside two
+    others at a later step answers as it does alone: past its prompt a
+    slot's id comes from the device array the step before returned, and
+    a prompt token (its last; a tail longer than the prefill of L = 4
+    positions) from the host, behind ``from_host``."""
+    _prefill_positions(monkeypatch, 4)
+    g, params = gpt_setup
+    rng = np.random.default_rng(31 + plen)
+    prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
+               for n in (3, plen, 6)]
+
+    def make_reqs():
+        return [DecodeRequest(prompt=p, max_new_tokens=4, request_id=i,
+                              seed=7 + i,
+                              temperature=temperature if i == 1 else
+                              (0.0 if i == 0 else 0.6))
+                for i, p in enumerate(prompts)]
+
+    solo = {}
+    for req in make_reqs():
+        eng = ContinuousBatchEngine(g, params, num_stages=2, width=3, top_k=5)
+        solo[req.request_id] = eng.run_all([req])[req.request_id]
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=3, top_k=5)
+
+    def stagger(e, queue):
+        while queue and e.steps >= 2 * queue[0].request_id:
+            e.join(queue.pop(0))
+
+    before = _decode_counts()
+    batched = eng.run_all(make_reqs(), joiner=stagger)
+    for rid, ids in solo.items():
+        np.testing.assert_array_equal(batched[rid], ids)
+    steps, ahead, tokens, *phases = _since(before)
+    # only the first launch found nothing in flight
+    assert steps == eng.steps and ahead == steps - 1
+    assert tokens == 3 * 4 and phases == [steps] * len(_STEP_PHASES)
+    assert eng._flight is None
+
+
+def test_engine_launches_step_n_plus_1_before_it_reads_step_n(
+        gpt_setup, traced):
+    """Order of the spans of a steady run: ``launch(0)``, then
+    ``launch(n + 1)`` in front of ``sync(n)`` (``ahead`` 1: the device
+    runs under the wait), and the last ``sync`` with nothing behind it.
+    ``serve.decode.ahead.launched`` counts the launches that found a
+    step unread; ``step()`` has answers only from its second call on."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    req = DecodeRequest(prompt=np.arange(3), max_new_tokens=5)
+    assert eng.join(req)
+    before = _decode_counts()
+    assert eng.step() == [] and eng.steps == 0      # launched, not read
+    assert eng._flight is not None and _since(before)[:2] == [0, 0]
+    calls = 1
+    while eng.active():
+        done = eng.step()
+        calls += 1
+    assert [r for r, _ in done] == [req]
+    # 5 tokens are 5 steps (the prompt went through the prefill), read
+    # by calls 2..6; the last call launched nothing
+    assert (eng.steps, calls) == (5, 6)
+    assert _since(before)[:2] == [5, 4]
+    order = [(s["name"], s["args"].get("ahead"))
+             for s in sorted(traced.spans, key=lambda s: s["ts_us"])
+             if s["name"] in ("engine.launch", "engine.sync")]
+    assert order == [("engine.launch", None)] \
+        + [("engine.launch", None), ("engine.sync", 1)] * 4 \
+        + [("engine.sync", 0)]
+    roots = [s for s in traced.spans if s["name"] == "engine.step"]
+    assert sorted(s["args"]["step"] for s in roots) == list(range(5))
+
+
+def test_engine_slot_at_its_last_token_is_not_launched_ahead(gpt_setup):
+    """Which slots a step holds is known before any token of the step
+    in front of it: a slot whose last step is in flight is left out of
+    the next launch (its row is an idle slot's), nothing is discarded,
+    and ``serve.decode.tokens`` is the sum of the answers."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    short = DecodeRequest(prompt=np.arange(4), max_new_tokens=2,
+                          request_id=0)
+    long = DecodeRequest(prompt=np.arange(5), max_new_tokens=5,
+                         request_id=1)
+    assert eng.join(short) and eng.join(long)
+    before = _decode_counts()
+    held = []
+    out = {}
+    while eng.active():
+        for r, ids in eng.step():
+            out[r.request_id] = ids
+        if eng._flight is not None:
+            held.append(sorted(s.req.request_id
+                               for _i, s, _p in eng._flight.rows))
+    assert held == [[0, 1], [0, 1], [1], [1], [1]]
+    assert [out[i].size for i in (0, 1)] == [4 + 2, 5 + 5]
+    steps, ahead, tokens, *_ = _since(before)
+    assert (steps, ahead, tokens) == (5, 4, 2 + 5)
+
+
+def test_engine_on_done_fires_in_the_call_that_reads_the_last_token(
+        gpt_setup):
+    """Not a call later: the delivery of a request's last token frees
+    its slot and calls ``on_done`` with the ids ``step()`` returns."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    seen = []
+    req = DecodeRequest(prompt=np.arange(3), max_new_tokens=3,
+                        on_done=seen.append)
+    other = DecodeRequest(prompt=np.arange(2), max_new_tokens=6)
+    assert eng.join(req) and eng.join(other)
+    returned = []
+    for call in range(1, 5):
+        assert not seen
+        returned = eng.step()
+    # three tokens: launched by calls 1-3, the third read by call 4
+    assert call == 4 and len(seen) == 1
+    assert [r for r, _ in returned] == [req] and returned[0][1] is seen[0]
+    assert eng.free_slots() == 1 and eng._flight is not None
+    assert [s.req for _i, s, _p in eng._flight.rows] == [other]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9],
+                         ids=["greedy", "sampled"])
+def test_engine_cancel_and_rejoin_while_a_step_is_in_flight(
+        gpt_setup, temperature):
+    """A request cancelled between two calls leaves a row in the step in
+    flight; a newcomer joins into the freed slot while that step is
+    still unread.  The stale token is dropped at delivery (the flight
+    holds the slot object: the index is the newcomer's by then), the
+    cancelled request hears ``None`` once, the newcomer's answer equals
+    its solo run and the bystander's is undisturbed."""
+    g, params = gpt_setup
+    rng = np.random.default_rng(41)
+    p_gone, p_stay, p_new = (rng.integers(0, 97, (n,)).astype(np.int32)
+                             for n in (5, 3, 4))
+
+    def fresh(rid):
+        prompt = {1: p_stay, 2: p_new}[rid]
+        return DecodeRequest(prompt=prompt, max_new_tokens=6,
+                             request_id=rid, seed=rid,
+                             temperature=temperature)
+
+    solo = {rid: ContinuousBatchEngine(
+        g, params, num_stages=2, width=2).run_all([fresh(rid)])[rid]
+        for rid in (1, 2)}
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    heard = []
+    gone = DecodeRequest(prompt=p_gone, max_new_tokens=9, request_id=0,
+                         on_done=heard.append)
+    assert eng.join(gone) and eng.join(fresh(1))
+    for _ in range(3):
+        eng.step()
+    flight = eng._flight
+    assert [s.req.request_id for _i, s, _p in flight.rows] == [0, 1]
+    tokens0 = REGISTRY.counter("serve.decode.tokens").value
+    assert eng.cancel(gone) and heard == [None]
+    assert eng._flight is flight, "a live slot is left: nothing is read"
+    assert eng.join(fresh(2)) and eng._slots[0].req.request_id == 2
+    out = {}
+    for r, ids in eng.step():               # reads the stale step
+        out[r.request_id] = ids
+    # of its two rows only the bystander's token counted
+    assert REGISTRY.counter("serve.decode.tokens").value == tokens0 + 1
+    assert eng._slots[0].out == [] and eng._slots[0].pos == p_new.size
+    out.update(eng.run_all([]))
+    assert heard == [None] and not eng.cancel(gone)
+    for rid in (1, 2):
+        np.testing.assert_array_equal(out[rid], solo[rid])
+
+
+def test_engine_join_while_a_step_is_queued_takes_part_from_the_next(
+        gpt_setup):
+    """A request that joins between two calls is in no row of the step
+    in flight: its prefill and its first step go behind it."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    first = DecodeRequest(prompt=np.arange(3), max_new_tokens=8,
+                          request_id=0)
+    late = DecodeRequest(prompt=np.arange(1, 6), max_new_tokens=2,
+                         request_id=1)
+    solo = ContinuousBatchEngine(g, params, num_stages=2, width=2).run_all(
+        [DecodeRequest(prompt=late.prompt, max_new_tokens=2,
+                       request_id=1)])[1]
+    assert eng.join(first)
+    eng.step()
+    eng.step()
+    in_flight = eng._flight
+    fills = REGISTRY.histogram("serve.decode.prefill_s")
+    fills0 = fills.count
+    assert eng.join(late) and eng._slots[1].prefill == 4
+    assert [s.req for _i, s, _p in in_flight.rows] == [first]
+    eng.step()              # prefills, launches both, reads ``in_flight``
+    assert fills.count == fills0 + 1 and eng._slots[1].out == []
+    assert [(s.req.request_id, p) for _i, s, p in eng._flight.rows] \
+        == [(0, 4), (1, 4)]
+    np.testing.assert_array_equal(eng.run_all([])[1], solo)
+
+
+def test_engine_drains_the_step_in_flight(gpt_setup):
+    """``run_all`` returns every answer with nothing left in flight; a
+    cancellation that empties the engine reads the step in flight there
+    and then (no token counted, every phase closed), so a caller that
+    parks leaves nothing unread; ``drain`` with nothing in flight is a
+    no-op."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    reqs = [DecodeRequest(prompt=np.arange(2 + i), max_new_tokens=3 + i,
+                          request_id=i) for i in range(4)]
+    out = eng.run_all(reqs)
+    assert sorted(out) == [0, 1, 2, 3] and eng._flight is None
+    assert [out[i].size for i in range(4)] == [2 * i + 5 for i in range(4)]
+    assert eng.drain() == [] and eng.step() == []
+    heard = []
+    lone = DecodeRequest(prompt=np.arange(4), max_new_tokens=9,
+                         on_done=heard.append)
+    assert eng.join(lone)
+    eng.step()
+    eng.step()
+    before = _decode_counts()
+    assert eng._flight is not None and eng.cancel(lone)
+    assert heard == [None] and eng._flight is None and eng.active() == 0
+    steps, ahead, tokens, *phases = _since(before)
+    assert (steps, ahead, tokens) == (1, 0, 0)
+    # the step read had been gathered and launched a call earlier
+    assert phases == [0, 0, 0, 0, 1, 1, 1]
+    assert eng.step() == []
+
+
+def test_engine_loop_stop_reads_the_step_in_flight(gpt_setup):
+    """``EngineLoop.stop`` with a request mid-answer: the loop ends with
+    no error and nothing in flight, and every launched step was read
+    (``step_s`` and the phases count alike)."""
+    g, params = gpt_setup
+    engine = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    door = ServeFrontDoor(engine=engine,
+                          decode_defaults={"max_new_tokens": 12}).start()
+    host, port = door.address
+    ServeClient(host, port, "t", max_new_tokens=2).stream(
+        [np.arange(3, dtype=np.int32)])         # compiled
+    before = _decode_counts()
+    def ask():      # its answer never comes: the door is stopped first
+        with pytest.raises(ConnectionError):
+            ServeClient(host, port, "t", max_new_tokens=12).stream(
+                [np.arange(4, dtype=np.int32)])
+
+    client = threading.Thread(target=ask, daemon=True)
+    client.start()
+    deadline = time.monotonic() + 20
+    while engine.steps < 3 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    loop = door._engine_loop
+    loop.stop()
+    loop.join(10)
+    assert not loop.is_alive() and loop.error is None
+    assert engine._flight is None
+    steps, _ahead, _tokens, *phases = _since(before)
+    assert steps >= 3 and phases == [steps] * len(_STEP_PHASES)
+    door.stop()
+    client.join(10)
+
+
+def test_engine_keeps_one_step_program_a_sample_value(gpt_setup):
+    """Reading the ids from the device took no second program: one
+    ``jit_step`` for greedy batches, one for batches that sample, each
+    compiled once however the slots' ids are owned."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=2)
+    reqs = [DecodeRequest(prompt=np.arange(1 + 3 * i), max_new_tokens=4,
+                          request_id=i, temperature=0.5 * (i >= 2))
+            for i in range(4)]
+    assert len(eng.run_all(reqs)) == 4
+    assert sorted(eng._step_fns) == [False, True]
+    assert [fn._cache_size() for fn in eng._step_fns.values()] == [1, 1]
 
 
 # ---------------------------------------------------------------------------
