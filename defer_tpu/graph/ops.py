@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.grouped import grouped_gate_up, grouped_product
 from .ir import Op, ShapeSpec
 
 
@@ -774,6 +775,18 @@ def expert_dispatch(x, eid, gate, num_experts: int, expert_fn):
     y = jnp.sum(ys.astype(jnp.float32)
                 * gate[..., None].astype(jnp.float32), axis=1)
     return y, sizes
+
+
+def grouped_swiglu(xs, experts, sizes):
+    """The routed experts' SwiGLU on rows sorted by expert: ``xs [rows,
+    d]``, ``experts`` the stacks ``gate`` / ``up [E, d, h]`` and ``down
+    [E, h, d]``, ``sizes [E]`` rows each (they may sum to less than the
+    rows: the ``expert_fn`` of both dispatchers).  ``[rows, d]`` in
+    ``xs``'s type.  Which way a product goes — the kernel that streams
+    the touched matrices once, or ``lax.ragged_dot`` — is its static
+    shape's choice (``ops/grouped.py``)."""
+    a = grouped_gate_up(xs, experts["gate"], experts["up"], sizes)
+    return grouped_product(a, experts["down"], sizes)
 
 
 #: the most (row, choice) pairs one grouped product of

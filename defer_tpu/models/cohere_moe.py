@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import _cast, expert_dispatch_held, route_top_k
+from ..graph.ops import (_cast, expert_dispatch_held, grouped_swiglu,
+                         route_top_k)
 from .decoder import DecoderBlock
 from .olmoe import OlmoeEmbedding
 
@@ -182,13 +183,9 @@ class CohereMoeBlock(DecoderBlock, Op):
             jnp.dot(h, p["router"]["w"], preferred_element_type=f32),
             self.experts_per_tok, scoring="sigmoid")
 
-        def experts(xs, sizes):
-            a = jax.nn.silu(lax.ragged_dot(xs, ex["gate"], sizes)) \
-                * lax.ragged_dot(xs, ex["up"], sizes)
-            return lax.ragged_dot(a, ex["down"], sizes)
-
-        routed, sizes = expert_dispatch_held(h, eid, gate, self.held,
-                                             experts)
+        routed, sizes = expert_dispatch_held(
+            h, eid, gate, self.held,
+            lambda xs, sizes: grouped_swiglu(xs, ex, sizes))
         a = jax.nn.silu(h @ p["shared_gate"]["w"]) \
             * (h @ p["shared_up"]["w"])
         shared = jnp.dot(a, p["shared_down"]["w"],

@@ -23,11 +23,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch, rms_norm,
-                         route_top_k)
+from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch,
+                         grouped_swiglu, rms_norm, route_top_k)
 from .decoder import DecoderBlock
 
 
@@ -123,12 +122,9 @@ class OlmoeBlock(DecoderBlock, Op):
             jnp.dot(h, p["router"]["w"], preferred_element_type=f32),
             self.experts_per_tok)
 
-        def experts(xs, sizes, _es):
-            a = jax.nn.silu(lax.ragged_dot(xs, ex["gate"], sizes)) \
-                * lax.ragged_dot(xs, ex["up"], sizes)
-            return lax.ragged_dot(a, ex["down"], sizes)
-
-        out, sizes = expert_dispatch(h, eid, gate, self.num_experts, experts)
+        out, sizes = expert_dispatch(
+            h, eid, gate, self.num_experts,
+            lambda xs, sizes, _es: grouped_swiglu(xs, ex, sizes))
         if sow is not None:
             sow["moe.chosen"] = eid             # [T, k]: not a statistic
             sow["moe.assignments"] = jnp.sum(sizes)

@@ -47,7 +47,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from defer_tpu.models import cohere_moe
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
-from hlo_cache_ops import computations, count_cache_ops, weight_copies
+from hlo_cache_ops import (GroupedCounters, computations, count_cache_ops,
+                           grouped_products, weight_copies)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 LIMIT_GB = 15.0
@@ -90,14 +91,17 @@ def main() -> int:
     prompt = arg((1, mb, plen), jnp.int32, P(None, None, None))
 
     _, chunk_steps = dec._schedule(max_len, plen, chunk)
+    rule = {"prefill": GroupedCounters(), "decode": GroupedCounters()}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        prefill = dec._build_prefill_fn(plen, False, None).lower(
-            w, prompt, u32, f32, caches)
-        decode = dec._build_decode_fn(chunk_steps, False, None).lower(
-            w, prompt, i32, i32, i32, u32, f32,
-            arg((1, mb), jnp.int32, P(None, None)), i32, i32,
-            arg((1, mb, dec.d_model), jnp.float32,
-                P(STAGE_AXIS, None, None)), caches)
+        with rule["prefill"]:
+            prefill = dec._build_prefill_fn(plen, False, None).lower(
+                w, prompt, u32, f32, caches)
+        with rule["decode"]:
+            decode = dec._build_decode_fn(chunk_steps, False, None).lower(
+                w, prompt, i32, i32, i32, u32, f32,
+                arg((1, mb), jnp.int32, P(None, None)), i32, i32,
+                arg((1, mb, dec.d_model), jnp.float32,
+                    P(STAGE_AXIS, None, None)), caches)
     row = {"device_kind": topo.devices[0].device_kind,
            "prefill_rows_a_piece": dec._prefill_rows(plen)}
     # (the router's 4096 x 128 is the size of a step's 128 sorted rows,
@@ -135,7 +139,9 @@ def main() -> int:
             "alias_gb": m.alias_size_in_bytes / 1e9,
             "peak_gb": total, **copies, "cache_ops": cache_ops,
             "kernels": text.count('custom_call_target="tpu_custom_call"'),
-            "ragged_dots": text.count(" ragged-dot("),
+            # the shape rule (defer_tpu/ops/grouped.py): a step's
+            # products on the kernel, the prompt's on ragged-dot
+            **grouped_products(text), **rule[name].read,
             "flops": float(compiled.cost_analysis().get("flops", 0.0))}
         ok = ok and total <= LIMIT_GB and not copies["weight_copies_in_loop"] \
             and not any(c["buffer_copies"] for c in cache_ops.values())

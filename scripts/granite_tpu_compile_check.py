@@ -53,7 +53,8 @@ from defer_tpu.models import granite_hybrid
 from defer_tpu.ops.layered import shapes_by_layer
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
-from hlo_cache_ops import computations, count_cache_ops, weight_copies
+from hlo_cache_ops import (GroupedCounters, computations, count_cache_ops,
+                           grouped_products, weight_copies)
 from jamba_tpu_compile_check import argument_bytes
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -98,14 +99,17 @@ def main() -> int:
     prompt = arg((1, mb, plen), jnp.int32, P(None, None, None))
 
     _, chunk_steps = dec._schedule(max_len, plen, chunk)
+    rule = {"prefill": GroupedCounters(), "decode": GroupedCounters()}
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        prefill = dec._build_prefill_fn(plen, False, None).lower(
-            w, prompt, u32, f32, caches)
-        decode = dec._build_decode_fn(chunk_steps, False, None).lower(
-            w, prompt, i32, i32, i32, u32, f32,
-            arg((1, mb), jnp.int32, P(None, None)), i32, i32,
-            arg((1, mb, dec.d_model), jnp.float32,
-                P(STAGE_AXIS, None, None)), caches)
+        with rule["prefill"]:
+            prefill = dec._build_prefill_fn(plen, False, None).lower(
+                w, prompt, u32, f32, caches)
+        with rule["decode"]:
+            decode = dec._build_decode_fn(chunk_steps, False, None).lower(
+                w, prompt, i32, i32, i32, u32, f32,
+                arg((1, mb), jnp.int32, P(None, None)), i32, i32,
+                arg((1, mb, dec.d_model), jnp.float32,
+                    P(STAGE_AXIS, None, None)), caches)
     mamba = sum(kind == "ssm" for kind in dec.memory)
     # (keys and values have one shape: counted together under ``k``)
     buffers = {key: next(s for s in shapes[key] if s is not None).shape
@@ -163,6 +167,9 @@ def main() -> int:
             "state_over_need": state / need,
             "state_ops": state_ops,
             "kernels": text.count('custom_call_target="tpu_custom_call"'),
+            # the shape rule (defer_tpu/ops/grouped.py): a step's
+            # products on the kernel, the prompt's on ragged-dot
+            **grouped_products(text), **rule[name].read,
             "flops": float(compiled.cost_analysis().get("flops", 0.0))}
         # a decode step rewrites a layer's window whole, by design (one
         # fusion a Mamba layer produces it); nothing else may produce an

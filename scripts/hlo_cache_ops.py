@@ -141,3 +141,35 @@ def weight_copies(comps: dict[str, list[str]], matrices) -> dict:
     return {"weight_copies_in_loop": len(made["loop"]),
             "weight_copy_kinds_in_loop": sorted(set(made["loop"])),
             "weight_copies_per_dispatch": len(made["ENTRY"])}
+
+
+def grouped_products(text: str) -> dict:
+    """How a compiled program runs its routed experts' grouped products:
+    calls of the ``grouped_experts`` kernel (``defer_tpu/ops/grouped.py``)
+    and ``lax.ragged_dot``'s (which the TPU compiler turns into a call
+    of its own, ``%ragged-dot-none.7 = ... custom-call(``)."""
+    return {"grouped_experts_calls": len(re.findall(
+                r"%grouped_experts[.\d]* = .*tpu_custom_call", text)),
+            "ragged_dots": len(re.findall(
+                r"%ragged-dot[\w.\-]* = \S+ custom-call\(", text))}
+
+
+class GroupedCounters:
+    """The shape rule's two counters over a ``with`` block (a program's
+    lowering): ``.read`` holds what the block added to
+    ``moe.grouped.kernel_products`` / ``.ragged_products``."""
+
+    NAMES = ("moe.grouped.kernel_products", "moe.grouped.ragged_products")
+
+    def _now(self) -> list[int]:
+        from defer_tpu.obs import REGISTRY
+        return [REGISTRY.counter(name).value for name in self.NAMES]
+
+    def __enter__(self):
+        self._before = self._now()
+        self.read: dict = {}
+        return self
+
+    def __exit__(self, *exc):
+        self.read = {name: after - before for name, before, after
+                     in zip(self.NAMES, self._before, self._now())}

@@ -52,7 +52,8 @@ from chipbench.roofline_moe import olmoe_prefill_needs
 from defer_tpu.models import olmoe
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
-from hlo_cache_ops import computations, count_cache_ops, weight_copies
+from hlo_cache_ops import (GroupedCounters, computations, count_cache_ops,
+                           grouped_products, weight_copies)
 
 ARGS = dict(num_layers=8, hidden=2048, heads=16, seq_len=4096, vocab=50304,
             num_experts=64, experts_per_tok=8, expert_hidden=1024)
@@ -92,13 +93,15 @@ def main() -> int:
     # ``jax.default_backend()`` is not the TPU; this host's is the CPU
     # and the programs are the chip's
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        prefill = dec._build_prefill_fn(PLEN, False, None).lower(
-            w, prompt, u32, f32, caches)
-        decode = dec._build_decode_fn(chunk_steps, False, None).lower(
-            w, prompt, i32, i32, i32, u32, f32,
-            arg((1, MB), jnp.int32, P(None, None)), i32, i32,
-            arg((1, MB, dec.d_model), jnp.float32,
-                P(STAGE_AXIS, None, None)), caches)
+        with GroupedCounters() as prefill_rule:
+            prefill = dec._build_prefill_fn(PLEN, False, None).lower(
+                w, prompt, u32, f32, caches)
+        with GroupedCounters() as decode_rule:
+            decode = dec._build_decode_fn(chunk_steps, False, None).lower(
+                w, prompt, i32, i32, i32, u32, f32,
+                arg((1, MB), jnp.int32, P(None, None)), i32, i32,
+                arg((1, MB, dec.d_model), jnp.float32,
+                    P(STAGE_AXIS, None, None)), caches)
     prefill, decode = prefill.compile(), decode.compile()
 
     e, d, h = ARGS["num_experts"], ARGS["hidden"], ARGS["expert_hidden"]
@@ -142,7 +145,11 @@ def main() -> int:
            "prefill_flops": flops, "prefill_needs_flops": needs_flops,
            "prefill_flops_over_needs": flops / needs_flops,
            "expert_sized_values_produced_in_decode": produced[:8],
-           "ragged_dot_calls_in_decode": text.count("ragged"),
+           # the shape rule (defer_tpu/ops/grouped.py): a step's
+           # products on the kernel, the prompt's on ragged-dot
+           "decode_grouped": {**grouped_products(text), **decode_rule.read},
+           "prefill_grouped": {**grouped_products(prefill.as_text()),
+                               **prefill_rule.read},
            }
     print(json.dumps(row))
     ok = not produced and flops / needs_flops <= 1.5 and not (
