@@ -15,9 +15,7 @@ The graph follows the decoder-model contract (``embeddings`` /
 :class:`~defer_tpu.models.decoder.RetentionBlock`: the full-sequence
 graph rides ``SpmdPipeline`` and generation rides ``PipelinedDecoder``
 like the other families', with a state of fixed size where they keep a
-KV cache.  Every matrix — the blocks', the embedding's, the head's — is
-named in ``stage_arg_keys``: at 5120 x 17408 a leaf cut out of the
-ring's flat weight row would be laid out anew every step.
+KV cache.
 """
 
 from __future__ import annotations
@@ -29,10 +27,10 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import RMSNorm, _cast, rms_norm
+from ..graph.ops import Dense, RMSNorm, _cast, rms_norm
 from ..ops import retention
 from .decoder import RetentionBlock
-from .olmoe import OlmoeEmbedding, OlmoeHead, rope
+from .olmoe import OlmoeEmbedding, rope
 
 
 @dataclasses.dataclass(frozen=True, repr=False)
@@ -51,8 +49,6 @@ class BrumbyBlock(RetentionBlock, Op):
 
     #: sequences whose state a step really updated (a bubble sows 0)
     decode_stats = ("retention.updates",)
-    stage_arg_keys = ("q", "k", "v", "proj", "mlp_gate", "mlp_up",
-                      "mlp_down")
 
     @property
     def kv_heads(self) -> int:
@@ -189,7 +185,7 @@ def brumby(num_layers: int, hidden: int, heads: int, kv_heads: int,
                               head_dim=head_dim),
                   x, name=f"block_{i}")
     x = b.add(RMSNorm(eps=rms_eps), x, name="final_ln")
-    x = b.add(OlmoeHead(vocab, use_bias=False), x, name="lm_head")
+    x = b.add(Dense(vocab, use_bias=False), x, name="lm_head")
     return b.build()
 
 

@@ -22,9 +22,7 @@ The graph follows the decoder-model contract (``embeddings`` /
 ``block_i`` / ``final_ln`` / ``lm_head``, models/decoder.py).  A window
 layer publishes its ``window``, so the holder of its memory keeps a
 ring buffer of that many rows for it (``ops/kv_cache.py``) beside the
-full layers' row a position.  Every matrix — the blocks', the
-embedding's, the head's — is named in ``stage_arg_keys``; only the
-norms' scales ride the ring's flat weight row.
+full layers' row a position.
 """
 
 from __future__ import annotations
@@ -100,8 +98,6 @@ class CohereMoeBlock(DecoderBlock, Op):
 
     decode_stats = ("moe.assignments", "moe.held_assignments",
                     "moe.experts_hit", "moe.load_max")
-    stage_arg_keys = ("q", "k", "v", "proj", "router", "experts",
-                      "shared_gate", "shared_up", "shared_down")
 
     @property
     def kv_heads(self) -> int:
@@ -272,13 +268,10 @@ class ScaleLayerNorm(Op):
 class CohereHead(Op):
     """The output head, laid out as the embedding's table is —
     ``[vocab, d]``, ``logits = logit_scale * h w^T`` — so that the tied
-    model's head *is* that table (:func:`tie_head`); on the ring it is
-    an argument of the last stage's own, as the table is the first's."""
+    model's head *is* that table (:func:`tie_head`)."""
 
     vocab: int
     logit_scale: float = 1.0
-
-    stage_arg_keys = ("w",)
 
     def init(self, key, in_specs):
         (spec,) = in_specs

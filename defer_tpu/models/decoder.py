@@ -10,10 +10,9 @@ it, whatever its family.
   layer's own: a block whose attention has a ``window`` keeps a ring
   buffer of that many rows beside a neighbour that keeps every
   position, and a state-space block a state of fixed size beside a
-  block that keeps a KV cache), ``final_ln``, ``lm_head``.  Any of
-  them may name
-  ``stage_arg_keys``.  :func:`decoder_parts` checks a graph against
-  this and hands back its parts; both engines' constructors call it.
+  block that keeps a KV cache), ``final_ln``, ``lm_head``.
+  :func:`decoder_parts` checks a graph against this and hands back its
+  parts; both engines' constructors call it.
 * **Blocks**: :class:`DecoderBlock`, whose per-sequence memory is a KV
   cache: it hands key and value *columns* to the cache's format
   (``ops/kv_cache.py``) and takes the attention's output back.  Its
@@ -58,10 +57,7 @@ class DecoderBlock:
       block after attention, ``y`` [b, nh*hd] the attention of ``q``
       over the cache with the heads merged.  A block that names
       ``decode_stats`` adds one scalar under each of those names to the
-      dict ``sow``;
-    * ``stage_arg_keys``: keys of its parameter dict whose leaves the
-      ring passes as stage-sharded arguments of their own instead of
-      slicing them out of the flat weight row.
+      dict ``sow``.
 
     Between the halves the caller writes the columns into its cache and
     attends over it, through ``ops/kv_cache.py``; :meth:`decode` is that
@@ -71,8 +67,6 @@ class DecoderBlock:
 
     #: per-step scalars ``decode_finish`` sows (summed over a generation)
     decode_stats: tuple = ()
-    #: parameter subtrees kept out of the flat weight row
-    stage_arg_keys: tuple = ()
 
     def _attend(self, q, k, v, window: int | None = None):
         """Causal attention on [b, nh, t, hd] by ``attn_impl``: the
@@ -191,9 +185,9 @@ class RetentionBlock(DecoderBlock):
     * ``decode_finish(params, x [T, d], y [T, nh*hd], sow=None)``: the
       rest of the block after the retention's output ``y``.
 
-    The head geometry, ``decode_stats`` and ``stage_arg_keys`` are
-    :class:`DecoderBlock`'s.  No serving engine takes such a block yet
-    (``serve/engine.py`` refuses every block but GPT's).
+    The head geometry and ``decode_stats`` are :class:`DecoderBlock`'s.
+    No serving engine takes such a block yet (``serve/engine.py``
+    refuses every block but GPT's).
     """
 
     memory = "retention"
@@ -271,7 +265,7 @@ class StateSpaceBlock(DecoderBlock):
       ``y`` (the skip term, the gate, the output projection, the
       second half).
 
-    ``decode_stats`` and ``stage_arg_keys`` are :class:`DecoderBlock`'s.
+    ``decode_stats`` is :class:`DecoderBlock`'s.
     No serving engine takes such a block yet (``serve/engine.py``
     refuses every block but GPT's).
     """

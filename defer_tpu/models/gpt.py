@@ -42,10 +42,6 @@ class CausalTransformerBlock(DecoderBlock, TransformerBlock):
 
     num_kv_heads: int | None = None
 
-    #: every subtree: a leaf cut out of the ring's flat weight row is
-    #: laid out anew by the compiled program every step, matrix or not
-    stage_arg_keys = ("ln1", "qkv", "proj", "ln2", "fc1", "fc2")
-
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
@@ -126,10 +122,7 @@ class CausalTransformerBlock(DecoderBlock, TransformerBlock):
 
 
 class GptEmbedding(Op):
-    """Token + learned positional embeddings (GPT-2 style, no post-LN),
-    both tables arguments of their own on the ring."""
-
-    stage_arg_keys = ("wte", "wpe")
+    """Token + learned positional embeddings (GPT-2 style, no post-LN)."""
 
     def __init__(self, vocab: int, features: int, max_len: int):
         self.vocab = vocab
@@ -173,15 +166,6 @@ class GptEmbedding(Op):
         return out_spec.size
 
 
-@dataclasses.dataclass(frozen=True, repr=False)
-class GptHead(Dense):
-    """The output head, its matrix and bias arguments of their own on
-    the ring (``Dense`` itself names nothing: every family's graphs
-    share it)."""
-
-    stage_arg_keys = ("w", "b")
-
-
 def gpt(num_layers: int, hidden: int, heads: int, seq_len: int,
         vocab: int = 50257, kv_heads: int | None = None,
         ln_eps: float = 1e-6, name: str = "gpt") -> LayerGraph:
@@ -202,7 +186,7 @@ def gpt(num_layers: int, hidden: int, heads: int, seq_len: int,
                                          ln_eps=ln_eps),
                   x, name=f"block_{i}")
     x = b.add(LayerNorm(eps=ln_eps), x, name="final_ln")
-    x = b.add(GptHead(vocab), x, name="lm_head")
+    x = b.add(Dense(vocab), x, name="lm_head")
     return b.build()
 
 

@@ -29,9 +29,7 @@ adds the shared expert whole.  Both kinds of block sow one ledger: the
 four ``moe.*`` sums and ``ssm.updates``.
 
 The graph follows the decoder-model contract (``embeddings`` /
-``block_i`` / ``final_ln`` / ``lm_head``, models/decoder.py).  Every
-matrix and every vector of the mixer is named in ``stage_arg_keys``;
-only the norms' scales ride the ring's flat weight row.
+``block_i`` / ``final_ln`` / ``lm_head``, models/decoder.py).
 
 Layouts that differ from the published checkpoint's (all of layout,
 none of arithmetic): ``conv/w`` is ``[d_conv, E + 2 N]`` (taps lead); an
@@ -173,8 +171,6 @@ class GraniteMambaBlock(_ExpertHalf, StateSpaceBlock, Op):
     rms_eps: float = 1e-5
 
     decode_stats = _STATS
-    stage_arg_keys = ("in_proj", "conv", "ssm", "out_proj", "router",
-                      "experts", "shared_gate", "shared_up", "shared_down")
 
     @property
     def channels(self) -> int:
@@ -306,8 +302,6 @@ class GraniteAttentionBlock(_ExpertHalf, DecoderBlock, Op):
     attn_impl: str = "auto"
 
     decode_stats = _STATS
-    stage_arg_keys = ("q", "k", "v", "proj", "router", "experts",
-                      "shared_gate", "shared_up", "shared_down")
 
     @property
     def kv_heads(self) -> int:
@@ -387,7 +381,7 @@ class GraniteAttentionBlock(_ExpertHalf, DecoderBlock, Op):
 
 class GraniteEmbedding(OlmoeEmbedding):
     """The token embedding times ``embedding_multiplier`` (in the
-    table's own type); its table an argument of its own on the ring."""
+    table's own type)."""
 
     def __init__(self, vocab: int, features: int, max_len: int,
                  multiplier: float):

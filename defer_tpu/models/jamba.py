@@ -17,10 +17,7 @@ and a state of fixed size), :class:`JambaAttentionBlock` a
 :class:`~defer_tpu.models.decoder.DecoderBlock` (a KV cache, many query
 heads on few KV heads).  The graph follows the decoder-model contract
 (``embeddings`` / ``block_i`` / ``final_ln`` / ``lm_head``,
-models/decoder.py).  Every matrix and every per-channel vector of the
-mixer — the blocks', the embedding's, the head's — is named in
-``stage_arg_keys``; only the norms' scales ride the ring's flat weight
-row.
+models/decoder.py).
 
 Layouts that differ from the published checkpoint's (all of layout,
 none of arithmetic): ``conv/w`` is ``[d_conv, E]`` (taps lead),
@@ -91,8 +88,6 @@ class JambaMambaBlock(StateSpaceBlock, Op):
     rms_eps: float = 1e-6
 
     decode_stats = _STATS
-    stage_arg_keys = ("in_proj", "conv", "x_proj", "dt_proj", "ssm",
-                      "out_proj", "mlp_gate", "mlp_up", "mlp_down")
 
     def init(self, key, in_specs):
         (spec,) = in_specs
@@ -218,8 +213,6 @@ class JambaAttentionBlock(DecoderBlock, Op):
     attn_impl: str = "auto"
 
     decode_stats = _STATS
-    stage_arg_keys = ("q", "k", "v", "proj", "mlp_gate", "mlp_up",
-                      "mlp_down")
 
     @property
     def kv_heads(self) -> int:

@@ -11,9 +11,7 @@ The graph follows the decoder-model contract (``embeddings`` /
 (:class:`~defer_tpu.models.decoder.DecoderBlock`), so the full-sequence graph
 rides ``SpmdPipeline`` and generation rides ``PipelinedDecoder`` like the
 GPT family's.  Keys are rotated *before* they are cached: a cache row is
-final when it is written.  Every matrix — the blocks', the embedding's,
-the head's — is named in ``stage_arg_keys``; only the norms' scales ride
-the ring's flat weight row.
+final when it is written.
 """
 
 from __future__ import annotations
@@ -58,7 +56,6 @@ class OlmoeBlock(DecoderBlock, Op):
     attn_impl: str = "auto"
 
     decode_stats = ("moe.assignments", "moe.experts_hit", "moe.load_max")
-    stage_arg_keys = ("q", "k", "v", "proj", "router", "experts")
 
     @property
     def kv_heads(self) -> int:
@@ -180,10 +177,7 @@ class OlmoeBlock(DecoderBlock, Op):
 
 
 class OlmoeEmbedding(Op):
-    """Token embedding alone: positions enter through RoPE.  Its table is
-    an argument of its own on the ring."""
-
-    stage_arg_keys = ("wte",)
+    """Token embedding alone: positions enter through RoPE."""
 
     def __init__(self, vocab: int, features: int, max_len: int):
         self.vocab = vocab
@@ -207,15 +201,6 @@ class OlmoeEmbedding(Op):
         return out_spec.size
 
 
-@dataclasses.dataclass(frozen=True, repr=False)
-class OlmoeHead(Dense):
-    """The untied, bias-free output head, its matrix an argument of its
-    own on the ring (``Dense`` itself names nothing: every family's
-    graphs share it)."""
-
-    stage_arg_keys = ("w",)
-
-
 def olmoe(num_layers: int, hidden: int, heads: int, seq_len: int,
           vocab: int = 50304, num_experts: int = 64,
           experts_per_tok: int = 8, expert_hidden: int = 1024,
@@ -233,7 +218,7 @@ def olmoe(num_layers: int, hidden: int, heads: int, seq_len: int,
                              rms_eps=rms_eps),
                   x, name=f"block_{i}")
     x = b.add(RMSNorm(eps=rms_eps), x, name="final_ln")
-    x = b.add(OlmoeHead(vocab, use_bias=False), x, name="lm_head")
+    x = b.add(Dense(vocab, use_bias=False), x, name="lm_head")
     return b.build()
 
 

@@ -1,4 +1,5 @@
-"""Device-side block-scale int8 quantization for inter-stage transfers.
+"""The int8 formats: block-scale quantization of inter-stage transfers on
+the device, and channel-scale quantization of resident weights (W8A16).
 
 The TPU-idiomatic analogue of the reference's lossy ZFP activation
 compression (reference src/node.py:107, src/dispatcher.py:92): instead of
@@ -14,10 +15,47 @@ comparable to the default ZFP tolerance the reference ships.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
+import numpy as np
+
+import jax
 import jax.numpy as jnp
 
 #: values per shared scale
 BLOCK = 256
+
+
+class Int8Weight(NamedTuple):
+    """A weight leaf held int8 (W8A16): its values in the leaf's own
+    shape and one float32 scale a channel of its last axis.  A pytree
+    node, so what maps over a weight tree's arrays maps over both."""
+
+    q: jax.Array | np.ndarray
+    scale: jax.Array | np.ndarray
+
+
+def quantize_weight(leaf) -> Int8Weight:
+    """Symmetric int8 with channel-wise (last-axis) scales, on the host.
+    A 1-D leaf (a norm's scale, a bias) gets a scale an element —
+    exactly invertible."""
+    a = np.asarray(leaf, np.float32)
+    red = tuple(range(a.ndim - 1))      # every axis but the last
+    scale = np.maximum(np.abs(a).max(axis=red) / 127.0, 1e-12)
+    q = np.clip(np.rint(a / scale), -127, 127).astype(np.int8)
+    return Int8Weight(q, scale.astype(np.float32))
+
+
+def dequantize_weights(tree, dtype):
+    """``tree`` with every :class:`Int8Weight` in it a ``dtype`` array
+    (inside jit).  The multiply stays next to the consuming op, where
+    XLA fuses it: HBM traffic is the int8 bytes plus the scales."""
+    def held(x):
+        return isinstance(x, Int8Weight)
+
+    return jax.tree.map(
+        lambda x: x.q.astype(dtype) * x.scale.astype(dtype) if held(x)
+        else x, tree, is_leaf=held)
 
 
 def quantize_int8_blocks(x: jnp.ndarray, use_pallas: bool | None = None):
@@ -28,7 +66,6 @@ def quantize_int8_blocks(x: jnp.ndarray, use_pallas: bool | None = None):
     On TPU the fused Pallas kernel (``ops/quant_pallas.py``) runs instead
     of this jnp reference; pass ``use_pallas`` to force either path.
     """
-    import jax
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:

@@ -12,11 +12,10 @@ on different in-flight inputs" (SURVEY.md §0) transposed to token time.
 TPU-native design, one SPMD program:
 
   * Weights: each device holds only its stage's parameters, stored in
-    the compute dtype: the leaves a node names in ``stage_arg_keys`` as
-    stage-sharded arguments of their own, in their own shapes (a leaf
-    cut out of a flat row is laid out anew by the compiled program,
-    every step), the rest in a stage-sharded flat buffer (the scheme of
-    ``SpmdPipeline``).
+    the compute dtype (int8 beside its scales under
+    ``weight_dtype="int8"``), every leaf a stage-sharded argument of its
+    own, in its own shape: local block ``l``'s leaves stacked over the
+    stages, the leaves of the nodes outside the blocks by name.
   * Sequence memory: per device, one resident buffer a local block and
     key, held and touched only through the format *that block* names
     (``DecoderBlock.memory_format``: kind, geometry and length are the
@@ -82,10 +81,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
 from ..obs import REGISTRY, span
+from ..ops import quant
 from ..ops.layered import shapes_by_layer, zeros_by_layer
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
-from . import flatbuf
 
 
 #: the most a piece of a group's prefill may hold in its widest
@@ -105,6 +104,14 @@ def sample_ids(logits, temp, top_k, step_key):
         kth = lax.top_k(lg, top_k)[0][:, -1:]
         lg = jnp.where(lg >= kth, lg, -jnp.inf)
     return jax.random.categorical(step_key, lg, axis=-1)
+
+
+def _leaf_layout(tree) -> dict:
+    """``{path: (shape, dtype)}`` of a parameter tree's leaves as they
+    were handed over, before any cast."""
+    return {jax.tree_util.keystr(path): (np.shape(leaf), np.dtype(
+        leaf.dtype if hasattr(leaf, "dtype") else np.asarray(leaf).dtype))
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
 class PipelinedDecoder:
@@ -152,10 +159,9 @@ class PipelinedDecoder:
             raise ValueError(
                 f"weight_dtype must be None or 'int8', got {weight_dtype!r}")
         #: W8A16: weights live int8 in HBM with channel-wise (last-axis)
-        #: f32 scales, dequantized inside each stage branch.  Decode is
-        #: HBM-bandwidth-bound (every step streams all weights), so int8
-        #: halves the dominant traffic vs bf16.  1-D leaves (LN scales,
-        #: biases) get per-element scales — exactly invertible.
+        #: f32 scales (``ops/quant.py``), dequantized inside each stage
+        #: branch.  Decode is HBM-bandwidth-bound (every step streams all
+        #: weights), so int8 halves the dominant traffic vs bf16.
         self.weight_quant = weight_dtype == "int8"
         if beam_width < 1 or mb % beam_width:
             raise ValueError(
@@ -167,7 +173,7 @@ class PipelinedDecoder:
         parts = decoder_parts(graph, n, max_len)
         self.embed_op = parts.embed_op
         self.max_len = max_len = parts.max_len
-        self.block_names = block_names = list(parts.block_names)
+        self.block_names = list(parts.block_names)
         self.d_model = parts.d_model
         self.vocab = parts.vocab
         #: per-step scalars the blocks sow (``DecoderBlock.decode_stats``);
@@ -183,54 +189,28 @@ class PipelinedDecoder:
         #: side by side)
         self.state_formats = self._layer_formats()
 
-        # --- stage-sharded flat weight buffer (scheme of runtime/spmd.py)
-        stage_param_names: list[list[str]] = []
-        for s in range(n):
-            names = list(self.stage_blocks[s])
-            if s == 0:
-                names.insert(0, "embeddings")
-            if s == n - 1:
-                names += ["final_ln", "lm_head"]
-            stage_param_names.append(names)
-        self._stage_param_names = stage_param_names
-        #: per node, the parameter subtrees that ride beside the flat row
-        #: as stage-sharded arguments of their own (the op's
-        #: ``stage_arg_keys``): a leaf sliced out of the 1-D row is laid
-        #: out anew by the compiled program, a copy that 0.4 GB of
-        #: experts a layer, or a 1.6 GB embedding, cannot afford.  Under
-        #: W8A16 every leaf rides the quantized rows.
-        self._own_keys = {
-            nm: () if self.weight_quant
-            else tuple(getattr(nodes[nm].op, "stage_arg_keys", ()))
-            for names in stage_param_names for nm in names}
-        #: the nodes outside the blocks that keep such leaves, with the
-        #: one stage that holds each
-        self._own_ends = {nm: s for s, names in enumerate(stage_param_names)
-                          for nm in names
-                          if nm not in block_names and self._own_keys[nm]}
-
+        #: the nodes outside the blocks, with the one stage that holds
+        #: each
+        self._ends = {"embeddings": 0, "final_ln": n - 1, "lm_head": n - 1}
         # weights live in the compute dtype (the runtime/spmd.py recipe):
         # bf16 deployments read 2 bytes/param from HBM per decode step with
         # no per-step downcast materialization
-        wdt = np.dtype(jnp.bfloat16) if self.compute_dtype == jnp.bfloat16 \
-            else np.float32
-        self._wdt = wdt
-        self._wmeta, self._wtreedef = [], []
-        self._smeta: list[list[tuple[int, int]]] = []  # per-leaf scale slots
+        self._wdt = np.dtype(jnp.bfloat16) \
+            if self.compute_dtype == jnp.bfloat16 else np.dtype(np.float32)
         self._w = self._place_weights(params, init=True)
-        # how much of the deployment rides the flat rows, and how much
-        # beside them: leaves as packed, before the device's tiling
-        # pads them, stand-in blocks and other stages' ends not counted
-        held = sum(m[1] for meta in self._wmeta for m in meta)
-        REGISTRY.gauge("decode.weights.row_bytes").set(
-            held + 4 * sum(size for sm in self._smeta for _, size in sm)
-            if self.weight_quant else held * np.dtype(wdt).itemsize)
+        # what the deployment holds: leaves as placed (int8 values and
+        # their scales under W8A16), before the device's tiling pads
+        # them, a shorter stage's zeros and other stages' ends not
+        # counted.  No leaf rides a flat row (the gauge keeps its name
+        # for its readers)
+        shapes = [shape for node in self._layout.values()
+                  for shape, _ in node.values()]
+        REGISTRY.gauge("decode.weights.row_bytes").set(0)
         REGISTRY.gauge("decode.weights.own_bytes").set(
-            np.dtype(wdt).itemsize * sum(
-                np.size(leaf) for nm, keys in self._own_keys.items()
-                for leaf in jax.tree.leaves([params[nm][k] for k in keys])))
-        #: shard_map spec for the weight argument (a pytree under W8A16
-        #: or with leaves of their own)
+            sum(math.prod(sh) + 4 * math.prod(sh[-1:]) for sh in shapes)
+            if self.weight_quant
+            else self._wdt.itemsize * sum(map(math.prod, shapes)))
+        #: shard_map spec for the weight argument
         self._wspec_tree = jax.tree.map(
             lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
 
@@ -346,116 +326,89 @@ class PipelinedDecoder:
                    if mb % r == 0 and (r == 1 or r * row
                                        <= _PREFILL_PIECE_BYTES))
 
-    def _pack_wbuf(self, params, *, init: bool = False) -> np.ndarray:
-        """Pack ``params`` into the [N, Pmax] flat weight buffer; with
-        ``init=False`` (reweight) the new leaves must match the deployed
-        treedef/shapes/dtypes exactly (the compiled programs unflatten
-        with the init-recorded layout)."""
-        wdt = self._wdt
-        flats, qflats, sflats = [], [], []
-        for s, names in enumerate(self._stage_param_names):
-            sub = {nm: {k: v for k, v in params[nm].items()
-                        if k not in self._own_keys.get(nm, ())}
-                   for nm in names}
-            leaves, treedef = jax.tree.flatten(sub)
-            # meta records PRE-cast shapes/dtypes so reweight validation
-            # catches dtype drift before the blind wire-dtype cast
-            if init:
-                self._wmeta.append(flatbuf.leaf_meta(leaves))
-                self._wtreedef.append(treedef)
-            else:
-                flatbuf.check_layout(leaves, treedef, self._wmeta[s],
-                                     self._wtreedef[s],
-                                     f"reweight: stage {s}")
-            if not self.weight_quant:
-                flats.append(flatbuf.pack_leaves(
-                    [np.asarray(l).astype(wdt) for l in leaves], wdt))
-                continue
-            # W8A16: shared layout (flatbuf.quantize_leaves) — int8 values
-            # at leaf_meta's element offsets + a parallel f32 scale row
-            q_row, s_row, smeta = flatbuf.quantize_leaves(leaves)
-            if init:
-                self._smeta.append(smeta)
-            qflats.append(q_row)
-            sflats.append(s_row)
-        if not self.weight_quant:
-            return flatbuf.stack_rows(flats, wdt)
-        return {"q": flatbuf.stack_rows(qflats, np.dtype(np.int8)),
-                "s": flatbuf.stack_rows(sflats, np.dtype(np.float32))}
+    def _check_layers_alike(self, layout: dict) -> None:
+        """Local layer ``l``'s leaves are stacked over the stages, so its
+        block on every stage must have the parameter tree, shapes
+        included, of the longest stage's."""
+        def shapes(nm):
+            return {k: shape for k, (shape, _) in layout[nm].items()}
 
-    def _pack_own(self, params) -> tuple:
-        """The leaves kept out of the flat rows, placed: ``(own, ends)``.
-        ``own`` is per local block ``l`` a ``{key: subtree}`` whose
-        leaves are ``[N, ...]``, stage ``s``'s part being its ``l``-th
-        block's (zeros where a stage has fewer blocks), ``()`` when no
-        block names any; ``ends`` the same by name for the nodes outside
-        the blocks (zeros on every stage but the node's own).  One leaf
-        at a time goes host -> device, so the host never holds a second
-        copy of all of them."""
-        wdt = self._wdt
-
-        def placed(names, real):
-            """The own leaves of ``names`` (one a stage), stacked
-            ``[N, ...]``; a stage whose ``real`` is false gets zeros."""
-            def place(*per_stage):
-                rows = [np.asarray(a).astype(wdt, copy=False) if ok
-                        else np.zeros(np.shape(a), wdt)
-                        for ok, a in zip(real, per_stage)]
-                stacked = rows[0][None] if len(rows) == 1 \
-                    else np.stack(rows)
-                return jax.device_put(stacked, NamedSharding(
-                    self.mesh, P(STAGE_AXIS, *(None,) * (stacked.ndim - 1))))
-
-            return jax.tree.map(place, *(
-                {k: params[nm][k] for k in self._own_keys[nm]}
-                for nm in names))
-
-        own = []
-        if any(self._own_keys[nm] for nm in self.block_names):
-            for l in range(self.l_max):
-                # stage s's l-th block, or a stand-in (zeroed) where it
-                # has fewer: every leaf is [N, ...] whatever the split
-                own.append(placed(
-                    [b[min(l, len(b) - 1)] for b in self.stage_blocks],
-                    [l < len(b) for b in self.stage_blocks]))
-        ends = {nm: placed([nm] * self.num_stages,
-                           [s == at for s in range(self.num_stages)])
-                for nm, at in self._own_ends.items()}
-        return tuple(own), ends
+        longest = max(self.stage_blocks, key=len)
+        for s, names in enumerate(self.stage_blocks):
+            for l, nm in enumerate(names):
+                mine, other = shapes(nm), shapes(longest[l])
+                differ = sorted(k for k in mine.keys() | other.keys()
+                                if mine.get(k) != other.get(k))
+                if differ:
+                    raise ValueError(
+                        f"stage {s}'s layer {l} ({nm}) and {longest[l]} "
+                        "at the same place of its stage differ in their "
+                        f"parameters {differ}: the ring stacks a local "
+                        "layer's leaves over the stages, so every "
+                        "stage's layers must repeat the same parameter "
+                        "trees in the same order (cut the graph at a "
+                        "whole period of its layer pattern)")
 
     def _place_weights(self, params, *, init: bool):
-        """``params`` on the mesh as the compiled programs take them: the
-        flat rows alone, or ``{"flat": rows, "own": leaves}`` when blocks
-        keep leaves of their own (and ``"ends"`` when other nodes do)."""
-        flat = jax.device_put(self._pack_wbuf(params, init=init),
-                              NamedSharding(self.mesh, P(STAGE_AXIS, None)))
-        # the leaves outside the rows are held to what was deployed as
-        # the rows' are (``flatbuf.check_layout``): shapes, and the
-        # types they had before the cast to the compute type
-        layout = {nm: jax.tree.map(
-            lambda a: (np.shape(a), np.dtype(
-                a.dtype if hasattr(a, "dtype") else np.asarray(a).dtype)),
-            {k: params[nm][k] for k in keys})
-            for nm, keys in self._own_keys.items() if keys}
+        """``params`` on the mesh as the compiled programs take them:
+        ``{"blocks": (tree, ...), "ends": {name: tree}}``, every leaf
+        ``[N, ...]`` and sharded over the stages.  Stage ``s``'s part of
+        ``blocks[l]`` is its ``l``-th block's tree (zeros where it has
+        fewer blocks), of ``ends[name]`` that node's tree on the one
+        stage that holds it (zeros on the others).  Under W8A16 a leaf
+        is an ``Int8Weight`` of two such arrays.  ``init=False``
+        (reweight): the new leaves must have the tree, the shapes and
+        the types, before the cast to the compute type, of what was
+        deployed — the compiled programs take that and nothing else.
+        One leaf at a time goes host -> device, so the host never holds
+        a second copy of all of them."""
+        n, wdt = self.num_stages, self._wdt
+        layout = {nm: _leaf_layout(params[nm])
+                  for nm in (*self.block_names, *self._ends)}
         if init:
-            self._own_layout = layout
+            self._check_layers_alike(layout)
+            self._layout = layout
         for nm, got in layout.items():
-            if got != self._own_layout[nm]:
-                raise ValueError(
-                    f"reweight: {nm}'s leaves outside the flat rows are "
-                    f"{got}, deployed {self._own_layout[nm]}")
-        own, ends = self._pack_own(params)
-        if not own and not ends:
-            return flat
-        w = {"flat": flat, "own": own}
-        return dict(w, ends=ends) if ends else w
+            if got != self._layout[nm]:
+                raise ValueError(f"reweight: {nm}'s leaves are {got}, "
+                                 f"deployed {self._layout[nm]}")
+
+        def stacked(rows):
+            rows = rows[0][None] if n == 1 else np.stack(rows)
+            return jax.device_put(rows, NamedSharding(
+                self.mesh, P(STAGE_AXIS, *(None,) * (rows.ndim - 1))))
+
+        def placed(trees, real):
+            """``trees``, one a stage, stacked leaf by leaf; a stage
+            that is not ``real`` holds zeros of each leaf's shape."""
+            def place(*per_stage):
+                rows = [np.asarray(a) if ok else np.zeros(np.shape(a), wdt)
+                        for ok, a in zip(real, per_stage)]
+                if self.weight_quant:
+                    return quant.Int8Weight(*map(stacked, zip(
+                        *map(quant.quantize_weight, rows))))
+                return stacked([r.astype(wdt, copy=False) for r in rows])
+
+            return jax.tree.map(place, *trees)
+
+        blocks = []
+        for l, longest in enumerate(max(self.stage_blocks, key=len)):
+            # stage s's l-th block; where it has fewer, the longest
+            # stage's stands in for the shapes
+            real = [l < len(b) for b in self.stage_blocks]
+            blocks.append(placed(
+                [params[b[l] if ok else longest]
+                 for ok, b in zip(real, self.stage_blocks)], real))
+        ends = {nm: placed([params[nm]] * n, [s == at for s in range(n)])
+                for nm, at in self._ends.items()}
+        return {"blocks": tuple(blocks), "ends": ends}
 
     def reweight(self, params) -> None:
         """Install fresh weights — no recompile, caches untouched.
 
         The decode analogue of ``SpmdPipeline.reweight``: compiled decode
-        and prefill programs read the flat buffer as an argument, so a
-        buffer swap redeploys (e.g. after further finetuning) without
+        and prefill programs read the weights as arguments, so a swap
+        redeploys (e.g. after further finetuning) without
         invalidating ``_decode_fns``/``_prefill_fns``.  Call between
         ``generate`` rounds — an in-flight generation keeps the weights
         it started with only up to its current dispatch boundary.
@@ -473,22 +426,13 @@ class PipelinedDecoder:
             tail.block_until_ready()
 
     def _stage_params(self, s: int, w_local):
-        if isinstance(w_local, dict) and "own" in w_local:
-            p = flatbuf.unpack_leaves(w_local["flat"], self._wmeta[s],
-                                      self._wtreedef[s])
-            if w_local["own"]:
-                for l, nm in enumerate(self.stage_blocks[s]):
-                    p[nm] = dict(p[nm], **w_local["own"][l])
-            for nm, leaves in w_local.get("ends", {}).items():
-                if self._own_ends[nm] == s:
-                    p[nm] = dict(p[nm], **leaves)
-            return p
-        if not self.weight_quant:
-            return flatbuf.unpack_leaves(w_local, self._wmeta[s],
-                                         self._wtreedef[s])
-        return flatbuf.unpack_quant_leaves(
-            w_local["q"], w_local["s"], self._wmeta[s], self._smeta[s],
-            self._wtreedef[s], self.compute_dtype)
+        """Stage ``s``'s parameter trees by node, out of a device's part
+        of the weights."""
+        p = dict(zip(self.stage_blocks[s], w_local["blocks"]))
+        p.update((nm, w_local["ends"][nm])
+                 for nm, at in self._ends.items() if at == s)
+        return quant.dequantize_weights(p, self.compute_dtype) \
+            if self.weight_quant else p
 
     def _make_branch(self, s: int, sample: bool, top_k: int | None):
         """Stage ``s``'s step: consume the ring buffer, update caches.

@@ -1,12 +1,11 @@
 """Flat per-stage weight buffers: the shared pack/unpack scheme.
 
-Both pipeline runtimes (inference ``runtime/spmd.py``, decoding
-``runtime/decode.py``) ship each stage's parameter pytree as one flat row of
-a ``[num_stages, Pmax]`` array sharded over the ``stage`` mesh axis — the
+The stream pipeline (``runtime/spmd.py``, whose layout its trainer
+``runtime/training.py`` reads) ships each stage's parameter pytree as one flat
+row of a ``[num_stages, Pmax]`` array sharded over the ``stage`` mesh axis — the
 TPU-native replacement for the reference's runtime weight shipping
 (reference src/dispatcher.py:67-80): placement is a sharding annotation, not
-a socket protocol.  This module is the single definition of the row layout
-so both engines (and any future one) pack and unpack identically.
+a socket protocol.  This module is the single definition of the row layout.
 """
 
 from __future__ import annotations
@@ -38,7 +37,7 @@ def check_layout(leaves: Sequence[np.ndarray], treedef,
                  what: str) -> None:
     """Validate PRE-cast leaves + treedef against a deployed row layout.
 
-    The one rule both engines' ``reweight`` paths share: the compiled
+    The rule of ``SpmdPipeline.reweight``: the compiled
     programs unflatten with the init-recorded treedef/shapes, and a
     silent dtype change would blind-cast values — so structure, shapes,
     AND original dtypes must match or we raise before touching the
@@ -72,52 +71,6 @@ def stack_rows(rows: Sequence[np.ndarray], wire_dtype) -> np.ndarray:
     for i, r in enumerate(rows):
         buf[i, : r.size] = r
     return buf
-
-
-#: per-leaf scale slot within the scale row: (offset, size)
-ScaleMeta = tuple[int, int]
-
-
-def quantize_leaves(leaves: Sequence[np.ndarray]
-                    ) -> tuple[np.ndarray, np.ndarray, list[ScaleMeta]]:
-    """Symmetric int8 quantization with channel-wise (last-axis) scales.
-
-    Returns ``(q_row int8, scale_row f32, smeta)`` — the W8A16 leaf
-    layout: each leaf's int8 values at the SAME element offsets
-    ``leaf_meta`` records, plus a parallel f32 scale row.  1-D leaves
-    (LN scales, biases) get per-element scales — exactly invertible.
-    """
-    qs, ss, smeta, soff = [], [], [], 0
-    for leaf in leaves:
-        a = np.asarray(leaf, np.float32)
-        red = tuple(range(max(a.ndim - 1, 0)))  # all axes but the last
-        scale = np.maximum(np.abs(a).max(axis=red) / 127.0, 1e-12) \
-            if a.ndim else np.maximum(np.abs(a) / 127.0, 1e-12)
-        q = np.clip(np.rint(a / scale), -127, 127).astype(np.int8)
-        qs.append(q.ravel())
-        ss.append(np.asarray(scale, np.float32).ravel())
-        smeta.append((soff, ss[-1].size))
-        soff += ss[-1].size
-    q_row = np.concatenate(qs) if qs else np.zeros((0,), np.int8)
-    s_row = np.concatenate(ss) if ss else np.zeros((0,), np.float32)
-    return q_row, s_row, smeta
-
-
-def unpack_quant_leaves(q_local: jax.Array, s_local: jax.Array,
-                        meta: Sequence[LeafMeta],
-                        smeta: Sequence[ScaleMeta], treedef, dtype):
-    """Rebuild a pytree from its int8 row + scale row (inside jit).
-
-    The dequant multiply stays next to the consuming op so XLA fuses it;
-    HBM traffic is the int8 bytes plus the (negligible) scales.
-    """
-    leaves = []
-    for (off, size, shape, _dt), (soff, ssize) in zip(meta, smeta):
-        q = lax.slice(q_local, (off,), (off + size,)).reshape(shape)
-        sc = lax.slice(s_local, (soff,), (soff + ssize,))
-        sc = sc.reshape(shape[-1:] if shape else ())
-        leaves.append(q.astype(dtype) * sc.astype(dtype))
-    return jax.tree.unflatten(treedef, leaves)
 
 
 def unpack_leaves(w_local: jax.Array, meta: Sequence[LeafMeta], treedef,

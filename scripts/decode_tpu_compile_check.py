@@ -25,9 +25,9 @@ a group's, under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 ~30 s at the default; one JSON line; exit 0 when the compiled text
 writes rows in place and holds no whole-cache copy, no item-sized slice
 or copy inside a step, no whole-buffer conversion around the loop and,
-inside the loop, no copy of a weight matrix (a leaf cut out of the flat
-weight row and laid out anew every step: the GPT family's nodes name
-every leaf an argument of its own since PR 32), 1 otherwise (2 when the
+inside the loop, no copy of a weight matrix (a leaf cut out of a flat
+row of weights is laid out anew every step: the ring hands every leaf
+over as an argument of its own), 1 otherwise (2 when the
 program does not fit the chip).  A process
 of its own on purpose: loading the TPU's library takes a machine-wide
 lock (``/tmp/libtpu_lockfile``) that is held until the process ends, so
@@ -79,8 +79,8 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    # the weights as the ring placed them: the flat row and the leaves
-    # beside it, each behind the stage axis
+    # the weights as the ring placed them: every leaf an argument of
+    # its own behind the stage axis
     w = jax.tree.map(lambda a: arg(
         a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1))), dec._w)
     # the format's buffers behind the ring's own stage axis

@@ -120,9 +120,9 @@ def count_cache_ops(comps: dict[str, list[str]], item_dims, buffer_dims=None,
 def weight_copies(comps: dict[str, list[str]], matrices) -> dict:
     """The instructions, fusions' bodies and prefetches aside, that
     *produce* an array of the size of one of ``matrices`` (shapes): a
-    leaf that rides the ring's flat weight row is cut out of it and laid
-    out anew inside the loop, every step; one handed over as an argument
-    of its own is only read.  ``in_loop`` counts those of the loop's
+    leaf cut out of a flat row of weights is laid out anew inside the
+    loop, every step; one handed over as an argument of its own, as the
+    ring hands every leaf, is only read.  ``in_loop`` counts those of the loop's
     computations, ``per_dispatch`` the entry computation's (a layout
     the loop wants otherwise than the caller holds it, converted once)."""
     sizes = {tuple(_dims(shape)) for shape in matrices}

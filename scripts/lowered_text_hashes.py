@@ -41,8 +41,29 @@ from defer_tpu.serve.engine import ContinuousBatchEngine
 PLEN, CHUNK = 5, 2
 
 
-def ring_programs(name, graph, stages, kv_caches=("buffer", "int8"),
-                  beams=(1, 2)):
+def ring_configurations():
+    """``(name, graph, stages, kv_caches, beams)`` of every ring
+    configuration listed (``scripts/ring_tokens.py`` generates over the
+    same)."""
+    every, plain = (("buffer", "int8"), (1, 2)), (("buffer",), (1,))
+    yield "gpt_tiny", gpt_tiny(), (1, 4), *every
+    yield "olmoe_tiny", olmoe_tiny(), (1, 2), *every
+    # olmoe_tiny's widths at four layers, for four stages
+    yield "olmoe_4l", olmoe(4, 64, 4, 16, vocab=211, num_experts=8,
+                            experts_per_tok=2, expert_hidden=32), (4,), *every
+    # a retention state has neither int8 rows nor beams
+    yield "brumby_tiny", brumby_tiny(), (1, 2), *plain
+    # window layers' ring buffers beside full layers' caches: one period
+    # a stage
+    yield "cohere_moe_tiny", cohere_moe_tiny(), (1, 2), *every
+    # state-space layers' windows and states beside attention layers'
+    # caches: a state has neither int8 rows nor beams
+    yield "jamba_tiny", jamba_tiny(), (1, 2), *plain
+    # the state-space state's second shape, and every layer routing
+    yield "granite_hybrid_tiny", granite_hybrid_tiny(), (1, 2), *plain
+
+
+def ring_programs(name, graph, stages, kv_caches, beams):
     params = graph.init(jax.random.key(0))
     for n in stages:
         for kv_cache in kv_caches:
@@ -93,26 +114,8 @@ def main() -> int:
     out_dir = sys.argv[1] if len(sys.argv) > 1 else None
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)
-    programs = [*ring_programs("gpt_tiny", gpt_tiny(), (1, 4)),
-                *ring_programs("olmoe_tiny", olmoe_tiny(), (1, 2)),
-                # olmoe_tiny's widths at four layers, for four stages
-                *ring_programs("olmoe_4l", olmoe(
-                    4, 64, 4, 16, vocab=211, num_experts=8,
-                    experts_per_tok=2, expert_hidden=32), (4,)),
-                # a retention state has neither int8 rows nor beams
-                *ring_programs("brumby_tiny", brumby_tiny(), (1, 2),
-                               kv_caches=("buffer",), beams=(1,)),
-                # window layers' ring buffers beside full layers' caches:
-                # one period a stage
-                *ring_programs("cohere_moe_tiny", cohere_moe_tiny(), (1, 2)),
-                # state-space layers' windows and states beside attention
-                # layers' caches: a state has neither int8 rows nor beams
-                *ring_programs("jamba_tiny", jamba_tiny(), (1, 2),
-                               kv_caches=("buffer",), beams=(1,)),
-                # the state-space state's second shape, and every layer
-                # routing
-                *ring_programs("granite_hybrid_tiny", granite_hybrid_tiny(),
-                               (1, 2), kv_caches=("buffer",), beams=(1,)),
+    programs = [*(program for cfg in ring_configurations()
+                  for program in ring_programs(*cfg)),
                 *engine_programs()]
     for name, lowered in programs:
         text = lowered.as_text()

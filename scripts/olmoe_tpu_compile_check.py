@@ -12,9 +12,9 @@ What it answers before any chip time is spent (PERF.md, PR 26):
 * is a weight matrix copied anywhere in the decode program's loop (an
   instruction that produces an array of a matrix's size): every matrix
   — the experts', ``q`` / ``k`` / ``v`` / ``proj`` / ``router``, the
-  embedding's, the head's — is a stage-sharded argument of its own
-  (PR 35; the experts' since PR 26) because a leaf cut out of the flat
-  weight row is one, every step (``weight_copies_in_loop``;
+  embedding's, the head's — is a stage-sharded argument of its own,
+  as every leaf is (PR 44), because a leaf cut out of a flat row of
+  weights is one, every step (``weight_copies_in_loop``;
   ``weight_copies_per_dispatch`` counts layout conversions around the
   loop, once a call);
 * does a step cut a group's item out of a cache buffer, or the compiled

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from chipbench.agreement import logit_gaps, rel_err
 from chipbench.reference import brumby as ref
 from defer_tpu.models import brumby, brumby_tiny, gpt_tiny, olmoe_tiny
-from defer_tpu.models.brumby import BrumbyBlock
 from defer_tpu.models.decoder import (DecoderBlock, RetentionBlock,
                                       decoder_parts)
 from defer_tpu.obs import REGISTRY
@@ -314,16 +313,18 @@ def test_the_serving_engine_refuses_the_block_by_name(model):
         ContinuousBatchEngine(graph, params, num_stages=1, width=2)
 
 
-def test_every_matrix_rides_beside_the_flat_row(model):
-    """The blocks' matrices, the embedding and the head are arguments of
-    their own, each on the stage that holds it; the norms and the decay
-    map stay in the flat row; ``reweight`` swaps and checks them all."""
+def test_every_leaf_is_an_argument_of_its_own(model):
+    """The blocks' leaves — matrices, norms and the decay map alike —
+    the embedding, the last norm and the head are arguments of their
+    own, each on the stage that holds it; ``reweight`` swaps and checks
+    them all."""
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
                            max_len=SEQ)
-    assert set(dec._w) == {"flat", "own", "ends"}
-    assert set(dec._w["own"][0]) == set(BrumbyBlock.stage_arg_keys)
-    assert set(dec._w["ends"]) == {"embeddings", "lm_head"}
+    assert set(dec._w) == {"blocks", "ends"}
+    assert jax.tree.structure(dec._w["blocks"][0]) \
+        == jax.tree.structure(params["block_0"])
+    assert set(dec._w["ends"]) == {"embeddings", "final_ln", "lm_head"}
     wte = np.asarray(dec._w["ends"]["embeddings"]["wte"])
     assert wte.shape == (2, VOCAB, 64)
     np.testing.assert_array_equal(wte[0], params["embeddings"]["wte"])
@@ -335,13 +336,13 @@ def test_every_matrix_rides_beside_the_flat_row(model):
     assert not np.array_equal(
         dec.generate(np.zeros((4, 4), np.int32), 4, prefill=True), before)
     wrong = dict(params, lm_head={"w": params["lm_head"]["w"][:, :-1]})
-    with pytest.raises(ValueError, match="lm_head's leaves outside"):
+    with pytest.raises(ValueError, match="reweight: lm_head's leaves"):
         dec.reweight(wrong)
-    # GPT-2's ends are named too (PR 32), and OLMoE's (PR 35)
+    # the same ends, whatever the family
     for other in (gpt_tiny(), olmoe_tiny()):
         d2 = PipelinedDecoder(other, other.init(jax.random.key(0)),
                               num_stages=1, max_len=8)
-        assert set(d2._own_ends) == {"embeddings", "lm_head"}
+        assert set(d2._w["ends"]) == set(dec._w["ends"])
 
 
 def test_the_published_model_builds_at_its_widths():

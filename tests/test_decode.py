@@ -8,15 +8,19 @@ the stage ring).  Ground truth #2 is full-sequence recompute through
 only approximately).
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from defer_tpu.graph.ir import GraphBuilder
+from defer_tpu.graph.ops import Dense, LayerNorm
 from defer_tpu.models import gpt_stage_cuts, gpt_tiny
 from defer_tpu.models.decoder import decoder_parts, split_blocks
-from defer_tpu.models.gpt import CausalTransformerBlock
+from defer_tpu.models.gpt import CausalTransformerBlock, GptEmbedding
 from defer_tpu.ops.kv_cache import KVCacheFormat
 from defer_tpu.runtime.decode import PipelinedDecoder
 
@@ -62,7 +66,8 @@ def full_recompute_greedy(graph, params, prompt, t_tok):
 
 
 def _weight_gauges():
-    """(bytes on the flat rows, bytes beside them) of the newest decoder."""
+    """(0, bytes placed) of the newest decoder: the first gauge is kept
+    for the benchmark's drivers, no leaf rides a flat row."""
     from defer_tpu.obs import REGISTRY
     return (REGISTRY.gauge("decode.weights.row_bytes").value,
             REGISTRY.gauge("decode.weights.own_bytes").value)
@@ -306,8 +311,9 @@ def test_defer_score(model, prompt):
 
 def test_w8a16_weight_quant_decode(model, prompt):
     """int8 weight-only decoding (channel-wise scales, dequant fused in
-    the stage branch): the buffer really is int8, generations agree
+    the stage branch): the leaves really are int8, generations agree
     strongly with the f32 engine, and reweight works under quant."""
+    from defer_tpu.ops.quant import Int8Weight
     graph, params = model
     ref = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                            max_len=MAX_LEN)
@@ -316,20 +322,20 @@ def test_w8a16_weight_quant_decode(model, prompt):
         leaf.nbytes for leaf in jax.tree.leaves(params))
     q = PipelinedDecoder(graph, params, num_stages=4, microbatch=2,
                          max_len=MAX_LEN, weight_dtype="int8")
-    # the blocks' declarations are not read under W8A16: every leaf rides
-    # the quantized rows
-    assert set(q._w) == {"q", "s"} and q._w["q"].dtype == jnp.int8
-    assert _weight_gauges()[1] == 0
+    # the tree the f32 engine placed, each leaf an int8 array of the
+    # leaf's shape beside one f32 scale a channel of its last axis
+    held = jax.tree.leaves(q._w, is_leaf=lambda x: isinstance(x, Int8Weight))
+    plain = jax.tree.leaves(ref._w)
+    assert jax.tree.structure(q._w, is_leaf=lambda x: isinstance(
+        x, Int8Weight)) == jax.tree.structure(ref._w)
+    for h, leaf in zip(held, plain):
+        assert h.q.dtype == jnp.int8 and h.q.shape == leaf.shape
+        assert h.scale.dtype == jnp.float32 \
+            and h.scale.shape == (4,) + leaf.shape[-1:]
     # the weight stream is 1 byte/elem vs 4 (scales only matter for the
-    # tiny 1-D leaves; on real geometries they are ~1/last_dim overhead):
-    # the int8 rows hold every leaf the f32 engine placed, on the row or
-    # beside it (zeroed stand-ins and padding aside)
-    held = sum(m[1] for meta in q._wmeta for m in meta)
-    assert held == ref_placed // 4
-    assert q._w["q"].nbytes == 4 * max(
-        sum(m[1] for m in meta) for meta in q._wmeta)
-    assert _weight_gauges()[0] == held + 4 * sum(
-        size for sm in q._smeta for _, size in sm)
+    # tiny 1-D leaves; on real geometries they are ~1/last_dim overhead)
+    assert _weight_gauges() == (0, ref_placed // 4 + 4 * sum(
+        leaf.shape[-1] for leaf in jax.tree.leaves(params)))
     a = ref.generate(prompt, 8)
     b = q.generate(prompt, 8)
     assert (b[:, :5] == prompt).all()          # exact prompt echo
@@ -388,12 +394,14 @@ def test_decoder_reweight_no_recompile(model, prompt, num_stages):
 @pytest.mark.parametrize("name,node,key", [
     ("gpt_tiny", "block_2", "qkv"), ("gpt_tiny", "block_0", "fc1"),
     ("gpt_tiny", "embeddings", "wte"), ("olmoe_tiny", "block_1", "q"),
-    ("olmoe_tiny", "block_0", "router"), ("olmoe_tiny", "embeddings", "wte")])
+    ("olmoe_tiny", "block_0", "router"), ("olmoe_tiny", "embeddings", "wte"),
+    ("gpt_tiny", "final_ln", "scale"), ("gpt_tiny", "block_1", "ln2"),
+    ("olmoe_tiny", "block_1", "q_norm"), ("olmoe_tiny", "final_ln", "scale")])
 def test_reweight_changed_matrices_and_wrong_shapes(prompt, name, node, key):
-    """The leaves a node keeps beside the flat rows (``stage_arg_keys``):
-    ``reweight`` with one changed gives what a fresh decoder on those
-    weights gives, through the programs already compiled, and one of
-    another shape is refused by its node's name."""
+    """``reweight`` with one leaf changed, a matrix or a norm's scale,
+    gives what a fresh decoder on those weights gives, through the
+    programs already compiled, and one of another shape or type is
+    refused by its node's name: one error path for every leaf."""
     graph, params = _family(name, seq_len=MAX_LEN, vocab=VOCAB)
     dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=4,
                            max_len=MAX_LEN)
@@ -417,44 +425,47 @@ def test_reweight_changed_matrices_and_wrong_shapes(prompt, name, node, key):
         lambda x: np.zeros(x.shape + (2,), np.float32), params[node][key])})
     with pytest.raises(ValueError, match=f"reweight: {node}'s leaves"):
         dec.reweight(wrong)
+    drift = dict(params)
+    drift[node] = dict(params[node], **{key: jax.tree.map(
+        lambda x: np.asarray(x).astype(np.float16), params[node][key])})
+    with pytest.raises(ValueError, match=f"reweight: {node}'s leaves"):
+        dec.reweight(drift)
     np.testing.assert_array_equal(dec.generate(prompt, 6, prefill=True), b)
 
 
 @pytest.mark.parametrize("num_stages", [1, 3, 4])
-def test_gpt_weights_ride_beside_the_flat_row(model, num_stages):
-    """What GPT-2's nodes declare (``stage_arg_keys``) the ring keeps out
-    of the flat row, as stage-sharded arguments of their own: only
-    ``final_ln`` is left on it, and the two gauges say so."""
-    from defer_tpu.models.gpt import GptEmbedding, GptHead
+def test_gpt_weights_are_arguments_of_their_own(model, num_stages):
+    """Every leaf of GPT-2's nodes, ``final_ln``'s with the matrices, is
+    a stage-sharded argument of its own in its own shape — the ring asks
+    a node nothing about placement — and the gauges say so."""
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=num_stages,
                            microbatch=2, max_len=MAX_LEN)
-    assert set(dec._w) == {"flat", "own", "ends"}
-    assert len(dec._w["own"]) == dec.l_max
-    for leaves in dec._w["own"]:
-        assert set(leaves) == set(CausalTransformerBlock.stage_arg_keys) \
-            == set(params["block_0"])
-    assert set(dec._w["ends"]) == {"embeddings", "lm_head"}
-    assert set(dec._w["ends"]["embeddings"]) \
-        == set(GptEmbedding.stage_arg_keys) == set(params["embeddings"])
-    assert set(dec._w["ends"]["lm_head"]) \
-        == set(GptHead.stage_arg_keys) == set(params["lm_head"])
-    for leaf in jax.tree.leaves((dec._w["own"], dec._w["ends"])):
-        assert leaf.shape[0] == num_stages
-    # a stage with fewer blocks than the fullest holds a zeroed stand-in
+    assert set(dec._w) == {"blocks", "ends"}
+    assert len(dec._w["blocks"]) == dec.l_max
+    for tree in dec._w["blocks"]:
+        assert jax.tree.structure(tree) \
+            == jax.tree.structure(params["block_0"])
+    last = num_stages - 1
+    assert set(dec._w["ends"]) == {"embeddings", "final_ln", "lm_head"}
+    for nm, at in (("embeddings", 0), ("final_ln", last), ("lm_head", last)):
+        # each end whole on the stage that holds it, zeros elsewhere
+        for got, want in zip(jax.tree.leaves(dec._w["ends"][nm]),
+                             jax.tree.leaves(params[nm]), strict=True):
+            assert got.shape == (num_stages,) + want.shape
+            np.testing.assert_array_equal(np.asarray(got[at]), want)
+            assert not np.delete(np.asarray(got), at, axis=0).any()
+    # a stage with fewer blocks than the fullest holds zeros
     for s, blocks in enumerate(dec.stage_blocks):
         for l in range(dec.l_max):
-            w = np.asarray(dec._w["own"][l]["qkv"]["w"][s])
+            w = np.asarray(dec._w["blocks"][l]["qkv"]["w"][s])
             if l < len(blocks):
                 np.testing.assert_array_equal(
                     w, np.asarray(params[blocks[l]]["qkv"]["w"]))
             else:
                 assert not w.any()
-    row, own = _weight_gauges()
-    assert row == sum(leaf.nbytes
-                      for leaf in jax.tree.leaves(params["final_ln"]))
-    assert row + own == sum(leaf.nbytes for leaf in jax.tree.leaves(params))
-    assert dec._w["flat"].shape == (num_stages, row // 4)
+    assert _weight_gauges() == (0, sum(
+        leaf.nbytes for leaf in jax.tree.leaves(params)))
 
 
 def test_defer_score_bucketed_short_sequence(model):
@@ -1030,29 +1041,133 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
                       if beam > 1 else 0)
 
 
-@pytest.mark.parametrize("name,num_stages,beam", [
-    ("gpt_tiny", 1, 1), ("gpt_tiny", 4, 1), ("gpt_tiny", 3, 1),
-    ("gpt_tiny", 2, 2), ("olmoe_tiny", 1, 1), ("olmoe_tiny", 2, 1)])
-def test_decode_step_cuts_no_weight_out_of_the_flat_row(name, num_stages,
-                                                        beam):
+@dataclasses.dataclass(frozen=True, repr=False)
+class _GainBlock(CausalTransformerBlock):
+    """GPT-2's block with one more matrix, of which the class says
+    nothing to the ring."""
+
+    def init(self, key, in_specs):
+        d = in_specs[0].shape[-1]
+        return dict(super().init(key, in_specs),
+                    gain={"w": jnp.eye(d, dtype=jnp.float32)})
+
+    def decode_finish(self, params, x, y, sow=None):
+        return super().decode_finish(params, x, y, sow) @ params["gain"]["w"]
+
+
+def _gpt_of(blocks):
+    """GPT-2 at ``gpt_tiny``'s widths with the given block ops."""
+    b = GraphBuilder("gpt_of")
+    x = b.input((MAX_LEN,), jnp.int32)
+    x = b.add(GptEmbedding(VOCAB, 32, MAX_LEN), x, name="embeddings")
+    for i, op in enumerate(blocks):
+        x = b.add(op, x, name=f"block_{i}")
+    x = b.add(LayerNorm(), x, name="final_ln")
+    b.add(Dense(VOCAB), x, name="lm_head")
+    return b.build()
+
+
+@pytest.mark.parametrize("name,num_stages,beam,weight_dtype", [
+    ("gpt_tiny", 1, 1, None), ("gpt_tiny", 4, 1, None),
+    ("gpt_tiny", 3, 1, None), ("gpt_tiny", 2, 2, None),
+    ("olmoe_tiny", 1, 1, None), ("olmoe_tiny", 2, 1, None),
+    ("gain", 2, 1, None), ("gpt_tiny", 2, 1, "int8"),
+    ("olmoe_tiny", 2, 1, "int8")])
+def test_decode_step_takes_every_weight_in_its_own_shape(
+        name, num_stages, beam, weight_dtype):
     """Structural guard of the decode program's scan body: no ``slice``
-    or ``reshape`` produces a weight matrix, flat or in its shape.  A leaf
-    that rode the flat row was cut out of it and laid out anew inside
-    the loop, every step (12 of 21 ms a step at GPT-2 XL's widths on the
-    chip, 7 of 21 at OLMoE's with the experts alone beside the row); the
-    leaves the nodes name in ``stage_arg_keys`` arrive as arguments in
-    their own shapes."""
-    graph, params = _family(name, seq_len=MAX_LEN, vocab=VOCAB)
+    or ``reshape`` produces a weight, flat or in its shape.  A leaf cut
+    out of a flat row is laid out anew inside the loop, every step (12
+    of 21 ms a step at GPT-2 XL's widths on the chip, 7 of 21 at
+    OLMoE's with the experts alone out of the row); every leaf arrives
+    as an argument in its own shape, whatever its block's class and
+    whether it is held int8."""
+    if name == "gain":
+        graph = _gpt_of([_GainBlock(2)] * 4)
+        params = graph.init(jax.random.key(3))
+        assert params["block_0"]["gain"]["w"].shape == (32, 32)
+    else:
+        graph, params = _family(name, seq_len=MAX_LEN, vocab=VOCAB)
     dec = PipelinedDecoder(graph, params, num_stages=num_stages,
-                           microbatch=4, max_len=MAX_LEN, beam_width=beam)
-    # the row's cut (``flatbuf.unpack_leaves``): a 1-D slice of the
-    # leaf's size, reshaped to the leaf's shape
+                           microbatch=4, max_len=MAX_LEN, beam_width=beam,
+                           weight_dtype=weight_dtype)
+    # a row's cut: a 1-D slice of the leaf's size, reshaped to the
+    # leaf's shape
     shapes = {shape for leaf in jax.tree.leaves(params) if leaf.ndim > 1
               for shape in ((leaf.size,), leaf.shape)}
     cut = [eqn for eqn in _walk(_decode_scan_body(dec, 2 * num_stages))
            if eqn.primitive.name in ("slice", "reshape")
            and eqn.outvars[0].aval.shape in shapes]
     assert not cut, cut
+
+
+@pytest.mark.parametrize("other,differ", [
+    (_GainBlock(2), r"\['gain'\]\['w'\]"),
+    (CausalTransformerBlock(2, mlp_ratio=2), r"\['fc1'\]\['b'\].*\['fc2'\]\['w'\]"),
+], ids=["another_tree", "another_shape"])
+def test_layers_whose_parameter_trees_differ_are_refused(other, differ):
+    """Local layer ``l``'s leaves are stacked over the stages: two stages
+    whose ``l``-th blocks have different parameter trees, or one tree in
+    different shapes, are refused with both blocks' names and the leaves
+    that differ (one stage, which stacks nothing, takes them)."""
+    graph = _gpt_of([CausalTransformerBlock(2), other])
+    params = graph.init(jax.random.key(3))
+    with pytest.raises(ValueError, match=(
+            rf"stage 1's layer 0 \(block_1\) and block_0 .*{differ}")):
+        PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                         max_len=MAX_LEN)
+    one = PipelinedDecoder(graph, params, num_stages=1, microbatch=2,
+                           max_len=MAX_LEN)
+    prompt = np.arange(10).reshape(2, 5) % VOCAB
+    np.testing.assert_array_equal(
+        one.generate(prompt, 4),
+        incremental_greedy(graph, params, prompt, 9, MAX_LEN))
+
+
+@pytest.mark.parametrize("stages", [1, 2])
+@pytest.mark.parametrize("name", [
+    "gpt_tiny", "olmoe_tiny", "brumby_tiny", "cohere_moe_tiny", "jamba_tiny",
+    "granite_hybrid_tiny"])
+def test_the_weights_have_one_shape_whatever_the_family(name, stages):
+    """``_w`` is the local blocks' trees and the ends' trees for every
+    family, at one stage and at more, plain and W8A16: under W8A16 every
+    leaf is an int8 argument in its own shape beside its last axis's
+    f32 scales, and the bytes placed are a quarter of f32's plus the
+    scales."""
+    from defer_tpu.ops.quant import Int8Weight
+    graph, params = _family(name)
+
+    def held(x):
+        return isinstance(x, Int8Weight)
+
+    plain = PipelinedDecoder(graph, params, num_stages=stages, microbatch=2,
+                             max_len=16)
+    f32_bytes = _weight_gauges()[1]
+    assert f32_bytes == sum(l.nbytes for l in jax.tree.leaves(params))
+    q = PipelinedDecoder(graph, params, num_stages=stages, microbatch=2,
+                         max_len=16, weight_dtype="int8")
+    for dec in (plain, q):
+        assert set(dec._w) == {"blocks", "ends"}
+        assert isinstance(dec._w["blocks"], tuple) \
+            and len(dec._w["blocks"]) == dec.l_max
+        assert set(dec._w["ends"]) == {"embeddings", "final_ln", "lm_head"}
+    assert jax.tree.structure(q._w, is_leaf=held) \
+        == jax.tree.structure(plain._w)
+    for h, leaf in zip(jax.tree.leaves(q._w, is_leaf=held),
+                       jax.tree.leaves(plain._w), strict=True):
+        assert h.q.dtype == jnp.int8 and h.q.shape == leaf.shape
+        assert h.scale.dtype == jnp.float32 \
+            and h.scale.shape == (stages,) + leaf.shape[-1:]
+    assert _weight_gauges() == (0, f32_bytes // 4 + 4 * sum(
+        l.shape[-1] for l in jax.tree.leaves(params)))
+    # int8 of the leaf itself, scaled by its last axis's largest values
+    wte = np.asarray(params["embeddings"]["wte"])
+    got = q._w["ends"]["embeddings"]["wte"]
+    scale = np.abs(wte).max(axis=0) / 127
+    np.testing.assert_allclose(np.asarray(got.scale[0]), scale, rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(got.q[0]), np.clip(np.rint(wte / np.asarray(
+            got.scale[0])), -127, 127))
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["buffer", "int8"])

@@ -459,13 +459,16 @@ def test_the_blocks_declare_their_memory(model):
         ssm.SsdFormat(8, 16, 16, 4, 8, jnp.bfloat16, groups=2)
     assert mamba.decode_stats == attn.decode_stats == STATS
     assert decoder_parts(graph, 2).decode_stats == STATS
-    # every matrix and every vector of the mixer is an argument of its
-    # own; the norms' scales ride the row
+    # every leaf is an argument of its own on the ring, the norms'
+    # scales with the matrices and the mixer's vectors
     params = graph.init(jax.random.key(0))
-    for op, name, row in ((mamba, "block_0", {"ln1", "ln2", "gate_norm"}),
-                          (attn, "block_2", {"ln1", "ln2"})):
-        assert {k for k in params[name]
-                if k not in op.stage_arg_keys} == row
+    for name, norms in (("block_0", {"ln1", "ln2", "gate_norm"}),
+                        ("block_2", {"ln1", "ln2"})):
+        assert norms < set(params[name])
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=SEQ)
+    for tree, nm in zip(dec._w["blocks"], dec.stage_blocks[1], strict=True):
+        assert jax.tree.structure(tree) == jax.tree.structure(params[nm])
     assert params["block_0"]["in_proj"]["w"].shape == (64, 296)
     assert params["block_0"]["conv"]["w"].shape == (4, 160)
     assert {k: v.shape for k, v in params["block_0"]["ssm"].items()} == {

@@ -392,11 +392,15 @@ def test_the_blocks_declare_their_memory(model):
     assert mamba.memory_format(64, SEQ, jnp.bfloat16, groups=2) == \
         ssm.SsmFormat(128, 8, 4, jnp.bfloat16, groups=2)
     assert mamba.decode_stats == attn.decode_stats == ("ssm.updates",)
-    # every matrix and per-channel vector is an argument of its own
+    # every leaf is an argument of its own on the ring, the five norms'
+    # scales with the matrices and the per-channel vectors
     params = graph.init(jax.random.key(0))
-    rest = {k: v for k, v in params["block_0"].items()
-            if k not in mamba.stage_arg_keys}
-    assert set(rest) == {"ln1", "ln2", "dt_norm", "b_norm", "c_norm"}
+    assert {"ln1", "ln2", "dt_norm", "b_norm", "c_norm"} \
+        < set(params["block_0"])
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=SEQ)
+    for tree, nm in zip(dec._w["blocks"], dec.stage_blocks[1], strict=True):
+        assert jax.tree.structure(tree) == jax.tree.structure(params[nm])
 
 
 @pytest.mark.parametrize("family, kinds, heads", [

@@ -251,24 +251,25 @@ def test_the_int8_cache_serves_the_same_blocks(model, ids):
 
 
 def test_the_ring_holds_expert_leaves_as_arguments_of_their_own(model):
-    """The experts ride beside the flat rows, a leaf a local block,
-    stage-sharded — and since PR 35 every other matrix with them: only
-    the norms' scales are in the rows; ``reweight`` swaps both and
-    checks both."""
+    """The experts are arguments of their own, a leaf a local block,
+    stage-sharded — as every other leaf is, the norms' scales with the
+    matrices; ``reweight`` swaps all of them and checks all of them."""
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
                            max_len=SEQ)
-    assert set(dec._w) == {"flat", "own", "ends"}
-    assert len(dec._w["own"]) == 1
-    own = dec._w["own"][0]["experts"]
+    assert set(dec._w) == {"blocks", "ends"}
+    assert len(dec._w["blocks"]) == 1
+    own = dec._w["blocks"][0]["experts"]
     assert {k: v.shape for k, v in own.items()} == {
         "gate": (2, 8, 64, 32), "up": (2, 8, 64, 32),
         "down": (2, 8, 32, 64)}
     np.testing.assert_array_equal(np.asarray(own["up"][1]),
                                   np.asarray(params["block_1"]["experts"]["up"]))
-    # a stage's share of the row: its block's four scales, and the
-    # last stage's ``final_ln``
-    assert dec._w["flat"].shape == (2, 5 * 64)
+    # a block's four scales, each a stage's own
+    for key in ("ln1", "q_norm", "k_norm", "ln2"):
+        (scale,) = jax.tree.leaves(dec._w["blocks"][0][key])
+        assert scale.shape == (2,) + jax.tree.leaves(
+            params["block_0"][key])[0].shape
     before = dec.generate(np.zeros((4, 4), np.int32), 4)
     other = graph.init(jax.random.key(99))
     dec.reweight(other)
@@ -290,69 +291,64 @@ def _nbytes(tree):
 
 @pytest.mark.parametrize("layers,num_stages", [(2, 1), (2, 2), (3, 2)],
                          ids=["stages1", "stages2", "uneven"])
-def test_olmoe_weights_ride_beside_the_flat_row(layers, num_stages):
-    """What OLMoE's nodes declare (``stage_arg_keys``) the ring keeps out
-    of the flat row, as stage-sharded arguments of their own, each end
-    on the stage that holds it: only the norms' scales are left on the
-    row, and the two gauges say so."""
-    from defer_tpu.models.olmoe import OlmoeEmbedding, OlmoeHead
+def test_olmoe_weights_are_arguments_of_their_own(layers, num_stages):
+    """Every leaf of OLMoE's nodes is a stage-sharded argument of its
+    own in its own shape, the norms' scales with the matrices, each end
+    on the stage that holds it, and the gauges say so."""
     graph = olmoe(layers, 64, 4, SEQ, vocab=VOCAB, num_experts=8,
                   experts_per_tok=2, expert_hidden=32)
     params = graph.init(jax.random.key(4))
     dec = PipelinedDecoder(graph, params, num_stages=num_stages,
                            microbatch=2, max_len=SEQ)
     n, last = num_stages, num_stages - 1
-    assert set(dec._w) == {"flat", "own", "ends"}
-    assert len(dec._w["own"]) == dec.l_max == -(-layers // n)
-    scales = {"ln1", "q_norm", "k_norm", "ln2"}
-    for leaves in dec._w["own"]:
-        assert set(leaves) == set(OlmoeBlock.stage_arg_keys) \
-            == set(params["block_0"]) - scales
-    assert OlmoeEmbedding.stage_arg_keys == ("wte",)
-    assert OlmoeHead.stage_arg_keys == ("w",)
-    assert set(dec._w["ends"]) == {"embeddings", "lm_head"}
-    for leaf in jax.tree.leaves((dec._w["own"], dec._w["ends"])):
+    assert set(dec._w) == {"blocks", "ends"}
+    assert len(dec._w["blocks"]) == dec.l_max == -(-layers // n)
+    for tree in dec._w["blocks"]:
+        assert jax.tree.structure(tree) \
+            == jax.tree.structure(params["block_0"])
+    assert set(dec._w["ends"]) == {"embeddings", "final_ln", "lm_head"}
+    for leaf in jax.tree.leaves(dec._w):
         assert leaf.shape[0] == n
     # each end on the stage that holds it, zeros elsewhere
     wte = np.asarray(dec._w["ends"]["embeddings"]["wte"])
     head = np.asarray(dec._w["ends"]["lm_head"]["w"])
+    (norm,) = map(np.asarray, jax.tree.leaves(dec._w["ends"]["final_ln"]))
     np.testing.assert_array_equal(wte[0], params["embeddings"]["wte"])
     np.testing.assert_array_equal(head[last], params["lm_head"]["w"])
-    assert not wte[1:].any() and not head[:last].any()
-    # a stage with fewer blocks than the fullest holds a zeroed stand-in
+    np.testing.assert_array_equal(
+        norm[last], jax.tree.leaves(params["final_ln"])[0])
+    assert not wte[1:].any() and not head[:last].any() \
+        and not norm[:last].any()
+    # a stage with fewer blocks than the fullest holds zeros
     for s, blocks in enumerate(dec.stage_blocks):
         for l in range(dec.l_max):
-            for key in ("q", "router"):
-                w = np.asarray(dec._w["own"][l][key]["w"][s])
+            for key in ("q", "router", "ln1"):
+                (w,) = jax.tree.leaves(dec._w["blocks"][l][key])
+                w = np.asarray(w[s])
                 if l < len(blocks):
-                    np.testing.assert_array_equal(
-                        w, np.asarray(params[blocks[l]][key]["w"]))
+                    np.testing.assert_array_equal(w, np.asarray(
+                        jax.tree.leaves(params[blocks[l]][key])[0]))
                 else:
                     assert not w.any()
-    row = REGISTRY.gauge("decode.weights.row_bytes").value
-    own = REGISTRY.gauge("decode.weights.own_bytes").value
-    assert row == _nbytes(params["final_ln"]) + sum(
-        _nbytes(params[f"block_{i}"][k])
-        for i in range(layers) for k in scales) == (4 * layers + 1) * 64 * 4
-    assert row + own == _nbytes(params)
-    # the fullest stage's share, the last one's ``final_ln`` with it
-    assert dec._w["flat"].shape == (
-        n, 64 * max(4 * len(b) + (s == last)
-                    for s, b in enumerate(dec.stage_blocks)))
+    assert REGISTRY.gauge("decode.weights.row_bytes").value == 0
+    assert REGISTRY.gauge("decode.weights.own_bytes").value \
+        == _nbytes(params)
 
 
 def test_an_uneven_split_pads_the_leaves_of_the_shorter_stage(ids):
     """Three layers over two stages (2 + 1): the second local block's
-    leaves are [2, ...] with a zeroed stand-in on the stage that has
-    none, and the tokens are the one-stage decoder's."""
+    leaves are [2, ...] with zeros on the stage that has none, and the
+    tokens are the one-stage decoder's."""
     graph = olmoe(3, 64, 4, SEQ, vocab=VOCAB, num_experts=8,
                   experts_per_tok=2, expert_hidden=32)
     params = graph.init(jax.random.key(4))
     two = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
                            max_len=SEQ)
     assert [len(b) for b in two.stage_blocks] == [2, 1]
-    second = two._w["own"][1]["experts"]["down"]
+    second = two._w["blocks"][1]["experts"]["down"]
     assert second.shape == (2, 8, 32, 64) and not np.asarray(second[1]).any()
+    (scale,) = jax.tree.leaves(two._w["blocks"][1]["ln1"])
+    assert scale.shape == (2, 64) and not np.asarray(scale[1]).any()
     one = PipelinedDecoder(graph, params, num_stages=1, microbatch=4,
                            max_len=SEQ)
     np.testing.assert_array_equal(
@@ -496,8 +492,12 @@ def test_blocks_of_both_families_meet_the_rings_interface(model):
     for name in ("rows", "write_position", "write_slots", "write_prefix",
                  "reparent", "attend"):
         assert callable(getattr(KVCacheFormat, name))
-    assert OlmoeBlock.stage_arg_keys == ("q", "k", "v", "proj", "router",
-                                         "experts")
+    # what a block declares to its holder: its statistics and its
+    # memory, nothing about where its weights go
+    assert {a for a, v in vars(DecoderBlock).items()
+            if not a.startswith("_") and not callable(v)
+            and not isinstance(v, property)} \
+        == {"decode_stats", "memory", "window"}
     assert gpt_tiny().nodes["block_0"].op.decode_stats == ()
     assert DecoderBlock.__module__ == "defer_tpu.models.decoder"
     assert ".gpt" not in inspect.getsource(olmoe_module)    # no sibling
@@ -590,12 +590,15 @@ def test_the_serving_engine_refuses_the_block_by_name(model):
 #: became the kernel ``kv_attend`` and the ring's row write, under a
 #: lane row, the kernel ``kv_write_rows`` (``e8dcb192e737955e`` /
 #: ``aadd22d3adc8d6a3`` before it).  PR 32 did: the family's nodes name
-#: every leaf a stage-sharded argument of its own (``stage_arg_keys``),
+#: every leaf a stage-sharded argument of its own,
 #: so the program takes a tree where it cut leaves out of the flat row
 #: (``843764f96d5a7620`` / ``081b76872aa207eb`` before it); the tokens
-#: are PR 26's still.
+#: are PR 26's still.  PR 44 did: every leaf of every node is such an
+#: argument, ``final_ln``'s with the rest, and the ring holds no flat
+#: row (``4c8596d40d6c1428`` / ``7ddd12dc4515bd7c`` before it); the
+#: tokens are PR 26's still.
 PARENT_TOKENS_SHA = "0fef1cc65e752cd8"
-PARENT_DECODE_SHA = {1: "4c8596d40d6c1428", 2: "7ddd12dc4515bd7c"}
+PARENT_DECODE_SHA = {1: "1c5e166cd6dcff82", 2: "dcf5442ff4e733bd"}
 
 
 def _sha(text: str) -> str:
@@ -607,7 +610,7 @@ def test_gpt_tiny_decodes_as_on_the_parent(num_stages):
     """``decode_qkv`` takes a position and ``decode_finish`` a ``sow``,
     and the cache's half of a step lives in ``ops/kv_cache.py``: the
     GPT family ignores the first two, and its ring still lowers to the
-    text recorded (PR 29's) and gives d5480a9's tokens, bit for bit."""
+    text recorded (PR 44's) and gives d5480a9's tokens, bit for bit."""
     graph = gpt_tiny(seq_len=32)
     params = graph.init(jax.random.key(0))
     n, mb = num_stages, 8 // num_stages
