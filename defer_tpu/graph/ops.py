@@ -727,7 +727,12 @@ class TransformerBlock(Op):
 # ---------------------------------------------------------------------------
 
 
-def route_top_k(logits, k: int, scoring: str = "softmax"):
+#: the scoring rules :func:`route_top_k` knows
+SCORING_RULES = ("softmax", "sigmoid", "softmax_of_chosen", "noaux_tc")
+
+
+def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
+                scale: float = 1.0):
     """Scores over the experts in float32, then the ``k`` largest:
     ``(expert ids [..., k], their weights [..., k])``.  ``scoring`` is
     the family's rule, named by the block that calls (never a user's
@@ -737,7 +742,14 @@ def route_top_k(logits, k: int, scoring: str = "softmax"):
     the chosen ``k`` (command-a-plus, ``models/cohere_moe.py``);
     ``"softmax_of_chosen"`` — the ``k`` largest logits, then a softmax
     over those ``k`` values alone (Granite 4.0-H,
-    ``models/granite_hybrid.py``)."""
+    ``models/granite_hybrid.py``); ``"noaux_tc"`` — sigmoid scores
+    ``p``, the ``k`` largest of ``p + bias`` (``bias`` [experts], the
+    balancing term: it chooses and never weighs), the chosen ``p``
+    renormalised and multiplied by ``scale`` (Kimi K2 / DeepSeek-V3
+    without a group limit, ``models/kimi_k2.py``)."""
+    if scoring not in SCORING_RULES:
+        raise ValueError(f"scoring must be one of {SCORING_RULES}, "
+                         f"got {scoring!r}")
     logits = logits.astype(jnp.float32)
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
@@ -746,10 +758,11 @@ def route_top_k(logits, k: int, scoring: str = "softmax"):
     if scoring == "softmax_of_chosen":
         top, eid = lax.top_k(logits, k)
         return eid, jax.nn.softmax(top, axis=-1)
-    if scoring != "sigmoid":
-        raise ValueError(
-            "scoring must be 'softmax', 'sigmoid' or 'softmax_of_chosen', "
-            f"got {scoring!r}")
+    if scoring == "noaux_tc":
+        probs = jax.nn.sigmoid(logits)
+        _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
+        p = jnp.take_along_axis(probs, eid, axis=-1)
+        return eid, scale * p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
     p, eid = lax.top_k(jax.nn.sigmoid(logits), k)
     return eid, p / jnp.sum(p, axis=-1, keepdims=True)
 

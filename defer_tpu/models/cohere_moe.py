@@ -53,16 +53,19 @@ def layer_norm(x, scale, eps: float):
             * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_interleaved(x, pos, theta: float):
+def rope_interleaved(x, pos, theta: float, inv_freq=None):
     """Interleaved RoPE over the whole head (``rope_gptj``): the pair
-    ``(x[2i], x[2i + 1])`` turned by ``pos * theta ** (-2i / hd)``;
+    ``(x[2i], x[2i + 1])`` turned by ``pos * theta ** (-2i / hd)`` — or
+    by ``pos * inv_freq[i]`` where a family scales its frequencies
+    (``inv_freq`` [hd / 2]; ``theta`` is then not read);
     ``x`` [..., t, nh, hd] at positions ``pos`` [t] (angles in float32).
     A pair's partner, signed (``-x[2i + 1]`` at ``2i``, ``x[2i]`` at ``2i
     + 1``), is ``x`` times a fixed ``hd x hd`` matrix of 0 and +-1: one
     small product, exact in ``x``'s own type — a strided gather or a
     roll along the lanes costs the chip a padded copy of ``x``."""
     hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]      # [t, hd/2]
     cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)[:, None, :]
     sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)[:, None, :]

@@ -21,7 +21,10 @@ it, whatever its family.
   (``ops/retention.py``) and takes the layer's output back.  Its
   sibling :class:`StateSpaceBlock` keeps a convolution window and a
   state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's) and has no
-  attention heads at all.  None
+  attention heads at all.  Its sibling :class:`LatentBlock` keeps a
+  latent cache (``ops/latent_cache.py``): one row a position that every
+  head shares, which a step attends over with queries absorbed into the
+  latent space and a prompt over the expanded heads.  None
   knows an axis order, key or type of what the format holds; which kind
   a block keeps is the class it is (``memory``), and the holder asks
   every block for its format (:meth:`DecoderBlock.memory_format`).
@@ -68,19 +71,24 @@ class DecoderBlock:
     #: per-step scalars ``decode_finish`` sows (summed over a generation)
     decode_stats: tuple = ()
 
-    def _attend(self, q, k, v, window: int | None = None):
-        """Causal attention on [b, nh, t, hd] by ``attn_impl``: the
-        flash kernel (bottom-right aligned) on a TPU, plain XLA
-        elsewhere.  ``k`` / ``v`` may have fewer heads, each serving a
-        group of queries; with ``window`` a row attends its ``window``
-        newest keys, itself counted."""
+    def _attention_impl(self) -> str:
+        """``attn_impl`` resolved: ``"flash"`` (the Pallas kernels; what
+        ``"auto"`` means on a TPU) or ``"xla"``."""
         impl = self.attn_impl
         if impl == "auto":
             impl = "flash" if jax.default_backend() == "tpu" else "xla"
         if impl not in ("flash", "xla"):
             raise ValueError(
                 f"attn_impl must be 'auto', 'flash' or 'xla', got {impl!r}")
-        if impl == "flash":
+        return impl
+
+    def _attend(self, q, k, v, window: int | None = None):
+        """Causal attention on [b, nh, t, hd] by ``attn_impl``: the
+        flash kernel (bottom-right aligned) on a TPU, plain XLA
+        elsewhere.  ``k`` / ``v`` may have fewer heads, each serving a
+        group of queries; with ``window`` a row attends its ``window``
+        newest keys, itself counted."""
+        if self._attention_impl() == "flash":
             from ..ops import flash_attention
             return flash_attention(q, k, v, causal=True, window=window)
         hd = q.shape[-1]
@@ -333,6 +341,86 @@ class StateSpaceBlock(DecoderBlock):
         out = self.decode_finish(params, rows(x), rows(y), rows(xs),
                                  jax.tree.map(rows, rest), sow=sow)
         return out.reshape(b, t, d), state
+
+
+class LatentBlock(DecoderBlock):
+    """A decoder block whose per-sequence memory is a latent cache
+    (``ops/latent_cache.py``): **one row a position** — the normalised
+    latent and, behind it, the one rotated key every head shares —
+    where a KV cache keeps a key and a value a head.  One layer, two
+    attention paths that must agree: a step attends *in the latent
+    space*, every head on the same row (the up-projections of keys and
+    values absorbed into the query and the output), a prompt over the
+    *expanded* heads.  In place of ``apply_with_kv`` / ``decode_qkv``
+    such a block has
+
+    * ``num_heads``, ``latent_dim`` (the latent's columns),
+      ``rope_dim`` (the shared key's), ``nope_dim`` (a head's own key
+      columns), ``v_dim`` (a head's value columns) and
+      ``softmax_scale``, what a score is multiplied by;
+    * ``decode_q_row(params, x [b, d], pos) -> (q [b, heads * (latent
+      + rope)], row [b, latent + rope])``: every head's query *already
+      absorbed* (``q_nope W_uk`` and the rotated ``q_rope``) and the
+      token's one new row, final when handed over;
+    * ``decode_finish(params, x, y [b, heads * latent], sow=None)``:
+      the rest of the block after the attention's output in the latent
+      space (``W_uv``, the output projection, the second half);
+    * ``apply_with_rows(params, x [b, t, d], sow=None) -> (y, rows [b,
+      t, latent + rope])``: the full-sequence forward over the
+      expanded heads, with the rows as :meth:`decode_q_row` would have
+      handed them over one by one.
+
+    ``decode_stats`` is :class:`DecoderBlock`'s.  No serving engine
+    takes such a block yet (``serve/engine.py`` refuses every block but
+    GPT's).
+    """
+
+    memory = "latent_cache"
+
+    def geometry(self, d_model: int):
+        """Every head has a key of its own once expanded: ``(heads,
+        heads, nope + rope)``."""
+        del d_model
+        return (self.num_heads, self.num_heads,
+                self.nope_dim + self.rope_dim)
+
+    def widest(self, d_model: int) -> int:
+        """The queries' columns, or the expanded keys and values'."""
+        return max(d_model,
+                   self.num_heads * (self.nope_dim + self.rope_dim),
+                   self.num_heads * (self.nope_dim + self.v_dim))
+
+    def memory_format(self, d_model: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """A row's width is the block's own, whatever the stream's."""
+        del d_model
+        if quantized:
+            raise ValueError(
+                "kv_cache='int8' quantizes cached key and value rows; "
+                "these blocks keep a latent cache, which has none")
+        from ..ops import latent_cache
+        return latent_cache.LatentCacheFormat(
+            self.latent_dim, self.rope_dim, positions, dtype,
+            self.softmax_scale, groups=groups)
+
+    def decode(self, params, x, cache, pos, fmt, slot=None, group=None,
+               sow=None):
+        """One-token step: the new row written at ``pos`` (``slot``:
+        ``fmt.decode_slot``'s, opaque here as in
+        :meth:`DecoderBlock.decode`), then every head's absorbed query
+        over the rows ``<= pos``."""
+        slot = pos if slot is None else slot
+        q, row = self.decode_q_row(params, x, pos)
+        cache = fmt.write_position(cache, fmt.rows(row), slot, group=group)
+        return self.decode_finish(
+            params, x, fmt.attend(q, cache, slot, group=group),
+            sow=sow), cache
+
+    def prefill(self, params, x, cache, fmt, slot):
+        """A whole prompt ``x`` [b, t, d] through the layer over the
+        expanded heads, its rows bulk-written where ``slot`` says."""
+        x, rows = self.apply_with_rows(params, x)
+        return x, fmt.write_prefix(cache, rows, slot)
 
 
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:

@@ -1,0 +1,428 @@
+"""Moonshot's Kimi K2 decoder family (Hugging Face ``model_type``
+``kimi_k2``: the DeepSeek-V3 block): **latent attention** (MLA) — the
+keys and values of all heads are made from one compressed row a
+position, the normalised latent ``c`` and one rotated key ``k_r`` every
+head shares, and that row is all a sequence keeps — in front of a dense
+SwiGLU in the leading layer (:class:`KimiDenseBlock`) and of routed
+SwiGLU experts beside a shared one in every layer behind it
+(:class:`KimiMoeBlock`); RMSNorm before each half, two residuals a
+layer; an untied head.
+
+**Attention**, position ``t``, head ``i``: ``c_q = rms(h W_qa)``, ``[q_n,
+q_r] = c_q W_qb`` a head; ``[c', k'] = h W_kva``, ``c = rms(c')``, ``k_r =
+rope(k', t)`` (one for all heads), ``q_r <- rope(q_r, t)``; ``k_n[i] =
+W_uk[i] c``, ``v[i] = c W_uv[i]`` (the two halves of the published
+``kv_b_proj``, held as two stacks a head: ``k_up`` ``[heads, nope,
+latent]``, ``v_up`` ``[heads, latent, v]``); scores ``(q_n . k_n + q_r .
+k_r) * softmax_scale``, causal.  A prompt is computed in exactly this,
+*expanded*, form (``ops/flash_attention.py::flash_latent``).  A step is
+computed in the *absorbed* form: ``q~[i] = q_n[i] W_uk[i]`` so that a
+score is ``[q~[i], q_r[i]] . [c, k_r]``, the heads' outputs
+``o~[i] = sum_s p c_s`` stay in the latent space (``ops/latent_cache.py``)
+and ``o[i] = o~[i] W_uv[i]`` — the same numbers up to rounding.
+
+**RoPE** turns adjacent pairs (the checkpoint's order) by YaRN's
+frequencies (:func:`yarn_inv_freq`), computed once in float32 when the
+graph is built; ``softmax_scale`` is ``(nope + rope) ** -0.5 * m ** 2``,
+``m = 0.1 * mscale_all_dim * ln(factor) + 1`` (:func:`yarn_softmax_scale`).
+
+**Routing** (``topk_method`` ``noaux_tc`` with one group): sigmoid scores
+``p`` over all experts, the ``k`` largest of ``p + b`` (``b``, the
+balancing bias, chooses and never weighs), the chosen ``p`` renormalised
+and multiplied by ``routed_scaling_factor``
+(``graph/ops.py::route_top_k``).  A layer may hold a share of its routed
+experts (``experts_held``), as ``models/cohere_moe.py``'s do: it routes
+over all of them, computes the pairs that fell to the experts it holds
+and adds the shared expert whole.
+
+The graph follows the decoder-model contract (``embeddings`` /
+``block_i`` / ``final_ln`` / ``lm_head``, models/decoder.py); both kinds
+of block are :class:`~defer_tpu.models.decoder.LatentBlock`s and sow one
+ledger (the dense block zeros).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..graph.ir import GraphBuilder, LayerGraph, Op
+from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch_held,
+                         grouped_swiglu, rms_norm, route_top_k)
+from .cohere_moe import rope_interleaved
+from .decoder import LatentBlock
+from .olmoe import OlmoeEmbedding
+
+
+#: the spread of a seeded balancing bias (a checkpoint's is trained):
+#: large enough to turn choices at near-ties (among hundreds of experts
+#: the last chosen score and the first left out lie a few thousandths
+#: apart), small beside what makes an expert popular with a run's
+#: sequences (PERF.md, PR 45: at 0.003 and at 0.001 a cell's tokens a
+#: second followed the held experts' hits alike)
+_BIAS_SPREAD = 0.001
+
+
+def _normal(key, shape, fan_in: int):
+    """A matrix as the other families draw theirs: N(0, 1 / fan_in)."""
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0) -> tuple:
+    """YaRN's ``dim / 2`` frequencies, float32: pair ``j`` keeps ``e_j =
+    theta ** (-2j / dim)`` below ``lo``, turns ``factor`` times slower
+    from ``hi`` on, and ramps linearly between — ``lo`` / ``hi`` the
+    pairs that make ``beta_fast`` / ``beta_slow`` turns over the
+    ``original`` positions."""
+    def pair(turns):
+        return dim * math.log(original / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(pair(beta_fast)), 0)
+    hi = min(math.ceil(pair(beta_slow)), dim // 2 - 1)
+    j = np.arange(dim // 2, dtype=np.float32)
+    e = np.float32(theta) ** (-2 * j / np.float32(dim))
+    ramp = np.clip((j - lo) / max(hi - lo, 1e-3), 0, 1).astype(np.float32)
+    return tuple(float(f) for f in
+                 (e * (1 - ramp) + e / np.float32(factor) * ramp))
+
+
+def yarn_softmax_scale(width: int, factor: float,
+                       mscale_all_dim: float = 1.0) -> float:
+    """What a score is multiplied by under YaRN: ``width ** -0.5 * m **
+    2``, ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
+    m = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return width ** -0.5 * m * m
+
+
+@dataclasses.dataclass(frozen=True, repr=False, kw_only=True)
+class _KimiBlock(LatentBlock, Op):
+    """The attention half both kinds of layer share, and the two
+    residuals; a subclass says ``_ffn`` and its parameters."""
+
+    num_heads: int
+    q_rank: int
+    latent_dim: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    #: the rotation's frequencies a pair (:func:`yarn_inv_freq`)
+    rope_freqs: tuple
+    softmax_scale: float
+    rms_eps: float = 1e-5
+    attn_impl: str = "auto"
+
+    decode_stats = ("moe.assignments", "moe.held_assignments",
+                    "moe.experts_hit", "moe.load_max")
+    #: parameters the query-and-row half reads, and the other half's
+    _front = ("in_ln", "q_a", "q_a_ln", "q_b", "kv_a", "kv_a_ln", "k_up")
+    _back = ("v_up", "proj", "ff_ln")
+
+    def _attention_init(self, keys, d: int) -> dict:
+        nh, r, c = self.num_heads, self.q_rank, self.latent_dim
+
+        def ones(n):
+            return {"scale": jnp.ones((n,), jnp.float32)}
+
+        return {
+            "in_ln": ones(d),
+            "q_a": {"w": _normal(keys[0], (d, r), d)}, "q_a_ln": ones(r),
+            # a head's columns: nope_dim of q_n, then rope_dim of q_r
+            "q_b": {"w": _normal(keys[1], (
+                r, nh * (self.nope_dim + self.rope_dim)), r)},
+            # the latent's columns, then the shared key's
+            "kv_a": {"w": _normal(keys[2], (d, c + self.rope_dim), d)},
+            "kv_a_ln": ones(c),
+            "k_up": {"w": _normal(keys[3], (nh, self.nope_dim, c), c)},
+            "v_up": {"w": _normal(keys[4], (nh, c, self.v_dim), c)},
+            "proj": {"w": _normal(keys[5], (nh * self.v_dim, d),
+                              nh * self.v_dim)},
+            "ff_ln": ones(d),
+        }
+
+    # -- the attention's two forms -----------------------------------------
+
+    def _q_rows(self, p, x, pos):
+        """``(q_n [..., t, nh, nope], q_r [..., t, nh, rope] rotated, rows
+        [..., t, latent + rope])`` of ``x`` [..., t, d] at positions
+        ``pos`` [t]: the rows final, as the cache keeps them."""
+        nh, c = self.num_heads, self.latent_dim
+        h = rms_norm(x, p["in_ln"]["scale"], self.rms_eps)
+        cq = rms_norm(h @ p["q_a"]["w"], p["q_a_ln"]["scale"], self.rms_eps)
+        q = (cq @ p["q_b"]["w"]).reshape(x.shape[:-1] + (nh, -1))
+        kv = h @ p["kv_a"]["w"]
+        latent = rms_norm(kv[..., :c], p["kv_a_ln"]["scale"], self.rms_eps)
+        k_r = rope_interleaved(kv[..., None, c:], pos, 0.0, self.rope_freqs)
+        q_r = rope_interleaved(q[..., self.nope_dim:], pos, 0.0,
+                               self.rope_freqs)
+        return q[..., :self.nope_dim], q_r, jnp.concatenate(
+            [latent, k_r[..., 0, :]], axis=-1)
+
+    def _expanded(self, p, q_n, q_r, rows):
+        """Causal attention of a prompt ``[b, t, ...]`` over the expanded
+        heads: ``[b, t, nh * v]``."""
+        c, f32 = self.latent_dim, jnp.float32
+        latent, k_r = rows[..., :c], rows[..., None, c:]
+        k_n = jnp.einsum("btc,hnc->bhtn", latent, p["k_up"]["w"],
+                         preferred_element_type=f32).astype(rows.dtype)
+        v = jnp.einsum("btc,hcv->bhtv", latent, p["v_up"]["w"],
+                       preferred_element_type=f32).astype(rows.dtype)
+        q_n, q_r, k_r = (a.transpose(0, 2, 1, 3) for a in (q_n, q_r, k_r))
+        if self._attention_impl() == "flash":
+            from ..ops.flash_attention import flash_latent
+            y = flash_latent(q_n, q_r, k_n, k_r, v, scale=self.softmax_scale)
+        else:
+            att = (jnp.einsum("bhqn,bhkn->bhqk", q_n, k_n)
+                   + jnp.einsum("bhqr,bxkr->bhqk", q_r, k_r)) \
+                * self.softmax_scale
+            t = att.shape[-1]
+            att = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :],
+                            att, jnp.asarray(-jnp.inf, att.dtype))
+            y = jnp.einsum("bhqk,bhkv->bhqv", jax.nn.softmax(att, axis=-1), v)
+        return y.transpose(0, 2, 1, 3).reshape(y.shape[0], y.shape[2], -1)
+
+    def _finish(self, p, x, y, sow=None):
+        """The layer's output from the stream ``x`` [T, d] and the heads'
+        values merged ``y`` [T, nh * v]: the output projection, then the
+        feed-forward half on the normed sum, each added to the stream in
+        float32, which is rounded once on the way out."""
+        f32 = jnp.float32
+        x32 = x.astype(f32) + jnp.dot(y, p["proj"]["w"],
+                                      preferred_element_type=f32)
+        h = rms_norm(x32, p["ff_ln"]["scale"], self.rms_eps).astype(x.dtype)
+        return (x32 + self._ffn(p, h, sow)).astype(x.dtype)
+
+    # -- full sequence -----------------------------------------------------
+
+    def apply(self, params, x, sow=None):
+        """Full-sequence forward on ``x`` [b, t, d] or [t, d]."""
+        lead = x.shape[:-2]
+        y = self.apply_with_rows(
+            params, x.reshape((-1,) + x.shape[-2:]), sow)[0]
+        return y.reshape(lead + y.shape[-2:])
+
+    def apply_with_rows(self, params, x, sow=None):
+        """Full-sequence forward on ``x`` [b, t, d] over the expanded
+        heads; also the rows [b, t, latent + rope] that
+        :meth:`decode_q_row` would have handed over one by one.  A dict
+        ``sow`` is filled as :meth:`decode_finish` fills it, over all
+        b*t rows."""
+        p = _cast(params, x.dtype)
+        b, t, d = x.shape
+        q_n, q_r, rows = self._q_rows(p, x, jnp.arange(t))
+        y = self._expanded(p, q_n, q_r, rows)
+        out = self._finish(p, x.reshape(b * t, d), y.reshape(b * t, -1), sow)
+        return out.reshape(b, t, d), rows
+
+    # -- one token against the cache ---------------------------------------
+
+    def decode_q_row(self, params, x, pos):
+        """Every head's absorbed query ``[b, nh * (latent + rope)]`` and
+        the new row ``[b, latent + rope]`` of ``x`` [b, d] at scalar
+        ``pos``."""
+        p = _cast({nm: params[nm] for nm in self._front}, x.dtype)
+        q_n, q_r, rows = self._q_rows(p, x[:, None], jnp.reshape(pos, (1,)))
+        q_abs = jnp.einsum("bhn,hnc->bhc", q_n[:, 0], p["k_up"]["w"],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_abs.astype(x.dtype), q_r[:, 0]], axis=-1)
+        return q.reshape(x.shape[0], -1), rows[:, 0]
+
+    def decode_finish(self, params, x, y, sow=None):
+        """The heads' outputs ``y`` [b, nh * latent] out of the latent
+        space (``W_uv``), then the output projection and the
+        feed-forward half; sows :attr:`decode_stats` of this step."""
+        p = _cast({nm: params[nm] for nm in self._back + self._ffn_params},
+                  x.dtype)
+        o = jnp.einsum("bhc,hcv->bhv",
+                       y.reshape(x.shape[0], self.num_heads, -1),
+                       p["v_up"]["w"], preferred_element_type=jnp.float32)
+        return self._finish(p, x, o.astype(x.dtype).reshape(x.shape[0], -1),
+                            sow)
+
+    def _attention_flops(self, t: int, d: int) -> int:
+        nh = self.num_heads
+        qk, kv = self.nope_dim + self.rope_dim, self.nope_dim + self.v_dim
+        return (2 * t * (d * self.q_rank + self.q_rank * nh * qk
+                         + d * (self.latent_dim + self.rope_dim)
+                         + self.latent_dim * nh * kv + nh * self.v_dim * d)
+                + 2 * t * t * nh * (qk + self.v_dim))
+
+
+@dataclasses.dataclass(frozen=True, repr=False, kw_only=True)
+class KimiDenseBlock(_KimiBlock):
+    """A leading layer (``first_k_dense_replace``): latent attention,
+    then one dense SwiGLU of ``hidden`` columns."""
+
+    hidden: int
+
+    _ffn_params = ("gate", "up", "down")
+
+    def init(self, key, in_specs):
+        (spec,) = in_specs
+        d, ks = spec.shape[-1], jax.random.split(key, 9)
+
+        return dict(self._attention_init(ks, d),
+                    gate={"w": _normal(ks[6], (d, self.hidden), d)},
+                    up={"w": _normal(ks[7], (d, self.hidden), d)},
+                    down={"w": _normal(ks[8], (self.hidden, d), self.hidden)})
+
+    def widest(self, d_model: int) -> int:
+        return max(super().widest(d_model), self.hidden)
+
+    def _ffn(self, p, h, sow=None):
+        if sow is not None:
+            # no router: the ledger every block of the graph shares
+            # takes zeros from this one
+            sow.update({name: jnp.int32(0) for name in self.decode_stats})
+        a = jax.nn.silu(h @ p["gate"]["w"]) * (h @ p["up"]["w"])
+        return jnp.dot(a, p["down"]["w"], preferred_element_type=jnp.float32)
+
+    def flops(self, in_specs, out_spec):
+        (spec,) = in_specs
+        t, d = spec.shape
+        return self._attention_flops(t, d) + 2 * t * 3 * d * self.hidden
+
+
+@dataclasses.dataclass(frozen=True, repr=False, kw_only=True)
+class KimiMoeBlock(_KimiBlock):
+    """A routed layer: latent attention, then ``experts_per_tok`` of
+    ``num_experts`` routed SwiGLU experts by the ``noaux_tc`` rule beside
+    ``num_shared`` shared ones every token passes, added whole.
+    ``experts_held`` is the half-open range the layer holds and computes
+    (None: all)."""
+
+    num_experts: int
+    experts_per_tok: int
+    expert_hidden: int
+    num_shared: int = 1
+    routed_scale: float = 1.0
+    experts_held: tuple | None = None
+
+    _ffn_params = ("router", "experts", "shared_gate", "shared_up",
+                   "shared_down")
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """The routed experts this layer holds, ``[lo, hi)``."""
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is no range "
+                             f"of {self.num_experts} experts")
+        return lo, hi
+
+    def init(self, key, in_specs):
+        (spec,) = in_specs
+        d, ks = spec.shape[-1], jax.random.split(key, 14)
+        h, sh = self.expert_hidden, self.num_shared * self.expert_hidden
+        e = self.held[1] - self.held[0]
+
+        return dict(
+            self._attention_init(ks, d),
+            # every expert's column and bias, held or not: the choice is
+            # the whole layer's
+            router={"w": _normal(ks[6], (d, self.num_experts), d),
+                    "bias": jax.random.normal(
+                        ks[7], (self.num_experts,), jnp.float32)
+                    * _BIAS_SPREAD},
+            experts={"gate": _normal(ks[8], (e, d, h), d),
+                     "up": _normal(ks[9], (e, d, h), d),
+                     "down": _normal(ks[10], (e, h, d), h)},
+            shared_gate={"w": _normal(ks[11], (d, sh), d)},
+            shared_up={"w": _normal(ks[12], (d, sh), d)},
+            shared_down={"w": _normal(ks[13], (sh, d), h)})
+
+    def route(self, params, h):
+        """``(expert ids [T, k], their weights [T, k])`` of the normed
+        stream ``h`` [T, d] in the type of ``params``: what the layer
+        dispatches by."""
+        p = _cast(params["router"], params["router"]["w"].dtype)
+        # router logits leave the product in float32: rounded, they
+        # would flip the last of the chosen at near-ties
+        return route_top_k(
+            jnp.dot(h.astype(p["w"].dtype), p["w"],
+                    preferred_element_type=jnp.float32),
+            self.experts_per_tok, scoring="noaux_tc", bias=p["bias"],
+            scale=self.routed_scale)
+
+    def _ffn(self, p, h, sow=None):
+        f32, ex = jnp.float32, p["experts"]
+        eid, gate = self.route(p, h)
+        routed, sizes = expert_dispatch_held(
+            h, eid, gate, self.held,
+            lambda xs, sizes: grouped_swiglu(xs, ex, sizes))
+        a = jax.nn.silu(h @ p["shared_gate"]["w"]) \
+            * (h @ p["shared_up"]["w"])
+        shared = jnp.dot(a, p["shared_down"]["w"], preferred_element_type=f32)
+        if sow is not None:
+            sow["moe.chosen"] = eid             # [T, k]: not a statistic
+            sow["moe.weights"] = gate           # [T, k]: not one either
+            sow["moe.assignments"] = jnp.int32(eid.size)
+            sow["moe.held_assignments"] = jnp.sum(sizes)
+            sow["moe.experts_hit"] = jnp.sum(sizes > 0, dtype=jnp.int32)
+            sow["moe.load_max"] = jnp.max(sizes)
+        return routed + shared
+
+    def flops(self, in_specs, out_spec):
+        # the whole layer's experts_per_tok routed and num_shared shared
+        # experts a token (a share holds fewer)
+        (spec,) = in_specs
+        t, d = spec.shape
+        return (self._attention_flops(t, d) + 2 * t * d * self.num_experts
+                + (self.experts_per_tok + self.num_shared)
+                * 2 * t * 3 * d * self.expert_hidden)
+
+
+def kimi_k2(num_layers: int, hidden: int, heads: int, q_rank: int,
+            latent_dim: int, nope_dim: int, rope_dim: int, v_dim: int,
+            dense_hidden: int, seq_len: int, vocab: int, num_experts: int,
+            experts_per_tok: int, expert_hidden: int, num_shared: int = 1,
+            routed_scale: float = 1.0, dense_layers: int = 1,
+            experts_held=None, rope_theta: float = 50000.0,
+            rope_factor: float = 1.0, rope_original: int = 4096,
+            beta_fast: float = 32.0, beta_slow: float = 1.0,
+            mscale_all_dim: float = 1.0, rms_eps: float = 1e-5,
+            name: str = "kimi_k2") -> LayerGraph:
+    """Causal LM graph: ids [t] -> logits [t, vocab]; ``seq_len`` is the
+    number of positions.  The first ``dense_layers`` layers are dense
+    (``first_k_dense_replace``), the rest routed; ``experts_held``
+    ``(lo, hi)`` makes every routed layer one chip's share of its
+    experts.  ``rope_factor`` 1 is plain RoPE; YaRN's frequencies and
+    the softmax's scale are computed here, once."""
+    if experts_held is not None:
+        experts_held = tuple(experts_held)
+    attn = dict(
+        num_heads=heads, q_rank=q_rank, latent_dim=latent_dim,
+        nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+        rope_freqs=yarn_inv_freq(rope_dim, rope_theta, rope_factor,
+                                 rope_original, beta_fast, beta_slow),
+        softmax_scale=yarn_softmax_scale(nope_dim + rope_dim, rope_factor,
+                                         mscale_all_dim),
+        rms_eps=rms_eps)
+    b = GraphBuilder(name)
+    x = b.input((seq_len,), jnp.int32)
+    x = b.add(OlmoeEmbedding(vocab, hidden, seq_len), x, name="embeddings")
+    for i in range(num_layers):
+        op = KimiDenseBlock(hidden=dense_hidden, **attn) \
+            if i < dense_layers else KimiMoeBlock(
+                num_experts=num_experts, experts_per_tok=experts_per_tok,
+                expert_hidden=expert_hidden, num_shared=num_shared,
+                routed_scale=routed_scale, experts_held=experts_held, **attn)
+        x = b.add(op, x, name=f"block_{i}")
+    x = b.add(RMSNorm(eps=rms_eps), x, name="final_ln")
+    x = b.add(Dense(vocab, use_bias=False), x, name="lm_head")
+    return b.build()
+
+
+def kimi_k2_tiny(seq_len: int = 32, vocab: int = 211,
+                 experts_held=(0, 4)) -> LayerGraph:
+    """A dense layer and four routed ones; 4 heads of 16 + 8 over a
+    latent of 32; 4 of 16 experts a token, 4 of the 16 held; YaRN from
+    8 positions on."""
+    return kimi_k2(5, 64, 4, 24, 32, 16, 8, 16, 96, seq_len, vocab, 16, 4,
+                   32, routed_scale=2.827, experts_held=experts_held,
+                   rope_factor=4.0, rope_original=8, name="kimi_k2_tiny")

@@ -121,10 +121,31 @@ DECODE_DISPATCH_PHASES = ("upload", "launch")
 #: ``decode.moe.*`` counters, the retention blocks'
 #: ``decode.retention.updates`` and the state-space blocks'
 #: ``decode.ssm.updates``; a graph whose blocks both route and keep a
-#: state, ``models/granite_hybrid.py``'s, sows all five): their sums,
+#: state, ``models/granite_hybrid.py``'s, sows all five;
+#: ``models/kimi_k2.py``'s two kinds of block the four ``moe.*`` names,
+#: the dense one zeros): their sums,
 #: which came to the host a chunk at a time with the chunk's ids, go to
 #: the counters
 DECODE_STATS_PHASES = ("moe_stats",)
+
+#: what a ``PipelinedDecoder`` says of the per-sequence memory it holds,
+#: set once when it is built (``runtime/decode.py``): a gauge a kind of
+#: memory (``decode.<kind>.state_bytes``, the kind a block's ``memory``)
+#: and each kind's own parts.  The latent cache's pair is bytes and the
+#: rows those bytes are — their quotient is what a live row costs a step
+#: to read, which ``latent_moe_decode_step_roofline`` holds against the
+#: configuration's 1152 B
+DECODE_MEMORY_GAUGES = (
+    "decode.cache.window_bytes", "decode.cache.full_bytes",
+    "decode.cache.window_positions", "decode.cache.latent_bytes",
+    "decode.cache.latent_positions", "decode.ssm.conv_bytes")
+
+#: the Pallas kernels' names in a device trace, which the benchmark's
+#: kernel readers search for (``chipbench/metrics/*_kernel_roofline.py``)
+KERNEL_NAMES = (
+    "kv_attend", "kv_write_rows", "flash_band", "flash_grouped",
+    "flash_latent", "latent_attend", "retention_step", "ssm_step",
+    "ssm_scan", "ssd_step", "ssd_scan", "grouped_experts")
 
 #: the front door's per-request phase on the client's reader thread
 #: (serve/frontdoor.py): prompt frame received -> queued or shed

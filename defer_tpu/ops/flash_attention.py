@@ -31,6 +31,11 @@ first.  Its products take the operands in their own type (bf16 on the
 matrix unit at full rate) and accumulate in f32; only a band's edge
 blocks pay for masks.
 
+A latent-attention layer's prompt takes a third kernel,
+:func:`flash_latent`: a score is the sum of a head's own product and
+one over a key every head shares, and the value is narrower than the
+key.
+
 On non-TPU backends (CPU tests) the same kernel runs in interpreter mode, so
 there is exactly one implementation of the math.  On a TPU backend it is
 compiled by Mosaic and a compile error raises — there is no XLA-attention
@@ -275,6 +280,117 @@ def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
         name="flash_band" if window is not None else "flash_grouped",
     )(qp, kp, vp)
     return out[:, :, :t_q, :d]
+
+
+def _latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, m_ref,
+                   l_ref, acc_ref, *, scale, block, t):
+    """One key block of one query block of one head of
+    :func:`flash_latent`: causal, queries and keys at the same
+    positions.  A score is the sum of two products — the head's own
+    part ``qn . kn`` and the part every head shares ``qr . kr`` — so
+    the shared key is never laid beside each head's own; the value has
+    a width of its own.  qn_ref / kn_ref ``[1, 1, block, dn]``, qr_ref
+    / kr_ref ``[1, 1, block, dr]``, v_ref / o_ref ``[1, 1, block,
+    dv]``."""
+    qi, kb = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(edge):
+        nt = (((1,), (1,)), ((), ()))
+        v = v_ref[0, 0]
+        s = (jax.lax.dot_general(qn_ref[0, 0], kn_ref[0, 0], nt,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0, 0], kr_ref[0, 0], nt,
+                                   preferred_element_type=jnp.float32)
+             ) * scale                                    # [block, block]
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        if edge:
+            # the diagonal block: a row sees the keys up to its own, and
+            # no padding (every row has its own key or, a padded row,
+            # every real one: none is left without)
+            k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            s = jnp.where(jnp.logical_and(qi * block + k_pos < t,
+                                          q_pos >= k_pos), s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    # key blocks ahead of the diagonal are neither fetched (the index
+    # map names the diagonal block again) nor computed
+    pl.when(kb < qi)(lambda: accumulate(False))
+    pl.when(kb == qi)(lambda: accumulate(True))
+
+    @pl.when(kb == pl.num_programs(3) - 1)
+    def _finalize():
+        o_ref[0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def flash_latent(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
+                 block: int | None = None, interpret: bool | None = None):
+    """Causal attention over the expanded heads of a latent-attention
+    layer's prompt, scores never materialized: ``softmax((q_nope .
+    k_nope + q_rope . k_rope) * scale) v``, row ``t`` over rows ``<=
+    t``.  q_nope / k_nope ``[b, h, t, dn]``, q_rope ``[b, h, t, dr]``,
+    **k_rope** ``[b, 1, t, dr]`` — the one rotated key all heads share,
+    read by index and not repeated ``h`` times —, **v** ``[b, h, t,
+    dv]``, a width of its own (a key of ``dn + dr`` laid out whole and
+    a value padded to it would cost 192 + 192 a pair where 192 + 128
+    are needed).  ``scale`` is the block's (not one over a width's
+    root).  Operands go to the matrix unit in their own type and
+    accumulate in f32; blocks of :data:`_BAND_BLOCK` rows; returns
+    ``[b, h, t, dv]``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, t, dn = q_nope.shape
+    dr, dv = q_rope.shape[-1], v.shape[-1]
+    block = min(block or _BAND_BLOCK, max(8, 1 << (t - 1).bit_length()))
+    qn, qr, kn, kr, vp = (_pad_to(a, 2, block)
+                          for a in (q_nope, q_rope, k_nope, k_rope, v))
+    num_b = qn.shape[2] // block
+
+    def q_block(bi, hi, qi, kb):
+        return (bi, hi, qi, 0)
+
+    def k_block(bi, hi, qi, kb):
+        return (bi, hi, jnp.minimum(kb, qi), 0)
+
+    def shared_block(bi, hi, qi, kb):
+        return (bi, 0, jnp.minimum(kb, qi), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, block=block, t=t),
+        grid=(b, h, num_b, num_b),
+        in_specs=[pl.BlockSpec((1, 1, block, dn), q_block),
+                  pl.BlockSpec((1, 1, block, dr), q_block),
+                  pl.BlockSpec((1, 1, block, dn), k_block),
+                  pl.BlockSpec((1, 1, block, dr), shared_block),
+                  pl.BlockSpec((1, 1, block, dv), k_block)],
+        out_specs=pl.BlockSpec((1, 1, block, dv), q_block),
+        out_shape=jax.ShapeDtypeStruct((b, h, qn.shape[2], dv), v.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((block, _LANES), jnp.float32),  # running denom
+            pltpu.VMEM((block, dv), jnp.float32),      # value accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_latent",
+    )(qn, qr, kn, kr, vp)
+    return out[:, :, :t]
 
 
 @functools.partial(jax.jit, static_argnames=(

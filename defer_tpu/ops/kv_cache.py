@@ -36,8 +36,12 @@ format is a layer's, and a holder of several layers holds one a layer
 (a window layer's beside a full layer's).
 
 The *state* of several layers is a dict of tuples, one buffer a layer
-under each key, never stacked (``ops/layered.py``, which the retention
-state's format shares).
+under each key, never stacked (``ops/layered.py``, which the three
+other kinds of per-sequence memory share: the retention state's format,
+``ops/retention.py``, the state-space state's, ``ops/ssm.py``, and the
+latent cache's, ``ops/latent_cache.py`` — the fourth kind, one row a
+position that every head shares, which keeps rows as this format does
+and inherits the bubbles' bookkeeping from it, :class:`RingRows`).
 
 **The three writes**, each the operation its caller's positions make
 cheapest (docs/DECODE_CLIFF.md):
@@ -534,42 +538,15 @@ def attend_einsum(q, item: dict, pos, window: int | None = None):
     return jnp.einsum("bkgl,bkld->bkgd", att, vh).reshape(b, d)
 
 
-@dataclasses.dataclass(frozen=True)
-class KVCacheFormat(LayeredState):
-    """One layer's cache, described: what both decode engines build
-    their buffers from and write and read them through (``zeros``,
-    ``layer`` and ``with_layer`` are ``ops/layered.py``'s)."""
+class RingRows(LayeredState):
+    """The ring's bookkeeping of a memory that keeps rows: where a step's
+    row and a prompt's rows go, and where a bubble's go instead — the
+    scratch row, the scratch group.  What :class:`KVCacheFormat` and the
+    latent cache's format (``ops/latent_cache.py``) share; a format says
+    ``positions``, ``groups`` and, for a ring buffer, ``window``."""
 
-    kv_heads: int
-    head_dim: int
-    #: positions a sequence may hold
-    positions: int
-    #: the rows' float type (not read when quantized: the rows are int8)
-    dtype: Any
-    quantized: bool = False
-    #: the ring's round-robin groups (a leading axis, with the scratch
-    #: group and the scratch row); None for slots alone
-    groups: int | None = None
-    #: a ring buffer: the rows a sequence keeps, fewer than
-    #: ``positions`` (the module docstring); None: a row a position
-    window: int | None = None
-    #: queries that read one KV head (what decides :attr:`joined`)
-    query_group: int = 1
-
-    @property
-    def joined(self) -> bool:
-        """Whether the buffers are ``[batch, positions, kv_heads *
-        head_dim]`` (the module docstring): for float rows of whole
-        lane rows read by a group the matrix unit is for."""
-        return (self.query_group >= _MXU_GROUP and not self.quantized
-                and self.head_dim % _LANES == 0)
-
-    def __post_init__(self):
-        if self.window is not None and not 0 < self.window < self.positions:
-            raise ValueError(
-                f"a ring buffer of {self.window} rows for {self.positions} "
-                "positions: a window that never wraps is no window "
-                "(window=None holds a row a position)")
+    #: a ring buffer's rows (a format that has them names its own)
+    window = None
 
     @property
     def rows_held(self) -> int:
@@ -606,6 +583,51 @@ class KVCacheFormat(LayeredState):
         group = jnp.where(valid, group, self.scratch_group)
         return group if row is None else (group, row)
 
+    def _buffer_rows(self) -> tuple:
+        """``(leading axes, rows)`` of a buffer: with ``groups`` the
+        scratch group and the scratch row are counted."""
+        if self.groups is None:
+            return (), self.rows_held
+        return (self.groups + 1,), self.rows_held + 1
+
+
+@dataclasses.dataclass(frozen=True)
+class KVCacheFormat(RingRows):
+    """One layer's cache, described: what both decode engines build
+    their buffers from and write and read them through (``zeros``,
+    ``layer`` and ``with_layer`` are ``ops/layered.py``'s)."""
+
+    kv_heads: int
+    head_dim: int
+    #: positions a sequence may hold
+    positions: int
+    #: the rows' float type (not read when quantized: the rows are int8)
+    dtype: Any
+    quantized: bool = False
+    #: the ring's round-robin groups (a leading axis, with the scratch
+    #: group and the scratch row); None for slots alone
+    groups: int | None = None
+    #: a ring buffer: the rows a sequence keeps, fewer than
+    #: ``positions`` (the module docstring); None: a row a position
+    window: int | None = None
+    #: queries that read one KV head (what decides :attr:`joined`)
+    query_group: int = 1
+
+    @property
+    def joined(self) -> bool:
+        """Whether the buffers are ``[batch, positions, kv_heads *
+        head_dim]`` (the module docstring): for float rows of whole
+        lane rows read by a group the matrix unit is for."""
+        return (self.query_group >= _MXU_GROUP and not self.quantized
+                and self.head_dim % _LANES == 0)
+
+    def __post_init__(self):
+        if self.window is not None and not 0 < self.window < self.positions:
+            raise ValueError(
+                f"a ring buffer of {self.window} rows for {self.positions} "
+                "positions: a window that never wraps is no window "
+                "(window=None holds a row a position)")
+
     def _row(self, slot):
         """The ring buffer's row for :meth:`decode_slot`'s ``slot``."""
         return jnp.where(slot < 0, self.scratch_position,
@@ -620,9 +642,7 @@ class KVCacheFormat(LayeredState):
 
     def buffers(self, batch: int) -> dict[str, jax.ShapeDtypeStruct]:
         """One layer's buffers for ``batch`` sequences (a group), by key."""
-        lead, length = (), self.rows_held
-        if self.groups is not None:
-            lead, length = (self.groups + 1,), self.rows_held + 1
+        lead, length = self._buffer_rows()
         if self.joined:
             # whole sublane tiles of positions: at 4097 rows XLA:TPU
             # would tile another dimension with the lanes (the sequences:

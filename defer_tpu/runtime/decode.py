@@ -31,7 +31,9 @@ TPU-native design, one SPMD program:
     place, every sequence of the group at one position, and attends
     over the group's live rows where they lie; warmup bubbles write the
     format's scratch row and prefill bubbles its scratch group, so no
-    masked read-modify-write of the cache is ever needed.  A retention
+    masked read-modify-write of the cache is ever needed.  A latent
+    cache (``ops/latent_cache.py``): one row a position that every head
+    shares, written and attended over the same way.  A retention
     state (``ops/retention.py``) or a state-space state
     (``ops/ssm.py``): of fixed size, read and rewritten whole each
     step; a bubble is its identity update, so it has neither scratch.
@@ -257,6 +259,16 @@ class PipelinedDecoder:
             REGISTRY.gauge("decode.cache.window_positions").set(max(
                 (fmt.window or 0 for k, fmt, _key, _size in sizes
                  if k == "kv_cache"), default=0))
+        if "latent_cache" in kinds:
+            # the latent layers' buffers as they are laid out, and the
+            # rows (of a layer, a sequence, a group) those bytes are:
+            # their quotient is what a live row costs a step to read
+            REGISTRY.gauge("decode.cache.latent_bytes").set(held(
+                lambda k, fmt, key: k == "latent_cache"))
+            REGISTRY.gauge("decode.cache.latent_positions").set(sum(
+                n * math.prod(fmt.buffers(mb)["latent"].shape[:-1])
+                for k, fmt in zip(self.memory, self.state_formats)
+                if k == "latent_cache"))
         if "ssm" in kinds:
             # the convolutions' windows, of the state-space layers' all
             REGISTRY.gauge("decode.ssm.conv_bytes").set(held(
@@ -326,38 +338,20 @@ class PipelinedDecoder:
                    if mb % r == 0 and (r == 1 or r * row
                                        <= _PREFILL_PIECE_BYTES))
 
-    def _check_layers_alike(self, layout: dict) -> None:
-        """Local layer ``l``'s leaves are stacked over the stages, so its
-        block on every stage must have the parameter tree, shapes
-        included, of the longest stage's."""
-        def shapes(nm):
-            return {k: shape for k, (shape, _) in layout[nm].items()}
-
-        longest = max(self.stage_blocks, key=len)
-        for s, names in enumerate(self.stage_blocks):
-            for l, nm in enumerate(names):
-                mine, other = shapes(nm), shapes(longest[l])
-                differ = sorted(k for k in mine.keys() | other.keys()
-                                if mine.get(k) != other.get(k))
-                if differ:
-                    raise ValueError(
-                        f"stage {s}'s layer {l} ({nm}) and {longest[l]} "
-                        "at the same place of its stage differ in their "
-                        f"parameters {differ}: the ring stacks a local "
-                        "layer's leaves over the stages, so every "
-                        "stage's layers must repeat the same parameter "
-                        "trees in the same order (cut the graph at a "
-                        "whole period of its layer pattern)")
-
     def _place_weights(self, params, *, init: bool):
         """``params`` on the mesh as the compiled programs take them:
         ``{"blocks": (tree, ...), "ends": {name: tree}}``, every leaf
         ``[N, ...]`` and sharded over the stages.  Stage ``s``'s part of
         ``blocks[l]`` is its ``l``-th block's tree (zeros where it has
         fewer blocks), of ``ends[name]`` that node's tree on the one
-        stage that holds it (zeros on the others).  Under W8A16 a leaf
-        is an ``Int8Weight`` of two such arrays.  ``init=False``
-        (reweight): the new leaves must have the tree, the shapes and
+        stage that holds it (zeros on the others).  Where the stages'
+        ``l``-th blocks are of unlike kinds (a leading dense layer at
+        the place of another stage's routed one), ``blocks[l]`` is a
+        tuple of trees, one a kind, zeros on the stages whose block is
+        of another (``self._variant[l]`` says which a stage reads).
+        Under W8A16 a leaf is an ``Int8Weight`` of two such arrays.
+        ``init=False`` (reweight): the new leaves must have the tree,
+        the shapes and
         the types, before the cast to the compute type, of what was
         deployed — the compiled programs take that and nothing else.
         One leaf at a time goes host -> device, so the host never holds
@@ -366,7 +360,6 @@ class PipelinedDecoder:
         layout = {nm: _leaf_layout(params[nm])
                   for nm in (*self.block_names, *self._ends)}
         if init:
-            self._check_layers_alike(layout)
             self._layout = layout
         for nm, got in layout.items():
             if got != self._layout[nm]:
@@ -391,14 +384,31 @@ class PipelinedDecoder:
 
             return jax.tree.map(place, *trees)
 
-        blocks = []
+        blocks, variant = [], []
         for l, longest in enumerate(max(self.stage_blocks, key=len)):
             # stage s's l-th block; where it has fewer, the longest
             # stage's stands in for the shapes
             real = [l < len(b) for b in self.stage_blocks]
-            blocks.append(placed(
-                [params[b[l] if ok else longest]
-                 for ok, b in zip(real, self.stage_blocks)], real))
+            names = [b[l] if ok else longest
+                     for ok, b in zip(real, self.stage_blocks)]
+            kinds = [tuple(layout[nm].items()) for nm in names]
+            distinct = list(dict.fromkeys(kinds))
+            if len(distinct) == 1:
+                variant.append(None)
+                blocks.append(placed([params[nm] for nm in names], real))
+                continue
+            # unlike blocks at one place of their stages: a tree a kind
+            variant.append([distinct.index(kind) for kind in kinds])
+            blocks.append(tuple(
+                placed([params[nm if kind == want else
+                               names[kinds.index(want)]]
+                        for nm, kind in zip(names, kinds)],
+                       [ok and kind == want
+                        for ok, kind in zip(real, kinds)])
+                for want in distinct))
+        #: per local layer, None where every stage's block has the same
+        #: parameter tree, else the tree of ``blocks[l]`` each stage reads
+        self._variant = variant
         ends = {nm: placed([params[nm]] * n, [s == at for s in range(n)])
                 for nm, at in self._ends.items()}
         return {"blocks": tuple(blocks), "ends": ends}
@@ -428,7 +438,9 @@ class PipelinedDecoder:
     def _stage_params(self, s: int, w_local):
         """Stage ``s``'s parameter trees by node, out of a device's part
         of the weights."""
-        p = dict(zip(self.stage_blocks[s], w_local["blocks"]))
+        p = {nm: tree if which is None else tree[which[s]]
+             for nm, tree, which in zip(self.stage_blocks[s],
+                                        w_local["blocks"], self._variant)}
         p.update((nm, w_local["ends"][nm])
                  for nm, at in self._ends.items() if at == s)
         return quant.dequantize_weights(p, self.compute_dtype) \

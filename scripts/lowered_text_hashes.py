@@ -8,7 +8,9 @@ several; ``olmoe_tiny``'s widths at four layers for four stages;
 ``brumby_tiny``, whose state has neither int8 rows nor beams;
 ``cohere_moe_tiny``, a format a layer; ``jamba_tiny``, two kinds of
 memory in one graph, a period a stage; ``granite_hybrid_tiny``, the
-same with a state of heads and routed experts in every layer) and
+same with a state of heads and routed experts in every layer;
+``kimi_k2_tiny``, a latent cache, and at two stages a dense block at
+the place of the other stage's routed one) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -34,7 +36,7 @@ import jax.numpy as jnp
 
 from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
                               granite_hybrid_tiny, gpt_tiny, jamba_tiny,
-                              olmoe, olmoe_tiny)
+                              kimi_k2_tiny, olmoe, olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -61,6 +63,9 @@ def ring_configurations():
     yield "jamba_tiny", jamba_tiny(), (1, 2), *plain
     # the state-space state's second shape, and every layer routing
     yield "granite_hybrid_tiny", granite_hybrid_tiny(), (1, 2), *plain
+    # a latent cache has neither int8 rows nor beams; two stages put the
+    # dense block beside a routed one
+    yield "kimi_k2_tiny", kimi_k2_tiny(), (1, 2), *plain
 
 
 def ring_programs(name, graph, stages, kv_caches, beams):
