@@ -49,7 +49,8 @@ cheapest (docs/DECODE_CLIFF.md):
 * :meth:`KVCacheFormat.write_position` — one position for every
   sequence: one ``lax.dynamic_update_slice`` a buffer;
 * :meth:`KVCacheFormat.write_slots` — a position a sequence: the
-  aliased Pallas call :func:`write_kv_rows`;
+  aliased Pallas call :func:`write_kv_rows`, which with a list of live
+  sequences (:func:`live_slots`) moves their windows and no other;
 * :meth:`KVCacheFormat.write_prefix` — a whole prompt for one group:
   one relayout to head-major a prompt, then one bulk write.
 
@@ -59,6 +60,15 @@ over a layer's buffers *where they lie* — the Pallas kernel
 position blocks that hold live rows and no other.  Only the int8 rows
 stay on the plain einsum (:func:`attend_einsum`), which is also the
 oracle the tests hold the kernel to.
+
+**A list of live sequences** (:func:`live_slots`), for a holder most of
+whose sequences are idle — the serving engine, 2 of 16 slots live under
+its knee: ``write_slots`` and ``attend`` take it as data, a scalar row
+their kernels read, and visit the listed sequences only.  The grid
+keeps its extent (a program a *count* of live sequences would be a
+compile a count); a grid step past the list names what is already in
+fast memory and does nothing.  Without a list both calls are what they
+were, operand for operand: the ring passes none.
 """
 
 from __future__ import annotations
@@ -70,6 +80,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
@@ -92,26 +103,73 @@ _JOINED_ROWS = 16
 _MXU_GROUP = 8
 
 
-def _write_kernel(group_ref, pos_ref, rows_ref, win_ref, out_ref):
-    # rows_ref [1, hd, kv] f32; win_ref / out_ref [1, 1, kv, hd, window]
+def live_slots(slots, width: int) -> np.ndarray:
+    """The list the slot-wise calls take (:meth:`KVCacheFormat.write_slots`,
+    :meth:`KVCacheFormat.attend`): ``[width + 1]`` int32 — the ``n``
+    slots that hold a row this step, ascending, the last of them
+    repeated up to ``width``, and ``n`` (at least 1) behind them.  One
+    host row: a step's kernels read it from scalar memory, so who is
+    live is data and a step stays one program."""
+    out = np.empty(width + 1, np.int32)
+    n = len(slots)
+    out[:n] = slots
+    out[n:width] = slots[-1]
+    out[width] = n
+    return out
+
+
+def _visited(j, live_ref):
+    """Grid step ``j`` of a call that walks a list (:func:`live_slots`):
+    ``(slot, wanted)`` — the slot it serves, and whether the step is one
+    of the list's ``n``.  A step past them names the last live slot
+    again: its blocks are the blocks already in fast memory, and under
+    ``pl.when(wanted)`` it neither fetches, computes nor writes back."""
+    n = live_ref[live_ref.shape[0] - 1]
+    return live_ref[jnp.minimum(j, n - 1)], j < n
+
+
+def _write_kernel(group_ref, pos_ref, *refs):
+    # rows_ref [1, hd, kv] f32; win_ref / out_ref [1, 1, kv, hd, window];
+    # with a list, live_ref [b + 1] leads them
     del group_ref                       # the index maps read it
+    *live, rows_ref, win_ref, out_ref = refs
     kv, hd, window = win_ref.shape[2:]
-    at = pos_ref[pl.program_id(0)] % window
-    hit = lax.broadcasted_iota(jnp.int32, (hd, window), 1) == at
-    for k in range(kv):
-        out_ref[0, 0, k] = jnp.where(
-            hit, rows_ref[0, :, k:k + 1],
-            win_ref[0, 0, k].astype(jnp.float32)).astype(out_ref.dtype)
+    i, wanted = pl.program_id(0), None
+    if live:
+        i, wanted = _visited(i, live[0])
+
+    def write():
+        at = pos_ref[i] % window
+        hit = lax.broadcasted_iota(jnp.int32, (hd, window), 1) == at
+        for k in range(kv):
+            out_ref[0, 0, k] = jnp.where(
+                hit, rows_ref[0, :, k:k + 1],
+                win_ref[0, 0, k].astype(jnp.float32)).astype(out_ref.dtype)
+
+    if wanted is None:
+        write()
+    else:
+        pl.when(wanted)(write)
 
 
 @jax.jit
-def write_kv_rows(cache, rows, pos, group=None):
+def write_kv_rows(cache, rows, pos, group=None, live=None):
     """``cache`` [b, kv, L, hd] with ``rows[i]`` ([b, kv, 1, hd], cast
     to the cache's type) written at position ``pos[i]`` of sequence
     ``i``; nothing else of the buffer is touched.  ``pos`` [b] int32
     in ``[0, L)``.  With ``group`` ([1] int32) the cache is a ring's
     ``[groups, b, kv, L, hd]`` and the rows are that group's.  The
     result aliases ``cache``: donate it.
+
+    With ``live`` (:func:`live_slots`, [b + 1] int32) only the listed
+    sequences' rows are written, and only their windows move: the grid
+    keeps its ``b`` steps, step ``j`` serves sequence ``live[j]``, and a
+    step past the list's ``n`` names the window already in fast memory
+    and does nothing (:func:`_visited`) — an unlisted sequence's window
+    stays as it lies, through the alias.  The serving engine's call: 2.5
+    of its 16 slots hold a request, and a window is 0.8 MB each way.
+    The slot axis is revisited on those steps, so it is ``"arbitrary"``
+    (the default; the v5e has one TensorCore to run it on).
 
     XLA:TPU keeps such an array with the positions on the lanes when
     ``hd`` is under a lane row (128): ``hd`` 64 would otherwise be
@@ -140,13 +198,22 @@ def write_kv_rows(cache, rows, pos, group=None):
     6 s to the serving cell's set-up."""
     if group is None:
         return write_kv_rows(cache.reshape((1,) + cache.shape), rows, pos,
-                             jnp.zeros(1, jnp.int32)).reshape(cache.shape)
+                             jnp.zeros(1, jnp.int32),
+                             live).reshape(cache.shape)
     groups, b, kv, cache_len, hd = cache.shape
     window = min(_LANES, cache_len)
     pos = jnp.clip(pos.astype(jnp.int32), 0, cache_len - 1)
     group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
+    listed = () if live is None else (live.astype(jnp.int32),)
 
-    def at_window(i, group_ref, pos_ref):
+    def sequence(i, live):
+        return _visited(i, live[0])[0] if live else i
+
+    def at_row(i, group_ref, pos_ref, *live):
+        return (sequence(i, live), 0, 0)
+
+    def at_window(i, group_ref, pos_ref, *live):
+        i = sequence(i, live)
         return (group_ref[0], i, 0, 0, pos_ref[i] // window)
 
     # the rows go in as [b, hd, kv]: a head's row is then a column the
@@ -156,17 +223,16 @@ def write_kv_rows(cache, rows, pos, group=None):
     out = pl.pallas_call(
         _write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b,),
-            in_specs=[pl.BlockSpec((1, hd, kv),
-                                   lambda i, group_ref, pos_ref: (i, 0, 0)),
+            num_scalar_prefetch=2 + len(listed), grid=(b,),
+            in_specs=[pl.BlockSpec((1, hd, kv), at_row),
                       pl.BlockSpec((1, 1, kv, hd, window), at_window)],
             out_specs=pl.BlockSpec((1, 1, kv, hd, window), at_window)),
         out_shape=jax.ShapeDtypeStruct((groups, b, kv, hd, cache_len),
                                        cache.dtype),
-        input_output_aliases={3: 0},
+        input_output_aliases={3 + len(listed): 0},
         interpret=jax.default_backend() != "tpu",
         name="kv_write_rows",
-    )(group, pos, rows, jnp.swapaxes(cache, 3, 4))
+    )(group, pos, *listed, rows, jnp.swapaxes(cache, 3, 4))
     return jnp.swapaxes(out, 3, 4)
 
 
@@ -211,8 +277,7 @@ def attend_blocks(kv: int, hd: int, length: int, itemsize: int):
     return kvb, tl
 
 
-def _attend_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   qs_ref, m_ref, l_ref, acc_ref, *, tl, on_lanes, scale):
+def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale):
     """One position block of one sequence's KV heads: online softmax on
     the vector unit, in f32.  A block is ``[kvb, hd, tl]`` with the
     positions on the lanes (``on_lanes``) or ``[tl, kvb, hd]``; one body
@@ -225,19 +290,32 @@ def _attend_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     q_ref / o_ref ``[1, kvb, g, hd]`` (``[1, g, kvb, hd]`` off the
     lanes); qs_ref / acc_ref ``[g, kvb, hd, 1]`` (``[g, 1, kvb, hd]``):
     a query in the shape that multiplies a block; m_ref / l_ref the
-    same with ``hd`` reduced away."""
+    same with ``hd`` reduced away.  With a list two operands lead them:
+    ``live_ref`` [b + 1], which the grid's first axis walks
+    (:func:`_visited`), and the output's zeros, aliased to it and never
+    read."""
     del group_ref                       # the index maps read it
+    *live, q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref, acc_ref = refs
     pa, da = (2, 1) if on_lanes else (0, 2)
     g, hd = qs_ref.shape[0], qs_ref.shape[2 if on_lanes else 3]
     t = pl.program_id(2)
-    pos = pos_ref[pl.program_id(0)]
+    i, wanted = pl.program_id(0), None
+    if live:
+        i, wanted = _visited(i, live[0])
+    pos = pos_ref[i]
+
+    def when(condition):
+        # a step past the list's last slot does nothing at all
+        return pl.when(condition if wanted is None
+                       else jnp.logical_and(wanted, condition))
+
     if on_lanes:
         # a head's row [1, hd] and its column [hd, 1] are each the
         # other spread over this diagonal and reduced
         eye = (lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
                == lax.broadcasted_iota(jnp.int32, (hd, hd), 1))[None]
 
-    @pl.when(t == 0)
+    @when(t == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -278,11 +356,11 @@ def _attend_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     # a block wholly past ``pos`` is not computed (nor fetched: its
     # index map names a block that is wanted next); only the block that
     # holds ``pos`` pays for masks
-    pl.when((t + 1) * tl - 1 <= pos)(lambda: accumulate(False))
-    pl.when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - 1 > pos))(
+    when((t + 1) * tl - 1 <= pos)(lambda: accumulate(False))
+    when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - 1 > pos))(
         lambda: accumulate(True))
 
-    @pl.when(t == pl.num_programs(2) - 1)
+    @when(t == pl.num_programs(2) - 1)
     def _finish():
         for j in range(g):
             out = acc_ref[j] / l_ref[j]
@@ -414,7 +492,7 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int):
 
 
 @jax.jit
-def kv_attend(q, k_buf, v_buf, pos, group):
+def kv_attend(q, k_buf, v_buf, pos, group, live=None):
     """One query a sequence over its live rows: ``q`` [b, heads * hd]
     against ``k_buf`` / ``v_buf`` [groups, b, kv, L, hd] as they are
     stored, sequence ``i`` of group ``group`` [1] over its positions
@@ -445,6 +523,19 @@ def kv_attend(q, k_buf, v_buf, pos, group):
     queries; a group of :data:`_MXU_GROUP` or more is a matrix's rows,
     and a format for such a group holds its buffers joined for
     :func:`kv_attend_joined` (the same name in a device trace).
+
+    With ``live`` (:func:`live_slots`, [b + 1] int32) the grid's first
+    axis walks the list as :func:`write_kv_rows`'s does: step ``j``
+    serves sequence ``live[j]``, a step past the list's ``n`` names the
+    blocks of the last live step again and does nothing, and the
+    look-ahead of a dead position block names the first block of
+    ``live[j + 1]`` (behind the last listed sequence there is none).
+    An unlisted sequence's keys and values are not fetched and its
+    output row is zeros: the output aliases a zero operand, so what no
+    step writes is defined.  Revisited on the steps past ``n``, the
+    first axis is then ``"arbitrary"``, and the heads' with it (one
+    TensorCore on the v5e; on two, a split of the heads' axis would
+    hand a core steps whose blocks another core holds).
     Jitted for the reason :func:`write_kv_rows` is."""
     b, d = q.shape
     groups, _, kv, length, hd = k_buf.shape
@@ -454,25 +545,40 @@ def kv_attend(q, k_buf, v_buf, pos, group):
     pos = jnp.clip(pos.astype(jnp.int32), 0, length - 1)
     group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
     q = q.reshape(b, kv, g, hd)
+    steps = (b, kv // kvb, pl.cdiv(length, tl))
 
-    def head_block(i, h, t, group_ref, pos_ref):
-        return (i, h, 0, 0) if on_lanes else (i, 0, h, 0)
+    def walk(i, h, t, live):
+        """``(i, h, t, sequence of, count)``: without a list the grid's
+        own step, the identity and ``b``; with one, a step past the
+        list's ``n`` is the last live step again, a step serves
+        ``live[i]``, and there are ``n`` to serve."""
+        if not live:
+            return i, h, t, lambda i: i, b
+        n = live[0][b]
+        over = i >= n
+        return (jnp.where(over, n - 1, i), jnp.where(over, steps[1] - 1, h),
+                jnp.where(over, steps[2] - 1, t), lambda i: live[0][i], n)
 
-    def cache_block(i, h, t, group_ref, pos_ref):
+    def head_block(i, h, t, group_ref, pos_ref, *live):
+        i, h, t, sequence, _ = walk(i, h, t, live)
+        return (sequence(i), h, 0, 0) if on_lanes else (sequence(i), 0, h, 0)
+
+    def cache_block(i, h, t, group_ref, pos_ref, *live):
         # past the last live block, name the first block of the grid's
         # next (sequence, heads): it is fetched while the last live one
         # is computed, and being named again until its turn comes it is
         # fetched once.  Naming the last live block again would leave
         # the next sequence's first fetch with nothing to hide behind.
-        dead = t > pos_ref[i] // tl
+        i, h, t, sequence, count = walk(i, h, t, live)
+        dead = t > pos_ref[sequence(i)] // tl
         wrap = h + 1 == kv // kvb
-        more = jnp.logical_and(dead, jnp.logical_or(i + 1 < b,
+        more = jnp.logical_and(dead, jnp.logical_or(i + 1 < count,
                                                     jnp.logical_not(wrap)))
         i = jnp.where(jnp.logical_and(more, wrap), i + 1, i)
         h = jnp.where(more, jnp.where(wrap, 0, h + 1), h)
-        t = jnp.where(more, 0, jnp.minimum(t, pos_ref[i] // tl))
-        return (group_ref[0], i, h, 0, t) if on_lanes \
-            else (group_ref[0], i, t, h, 0)
+        t = jnp.where(more, 0, jnp.minimum(t, pos_ref[sequence(i)] // tl))
+        return (group_ref[0], sequence(i), h, 0, t) if on_lanes \
+            else (group_ref[0], sequence(i), t, h, 0)
 
     if on_lanes:
         k_buf, v_buf = jnp.swapaxes(k_buf, 3, 4), jnp.swapaxes(v_buf, 3, 4)
@@ -483,14 +589,19 @@ def kv_attend(q, k_buf, v_buf, pos, group):
         k_buf, v_buf = jnp.swapaxes(k_buf, 2, 3), jnp.swapaxes(v_buf, 2, 3)
         heads, block = (1, g, kvb, hd), (1, 1, tl, kvb, hd)
         state, reduced = (g, 1, kvb, hd), (g, 1, kvb, 1)
+    # with a list: the list, and the zeros an unvisited row keeps (the
+    # output's alias, left where it lies)
+    listed = () if live is None else (live.astype(jnp.int32),
+                                      jnp.zeros_like(q))
     out = pl.pallas_call(
         functools.partial(_attend_kernel, tl=tl, on_lanes=on_lanes,
                           scale=1.0 / math.sqrt(hd)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, kv // kvb, pl.cdiv(length, tl)),
-            in_specs=[pl.BlockSpec(heads, head_block),
-                      pl.BlockSpec(block, cache_block),
-                      pl.BlockSpec(block, cache_block)],
+            num_scalar_prefetch=2 + len(listed[:1]), grid=steps,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in listed[1:]]
+            + [pl.BlockSpec(heads, head_block),
+               pl.BlockSpec(block, cache_block),
+               pl.BlockSpec(block, cache_block)],
             out_specs=pl.BlockSpec(heads, head_block),
             scratch_shapes=[pltpu.VMEM(state, jnp.float32),
                             pltpu.VMEM(reduced, jnp.float32),
@@ -498,10 +609,12 @@ def kv_attend(q, k_buf, v_buf, pos, group):
                             pltpu.VMEM(state, jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",) * 3 if listed
+            else ("parallel", "parallel", "arbitrary")),
+        input_output_aliases={3: 0} if listed else {},
         interpret=jax.default_backend() != "tpu",
         name="kv_attend",
-    )(group, pos, q, k_buf, v_buf)
+    )(group, pos, *listed, q, k_buf, v_buf)
     if not on_lanes:
         out = jnp.swapaxes(out, 1, 2)
     return out.reshape(b, d)
@@ -712,15 +825,17 @@ class KVCacheFormat(RingRows):
             out[key] = lax.dynamic_update_slice(buf, row, at)
         return out
 
-    def write_slots(self, layer: dict, rows: dict, pos) -> dict:
+    def write_slots(self, layer: dict, rows: dict, pos, live=None) -> dict:
         """``rows`` written in place, sequence ``i``'s at its own
-        position ``pos[i]``: the layer."""
+        position ``pos[i]``: the layer.  With ``live``
+        (:func:`live_slots`) the listed sequences' rows only: an
+        unlisted sequence's rows are neither written nor moved."""
         if self.quantized or self.groups is not None \
                 or self.window is not None or self.joined:
             raise NotImplementedError(
                 "a position a sequence is written into unquantized "
                 "slots of a row a position only (ROADMAP.md A5, B2)")
-        return {key: write_kv_rows(layer[key], rows[key], pos)
+        return {key: write_kv_rows(layer[key], rows[key], pos, live=live)
                 for key in layer}
 
     def write_prefix(self, layer: dict, k, v, slot) -> dict:
@@ -804,14 +919,22 @@ class KVCacheFormat(RingRows):
 
     # -- attention -------------------------------------------------------
 
-    def attend(self, q, layer: dict, pos, group=None):
+    def attend(self, q, layer: dict, pos, group=None, live=None):
         """One query a sequence over ``layer``'s buffers (group
         ``group``'s sequences, where the format has groups): ``q`` [b,
         heads * head_dim], positions ``<= pos`` live — ``pos`` a scalar
         (every sequence at one position) or [b], one a sequence;
         returns [b, heads * head_dim].  :func:`kv_attend`, or the
         einsums for int8 rows.  Over a ring buffer ``pos`` is
-        :meth:`decode_slot`'s, the sequences' true position."""
+        :meth:`decode_slot`'s, the sequences' true position.  With
+        ``live`` (:func:`live_slots`) the listed sequences only: an
+        unlisted sequence's rows are not read and its output is zeros
+        (plain rows only: neither the einsums nor
+        :func:`kv_attend_joined` walk a list)."""
+        if live is not None and (self.quantized or self.joined):
+            raise NotImplementedError(
+                "a list of live sequences is walked over plain rows only "
+                "(kv_attend; ROADMAP.md A5)")
         if self.window is not None:
             pos = self._last_live(pos)
         if self.quantized:
@@ -827,4 +950,4 @@ class KVCacheFormat(RingRows):
         if self.joined:
             return kv_attend_joined(q, k_buf, v_buf, pos, group,
                                     kv=self.kv_heads)
-        return kv_attend(q, k_buf, v_buf, pos, group)
+        return kv_attend(q, k_buf, v_buf, pos, group, live)

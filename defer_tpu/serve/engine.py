@@ -42,7 +42,13 @@ engine is continuous batching proper:
   ``write_slots``: slots sit at different positions, and a vmapped
   write would be a batched scatter over a re-laid-out item,
   docs/DECODE_CLIFF.md), attention with each slot's own live mask, then
-  ``decode_finish``.  Every row's
+  ``decode_finish``.  The cache's two kernels walk the list of LIVE
+  slots the host sends with the step's other rows
+  (``kv_cache.live_slots``: who has a step left is known before the
+  step in flight is read) and touch no other slot: an idle slot's
+  window is not moved, its keys and values are not read, its attention
+  is zeros and its id is dropped at delivery — under the knee most
+  slots are idle, and moving their rows was 40% of a step.  Every row's
   computation reads its own rows only, so a row's output bytes are
   INDEPENDENT of who shares the batch — per-request outputs are
   byte-identical to the request run alone, the correctness bar
@@ -238,6 +244,10 @@ class ContinuousBatchEngine:
         #: for.  None is ever discarded: a finish is known before the
         #: launch
         self._ahead_count = REGISTRY.counter("serve.decode.ahead.launched")
+        #: slots the launched steps' cache kernels visited: over steps
+        #: times ``width``, the share of the slot-wise work a step does
+        #: (1.0: every slot live, the list saves nothing)
+        self._rows_count = REGISTRY.counter("serve.decode.rows.launched")
         #: how often the prefill engages: prompt tokens it took, and
         #: prompt tokens a step was fed (a request's last, a long tail)
         self._prefilled_count = REGISTRY.counter(
@@ -310,7 +320,7 @@ class ContinuousBatchEngine:
         fmt = self.kv_format
 
         def step(params, caches, prev_ids, host_ids, from_host, pos, seeds,
-                 temps):
+                 temps, live):
             # the id a slot feeds: the one the step before sampled for
             # it, still on the device — or the host's, where the host
             # owns it (a prompt token, a slot with no request)
@@ -320,12 +330,16 @@ class ContinuousBatchEngine:
                                  safe).astype(jnp.float32)
             for l, (op, nm) in enumerate(blocks):
                 q, k_new, v_new = op.decode_qkv(params[nm], x, safe)
+                # the cache's kernels visit the slots on the list and
+                # no other: an idle slot's window is not moved, its keys
+                # and values are not read and its attention is zeros
                 layer = fmt.write_slots(fmt.layer(caches, l),
-                                        fmt.rows(k_new, v_new), safe)
+                                        fmt.rows(k_new, v_new), safe, live)
                 caches = fmt.with_layer(caches, l, layer)
-                # every slot attends over its own positions <= its own
+                # every live slot attends over its own positions <= its
+                # own
                 x = op.decode_finish(params[nm], x,
-                                     fmt.attend(q, layer, safe))
+                                     fmt.attend(q, layer, safe, live=live))
             h = final_ln.apply(params["final_ln"], x)
             logits = lm_head.apply(params["lm_head"],
                                    h).astype(jnp.float32)
@@ -450,14 +464,17 @@ class ContinuousBatchEngine:
         flight, self._flight = self._flight, None
         return self._deliver(flight) if flight is not None else []
 
-    def _blank_rows(self) -> tuple:
+    def _blank_rows(self, live=(0,)) -> tuple:
         """A step's host rows ``(host_ids, from_host, pos, seeds,
-        temps)`` with no request in any slot: id 0 at position 0, the
-        host's."""
+        temps, live)`` with no request in any slot: id 0 at position 0,
+        the host's.  The sixth row is the list of the slots ``live``
+        (ascending) as the cache's kernels take it
+        (``kv_cache.live_slots``): the slots they visit — never none, a
+        step is launched for a live slot or not at all."""
         w = self.width
         return (np.zeros(w, np.int32), np.ones(w, np.bool_),
                 np.zeros(w, np.int32), np.zeros(w, np.uint32),
-                np.zeros(w, np.float32))
+                np.zeros(w, np.float32), kv_cache.live_slots(live, w))
 
     def _launch(self, rows) -> None:
         """The first two phases of ``obs/profile.py::ENGINE_PHASES`` for
@@ -467,7 +484,9 @@ class ContinuousBatchEngine:
         ``ENGINE_DISPATCH_PHASES``).  The step becomes the one in
         flight."""
         with span("engine", "gather"):
-            host_ids, from_host, pos, seeds, temps = self._blank_rows()
+            # who is live is known before the step in flight is read
+            host_ids, from_host, pos, seeds, temps, live = self._blank_rows(
+                [i for i, _ in rows])
             sample = False
             fed = []
             for i, s in rows:
@@ -484,11 +503,13 @@ class ContinuousBatchEngine:
                 sample = sample or s.req.temperature > 0
                 fed.append((i, s, s.pos))
                 s.pos += 1
+        self._rows_count.n += len(rows)
         if self._flight is not None:    # launched ahead of its read
             self._ahead_count.n += 1
         with span("engine", "dispatch") as dispatched:
             with span("engine", "upload"):
-                up = jax.device_put((host_ids, from_host, pos, seeds, temps))
+                up = jax.device_put((host_ids, from_host, pos, seeds, temps,
+                                     live))
             with span("engine", "launch"):
                 self._prev_ids, self._caches = self._step_fn(sample)(
                     self.params, self._caches, self._prev_ids, *up)

@@ -864,7 +864,8 @@ class ServeFrontDoor:
                 "steps": self.engine.steps,
                 **{name: REGISTRY.counter(f"serve.decode.{name}").value
                    for name in ("tokens", "prompt_tokens_prefilled",
-                                "prompt_tokens_forced", "ahead.launched")},
+                                "prompt_tokens_forced", "ahead.launched",
+                                "rows.launched")},
                 **{f"{name}_s": REGISTRY.histogram(
                     f"serve.decode.{name}_s").summary()
                    for name in ("step",) + ENGINE_LOOP_PHASES},

@@ -75,7 +75,8 @@ def main() -> int:
     item = buffers["k"].shape
 
     # the step's per-slot rows: the ids the step before returned and
-    # the host's five
+    # the host's six (the last the list of live slots, which both cache
+    # kernels take as a scalar-prefetch operand: Mosaic sees them here)
     rows = [shaped(x) for x in (eng._prev_ids, *eng._blank_rows())]
 
     def report(lowered) -> dict:

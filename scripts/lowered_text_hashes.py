@@ -22,7 +22,10 @@ written to ``DIR/<name>.txt`` for ``diff``.  It reads only what both
 engines have always had: ``_init_state``, ``_get_decode_fn``,
 ``_build_prefill_fn``, ``_step_fn``, ``_caches`` (and the engine's
 ``_prefill_fns`` and ``_blocks``, which it has had since PR 39, and its
-``_prev_ids`` and ``_blank_rows``, the step's inputs since PR 42).
+``_prev_ids`` and ``_blank_rows``, the step's inputs since PR 42; since
+PR 46 ``_blank_rows`` ends with a sixth host row, the list of live
+slots the step's cache kernels walk, ``ops/kv_cache.py::live_slots``,
+so both ``engine.step.*`` lines are new there and no other line is).
 """
 
 import hashlib

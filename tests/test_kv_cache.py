@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from defer_tpu.models import gpt_tiny
 from defer_tpu.ops import kv_cache
 from defer_tpu.ops.kv_cache import (KVCacheFormat, attend_blocks,
-                                    attend_einsum, quantize_rows,
+                                    attend_einsum, live_slots, quantize_rows,
                                     write_kv_rows)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine, DecodeRequest
@@ -372,6 +372,141 @@ def test_write_slots_is_the_row_writer_on_every_buffer():
         _fmt(quantized=True).write_slots(layer, rows, pos)
 
 
+# -- a list of live slots: the serving engine's calls ---------------------------
+
+#: lists over 16 slots: one, three apart, every one, the last alone
+_LISTS = [[5], [1, 7, 15], list(range(16)), [15]]
+_LIST_IDS = ["one", "three", "all-16", "last"]
+
+
+def test_live_slots_pads_with_the_last_and_ends_with_the_count():
+    np.testing.assert_array_equal(
+        live_slots([1, 7, 15], 16), [1, 7, 15] + [15] * 13 + [3])
+    np.testing.assert_array_equal(live_slots((0,), 3), [0, 0, 0, 1])
+    every = live_slots(range(4), 4)
+    np.testing.assert_array_equal(every, [0, 1, 2, 3, 4])
+    assert every.dtype == np.int32
+
+
+@pytest.mark.parametrize("slots", _LISTS, ids=_LIST_IDS)
+def test_write_kv_rows_with_a_list_touches_the_listed_slots_only(slots):
+    """The listed slots' one position each — positions in all three
+    windows of 300, window edges among them — and every other byte of
+    the buffer as it was: an unlisted slot's window holds NaN where its
+    row would land, and keeps it."""
+    rng = np.random.default_rng(21)
+    shape = (16, 2, 300, 8)
+    positions = [0, 127, 128, 255, 256, 299, 5, 200, 130, 64, 257, 1, 290,
+                 129, 126, 298]
+    cache = rng.normal(size=shape).astype(np.float32)
+    for i, at in enumerate(positions):
+        if i not in slots:
+            cache[i, :, at] = np.nan
+    rows = rng.normal(size=(16, 2, 1, 8)).astype(np.float32)
+    got = jax.jit(write_kv_rows)(cache, rows,
+                                 jnp.asarray(positions, jnp.int32),
+                                 live=jnp.asarray(live_slots(slots, 16)))
+    want = cache.copy()
+    for i in slots:
+        want[i, :, positions[i]] = rows[i, :, 0]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("slots", _LISTS, ids=_LIST_IDS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_kv_attend_with_a_list_is_the_einsums_on_the_listed_slots(
+        hd, dtype, slots):
+    """Both block shapes and both row types: a listed slot's output is
+    the einsums' over its own live rows, in the first block, the second
+    and the ragged third of 300; an unlisted slot's keys and values
+    (NaN here) are never read and its output is zeros."""
+    fmt, layer, q = _attend_case(hd, dtype, 2, None, 300, batch=16, seed=3)
+    listed = np.zeros(16, bool)
+    listed[slots] = True
+    layer = {key: jnp.where(listed[:, None, None, None], buf, jnp.nan)
+             for key, buf in layer.items()}
+    pos = jnp.asarray([0, 130, 255, 299, 17, 128, 127, 256, 64, 200, 1, 290,
+                       129, 126, 298, 131], jnp.int32)
+    got = fmt.attend(q, layer, pos, live=jnp.asarray(live_slots(slots, 16)))
+    assert got.dtype == q.dtype and got.shape == q.shape
+    got = np.asarray(got, np.float32)
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(got[listed], _oracle(q, layer, pos)[listed],
+                               rtol=tol, atol=tol)
+    np.testing.assert_array_equal(got[~listed], 0.0)
+
+
+@pytest.mark.parametrize("slots", [[0, 2], [1], [0, 1, 2]],
+                         ids=["ends", "middle", "all"])
+def test_kv_attend_with_a_list_over_blocks_of_heads_and_positions(
+        monkeypatch, request, slots):
+    """Two blocks of heads and four of positions a slot: the look-ahead
+    of a dead position block names the next block of heads, then the
+    next *listed* slot's first block, and a step past the list the last
+    live step's blocks; the results are the einsums'."""
+    monkeypatch.setattr(kv_cache, "_BLOCK_BYTES", 64 * 128 * 4)
+    attend_blocks.cache_clear()         # sizes are reckoned once a shape
+    request.addfinalizer(attend_blocks.cache_clear)
+    assert attend_blocks(2, 64, 420, 4) == (1, 128)
+    fmt, layer, q = _attend_case(64, jnp.float32, 2, None, 420, batch=3)
+    pos = jnp.asarray([60, 200, 419], jnp.int32)
+    # a fresh trace: the block size is read when the kernel is built
+    got = np.asarray(kv_cache.kv_attend.__wrapped__(
+        q, layer["k"][None], layer["v"][None], pos, jnp.zeros(1, jnp.int32),
+        jnp.asarray(live_slots(slots, 3))))
+    want = _oracle(q, layer, pos)
+    listed = np.zeros(3, bool)
+    listed[slots] = True
+    np.testing.assert_allclose(got[listed], want[listed], rtol=2e-6,
+                               atol=2e-6)
+    np.testing.assert_array_equal(got[~listed], 0.0)
+
+
+@pytest.mark.parametrize("call", ["write", "attend-64", "attend-128"])
+def test_a_list_of_every_slot_is_the_call_without_one(call):
+    """``n = width``: the list is the identity and both kernels give the
+    call without a list, bit for bit."""
+    rng = np.random.default_rng(23)
+    every = jnp.asarray(live_slots(range(16), 16))
+    pos = jnp.asarray(rng.integers(0, 300, 16), jnp.int32)
+    if call == "write":
+        cache = rng.normal(size=(16, 2, 300, 8)).astype(np.float32)
+        rows = rng.normal(size=(16, 2, 1, 8)).astype(np.float32)
+        got, want = (write_kv_rows(cache, rows, pos, live=live)
+                     for live in (every, None))
+    else:
+        fmt, layer, q = _attend_case(int(call[7:]), jnp.float32, 2, None,
+                                     300, batch=16)
+        got, want = (fmt.attend(q, layer, pos, live=live)
+                     for live in (every, None))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("fmt", [
+    KVCacheFormat(KV, HD, L, jnp.float32, quantized=True),
+    KVCacheFormat(2, 128, 32, jnp.bfloat16, query_group=8),
+], ids=["int8", "joined"])
+def test_a_format_that_cannot_walk_a_list_raises(fmt):
+    """Neither the einsums of int8 rows nor ``kv_attend_joined`` take a
+    list: such a format says so, as ``write_slots`` does for what it
+    cannot write."""
+    layer = fmt.zeros(3, 1)
+    layer = {key: bufs[0] for key, bufs in layer.items()}
+    q = jnp.zeros((3, fmt.kv_heads * fmt.query_group * fmt.head_dim),
+                  fmt.dtype)
+    pos, live = jnp.zeros(3, jnp.int32), jnp.asarray(live_slots([1], 3))
+    with pytest.raises(NotImplementedError, match="list of live"):
+        fmt.attend(q, layer, pos, live=live)
+    with pytest.raises(NotImplementedError, match="unquantized"):
+        fmt.write_slots(layer, fmt.rows(q[:, :fmt.kv_heads * fmt.head_dim],
+                                        q[:, :fmt.kv_heads * fmt.head_dim]),
+                        pos, live)
+    assert np.isfinite(np.asarray(fmt.attend(q, layer, pos),
+                                  np.float32)).all()
+
+
 def test_reparent_moves_one_groups_rows_in_every_layer():
     fmt, rng = _fmt(quantized=True, groups=2), np.random.default_rng(10)
     state = {key: tuple(_random_layer(fmt, 4, rng)[key] for _ in range(2))
@@ -417,8 +552,9 @@ class PositionsLeading(KVCacheFormat):
         return self._out(super().write_position(self._in(layer), rows, pos,
                                                 group))
 
-    def write_slots(self, layer, rows, pos):
-        return self._out(super().write_slots(self._in(layer), rows, pos))
+    def write_slots(self, layer, rows, pos, live=None):
+        return self._out(super().write_slots(self._in(layer), rows, pos,
+                                             live))
 
     def write_prefix(self, layer, k, v, group):
         return self._out(super().write_prefix(self._in(layer), k, v, group))
@@ -430,8 +566,8 @@ class PositionsLeading(KVCacheFormat):
         return each(self._out,
                     super().reparent(each(self._in, state), group, parents))
 
-    def attend(self, q, layer, pos, group=None):
-        return super().attend(q, self._in(layer), pos, group)
+    def attend(self, q, layer, pos, group=None, live=None):
+        return super().attend(q, self._in(layer), pos, group, live)
 
 
 @pytest.fixture(scope="module")

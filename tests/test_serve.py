@@ -584,13 +584,13 @@ def test_engine_writes_and_reads_the_last_position(gpt_setup):
     _, want, oracle_caches = _oracle_run(g, params, tokens, eng.max_len)
     step = eng._step_fn(False)
     caches = eng._caches
-    _, from_host, _, seeds, temps = eng._blank_rows()
+    _, from_host, _, seeds, temps, live = eng._blank_rows([0, 1])
     for pos, tok in enumerate(tokens):
         # slot 1 rides along at another position, with another token
         ids, caches = step(eng.params, caches, eng._prev_ids,
                            np.asarray([tok, 5], np.int32), from_host,
                            np.asarray([pos, last - pos], np.int32),
-                           seeds, temps)
+                           seeds, temps, live)
         assert int(ids[0]) == want[pos], pos
     for side in ("k", "v"):
         got = np.asarray(caches[side][0])[0]
@@ -675,6 +675,75 @@ def test_engine_launched_ahead_answers_equal_the_request_run_alone(
     assert steps == eng.steps and ahead == steps - 1
     assert tokens == 3 * 4 and phases == [steps] * len(_STEP_PHASES)
     assert eng._flight is None
+
+
+@pytest.mark.parametrize("joined,long", [
+    (1, [0]), (16, [2, 7, 15]), (16, list(range(16)))],
+    ids=["1-of-16", "3-of-16", "16-of-16"])
+def test_engine_answers_at_any_number_of_live_slots_equal_the_request_alone(
+        gpt_setup, joined, long):
+    """The step's cache kernels visit the live slots only: with one slot
+    of 16 live, with three apart (13 neighbours finished and idle, their
+    rows stale) and with all 16, every request — greedy and sampled —
+    answers bit for bit as it does alone, and the engine did launch a
+    step for exactly the slots in question."""
+    g, params = gpt_setup
+    rng = np.random.default_rng(53)
+    prompts = _prompts(joined, rng)
+
+    def make_reqs():
+        return [DecodeRequest(prompt=p, request_id=i, seed=11 + i,
+                              max_new_tokens=7 if i in long else 2,
+                              temperature=0.7 * (i % 2))
+                for i, p in enumerate(prompts)]
+
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=16, top_k=5)
+    solo = {req.request_id: eng.run_all([req])[req.request_id]
+            for req in make_reqs()}
+    visited = []
+    launch = eng._launch
+
+    def watched(rows):
+        visited.append([i for i, _ in rows])
+        return launch(rows)
+
+    eng._launch = watched
+    batched = eng.run_all(make_reqs())
+    assert list(range(joined)) in visited and long in visited
+    for rid, ids in solo.items():
+        np.testing.assert_array_equal(batched[rid], ids)
+
+
+def test_engine_counts_the_live_slots_it_launches(gpt_setup):
+    """``serve.decode.rows.launched`` rises by ``len(rows)`` a launch —
+    the slots the step's cache kernels visit — and the list the step is
+    handed names those slots, padded by the last, with their count."""
+    g, params = gpt_setup
+    eng = ContinuousBatchEngine(g, params, num_stages=2, width=4)
+    reqs = [DecodeRequest(prompt=np.arange(2 + i), max_new_tokens=n,
+                          request_id=i) for i, n in enumerate((2, 5, 3))]
+    for req in reqs:
+        assert eng.join(req)
+    rows = REGISTRY.counter("serve.decode.rows.launched")
+    steps = REGISTRY.histogram("serve.decode.step_s")
+    rows0, steps0 = rows.value, steps.count
+    lists = []
+    step_fn = eng._step_fn
+
+    def watched(sample):
+        def call(*args):
+            lists.append(np.asarray(args[-1]).tolist())
+            return step_fn(sample)(*args)
+        return call
+
+    eng._step_fn = watched
+    while eng.active():
+        eng.step()
+    # 2, 5 and 3 steps: [0 1 2] [0 1 2] [1 2] [1] [1]
+    assert lists == [[0, 1, 2, 2, 3], [0, 1, 2, 2, 3], [1, 2, 2, 2, 2],
+                     [1, 1, 1, 1, 1], [1, 1, 1, 1, 1]]
+    assert rows.value - rows0 == 2 + 5 + 3
+    assert steps.count - steps0 == 5
 
 
 def test_engine_launches_step_n_plus_1_before_it_reads_step_n(
@@ -768,7 +837,9 @@ def test_engine_cancel_and_rejoin_while_a_step_is_in_flight(
     still unread.  The stale token is dropped at delivery (the flight
     holds the slot object: the index is the newcomer's by then), the
     cancelled request hears ``None`` once, the newcomer's answer equals
-    its solo run and the bystander's is undisturbed."""
+    its solo run and the bystander's is undisturbed — the last tenant's
+    rows, which no step has touched since it left the list of live
+    slots, are each rewritten before the newcomer reads them."""
     g, params = gpt_setup
     rng = np.random.default_rng(41)
     p_gone, p_stay, p_new = (rng.integers(0, 97, (n,)).astype(np.int32)
