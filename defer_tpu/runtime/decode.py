@@ -78,6 +78,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.ir import LayerGraph
@@ -114,6 +115,51 @@ def _leaf_layout(tree) -> dict:
     return {jax.tree_util.keystr(path): (np.shape(leaf), np.dtype(
         leaf.dtype if hasattr(leaf, "dtype") else np.asarray(leaf).dtype))
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def off_default_layout(leaf: jax.Array) -> bool:
+    """Whether a placed array lies otherwise than its device lays out a
+    shard of its shape and type when nobody says how (a backend that
+    reports no layouts has only that one)."""
+    held = leaf.format.layout
+    if held is None:
+        return False
+    dev = next(iter(leaf.devices()))
+    default = Layout.from_pjrt_layout(dev.client.get_default_layout(
+        leaf.dtype, leaf.sharding.shard_shape(leaf.shape), dev))
+    return held != default
+
+
+def _as_is(x):
+    return x
+
+
+def relaid(leaf: jax.Array, want: Format) -> jax.Array:
+    """``leaf`` (given up) laid out anew on its devices as ``want`` says.
+
+    What ``jax.device_put(leaf, want, donate=True)`` does, but for the
+    persistent compilation cache: a program read back from it hands out
+    its results tagged with the device's *default* layout whatever it
+    was compiled to produce (jax 0.9.0 on the v5e and on the CPU: the
+    buffer lies as asked, ``.format`` says otherwise, and every program
+    compiled for that array afterwards is compiled for a layout it does
+    not have).  So this one program is compiled in every process
+    (0.1-0.6 s a shape on the chip) and never written; programs that
+    only *take* arrays in a named layout come back from the cache whole.
+    """
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", math.inf)
+    try:
+        out = jax.jit(_as_is, out_shardings=want, donate_argnums=0)(leaf)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+    if out.format.layout.major_to_minor != want.layout.major_to_minor:
+        raise RuntimeError(
+            f"a leaf {out.shape} asked for as {want.layout} came back as "
+            f"{out.format.layout}: the program that lays it out anew must "
+            "not come from the persistent compilation cache")
+    return out
 
 
 class PipelinedDecoder:
@@ -212,9 +258,18 @@ class PipelinedDecoder:
             sum(math.prod(sh) + 4 * math.prod(sh[-1:]) for sh in shapes)
             if self.weight_quant
             else self._wdt.itemsize * sum(map(math.prod, shapes)))
+        # the arrays (one a leaf, stage-sharded) that the device would
+        # have laid out otherwise than row-major, and their bytes over
+        # the stages: what every dispatch converted while they lay in
+        # the device's default
+        moved = [a for a in jax.tree.leaves(self._w)
+                 if off_default_layout(a)]
+        REGISTRY.gauge("decode.weights.relaid_leaves").set(len(moved))
+        REGISTRY.gauge("decode.weights.relaid_bytes").set(
+            sum(a.nbytes for a in moved))
         #: shard_map spec for the weight argument
         self._wspec_tree = jax.tree.map(
-            lambda a: P(STAGE_AXIS, *(None,) * (a.ndim - 1)), self._w)
+            lambda f: f.sharding.spec, self.weight_formats())
 
         #: the first local layer's (every layer's, where they are alike)
         self.state_format = self.state_formats[0]
@@ -355,7 +410,14 @@ class PipelinedDecoder:
         the types, before the cast to the compute type, of what was
         deployed — the compiled programs take that and nothing else.
         One leaf at a time goes host -> device, so the host never holds
-        a second copy of all of them."""
+        a second copy of all of them, and lies there row-major
+        (:meth:`_leaf_format`): the compiled loops read a matrix with
+        its last dimension on the lanes, and a device whose default for
+        a shape is another order (the v5e's for ``[1, 6400, 1600]`` puts
+        the 6400 there, 1600 being no multiple of 128) would have every
+        dispatch convert the leaf on its way in.  Such a leaf is re-laid
+        on the device, once, here; a program takes the layout its
+        committed arguments have."""
         n, wdt = self.num_stages, self._wdt
         layout = {nm: _leaf_layout(params[nm])
                   for nm in (*self.block_names, *self._ends)}
@@ -368,8 +430,13 @@ class PipelinedDecoder:
 
         def stacked(rows):
             rows = rows[0][None] if n == 1 else np.stack(rows)
-            return jax.device_put(rows, NamedSharding(
-                self.mesh, P(STAGE_AXIS, *(None,) * (rows.ndim - 1))))
+            want = self._leaf_format(rows.ndim)
+            leaf = jax.device_put(rows, want.sharding)
+            held = leaf.format.layout
+            if held is not None \
+                    and held.major_to_minor != want.layout.major_to_minor:
+                leaf = relaid(leaf, want)
+            return leaf
 
         def placed(trees, real):
             """``trees``, one a stage, stacked leaf by leaf; a stage
@@ -412,6 +479,20 @@ class PipelinedDecoder:
         ends = {nm: placed([params[nm]] * n, [s == at for s in range(n)])
                 for nm, at in self._ends.items()}
         return {"blocks": tuple(blocks), "ends": ends}
+
+    def _leaf_format(self, ndim: int) -> Format:
+        """Where a weight leaf of ``ndim`` dimensions lies: sharded over
+        the stages by its first, and row-major — the tiling the
+        device's own for the type."""
+        return Format(Layout(tuple(range(ndim))), NamedSharding(
+            self.mesh, P(STAGE_AXIS, *(None,) * (ndim - 1))))
+
+    def weight_formats(self):
+        """The ``Format`` of every leaf of the weights, in their tree: how
+        a script that lowers a program from shapes
+        (``jax.ShapeDtypeStruct(..., sharding=format)``) declares them
+        as the decoder holds them."""
+        return jax.tree.map(lambda a: self._leaf_format(a.ndim), self._w)
 
     def reweight(self, params) -> None:
         """Install fresh weights — no recompile, caches untouched.

@@ -16,8 +16,10 @@ What it answers before any chip time is spent:
 
     env JAX_PLATFORMS=cpu python scripts/brumby_tpu_compile_check.py
 
-A few minutes and ~10 GB of host memory (the weights are zeros); one
-JSON line; exit 0 when the decode program holds no state-sized copy.
+A few minutes and ~20 GB of host memory (the weights are zeros, placed
+on the host's CPU device); one JSON line; exit 0 when the decode program
+holds no state-sized copy and produces no weight matrix inside its loop
+(``weight_copies_in_loop``; ``weight_copies_per_dispatch`` is reported).
 A process of its own, like the other compile checks: the TPU's library
 is locked machine-wide while it runs.
 """
@@ -42,7 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from defer_tpu.models import brumby
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
-from hlo_cache_ops import computations, count_cache_ops
+from hlo_cache_ops import computations, count_cache_ops, weight_copies
 
 ARGS = dict(num_layers=8, hidden=5120, heads=40, kv_heads=8,
             mlp_hidden=17408, seq_len=32768, vocab=151936)
@@ -56,10 +58,10 @@ def main() -> int:
     graph = brumby(**ARGS)
     params = jax.tree.map(lambda s: np.zeros(s.shape, jnp.bfloat16),
                           jax.eval_shape(graph.init, jax.random.key(0)))
-    # nothing is placed: the described devices hold no arrays
-    with mock.patch.object(jax, "device_put", lambda a, _sharding: a):
-        dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=MB,
-                               max_len=MAX_LEN, compute_dtype=jnp.bfloat16)
+    # placed on this host's CPU device (the described devices hold no
+    # arrays): the decoder reads back how each leaf lies
+    dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=MB,
+                           max_len=MAX_LEN, compute_dtype=jnp.bfloat16)
     dec.mesh = Mesh(np.array(topo.devices[:1]).reshape(dec.mesh.devices.shape),
                     dec.mesh.axis_names)
 
@@ -67,10 +69,10 @@ def main() -> int:
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    def staged(a):
-        return arg(a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1)))
-
-    w = jax.tree.map(staged, dec._w)
+    # the weights as the decoder holds them: every leaf stage-sharded
+    # and row-major (``PipelinedDecoder.weight_formats``)
+    w = jax.tree.map(lambda a, f: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=f), dec._w, dec.weight_formats())
     buffers = dec.state_format.buffers(MB)
     # the format's buffers behind the ring's own stage axis
     state = {key: (arg((1,) + buf.shape, buf.dtype,
@@ -122,6 +124,14 @@ def main() -> int:
             row[f"decode_state_ops_{tag}"] = ops
             ok = ok and not (ops["item_copies"] or ops["buffer_copies"])
         ok = ok and row["decode"]["retention_step_calls"] == dec.l_max
+        # a weight matrix produced inside the loop (every step) or in
+        # front of it (every dispatch: a layout the loop reads otherwise
+        # than the caller holds the leaf)
+        copies = weight_copies(comps, [leaf.shape for leaf in
+                                       jax.tree.leaves(params)
+                                       if leaf.ndim > 1])
+        row["decode"].update(copies)
+        ok = ok and not copies["weight_copies_in_loop"]
     print(json.dumps(row))
     return 0 if ok else 1
 

@@ -79,10 +79,11 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    # the weights as the ring placed them: every leaf an argument of
-    # its own behind the stage axis
-    w = jax.tree.map(lambda a: arg(
-        a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1))), dec._w)
+    # the weights as the decoder holds them: every leaf an argument of
+    # its own behind the stage axis, row-major
+    # (``PipelinedDecoder.weight_formats``)
+    w = jax.tree.map(lambda a, f: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=f), dec._w, dec.weight_formats())
     # the format's buffers behind the ring's own stage axis
     buffers = dec.state_format.buffers(mb)
     caches = {key: (arg((stages,) + buf.shape, buf.dtype,
@@ -127,7 +128,8 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
     print(json.dumps(row))
     return 0 if row["row_writes"] and not (
         row["whole_cache_copies"] or row["item_copies"]
-        or row["buffer_copies"] or row["weight_copies_in_loop"]) else 1
+        or row["buffer_copies"] or row["weight_copies_in_loop"]
+        or row["weight_copies_per_dispatch"]) else 1
 
 
 if __name__ == "__main__":

@@ -86,10 +86,10 @@ def main() -> int:
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    def staged(a):
-        return arg(a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1)))
-
-    w = jax.tree.map(staged, dec._w)
+    # the weights as the decoder holds them: every leaf stage-sharded
+    # and row-major (``PipelinedDecoder.weight_formats``)
+    w = jax.tree.map(lambda a, f: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=f), dec._w, dec.weight_formats())
     # each layer's own buffers behind the ring's stage axis
     shapes = shapes_by_layer(dec.state_formats, mb)
     caches = jax.tree.map(

@@ -88,10 +88,10 @@ def main() -> int:
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    def staged(a):
-        return arg(a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1)))
-
-    w = jax.tree.map(staged, dec._w)
+    # the weights as the decoder holds them: every leaf stage-sharded
+    # and row-major (``PipelinedDecoder.weight_formats``)
+    w = jax.tree.map(lambda a, f: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=f), dec._w, dec.weight_formats())
     shapes = shapes_by_layer(dec.state_formats, mb)
     caches = jax.tree.map(
         lambda s: arg((1,) + s.shape, s.dtype,

@@ -76,10 +76,10 @@ def main() -> int:
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(dec.mesh, spec))
 
-    def staged(a):
-        return arg(a.shape, a.dtype, P(STAGE_AXIS, *(None,) * (a.ndim - 1)))
-
-    w = jax.tree.map(staged, dec._w)
+    # the weights as the decoder holds them: every leaf stage-sharded
+    # and row-major (``PipelinedDecoder.weight_formats``)
+    w = jax.tree.map(lambda a, f: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=f), dec._w, dec.weight_formats())
     # the format's buffers behind the ring's own stage axis
     caches = {key: (arg((1,) + buf.shape, buf.dtype,
                         P(STAGE_AXIS, *(None,) * len(buf.shape))),)

@@ -15,6 +15,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from defer_tpu.graph.ir import GraphBuilder
 from defer_tpu.graph.ops import Dense, LayerNorm
@@ -22,7 +24,8 @@ from defer_tpu.models import gpt_stage_cuts, gpt_tiny
 from defer_tpu.models.decoder import decoder_parts, split_blocks
 from defer_tpu.models.gpt import CausalTransformerBlock, GptEmbedding
 from defer_tpu.ops.kv_cache import KVCacheFormat
-from defer_tpu.runtime.decode import PipelinedDecoder
+from defer_tpu.runtime.decode import (PipelinedDecoder, off_default_layout,
+                                      relaid)
 
 VOCAB = 97
 MAX_LEN = 24
@@ -466,6 +469,150 @@ def test_gpt_weights_are_arguments_of_their_own(model, num_stages):
                 assert not w.any()
     assert _weight_gauges() == (0, sum(
         leaf.nbytes for leaf in jax.tree.leaves(params)))
+
+
+def _relaid_gauges():
+    from defer_tpu.obs import REGISTRY
+    return (REGISTRY.gauge("decode.weights.relaid_leaves").value,
+            REGISTRY.gauge("decode.weights.relaid_bytes").value)
+
+
+@pytest.mark.parametrize("weight_dtype", [None, "int8"])
+@pytest.mark.parametrize("num_stages", [1, 3])
+def test_every_weight_leaf_lies_row_major_behind_the_stage_axis(
+        model, num_stages, weight_dtype):
+    """Every leaf the decoder holds — a stage with fewer blocks' zeros,
+    an ``Int8Weight``'s values and scales alike — reports the row-major
+    layout and the stage sharding, which is what ``weight_formats``
+    declares to a script that lowers from shapes."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=2, max_len=MAX_LEN,
+                           weight_dtype=weight_dtype)
+    leaves = jax.tree.leaves(dec._w)
+    formats = jax.tree.leaves(dec.weight_formats())
+    assert len(leaves) == len(formats) > 0
+    for leaf, fmt in zip(leaves, formats):
+        assert leaf.format.layout.major_to_minor == tuple(range(leaf.ndim))
+        assert fmt.layout.major_to_minor == tuple(range(leaf.ndim))
+        assert leaf.sharding == fmt.sharding == NamedSharding(
+            dec.mesh, P("stage", *(None,) * (leaf.ndim - 1)))
+        # on the CPU the device's own order is that one: nothing re-laid
+        assert not off_default_layout(leaf)
+    assert _relaid_gauges() == (0, 0)
+
+
+@pytest.mark.parametrize("num_stages", [1, 3])
+def test_reweight_places_leaves_as_deployed_and_compiles_nothing(
+        model, prompt, num_stages):
+    """``reweight`` puts the new leaves where and how the deployed ones
+    lay, so the decode and prefill programs — compiled for the layouts
+    their arguments had — are the same objects and take them without a
+    second compilation."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=4, max_len=MAX_LEN)
+    dec.generate(prompt, 6, prefill=True)
+
+    def programs():
+        return {(kind, key): fn for kind, held in (
+            ("decode", dec._decode_fns), ("prefill", dec._prefill_fns))
+            for key, fn in held.items()}
+
+    fns = programs()
+    assert {kind for kind, _ in fns} == {"decode", "prefill"}
+    misses = {key: fn._cache_size() for key, fn in fns.items()}
+    before = [leaf.format for leaf in jax.tree.leaves(dec._w)]
+
+    params2 = jax.tree.map(lambda x: x * 1.1, params)
+    dec.reweight(params2)
+    assert [leaf.format for leaf in jax.tree.leaves(dec._w)] == before
+    got = dec.generate(prompt, 6, prefill=True)
+    np.testing.assert_array_equal(
+        got, incremental_greedy(graph, params2, prompt, 5 + 6, MAX_LEN))
+    after = programs()
+    assert after.keys() == fns.keys()
+    for key, fn in after.items():
+        assert fn is fns[key]
+        assert fn._cache_size() == misses[key], key
+
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_widths_that_are_no_multiple_of_128_decode_as_the_reference(
+        num_stages):
+    """GPT-2 XL's kind of width (``d_model`` 192, ``mlp`` 768: neither
+    matrix has a whole number of lane tiles both ways, which is where a
+    device's default order leaves row-major) generates the incremental
+    reference's tokens, prompt prefilled and not."""
+    from defer_tpu.models import gpt
+    graph = gpt(2, 192, 3, MAX_LEN, vocab=VOCAB)
+    params = graph.init(jax.random.key(11))
+    assert params["block_0"]["fc2"]["w"].shape == (768, 192)
+    prompt = np.random.default_rng(5).integers(
+        0, VOCAB, size=(4, 5)).astype(np.int32)
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=4 // num_stages, max_len=MAX_LEN)
+    want = incremental_greedy(graph, params, prompt, 5 + 7, MAX_LEN)
+    np.testing.assert_array_equal(dec.generate(prompt, 7), want)
+    np.testing.assert_array_equal(
+        dec.generate(prompt, 7, prefill=True), want)
+
+
+def test_off_default_layout_counts_a_leaf_put_in_another_order(model):
+    """The function behind ``decode.weights.relaid_leaves`` holds a
+    placed array's layout against its device's default for the shape: an
+    array put by hand with its last two dimensions exchanged counts, one
+    the decoder placed does not (the CPU's default is row-major)."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=MAX_LEN)
+    placed = dec._w["blocks"][0]["fc2"]["w"]
+    assert not off_default_layout(placed)
+    by_hand = jax.device_put(np.asarray(placed), Format(
+        Layout(major_to_minor=(0, 2, 1)), placed.sharding))
+    assert by_hand.format.layout.major_to_minor == (0, 2, 1)
+    np.testing.assert_array_equal(np.asarray(by_hand), np.asarray(placed))
+    assert off_default_layout(by_hand)
+    assert [off_default_layout(a) for a in jax.tree.leaves(dec._w)] \
+        == [False] * len(jax.tree.leaves(dec._w))
+    assert _relaid_gauges() == (0, 0)
+
+
+def test_relaid_lays_a_leaf_out_anew_and_writes_no_cache_entry(model):
+    """The one program that *produces* an array in a named layout (on
+    the chip: a leaf whose default is not row-major; here a leaf asked
+    for with its last two dimensions exchanged) gives the leaf's values
+    in that layout, gives the old array up, and is never written to the
+    persistent compilation cache, whatever the floor for writing is: a
+    program read back from there tags its results with the default
+    layout."""
+    import glob
+    import os
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                           max_len=MAX_LEN)
+    placed = dec._w["blocks"][0]["fc1"]["w"]
+    values = np.asarray(placed)
+    cache_dir = jax.config.jax_compilation_cache_dir
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        out = relaid(placed, Format(Layout((0, 2, 1)), placed.sharding))
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+    assert out.format.layout.major_to_minor == (0, 2, 1)
+    assert out.sharding == placed.sharding and off_default_layout(out)
+    np.testing.assert_array_equal(np.asarray(out), values)
+    assert placed.is_deleted()
+    if cache_dir:
+        assert not glob.glob(os.path.join(cache_dir, "jit__as_is*"))
+    # a program compiled for the leaf takes it as it lies
+    twice = jax.jit(lambda w: w * 2)
+    assert twice.lower(out).compile().input_formats[0][0].layout \
+        .major_to_minor == (0, 2, 1)
+    np.testing.assert_array_equal(np.asarray(twice(out)), values * 2)
 
 
 def test_defer_score_bucketed_short_sequence(model):
