@@ -23,18 +23,33 @@ Grouped queries and a window (``flash_attention(..., window=W)`` or
 ``k`` / ``v`` with fewer heads than ``q``) take a second kernel,
 :func:`_band_kernel`: query head ``j`` reads KV head ``j // (H / Hkv)``
 *by index* (repeating 8 heads to 128 would be 16x the prompt's keys),
-row ``t`` attends keys ``s`` with ``0 <= t - s < W``, and a key block
-wholly outside a query block's band — behind the window or ahead of the
-diagonal — is neither fetched nor computed: the grid's key axis runs
-over the band's blocks alone and its index map starts at the band's
-first.  Its products take the operands in their own type (bf16 on the
-matrix unit at full rate) and accumulate in f32; only a band's edge
-blocks pay for masks.
+row ``t`` attends keys ``s`` with ``0 <= t - s < W``.  Its products take
+the operands in their own type (bf16 on the matrix unit at full rate)
+and accumulate in f32; only a band's edge blocks pay for masks.
 
 A latent-attention layer's prompt takes a third kernel,
 :func:`flash_latent`: a score is the sum of a head's own product and
 one over a key every head shares, and the value is narrower than the
 key.
+
+**How a grid step of these two finds its pair.**  Their grid is
+``(batch, heads, pairs)``: the last axis walks the (query block, key
+block) pairs that hold an allowed (query, key) — the causal triangle,
+cut by the window where there is one — and no others, so a call's
+steps are its live blocks (136 a head at 8192 / 512, where the
+rectangle over them has 256; 108 under a window of 4096).  The sizes,
+the blocks and the window are static, so :func:`live_pairs` lists the
+pairs on the host, query-block-major, with three bits a pair (the
+query block's first, its last, an edge that needs the mask); the table
+goes in as three scalar-prefetched int32 rows, the index maps read
+``qi[step]`` / ``kb[step]`` to name the blocks to fetch, and the kernel
+reads the same column: it starts the statistics on a first pair and
+writes the output on a last.  Both kernels form and mask their own
+scores and hand them to one :func:`_block_update`, whose statistics
+stay a whole lane tile wide (a row's max on every lane, the sum a lane:
+one cross-lane reduction a block, none of a single lane's broadcasts).
+Two gauges, ``prefill.flash.grid_steps`` and ``.live_steps``, hold the
+newest traced call's steps and those of them that work.
 
 On non-TPU backends (CPU tests) the same kernel runs in interpreter mode, so
 there is exactly one implementation of the math.  On a TPU backend it is
@@ -42,8 +57,10 @@ compiled by Mosaic and a compile error raises — there is no XLA-attention
 or interpret-mode fallback.  Established on the v5e (libtpu 0.0.34,
 ``chip_smoke.py`` and its bring-up probe): Mosaic accepts the 8-row clamp
 for bf16 blocks (below the (16, 128) bf16 tile), the lane-1 ``[:, :1]``
-reads of the (block_q, 128) m/l scratch, f32 and bf16 operands, ragged
-Tq/Tk, and the kernel inside ``lax.switch`` / ``shard_map`` /
+reads of the (block_q, 128) m/l scratch (:func:`_attn_kernel`'s; on
+``[512, 512]`` blocks they and the two reductions behind them were
+half and more of the causal kernels' time, PERF.md PR 50), f32 and bf16 operands,
+ragged Tq/Tk, and the kernel inside ``lax.switch`` / ``shard_map`` /
 ``jax.export``.
 """
 
@@ -54,8 +71,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..obs.registry import REGISTRY
 
 _NEG_INF = float("-inf")
 #: lane width of the m/l scratch rows (per-row scalars broadcast across it)
@@ -131,99 +151,183 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, pad)
 
 
-#: query and key rows of one block of :func:`_band_kernel`: at 128 a
-#: prompt of 8192 is 4096 grid steps a head, and their fixed cost is
-#: the kernel's time
+#: query and key rows of one block of the causal kernels
+#: (:func:`_band_kernel`, :func:`_latent_kernel`): at 128 a prompt of
+#: 8192 is 2080 grid steps a head, and their fixed cost is the kernel's
+#: time
 _BAND_BLOCK = 512
 
-
-def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 scale, block_q, block_k, num_kb, t_q, t_k, window):
-    """One key block of one query block's band: causal, bottom-right
-    aligned, reaching back ``window`` keys (the query's own counted)
-    where there is one.  q_ref / o_ref ``[1, 1, block_q, d]``, k_ref /
-    v_ref ``[1, 1, block_k, d]``: block ``first + kb`` of the keys,
-    ``first`` the band's first block (``_band_first``: the index map
-    used the same)."""
-    qi, kb = pl.program_id(2), pl.program_id(3)
-    off = t_k - t_q
-    first = _band_first(qi, block_q, block_k, off, window)
-    k0 = (first + kb) * block_k                # this block's first key
-    r0 = qi * block_q + off                    # first query, as a key
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def accumulate(edge):
-        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        if edge:
-            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = jnp.logical_and(k_pos < t_k, q_pos >= k_pos)
-            if window is not None:
-                mask = jnp.logical_and(mask, q_pos - k_pos < window)
-            s = jnp.where(mask, s, _NEG_INF)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # rows with no key yet carry m = -inf; keep them inert
-            safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-            alpha = jnp.where(m_prev == _NEG_INF, 0.0,
-                              jnp.exp(m_prev - safe_m))
-            p = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
-        else:
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    # a block is in the band when some (query, key) pair of it is
-    # allowed, and inside it when every pair is
-    r1, k1 = r0 + block_q - 1, k0 + block_k - 1
-    live = jnp.logical_and(k0 <= r1, k0 < t_k)
-    inside = jnp.logical_and(k1 <= r0, k1 < t_k)
-    if window is not None:
-        live = jnp.logical_and(live, r0 - k1 < window)
-        inside = jnp.logical_and(inside, r1 - k0 < window)
-    pl.when(inside)(lambda: accumulate(False))
-    pl.when(jnp.logical_and(live, jnp.logical_not(inside)))(
-        lambda: accumulate(True))
-
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+#: what :func:`live_pairs` says of a pair, as bits: the query block's
+#: first pair, its last, and a pair that holds a forbidden (query, key)
+#: beside its allowed ones (the kernel masks those pairs alone)
+_FIRST, _LAST, _EDGE = 1, 2, 4
 
 
-def _band_first(qi, block_q: int, block_k: int, off: int, window):
-    """The first key block of query block ``qi``'s band."""
-    if window is None:
-        return 0 * qi
-    return jnp.maximum(qi * block_q + off - window + 1, 0) // block_k
-
-
-def band_key_steps(t_q: int, t_k: int, block_q: int, block_k: int,
-                   window: int | None) -> int:
-    """Key blocks the widest band of any query block spans: the length
-    of the band kernel's key axis (host integers, padded sizes)."""
-    off, last_kb = t_k - t_q, -(-t_k // block_k) - 1
-    steps = 1
+def live_pairs(t_q: int, t_k: int, block_q: int, block_k: int,
+               window: int | None) -> np.ndarray:
+    """The (query block, key block) pairs a causal call works on, in
+    the order its grid walks them: int32 ``[3, n]``, a column a pair —
+    the query block, the key block, the pair's bits (``_FIRST``,
+    ``_LAST``, ``_EDGE``).  A pair is listed when it holds an allowed
+    (query, key): the query at key position ``r = row + t_k - t_q``
+    (bottom-right aligned) sees the keys ``s <= r``, and with
+    ``window`` only those with ``r - s < window``.  Query-block-major,
+    a query block's key blocks ascending, so that the running
+    statistics live across one query block's pairs.  Host integers, of
+    the real (unpadded) sizes.  A query block ahead of every key
+    (``t_q > t_k``) is listed once all the same, as an edge, so that
+    its rows are written."""
+    off, cols = t_k - t_q, []
     for qi in range(-(-t_q // block_q)):
-        r0 = qi * block_q + off
-        first = 0 if window is None else max(r0 - window + 1, 0) // block_k
-        steps = max(steps,
-                    min((r0 + block_q - 1) // block_k, last_kb) - first + 1)
-    return steps
+        r0 = qi * block_q + off               # the first query, as a key
+        r1 = min(r0 + block_q, t_k) - 1       # the last real one
+        lo = 0 if window is None else max(r0 - window + 1, 0) // block_k
+        hi = max(r1 // block_k, lo)
+        for kb in range(lo, hi + 1):
+            k0, k1 = kb * block_k, kb * block_k + block_k - 1
+            inside = k1 <= r0 and (
+                window is None or r0 + block_q - 1 - k0 < window)
+            cols.append((qi, kb, _FIRST * (kb == lo) | _LAST * (kb == hi)
+                         | _EDGE * (not inside)))
+    return np.asarray(cols, np.int32).T
+
+
+def _paired_call(kernel, name, operands, *, sizes, in_specs, out_spec,
+                 out_shape, interpret):
+    """A causal kernel called over the live pairs of ``sizes`` =
+    ``(t_q, t_k, block_q, block_k, window)``: grid ``(batch, heads,
+    pairs)``, :func:`live_pairs`' table handed over as three
+    scalar-prefetched rows, which every index map (``(bi, hi, step, qi,
+    kb, bits)``) and the kernel read at column ``step``; sets the two
+    gauges of the newest traced call."""
+    t_q, t_k, block_q, block_k, _ = sizes
+    pairs = live_pairs(*sizes)
+    (b, h), n = out_shape.shape[:2], pairs.shape[1]
+    # :func:`_block_update`'s statistics: rows of a lane tile, or of a
+    # whole key block where that is narrower or no multiple of one
+    w = _LANES if block_k % _LANES == 0 else block_k
+    REGISTRY.gauge("prefill.flash.grid_steps").set(b * h * n)
+    # a query block ahead of every key is listed and holds nothing
+    REGISTRY.gauge("prefill.flash.live_steps").set(
+        b * h * (n - max(t_q - t_k, 0) // block_q))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, h, n), in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, w), jnp.float32),   # running max
+                pltpu.VMEM((block_q, w), jnp.float32),   # running sum a lane
+                pltpu.VMEM((block_q, out_shape.shape[-1]), jnp.float32)]),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
+        interpret=interpret, name=name,
+    )(*(jnp.asarray(row) for row in pairs), *operands)
+
+
+def _q_block(bi, hi, step, qi, kb, bits):
+    """Index map of a query-side operand and of the output."""
+    return (bi, hi, qi[step], 0)
+
+
+def _k_block(group: int):
+    """Index map of a key-side operand that ``group`` query heads
+    share a head of."""
+    return lambda bi, hi, step, qi, kb, bits: (bi, hi // group, kb[step], 0)
+
+
+def _block_update(s, v, m_ref, l_ref, acc_ref):
+    """One block of the online softmax, the causal kernels' one spelling
+    of it: ``s`` the block's f32 scores ``[block_q, block_k]`` (``-inf``
+    where a pair is forbidden), ``v`` its values ``[block_k, dv]``.
+    The scratch rows are ``w = l_ref.shape[1]`` lanes wide, ``block_k``
+    a multiple of it: ``m_ref [block_q, w]`` holds a row's running max
+    on every lane, ``l_ref [block_q, w]`` the running sum *a lane* —
+    lane ``j`` sums the keys ``j, w + j, ...`` of every block, rescaled
+    with the rest, and the lanes are added once, in :func:`_finish` —,
+    ``acc_ref [block_q, dv]`` the values' sum.  So a block pays one
+    cross-lane reduction (its max, after the column groups are folded
+    elementwise) and nothing is broadcast from a single lane."""
+    w = l_ref.shape[1]
+    cols = [s[:, j:j + w] for j in range(0, s.shape[1], w)]
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(
+        functools.reduce(jnp.maximum, cols), axis=-1, keepdims=True))
+    # a row that has seen no key yet carries -inf: keep it inert
+    safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+    alpha = jnp.exp(m_prev - safe)
+    ps = [jnp.exp(c - safe) for c in cols]
+    l_ref[...] = l_ref[...] * alpha + functools.reduce(jnp.add, ps)
+    p = ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=-1)
+    acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) \
+        + jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _lanes(x, n: int):
+    """``x`` ``[rows, w]``, a row's one value on every lane, as ``[rows,
+    n]``."""
+    w = x.shape[1]
+    if n % w == 0:
+        return x if n == w else jnp.concatenate([x] * (n // w), axis=-1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _start(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _finish(o_ref, l_ref, acc_ref):
+    l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+
+
+def _masked(s, qi, kb, t_q: int, t_k: int, window):
+    """An edge pair's scores ``s`` (query block ``qi``, key block
+    ``kb``) with ``-inf`` where :func:`live_pairs`' rule forbids the
+    (query, key) or the key is padding."""
+    k_pos = kb * s.shape[1] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    q_pos = qi * s.shape[0] + (t_k - t_q) + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 0)
+    mask = jnp.logical_and(k_pos < t_k, q_pos >= k_pos)
+    if window is not None:
+        mask = jnp.logical_and(mask, q_pos - k_pos < window)
+    return jnp.where(mask, s, _NEG_INF)
+
+
+def _pair_step(table, scores, v_ref, o_ref, scratch, *, t_q, t_k, window):
+    """One grid step of a causal kernel: the step's pair from the
+    ``table`` (the three prefetched rows), the statistics started on a
+    query block's first pair, the kernel's own ``scores()`` (f32,
+    ``[block_q, block_k]``), masked on an edge, through
+    :func:`_block_update`, the output written on the last pair."""
+    qi, kb, bits = (row[pl.program_id(2)] for row in table)
+    pl.when(bits & _FIRST != 0)(lambda: _start(*scratch))
+    pl.when(bits & _EDGE == 0)(
+        lambda: _block_update(scores(), v_ref[0, 0], *scratch))
+    pl.when(bits & _EDGE != 0)(
+        lambda: _block_update(_masked(scores(), qi, kb, t_q, t_k, window),
+                              v_ref[0, 0], *scratch))
+    pl.when(bits & _LAST != 0)(lambda: _finish(o_ref, *scratch[1:]))
+
+
+def _band_kernel(qi_ref, kb_ref, bits_ref, q_ref, k_ref, v_ref, o_ref,
+                 *scratch, scale, **band):
+    """One (query block, key block) pair of the band: causal,
+    bottom-right aligned, reaching back ``window`` keys (the query's own
+    counted) where there is one.  q_ref / o_ref ``[1, 1, block_q, d]``,
+    k_ref / v_ref ``[1, 1, block_k, d]``."""
+    def scores():
+        return jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [bq, bk]
+
+    _pair_step((qi_ref, kb_ref, bits_ref), scores, v_ref, o_ref, scratch,
+               **band)
 
 
 def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
@@ -233,108 +337,47 @@ def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
     hkv, t_k = k.shape[1], k.shape[2]
     if h % hkv:
         raise ValueError(f"{hkv} KV heads do not divide {h} query heads")
-    g, off = h // hkv, t_k - t_q
     block_q = min(block_q, max(8, 1 << (t_q - 1).bit_length()))
     block_k = min(block_k, max(8, 1 << (t_k - 1).bit_length()))
     qp = _pad_to(_pad_to(q, 2, block_q), 3, _LANES)
     kp = _pad_to(_pad_to(k, 2, block_k), 3, _LANES)
     vp = _pad_to(_pad_to(v, 2, block_k), 3, _LANES)
-    dp, tqp = qp.shape[-1], qp.shape[2]
-    num_qb, last_kb = tqp // block_q, kp.shape[2] // block_k - 1
-
-    # the key axis holds the widest band's blocks; a narrower band's
-    # steps past its last block name that block again (not fetched
-    # twice) and compute nothing
-    num_kb = band_key_steps(tqp, kp.shape[2], block_q, block_k, window)
-
-    def kv_block(bi, hi, qi, kb):
-        first = _band_first(qi, block_q, block_k, off, window)
-        last = jnp.minimum((qi * block_q + off + block_q - 1) // block_k,
-                           last_kb)
-        return (bi, hi // g, jnp.minimum(first + kb, last), 0)
-
-    kernel = functools.partial(
-        _band_kernel, scale=1.0 / math.sqrt(d), block_q=block_q,
-        block_k=block_k, num_kb=num_kb, t_q=t_q, t_k=t_k, window=window)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, h, num_qb, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, dp),
-                         lambda bi, hi, qi, kb: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, dp), kv_block),
-            pl.BlockSpec((1, 1, block_k, dp), kv_block),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, dp),
-                               lambda bi, hi, qi, kb: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, tqp, dp), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
-            pltpu.VMEM((block_q, dp), jnp.float32),      # value accumulator
-        ],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+    dp, kv_block = qp.shape[-1], _k_block(h // hkv)
+    out = _paired_call(
+        functools.partial(_band_kernel, scale=1.0 / math.sqrt(d), t_q=t_q,
+                          t_k=t_k, window=window),
         # by kind, so that a device trace tells a window layer's calls
-        name="flash_band" if window is not None else "flash_grouped",
-    )(qp, kp, vp)
+        "flash_band" if window is not None else "flash_grouped",
+        (qp, kp, vp), sizes=(t_q, t_k, block_q, block_k, window),
+        in_specs=[pl.BlockSpec((1, 1, block_q, dp), _q_block),
+                  pl.BlockSpec((1, 1, block_k, dp), kv_block),
+                  pl.BlockSpec((1, 1, block_k, dp), kv_block)],
+        out_spec=pl.BlockSpec((1, 1, block_q, dp), _q_block),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        interpret=interpret)
     return out[:, :, :t_q, :d]
 
 
-def _latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, m_ref,
-                   l_ref, acc_ref, *, scale, block, t):
-    """One key block of one query block of one head of
+def _latent_kernel(qi_ref, kb_ref, bits_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                   v_ref, o_ref, *scratch, scale, t):
+    """One (query block, key block) pair of one head of
     :func:`flash_latent`: causal, queries and keys at the same
-    positions.  A score is the sum of two products — the head's own
-    part ``qn . kn`` and the part every head shares ``qr . kr`` — so
-    the shared key is never laid beside each head's own; the value has
-    a width of its own.  qn_ref / kn_ref ``[1, 1, block, dn]``, qr_ref
-    / kr_ref ``[1, 1, block, dr]``, v_ref / o_ref ``[1, 1, block,
-    dv]``."""
-    qi, kb = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def accumulate(edge):
+    positions (an edge is a diagonal block).  A score is the sum of two
+    products — the head's own part ``qn . kn`` and the part every head
+    shares ``qr . kr`` — so the shared key is never laid beside each
+    head's own; the value has a width of its own.  qn_ref / kn_ref
+    ``[1, 1, block, dn]``, qr_ref / kr_ref ``[1, 1, block, dr]``, v_ref
+    / o_ref ``[1, 1, block, dv]``."""
+    def scores():
         nt = (((1,), (1,)), ((), ()))
-        v = v_ref[0, 0]
-        s = (jax.lax.dot_general(qn_ref[0, 0], kn_ref[0, 0], nt,
-                                 preferred_element_type=jnp.float32)
-             + jax.lax.dot_general(qr_ref[0, 0], kr_ref[0, 0], nt,
-                                   preferred_element_type=jnp.float32)
-             ) * scale                                    # [block, block]
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        if edge:
-            # the diagonal block: a row sees the keys up to its own, and
-            # no padding (every row has its own key or, a padded row,
-            # every real one: none is left without)
-            k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(jnp.logical_and(qi * block + k_pos < t,
-                                          q_pos >= k_pos), s, _NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        return (jax.lax.dot_general(qn_ref[0, 0], kn_ref[0, 0], nt,
+                                    preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(qr_ref[0, 0], kr_ref[0, 0], nt,
+                                      preferred_element_type=jnp.float32)
+                ) * scale                                 # [block, block]
 
-    # key blocks ahead of the diagonal are neither fetched (the index
-    # map names the diagonal block again) nor computed
-    pl.when(kb < qi)(lambda: accumulate(False))
-    pl.when(kb == qi)(lambda: accumulate(True))
-
-    @pl.when(kb == pl.num_programs(3) - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+    _pair_step((qi_ref, kb_ref, bits_ref), scores, v_ref, o_ref, scratch,
+               t_q=t, t_k=t, window=None)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
@@ -350,8 +393,8 @@ def flash_latent(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
     a value padded to it would cost 192 + 192 a pair where 192 + 128
     are needed).  ``scale`` is the block's (not one over a width's
     root).  Operands go to the matrix unit in their own type and
-    accumulate in f32; blocks of :data:`_BAND_BLOCK` rows; returns
-    ``[b, h, t, dv]``."""
+    accumulate in f32; blocks of :data:`_BAND_BLOCK` rows, the causal
+    triangle's alone (:func:`live_pairs`); returns ``[b, h, t, dv]``."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, t, dn = q_nope.shape
@@ -359,37 +402,18 @@ def flash_latent(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
     block = min(block or _BAND_BLOCK, max(8, 1 << (t - 1).bit_length()))
     qn, qr, kn, kr, vp = (_pad_to(a, 2, block)
                           for a in (q_nope, q_rope, k_nope, k_rope, v))
-    num_b = qn.shape[2] // block
-
-    def q_block(bi, hi, qi, kb):
-        return (bi, hi, qi, 0)
-
-    def k_block(bi, hi, qi, kb):
-        return (bi, hi, jnp.minimum(kb, qi), 0)
-
-    def shared_block(bi, hi, qi, kb):
-        return (bi, 0, jnp.minimum(kb, qi), 0)
-
-    out = pl.pallas_call(
-        functools.partial(_latent_kernel, scale=scale, block=block, t=t),
-        grid=(b, h, num_b, num_b),
-        in_specs=[pl.BlockSpec((1, 1, block, dn), q_block),
-                  pl.BlockSpec((1, 1, block, dr), q_block),
-                  pl.BlockSpec((1, 1, block, dn), k_block),
-                  pl.BlockSpec((1, 1, block, dr), shared_block),
-                  pl.BlockSpec((1, 1, block, dv), k_block)],
-        out_specs=pl.BlockSpec((1, 1, block, dv), q_block),
+    own, shared = _k_block(1), _k_block(h)    # the one rotated key: head 0
+    out = _paired_call(
+        functools.partial(_latent_kernel, scale=scale, t=t), "flash_latent",
+        (qn, qr, kn, kr, vp), sizes=(t, t, block, block, None),
+        in_specs=[pl.BlockSpec((1, 1, block, dn), _q_block),
+                  pl.BlockSpec((1, 1, block, dr), _q_block),
+                  pl.BlockSpec((1, 1, block, dn), own),
+                  pl.BlockSpec((1, 1, block, dr), shared),
+                  pl.BlockSpec((1, 1, block, dv), own)],
+        out_spec=pl.BlockSpec((1, 1, block, dv), _q_block),
         out_shape=jax.ShapeDtypeStruct((b, h, qn.shape[2], dv), v.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block, _LANES), jnp.float32),  # running denom
-            pltpu.VMEM((block, dv), jnp.float32),      # value accumulator
-        ],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_latent",
-    )(qn, qr, kn, kr, vp)
+        interpret=interpret)
     return out[:, :, :t]
 
 

@@ -1,0 +1,159 @@
+"""The prefill's causal flash kernels alone, timed on the chip:
+``flash_latent``, ``flash_band`` and ``flash_grouped``
+(``defer_tpu/ops/flash_attention.py``) at the shapes of one call of the
+two long-prompt cells' prefills (Kimi's 64 expanded heads and
+command-a-plus's 128 query heads on 8 KV heads, window 4096 and none,
+one prompt of 8192) and at Jamba's and granite's (prompts of 256 and
+1024, where a head's triangle is one and three pairs).  Chip only.
+
+    python scripts/flash_kernel_bench.py [OUT.json] [shape ...]
+
+A line a shape: milliseconds a call (``CALLS`` calls behind two
+warm-ups), the call's operations by the benchmark's own functions
+(``chipbench/roofline_latent_moe.py::flash_flops``,
+``roofline_window_moe.py::band_flops``) over that as a share of the
+matrix peak, the largest distance from the masked softmax in float32
+over the first heads, the grid's steps and those that work
+(``prefill.flash.grid_steps`` / ``.live_steps``) and microseconds a
+step.  It runs on a tree from before PR 50 too (copy it there), which
+sets no gauges.
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, ".")
+
+import jax                                                  # noqa: E402
+import jax.numpy as jnp                                     # noqa: E402
+
+from chipbench import roofline_latent_moe as rl             # noqa: E402
+from chipbench import roofline_window_moe as rw             # noqa: E402
+from chipbench.roofline import peaks_for                    # noqa: E402
+from defer_tpu.obs.registry import REGISTRY                 # noqa: E402
+from defer_tpu.ops.flash_attention import (                 # noqa: E402
+    flash_attention, flash_latent)
+
+CALLS = 8
+
+#: name: (configuration, kernel, prompts a call, prompt length, window)
+SHAPES = {
+    "kimi": ("kimi-k2.7-code-5l-ep32", "flash_latent", 1, 8192, None),
+    "commandaplus_window": ("command-a-plus-4l-ep8", "flash_band", 1, 8192,
+                            4096),
+    "commandaplus_full": ("command-a-plus-4l-ep8", "flash_grouped", 1, 8192,
+                          None),
+    "jamba": ("jamba2-3b", "flash_grouped", 64, 256, None),
+    "granite": ("granite-4.0-h-small-10l-ep2", "flash_grouped", 8, 1024,
+                None),
+}
+
+
+def model_args(config: str) -> dict:
+    with open(f"chipbench/configs/{config}.json") as f:
+        return json.load(f)["model_args"]
+
+
+def operands(kernel: str, a: dict, rows: int, t: int):
+    """bf16 operands of one call."""
+    ks = jax.random.split(jax.random.key(7), 5)
+    bf, h = jnp.bfloat16, a["heads"]
+    if kernel == "flash_latent":
+        return (jax.random.normal(ks[0], (rows, h, t, a["nope_dim"]), bf),
+                jax.random.normal(ks[1], (rows, h, t, a["rope_dim"]), bf),
+                jax.random.normal(ks[2], (rows, h, t, a["nope_dim"]), bf),
+                jax.random.normal(ks[3], (rows, 1, t, a["rope_dim"]), bf),
+                jax.random.normal(ks[4], (rows, h, t, a["v_dim"]), bf))
+    d = a["head_dim"]
+    return (jax.random.normal(ks[0], (rows, h, t, d), bf),
+            jax.random.normal(ks[1], (rows, a["kv_heads"], t, d), bf),
+            jax.random.normal(ks[2], (rows, a["kv_heads"], t, d), bf))
+
+
+def masked_softmax(kernel, ops, window, heads, scale):
+    """The first ``heads`` heads of the first prompt in float32."""
+    f32 = jnp.float32
+    if kernel == "flash_latent":
+        qn, qr, kn, kr, v = (o[:1].astype(f32) for o in ops)
+        s = (jnp.einsum("bhqd,bhkd->bhqk", qn[:, :heads], kn[:, :heads])
+             + jnp.einsum("bhqd,bxkd->bhqk", qr[:, :heads], kr)) * scale
+        v = v[:, :heads]
+    else:
+        q, k, v = (o[:1].astype(f32) for o in ops)
+        s = jnp.einsum("bhqd,bxkd->bhqk", q[:, :heads], k[:, :1]) * scale
+        v = jnp.broadcast_to(v[:, :1], (1, heads) + v.shape[2:])
+    t = s.shape[-1]
+    qp, kp = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    seen = kp <= qp
+    if window is not None:
+        seen &= qp - kp < window
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def run(name: str, peak: float) -> dict:
+    config, kernel, rows, t, window = SHAPES[name]
+    a = model_args(config)
+    ops = operands(kernel, a, rows, t)
+    if kernel == "flash_latent":
+        flops = rl.flash_flops(a, rows=rows, prompt_len=t)
+        scale, heads = (a["nope_dim"] + a["rope_dim"]) ** -0.5, 2
+
+        def call(*o):
+            return flash_latent(*o, scale=scale)
+    else:
+        flops = rw.band_flops(a, rows=rows, prompt_len=t, window=window)
+        # heads that share the first KV head, so that one K / V serves
+        scale, heads = a["head_dim"] ** -0.5, min(
+            2, a["heads"] // a["kv_heads"])
+
+        def call(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=window)
+    y = call(*ops).block_until_ready()
+    call(*ops).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(CALLS):
+        y = call(*ops)
+    y.block_until_ready()
+    ms = (time.perf_counter() - t0) / CALLS * 1e3
+    ref = masked_softmax(kernel, ops, window, heads, scale)
+    err = float(jnp.abs(y[:1, :heads].astype(jnp.float32) - ref).max())
+    steps = REGISTRY.gauge("prefill.flash.grid_steps").value
+    live = REGISTRY.gauge("prefill.flash.live_steps").value
+    row = {"shape": name, "kernel": kernel, "rows": rows, "prompt_len": t,
+           "window": window, "ms": ms, "flops": flops,
+           "peak_share": flops / peak / (ms / 1e3), "grid_steps": steps,
+           "live_steps": live, "max_err": err}
+    print(f"{name}: {kernel} {ms:.3f} ms a call, "
+          f"{100 * row['peak_share']:.1f}% of the matrix peak, "
+          f"err {err:.4f}; "
+          # a tree from before PR 50 sets no gauge
+          + (f"{live:.0f} of {steps:.0f} steps work, "
+             f"{ms * 1e3 / steps:.3f} us a step" if steps else
+             "no step gauges"), flush=True)
+    return row
+
+
+def main() -> int:
+    args = sys.argv[1:]
+    out = args.pop(0) if args and args[0].endswith(".json") else None
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"flash_kernel_bench: a chip only, found {dev.platform}",
+              file=sys.stderr)
+        return 1
+    peak = peaks_for(dev.device_kind)["bf16_flops_per_s"]
+    rows = [run(name, peak) for name in (args or SHAPES)]
+    if out:
+        with open(out, "w") as f:
+            json.dump({"device": dev.device_kind, "rows": rows}, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

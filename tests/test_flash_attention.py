@@ -146,7 +146,10 @@ def _banded_reference(q, k, v, window):
     (50, 16, 8),        # over it, and no multiple of the block
     (70, 24, 16),       # a window that is no multiple of the block
     (64, 1, 16),        # a row sees itself alone
-], ids=["causal", "under", "at", "over-ragged", "odd-window", "window1"])
+    (512, None, 128),   # 4 x 4 blocks of four lane groups: the sum a lane
+    (520, 300, 128),    # the same under a window, ragged
+], ids=["causal", "under", "at", "over-ragged", "odd-window", "window1",
+        "lanes", "lanes-window"])
 def test_band_kernel_matches_the_masked_softmax(h, hkv, t, window, block):
     if h == hkv and window is None:
         pytest.skip("the default path: tested above")
@@ -175,16 +178,113 @@ def test_band_kernel_decode_alignment_and_default_blocks():
                                atol=3e-2, rtol=3e-2)
 
 
-def test_band_kernel_skips_blocks_outside_the_band():
-    """The key axis of the grid holds the band's blocks, not the
-    prompt's: at 4 blocks of window over 32 of keys, 5 steps a query
-    block and not 32; at the cell's size 9 of 16 in a window layer."""
-    from defer_tpu.ops.flash_attention import band_key_steps
-    assert band_key_steps(512, 512, 16, 16, 64) == 5
-    assert band_key_steps(512, 512, 16, 16, None) == 32
-    assert band_key_steps(8192, 8192, 512, 512, 4096) == 9
-    assert band_key_steps(8192, 8192, 512, 512, None) == 16
-    assert band_key_steps(16, 528, 16, 16, 64) == 5      # decode-aligned
+# (t_q, t_k, block_q, block_k, window) of the pair list's cases
+_PAIR_CASES = [
+    (512, 512, 16, 16, 64),         # 4 blocks of window over 32 of keys
+    (512, 512, 16, 16, None),
+    (8192, 8192, 512, 512, 4096),   # the cells' window layers
+    (8192, 8192, 512, 512, None),   # their full layers, and Kimi's
+    (16, 528, 16, 16, 64),          # decode-aligned
+    (50, 50, 8, 8, 16),             # ragged
+    (70, 70, 16, 16, 24),           # a window that is no multiple
+    (24, 700, 512, 512, 600),       # decode-aligned, one query block
+    (40, 40, 16, 8, None),          # unlike blocks
+]
+
+
+def _allowed(t_q, t_k, window):
+    """The mask itself: [t_q, t_k] booleans."""
+    r = np.arange(t_q)[:, None] + (t_k - t_q)
+    s = np.arange(t_k)[None, :]
+    seen = s <= r
+    if window is not None:
+        seen &= r - s < window
+    return seen
+
+
+@pytest.mark.parametrize("case,count", zip(_PAIR_CASES[:5],
+                                           [150, 528, 108, 136, 5]))
+def test_pair_list_counts_the_band_s_blocks(case, count):
+    """The grid walks the band's blocks, not a rectangle over them: at
+    the cells' size 136 pairs a head of 256 in a full layer, 108 in a
+    window layer (the old key axis, the widest band's, made 144)."""
+    from defer_tpu.ops.flash_attention import live_pairs
+    assert live_pairs(*case).shape == (3, count)
+
+
+@pytest.mark.parametrize("case", _PAIR_CASES)
+def test_pair_list_flags_fall_once_a_query_block(case):
+    """Query-block-major, key blocks ascending; the first and the last
+    flag fall once a query block each, on its first and last pair."""
+    from defer_tpu.ops.flash_attention import _FIRST, _LAST, live_pairs
+    qi, kb, bits = live_pairs(*case)
+    blocks = -(-case[0] // case[2])
+    assert sorted(set(qi)) == list(range(blocks))
+    assert np.all(np.diff(qi) >= 0)
+    for b in range(blocks):
+        mine = qi == b
+        assert np.all(np.diff(kb[mine]) == 1)
+        assert list(bits[mine] & _FIRST) == [_FIRST] + [0] * (mine.sum() - 1)
+        assert list(bits[mine] & _LAST) == [0] * (mine.sum() - 1) + [_LAST]
+
+
+@pytest.mark.parametrize("case", [c for c in _PAIR_CASES if c[0] < 8192])
+def test_pair_list_is_the_mask_s_blocks(case):
+    """Against the mask: every listed pair holds an allowed (query,
+    key), no allowed one lies in an unlisted pair, and a pair is an
+    edge exactly when it holds a forbidden one too (padding counted)."""
+    from defer_tpu.ops.flash_attention import _EDGE, live_pairs
+    t_q, t_k, bq, bk, window = case
+    nq, nk = -(-t_q // bq), -(-t_k // bk)
+    seen = np.zeros((nq * bq, nk * bk), bool)
+    seen[:t_q, :t_k] = _allowed(t_q, t_k, window)
+    # a padded query row is no query: it forbids nothing
+    full = seen.copy()
+    full[t_q:] = True
+    some = seen.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+    every = full.reshape(nq, bq, nk, bk).all(axis=(1, 3))
+    qi, kb, bits = live_pairs(*case)
+    listed = np.zeros_like(some)
+    listed[qi, kb] = True
+    assert np.array_equal(listed, some)
+    # an edge where the block forbids something; a block marked an edge
+    # that forbids nothing (only through padded rows) is merely masked
+    assert np.all((bits & _EDGE != 0)[~every[qi, kb]])
+
+
+def test_pair_list_of_queries_ahead_of_every_key():
+    """t_q > t_k: the leading query blocks see no key; each is listed
+    once, as an edge, so its rows are written (zeros)."""
+    from defer_tpu.ops.flash_attention import _EDGE, live_pairs
+    qi, kb, bits = live_pairs(32, 8, 8, 8, None)
+    assert list(qi) == [0, 1, 2, 3] and list(kb) == [0, 0, 0, 0]
+    assert np.all(bits & _EDGE != 0)
+    q = jax.random.normal(jax.random.key(0), (1, 2, 32, 8))
+    k = jax.random.normal(jax.random.key(1), (1, 1, 8, 8))
+    out = flash_attention(q, k, k, causal=True, block_q=8, block_k=8)
+    assert np.all(np.asarray(out[:, :, :24]) == 0)
+    ref = _banded_reference(q[:, :, 24:], k, k, None)
+    np.testing.assert_allclose(np.asarray(out[:, :, 24:]), np.asarray(ref),
+                               atol=TOL, rtol=TOL)
+
+
+def test_flash_gauges_count_the_steps_that_work():
+    """``prefill.flash.grid_steps`` / ``.live_steps`` of the newest
+    traced call: alike wherever every query has a key."""
+    from defer_tpu.obs.registry import REGISTRY
+    q = jnp.zeros((2, 4, 70, 8))
+    k = jnp.zeros((2, 2, 70, 8))
+    flash_attention(q, k, k, causal=True, window=24, block_q=16,
+                    block_k=16)
+    steps = REGISTRY.gauge("prefill.flash.grid_steps").value
+    assert steps == 2 * 4 * 12
+    assert REGISTRY.gauge("prefill.flash.live_steps").value == steps
+    # 40 queries on 16 keys: three query blocks of five ahead of every key
+    flash_attention(jnp.zeros((1, 2, 40, 8)), jnp.zeros((1, 1, 16, 8)),
+                    jnp.zeros((1, 1, 16, 8)), causal=True, block_q=8,
+                    block_k=8)
+    assert REGISTRY.gauge("prefill.flash.grid_steps").value == 2 * 6
+    assert REGISTRY.gauge("prefill.flash.live_steps").value == 2 * 3
 
 
 def test_band_kernel_refuses_what_it_is_not():

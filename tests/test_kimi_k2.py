@@ -219,10 +219,13 @@ def test_the_published_row_is_padded_to_whole_lane_tiles():
     assert latent_cache.block_rows(640, 12304, 2) == 768
 
 
-@pytest.mark.parametrize("t", [12, 150])
-def test_flash_latent_is_the_masked_softmax(t):
+@pytest.mark.parametrize("t,block", [(12, 64), (150, 64), (1000, 256)],
+                         ids=["12", "150", "4x4-blocks"])
+def test_flash_latent_is_the_masked_softmax(t, block):
     """The prompt's kernel (interpreter mode): a shared rotated key read
-    by index, a value narrower than the key."""
+    by index, a value narrower than the key; at 4 x 4 blocks of two
+    lane groups the sum a lane is rescaled across a query block's
+    pairs."""
     ks = jax.random.split(jax.random.key(t), 5)
     b, h = 2, 3
     q_n = jax.random.normal(ks[0], (b, h, t, 16))
@@ -230,7 +233,7 @@ def test_flash_latent_is_the_masked_softmax(t):
     k_n = jax.random.normal(ks[2], (b, h, t, 16))
     k_r = jax.random.normal(ks[3], (b, 1, t, 8))
     v = jax.random.normal(ks[4], (b, h, t, 12))
-    got = flash_latent(q_n, q_r, k_n, k_r, v, scale=0.3, block=64)
+    got = flash_latent(q_n, q_r, k_n, k_r, v, scale=0.3, block=block)
     att = (jnp.einsum("bhqd,bhkd->bhqk", q_n, k_n)
            + jnp.einsum("bhqd,bxkd->bhqk", q_r, k_r)) * 0.3
     att = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :], att,
