@@ -148,6 +148,9 @@ def measure(state, seconds, ctx):
             "model_args": state["config"]["model_args"],
             "weight_bytes": int(np.dtype(tr["compute_dtype"]).itemsize),
             "kv_bytes": int(np.dtype(tr["compute_dtype"]).itemsize),
+            # leaves the ring placed otherwise than the device's default
+            "relaid_leaves": float(
+                REGISTRY.gauge("decode.weights.relaid_leaves").value),
         },
     }
 

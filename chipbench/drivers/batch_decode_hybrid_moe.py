@@ -57,10 +57,9 @@ layer a step over held experts), ``held_share`` (held over all
 assignments: 1/2 expected), ``decode.ssm.updates`` (sequences x Mamba
 layers of every valid decode step), the program's gauges
 ``decode.ssm.state_bytes`` / ``decode.ssm.conv_bytes`` /
-``decode.cache.full_bytes`` / ``decode.weights.row_bytes`` /
-``decode.weights.own_bytes`` (as ``ssm_state_bytes`` ...),
-``mamba2_layers``, ``prefill_tokens``, ``prefill_piece_rows`` and
-``max_len``.
+``decode.cache.full_bytes`` / ``decode.weights.own_bytes`` (as
+``ssm_state_bytes`` ...), ``mamba2_layers``, ``prefill_tokens``,
+``prefill_piece_rows`` and ``max_len``.
 
 Traffic file keys: as ``batch_decode``, and ``check_tokens``.
 Configuration file keys: ``model_args`` (for
@@ -161,8 +160,7 @@ MOE_COUNTERS = ("decode.moe.assignments", "decode.moe.held_assignments",
                 "decode.moe.experts_hit", "decode.moe.load_max")
 UPDATES = "decode.ssm.updates"
 GAUGES = ("decode.ssm.state_bytes", "decode.ssm.conv_bytes",
-          "decode.cache.full_bytes", "decode.weights.row_bytes",
-          "decode.weights.own_bytes")
+          "decode.cache.full_bytes", "decode.weights.own_bytes")
 
 
 def make_weights(graph, seed: int, dtype, gains: dict) -> dict:

@@ -41,9 +41,9 @@ half of ``check``.
 Counters added: ``decode.ssm.updates`` over the window (sequences x
 Mamba layers of every valid decode step), the program's gauges
 ``decode.ssm.state_bytes`` / ``decode.ssm.conv_bytes`` /
-``decode.cache.full_bytes`` / ``decode.weights.row_bytes`` /
-``decode.weights.own_bytes`` (as ``ssm_state_bytes`` ...), ``mamba_layers``,
-``prefill_tokens``, ``prefill_piece_rows`` and ``max_len``.
+``decode.cache.full_bytes`` / ``decode.weights.own_bytes`` (as
+``ssm_state_bytes`` ...), ``mamba_layers``, ``prefill_tokens``,
+``prefill_piece_rows`` and ``max_len``.
 
 Traffic file keys: as ``batch_decode``, and ``check_tokens``.
 Configuration file keys: ``model_args`` (for ``defer_tpu.models.jamba``)
@@ -117,8 +117,7 @@ PROBE_DECAY = 0.004
 MEMORY_TOL = 0.012
 UPDATES = "decode.ssm.updates"
 GAUGES = ("decode.ssm.state_bytes", "decode.ssm.conv_bytes",
-          "decode.cache.full_bytes", "decode.weights.row_bytes",
-          "decode.weights.own_bytes")
+          "decode.cache.full_bytes", "decode.weights.own_bytes")
 
 
 def make_weights(graph, seed: int, dtype) -> dict:

@@ -40,7 +40,8 @@ that fell to held experts; ``experts_hit``: distinct held experts a
 layer a step; ``load_max``), ``experts_hit_share`` (held experts hit a
 layer a step over held experts), ``held_share`` (held over all
 assignments: 1/8 expected), the gauges ``decode.cache.window_bytes`` /
-``.full_bytes`` / ``.window_positions`` as ``cache_*`` and
+``.full_bytes`` / ``.window_positions`` as ``cache_*``,
+``prefill.flash.grid_steps`` / ``.live_steps`` as ``prefill_flash_*`` and
 ``prefill_tokens``.
 
 Traffic file keys: as ``batch_decode``, and ``check_tokens``.
@@ -106,6 +107,9 @@ MOE_COUNTERS = ("decode.moe.assignments", "decode.moe.held_assignments",
                 "decode.moe.experts_hit", "decode.moe.load_max")
 CACHE_GAUGES = ("decode.cache.window_bytes", "decode.cache.full_bytes",
                 "decode.cache.window_positions")
+#: grid steps of the newest traced call of a causal prefill kernel, and
+#: those of them that hold a live (query, key) block
+FLASH_GAUGES = ("prefill.flash.grid_steps", "prefill.flash.live_steps")
 
 
 def probe_plan(window: int) -> tuple[int, int, int, int]:
@@ -186,6 +190,9 @@ def measure(state, seconds, ctx):
     counters.update({"cache_" + name.rsplit(".", 1)[1]:
                      float(REGISTRY.gauge(name).value)
                      for name in CACHE_GAUGES})
+    counters.update({name.replace(".", "_"):
+                     float(REGISTRY.gauge(name).value)
+                     for name in FLASH_GAUGES})
     lo, hi = args["experts_held"] or (0, args["num_experts"])
     # one (layer, step) routes rows x experts_per_tok choices
     layer_steps = moe["decode.moe.assignments"] / (

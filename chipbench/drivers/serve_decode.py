@@ -183,6 +183,9 @@ def _engine_counters() -> dict:
         h = REGISTRY.histogram(f"serve.decode.{name}_s")
         out[f"{name}_s_sum"] = float(h.sum)
         out[f"{name}_count"] = int(h.count)
+    # slots the launched steps' cache kernels visited (serve_visited_share)
+    out["rows_launched"] = int(
+        REGISTRY.counter("serve.decode.rows.launched").n)
     return out
 
 
@@ -211,8 +214,10 @@ def measure(state, seconds, ctx):
     counters.update(
         admission_wait_ms_mean=adm.get("mean"),
         late_ms_p95=rd.quantile(late, 0.95) if late else None,
-        rows=float(sum(r.prompt.size + r.answer_len for r in ok))
+        # a prompt goes through the prefill call and takes no step
+        rows=float(sum(r.answer_len for r in ok))
         / max(counters["step_count"], 1),
+        width=tr["width"],
         live_positions=float(np.mean(
             [(r.prompt.size + r.answer_len) / 2 for r in reqs])),
         model_args=state["config"]["model_args"],

@@ -2,7 +2,8 @@
 round of the loop; the pauses the program itself recorded; and whether
 the trace's host and device planes read one clock.
 
-Three things over ``chipbench/spans.py`` (``idle_split``, ``durations``),
+Three things over ``chipbench/trace.py::idle_split`` and
+``chipbench/spans.py::durations``,
 for the readers ``decode_idle_*_ms``, ``decode_upload_ms``,
 ``decode_pause_share`` and the engine's four:
 
@@ -21,6 +22,10 @@ for the readers ``decode_idle_*_ms``, ``decode_upload_ms``,
   device plane's clock reads over the host plane's at one instant, every
   round gives ``skew >= -(wait end - program end)`` and ``skew <=
   program start - launch start``; the window's tightest two bracket it.
+  Which program a launch began and a wait read is taken from their
+  order, not from what lies nearest in time (both loops keep a program
+  queued ahead of the one they read), and the upper end only from
+  launches that found the chip idle.
   Where the bracket holds 0 (within :data:`SKEW_SLACK_S`) the distances
   between the planes may all be latencies; where it does not, some of
   them are skew for certain.  Either way :func:`split` first moves the
@@ -40,8 +45,8 @@ import statistics
 import types
 import weakref
 
-from chipbench.spans import durations, idle_split
-from chipbench.trace import clip, gaps, total, union
+from chipbench.spans import durations
+from chipbench.trace import clip, gaps, idle_split, total, union
 
 #: a bracket this close to 0 holds it: the annotation's own clock reads
 #: lie a fraction of a microsecond from the span's, a program's first
@@ -61,6 +66,8 @@ class Loop:
     call: str           #: the span around the jitted call alone
     upload: str
     program: str        #: pattern of the round's program (module) name
+    #: the span around one generation, whose end leaves a launch unread
+    generation: str | None = None
     #: spans around the whole loop: idle time they keep has no phase
     outer: tuple = ("generate", "loadgen", "unattributed")
 
@@ -69,8 +76,8 @@ DECODE = Loop(
     layer="decode", round="decode.dispatch", wake=("decode.sync",),
     launch=("decode.dispatch", "decode.upload", "decode.launch"),
     wait="decode.sync", call="decode.launch", upload="decode.upload",
-    program=r"jit_device_decode", outer=("generate", "decode.generate",
-                                         "loadgen", "unattributed"))
+    program=r"jit_device_decode", generation="decode.generate",
+    outer=("generate", "decode.generate", "loadgen", "unattributed"))
 ENGINE = Loop(
     layer="engine", round="engine.step",
     wake=("engine.device", "engine.sync"),
@@ -90,47 +97,77 @@ def _inside(red, name: str) -> list[tuple[float, float]]:
                   if n == name and s >= lo and e <= hi)
 
 
-#: a program's end this far behind a wait's end, or its start this far
-#: before a launch's, is still taken for that round's: the most skew the
-#: bracket can find.  Under a round's length, so the next round's program
-#: (which a host that woke late would otherwise be matched with) is not
+#: a program that began this long before the window's first launch was
+#: launched before the window (a program cannot begin before its launch,
+#: and no session's skew has read half of this): it is no launch's of
+#: the window and is left out of the count
 MATCH_SLACK_S = 3e-3
+
+
+def _rounds(red, loop: Loop):
+    """``(calls, waited)``: the window's launches ``(start, end)`` in
+    order, and for some of their indices the end of the wait that read
+    that launch's program.  Both loops read their programs in the order
+    they launched them, so the k-th wait of a generation read its k-th
+    launch; a generation that was stopped leaves its last launch unread,
+    which is why the count starts anew with each (``loop.generation``;
+    the engine discards no step, its window is one generation)."""
+    calls, waits = _inside(red, loop.call), _inside(red, loop.wait)
+    gens = _inside(red, loop.generation) if loop.generation else []
+    waited: dict[int, float] = {}
+    for g0, g1 in gens or [red.window]:
+        mine = [i for i, (s, _e) in enumerate(calls) if g0 <= s <= g1]
+        ends = [e for s, e in waits if g0 <= s <= g1]
+        waited.update(zip(mine, ends))
+    return calls, waited
 
 
 def causality_bracket(red, loop: Loop):
     """``(lo, hi, rounds)``: the skew between the planes lies in
     ``[lo, hi]`` seconds (see the module's text), from ``rounds`` waits;
-    ``None`` where the window holds no wait, launch or program.
+    ``None`` where the window holds no wait, no launch that found the
+    chip idle, or no program.
 
-    A wait is held against the last program that ended before it did
-    (or up to MATCH_SLACK_S after), a launch against the first that
-    began after it did (or up to as much before).  On several chips the
-    host waits for one of them, not known here: the earliest end bounds
-    it (the weakest ``lo`` is the valid one), and every chip's start
-    follows the launch (the tightest ``hi``)."""
-    waits, calls = _inside(red, loop.wait), _inside(red, loop.call)
+    The k-th launch of the window is held against the k-th run of the
+    loop's program on a chip, and a wait against the run its launch
+    began (:func:`_rounds`), never against the run nearest in time: with
+    a program queued ahead the nearest is the one before.  ``hi`` is
+    taken only from launches that found the chip idle (their start lies
+    in no program's run but, at most, their own): behind a running
+    program the distance from launch to start is the rest of that
+    program, no latency, and a bracket that wide would move the spans by
+    half of it.  On several chips the host waits for one of them, not known
+    here: the earliest end bounds it (the weakest ``lo`` is the valid
+    one), and every chip's start follows the launch (the tightest
+    ``hi``)."""
+    calls, waited = _rounds(red, loop)
+    if not calls or not waited:
+        return None
     rx = re.compile(loop.program)
     los, his = [], []
     for dev in red.devices:
-        runs = [(s, e) for n, s, e in dev.modules if rx.search(n)]
-        ends = sorted(e for _s, e in runs)
-        starts = sorted(s for s, _e in runs)
+        runs = sorted((s, e) for n, s, e in dev.modules if rx.search(n)
+                      and s >= calls[0][0] - MATCH_SLACK_S)
+        # the chip's programs of any name, back to back ones as one
+        stretches = union((s, e) for _n, s, e in dev.modules)
+        heads = [s for s, _e in stretches]
         wake, begin = [], []
-        for _s, e in waits:
-            i = bisect.bisect_right(ends, e + MATCH_SLACK_S)
-            if i:
-                wake.append(e - ends[i - 1])
-        for s, _e in calls:
-            i = bisect.bisect_left(starts, s - MATCH_SLACK_S)
-            if i < len(starts):
-                begin.append(starts[i] - s)
+        for i, ((s, _e), (r0, r1)) in enumerate(zip(calls, runs)):
+            j = bisect.bisect_right(heads, s)
+            # idle: no program ran at ``s``, or none but the launch's
+            # own, which a skew below 0 shows ahead of its launch
+            if not j or stretches[j - 1][1] <= s \
+                    or stretches[j - 1][0] >= r0 - SKEW_SLACK_S:
+                begin.append(r0 - s)
+            if i in waited:
+                wake.append(waited[i] - r1)
         if not wake or not begin:
             return None
         los.append(-min(wake))
         his.append(min(begin))
     if not los:
         return None
-    return min(los), min(his), len(waits)
+    return min(los), min(his), len(waited)
 
 
 def skew_shift(red, loop: Loop) -> float:

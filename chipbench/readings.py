@@ -11,6 +11,8 @@ benchmark of this repository was turned down for.
 
 from __future__ import annotations
 
+import statistics
+
 MIN_READINGS = 12
 
 
@@ -27,6 +29,32 @@ def quantile(values, q: float) -> float:
     lo = int(pos)
     hi = min(lo + 1, len(ys) - 1)
     return ys[lo] + (ys[hi] - ys[lo]) * (pos - lo)
+
+
+def spread(values, *, trim: bool = True) -> float | None:
+    """The spread of some runs' values of one metric, as a share of their
+    median: the distance between the first and the third quartile as
+    ``statistics.quantiles(values, n=4)`` gives them, over the median.
+    With ``trim`` the run farthest from the median is left out where
+    that narrows the spread (the check's own rule for whether a bound is
+    too tight; without it, its rule for too loose); three runs or fewer
+    all stay, since two values' quartiles lie outside them.  ``None`` for
+    fewer than two values or a median of 0.  For ``chipbench/steady.py``:
+    nothing that times a cell reads it."""
+    ys = sorted(float(v) for v in values)
+    mid = statistics.median(ys) if ys else 0.0
+    if len(ys) < 2 or not mid:
+        return None
+    q1, _q2, q3 = statistics.quantiles(ys, n=4)
+    out = (q3 - q1) / abs(mid)
+    if trim and len(ys) > 3:
+        far = max(ys, key=lambda y: abs(y - mid))
+        rest = list(ys)
+        rest.remove(far)
+        narrower = spread(rest, trim=False)
+        if narrower is not None:
+            out = min(out, narrower)
+    return out
 
 
 def require_readings(readings, *, need: int = MIN_READINGS) -> None:

@@ -7,15 +7,12 @@
 without them (the parent of the PR that added them) leaves none, and
 every function here then returns nothing.
 
-``idle_split`` is what ``TraceReduction.idle_gaps`` should become: that
-rule hands a whole gap to the span with most overlap, which is always
-the harness's span around the whole call.  The switch is an edit to
-``chipbench/trace.py`` that only a benchmark PR may make (PERF.md §7).
+How idle time is split among them is ``chipbench/trace.py::idle_split``
+(``TraceReduction.idle_gaps`` since PR 52): each instant of a gap goes to
+the innermost span over it.
 """
 
 from __future__ import annotations
-
-from chipbench.trace import SHORT_GAP_S, gaps
 
 
 def durations(red, name: str) -> list[float]:
@@ -24,42 +21,3 @@ def durations(red, name: str) -> list[float]:
     lo, hi = red.window
     return [e - s for n, s, e in red.spans
             if n == name and s >= lo and e <= hi]
-
-
-def idle_split(red, n: int = 10, device: int = 0):
-    """``[[what, seconds], ...]``: idle time of one chip by what the
-    host was doing in it.  A gap is split among the spans over it, each
-    instant going to the innermost span there (the shortest; spans of
-    several threads count alike); what no span covers goes to the gap's
-    largest sharer, ``unattributed`` where no span touches the gap."""
-    lo, hi = red.window
-    by: dict[str, float] = {}
-    spans = sorted((s, e, name) for name, s, e in red.spans
-                   if name != "window" and e > s)
-    nxt, over = 0, []       # spans[:nxt] were opened; ``over`` may touch
-    for g0, g1 in gaps(red.busy_by_device[device], lo, hi):
-        if g1 - g0 < SHORT_GAP_S:
-            by["between_ops_under_20us"] = \
-                by.get("between_ops_under_20us", 0.0) + g1 - g0
-            continue
-        while nxt < len(spans) and spans[nxt][0] < g1:
-            over.append(spans[nxt])
-            nxt += 1
-        over = [sp for sp in over if sp[1] > g0]    # gaps come in order
-        cuts = sorted({g0, g1} | {t for s, e, _n in over
-                                  for t in (s, e) if g0 < t < g1})
-        shares: dict[str, float] = {}
-        for a, b in zip(cuts, cuts[1:]):
-            inner = min(((e - s, -s, name) for s, e, name in over
-                         if s <= a and e >= b), default=None)
-            what = inner[2] if inner else ""
-            shares[what] = shares.get(what, 0.0) + b - a
-        bare = shares.pop("", 0.0)
-        if shares:
-            shares[max(shares, key=shares.get)] += bare
-        else:
-            shares["unattributed"] = bare
-        for what, d in shares.items():
-            by[what] = by.get(what, 0.0) + d
-    return [[k, v] for k, v in
-            sorted(by.items(), key=lambda kv: -kv[1])[:n]]

@@ -212,7 +212,8 @@ def test_the_window_counts_routing_updates_and_the_ring_s_bytes(root):
     assert c["ssm_conv_bytes"] == conv
     assert c["ssm_state_bytes"] == 6 * 4 * 16 * 128 * 4 + conv
     assert c["cache_full_bytes"] == 2 * 2 * 4 * 33 * 2 * 16 * 4 * 2
-    assert c["weights_row_bytes"] > 0 and c["weights_own_bytes"] > 0
+    # no leaf rides a flat row since PR 44: the driver reads no such gauge
+    assert "weights_row_bytes" not in c and c["weights_own_bytes"] > 0
     assert c["prefill_piece_rows"] == 4 and c["max_len"] == 32
     # float32 windows here: 4 bytes a value
     rh.check_held(dict(c, weight_bytes=4, kv_bytes=4), ARGS)

@@ -118,12 +118,62 @@ def test_a_host_that_woke_late_is_not_held_against_the_next_program():
     lo, hi, rounds = idle.causality_bracket(red, idle.DECODE)
     assert (lo, hi, rounds) == (pytest.approx(-0.0005),
                                 pytest.approx(0.0015), 3)
-    # a bracket that contradicts itself moves nothing
+    # a bracket that contradicts itself moves nothing: the first program
+    # begins before its launch did and ends after its wait did
     for dev in red.devices:
-        dev.modules = [(n, s + (0.004 if i == 0 else -0.004), e)
-                       for i, (n, s, e) in enumerate(dev.modules)]
+        dev.modules[0] = ("jit_device_decode(1)", 0.0, 0.0215)
     lo, hi, _n = idle.causality_bracket(red, idle.DECODE)
-    assert lo > hi and idle.skew_shift(red, idle.DECODE) == 0.0
+    assert (lo, hi) == (pytest.approx(0.0010), pytest.approx(-0.0005))
+    assert idle.skew_shift(red, idle.DECODE) == 0.0
+
+
+def _queued_trace(skew=0.0):
+    """The ring since PR 37, one chip, 140 ms: a chunk is launched before
+    the one ahead of it is read.  Generation A launches L0 onto an idle
+    chip (its program begins 1.5 ms later) and L1 behind it, then reads
+    and launches in turn; L2 and L3 find the chip 1.5 ms into the
+    program before theirs.  A is stopped behind its third read: P3 is
+    never read.  Generation B waits for P3, launches L4 onto an idle
+    chip (1.0 ms) and L5, and its first wait ends 0.05 ms behind P4: the
+    tightest of the four, and the fourth wait of the window."""
+    dev = tr.DeviceTrace("/device:TPU:0")
+    runs = [(0.0035, 0.0235), (0.02351, 0.0435), (0.04351, 0.0635),
+            (0.06351, 0.0835), (0.0910, 0.1110), (0.11101, 0.1310)]
+    dev.ops = [("%fusion.1 = f32[] fusion()", s + skew, e + skew)
+               for s, e in runs]
+    dev.modules = [("jit_device_decode(9)", s + skew, e + skew)
+                   for s, e in runs]
+    spans = [("window", 0.0, 0.140), ("generate", 0.0005, 0.0650),
+             ("decode.generate", 0.0010, 0.0650),
+             ("generate", 0.0655, 0.1400),
+             ("decode.generate", 0.0660, 0.1399)]
+    for t in (0.0020, 0.0030, 0.0250, 0.0450, 0.0900, 0.0910):
+        spans += [("decode.dispatch", t, t + 0.0010),
+                  ("decode.upload", t, t + 0.0003),
+                  ("decode.launch", t + 0.0003, t + 0.0010)]
+    spans += [("decode.sync", 0.0040, 0.0237), ("decode.sync", 0.0260, 0.0436),
+              ("decode.sync", 0.0460, 0.0638),
+              ("decode.init", 0.0661, 0.0836),      # waits for P3
+              ("decode.sync", 0.0920, 0.11105)]
+    return tr.TraceReduction([dev], spans)
+
+
+@pytest.mark.parametrize("skew", [0.0, 0.0012, -0.0016])
+def test_with_a_chunk_queued_ahead_the_kth_wait_reads_the_kth_program(skew):
+    red = _queued_trace(skew)
+    lo, hi, rounds = idle.causality_bracket(red, idle.DECODE)
+    # hi from L4 alone of the launches that found the chip idle (L0:
+    # 1.2 ms from the launch's own start); lo from B's first wait, held
+    # against B's first program, not A's unread one
+    assert (lo, hi, rounds) == (pytest.approx(skew - 0.00005),
+                                pytest.approx(skew + 0.0007), 4)
+    assert idle.skew_shift(red, idle.DECODE) == pytest.approx(
+        skew + 0.000325)
+    # until PR 52 L2 was held against P1, running since 1.5 ms: hi < lo
+    # and "contradicts itself, nothing moved" in every ring cell
+    wake = _read("decode_idle_wake_ms", red)
+    assert wake == pytest.approx(_read("decode_idle_wake_ms",
+                                       _queued_trace()), abs=1e-6)
 
 
 @pytest.mark.parametrize("skew", [0.003, -0.002])

@@ -22,7 +22,9 @@ def _root(tmp_path, doc):
 
 def test_the_committed_manifest_loads_and_names_real_files():
     m = Manifest()
-    assert m.workload_names() == ["gpt2xl_batch_decode", "gpt2xl_chat_serve"]
+    # PR 23's two cells lead the list; later PRs append theirs
+    assert m.workload_names()[:2] == ["gpt2xl_batch_decode",
+                                      "gpt2xl_chat_serve"]
     for name in m.workload_names():
         cell = m.cell(name)
         assert hasattr(m.driver(cell), "measure")

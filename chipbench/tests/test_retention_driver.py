@@ -98,7 +98,8 @@ def test_the_real_manifest_gives_the_cell_its_files_and_metrics():
             entry["layer"], entry["source"], entry["moves"])
         assert entry["workloads"] == ["brumby_batch_decode"]
     four = [w for w in m.doc["workloads"] if w["chips"] == 4]
-    assert len(m.doc["workloads"]) == 5 and len(four) == 1
+    assert len(m.doc["workloads"]) >= 5 and len(four) == 1
+    assert "brumby_batch_decode" in m.workload_names()[:5]
 
 
 def test_an_untraced_run_checks_tokens_and_state(root):

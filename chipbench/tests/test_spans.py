@@ -51,7 +51,7 @@ def test_durations_and_the_split_of_a_gap_among_the_spans_over_it():
     # the park span ends outside the window: not an occurrence inside it
     assert sp.durations(red, "engine.park") == []
     assert sp.durations(red, "no.such.span") == []
-    gaps = dict(sp.idle_split(red))
+    gaps = dict(tr.idle_split(red))
     assert sum(gaps.values()) == pytest.approx(0.024)
     # the 4 ms gap, each instant to the innermost span over it; the
     # 0.1 ms before each 'delivery' lies under 'sync'
@@ -64,8 +64,11 @@ def test_durations_and_the_split_of_a_gap_among_the_spans_over_it():
     assert gaps["engine.join"] == pytest.approx(0.0011)
     assert gaps["engine.park"] == pytest.approx(0.0188)
     assert "loadgen" not in gaps and "unattributed" not in gaps
-    # the committed rule hands every gap to the span around the whole call
-    assert dict(red.idle_gaps()) == {"loadgen": pytest.approx(0.024)}
+    # the breakdown's split is this one (until PR 52 it handed every gap
+    # to the span around the whole call: {"loadgen": 0.024})
+    assert dict(red.idle_gaps(n=99)) == gaps
+    assert [k for k, _v in red.breakdown()["idle_gaps"]][:2] == [
+        "engine.park", "engine.delivery"]
 
 
 def test_a_gaps_uncovered_remainder_goes_to_its_largest_sharer():
@@ -74,21 +77,22 @@ def test_a_gaps_uncovered_remainder_goes_to_its_largest_sharer():
                ("%copy.2 = f32[] copy()", 5.0, 6.0),
                ("%copy.3 = f32[] copy()", 8.0, 9.0)]
     spans = [("window", 0.0, 9.0), ("a", 1.5, 2.5), ("b", 3.0, 4.5)]
-    gaps = dict(sp.idle_split(tr.TraceReduction([dev], spans)))
+    gaps = dict(tr.idle_split(tr.TraceReduction([dev], spans)))
     # gap 1-5: a 1.0, b 1.5, bare 1.5 -> b; gap 6-8: no span touches it
     assert gaps == {"a": pytest.approx(1.0), "b": pytest.approx(3.0),
                     "unattributed": pytest.approx(2.0)}
 
 
-def test_with_one_span_over_a_gap_the_split_is_the_committed_rule():
-    """The made-up trace of ``test_trace.py``: both rules agree."""
+def test_with_one_span_over_a_gap_the_split_is_the_old_rule_s():
+    """The made-up trace of ``test_trace.py``: where one span lies over a
+    gap, the split reads what most-overlap read until PR 52."""
     dev = tr.DeviceTrace("/device:TPU:0")
     dev.ops = [("%fusion.1 = f32[] fusion()", 1.0, 2.5),
                ("%copy.3 = f32[] copy()", 4.0, 5.0)]
     spans = [("window", 0.0, 10.0), ("loadgen", 0.0, 10.0),
              ("drain", 2.4, 4.1)]
     red = tr.TraceReduction([dev], spans)
-    assert dict(sp.idle_split(red)) == dict(red.idle_gaps()) \
+    assert dict(tr.idle_split(red)) == dict(red.idle_gaps()) \
         == {"drain": pytest.approx(1.5), "loadgen": pytest.approx(6.0)}
 
 
@@ -139,7 +143,7 @@ def test_the_recorded_trace_holds_no_program_span():
                                                        rel=0.03)
     assert not [n for n, _s, _e in red.spans if "." in n]
     # two spans touch each gap there: the sleep, and the work beside it
-    split = dict(sp.idle_split(red))
+    split = dict(tr.idle_split(red))
     assert split["sleep"] == pytest.approx(0.1511, rel=0.01)
     assert split["work"] == pytest.approx(0.0084, rel=0.05)
 

@@ -73,7 +73,13 @@ def test_the_real_manifest_gives_the_cell_its_files_and_metrics():
     m = Manifest()
     cell = m.cell("commandaplus_batch_decode")
     assert set(NEW) | set(SHARED[1:]) <= set(cell.per_layer)
-    assert len(cell.per_layer) == 10
+    # the ring's idle readers of PR 36, which this count kept the cell
+    # off until PR 52, and that PR's two counters: 10 + 4 + 2
+    assert {"decode_idle_wake_ms", "decode_idle_launch_ms",
+            "decode_upload_ms", "decode_pause_share",
+            "weights_relaid_leaves", "prefill_flash_live_share"} \
+        <= set(cell.per_layer)
+    assert len(cell.per_layer) >= 16
     assert cell.end_to_end == ("tokens_per_s", "setup_s")
     assert cell.chips == 1
     assert cell.traffic["driver"] == "batch_decode_window_moe"
@@ -106,7 +112,8 @@ def test_the_real_manifest_gives_the_cell_its_files_and_metrics():
             entry["layer"], entry["source"], entry["moves"])
         assert entry["workloads"] == ["commandaplus_batch_decode"]
     four = [w for w in m.doc["workloads"] if w["chips"] == 4]
-    assert len(m.doc["workloads"]) == 6 and len(four) == 1
+    assert len(m.doc["workloads"]) >= 6 and len(four) == 1
+    assert "commandaplus_batch_decode" in m.workload_names()[:6]
 
 
 def test_an_untraced_run_checks_tokens_router_and_window(root):

@@ -153,30 +153,54 @@ class TraceReduction:
     # -- idle gaps -----------------------------------------------------------
 
     def idle_gaps(self, n: int = 10, device: int = 0):
-        """``[[what, seconds], ...]``: idle time of one chip by the
-        harness span that covered most of each gap (the innermost where
-        spans nest), ``unattributed`` where none did."""
-        lo, hi = self.window
-        by: dict[str, float] = {}
-        spans = [s for s in self.spans if s[0] != "window"]
-        for gap in gaps(self.busy_by_device[device], lo, hi):
-            length = gap[1] - gap[0]
-            if length < SHORT_GAP_S:
-                what = "between_ops_under_20us"
-            else:
-                best, best_key = "unattributed", (0.0, 0.0)
-                for name, s, e in spans:
-                    ov = overlap(gap, (s, e))
-                    key = (ov, -(e - s))     # most overlap, then shortest
-                    if ov > 0 and key > best_key:
-                        best, best_key = name, key
-                what = best
-            by[what] = by.get(what, 0.0) + length
-        return [[k, v] for k, v in
-                sorted(by.items(), key=lambda kv: -kv[1])[:n]]
+        """``[[what, seconds], ...]``: idle time of one chip by what the
+        host was doing in it (:func:`idle_split`): the innermost span
+        over each instant, so a ``breakdown`` names the loops' phases
+        (``decode.sync``, ``engine.device``, ``engine.park``) and not the
+        span around the whole call."""
+        return idle_split(self, n, device)
 
     def breakdown(self) -> dict:
         return {"device_ops": self.top_ops(), "idle_gaps": self.idle_gaps()}
+
+
+def idle_split(red, n: int = 10, device: int = 0):
+    """``[[what, seconds], ...]``: idle time of one chip by what the
+    host was doing in it.  A gap is split among the spans over it, each
+    instant going to the innermost span there (the shortest; spans of
+    several threads count alike); what no span covers goes to the gap's
+    largest sharer, ``unattributed`` where no span touches the gap."""
+    lo, hi = red.window
+    by: dict[str, float] = {}
+    spans = sorted((s, e, name) for name, s, e in red.spans
+                   if name != "window" and e > s)
+    nxt, over = 0, []       # spans[:nxt] were opened; ``over`` may touch
+    for g0, g1 in gaps(red.busy_by_device[device], lo, hi):
+        if g1 - g0 < SHORT_GAP_S:
+            by["between_ops_under_20us"] = \
+                by.get("between_ops_under_20us", 0.0) + g1 - g0
+            continue
+        while nxt < len(spans) and spans[nxt][0] < g1:
+            over.append(spans[nxt])
+            nxt += 1
+        over = [sp for sp in over if sp[1] > g0]    # gaps come in order
+        cuts = sorted({g0, g1} | {t for s, e, _n in over
+                                  for t in (s, e) if g0 < t < g1})
+        shares: dict[str, float] = {}
+        for a, b in zip(cuts, cuts[1:]):
+            inner = min(((e - s, -s, name) for s, e, name in over
+                         if s <= a and e >= b), default=None)
+            what = inner[2] if inner else ""
+            shares[what] = shares.get(what, 0.0) + b - a
+        bare = shares.pop("", 0.0)
+        if shares:
+            shares[max(shares, key=shares.get)] += bare
+        else:
+            shares["unattributed"] = bare
+        for what, d in shares.items():
+            by[what] = by.get(what, 0.0) + d
+    return [[k, v] for k, v in
+            sorted(by.items(), key=lambda kv: -kv[1])[:n]]
 
 
 def _events(line) -> list[tuple[str, float, float]]:
