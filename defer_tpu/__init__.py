@@ -17,6 +17,14 @@ Quick start::
     outputs = defer.run(graph, params, inputs, num_stages=8)
 """
 
+import sys as _sys
+import time as _time
+
+# ``setup.import`` (obs/profile.py): a span cannot wrap the import of
+# its own module, so the two reads are taken here and recorded below
+_t_import = _time.perf_counter()
+_jax_preloaded = int("jax" in _sys.modules)
+
 from . import models
 from . import plan
 from .graph.analysis import (auto_cut_points, max_activation_bytes,
@@ -54,12 +62,19 @@ from .obs import (LatencyHistogram, MetricsRegistry, REGISTRY,
 from .utils.metrics import PipelineMetrics, StopwatchWindow
 from .utils.profiling import profile_pipeline
 from .utils import compile_cache as _compile_cache
+from .obs import profile as _obs_profile, trace as _obs_trace
 
 __version__ = "0.1.0"
 
 # nothing above compiles at import; every process of the package (its
 # children too) resolves the same persistent-cache directory here
 _compile_cache.configure()
+# the compile listener from here on, unarmed: the programs of a weight
+# draw or a checkpoint's load, which run before a decoder is built, have
+# names too.  It fires only when jax traces or builds a program
+_obs_profile.recompile_watcher().install()
+_obs_trace.record_span("setup", "import", _t_import, _time.perf_counter(),
+                       {"jax_preloaded": _jax_preloaded})
 
 __all__ = [
     "GraphBuilder", "LayerGraph", "Op", "ShapeSpec", "StageSpec",

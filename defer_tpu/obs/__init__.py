@@ -24,7 +24,8 @@ predicate per instrumentation site.  See docs/OBSERVABILITY.md.
 from .histogram import LatencyHistogram
 from .registry import REGISTRY, Gauge, MetricsRegistry, get_registry
 from .trace import (Tracer, enable_tracing, export_chrome_trace,
-                    new_span_id, span, tracer)
+                    new_span_id, record_span, span, spanned_first_call,
+                    tracer)
 from .events import recorder
 from .cluster import ClusterView, StragglerDetector
 from .capacity import (CapacityModel, DriftAuditor, achieved_mfu,
@@ -36,15 +37,16 @@ from .postmortem import collect as collect_postmortem, maybe_autopsy
 from .profile import (DECODE_DISPATCH_PHASES, DECODE_PHASES,
                       DECODE_STATS_PHASES, DOOR_PHASES,
                       ENGINE_DISPATCH_PHASES, ENGINE_LOOP_PHASES,
-                      ENGINE_PHASES, SPAN_LAYERS, MemoryWatcher,
-                      PauseWatcher, ProfileSession, RecompileWatcher,
-                      pause_watcher, recompile_watcher)
+                      ENGINE_PHASES, SETUP_PHASES, SPAN_LAYERS,
+                      MemoryWatcher, PauseWatcher, ProfileSession,
+                      RecompileWatcher, pause_watcher, recompile_watcher,
+                      setup_breakdown, setup_log)
 
 __all__ = [
     "LatencyHistogram",
     "MetricsRegistry", "REGISTRY", "get_registry", "Gauge",
     "Tracer", "tracer", "enable_tracing", "export_chrome_trace",
-    "new_span_id", "span",
+    "new_span_id", "span", "record_span", "spanned_first_call",
     "recorder",
     "ClusterView", "StragglerDetector",
     "CapacityModel", "DriftAuditor", "achieved_mfu", "stage_flops_bytes",
@@ -54,7 +56,7 @@ __all__ = [
     "collect_postmortem", "maybe_autopsy",
     "ENGINE_PHASES", "ENGINE_DISPATCH_PHASES", "ENGINE_LOOP_PHASES",
     "DECODE_PHASES", "DECODE_DISPATCH_PHASES", "DECODE_STATS_PHASES",
-    "DOOR_PHASES", "SPAN_LAYERS", "ProfileSession",
+    "DOOR_PHASES", "SETUP_PHASES", "SPAN_LAYERS", "ProfileSession",
     "RecompileWatcher", "recompile_watcher", "MemoryWatcher",
-    "PauseWatcher", "pause_watcher",
+    "PauseWatcher", "pause_watcher", "setup_breakdown", "setup_log",
 ]

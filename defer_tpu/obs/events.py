@@ -74,7 +74,7 @@ EVENT_KINDS = {
     "replica_respawn": "the chain supervisor respawned a dead replica "
                        "(stage, replica, addr, rc)",
     "recompile": "XLA compiled a program after warmup — one event per "
-                 "episode (count, via, label, shapes)",
+                 "episode (count, program)",
     "mem_pressure": "live device-array bytes crossed the configured "
                     "threshold (bytes, threshold, live_arrays)",
     "host_pause": "a program phase took far longer than it usually does "
@@ -82,6 +82,11 @@ EVENT_KINDS = {
                   "proc_cpu_ms; since the thread's baseline: since_ms, "
                   "vol_switches, invol_switches, major_faults, "
                   "runq_wait_ms, steal_ms, gc_collections)",
+    "setup_done": "the process's set-up is over: where its time went, in "
+                  "seconds that sum to elapsed_s (elapsed_s, import_s, "
+                  "place_s, relay_s, state_s, trace_s, lower_s, "
+                  "compile_s, cache_load_s, first_call_s, warm_run_s, "
+                  "unnamed_s, costliest, threads)",
     "journal": "the black-box journal spiller started or stopped "
                "(action, dir)",
     "postmortem": "a postmortem bundle was assembled "

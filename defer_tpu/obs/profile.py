@@ -8,14 +8,18 @@ instead of replacing it:
   call returning, ``device``: ``block_until_ready``, ``host_sync``:
   ``np.asarray``); this module owns the phase NAME table and the
   session arithmetic over the per-node histograms the loops feed.
-* **Recompile telemetry** — :class:`RecompileWatcher` hooks
-  ``jax.monitoring``'s ``backend_compile_duration`` stream (with a
-  :meth:`~RecompileWatcher.wrap` shape-signature fallback for callables
-  that bypass jit, or for builds without the monitoring events) to
-  count XLA compilations per process and emit ONE ``recompile``
+* **Recompile telemetry** — :class:`RecompileWatcher` is the
+  program's one ``jax.monitoring`` listener: it says of every program
+  jax builds what kind of time it took (trace, lower, compile, load
+  from the persistent cache) and whose (the event's ``fun_name``),
+  counts XLA's backend events per process and emits ONE ``recompile``
   flight-recorder event per compile episode — the same
   emit-once/re-arm discipline as ``model_drift``, so a recompile storm
   is one log line per burst, not thousands.
+* **Set-up telemetry** — :class:`SetupLog` keeps the intervals of a
+  process's start-up (the ``setup`` spans and the listener's events)
+  until set-up is over; :func:`setup_breakdown` turns them into seconds
+  by kind that sum to the elapsed time.
 * **Memory telemetry** — :func:`device_memory_bytes` prices the live
   device arrays (``jax.live_arrays``) without importing jax into a
   process that never used it; :class:`MemoryWatcher` turns it into the
@@ -33,14 +37,17 @@ and reply with the DELTA phase breakdown (counts and summed seconds
 per phase over exactly that window), the recompiles inside it, and the
 live-memory reading — the machine-readable row the ``defer_tpu
 profile`` CLI merges across nodes.  Everything here is off until
-asked for: the watchers are installed lazily and the phase histograms
-are the same always-on-cheap instruments the stats plane already pays
-for.
+asked for but the compile listener, which the package installs when it
+is imported (unarmed: it fires only when jax traces or builds a
+program): the other watchers are installed lazily and the phase
+histograms are the same always-on-cheap instruments the stats plane
+already pays for.
 """
 
 from __future__ import annotations
 
 import gc
+import heapq
 import os
 import resource
 import sys
@@ -151,6 +158,17 @@ KERNEL_NAMES = (
 #: (serve/frontdoor.py): prompt frame received -> queued or shed
 DOOR_PHASES = ("admit",)
 
+#: a process's start-up, each once a call and none inside a loop:
+#: ``import`` is ``import defer_tpu`` (and ``import jax`` where the
+#: package was the first to ask for it: ``args["jax_preloaded"]``),
+#: ``place`` one placement of a model's weights on its devices,
+#: ``relay`` one leaf laid out anew on the device (inside ``place``),
+#: ``state`` the zero caches and states' allocation, ``first_call`` the
+#: first call of a compiled program through its return — the launch, not
+#: the device's run — with the listener's trace, lower and
+#: compile-or-load of ``args["program"]`` inside it
+SETUP_PHASES = ("import", "place", "relay", "state", "first_call")
+
 #: every program span (obs/trace.py ``span``): layer -> (registry
 #: prefix, root, phases).  ``span(layer, phase)`` is named
 #: ``<layer>.<phase>`` and feeds the histogram ``<prefix>.<phase>_s``;
@@ -165,19 +183,29 @@ SPAN_LAYERS = {
     "engine": ("serve.decode", "step", ENGINE_PHASES
                + ENGINE_DISPATCH_PHASES + ENGINE_LOOP_PHASES),
     "door": ("serve.door", None, DOOR_PHASES),
+    "setup": ("setup", None, SETUP_PHASES),
 }
 
 #: phases the pause watch never judges: ``dispatch`` is tiled by its two
 #: children, which are judged (a pause would count twice), and ``park``
 #: is a blocking pop by design.  A root encloses a whole round and is
-#: not judged either.
-PAUSE_UNJUDGED = {"decode": ("dispatch",), "engine": ("dispatch", "park")}
+#: not judged either, nor any phase of set-up: a 9 s compile is no pause.
+PAUSE_UNJUDGED = {"decode": ("dispatch",), "engine": ("dispatch", "park"),
+                  "setup": SETUP_PHASES}
 
 #: where a layer's pause baseline is taken (:meth:`PauseWatcher.rebase`):
 #: a generation begins, the engine leaves ``park`` — so that nothing is
 #: read per round
 PAUSE_BASELINE_AT = {"decode": ("generate", "enter"),
                      "engine": ("park", "exit")}
+
+#: where set-up ends (:meth:`SetupLog.close`), at the other end of the
+#: baseline's span: the process's first generation is over (the ring's
+#: warm-up has built every program its loop uses), the engine parks
+#: after its first busy period.  One comparison a generation or a busy
+#: period, nothing a chunk or a step
+SETUP_CLOSE_AT = {"decode": ("generate", "exit"),
+                  "engine": ("park", "enter")}
 
 #: the key of a span's ``args`` that numbers the layer's rounds: a
 #: ``host_pause`` event names the newest one its thread has seen
@@ -197,67 +225,96 @@ PAUSE_TIMES = 3.0
 #: phase's first, and the median of these leaves it out)
 PAUSE_UNJUDGED_FIRST = 7
 
-#: the jax.monitoring duration event that fires once per XLA backend
-#: compilation (and never on a program-cache hit)
+#: the jax.monitoring duration event around ``compile_or_get_cached``
+#: (jax 0.9.0, ``pxla.py``): it fires once a program jax builds — never
+#: on a hit in the in-memory program cache, but on a hit in the
+#: persistent cache too, which fires _RETRIEVAL_EVENT inside it first
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: the listener's events -> the kind of time each is.  A backend event
+#: that held a retrieval is a ``cache_load``, whole, and no ``compile``
+_JAX_EVENT_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _COMPILE_EVENT: "compile",
+}
+JAX_KINDS = ("trace", "lower", "compile", "cache_load")
+#: programs the listener's table names; the cheaper ones are summed
+#: under ``"other"``
+PROGRAM_TABLE_SIZE = 64
 
-
-def _fmt_shapes(args) -> list[str]:
-    """``f32[8,128]``-style abstract shapes for event payloads (arrays
-    only; scalars/pytrees are summarized by type name)."""
-    out = []
-    for a in args:
-        shape = getattr(a, "shape", None)
-        dtype = getattr(a, "dtype", None)
-        if shape is not None and dtype is not None:
-            out.append(f"{dtype}[{','.join(str(s) for s in shape)}]")
-        else:
-            out.append(type(a).__name__)
-    return out
+#: the kinds of :func:`setup_breakdown`: the ``setup`` spans, the
+#: listener's, and ``warm_run`` — what of the first generation (the
+#: engine's first busy period) lies under neither: the prefill's and the
+#: first chunks' runs on the device.  In the set-up line's order
+SETUP_KINDS = ("import", "place", "relay", "state") + JAX_KINDS \
+    + ("first_call", "warm_run")
+#: intervals a :class:`SetupLog` keeps (a cell's set-up holds a few
+#: hundred); what comes after is counted in ``setup.dropped_intervals``
+#: and its time stays unnamed
+SETUP_MAX_INTERVALS = 16384
 
 
 class RecompileWatcher:
-    """Counts XLA compilations in this process and emits ONE
-    ``recompile`` flight-recorder event per compile EPISODE.
+    """The program's one ``jax.monitoring`` listener: what kind of time
+    building a program took, and whose.
 
-    An episode is a burst of compiles separated from the previous burst
-    by at least ``episode_gap_s`` of quiet: the first compile of a
-    burst emits (carrying the via/label/shape attribution), the rest
-    only count — so an injected shape change on a hot loop produces
-    exactly one event, and warmup compiles before :meth:`arm` produce
-    none.  Counting is always on once installed; event emission starts
-    at :meth:`arm` (call it after warmup, or never for a silent
-    counter).
+    Every duration event of :data:`_JAX_EVENT_KINDS` feeds a histogram
+    (``jax.trace_s``, ``jax.lower_s``, ``jax.cache_load_s`` beside
+    ``jax.compile_s``, which holds every backend event, loads included,
+    as ``jax.compiles`` counts them), a row of the table
+    :meth:`programs` under the event's ``fun_name``, and ``intervals``
+    (the process's watcher: its :class:`SetupLog`).  An event is called
+    at its end, so its interval is ``(now - duration, now)``; an inner
+    ``jit`` traced inside an outer one's trace fires inside the outer's
+    time, and a thread's events of one kind are not summed twice: jax
+    records a scalar under the same name where such a time *begins*
+    (:meth:`on_start`), so the listener keeps a depth a thread and a
+    kind, and an event's histogram and row take its time less that of
+    the events of its kind directly inside it.
+
+    It also emits ONE ``recompile`` flight-recorder event per compile
+    EPISODE.  An episode is a burst of backend events separated from the
+    previous burst by at least ``episode_gap_s`` of quiet: the first of
+    a burst emits (naming its ``program``), the rest only count — so an
+    injected shape change on a hot loop produces exactly one event, and
+    warmup compiles before :meth:`arm` produce none.  Counting is always
+    on once installed; event emission starts at :meth:`arm` (call it
+    after warmup, or never for a silent counter).
     """
 
-    def __init__(self, *, episode_gap_s: float = 5.0):
+    def __init__(self, *, episode_gap_s: float = 5.0, intervals=None):
         self.episode_gap_s = float(episode_gap_s)
         self._lock = threading.Lock()
+        self._local = threading.local()
         self._installed = False
         self._armed = False
         self._last_t: float | None = None
+        self._intervals = intervals     # callable (kind, t0, t1) or None
         self._compiles = REGISTRY.counter("jax.compiles")
         self._compile_s = REGISTRY.histogram("jax.compile_s")
+        self._hists = {kind: REGISTRY.histogram(f"jax.{kind}_s")
+                       for kind in JAX_KINDS if kind != "compile"}
+        self._programs: dict[str, dict] = {}
 
     @property
     def count(self) -> int:
         return self._compiles.value
 
     def install(self) -> "RecompileWatcher":
-        """Register the ``jax.monitoring`` listener (idempotent; a
-        process that never imports jax can still :meth:`wrap`)."""
+        """Register the ``jax.monitoring`` listener (idempotent)."""
         with self._lock:
             if self._installed:
                 return self
             try:
                 import jax.monitoring as _mon
                 _mon.register_event_duration_secs_listener(
-                    self._on_duration)
-            except Exception as e:  # noqa: BLE001 — builds without the
-                # monitoring events fall back to wrap(); counting just
-                # loses the listener path, loudly on stderr once
+                    self.on_duration)
+                _mon.register_scalar_listener(self.on_start)
+            except Exception as e:  # noqa: BLE001 — a build without the
+                # monitoring events counts nothing, loudly on stderr once
                 print(f"profile: jax.monitoring unavailable ({e!r}); "
-                      f"recompile counting rides wrap() only",
+                      f"no program is timed or counted",
                       file=sys.stderr, flush=True)
             self._installed = True
             return self
@@ -275,38 +332,74 @@ class RecompileWatcher:
         with self._lock:
             self._armed = False
 
-    # -- the two ingestion paths -------------------------------------------
+    def _open_events(self, kind: str) -> list:
+        """The calling thread's events of ``kind`` that have begun and
+        not ended, outermost first: for each, the seconds of the events
+        directly inside it that have ended (a list of one)."""
+        stacks = self._local.__dict__
+        stack = stacks.get(kind)
+        if stack is None:
+            stack = stacks[kind] = []
+        return stack
 
-    def _on_duration(self, name: str, dur: float, **kw) -> None:
-        if name != _COMPILE_EVENT:
+    def on_start(self, name: str, _value, **_kw) -> None:
+        """The listener's other ear: the scalar jax records where a
+        timed event begins (``dispatch.log_elapsed_time``)."""
+        kind = _JAX_EVENT_KINDS.get(name)
+        if kind is not None:
+            self._open_events(kind).append([0.0])
+
+    def on_duration(self, name: str, dur: float, fun_name=None,
+                    **_kw) -> None:
+        """The listener: one ``jax.monitoring`` duration event."""
+        local = self._local
+        if name == _RETRIEVAL_EVENT:
+            # inside the backend event that follows on this thread
+            local.retrieved = True
             return
-        self._record(dur, via="jax.monitoring", label=None, shapes=None)
+        kind = _JAX_EVENT_KINDS.get(name)
+        if kind is None:
+            return
+        now = time.perf_counter()
+        # the trace event names the function, the others its module
+        program = str(fun_name or "?")
+        if program.startswith("jit(") and program.endswith(")"):
+            program = program[4:-1]
+        # less the events of its kind directly inside it; its own time
+        # is inside the one around it, if any
+        opened = self._open_events(kind)
+        own = max(dur - opened.pop()[0], 0.0) if opened else dur
+        if opened:
+            opened[-1][0] += dur
+        if kind == "compile":
+            self._backend_event(dur, program)
+            if getattr(local, "retrieved", False):
+                local.retrieved = False
+                kind = "cache_load"
+        if kind != "compile":
+            self._hists[kind].record(own)
+        with self._lock:
+            row = self._programs.get(program)
+            if row is None:
+                if len(self._programs) >= 2 * PROGRAM_TABLE_SIZE:
+                    self._programs = _costliest_rows(self._programs)
+                row = self._programs[program] = _program_row()
+            row[kind + "_s"] += own
+            row["count"] += kind in ("compile", "cache_load")
+        if self._intervals is not None:
+            self._intervals(kind, now - dur, now)
 
-    def wrap(self, fn, label: str = ""):
-        """Shape-signature fallback: returns ``fn`` wrapped so a call
-        whose array signature (shape+dtype per argument) was never seen
-        before is recorded as a compilation — what a jitted callable
-        would do — with the abstract shapes attached to the event.
-        Use when ``jax.monitoring`` is unavailable, or to attribute
-        recompiles to a specific call site by ``label``."""
-        seen: set = set()
-        lock = threading.Lock()
+    def programs(self) -> dict:
+        """Program name -> ``{trace_s, lower_s, compile_s, cache_load_s,
+        count}``: seconds of each kind jax spent on the programs of that
+        name (a load from the persistent cache is ``cache_load_s`` and
+        no ``compile_s``) and how many it built or loaded.  The
+        :data:`PROGRAM_TABLE_SIZE` costliest names; the rest are summed
+        under ``"other"``."""
+        with self._lock:
+            return _costliest_rows(self._programs)
 
-        def wrapped(*args, **kwargs):
-            sig = tuple(_fmt_shapes(args))
-            with lock:
-                fresh = sig not in seen
-                if fresh:
-                    seen.add(sig)
-            if fresh:
-                self._record(0.0, via="wrap", label=label,
-                             shapes=list(sig))
-            return fn(*args, **kwargs)
-
-        wrapped.__wrapped__ = fn
-        return wrapped
-
-    def _record(self, dur: float, *, via, label, shapes) -> None:
+    def _backend_event(self, dur: float, program: str) -> None:
         self._compiles.inc()
         if dur:
             self._compile_s.record(dur)
@@ -320,12 +413,157 @@ class RecompileWatcher:
             # counts (re-arming is lazy — no timer thread)
             fire = self._armed and quiet
         if fire:
-            data = {"count": self._compiles.value, "via": via}
-            if label:
-                data["label"] = label
-            if shapes:
-                data["shapes"] = shapes
-            emit_event("recompile", **data)
+            emit_event("recompile", count=self._compiles.value,
+                       program=program)
+
+
+def _program_row() -> dict:
+    return {**{kind + "_s": 0.0 for kind in JAX_KINDS}, "count": 0}
+
+
+def _row_seconds(row: dict) -> float:
+    return sum(row[kind + "_s"] for kind in JAX_KINDS)
+
+
+def _costliest_rows(rows: dict) -> dict:
+    """A copy of a program table, costliest first, at most
+    :data:`PROGRAM_TABLE_SIZE` names and the others under ``"other"``."""
+    rows = {name: dict(row) for name, row in rows.items()}
+    other = rows.pop("other", None)
+    names = sorted(rows, key=lambda nm: -_row_seconds(rows[nm]))
+    out = {nm: rows[nm] for nm in names[:PROGRAM_TABLE_SIZE]}
+    for nm in names[PROGRAM_TABLE_SIZE:]:
+        other = other or _program_row()
+        for key, v in rows[nm].items():
+            other[key] += v
+    if other is not None:
+        out["other"] = other
+    return out
+
+
+class SetupLog:
+    """The intervals ``(kind, thread, t0, t1)`` of a process's set-up,
+    on ``perf_counter``'s clock: every ``setup`` span and every event of
+    the compile listener, until set-up is over (:meth:`close`).  Later
+    intervals are not kept: a program compiled after the close goes to
+    the listener's table and the ``recompile`` events, and is no set-up.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._dropped = REGISTRY.counter("setup.dropped_intervals")
+        self.clear()
+
+    def clear(self) -> None:
+        """Forget everything and open again (a process has one set-up;
+        a test has several)."""
+        with self._lock:
+            self._intervals: list = []
+            self._open = True
+            #: open, and a compiled program has been called: the next
+            #: generation's end or park ends set-up
+            self._ripe = False
+            #: :func:`setup_breakdown` as it stood at the close, and the
+            #: listener's table then, costliest first (the programs of
+            #: set-up, and none built later)
+            self.done: dict | None = None
+            self.programs: dict = {}
+
+    def add(self, kind: str, t0: float, t1: float) -> None:
+        if not self._open:
+            return
+        with self._lock:
+            if len(self._intervals) >= SETUP_MAX_INTERVALS:
+                self._dropped.inc()
+                return
+            self._intervals.append((kind, threading.get_ident(), t0, t1))
+            if kind == "first_call":
+                self._ripe = self._open
+
+    def intervals(self) -> list:
+        with self._lock:
+            return list(self._intervals)
+
+    def close(self, layer: str) -> None:
+        """Set-up is over, if a compiled program has run: called where
+        :data:`SETUP_CLOSE_AT` says, on the thread that ran ``layer``'s
+        first round.  What of that round lies under no other interval
+        is ``warm_run``: the round began where the layer's pause
+        baseline was taken.  Freezes the breakdown, says it in one line
+        on stderr and emits it as one ``setup_done`` event."""
+        if not self._ripe:
+            return
+        now = time.perf_counter()
+        base = pause_watcher()._bases().get(layer)
+        with self._lock:
+            if not self._ripe:
+                return
+            self._ripe = self._open = False
+            if base is not None:
+                self._intervals.append(
+                    ("warm_run", threading.get_ident(), base[0], now))
+            intervals = list(self._intervals)
+        done = setup_breakdown(intervals, now)
+        self.programs = recompile_watcher().programs()
+        self.done = done
+        costliest = ",".join(
+            f"{name}:{_row_seconds(row):.3f}"
+            for name, row in list(self.programs.items())[:3]
+            if name != "other")
+        emit_event("setup_done", **done, costliest=costliest,
+                   threads=len({thread for _, thread, _, _ in intervals}))
+        print("defer_tpu: setup " + " ".join(
+            f"{k}={v:.6f}" for k, v in done.items())
+            + f" costliest={costliest}", file=sys.stderr, flush=True)
+
+
+def setup_breakdown(intervals=None, end: float | None = None) -> dict | None:
+    """Where set-up's time went: ``elapsed_s`` (the first interval's
+    start to set-up's end), a ``<kind>_s`` for each of
+    :data:`SETUP_KINDS` and ``unnamed_s``, which sum to ``elapsed_s``.
+
+    The seconds are exclusive: each instant goes to the innermost
+    interval over it — the one that ended first, as a child ends before
+    its parent and an event is listed at its end — so a re-laying
+    program's compile is ``compile`` and not also ``relay`` and
+    ``place``, and an inner ``jit``'s trace is counted once.  What no
+    interval of any thread covers is ``unnamed_s``.  (The histograms
+    ``setup.<phase>_s`` stay inclusive: ``setup.relay_s`` is what
+    re-laying costs, its compile included.)
+
+    Without arguments: of this process's :class:`SetupLog` — as frozen
+    at the close once set-up is over, so far before that; ``None`` while
+    the log is empty.  With ``intervals`` (and ``end``, default the
+    last interval's): of that list."""
+    if intervals is None:
+        log = setup_log()
+        if log.done is not None:
+            return dict(log.done)
+        intervals = log.intervals()
+    if not intervals:
+        return None
+    cuts = sorted({t for _, _, t0, t1 in intervals for t in (t0, t1)})
+    if end is None:
+        end = cuts[-1]
+    by_start = sorted(range(len(intervals)), key=lambda i: intervals[i][2])
+    seconds = dict.fromkeys(SETUP_KINDS, 0.0)
+    live: list = []         # (t1, -t0, index): the innermost first
+    nxt, covered = 0, 0.0
+    for a, b in zip(cuts, cuts[1:]):
+        while nxt < len(by_start) and intervals[by_start[nxt]][2] <= a:
+            i = by_start[nxt]
+            heapq.heappush(live, (intervals[i][3], -intervals[i][2], i))
+            nxt += 1
+        while live and live[0][0] <= a:
+            heapq.heappop(live)
+        if live:
+            kind = intervals[live[0][2]][0]
+            seconds[kind] = seconds.get(kind, 0.0) + (b - a)
+            covered += b - a
+    elapsed = end - cuts[0]
+    return {"elapsed_s": elapsed,
+            **{kind + "_s": s for kind, s in seconds.items()},
+            "unnamed_s": elapsed - covered}
 
 
 def device_memory(ensure: bool = False) -> tuple[int, int] | None:
@@ -690,19 +928,25 @@ class ProfileSession:
 
 
 _WATCHER: RecompileWatcher | None = None
+_SETUP = SetupLog()
 _MEM: MemoryWatcher | None = None
 _PAUSES: PauseWatcher | None = None
 _LOCK = threading.Lock()
 
 
 def recompile_watcher() -> RecompileWatcher:
-    """This process's recompile watcher (NOT auto-installed: call
-    ``.install()`` to hook jax.monitoring)."""
+    """This process's compile listener, which feeds its
+    :class:`SetupLog` (``import defer_tpu`` installs it, unarmed)."""
     global _WATCHER
     with _LOCK:
         if _WATCHER is None:
-            _WATCHER = RecompileWatcher()
+            _WATCHER = RecompileWatcher(intervals=_SETUP.add)
         return _WATCHER
+
+
+def setup_log() -> SetupLog:
+    """This process's set-up log (the one the ``setup`` spans feed)."""
+    return _SETUP
 
 
 def memory_watcher() -> MemoryWatcher:

@@ -401,7 +401,8 @@ _CPU_FRESH_S = 5e-3
 
 def _program_span_entry(layer: str, phase: str):
     # profile imports events imports us
-    from .profile import PAUSE_BASELINE_AT, SPAN_LAYERS, pause_watcher
+    from .profile import (PAUSE_BASELINE_AT, SETUP_CLOSE_AT, SPAN_LAYERS,
+                          pause_watcher, setup_log)
     prefix, root, phases = SPAN_LAYERS[layer]
     if phase != root and phase not in phases:
         raise KeyError(f"{layer}.{phase} is not in obs/profile.py's "
@@ -411,10 +412,17 @@ def _program_span_entry(layer: str, phase: str):
         else REGISTRY.histogram(f"{prefix}.{phase}_s")
     pauses = pause_watcher()
     cls = _ProgramSpan
-    if PAUSE_BASELINE_AT.get(layer, (None,))[0] == phase:
-        cls = functools.partial(
-            _BaselineSpan, PAUSE_BASELINE_AT[layer][1] == "enter",
-            functools.partial(pauses.rebase, layer))
+    if layer == "setup":
+        cls = functools.partial(_SetupSpan, setup_log().add, phase)
+    # what rides on the span's ends: the pause baseline, set-up's close
+    ends = {"enter": [], "exit": []}
+    for table, fn in ((PAUSE_BASELINE_AT, pauses.rebase),
+                      (SETUP_CLOSE_AT, setup_log().close)):
+        if table.get(layer, (None,))[0] == phase:
+            ends[table[layer][1]].append(functools.partial(fn, layer))
+    if ends["enter"] or ends["exit"]:
+        cls = functools.partial(_BaselineSpan, tuple(ends["enter"]),
+                                tuple(ends["exit"]))
     entry = _PROGRAM_SPANS[layer, phase] = \
         (cls, name, ANNOTATION_PREFIX + name, hist,
          pauses.phase(layer, phase))
@@ -495,25 +503,46 @@ class _ProgramSpan:
 
 
 class _BaselineSpan(_ProgramSpan):
-    """The span at whose start or end its layer's pause baseline is
-    taken anew on this thread (``obs/profile.py::PAUSE_BASELINE_AT``)."""
+    """The span at whose start and end its layer's bookkeeping of a
+    whole round rides, so that nothing is read per phase: the pause
+    baseline taken anew on this thread at one end
+    (``obs/profile.py::PAUSE_BASELINE_AT``), set-up's close at the other
+    (``SETUP_CLOSE_AT``)."""
 
-    __slots__ = ("_at_enter", "_rebase")
+    __slots__ = ("_at_enter", "_at_exit")
 
-    def __init__(self, at_enter: bool, rebase, *entry):
+    def __init__(self, at_enter: tuple, at_exit: tuple, *entry):
         super().__init__(*entry)
         self._at_enter = at_enter
-        self._rebase = rebase
+        self._at_exit = at_exit
 
     def __enter__(self):
-        if self._at_enter:
-            self._rebase()
+        for fn in self._at_enter:
+            fn()
         return super().__enter__()
 
     def __exit__(self, exc_type, exc, tb):
         super().__exit__(exc_type, exc, tb)
-        if not self._at_enter:
-            self._rebase()
+        for fn in self._at_exit:
+            fn()
+        return False
+
+
+class _SetupSpan(_ProgramSpan):
+    """A phase of set-up: its interval also goes to the process's
+    set-up log (``obs/profile.py::SetupLog``), which keeps it until
+    set-up is over."""
+
+    __slots__ = ("_log", "_kind")
+
+    def __init__(self, log, kind: str, *entry):
+        super().__init__(*entry)
+        self._log = log
+        self._kind = kind
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        self._log(self._kind, self.t0, self.t1)
         return False
 
 
@@ -541,6 +570,60 @@ def span(layer: str, phase: str, args: dict | None = None) -> _ProgramSpan:
     if args is not None:
         ROUND_ARGS[layer] = args
     return cls(name, ann_name, hist, watch, args)
+
+
+def record_span(layer: str, phase: str, t0: float, t1: float,
+                args: dict | None = None) -> None:
+    """An already-timed occurrence of a phase (``perf_counter`` reads),
+    for the one that cannot be wrapped — ``setup.import`` cannot span
+    the import of its own module: the phase's histogram, the tracer's
+    span while it is enabled (:meth:`Tracer.record`) and, for a phase of
+    set-up, the set-up log.  No annotation: the profiler takes none for
+    a time gone by."""
+    _cls, name, _ann_name, hist, _watch = \
+        _PROGRAM_SPANS.get((layer, phase)) \
+        or _program_span_entry(layer, phase)
+    if hist is not None:
+        hist.record(t1 - t0)
+    if _TRACER.enabled:
+        _TRACER.record(name, t0, t1 - t0, args)
+    if layer == "setup":
+        from .profile import setup_log
+        setup_log().add(phase, t0, t1)
+
+
+class spanned_first_call:
+    """``fn``, a jitted function fresh from ``jax.jit``, whose first
+    call runs under ``setup.first_call``: jax traces, lowers and
+    compiles or loads the program inside that call, and the span ends
+    when the launch returns.  ``stored(fn)``, where given, is called
+    behind the first call: a caller that keeps the callable puts ``fn``
+    itself back there, and calls nothing of this from then on.  Every
+    other attribute is ``fn``'s (``lower``, ``trace``: a script that
+    compiles from shapes takes either)."""
+
+    __slots__ = ("_fn", "_stored", "_first")
+
+    def __init__(self, fn, stored=None):
+        self._fn = fn
+        self._stored = stored
+        self._first = True
+
+    def __call__(self, *args):
+        fn = self._fn
+        if not self._first:
+            return fn(*args)
+        self._first = False
+        program = getattr(fn, "__name__", None) or repr(fn)
+        try:
+            with span("setup", "first_call", {"program": program}):
+                return fn(*args)
+        finally:
+            if self._stored is not None:
+                self._stored(fn)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
 
 
 def tracer() -> Tracer:

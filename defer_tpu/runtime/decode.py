@@ -83,7 +83,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
-from ..obs import REGISTRY, span
+from ..obs import REGISTRY, span, spanned_first_call
 from ..ops import quant
 from ..ops.layered import shapes_by_layer, zeros_by_layer
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
@@ -150,7 +150,9 @@ def relaid(leaf: jax.Array, want: Format) -> jax.Array:
     floor = jax.config.jax_persistent_cache_min_compile_time_secs
     jax.config.update("jax_persistent_cache_min_compile_time_secs", math.inf)
     try:
-        out = jax.jit(_as_is, out_shardings=want, donate_argnums=0)(leaf)
+        with span("setup", "relay"):
+            out = jax.jit(_as_is, out_shardings=want,
+                          donate_argnums=0)(leaf)
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           floor)
@@ -249,11 +251,9 @@ class PipelinedDecoder:
         # what the deployment holds: leaves as placed (int8 values and
         # their scales under W8A16), before the device's tiling pads
         # them, a shorter stage's zeros and other stages' ends not
-        # counted.  No leaf rides a flat row (the gauge keeps its name
-        # for its readers)
+        # counted
         shapes = [shape for node in self._layout.values()
                   for shape, _ in node.values()]
-        REGISTRY.gauge("decode.weights.row_bytes").set(0)
         REGISTRY.gauge("decode.weights.own_bytes").set(
             sum(math.prod(sh) + 4 * math.prod(sh[-1:]) for sh in shapes)
             if self.weight_quant
@@ -451,34 +451,44 @@ class PipelinedDecoder:
 
             return jax.tree.map(place, *trees)
 
-        blocks, variant = [], []
-        for l, longest in enumerate(max(self.stage_blocks, key=len)):
-            # stage s's l-th block; where it has fewer, the longest
-            # stage's stands in for the shapes
-            real = [l < len(b) for b in self.stage_blocks]
-            names = [b[l] if ok else longest
-                     for ok, b in zip(real, self.stage_blocks)]
-            kinds = [tuple(layout[nm].items()) for nm in names]
-            distinct = list(dict.fromkeys(kinds))
-            if len(distinct) == 1:
-                variant.append(None)
-                blocks.append(placed([params[nm] for nm in names], real))
-                continue
-            # unlike blocks at one place of their stages: a tree a kind
-            variant.append([distinct.index(kind) for kind in kinds])
-            blocks.append(tuple(
-                placed([params[nm if kind == want else
-                               names[kinds.index(want)]]
-                        for nm, kind in zip(names, kinds)],
-                       [ok and kind == want
-                        for ok, kind in zip(real, kinds)])
-                for want in distinct))
-        #: per local layer, None where every stage's block has the same
-        #: parameter tree, else the tree of ``blocks[l]`` each stage reads
-        self._variant = variant
-        ends = {nm: placed([params[nm]] * n, [s == at for s in range(n)])
-                for nm, at in self._ends.items()}
-        return {"blocks": tuple(blocks), "ends": ends}
+        # one span a call, not one a leaf
+        args: dict = {}
+        with span("setup", "place", args):
+            blocks, variant = [], []
+            for l, longest in enumerate(max(self.stage_blocks, key=len)):
+                # stage s's l-th block; where it has fewer, the longest
+                # stage's stands in for the shapes
+                real = [l < len(b) for b in self.stage_blocks]
+                names = [b[l] if ok else longest
+                         for ok, b in zip(real, self.stage_blocks)]
+                kinds = [tuple(layout[nm].items()) for nm in names]
+                distinct = list(dict.fromkeys(kinds))
+                if len(distinct) == 1:
+                    variant.append(None)
+                    blocks.append(
+                        placed([params[nm] for nm in names], real))
+                    continue
+                # unlike blocks at one place of their stages: a tree a
+                # kind
+                variant.append([distinct.index(kind) for kind in kinds])
+                blocks.append(tuple(
+                    placed([params[nm if kind == want else
+                                   names[kinds.index(want)]]
+                            for nm, kind in zip(names, kinds)],
+                           [ok and kind == want
+                            for ok, kind in zip(real, kinds)])
+                    for want in distinct))
+            #: per local layer, None where every stage's block has the
+            #: same parameter tree, else the tree of ``blocks[l]`` each
+            #: stage reads
+            self._variant = variant
+            ends = {nm: placed([params[nm]] * n, [s == at for s in range(n)])
+                    for nm, at in self._ends.items()}
+            w = {"blocks": tuple(blocks), "ends": ends}
+            leaves = jax.tree.leaves(w)
+            args.update(leaves=len(leaves),
+                        bytes=sum(a.nbytes for a in leaves))
+        return w
 
     def _leaf_format(self, ndim: int) -> Format:
         """Where a weight leaf of ``ndim`` dimensions lies: sharded over
@@ -831,23 +841,26 @@ class PipelinedDecoder:
         The zero-fill programs are jitted ONCE and cached — a fresh lambda
         per call would recompile (~0.4 s each) on every ``generate``.
         """
-        if self._init_fn is None:
-            n, mb, d = self.num_stages, self.microbatch, self.d_model
-            act_sh = NamedSharding(self.mesh, P(STAGE_AXIS, None, None))
-            state_sh = jax.tree.map(
-                lambda spec: NamedSharding(self.mesh, spec),
-                self._state_specs())
+        with span("setup", "state"):
+            if self._init_fn is None:
+                n, mb = self.num_stages, self.microbatch
+                act_sh = NamedSharding(self.mesh, P(STAGE_AXIS, None, None))
+                state_sh = jax.tree.map(
+                    lambda spec: NamedSharding(self.mesh, spec),
+                    self._state_specs())
 
-            def zeros():
-                caches = zeros_by_layer(self.state_formats, mb, lead=(n,))
-                if self.beam_width > 1:
-                    caches["beam_cum"] = jnp.zeros((n, n, mb), jnp.float32)
-                return (jnp.zeros((n, mb, self._ring_width), jnp.float32),
-                        caches)
+                def zeros():
+                    caches = zeros_by_layer(self.state_formats, mb,
+                                            lead=(n,))
+                    if self.beam_width > 1:
+                        caches["beam_cum"] = jnp.zeros((n, n, mb),
+                                                       jnp.float32)
+                    return (jnp.zeros((n, mb, self._ring_width),
+                                      jnp.float32), caches)
 
-            self._init_fn = jax.jit(
-                zeros, out_shardings=(act_sh, state_sh))
-        return self._init_fn()
+                self._init_fn = jax.jit(
+                    zeros, out_shardings=(act_sh, state_sh))
+            return self._init_fn()
 
     def _build_decode_fn(self, chunk_steps: int, sample: bool,
                          top_k: int | None):
@@ -935,8 +948,9 @@ class PipelinedDecoder:
         key = (chunk_steps, sample, top_k)
         fn = self._decode_fns.get(key)
         if fn is None:
-            fn = self._decode_fns[key] = \
-                self._build_decode_fn(chunk_steps, sample, top_k)
+            # this generation calls it through ``setup.first_call``
+            fn = spanned_first_call(self._decode_fns.setdefault(
+                key, self._build_decode_fn(chunk_steps, sample, top_k)))
         return fn
 
     def _gather_init(self, prompt: np.ndarray, plen: int, t_tok: int,
@@ -1125,8 +1139,8 @@ class PipelinedDecoder:
             pkey = (plen, sample, top_k)
             pfn = self._prefill_fns.get(pkey)
             if pfn is None:
-                pfn = self._prefill_fns[pkey] = \
-                    self._build_prefill_fn(plen, sample, top_k)
+                pfn = spanned_first_call(self._prefill_fns.setdefault(
+                    pkey, self._build_prefill_fn(plen, sample, top_k)))
             with span("decode", "prefill"):
                 caches, pre_ids = pfn(self._w, prompt_dev, seed_s, temp_s,
                                       caches)

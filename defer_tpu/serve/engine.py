@@ -80,6 +80,7 @@ needs stateful stage artifacts — the documented next step
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Callable
@@ -92,7 +93,7 @@ import jax.numpy as jnp
 from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
 from ..models.gpt import CausalTransformerBlock, GptEmbedding
-from ..obs import REGISTRY, span
+from ..obs import REGISTRY, span, spanned_first_call
 from ..obs.events import emit as emit_event
 from ..ops import kv_cache
 from ..runtime.decode import sample_ids
@@ -201,7 +202,12 @@ class ContinuousBatchEngine:
                     "CausalTransformerBlock: the decode engine serves the "
                     "GPT family only (PipelinedDecoder runs the others)")
         self.graph = graph
-        self.params = jax.tree.map(jnp.asarray, params)
+        placed: dict = {}
+        with span("setup", "place", placed):
+            self.params = jax.tree.map(jnp.asarray, params)
+            leaves = jax.tree.leaves(self.params)
+            placed.update(leaves=len(leaves),
+                          bytes=sum(a.nbytes for a in leaves))
         self.width = width
         self.num_stages = num_stages
         self.embed_op: GptEmbedding = parts.embed_op
@@ -224,18 +230,26 @@ class ContinuousBatchEngine:
             kv_heads, head_dim, self.max_len, jnp.float32)
 
         self._slots: list[_Slot | None] = [None] * width
-        #: every layer's buffers, each a donated argument of the step and
-        #: its aliased output (docs/DECODE_CLIFF.md, "The engine")
-        self._caches = self.kv_format.zeros(width, len(parts.block_names))
+        with span("setup", "state"):
+            #: every layer's buffers, each a donated argument of the step
+            #: and its aliased output (docs/DECODE_CLIFF.md, "The engine")
+            self._caches = self.kv_format.zeros(width,
+                                                len(parts.block_names))
+            #: the ids the last launched step returned, on the device:
+            #: what the next step feeds every slot that is past its prompt
+            self._prev_ids = jnp.zeros(width, jnp.int32)
         self._step_fns: dict[bool, Any] = {}
-        #: the ids the last launched step returned, on the device: what
-        #: the next step feeds every slot that is past its prompt
-        self._prev_ids = jnp.zeros(width, jnp.int32)
         #: the step launched and not yet read, if any
         self._flight: _Flight | None = None
         #: positions a prefill takes (0: a model of one position)
         self.prefill_len = min(PREFILL_POSITIONS, self.max_len - 1)
         self._prefill_fns = self._build_prefill()
+        #: what ``_prefill`` calls: each program through
+        #: ``setup.first_call`` once, itself from then on
+        calls = self._prefill_calls = []
+        calls.extend(
+            spanned_first_call(fn, functools.partial(calls.__setitem__, i))
+            for i, fn in enumerate(self._prefill_fns))
         self.steps = 0              #: steps whose ids the host has read
         self._step_hist = REGISTRY.histogram("serve.decode.step_s")
         self._tok_count = REGISTRY.counter("serve.decode.tokens")
@@ -360,7 +374,9 @@ class ContinuousBatchEngine:
     def _step_fn(self, sample: bool):
         fn = self._step_fns.get(sample)
         if fn is None:
-            fn = self._step_fns[sample] = self._build_step(sample)
+            # the launch that follows is ``setup.first_call``
+            fn = spanned_first_call(self._step_fns.setdefault(
+                sample, self._build_step(sample)))
         return fn
 
     # -- the prefill programs ----------------------------------------------
@@ -407,7 +423,7 @@ class ContinuousBatchEngine:
         ``jit_engine_prefill`` runs of a profiler trace."""
         n, s.prefill = s.prefill, 0
         fmt = self.kv_format
-        embed, blocks_prefill = self._prefill_fns
+        embed, blocks_prefill = self._prefill_calls
         with span("engine", "prefill", {"step": self.steps, "slot": i,
                                         "positions": n}):
             ids = np.zeros(self.prefill_len, np.int32)

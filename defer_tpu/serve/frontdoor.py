@@ -41,7 +41,8 @@ from typing import Any, Sequence
 
 import numpy as np
 
-from ..obs import ENGINE_LOOP_PHASES, REGISTRY, span, tracer
+from ..obs import (ENGINE_LOOP_PHASES, REGISTRY, recompile_watcher,
+                   setup_breakdown, span, tracer)
 from ..obs.attrib import DoorAttribution
 from ..obs.events import emit as emit_event
 from ..obs.events import recorder
@@ -870,4 +871,10 @@ class ServeFrontDoor:
                     f"serve.decode.{name}_s").summary()
                    for name in ("step",) + ENGINE_LOOP_PHASES},
             }
+        setup = setup_breakdown()
+        if setup is not None:
+            # where this deploy's start-up went (the set-up line's
+            # numbers) and what jax spent on each program it built
+            doc["setup"] = {**setup,
+                            "programs": recompile_watcher().programs()}
         return doc

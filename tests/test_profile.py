@@ -77,37 +77,43 @@ def test_profile_session_absent_phase_stays_honest():
 # ---------------------------------------------------------------------------
 
 def test_recompile_wrap_episode_discipline():
+    """The episodes, driven through the listener's own entry: a backend
+    event a fresh signature, under the compiled function's name."""
     w = RecompileWatcher(episode_gap_s=0.2)
-    calls = []
-    f = w.wrap(lambda *a: calls.append(a), label="stage_fn")
+    event = "/jax/core/compile/backend_compile_duration"
+
+    def compiled(program="stage_fn"):
+        w.on_duration(event, 0.001, fun_name=f"jit({program})")
+
     c0 = w.count
     ev0 = len(_recompile_events())
-    # warmup signatures BEFORE arm: counted, silent
-    f(np.zeros((2, 4), np.float32))
-    f(np.zeros((2, 4), np.float32))       # repeat: cache hit, no count
+    # warmup compiles BEFORE arm: counted, silent
+    compiled()
+    w.on_duration("/jax/core/compile/jaxpr_trace_duration", 0.001,
+                  fun_name="stage_fn")      # no backend event: no count
     assert w.count - c0 == 1
     assert len(_recompile_events()) == ev0
     w.arm()
     # a burst of fresh signatures: every one counts, ONE event
-    f(np.zeros((3, 4), np.float32))
-    f(np.zeros((4, 4), np.float32))
-    f(np.zeros((5, 4), np.float32))
+    compiled()
+    compiled("other_fn")
+    compiled()
     assert w.count - c0 == 4
     evs = _recompile_events()
     assert len(evs) == ev0 + 1
-    assert evs[-1]["data"]["via"] == "wrap"
-    assert evs[-1]["data"]["label"] == "stage_fn"
-    assert evs[-1]["data"]["shapes"] == ["float32[3,4]"]
+    assert evs[-1]["data"] == {"count": w.count - 2, "program": "stage_fn"}
     # quiet >= episode_gap_s re-arms lazily: the next compile fires
     time.sleep(0.25)
-    f(np.zeros((6, 4), np.float32))
+    compiled()
     assert len(_recompile_events()) == ev0 + 2
     # disarm: counting continues, emission stops
     w.disarm()
-    f(np.zeros((7, 4), np.float32))
+    compiled()
     assert w.count - c0 == 6
     assert len(_recompile_events()) == ev0 + 2
-    assert len(calls) == 7                # wrapping never eats calls
+    # and every one of them is in the table, under its program
+    rows = w.programs()
+    assert rows["stage_fn"]["count"] == 5 and rows["other_fn"]["count"] == 1
 
 
 def test_recompile_monitoring_listener_counts_real_jit():

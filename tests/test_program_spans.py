@@ -22,8 +22,9 @@ from defer_tpu.models.gpt import gpt_tiny
 from defer_tpu.obs import (DECODE_DISPATCH_PHASES, DECODE_PHASES,
                            DECODE_STATS_PHASES, DOOR_PHASES,
                            ENGINE_DISPATCH_PHASES, ENGINE_LOOP_PHASES,
-                           ENGINE_PHASES, REGISTRY, SPAN_LAYERS,
-                           pause_watcher, recorder, span, tracer)
+                           ENGINE_PHASES, REGISTRY, SETUP_PHASES,
+                           SPAN_LAYERS, pause_watcher, recorder, span,
+                           tracer)
 from defer_tpu.obs.events import validate_event
 from defer_tpu.obs.profile import (PAUSE_MARKER, PAUSE_OVER_S,
                                    PAUSE_UNJUDGED, PAUSE_UNJUDGED_FIRST)
@@ -121,7 +122,7 @@ def test_names_come_from_the_phase_tables_and_nowhere_else(layer):
         + DECODE_STATS_PHASES,
         "engine": ENGINE_PHASES + ENGINE_DISPATCH_PHASES
         + ENGINE_LOOP_PHASES,
-        "door": DOOR_PHASES}[layer]
+        "door": DOOR_PHASES, "setup": SETUP_PHASES}[layer]
     for phase in phases:
         hist = REGISTRY.histogram(f"{prefix}.{phase}_s")
         n0 = hist.count
@@ -669,7 +670,8 @@ def test_a_profiler_session_records_the_spans_as_host_events(
         | {f"{PREFIX}engine.{p}" for p in
            ENGINE_PHASES + ENGINE_DISPATCH_PHASES + ENGINE_LOOP_PHASES
            + ("step",)} \
-        | {PREFIX + "door.admit"}
+        | {PREFIX + "door.admit"} \
+        | {PREFIX + "setup.state"}      # a generation's zero state
     # a host that stalled meanwhile may have left a pause's marker too
     assert named - {f"{PREFIX}{layer}.{PAUSE_MARKER}"
                     for layer in SPAN_LAYERS} == want
