@@ -165,11 +165,9 @@ class DecoderBlock:
         """
         slot = pos if slot is None else slot
         q, k_new, v_new = self.decode_qkv(params, x, pos)
-        cache = fmt.write_position(cache, fmt.rows(k_new, v_new), slot,
-                                   group=group)
-        return self.decode_finish(
-            params, x, fmt.attend(q, cache, slot, group=group),
-            sow=sow), cache
+        att, cache = fmt.step(q, cache, fmt.rows(k_new, v_new), slot,
+                              group=group)
+        return self.decode_finish(params, x, att, sow=sow), cache
 
     def prefill(self, params, x, cache, fmt, slot):
         """A whole prompt ``x`` [b, t, d] through the layer, its rows
@@ -411,10 +409,8 @@ class LatentBlock(DecoderBlock):
         over the rows ``<= pos``."""
         slot = pos if slot is None else slot
         q, row = self.decode_q_row(params, x, pos)
-        cache = fmt.write_position(cache, fmt.rows(row), slot, group=group)
-        return self.decode_finish(
-            params, x, fmt.attend(q, cache, slot, group=group),
-            sow=sow), cache
+        att, cache = fmt.step(q, cache, fmt.rows(row), slot, group=group)
+        return self.decode_finish(params, x, att, sow=sow), cache
 
     def prefill(self, params, x, cache, fmt, slot):
         """A whole prompt ``x`` [b, t, d] through the layer over the

@@ -150,7 +150,7 @@ DECODE_MEMORY_GAUGES = (
 #: the Pallas kernels' names in a device trace, which the benchmark's
 #: kernel readers search for (``chipbench/metrics/*_kernel_roofline.py``)
 KERNEL_NAMES = (
-    "kv_attend", "kv_write_rows", "flash_band", "flash_grouped",
+    "kv_attend", "kv_write_rows", "kv_step", "flash_band", "flash_grouped",
     "flash_latent", "latent_attend", "retention_step", "ssm_step",
     "ssm_scan", "ssd_step", "ssd_scan", "grouped_experts")
 

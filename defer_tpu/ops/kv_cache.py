@@ -47,12 +47,26 @@ and inherits the bubbles' bookkeeping from it, :class:`RingRows`).
 cheapest (docs/DECODE_CLIFF.md):
 
 * :meth:`KVCacheFormat.write_position` — one position for every
-  sequence: one ``lax.dynamic_update_slice`` a buffer;
+  sequence: one ``lax.dynamic_update_slice`` a buffer (the row-writer
+  where the positions lie on the lanes);
 * :meth:`KVCacheFormat.write_slots` — a position a sequence: the
   aliased Pallas call :func:`write_kv_rows`, which with a list of live
   sequences (:func:`live_slots`) moves their windows and no other;
 * :meth:`KVCacheFormat.write_prefix` — a whole prompt for one group:
   one relayout to head-major a prompt, then one bulk write.
+
+**The step that writes while it attends**, :meth:`KVCacheFormat.step`
+(``RingRows.step``: what a block's decode step calls, ``write_position``
+and then ``attend`` by default).  Where the positions lie on the lanes —
+float rows under a lane row, a row a position, plain buffers:
+:attr:`KVCacheFormat.writes_in_attention`, the format's geometry and
+nothing else — the least a write can move is the lane row of 128
+positions that holds its position, and the attention fetches that same
+lane row an instant later inside its block.  There a layer's step is one
+kernel, :func:`kv_step`: the attention's grid and blocks, the new rows
+put into the block that holds ``pos`` in fast memory, the lane row stored
+back through an aliased output.  Every other format keeps the two calls:
+a position's rows lie together there, and their write is a slice.
 
 **The attention**, :meth:`KVCacheFormat.attend`: one query a sequence
 over a layer's buffers *where they lie* — the Pallas kernel
@@ -86,6 +100,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.registry import REGISTRY
 from .layered import LayeredState
 
 #: what a lane row holds: the positions of a window of the row-writer,
@@ -277,7 +292,8 @@ def attend_blocks(kv: int, hd: int, length: int, itemsize: int):
     return kvb, tl
 
 
-def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale):
+def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale,
+                   writes=False):
     """One position block of one sequence's KV heads: online softmax on
     the vector unit, in f32.  A block is ``[kvb, hd, tl]`` with the
     positions on the lanes (``on_lanes``) or ``[tl, kvb, hd]``; one body
@@ -293,9 +309,22 @@ def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale):
     same with ``hd`` reduced away.  With a list two operands lead them:
     ``live_ref`` [b + 1], which the grid's first axis walks
     (:func:`_visited`), and the output's zeros, aliased to it and never
-    read."""
+    read.
+
+    ``writes`` (:func:`kv_step`; on the lanes, no list): the step's new
+    rows ``krow_ref`` / ``vrow_ref`` ``[1, kvb, 1, hd]`` follow
+    ``q_ref``, and behind ``o_ref`` come ``kwin_ref`` / ``vwin_ref``
+    ``[1, 1, kvb, hd, window]``, the lane row of positions that holds
+    ``pos`` as the buffers' aliases take it back.  The block that holds
+    ``pos`` gets the rows at lane ``pos % tl`` before anything reads
+    it."""
     del group_ref                       # the index maps read it
-    *live, q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref, acc_ref = refs
+    if writes:
+        (q_ref, krow_ref, vrow_ref, k_ref, v_ref, o_ref, kwin_ref, vwin_ref,
+         qs_ref, m_ref, l_ref, acc_ref), live = refs, ()
+    else:
+        *live, q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref, acc_ref = \
+            refs
     pa, da = (2, 1) if on_lanes else (0, 2)
     g, hd = qs_ref.shape[0], qs_ref.shape[2 if on_lanes else 3]
     t = pl.program_id(2)
@@ -328,9 +357,37 @@ def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale):
             else:
                 qs_ref[j, 0] = q_ref[0, j].astype(jnp.float32) * scale
 
+    def column(row_ref):
+        # a new row [kvb, 1, hd] as the column [kvb, hd, 1] that stands
+        # at one lane of a block; the largest of one value and -inf is
+        # that value, bit for bit
+        row = row_ref[0].astype(jnp.float32)
+        return jnp.max(jnp.where(eye, row, -jnp.inf), axis=2, keepdims=True)
+
+    def written(col, ref, win_ref, at):
+        """``ref``'s block as f32 with ``col`` at lane ``at``; the lane
+        row that holds ``at`` goes out through ``win_ref``."""
+        window = win_ref.shape[4]
+        # a block of one lane row (a buffer under 128 positions) is its
+        # own window: Mosaic wants a lane index it can see is aligned
+        first = 0 if window == tl else pl.multiple_of(
+            at // window * window, window)
+
+        def put(block, at):
+            lane = lax.broadcasted_iota(jnp.int32, (1, 1, block.shape[2]), 2)
+            return jnp.where(lane == at, col, block.astype(jnp.float32))
+
+        win_ref[0, 0] = put(ref[0, 0, :, :, pl.ds(first, window)],
+                            at - first).astype(win_ref.dtype)
+        return put(ref[0, 0], at)
+
     def accumulate(ragged):
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+        if writes and ragged:
+            k = written(column(krow_ref), k_ref, kwin_ref, pos - t * tl)
+            v = written(column(vrow_ref), v_ref, vwin_ref, pos - t * tl)
+        else:
+            k = k_ref[0, 0].astype(jnp.float32)
+            v = v_ref[0, 0].astype(jnp.float32)
 
         def live(shape):
             return t * tl + lax.broadcasted_iota(jnp.int32, shape, pa) <= pos
@@ -355,9 +412,12 @@ def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale):
 
     # a block wholly past ``pos`` is not computed (nor fetched: its
     # index map names a block that is wanted next); only the block that
-    # holds ``pos`` pays for masks
-    when((t + 1) * tl - 1 <= pos)(lambda: accumulate(False))
-    when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - 1 > pos))(
+    # holds ``pos`` pays for masks — and is the block written, so with
+    # ``writes`` a block whose last row is ``pos`` goes that way too
+    # (every position live: the masks change nothing)
+    edge = 0 if writes else 1
+    when((t + 1) * tl - edge <= pos)(lambda: accumulate(False))
+    when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - edge > pos))(
         lambda: accumulate(True))
 
     @when(t == pl.num_programs(2) - 1)
@@ -537,6 +597,40 @@ def kv_attend(q, k_buf, v_buf, pos, group, live=None):
     TensorCore on the v5e; on two, a split of the heads' axis would
     hand a core steps whose blocks another core holds).
     Jitted for the reason :func:`write_kv_rows` is."""
+    return _attend_call(q, k_buf, v_buf, pos, group, live)
+
+
+@jax.jit
+def kv_step(q, k_row, v_row, k_buf, v_buf, pos, group):
+    """A ring step's write and attention in one call, where the
+    positions lie on the lanes (``hd`` under a lane row):
+    :func:`write_kv_rows` of ``k_row`` / ``v_row`` ([b, kv, 1, hd]) at
+    ``pos`` ([b]) of group ``group`` ([1]) into ``k_buf`` / ``v_buf``
+    [groups, b, kv, L, hd], then :func:`kv_attend` of ``q`` over the
+    rows ``<= pos``, bit for bit: ``(out, k_buf, v_buf)``.  The buffers
+    alias their results: donate them.
+
+    On the lanes one position of a sequence is one lane of ``kv x hd``
+    sublane rows, and the least a DMA moves is the lane row of 128
+    positions that holds it (410 KB at gpt2-xl).  The writer fetches
+    that window, replaces a lane and stores it; the attention, the
+    layer's very next call, fetches the same window again inside the
+    block that holds ``pos``.  Here the attention's own grid and blocks
+    do both: the rows ride in beside the query (cast to the buffers'
+    type first, so the attention sees the row it would have read back),
+    the block that holds ``pos`` gets them at lane ``pos % tl`` in fast
+    memory, and its lane row of 128 positions goes back through a
+    second and third output, aliased to the buffers, whose block index
+    ``pos // 128`` is constant over a sequence's position blocks:
+    stored once a (sequence, head block), after that sequence's last
+    read.  The writer's fetch and two of a layer's three launches go
+    (docs/DECODE_CLIFF.md, "The attention").  ``kv_step`` in a device
+    trace."""
+    return _attend_call(q, k_buf, v_buf, pos, group, rows=(k_row, v_row))
+
+
+def _attend_call(q, k_buf, v_buf, pos, group, live=None, rows=None):
+    """:func:`kv_attend`, or with ``rows`` :func:`kv_step`."""
     b, d = q.shape
     groups, _, kv, length, hd = k_buf.shape
     g = d // (kv * hd)
@@ -593,28 +687,53 @@ def kv_attend(q, k_buf, v_buf, pos, group, live=None):
     # output's alias, left where it lies)
     listed = () if live is None else (live.astype(jnp.int32),
                                       jnp.zeros_like(q))
+    out_specs = pl.BlockSpec(heads, head_block)
+    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    aliases = {3: 0} if listed else {}
+    if rows is None:
+        rows = ()
+    else:
+        # the rows [b, kv, 1, hd] ride as the query does, in the
+        # buffers' type; the lane row of positions that holds ``pos``
+        # goes back into each buffer, one a (sequence, head block)
+        window = min(_LANES, length)
+
+        def window_block(i, h, t, group_ref, pos_ref):
+            return (group_ref[0], i, h, 0, pos_ref[i] // window)
+
+        rows = tuple(row.astype(k_buf.dtype) for row in rows)
+        out_specs = [out_specs] + [pl.BlockSpec(
+            (1, 1, kvb, hd, window), window_block)] * 2
+        out_shape = [out_shape] + [jax.ShapeDtypeStruct(
+            buf.shape, buf.dtype) for buf in (k_buf, v_buf)]
+        aliases = {5: 1, 6: 2}
     out = pl.pallas_call(
         functools.partial(_attend_kernel, tl=tl, on_lanes=on_lanes,
-                          scale=1.0 / math.sqrt(hd)),
+                          scale=1.0 / math.sqrt(hd), writes=bool(rows)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(listed[:1]), grid=steps,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in listed[1:]]
-            + [pl.BlockSpec(heads, head_block),
-               pl.BlockSpec(block, cache_block),
+            + [pl.BlockSpec(heads, head_block)]
+            + [pl.BlockSpec((1, kvb, 1, hd), head_block) for _ in rows]
+            + [pl.BlockSpec(block, cache_block),
                pl.BlockSpec(block, cache_block)],
-            out_specs=pl.BlockSpec(heads, head_block),
+            out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM(state, jnp.float32),
                             pltpu.VMEM(reduced, jnp.float32),
                             pltpu.VMEM(reduced, jnp.float32),
                             pltpu.VMEM(state, jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3 if listed
             else ("parallel", "parallel", "arbitrary")),
-        input_output_aliases={3: 0} if listed else {},
+        input_output_aliases=aliases,
         interpret=jax.default_backend() != "tpu",
-        name="kv_attend",
-    )(group, pos, *listed, q, k_buf, v_buf)
+        name="kv_step" if rows else "kv_attend",
+    )(group, pos, *listed, q, *rows, k_buf, v_buf)
+    if rows:
+        out, k_buf, v_buf = out
+        return (out.reshape(b, d), jnp.swapaxes(k_buf, 3, 4),
+                jnp.swapaxes(v_buf, 3, 4))
     if not on_lanes:
         out = jnp.swapaxes(out, 1, 2)
     return out.reshape(b, d)
@@ -702,6 +821,14 @@ class RingRows(LayeredState):
         if self.groups is None:
             return (), self.rows_held
         return (self.groups + 1,), self.rows_held + 1
+
+    def step(self, q, layer: dict, rows: dict, pos, group=None):
+        """A decode step's half of a layer that touches the memory:
+        ``rows`` written at ``pos`` (:meth:`write_position`), then ``q``
+        over the rows ``<= pos`` (:meth:`attend`): ``(out, layer)``.
+        Two calls, unless a format has one that does both."""
+        layer = self.write_position(layer, rows, pos, group=group)
+        return self.attend(q, layer, pos, group=group), layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -919,6 +1046,47 @@ class KVCacheFormat(RingRows):
 
     # -- attention -------------------------------------------------------
 
+    @property
+    def writes_in_attention(self) -> bool:
+        """Whether :meth:`step` is one call, :func:`kv_step`: float rows
+        with the positions on the lanes, a row a position.  Only there
+        does a row's write move a whole lane row of positions, which the
+        attention's block holds anyway; everywhere else a position's
+        rows lie together and their write is a slice.  (A ring buffer
+        on the lanes writes at ``pos % window`` and attends up to
+        ``min(pos, window - 1)``: two blocks after the first wrap.)"""
+        return (not self.quantized and not self.joined
+                and self.window is None and _on_lanes(self.head_dim))
+
+    def step(self, q, layer: dict, rows: dict, pos, group=None):
+        """:meth:`write_position` then :meth:`attend`, as one call where
+        the format :attr:`writes_in_attention`; the result is the two
+        calls' bit for bit.  A subclass that writes or attends its own
+        way is stepped through its own two calls."""
+        # ``__class__``: the class this method is defined in
+        own = (type(self).write_position is __class__.write_position
+               and type(self).attend is __class__.attend)
+        if not (own and self.writes_in_attention):
+            return super().step(q, layer, rows, pos, group)
+        REGISTRY.gauge("decode.kv.fused_layers").inc()
+        out, k_buf, v_buf = kv_step(
+            q, rows["k"], rows["v"],
+            *self._kernel_operands(layer, pos, group, q.shape[0]))
+        return out, {"k": k_buf.reshape(layer["k"].shape),
+                     "v": v_buf.reshape(layer["v"].shape)}
+
+    @staticmethod
+    def _kernel_operands(layer: dict, pos, group, b: int) -> tuple:
+        """``(k_buf, v_buf, pos, group)`` as the attention's kernels take
+        them: the buffers behind a group axis, a position a sequence,
+        the group a row of one."""
+        k_buf, v_buf = layer["k"], layer["v"]
+        if group is None:
+            k_buf, v_buf, group = k_buf[None], v_buf[None], 0
+        return (k_buf, v_buf,
+                jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,)),
+                jnp.asarray(group, jnp.int32).reshape(1))
+
     def attend(self, q, layer: dict, pos, group=None, live=None):
         """One query a sequence over ``layer``'s buffers (group
         ``group``'s sequences, where the format has groups): ``q`` [b,
@@ -942,11 +1110,8 @@ class KVCacheFormat(RingRows):
                 key: _group_slice(buf, group)[0]
                 for key, buf in layer.items()}
             return attend_einsum(q, item, pos)
-        k_buf, v_buf = layer["k"], layer["v"]
-        if group is None:
-            k_buf, v_buf, group = k_buf[None], v_buf[None], 0
-        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), q.shape[:1])
-        group = jnp.asarray(group, jnp.int32).reshape(1)
+        k_buf, v_buf, pos, group = self._kernel_operands(
+            layer, pos, group, q.shape[0])
         if self.joined:
             return kv_attend_joined(q, k_buf, v_buf, pos, group,
                                     kv=self.kv_heads)

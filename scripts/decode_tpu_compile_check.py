@@ -12,8 +12,17 @@ none.  Until PR 29 a step also cut a group's item out of every buffer
 (``slice``), and the compiled loop converted every buffer to a padded
 layout of its own and back, every dispatch (``copy``, and a temporary of
 all of them): with the attention a kernel over the buffers as they lie
-(``ops/kv_cache.py::kv_attend``) there is neither.  Run it before
-spending chip time on a change to how the ring holds its caches:
+(``ops/kv_cache.py::kv_attend``) there is neither.  Since PR 54 a
+layer's step under a lane row is one kernel that writes while it attends
+(``kv_step``): the line counts the cache kernels by name
+(``cache_kernels``; 24 / 0 / 0 at the batch cell, all four stages'
+branches at the four-chip cell: 48 / 0 / 0) and reads the gauge
+``decode.kv.fused_layers`` (a stage's layers).  Run it before spending
+chip time on a change to how the ring holds its caches — after one to
+the cache kernels also at a buffer of under 128 positions (``2 768 12 2
+64 4``, seconds: there a block is one partial lane row, and Mosaic
+refused PR 54's first ``kv_step`` for a lane slice it could not see was
+aligned, which only the chip smoke's small decoder showed):
 
     env JAX_PLATFORMS=cpu python scripts/decode_tpu_compile_check.py \\
         [layers d_model heads sequences max_len token_chunk stages]
@@ -52,6 +61,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from defer_tpu.models import gpt
+from defer_tpu.obs.registry import REGISTRY
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
 from hlo_cache_ops import computations, count_cache_ops, weight_copies
@@ -119,6 +129,11 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
                r"remat_(?:un)?compressed[\w.]* = ", text)),
            **count_cache_ops(comps, shape[1:], shape),
            "kernels": text.count('custom_call_target="tpu_custom_call"'),
+           # the cache kernels by name, as a device trace tells them
+           "cache_kernels": {name: len(re.findall(
+               rf"%{name}[.\d]* = .*tpu_custom_call", text))
+               for name in ("kv_step", "kv_attend", "kv_write_rows")},
+           "fused_layers": int(REGISTRY.gauge("decode.kv.fused_layers").value),
            "argument_bytes": mem.argument_size_in_bytes,
            "temp_bytes": mem.temp_size_in_bytes}
     dump = os.environ.get("DECODE_CHECK_DUMP")

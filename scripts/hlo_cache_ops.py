@@ -23,6 +23,10 @@ import re
 #: ``%name = f32[16,25,192,64]{...} opcode(operands), attrs``
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
                     r"([\w\-]+)\((.*)$")
+#: a custom call of several results: ``%name = (f32[..]{..}, ..)
+#: custom-call(operands), attrs`` (a kernel that attends and writes:
+#: ``ops/kv_cache.py::kv_step``)
+_CALL = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \((.*?)\) custom-call\((.*)$")
 #: opcodes that name or alias an array and move nothing
 _FREE = {"parameter", "bitcast", "get-tuple-element", "tuple", "while",
          "conditional", "call", "optimization-barrier"}
@@ -86,6 +90,18 @@ def count_cache_ops(comps: dict[str, list[str]], item_dims, buffer_dims=None,
         for line in lines:
             m = _INSTR.match(line)
             if not m:
+                # of a call's several results, those that alias an
+                # operand are written where it lies
+                call = _CALL.match(line)
+                if call and "output_to_operand_aliasing" in call[2]:
+                    # ``{{1}: (5, {}), {2}: (6, {})}``: result -> operand
+                    aliased = {int(i) for i in
+                               re.findall(r"\{(\d+)\}: \(", call[2])}
+                    shapes = re.findall(r"\w+\[([\d,]*)\]", call[1])
+                    row_writes += sum(
+                        i in aliased and bool(dims)
+                        and _dims(dims.split(",")) in (item, whole)
+                        for i, dims in enumerate(shapes))
                 continue
             op_name, _dtype, dims, opcode, rest = m.groups()
             if opcode in _FREE or not dims:

@@ -1148,9 +1148,11 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
                                                         num_stages, beam):
     """Structural guard of the decode program's scan body: every write
     into a K/V (or scale) buffer is one position wide — a
-    ``dynamic_update_slice``, or the row-writer kernel where the
-    positions lie on the lanes (float rows, ``head_dim`` under 128:
-    ``ops/kv_cache.py``) — beam search may also re-parent one whole
+    ``dynamic_update_slice``, or, where the positions lie on the lanes
+    (float rows, ``head_dim`` under 128: ``ops/kv_cache.py``), the
+    attention's own kernel, which writes the step's key and value rows
+    into the block it reads (``kv_step``; the row-writer kernel until
+    PR 54) — beam search may also re-parent one whole
     group, and no value has the shape of the stack of all local blocks'
     caches — the shape whose whole-stack copies were 92% of a step on
     the chip (docs/DECODE_CLIFF.md)."""
@@ -1167,10 +1169,11 @@ def test_decode_step_writes_rows_into_per_block_buffers(model, kv_cache,
     for eqn in _walk(body):
         for v in list(eqn.invars) + list(eqn.outvars):
             assert getattr(v.aval, "shape", None) not in stacked, eqn
-        if eqn.primitive.name == "pallas_call" \
-                and eqn.params["name"] == "kv_write_rows":
-            assert kv_cache == "buffer"
-            rows += 1
+        if eqn.primitive.name == "pallas_call":
+            assert eqn.params["name"] != "kv_write_rows"
+            if eqn.params["name"] == "kv_step":
+                assert kv_cache == "buffer"
+                rows += 2               # a layer's key and value rows
         if eqn.primitive.name != "dynamic_update_slice":
             continue
         buf, upd = eqn.invars[0].aval.shape, eqn.invars[1].aval.shape

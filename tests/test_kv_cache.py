@@ -232,6 +232,212 @@ def test_kv_attend_of_a_bubble_step_is_finite():
     assert np.isfinite(np.asarray(got, np.float32)).all()
 
 
+# -- the step that writes while it attends -------------------------------------------
+
+#: positions of a sequence of 650 whose blocks hold 256: a block's first
+#: row, its last (the block is full *and* holds ``pos``), either lane row
+#: of a block, the first and the last position, and a bubble's scratch row
+_STEP_LENGTH = 650
+_STEP_POSITIONS = {"first": 0, "block-first": 256, "block-last": 255,
+                   "first-half": 300, "second-half": 450, "last": 649,
+                   "scratch": 650}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _step_case(monkeypatch, request, hd, dtype, g, groups, seed=0):
+    """:func:`_attend_case` over 650 positions in blocks of 256 (the
+    shape is this section's own: the kernels are traced once a shape,
+    whatever ``_BLOCK_BYTES`` then says), and a step's new rows."""
+    size = jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(kv_cache, "_BLOCK_BYTES", 2 * hd * 256 * size)
+    attend_blocks.cache_clear()         # sizes are reckoned once a shape
+    request.addfinalizer(attend_blocks.cache_clear)
+    fmt, layer, q = _attend_case(hd, dtype, g, groups, _STEP_LENGTH,
+                                 batch=3, seed=seed)
+    assert attend_blocks(2, hd, _STEP_LENGTH + (groups is not None),
+                         size) == (2, 256)
+    rng = np.random.default_rng(seed + 1)
+    rows = fmt.rows(*(jnp.asarray(rng.standard_normal((3, 2 * hd)),
+                                  jnp.float32) for _ in range(2)))
+    return fmt, layer, q, rows
+
+
+def _assert_step_is_the_two_calls(fmt, q, layer, rows, pos, group):
+    """``fmt.step`` against ``write_position`` then ``attend`` from the
+    same buffers: the output and every buffer as bits.  Returns the
+    step's ``(out, layer)``."""
+    wrote = fmt.write_position(layer, rows, pos, group=group)
+    want = fmt.attend(q, wrote, pos, group=group)
+    got, stepped = fmt.step(q, layer, rows, pos, group=group)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert set(stepped) == set(layer)
+    for key, buf in wrote.items():
+        assert stepped[key].dtype == buf.dtype
+        np.testing.assert_array_equal(_bits(stepped[key]), _bits(buf))
+    return got, stepped
+
+
+@pytest.mark.parametrize("groups,at", [
+    (groups, at) for groups in (None, 2) for at in _STEP_POSITIONS
+    if groups or at != "scratch"])      # slots alone have no scratch row
+@pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hd", [32, 64])
+def test_step_is_write_position_then_attend_bit_for_bit(
+        monkeypatch, request, hd, dtype, g, groups, at):
+    """Under a lane row a step is one kernel (``kv_step``), and it gives
+    what the two calls give: the output and every buffer, bit for bit —
+    the rows are float32 and the buffers' type rounds them before the
+    attention reads them, in both."""
+    fmt, layer, q, rows = _step_case(monkeypatch, request, hd, dtype, g,
+                                     groups)
+    assert fmt.writes_in_attention
+    pos = jnp.int32(_STEP_POSITIONS[at])
+    group = None if groups is None else jnp.int32(1)
+    got, _ = _assert_step_is_the_two_calls(fmt, q, layer, rows, pos, group)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    # and it is one kernel: neither of the two calls' is reached
+    monkeypatch.setattr(kv_cache, "write_kv_rows", None)
+    monkeypatch.setattr(kv_cache, "kv_attend", None)
+    fmt.step(q, layer, rows, pos, group=group)
+
+
+def test_a_bubble_step_touches_the_scratch_row_of_its_group_only(
+        monkeypatch, request):
+    """A bubble writes the scratch row and attends at it: numbers nobody
+    reads, but numbers, and no other row or group moves."""
+    fmt, layer, q, rows = _step_case(monkeypatch, request, 64, jnp.bfloat16,
+                                     1, 2)
+    layer = {key: jnp.nan_to_num(buf) for key, buf in layer.items()}
+    got, stepped = fmt.step(q, layer, rows, jnp.int32(fmt.scratch_position),
+                            group=jnp.int32(0))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    for key, buf in layer.items():
+        want = np.asarray(buf).copy()
+        want[0, :, :, fmt.scratch_position] = np.asarray(
+            rows[key].astype(buf.dtype))[:, :, 0]
+        np.testing.assert_array_equal(_bits(stepped[key]), _bits(want))
+
+
+def test_step_over_blocks_of_heads(monkeypatch, request):
+    """Two blocks of heads a sequence: each stores its own heads' lane
+    row, once."""
+    monkeypatch.setattr(kv_cache, "_BLOCK_BYTES", 64 * 128 * 4)
+    attend_blocks.cache_clear()         # sizes are reckoned once a shape
+    request.addfinalizer(attend_blocks.cache_clear)
+    assert attend_blocks(2, 64, 421, 4) == (1, 128)
+    fmt, layer, q = _attend_case(64, jnp.float32, 2, 2, 420, batch=3)
+    rng = np.random.default_rng(5)
+    rows = fmt.rows(*(jnp.asarray(rng.standard_normal((3, 2 * 64)),
+                                  jnp.float32) for _ in range(2)))
+    for pos in (0, 127, 200, 419, 420):
+        _assert_step_is_the_two_calls(fmt, q, layer, rows, jnp.int32(pos),
+                                      jnp.int32(0))
+
+
+class _WritesItsOwnWay(KVCacheFormat):
+    """On the lanes by its geometry, but not the base's writer."""
+
+    def write_position(self, layer, rows, pos, group=None):
+        return super().write_position(layer, rows, pos, group)
+
+
+@pytest.mark.parametrize("fmt", [
+    KVCacheFormat(2, 128, 40, jnp.float32, groups=2),
+    KVCacheFormat(2, 64, 40, jnp.float32, quantized=True, groups=2),
+    KVCacheFormat(2, 128, 40, jnp.bfloat16, groups=2, query_group=8),
+    KVCacheFormat(2, 64, 40, jnp.float32, groups=2, window=16),
+    _WritesItsOwnWay(2, 64, 40, jnp.float32, groups=2),
+], ids=["lane-rows", "int8", "joined", "ring-buffer", "subclass"])
+def test_a_format_off_the_lanes_steps_through_its_two_calls(monkeypatch,
+                                                            fmt):
+    """Heads of a lane row, int8 rows, joined rows, a ring buffer: a
+    position's rows lie together (or land in another block than the
+    last live one), there is nothing to fuse, and ``step`` is the write
+    and then the attention over what it wrote — the calls the blocks
+    made themselves until PR 54.  So is a subclass's whose write is its
+    own."""
+    assert fmt.writes_in_attention == isinstance(fmt, _WritesItsOwnWay)
+    rng = np.random.default_rng(3)
+    layer = {key: jnp.asarray(rng.integers(-9, 9, s.shape), s.dtype)
+             for key, s in fmt.buffers(3).items()}
+    width = fmt.kv_heads * fmt.head_dim
+    q = jnp.asarray(rng.standard_normal((3, width * fmt.query_group)),
+                    fmt.dtype)
+    rows = fmt.rows(*(jnp.asarray(rng.standard_normal((3, width)), fmt.dtype)
+                      for _ in range(2)))
+    calls = []
+
+    def spy(name):
+        real = getattr(KVCacheFormat, name)
+
+        def call(self, *args, **kwargs):
+            calls.append((name, args, kwargs))
+            return real(self, *args, **kwargs)
+        monkeypatch.setattr(KVCacheFormat, name, call)
+
+    spy("write_position")
+    spy("attend")
+    monkeypatch.setattr(kv_cache, "kv_step", None)       # never reached
+    pos, group = jnp.int32(5), jnp.int32(1)
+    got, stepped = fmt.step(q, layer, rows, pos, group=group)
+    assert [name for name, _, _ in calls] == ["write_position", "attend"]
+    (_, wrote, _), (_, read, _) = calls
+    assert wrote[0] is layer and wrote[1] is rows and wrote[2] is pos
+    assert read[0] is q and read[1] is stepped and read[2] is pos
+    monkeypatch.undo()
+    want = fmt.attend(q, fmt.write_position(layer, rows, pos, group=group),
+                      pos, group=group)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def _ring_step_jaxpr(model, num_stages, **kw):
+    """A ring decoder of ``gpt_tiny`` (3 sequences a group, 24
+    positions) and the jaxpr of its 4-step decode program."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=num_stages,
+                           microbatch=3, max_len=24, **kw)
+    a, caches = dec._init_state()
+    i32 = jnp.int32(0)
+    return dec, jax.make_jaxpr(dec._get_decode_fn(4, False, None))(
+        dec._w, jnp.zeros((num_stages, 3, 5), jnp.int32), i32, i32, i32,
+        jnp.uint32(0), jnp.float32(0), jnp.zeros((num_stages, 3), jnp.int32),
+        i32, i32, a, caches)
+
+
+def _kernel_names(jaxpr) -> list:
+    """The ``pallas_call`` names of a jaxpr, at any depth, in order."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernel_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("num_stages,kv", [(1, "buffer"), (2, "buffer"),
+                                           (1, "int8")])
+def test_a_ring_step_holds_one_cache_kernel_a_layer(model, num_stages, kv):
+    """The lowered GPT ring step: one ``kv_step`` a layer, no
+    ``kv_write_rows`` and no ``kv_attend``, and the gauge
+    ``decode.kv.fused_layers`` reads a stage's layers; int8 rows take
+    the format's two calls (einsums, no kernel) and the gauge reads 0."""
+    from defer_tpu.obs.registry import REGISTRY
+    REGISTRY.gauge("decode.kv.fused_layers").set(-1)
+    dec, jaxpr = _ring_step_jaxpr(model, num_stages, kv_cache=kv)
+    fused = dec.l_max if kv == "buffer" else 0
+    assert _kernel_names(jaxpr.jaxpr) == ["kv_step"] * (fused * num_stages)
+    assert REGISTRY.gauge("decode.kv.fused_layers").value == fused
+
+
 @pytest.mark.parametrize("kv,hd,length,itemsize,want", [
     (25, 64, 769, 2, (25, 256)),     # gpt2-xl's ring: 0.8 MB a block
     (25, 64, 192, 4, (25, 128)),     # the engine's f32 slots
@@ -261,28 +467,24 @@ def _cache_slices(jaxpr, item):
 def test_a_step_cuts_no_item_out_of_a_cache_buffer(model, engine):
     """Neither engine's step holds a ``slice`` / ``dynamic_slice`` that
     produces an array of one group's (one batch of slots') size: the
-    attention reads the buffers where they lie, once a layer."""
+    attention reads the buffers where they lie, once a layer (the
+    ring's writes the step's rows too: ``kv_step``)."""
     graph, params = model
     if engine == "ring":
-        dec = PipelinedDecoder(graph, params, num_stages=2, microbatch=3,
-                               max_len=24)
-        a, caches = dec._init_state()
-        i32 = jnp.int32(0)
-        jaxpr = jax.make_jaxpr(dec._get_decode_fn(4, False, None))(
-            dec._w, jnp.zeros((2, 3, 5), jnp.int32), i32, i32, i32,
-            jnp.uint32(0), jnp.float32(0), jnp.zeros((2, 3), jnp.int32),
-            i32, i32, a, caches)
+        dec, jaxpr = _ring_step_jaxpr(model, 2)
         shape = dec.state_format.buffers(3)["k"].shape[1:]
         layers = dec.l_max * 2          # a call a block and a stage
+        kernel = "kv_step"
     else:
         eng = ContinuousBatchEngine(graph, params, num_stages=2, width=3)
         jaxpr = jax.make_jaxpr(eng._step_fn(False))(
             eng.params, eng._caches, eng._prev_ids, *eng._blank_rows())
         shape = eng.kv_format.buffers(3)["k"].shape
         layers = len(eng._caches["k"])
+        kernel = "kv_attend"
     assert _cache_slices(jaxpr.jaxpr, sorted(d for d in shape if d != 1)) == []
-    assert str(jaxpr).count("name=kv_attend") >= 1
-    assert str(jaxpr).count("kv_attend") >= layers
+    assert str(jaxpr).count(f"name={kernel}") >= 1
+    assert str(jaxpr).count(kernel) >= layers
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["buffer", "int8"])

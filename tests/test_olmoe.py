@@ -596,9 +596,12 @@ def test_the_serving_engine_refuses_the_block_by_name(model):
 #: are PR 26's still.  PR 44 did: every leaf of every node is such an
 #: argument, ``final_ln``'s with the rest, and the ring holds no flat
 #: row (``4c8596d40d6c1428`` / ``7ddd12dc4515bd7c`` before it); the
-#: tokens are PR 26's still.
+#: tokens are PR 26's still.  PR 54 did: under a lane row a layer's row
+#: write rides the attention's block, one kernel ``kv_step`` where
+#: ``kv_write_rows`` twice and ``kv_attend`` stood (``1c5e166cd6dcff82``
+#: / ``dcf5442ff4e733bd`` before it); the tokens are PR 26's still.
 PARENT_TOKENS_SHA = "0fef1cc65e752cd8"
-PARENT_DECODE_SHA = {1: "1c5e166cd6dcff82", 2: "dcf5442ff4e733bd"}
+PARENT_DECODE_SHA = {1: "415f9fbfbdf516ad", 2: "f6dd70ad3a5dd88d"}
 
 
 def _sha(text: str) -> str:
@@ -610,7 +613,7 @@ def test_gpt_tiny_decodes_as_on_the_parent(num_stages):
     """``decode_qkv`` takes a position and ``decode_finish`` a ``sow``,
     and the cache's half of a step lives in ``ops/kv_cache.py``: the
     GPT family ignores the first two, and its ring still lowers to the
-    text recorded (PR 44's) and gives d5480a9's tokens, bit for bit."""
+    text recorded (PR 54's) and gives d5480a9's tokens, bit for bit."""
     graph = gpt_tiny(seq_len=32)
     params = graph.init(jax.random.key(0))
     n, mb = num_stages, 8 // num_stages
