@@ -22,9 +22,9 @@ score is ``[q~[i], q_r[i]] . [c, k_r]``, the heads' outputs
 and ``o[i] = o~[i] W_uv[i]`` — the same numbers up to rounding.
 
 **RoPE** turns adjacent pairs (the checkpoint's order) by YaRN's
-frequencies (:func:`yarn_inv_freq`), computed once in float32 when the
-graph is built; ``softmax_scale`` is ``(nope + rope) ** -0.5 * m ** 2``,
-``m = 0.1 * mscale_all_dim * ln(factor) + 1`` (:func:`yarn_softmax_scale`).
+frequencies (``models/rotary.py::yarn_inv_freq``), computed once in
+float32 when the graph is built; ``softmax_scale`` is ``(nope + rope)
+** -0.5 * m ** 2``, ``m = 0.1 * mscale_all_dim * ln(factor) + 1`` (:func:`yarn_softmax_scale`).
 
 **Routing** (``topk_method`` ``noaux_tc`` with one group): sigmoid scores
 ``p`` over all experts, the ``k`` largest of ``p + b`` (``b``, the
@@ -48,7 +48,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
 from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch_held,
@@ -56,6 +55,7 @@ from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch_held,
 from .cohere_moe import rope_interleaved
 from .decoder import LatentBlock
 from .olmoe import OlmoeEmbedding
+from .rotary import yarn_attention_factor, yarn_inv_freq
 
 
 #: the spread of a seeded balancing bias (a checkpoint's is trained):
@@ -72,31 +72,11 @@ def _normal(key, shape, fan_in: int):
     return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
 
 
-def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
-                  beta_fast: float = 32.0, beta_slow: float = 1.0) -> tuple:
-    """YaRN's ``dim / 2`` frequencies, float32: pair ``j`` keeps ``e_j =
-    theta ** (-2j / dim)`` below ``lo``, turns ``factor`` times slower
-    from ``hi`` on, and ramps linearly between — ``lo`` / ``hi`` the
-    pairs that make ``beta_fast`` / ``beta_slow`` turns over the
-    ``original`` positions."""
-    def pair(turns):
-        return dim * math.log(original / (2 * math.pi * turns)) \
-            / (2 * math.log(theta))
-
-    lo = max(math.floor(pair(beta_fast)), 0)
-    hi = min(math.ceil(pair(beta_slow)), dim // 2 - 1)
-    j = np.arange(dim // 2, dtype=np.float32)
-    e = np.float32(theta) ** (-2 * j / np.float32(dim))
-    ramp = np.clip((j - lo) / max(hi - lo, 1e-3), 0, 1).astype(np.float32)
-    return tuple(float(f) for f in
-                 (e * (1 - ramp) + e / np.float32(factor) * ramp))
-
-
 def yarn_softmax_scale(width: int, factor: float,
                        mscale_all_dim: float = 1.0) -> float:
     """What a score is multiplied by under YaRN: ``width ** -0.5 * m **
     2``, ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
-    m = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    m = yarn_attention_factor(factor, mscale_all_dim)
     return width ** -0.5 * m * m
 
 
@@ -111,7 +91,7 @@ class _KimiBlock(LatentBlock, Op):
     nope_dim: int
     rope_dim: int
     v_dim: int
-    #: the rotation's frequencies a pair (:func:`yarn_inv_freq`)
+    #: the rotation's frequencies a pair (``rotary.yarn_inv_freq``)
     rope_freqs: tuple
     softmax_scale: float
     rms_eps: float = 1e-5

@@ -28,14 +28,22 @@ from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch,
 from .decoder import DecoderBlock
 
 
-def rope(x, pos, theta: float):
+def rope(x, pos, theta: float, inv_freq=None, scale: float = 1.0):
     """Rotate-half RoPE over the whole head: ``x`` [..., t, nh, hd] at
-    positions ``pos`` [t] (angles in float32)."""
+    positions ``pos`` [t] (angles in float32), pair ``(j, j + hd / 2)``
+    turned by ``pos * theta ** (-2j / hd)`` — or by ``pos *
+    inv_freq[j]`` where a family scales its frequencies (``inv_freq``
+    [hd / 2]; ``theta`` is then not read).  ``scale`` multiplies both
+    ``cos`` and ``sin`` (YaRN's attention factor: a rotated key carries
+    it into the cache)."""
     hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]      # [t, hd/2]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., : hd // 2], xf[..., hd // 2:]
     rot = jnp.concatenate([-x2, x1], axis=-1)
@@ -56,6 +64,11 @@ class OlmoeBlock(DecoderBlock, Op):
     attn_impl: str = "auto"
 
     decode_stats = ("moe.assignments", "moe.experts_hit", "moe.load_max")
+    #: the router's rule (``graph/ops.py::route_top_k``), and the leaves
+    #: :meth:`_qkv` reads: what a family on this block's routed tail
+    #: (``models/mellum.py``) says of itself instead of copying the tail
+    scoring = "softmax"
+    qkv_leaves = ("ln1", "q", "q_norm", "k", "k_norm", "v")
 
     @property
     def kv_heads(self) -> int:
@@ -104,7 +117,7 @@ class OlmoeBlock(DecoderBlock, Op):
 
     def _finish(self, p, x, y, sow=None):
         """The rest of a layer after attention: ``x`` [T, d] the residual
-        stream, ``y`` [T, d] the attention's heads merged.  Output
+        stream, ``y`` [T, heads * hd] the attention's heads merged.  Output
         projection, then the routed experts, each added to the stream in
         float32; the stream is rounded to its own type once, on the way
         out (rounded after each add, bfloat16 moved near-tied logits
@@ -117,7 +130,7 @@ class OlmoeBlock(DecoderBlock, Op):
         # would flip the last of the chosen experts at near-ties
         eid, gate = route_top_k(
             jnp.dot(h, p["router"]["w"], preferred_element_type=f32),
-            self.experts_per_tok)
+            self.experts_per_tok, scoring=self.scoring)
 
         out, sizes = expert_dispatch(
             h, eid, gate, self.num_experts,
@@ -136,28 +149,28 @@ class OlmoeBlock(DecoderBlock, Op):
 
     def apply_with_kv(self, params, x, sow=None):
         """Full-sequence forward on ``x`` [b, t, d]; also returns the key
-        (normed and rotated) and value columns [b, t, nh*hd] that
+        (normed and rotated) and value columns [b, t, kv*hd] that
         :meth:`decode_qkv` would have handed over row by row.  A dict
         ``sow`` is filled as :meth:`decode_finish` fills it, over all
         b*t rows, and with their chosen experts under ``moe.chosen``."""
         p = _cast(params, x.dtype)
         b, t, d = x.shape
         q, k, v = self._qkv(p, x, jnp.arange(t))
-        y = self._attend(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)))
+        y = self._attend(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                         window=self.window)
         x = self._finish(p, x.reshape(b * t, d),
-                         y.transpose(0, 2, 1, 3).reshape(b * t, d), sow)
-        return x.reshape(b, t, d), k.reshape(b, t, d), v.reshape(b, t, d)
+                         y.transpose(0, 2, 1, 3).reshape(b * t, -1), sow)
+        return x.reshape(b, t, d), k.reshape(b, t, -1), v.reshape(b, t, -1)
 
     # -- one token against the cache --------------------------------------
 
     def decode_qkv(self, params, x, pos):
         """Query and new key and value columns of ``x`` [b, d] at scalar
         ``pos``."""
-        p = _cast({nm: params[nm] for nm in
-                   ("ln1", "q", "q_norm", "k", "k_norm", "v")}, x.dtype)
-        b, d = x.shape
+        p = _cast({nm: params[nm] for nm in self.qkv_leaves}, x.dtype)
+        b = x.shape[0]
         q, k, v = self._qkv(p, x[:, None], jnp.reshape(pos, (1,)))
-        return q.reshape(b, d), k.reshape(b, d), v.reshape(b, d)
+        return q.reshape(b, -1), k.reshape(b, -1), v.reshape(b, -1)
 
     def decode_finish(self, params, x, y, sow=None):
         """The output projection of the attention's output ``y`` and the

@@ -499,8 +499,9 @@ def joined_block_rows(kv: int, hd: int, length: int, itemsize: int) -> int:
                            // _LANES * _LANES))
 
 
-@functools.partial(jax.jit, static_argnames=("kv",))
-def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int):
+@functools.partial(jax.jit, static_argnames=("kv", "name"))
+def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
+                     name: str = "kv_attend"):
     """:func:`kv_attend` over *joined* buffers ``[groups, b, L, kv *
     hd]`` (:attr:`KVCacheFormat.joined`: a position's rows of all KV
     heads side by side on the lanes), for a query group that fills the
@@ -510,7 +511,8 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int):
     a block past ``pos[i]`` is neither fetched nor computed, and each
     KV head's ``[positions, hd]`` is a lane-aligned slice of it — the
     operand the products want.  The same name in a device trace as
-    :func:`kv_attend`."""
+    :func:`kv_attend`, and like it ``name`` where a format names its
+    kernels (:attr:`KVCacheFormat.kernel_suffix`)."""
     b, d = q.shape
     groups, _, length, width = k_buf.shape
     hd = width // kv
@@ -546,13 +548,14 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=jax.default_backend() != "tpu",
-        name="kv_attend",
+        name=name,
     )(group, pos, q.reshape(b, heads, hd), k_buf, v_buf)
     return out.reshape(b, d)
 
 
-@jax.jit
-def kv_attend(q, k_buf, v_buf, pos, group, live=None):
+@functools.partial(jax.jit, static_argnames=("name",))
+def kv_attend(q, k_buf, v_buf, pos, group, live=None, *,
+              name: str = "kv_attend"):
     """One query a sequence over its live rows: ``q`` [b, heads * hd]
     against ``k_buf`` / ``v_buf`` [groups, b, kv, L, hd] as they are
     stored, sequence ``i`` of group ``group`` [1] over its positions
@@ -596,12 +599,14 @@ def kv_attend(q, k_buf, v_buf, pos, group, live=None):
     first axis is then ``"arbitrary"``, and the heads' with it (one
     TensorCore on the v5e; on two, a split of the heads' axis would
     hand a core steps whose blocks another core holds).
-    Jitted for the reason :func:`write_kv_rows` is."""
-    return _attend_call(q, k_buf, v_buf, pos, group, live)
+    Jitted for the reason :func:`write_kv_rows` is.  ``name`` is the
+    call's in a device trace."""
+    return _attend_call(q, k_buf, v_buf, pos, group, live, name=name)
 
 
-@jax.jit
-def kv_step(q, k_row, v_row, k_buf, v_buf, pos, group):
+@functools.partial(jax.jit, static_argnames=("name",))
+def kv_step(q, k_row, v_row, k_buf, v_buf, pos, group, *,
+            name: str = "kv_step"):
     """A ring step's write and attention in one call, where the
     positions lie on the lanes (``hd`` under a lane row):
     :func:`write_kv_rows` of ``k_row`` / ``v_row`` ([b, kv, 1, hd]) at
@@ -624,12 +629,14 @@ def kv_step(q, k_row, v_row, k_buf, v_buf, pos, group):
     ``pos // 128`` is constant over a sequence's position blocks:
     stored once a (sequence, head block), after that sequence's last
     read.  The writer's fetch and two of a layer's three launches go
-    (docs/DECODE_CLIFF.md, "The attention").  ``kv_step`` in a device
-    trace."""
-    return _attend_call(q, k_buf, v_buf, pos, group, rows=(k_row, v_row))
+    (docs/DECODE_CLIFF.md, "The attention").  ``kv_step`` (``name``) in
+    a device trace."""
+    return _attend_call(q, k_buf, v_buf, pos, group, rows=(k_row, v_row),
+                        name=name)
 
 
-def _attend_call(q, k_buf, v_buf, pos, group, live=None, rows=None):
+def _attend_call(q, k_buf, v_buf, pos, group, live=None, rows=None, *,
+                 name: str):
     """:func:`kv_attend`, or with ``rows`` :func:`kv_step`."""
     b, d = q.shape
     groups, _, kv, length, hd = k_buf.shape
@@ -728,7 +735,7 @@ def _attend_call(q, k_buf, v_buf, pos, group, live=None, rows=None):
             else ("parallel", "parallel", "arbitrary")),
         input_output_aliases=aliases,
         interpret=jax.default_backend() != "tpu",
-        name="kv_step" if rows else "kv_attend",
+        name=name,
     )(group, pos, *listed, q, *rows, k_buf, v_buf)
     if rows:
         out, k_buf, v_buf = out
@@ -852,6 +859,10 @@ class KVCacheFormat(RingRows):
     window: int | None = None
     #: queries that read one KV head (what decides :attr:`joined`)
     query_group: int = 1
+    #: appended to the names of this layer's attention kernels
+    #: (``kv_attend`` / ``kv_step``) in a device trace: a family whose
+    #: layers are of several kinds tells them apart by it
+    kernel_suffix: str = ""
 
     @property
     def joined(self) -> bool:
@@ -1071,7 +1082,8 @@ class KVCacheFormat(RingRows):
         REGISTRY.gauge("decode.kv.fused_layers").inc()
         out, k_buf, v_buf = kv_step(
             q, rows["k"], rows["v"],
-            *self._kernel_operands(layer, pos, group, q.shape[0]))
+            *self._kernel_operands(layer, pos, group, q.shape[0]),
+            name="kv_step" + self.kernel_suffix)
         return out, {"k": k_buf.reshape(layer["k"].shape),
                      "v": v_buf.reshape(layer["v"].shape)}
 
@@ -1112,7 +1124,8 @@ class KVCacheFormat(RingRows):
             return attend_einsum(q, item, pos)
         k_buf, v_buf, pos, group = self._kernel_operands(
             layer, pos, group, q.shape[0])
+        name = "kv_attend" + self.kernel_suffix
         if self.joined:
             return kv_attend_joined(q, k_buf, v_buf, pos, group,
-                                    kv=self.kv_heads)
-        return kv_attend(q, k_buf, v_buf, pos, group, live)
+                                    kv=self.kv_heads, name=name)
+        return kv_attend(q, k_buf, v_buf, pos, group, live, name=name)

@@ -314,6 +314,17 @@ class PipelinedDecoder:
             REGISTRY.gauge("decode.cache.window_positions").set(max(
                 (fmt.window or 0 for k, fmt, _key, _size in sizes
                  if k == "kv_cache"), default=0))
+        #: where some layer keeps a ring buffer: of every block that
+        #: keeps a KV cache, over all stages, how many rows of a sequence
+        #: its attention reads at most (the window; None: every position
+        #: so far).  Empty where no format has a window: the rows a step
+        #: reads are then one kind's, and no gauge tells kinds apart
+        self._kv_reach = tuple(
+            self.state_formats[l].window
+            for names in self.stage_blocks for l, nm in enumerate(names)
+            if nodes[nm].op.memory == "kv_cache")
+        if all(w is None for w in self._kv_reach):
+            self._kv_reach = ()
         if "latent_cache" in kinds:
             # the latent layers' buffers as they are laid out, and the
             # rows (of a layer, a sequence, a group) those bytes are:
@@ -1099,6 +1110,22 @@ class PipelinedDecoder:
                 seed=seed, eos_id=eos_id, token_chunk=token_chunk,
                 prefill=prefill, on_tokens=on_tokens)
 
+    def _post_rows_read(self, rows: int, positions: int) -> None:
+        """Set ``decode.cache.full_rows_read`` / ``.window_rows_read``:
+        the cached rows the attention of the newest step read, over its
+        ``rows`` sequences and the layers of each kind — ``positions``
+        of a sequence in a layer that keeps every position, the window's
+        at most in a ring buffer.  From positions and shapes: host
+        integers, no device work; nothing where no layer has a window."""
+        if not self._kv_reach:
+            return
+        full = sum(w is None for w in self._kv_reach)
+        REGISTRY.gauge("decode.cache.full_rows_read").set(
+            rows * full * positions)
+        REGISTRY.gauge("decode.cache.window_rows_read").set(
+            rows * sum(min(positions, w) for w in self._kv_reach
+                       if w is not None))
+
     def _post_stats(self, sums: np.ndarray) -> None:
         """Add ``sums``, what the blocks sowed
         (``DecoderBlock.decode_stats``) in the chunks a generation read,
@@ -1237,6 +1264,9 @@ class PipelinedDecoder:
                         (t0 + chunk_steps - 1 - (n - 1) - g) // n + 1
                         for g in range(n))
                     p_avail = min(p_avail, t_tok - 1)
+                    # the step that made position p_avail read the
+                    # p_avail rows before it
+                    self._post_rows_read(b, p_avail)
                     new = None
                     if on_tokens is not None and p_avail > p_done \
                             and p_avail >= plen:

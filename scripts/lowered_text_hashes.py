@@ -10,7 +10,8 @@ several; ``olmoe_tiny``'s widths at four layers for four stages;
 memory in one graph, a period a stage; ``granite_hybrid_tiny``, the
 same with a state of heads and routed experts in every layer;
 ``kimi_k2_tiny``, a latent cache, and at two stages a dense block at
-the place of the other stage's routed one) and
+the place of the other stage's routed one; ``mellum_tiny``, two
+rotations by layer kind) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -39,7 +40,7 @@ import jax.numpy as jnp
 
 from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
                               granite_hybrid_tiny, gpt_tiny, jamba_tiny,
-                              kimi_k2_tiny, olmoe, olmoe_tiny)
+                              kimi_k2_tiny, mellum_tiny, olmoe, olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -69,6 +70,9 @@ def ring_configurations():
     # a latent cache has neither int8 rows nor beams; two stages put the
     # dense block beside a routed one
     yield "kimi_k2_tiny", kimi_k2_tiny(), (1, 2), *plain
+    # two rotations by layer kind, ring buffers beside full layers'
+    # caches, kernels named by kind: one period a stage
+    yield "mellum_tiny", mellum_tiny(), (1, 2), *every
 
 
 def ring_programs(name, graph, stages, kv_caches, beams):

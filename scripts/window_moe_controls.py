@@ -32,6 +32,48 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 
+def rounded_reference_control(ref, ref_args: dict, params, ids, *,
+                              num_experts: int, judged_from: int = 1,
+                              inputs: str = "float8_e4m3fn") -> dict:
+    """The plain reference against itself with every product's operands
+    rounded to ``inputs`` (``float8_e4m3fn``: the nearest precision
+    below the cells' bfloat16), on ``ids`` [b, t]: the low-precision
+    run *in the program's place* of ``check`` (a).  Both runs are
+    teacher-forced on ``ids``, so every position is judged by itself,
+    as ``chipbench/agreement.py::logit_gaps`` judges a program's: the
+    token the rounded run would emit after ``ids[:, :p]`` is its argmax
+    there, and its gap is how far the float32 run's logit of that token
+    sits under the float32 run's best, over that position's spread (max
+    - mean) — ``logit_gaps``'s measure, for the tokens at positions
+    ``judged_from .. t`` (a cell's generated ones, behind its prompt).
+    And check (b)'s: the share of the float32 run's expert choices the
+    rounded run makes, over all ``t`` positions, by layer."""
+    import jax.numpy as jnp
+
+    kw = dict(ref_args, experts=True, lo=judged_from - 1)
+    hi, hi_chosen = ref.logits(params, ids, **kw)
+    lo, lo_chosen = ref.logits(params, ids, inputs=getattr(jnp, inputs), **kw)
+    hi, lo = np.asarray(hi), np.asarray(lo)
+    picked = np.take_along_axis(hi, lo.argmax(-1)[..., None], -1)[..., 0]
+    best = hi.max(-1)
+    gaps = (best - picked) / np.maximum(best - hi.mean(-1), 1e-6)
+
+    def chose(c):
+        hot = np.zeros(c.shape[:-1] + (num_experts,), bool)
+        np.put_along_axis(hot, np.asarray(c), True, -1)
+        return hot
+
+    agree = [float((chose(a) & chose(b)).sum() / np.asarray(a).size)
+             for a, b in zip(hi_chosen, lo_chosen)]
+    label = inputs.split("_")[0]                # float8, bfloat16
+    return {"tokens": list(ids.shape), "judged_from": judged_from,
+            f"{label}_worst_logit_gap_share": float(gaps.max()),
+            f"{label}_exact_argmax_share": float((gaps <= 0).mean()),
+            f"{label}_router_agreement_share": min(agree),
+            f"{label}_router_agreement_by_layer": agree,
+            "logit_spread_mean": float((best - hi.mean(-1)).mean())}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", action="store_true")
@@ -72,27 +114,9 @@ def main() -> int:
                 tr["compute_dtype"]), cfg.get("init_gain", {}))
             ids = np.random.default_rng(seed).integers(
                 0, args["vocab"], (1, opts.tokens)).astype(np.int32)
-            kw = dict(cfg["reference"]["args"], experts=True)
-            hi, hi_chosen = ref.logits(params, ids, **kw)
-            lo, lo_chosen = ref.logits(params, ids, inputs=jnp.float8_e4m3fn,
-                                       **kw)
-            hi, lo = np.asarray(hi), np.asarray(lo)
-            picked = np.take_along_axis(hi, lo.argmax(-1)[..., None],
-                                        -1)[..., 0]
-            best = hi.max(-1)
-            gaps = (best - picked) / np.maximum(best - hi.mean(-1), 1e-6)
-
-            def chose(c):
-                hot = np.zeros(c.shape[:-1] + (args["num_experts"],), bool)
-                np.put_along_axis(hot, np.asarray(c), True, -1)
-                return hot
-
-            agree = [float((chose(a) & chose(b)).sum() / np.asarray(a).size)
-                     for a, b in zip(hi_chosen, lo_chosen)]
-            row.update(float8_worst_logit_gap_share=float(gaps.max()),
-                       float8_router_agreement_share=min(agree),
-                       float8_router_agreement_by_layer=agree,
-                       logit_spread_mean=float((best - hi.mean(-1)).mean()))
+            row.update(rounded_reference_control(
+                ref, cfg["reference"]["args"], params, ids,
+                num_experts=args["num_experts"]))
         print(json.dumps(row), flush=True)
     return 0
 

@@ -13,6 +13,8 @@ edit them).
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "chipbench", "tests"))
 
@@ -30,3 +32,68 @@ from test_trace import *  # noqa: E402,F401,F403
 # list no cells and so belong to every cell, as ``setup_s`` does (the
 # file is the benchmark's: PERF.md section 7)
 del test_a_cell_and_a_metric_are_added_by_adding_files  # noqa: F821
+
+# kept, and expected to fail until a ``benchmark`` PR repairs it: it takes
+# the ten ``setup_*_s`` metrics for the *last* ten entries of
+# ``per_layer``.  The contract a PR is built under says of
+# ``BENCHMARK.json``: "Put new entries at the end of their lists: one
+# put first or in the middle reads as a change to what was there", and
+# every accepted revision of the file appended (``git log -- BENCHMARK.json``),
+# so PR 55's five per-layer metrics stand behind the ten; the test's file
+# is the benchmark's, which only a ``benchmark`` PR may edit (PERF.md
+# section 7 asks for the repair: find the ten by name).  ``strict``: the
+# day it passes again this mark has to go.  What the test holds besides
+# the place is held below, the ten found by name.
+_the_ten_last = test_the_manifest_gives_every_cell_the_ten_readers  # noqa: F821
+
+
+@pytest.mark.xfail(strict=True, raises=AssertionError,
+                   reason="the ten setup readers are no longer the last "
+                          "entries of per_layer (PR 55 appended five)")
+def test_the_manifest_gives_every_cell_the_ten_readers():  # noqa: F811
+    _the_ten_last()
+
+
+def test_the_manifest_gives_every_cell_the_ten_setup_readers():
+    from test_setup_readers import KINDS, LAYER
+
+    from chipbench.manifest import Manifest
+    m = Manifest()
+    names = [f"setup_{k}_s" for k in KINDS]
+    ten = [e for e in m.doc["per_layer"] if e["name"] in names]
+    assert [e["name"] for e in ten] == names        # together, in order
+    at = m.doc["per_layer"].index(ten[0])
+    assert m.doc["per_layer"][at:at + len(ten)] == ten
+    for entry in ten:
+        assert entry == {
+            "name": entry["name"], "unit": "s", "better": "lower",
+            "source": KINDS[entry["name"][len("setup_"):-len("_s")]],
+            "layer": LAYER, "moves": "setup_s"}
+        reader = m.reader(entry["name"])
+        assert (reader.LAYER, reader.SOURCE, reader.MOVES) == (
+            LAYER, entry["source"], "setup_s")
+    for cell in m.workload_names():
+        assert set(names) <= set(m.cell(cell).per_layer)
+
+
+def test_the_queued_gpt2_cell_is_the_batch_cells_traffic_at_full_depth():
+    """``gpt2xl_full_batch_decode``: configuration ``gpt2-xl`` (48
+    layers), one chip, ``gpt2xl_batch_decode``'s traffic number for
+    number.  ISSUE 55 named that cell's traffic file itself; the
+    four-chip cell already pairs ``gpt2-xl`` with
+    ``batch8_512in_256out_chunk4``, and the contract a PR is built under
+    says of ``workloads``: "A pair of configuration and traffic appears
+    once."  So the traffic stands under a name of its own, and this case
+    (in tier-1, where every PR runs it) holds the copy to the file the
+    24-layer cell is sized against: they cannot drift apart."""
+    from chipbench.manifest import Manifest
+    m = Manifest()
+    full = m.cell("gpt2xl_full_batch_decode")
+    half = m.cell("gpt2xl_batch_decode")
+    assert full.traffic == half.traffic and full.chips == 1
+    assert full.config["model_args"]["num_layers"] == 48
+    assert full.config == m.cell("gpt2xl_pipe4_decode").config
+    assert full.per_layer == half.per_layer
+    assert full.end_to_end == half.end_to_end == ("tokens_per_s", "setup_s")
+    pairs = [(w["config"], w["traffic"]) for w in m.doc["workloads"]]
+    assert len(set(pairs)) == len(pairs)

@@ -948,7 +948,17 @@ def test_engine_loop_stop_reads_the_step_in_flight(gpt_setup):
     host, port = door.address
     ServeClient(host, port, "t", max_new_tokens=2).stream(
         [np.arange(3, dtype=np.int32)])         # compiled
+    # the client has its answer from *inside* the last step's delivery
+    # span (``on_done``), which the loop's thread closes after: count
+    # from a loop gone quiet, or that span lands in the window below
     before = _decode_counts()
+    quiet = time.monotonic() + 5
+    while time.monotonic() < quiet:
+        time.sleep(0.02)
+        was, before = before, _decode_counts()
+        if was == before:
+            break
+    read = engine.steps         # the first request's: 3 more are this one's
     def ask():      # its answer never comes: the door is stopped first
         with pytest.raises(ConnectionError):
             ServeClient(host, port, "t", max_new_tokens=12).stream(
@@ -957,7 +967,7 @@ def test_engine_loop_stop_reads_the_step_in_flight(gpt_setup):
     client = threading.Thread(target=ask, daemon=True)
     client.start()
     deadline = time.monotonic() + 20
-    while engine.steps < 3 and time.monotonic() < deadline:
+    while engine.steps < read + 3 and time.monotonic() < deadline:
         time.sleep(0.001)
     loop = door._engine_loop
     loop.stop()
