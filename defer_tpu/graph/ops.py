@@ -796,8 +796,8 @@ def grouped_swiglu(xs, experts, sizes):
     [E, h, d]``, ``sizes [E]`` rows each (they may sum to less than the
     rows: the ``expert_fn`` of both dispatchers).  ``[rows, d]`` in
     ``xs``'s type.  Which way a product goes — the kernel that streams
-    the touched matrices once, or ``lax.ragged_dot`` — is its static
-    shape's choice (``ops/grouped.py``)."""
+    the touched matrices once, or the one that tiles a prompt's rows —
+    is its static shape's choice (``ops/grouped.py``)."""
     a = grouped_gate_up(xs, experts["gate"], experts["up"], sizes)
     return grouped_product(a, experts["down"], sizes)
 

@@ -152,7 +152,7 @@ DECODE_MEMORY_GAUGES = (
 KERNEL_NAMES = (
     "kv_attend", "kv_write_rows", "kv_step", "flash_band", "flash_grouped",
     "flash_latent", "latent_attend", "retention_step", "ssm_step",
-    "ssm_scan", "ssd_step", "ssd_scan", "grouped_experts")
+    "ssm_scan", "ssd_step", "ssd_scan", "grouped_experts", "grouped_rows")
 
 #: the front door's per-request phase on the client's reader thread
 #: (serve/frontdoor.py): prompt frame received -> queued or shed

@@ -140,7 +140,7 @@ def main() -> int:
             "peak_gb": total, **copies, "cache_ops": cache_ops,
             "kernels": text.count('custom_call_target="tpu_custom_call"'),
             # the shape rule (defer_tpu/ops/grouped.py): a step's
-            # products on the kernel, the prompt's on ragged-dot
+            # products on the kernel, the prompt's on the tiled one
             **grouped_products(text), **rule[name].read,
             "flops": float(compiled.cost_analysis().get("flops", 0.0))}
         ok = ok and total <= LIMIT_GB and not copies["weight_copies_in_loop"] \

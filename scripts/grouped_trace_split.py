@@ -7,8 +7,8 @@ the steps and the prefill).  Chip only.
 
 runs ``python -m chipbench --workload CELL --seed SEED --trace 1`` in
 this process with ``chipbench.trace.reduce_trace`` wrapped: every device
-event whose operation is a ``ragged-dot`` or the ``grouped_experts``
-kernel is keyed by its result and operand shapes (the event's name is
+event whose operation is a ``ragged-dot`` (none since PR 56) or one of
+the kernels ``grouped_experts`` / ``grouped_rows`` is keyed by its result and operand shapes (the event's name is
 its line of the compiled text) and by the program run that encloses it
 (``jit_device_decode`` / ``jit_device_prefill``).  ``OUT.json`` holds,
 a key, the count, the summed and the median seconds, and the runs of
@@ -26,7 +26,7 @@ import time
 
 _T_START = time.perf_counter()
 
-_WANTED = re.compile(r"ragged-dot|grouped_experts")
+_WANTED = re.compile(r"ragged-dot|grouped_experts|grouped_rows")
 
 
 def _split(red) -> dict:
@@ -46,7 +46,7 @@ def _split(red) -> dict:
             continue
         head = name.split(", metadata", 1)[0]
         shapes = " ".join(re.findall(r"\w+\[[\d,]*\]", head)[:6])
-        kind = "grouped_experts" if "grouped_experts" in name else "ragged-dot"
+        kind = _WANTED.search(name).group()
         by.setdefault(f"{program(s)} | {kind} | {shapes}", []).append(e - s)
     programs: dict[str, list[float]] = {}
     for s, e, n in runs:

@@ -161,21 +161,25 @@ def weight_copies(comps: dict[str, list[str]], matrices) -> dict:
 
 def grouped_products(text: str) -> dict:
     """How a compiled program runs its routed experts' grouped products:
-    calls of the ``grouped_experts`` kernel (``defer_tpu/ops/grouped.py``)
-    and ``lax.ragged_dot``'s (which the TPU compiler turns into a call
-    of its own, ``%ragged-dot-none.7 = ... custom-call(``)."""
+    calls of the two kernels of ``defer_tpu/ops/grouped.py`` (a step's
+    ``grouped_experts``, a prompt's ``grouped_rows``) and
+    ``lax.ragged_dot``'s (which the TPU compiler turns into a call of
+    its own, ``%ragged-dot-none.7 = ... custom-call(``; since PR 56 no
+    program of the package holds one)."""
     return {"grouped_experts_calls": len(re.findall(
                 r"%grouped_experts[.\d]* = .*tpu_custom_call", text)),
+            "grouped_rows_calls": len(re.findall(
+                r"%grouped_rows[.\d]* = .*tpu_custom_call", text)),
             "ragged_dots": len(re.findall(
                 r"%ragged-dot[\w.\-]* = \S+ custom-call\(", text))}
 
 
 class GroupedCounters:
-    """The shape rule's two counters over a ``with`` block (a program's
+    """The shape rule's counters over a ``with`` block (a program's
     lowering): ``.read`` holds what the block added to
-    ``moe.grouped.kernel_products`` / ``.ragged_products``."""
+    ``moe.grouped.kernel_products`` / ``.tiled_products``."""
 
-    NAMES = ("moe.grouped.kernel_products", "moe.grouped.ragged_products")
+    NAMES = ("moe.grouped.kernel_products", "moe.grouped.tiled_products")
 
     def _now(self) -> list[int]:
         from defer_tpu.obs import REGISTRY

@@ -18,8 +18,10 @@ What it answers before any chip time is spent:
   buffers where they lie;
 * does the decode program hold an attention kernel a layer *by kind*
   (``kv_attend_window`` six times, ``kv_attend_full`` twice) and a
-  grouped product over the 64 experts, and the prefill both flash
-  kernels (``flash_band``, ``flash_grouped``);
+  grouped product over the 64 experts (16 ``grouped_experts`` calls),
+  and the prefill both flash kernels (``flash_band``, ``flash_grouped``)
+  and its routed products on the tiled kernel (16 ``grouped_rows``
+  calls; no ``ragged-dot`` in either program since PR 56, exit 1 on one);
 * which weight leaves the v5e would lay out otherwise than row-major by
   default (``off_default``: what ``_place_weights`` re-lays once, and
   the gauge ``decode.weights.relaid_leaves`` then counts on the chip).
@@ -162,6 +164,8 @@ def main() -> int:
             "temp_gb": m.temp_size_in_bytes / 1e9,
             "output_gb": m.output_size_in_bytes / 1e9,
             "alias_gb": m.alias_size_in_bytes / 1e9,
+            # the program's own text, which the chip holds too
+            "code_mb": m.generated_code_size_in_bytes / 1e6,
             "peak_gb": total, **copies, "cache_ops": cache_ops,
             "kernels": text.count('custom_call_target="tpu_custom_call"'),
             # the cache and flash kernels by name: a layer's kind shows
@@ -170,10 +174,12 @@ def main() -> int:
                 "kv_attend_window", "kv_attend_full", "flash_band",
                 "flash_grouped")},
             # the shape rule (defer_tpu/ops/grouped.py): a step's
-            # products on the kernel, the prompt's on ragged-dot
+            # products on the kernel, the prompt's on the tiled one
             **grouped_products(text), **rule[name].read,
             "flops": float(compiled.cost_analysis().get("flops", 0.0))}
+        # (no ``lax.ragged_dot`` is left in either program since PR 56)
         ok = ok and total <= LIMIT_GB and not copies["weight_copies_in_loop"] \
+            and not row[name]["ragged_dots"] \
             and not any(c["buffer_copies"] for c in cache_ops.values())
         if name == "decode":
             ok = ok and not any(c["item_copies"] for c in cache_ops.values())

@@ -25,7 +25,9 @@ What it answers before any chip time is spent (PERF.md, PR 26):
     env JAX_PLATFORMS=cpu python scripts/olmoe_tpu_compile_check.py
 
 A minute or two and ~8 GB of host memory (the weights are zeros); one
-JSON line; exit 0 when all four hold (per-dispatch copies are reported,
+JSON line; exit 0 when all four hold and neither program holds a
+``ragged-dot`` (a step's grouped products are ``grouped_experts`` calls,
+a prompt's ``grouped_rows`` calls: ``decode_grouped`` / ``prefill_grouped``) (per-dispatch copies are reported,
 not refused).  A process of its own, like
 ``decode_tpu_compile_check.py``: the TPU's library is locked machine-wide
 while it runs.
@@ -146,15 +148,18 @@ def main() -> int:
            "prefill_flops_over_needs": flops / needs_flops,
            "expert_sized_values_produced_in_decode": produced[:8],
            # the shape rule (defer_tpu/ops/grouped.py): a step's
-           # products on the kernel, the prompt's on ragged-dot
+           # products on the kernel, the prompt's on the tiled one
            "decode_grouped": {**grouped_products(text), **decode_rule.read},
            "prefill_grouped": {**grouped_products(prefill.as_text()),
                                **prefill_rule.read},
            }
     print(json.dumps(row))
+    # (no ``lax.ragged_dot`` is left in either program since PR 56)
     ok = not produced and flops / needs_flops <= 1.5 and not (
         cache_ops["item_copies"] or cache_ops["buffer_copies"]
-        or copies["weight_copies_in_loop"])
+        or copies["weight_copies_in_loop"]
+        or row["decode_grouped"]["ragged_dots"]
+        or row["prefill_grouped"]["ragged_dots"])
     return 0 if ok else 1
 
 

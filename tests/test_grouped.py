@@ -1,7 +1,8 @@
 """The routed experts' grouped product (``defer_tpu/ops/grouped.py``):
-the kernel against ``lax.ragged_dot`` at tiny shapes in the interpreter,
-the fused gate-and-up pass, the held dispatch on top of it, and the
-shape rule in the three routed families' ring programs."""
+its two kernels — a step's ``grouped_experts`` and a prompt's row-tiled
+``grouped_rows`` — against ``lax.ragged_dot`` at tiny shapes in the
+interpreter, the fused gate-and-up pass, the held dispatch on top of
+it, and the shape rule in the four routed families' ring programs."""
 
 import numpy as np
 import pytest
@@ -13,7 +14,7 @@ from jax import lax
 import defer_tpu.graph.ops as gops
 from defer_tpu.graph.ops import expert_dispatch_held, grouped_swiglu
 from defer_tpu.models import (cohere_moe_tiny, granite_hybrid_tiny,
-                              olmoe_tiny)
+                              mellum_tiny, olmoe_tiny)
 from defer_tpu.obs import REGISTRY
 from defer_tpu.ops import grouped
 from defer_tpu.runtime.decode import PipelinedDecoder
@@ -46,14 +47,32 @@ def _f32(a):
     return np.asarray(a.astype(jnp.float32))
 
 
+#: the two Pallas calls under one contract.  The tiled one at 16-row
+#: tiles, so that the cases' boundaries fall inside a tile (several
+#: groups in one, ``"boundaries off ..."``), a group runs over several
+#: tiles and the last tile is partial (40, 37 and 7 rows)
+KERNELS = {"kernel": grouped.grouped_experts.__wrapped__,
+           "tiled": grouped.grouped_rows.__wrapped__}
+@pytest.fixture
+def small_row_tiles(monkeypatch):
+    monkeypatch.setattr(grouped, "_ROW_TILE", 16)
+
+
+def paths(test):
+    return pytest.mark.usefixtures("small_row_tiles")(
+        pytest.mark.parametrize("path", list(KERNELS))(test))
+
+
+@paths
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("case", list(CASES))
-def test_the_kernel_is_ragged_dot(case, dtype, monkeypatch):
+def test_the_kernel_is_ragged_dot(case, dtype, path, monkeypatch):
     if case == "two column tiles":
         monkeypatch.setattr(grouped, "_TILE_BYTES", 1 << 20)
+    assert grouped._ROW_TILE == 16
     xs, (w,), sizes = _operands(case, dtype)
-    got = grouped.grouped_experts.__wrapped__(xs, (w,), sizes)
+    got = KERNELS[path](xs, (w,), sizes)
     assert got.shape == (xs.shape[0], w.shape[-1]) and got.dtype == dtype
     live = np.arange(xs.shape[0]) < int(sizes.sum())
     want = _f32(lax.ragged_dot(xs, w, sizes))
@@ -67,14 +86,16 @@ def test_the_kernel_is_ragged_dot(case, dtype, monkeypatch):
         atol=tol, rtol=tol)
 
 
+@paths
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("case", ["empty groups between",
                                   "sizes sum to less than the rows",
                                   "boundaries off the 8- and 16-row tiles"])
-def test_gate_and_up_in_one_pass_are_the_two_products_and_silu(case, dtype):
+def test_gate_and_up_in_one_pass_are_the_two_products_and_silu(case, dtype,
+                                                               path):
     xs, (g, u), sizes = _operands(case, dtype, mats=2)
-    got = _f32(grouped.grouped_experts(xs, (g, u), sizes))
+    got = _f32(KERNELS[path](xs, (g, u), sizes))
     f32 = jnp.float32
     want = _f32(jax.nn.silu(lax.ragged_dot(xs, g, sizes,
                                            preferred_element_type=f32))
@@ -86,34 +107,58 @@ def test_gate_and_up_in_one_pass_are_the_two_products_and_silu(case, dtype):
     assert not got[~live].any()
 
 
-def test_a_tail_of_garbage_rows_stays_out_of_the_groups():
+@paths
+@pytest.mark.parametrize("mats", [1, 2], ids=["one matrix", "gate and up"])
+def test_a_tail_of_garbage_rows_stays_out_of_the_groups(path, mats):
     """Rows behind the last group may hold anything finite or not: no
-    group's product reads them into a row it owns."""
-    xs, (w,), sizes = _operands("sizes sum to less than the rows",
-                                jnp.float32)
+    group's product reads them into a row it owns (23 rows in groups:
+    the last group and the tail share the second 16-row tile)."""
+    xs, ws, sizes = _operands("sizes sum to less than the rows",
+                              jnp.float32, mats=mats)
     held = int(sizes.sum())
     dirty = xs.at[held:].set(jnp.inf)
-    got = grouped.grouped_experts(dirty, (w,), sizes)
+    got = KERNELS[path](dirty, ws, sizes)
     np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(grouped.grouped_experts(xs, (w,), sizes)))
+        np.asarray(got), np.asarray(KERNELS[path](xs, ws, sizes)))
+    assert not np.asarray(got)[held:].any()
 
 
-@pytest.mark.parametrize("rows, groups, k, n, kernel", [
-    (128, 64, 2048, 1024, True),        # OLMoE's step
-    (128, 16, 4096, 4096, True),        # command-a-plus's step
-    (640, 36, 4096, 768, True),         # granite-4.0-h's step
-    (640, 36, 768, 4096, True),
-    (4096, 36, 4096, 768, False),       # a run of a held prefill
-    (4096, 16, 4096, 4096, False),
-    (131072, 64, 2048, 1024, False),    # OLMoE's prompts
+def test_the_tiled_kernel_lists_a_row_tile_once_a_group_in_it():
+    """``_visits``: 40 rows in tiles of 16, groups of 3, 0, 17, 9, 0
+    and 2 rows and a tail of 9."""
+    sizes = jnp.asarray(CASES["empty groups between"][3], jnp.int32)
+    offsets, group, tile, fetch = grouped._visits(sizes, 40, 16)
+    assert offsets.tolist() == [0, 3, 3, 20, 29, 29, 31, 40, 40]
+    # tile 0: groups 0 and 2; tile 1: groups 2, 3, 5 and the tail
+    # (6); tile 2: the tail; behind them an empty group on the same
+    # tile and matrix
+    assert tile.tolist() == [0, 0, 1, 1, 1, 1, 2, 2, 2]
+    assert group.tolist() == [0, 2, 2, 3, 5, 6, 6, 7, 7]
+    assert fetch.tolist() == [0, 2, 2, 3, 5, 5, 5, 5, 5]
+
+
+@pytest.mark.parametrize("rows, groups, k, n, path", [
+    (128, 64, 2048, 1024, "kernel"),        # OLMoE's step
+    (128, 16, 4096, 4096, "kernel"),        # command-a-plus's step
+    (640, 36, 4096, 768, "kernel"),         # granite-4.0-h's step
+    (640, 36, 768, 4096, "kernel"),
+    (128, 64, 2304, 896, "kernel"),         # Mellum2's step
+    (128, 64, 896, 2304, "kernel"),
+    (256, 12, 7168, 2048, "kernel"),        # Kimi's step
+    (4096, 36, 4096, 768, "tiled"),         # a run of a held prefill
+    (4096, 16, 4096, 4096, "tiled"),
+    (4096, 12, 7168, 2048, "tiled"),
+    (131072, 64, 2048, 1024, "tiled"),      # OLMoE's prompts
+    (196608, 64, 2304, 896, "tiled"),       # Mellum2's prompts
+    (196608, 64, 896, 2304, "tiled"),
 ])
-def test_the_shape_rule_at_the_cells_shapes(rows, groups, k, n, kernel):
-    names = ("moe.grouped.kernel_products", "moe.grouped.ragged_products")
+def test_the_shape_rule_at_the_cells_shapes(rows, groups, k, n, path):
+    names = [f"moe.grouped.{p}_products" for p in ("kernel", "tiled")]
     before = [REGISTRY.counter(name).value for name in names]
-    assert grouped.takes_kernel(rows, groups, k, n, 2) is kernel
+    assert grouped.takes_kernel(rows, groups, k, n, 2) is (path == "kernel")
     after = [REGISTRY.counter(name).value for name in names]
-    assert [a - b for a, b in zip(after, before)] == [int(kernel),
-                                                      int(not kernel)]
+    assert [a - b for a, b in zip(after, before)] == [
+        int(path == "kernel"), int(path == "tiled")]
 
 
 @pytest.mark.parametrize("pairs_run", [4096, 16])
@@ -147,13 +192,13 @@ def test_the_held_dispatch_on_the_kernel(monkeypatch, pairs_run):
 
 def _asked() -> np.ndarray:
     return np.array([REGISTRY.counter(f"moe.grouped.{name}_products").value
-                     for name in ("kernel", "ragged")])
+                     for name in ("kernel", "tiled")])
 
 
 def _ring_programs(graph, plen):
     """The lowered text of a one-stage ring's decode and prefill
     program (``scripts/lowered_text_hashes.py`` lowers them so), each
-    with what its lowering added to the rule's (kernel, ragged)
+    with what its lowering added to the rule's (kernel, tiled)
     counters."""
     params = graph.init(jax.random.key(0))
     dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=2,
@@ -173,16 +218,16 @@ def _ring_programs(graph, plen):
 
 
 @pytest.mark.parametrize("family", [
-    olmoe_tiny, cohere_moe_tiny, granite_hybrid_tiny],
-    ids=["olmoe", "command-a-plus", "granite-4.0-h"])
-def test_a_step_takes_the_kernel_and_a_long_prompt_ragged_dot(family):
+    olmoe_tiny, cohere_moe_tiny, granite_hybrid_tiny, mellum_tiny],
+    ids=["olmoe", "command-a-plus", "granite-4.0-h", "mellum2"])
+def test_a_step_takes_the_kernel_and_a_long_prompt_the_tiled_one(family):
     """The path is the product's static shape's: a step's few rows a
-    group go to the kernel, 512 positions' rows to ``lax.ragged_dot``
-    (which the CPU's lowering spells as plain products: the rule's own
-    counters say which way each product went)."""
+    group go to ``grouped_experts``, 512 positions' rows to
+    ``grouped_rows``, and no program asks ``lax.ragged_dot``."""
     (decode, step), (prefill, prompt) = _ring_programs(
         family(seq_len=520), 512)
-    assert "grouped_experts" in decode
+    assert "grouped_experts" in decode and "grouped_rows" not in decode
     assert step[0] > 0 and step[1] == 0
-    assert "grouped_experts" not in prefill
+    assert "grouped_rows" in prefill and "grouped_experts" not in prefill
     assert prompt[0] == 0 and prompt[1] > 0
+    assert "ragged" not in decode + prefill
