@@ -728,7 +728,8 @@ class TransformerBlock(Op):
 
 
 #: the scoring rules :func:`route_top_k` knows
-SCORING_RULES = ("softmax", "sigmoid", "softmax_of_chosen", "noaux_tc")
+SCORING_RULES = ("softmax", "sigmoid", "softmax_of_chosen", "noaux_tc",
+                 "softmax_bias")
 
 
 def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
@@ -746,7 +747,13 @@ def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
     ``p``, the ``k`` largest of ``p + bias`` (``bias`` [experts], the
     balancing term: it chooses and never weighs), the chosen ``p``
     renormalised and multiplied by ``scale`` (Kimi K2 / DeepSeek-V3
-    without a group limit, ``models/kimi_k2.py``)."""
+    without a group limit, ``models/kimi_k2.py``); ``"softmax_bias"`` —
+    probabilities ``p`` over *all* columns (a layer's routed experts
+    and, behind them, its zero-compute ones), the ``k`` largest of ``p
+    + bias`` (the bias chooses and never weighs), the chosen ``p``
+    multiplied by ``scale`` and **not renormalised** (LongCat-Flash,
+    ``models/longcat_flash.py``; an id past the routed experts names a
+    zero-compute expert, :func:`zero_expert_pairs`)."""
     if scoring not in SCORING_RULES:
         raise ValueError(f"scoring must be one of {SCORING_RULES}, "
                          f"got {scoring!r}")
@@ -763,6 +770,10 @@ def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
         _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
         p = jnp.take_along_axis(probs, eid, axis=-1)
         return eid, scale * p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
+    if scoring == "softmax_bias":
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
+        return eid, scale * jnp.take_along_axis(probs, eid, axis=-1)
     p, eid = lax.top_k(jax.nn.sigmoid(logits), k)
     return eid, p / jnp.sum(p, axis=-1, keepdims=True)
 
@@ -871,6 +882,24 @@ def expert_dispatch_held(x, eid, gate, held: tuple[int, int], expert_fn):
         return one_run(y, start, lax.dynamic_slice(padded, (start,), (run,)))
 
     return lax.fori_loop(0, (jnp.sum(sizes) + run - 1) // run, body, y), sizes
+
+
+def zero_expert_pairs(x, eid, gate, num_experts: int):
+    """The third fate of a (row, choice) pair, beside
+    :func:`expert_dispatch_held`'s two (a held expert's: computed;
+    another chip's: left out): a pair whose id is ``>= num_experts``
+    fell to a **zero-compute expert**, the identity — it adds ``weight
+    * x`` and multiplies by no matrix.  Such pairs are never sorted nor
+    dispatched (to the held dispatcher they are no expert's): all of a
+    row's are one multiply of ``x`` by the sum of their weights.
+
+    ``x`` [T, d]; ``eid``/``gate`` [T, k] (:func:`route_top_k` over
+    real and zero columns).  Returns ``(x * sum of the zero pairs'
+    weights [T, d] in float32, the number of zero pairs)``."""
+    zero = eid >= num_experts
+    weight = jnp.sum(jnp.where(zero, gate.astype(jnp.float32), 0.0), axis=-1)
+    return x.astype(jnp.float32) * weight[:, None], \
+        jnp.sum(zero, dtype=jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True, repr=False)

@@ -19,6 +19,7 @@ from .olmoe import olmoe, olmoe_tiny
 from .jamba import jamba, jamba_tiny
 from .granite_hybrid import granite_hybrid, granite_hybrid_tiny
 from .kimi_k2 import kimi_k2, kimi_k2_tiny
+from .longcat_flash import longcat_flash, longcat_flash_tiny
 from .mellum import mellum, mellum_tiny
 from .inception import (INCEPTION_6STAGE_CUTS, inception, inception_tiny,
                         inception_v3)
@@ -41,5 +42,6 @@ __all__ = [
     "jamba", "jamba_tiny",
     "granite_hybrid", "granite_hybrid_tiny",
     "kimi_k2", "kimi_k2_tiny",
+    "longcat_flash", "longcat_flash_tiny",
     "mellum", "mellum_tiny",
 ]

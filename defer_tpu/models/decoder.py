@@ -368,12 +368,27 @@ class LatentBlock(DecoderBlock):
       expanded heads, with the rows as :meth:`decode_q_row` would have
       handed them over one by one.
 
+    **Sublayers.**  A block may hold several latent-attention
+    sublayers (``sublayers``; ``models/longcat_flash.py``'s double
+    layer has two around one shortcut-connected MoE).  It then keeps a
+    row buffer *a sublayer*, all of one format, and a step takes one
+    turn around the cache a sublayer, *inside the block*: what lives
+    across a turn (the shortcut's output) never leaves it, so a stage
+    cut, which falls between blocks, never meets it.  Such a block
+    overrides :meth:`round_q_row` and :meth:`round_finish`, which take
+    the sublayer's index, and its ``apply_with_rows`` hands back a
+    tuple of rows, one a sublayer; a block of one sublayer has the two
+    halves above and nothing else.
+
     ``decode_stats`` is :class:`DecoderBlock`'s.  No serving engine
     takes such a block yet (``serve/engine.py`` refuses every block but
     GPT's).
     """
 
     memory = "latent_cache"
+    #: latent-attention sublayers of the block: row buffers a layer
+    #: keeps, turns around the cache a step
+    sublayers = 1
 
     def geometry(self, d_model: int):
         """Every head has a key of its own once expanded: ``(heads,
@@ -399,24 +414,53 @@ class LatentBlock(DecoderBlock):
         from ..ops import latent_cache
         return latent_cache.LatentCacheFormat(
             self.latent_dim, self.rope_dim, positions, dtype,
-            self.softmax_scale, groups=groups)
+            self.softmax_scale, groups=groups, sublayers=self.sublayers)
+
+    def apply(self, params, x, sow=None):
+        """Full-sequence forward on ``x`` [b, t, d] or [t, d]:
+        :meth:`apply_with_rows`' first result."""
+        lead = x.shape[:-2]
+        y = self.apply_with_rows(
+            params, x.reshape((-1,) + x.shape[-2:]), sow)[0]
+        return y.reshape(lead + y.shape[-2:])
+
+    def round_q_row(self, params, x, pos, sublayer: int):
+        """:meth:`decode_q_row` of sublayer ``sublayer``."""
+        del sublayer
+        return self.decode_q_row(params, x, pos)
+
+    def round_finish(self, params, x, y, sublayer: int, carry, sow=None):
+        """The rest of sublayer ``sublayer`` after its attention's
+        output ``y``: ``(the stream, carry)``, ``carry`` whatever the
+        block keeps alive for a later sublayer of the same step (None
+        into the first)."""
+        del sublayer, carry
+        return self.decode_finish(params, x, y, sow=sow), None
 
     def decode(self, params, x, cache, pos, fmt, slot=None, group=None,
                sow=None):
-        """One-token step: the new row written at ``pos`` (``slot``:
-        ``fmt.decode_slot``'s, opaque here as in
-        :meth:`DecoderBlock.decode`), then every head's absorbed query
-        over the rows ``<= pos``."""
+        """One-token step, a round a sublayer: the new row written at
+        ``pos`` (``slot``: ``fmt.decode_slot``'s, opaque here as in
+        :meth:`DecoderBlock.decode`) of the sublayer's buffer, then
+        every head's absorbed query over that buffer's rows ``<=
+        pos``."""
         slot = pos if slot is None else slot
-        q, row = self.decode_q_row(params, x, pos)
-        att, cache = fmt.step(q, cache, fmt.rows(row), slot, group=group)
-        return self.decode_finish(params, x, att, sow=sow), cache
+        carry = None
+        for i in range(self.sublayers):
+            q, row = self.round_q_row(params, x, pos, i)
+            att, cache = fmt.step(q, cache, fmt.rows(row), slot,
+                                  group=group, sublayer=i)
+            x, carry = self.round_finish(params, x, att, i, carry, sow)
+        return x, cache
 
     def prefill(self, params, x, cache, fmt, slot):
         """A whole prompt ``x`` [b, t, d] through the layer over the
-        expanded heads, its rows bulk-written where ``slot`` says."""
+        expanded heads, each sublayer's rows bulk-written where
+        ``slot`` says."""
         x, rows = self.apply_with_rows(params, x)
-        return x, fmt.write_prefix(cache, rows, slot)
+        for i, r in enumerate(rows if isinstance(rows, tuple) else (rows,)):
+            cache = fmt.write_prefix(cache, r, slot, sublayer=i)
+        return x, cache
 
 
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:

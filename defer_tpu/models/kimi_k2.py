@@ -19,7 +19,9 @@ k_r) * softmax_scale``, causal.  A prompt is computed in exactly this,
 computed in the *absorbed* form: ``q~[i] = q_n[i] W_uk[i]`` so that a
 score is ``[q~[i], q_r[i]] . [c, k_r]``, the heads' outputs
 ``o~[i] = sum_s p c_s`` stay in the latent space (``ops/latent_cache.py``)
-and ``o[i] = o~[i] W_uv[i]`` — the same numbers up to rounding.
+and ``o[i] = o~[i] W_uv[i]`` — the same numbers up to rounding.  The
+half is ``models/latent_attention.py``'s, shared with
+``models/longcat_flash.py`` (whose two LoRA scales are 1 here).
 
 **RoPE** turns adjacent pairs (the checkpoint's order) by YaRN's
 frequencies (``models/rotary.py::yarn_inv_freq``), computed once in
@@ -44,7 +46,6 @@ ledger (the dense block zeros).
 from __future__ import annotations
 
 import dataclasses
-import math
 
 import jax
 import jax.numpy as jnp
@@ -52,8 +53,8 @@ import jax.numpy as jnp
 from ..graph.ir import GraphBuilder, LayerGraph, Op
 from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch_held,
                          grouped_swiglu, rms_norm, route_top_k)
-from .cohere_moe import rope_interleaved
 from .decoder import LatentBlock
+from .latent_attention import LatentAttention, _normal
 from .olmoe import OlmoeEmbedding
 from .rotary import yarn_attention_factor, yarn_inv_freq
 
@@ -67,11 +68,6 @@ from .rotary import yarn_attention_factor, yarn_inv_freq
 _BIAS_SPREAD = 0.001
 
 
-def _normal(key, shape, fan_in: int):
-    """A matrix as the other families draw theirs: N(0, 1 / fan_in)."""
-    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
-
-
 def yarn_softmax_scale(width: int, factor: float,
                        mscale_all_dim: float = 1.0) -> float:
     """What a score is multiplied by under YaRN: ``width ** -0.5 * m **
@@ -81,7 +77,7 @@ def yarn_softmax_scale(width: int, factor: float,
 
 
 @dataclasses.dataclass(frozen=True, repr=False, kw_only=True)
-class _KimiBlock(LatentBlock, Op):
+class _KimiBlock(LatentAttention, LatentBlock, Op):
     """The attention half both kinds of layer share, and the two
     residuals; a subclass says ``_ffn`` and its parameters."""
 
@@ -99,72 +95,12 @@ class _KimiBlock(LatentBlock, Op):
 
     decode_stats = ("moe.assignments", "moe.held_assignments",
                     "moe.experts_hit", "moe.load_max")
-    #: parameters the query-and-row half reads, and the other half's
-    _front = ("in_ln", "q_a", "q_a_ln", "q_b", "kv_a", "kv_a_ln", "k_up")
-    _back = ("v_up", "proj", "ff_ln")
+    #: the way out's parameters: the attention's and the second norm
+    _back = LatentAttention._back + ("ff_ln",)
 
     def _attention_init(self, keys, d: int) -> dict:
-        nh, r, c = self.num_heads, self.q_rank, self.latent_dim
-
-        def ones(n):
-            return {"scale": jnp.ones((n,), jnp.float32)}
-
-        return {
-            "in_ln": ones(d),
-            "q_a": {"w": _normal(keys[0], (d, r), d)}, "q_a_ln": ones(r),
-            # a head's columns: nope_dim of q_n, then rope_dim of q_r
-            "q_b": {"w": _normal(keys[1], (
-                r, nh * (self.nope_dim + self.rope_dim)), r)},
-            # the latent's columns, then the shared key's
-            "kv_a": {"w": _normal(keys[2], (d, c + self.rope_dim), d)},
-            "kv_a_ln": ones(c),
-            "k_up": {"w": _normal(keys[3], (nh, self.nope_dim, c), c)},
-            "v_up": {"w": _normal(keys[4], (nh, c, self.v_dim), c)},
-            "proj": {"w": _normal(keys[5], (nh * self.v_dim, d),
-                              nh * self.v_dim)},
-            "ff_ln": ones(d),
-        }
-
-    # -- the attention's two forms -----------------------------------------
-
-    def _q_rows(self, p, x, pos):
-        """``(q_n [..., t, nh, nope], q_r [..., t, nh, rope] rotated, rows
-        [..., t, latent + rope])`` of ``x`` [..., t, d] at positions
-        ``pos`` [t]: the rows final, as the cache keeps them."""
-        nh, c = self.num_heads, self.latent_dim
-        h = rms_norm(x, p["in_ln"]["scale"], self.rms_eps)
-        cq = rms_norm(h @ p["q_a"]["w"], p["q_a_ln"]["scale"], self.rms_eps)
-        q = (cq @ p["q_b"]["w"]).reshape(x.shape[:-1] + (nh, -1))
-        kv = h @ p["kv_a"]["w"]
-        latent = rms_norm(kv[..., :c], p["kv_a_ln"]["scale"], self.rms_eps)
-        k_r = rope_interleaved(kv[..., None, c:], pos, 0.0, self.rope_freqs)
-        q_r = rope_interleaved(q[..., self.nope_dim:], pos, 0.0,
-                               self.rope_freqs)
-        return q[..., :self.nope_dim], q_r, jnp.concatenate(
-            [latent, k_r[..., 0, :]], axis=-1)
-
-    def _expanded(self, p, q_n, q_r, rows):
-        """Causal attention of a prompt ``[b, t, ...]`` over the expanded
-        heads: ``[b, t, nh * v]``."""
-        c, f32 = self.latent_dim, jnp.float32
-        latent, k_r = rows[..., :c], rows[..., None, c:]
-        k_n = jnp.einsum("btc,hnc->bhtn", latent, p["k_up"]["w"],
-                         preferred_element_type=f32).astype(rows.dtype)
-        v = jnp.einsum("btc,hcv->bhtv", latent, p["v_up"]["w"],
-                       preferred_element_type=f32).astype(rows.dtype)
-        q_n, q_r, k_r = (a.transpose(0, 2, 1, 3) for a in (q_n, q_r, k_r))
-        if self._attention_impl() == "flash":
-            from ..ops.flash_attention import flash_latent
-            y = flash_latent(q_n, q_r, k_n, k_r, v, scale=self.softmax_scale)
-        else:
-            att = (jnp.einsum("bhqn,bhkn->bhqk", q_n, k_n)
-                   + jnp.einsum("bhqr,bxkr->bhqk", q_r, k_r)) \
-                * self.softmax_scale
-            t = att.shape[-1]
-            att = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :],
-                            att, jnp.asarray(-jnp.inf, att.dtype))
-            y = jnp.einsum("bhqk,bhkv->bhqv", jax.nn.softmax(att, axis=-1), v)
-        return y.transpose(0, 2, 1, 3).reshape(y.shape[0], y.shape[2], -1)
+        return dict(super()._attention_init(keys, d),
+                    ff_ln={"scale": jnp.ones((d,), jnp.float32)})
 
     def _finish(self, p, x, y, sow=None):
         """The layer's output from the stream ``x`` [T, d] and the heads'
@@ -178,13 +114,6 @@ class _KimiBlock(LatentBlock, Op):
         return (x32 + self._ffn(p, h, sow)).astype(x.dtype)
 
     # -- full sequence -----------------------------------------------------
-
-    def apply(self, params, x, sow=None):
-        """Full-sequence forward on ``x`` [b, t, d] or [t, d]."""
-        lead = x.shape[:-2]
-        y = self.apply_with_rows(
-            params, x.reshape((-1,) + x.shape[-2:]), sow)[0]
-        return y.reshape(lead + y.shape[-2:])
 
     def apply_with_rows(self, params, x, sow=None):
         """Full-sequence forward on ``x`` [b, t, d] over the expanded
@@ -205,12 +134,8 @@ class _KimiBlock(LatentBlock, Op):
         """Every head's absorbed query ``[b, nh * (latent + rope)]`` and
         the new row ``[b, latent + rope]`` of ``x`` [b, d] at scalar
         ``pos``."""
-        p = _cast({nm: params[nm] for nm in self._front}, x.dtype)
-        q_n, q_r, rows = self._q_rows(p, x[:, None], jnp.reshape(pos, (1,)))
-        q_abs = jnp.einsum("bhn,hnc->bhc", q_n[:, 0], p["k_up"]["w"],
-                           preferred_element_type=jnp.float32)
-        q = jnp.concatenate([q_abs.astype(x.dtype), q_r[:, 0]], axis=-1)
-        return q.reshape(x.shape[0], -1), rows[:, 0]
+        return self._absorbed_q_row(
+            _cast({nm: params[nm] for nm in self._front}, x.dtype), x, pos)
 
     def decode_finish(self, params, x, y, sow=None):
         """The heads' outputs ``y`` [b, nh * latent] out of the latent
@@ -218,19 +143,7 @@ class _KimiBlock(LatentBlock, Op):
         feed-forward half; sows :attr:`decode_stats` of this step."""
         p = _cast({nm: params[nm] for nm in self._back + self._ffn_params},
                   x.dtype)
-        o = jnp.einsum("bhc,hcv->bhv",
-                       y.reshape(x.shape[0], self.num_heads, -1),
-                       p["v_up"]["w"], preferred_element_type=jnp.float32)
-        return self._finish(p, x, o.astype(x.dtype).reshape(x.shape[0], -1),
-                            sow)
-
-    def _attention_flops(self, t: int, d: int) -> int:
-        nh = self.num_heads
-        qk, kv = self.nope_dim + self.rope_dim, self.nope_dim + self.v_dim
-        return (2 * t * (d * self.q_rank + self.q_rank * nh * qk
-                         + d * (self.latent_dim + self.rope_dim)
-                         + self.latent_dim * nh * kv + nh * self.v_dim * d)
-                + 2 * t * t * nh * (qk + self.v_dim))
+        return self._finish(p, x, self._out_of_latent(p, x, y), sow)
 
 
 @dataclasses.dataclass(frozen=True, repr=False, kw_only=True)

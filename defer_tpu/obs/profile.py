@@ -130,7 +130,9 @@ DECODE_DISPATCH_PHASES = ("upload", "launch")
 #: ``decode.ssm.updates``; a graph whose blocks both route and keep a
 #: state, ``models/granite_hybrid.py``'s, sows all five;
 #: ``models/kimi_k2.py``'s two kinds of block the four ``moe.*`` names,
-#: the dense one zeros): their sums,
+#: the dense one zeros; ``models/longcat_flash.py``'s those four and
+#: ``moe.zero_assignments`` / ``moe.real_assignments``, the pairs that
+#: fell to zero-compute experts and to routed ones): their sums,
 #: which came to the host a chunk at a time with the chunk's ids, go to
 #: the counters
 DECODE_STATS_PHASES = ("moe_stats",)
@@ -141,11 +143,14 @@ DECODE_STATS_PHASES = ("moe_stats",)
 #: and each kind's own parts.  The latent cache's pair is bytes and the
 #: rows those bytes are — their quotient is what a live row costs a step
 #: to read, which ``latent_moe_decode_step_roofline`` holds against the
-#: configuration's 1152 B
+#: configuration's 1152 B — and ``latent_sublayers`` the row buffers
+#: they lie in, over the stages (a block of two latent-attention
+#: sublayers keeps two a layer)
 DECODE_MEMORY_GAUGES = (
     "decode.cache.window_bytes", "decode.cache.full_bytes",
     "decode.cache.window_positions", "decode.cache.latent_bytes",
-    "decode.cache.latent_positions", "decode.ssm.conv_bytes")
+    "decode.cache.latent_positions", "decode.cache.latent_sublayers",
+    "decode.ssm.conv_bytes")
 
 #: the Pallas kernels' names in a device trace, which the benchmark's
 #: kernel readers search for (``chipbench/metrics/*_kernel_roofline.py``)

@@ -24,6 +24,14 @@ the 1152 B the row needs), every product contracts over whole tiles and
 the padding columns hold zeros, which add nothing to a score and are
 never read as values.
 
+**Sublayers.**  A block that holds several latent-attention sublayers
+(``models/longcat_flash.py``: two a block, around one shortcut-connected
+MoE) keeps one such buffer *a sublayer*: the format's ``sublayers``, the
+buffers under ``latent``, ``latent_1``, ...  They are written,
+bulk-written and attended separately, each call naming its ``sublayer``;
+the slots, the scratch row and the scratch group are the layer's, one
+for all of them.
+
 **The two writes** the ring makes: :meth:`LatentCacheFormat.write_position`
 (one position for every sequence: one ``lax.dynamic_update_slice``) and
 :meth:`LatentCacheFormat.write_prefix` (a whole prompt for a group or a
@@ -204,8 +212,15 @@ class LatentCacheFormat(RingRows):
     #: the ring's round-robin groups (a leading axis, with the scratch
     #: group and the scratch row); None for slots alone
     groups: int | None = None
+    #: row buffers a layer keeps, one a latent-attention sublayer of the
+    #: block (the module docstring)
+    sublayers: int = 1
 
-    keys = ("latent",)
+    @property
+    def keys(self) -> tuple:
+        """A buffer a sublayer: ``latent``, ``latent_1``, ..."""
+        return ("latent",) + tuple(
+            f"latent_{i}" for i in range(1, self.sublayers))
 
     @property
     def width(self) -> int:
@@ -218,11 +233,13 @@ class LatentCacheFormat(RingRows):
         return -(-self.width // _LANES) * _LANES
 
     def buffers(self, batch: int) -> dict[str, jax.ShapeDtypeStruct]:
-        """One layer's buffer for ``batch`` sequences (a group)."""
+        """One layer's buffers for ``batch`` sequences (a group): one a
+        sublayer, all alike."""
         lead, length = self._buffer_rows()
-        return {"latent": jax.ShapeDtypeStruct(
+        buf = jax.ShapeDtypeStruct(
             lead + (batch, -(-length // _JOINED_ROWS) * _JOINED_ROWS,
-                    self.padded), self.dtype)}
+                    self.padded), self.dtype)
+        return {key: buf for key in self.keys}
 
     def _pad(self, a):
         """``a [..., width]`` with zeros up to the buffer's columns."""
@@ -234,27 +251,31 @@ class LatentCacheFormat(RingRows):
         as :meth:`write_position` takes them."""
         return {"latent": self._pad(row)[:, None]}
 
-    def write_position(self, layer: dict, rows: dict, pos, group=None):
+    def write_position(self, layer: dict, rows: dict, pos, group=None,
+                       sublayer: int = 0):
         """``rows`` written in place at the one position ``pos`` of
         every sequence (of group ``group``, where the format has
-        groups): the layer."""
-        buf = layer["latent"]
+        groups) of sublayer ``sublayer``'s buffer: the layer."""
+        key = self.keys[sublayer]
+        buf = layer[key]
         lead = () if group is None else (group,)
         row = lax.expand_dims(rows["latent"], range(len(lead)))
-        return {"latent": lax.dynamic_update_slice(
-            buf, row.astype(buf.dtype), lead + (0, pos, 0))}
+        return dict(layer, **{key: lax.dynamic_update_slice(
+            buf, row.astype(buf.dtype), lead + (0, pos, 0))})
 
-    def write_prefix(self, layer: dict, rows, slot) -> dict:
+    def write_prefix(self, layer: dict, rows, slot,
+                     sublayer: int = 0) -> dict:
         """A whole prompt's rows ``[b, t, width]`` written at positions
-        ``0..t-1`` where ``slot`` (``prefill_slot``'s) says — a group,
-        or a group and the sequence of it the ``b`` prompts start at;
-        in a format without groups the sequence alone: one bulk
-        write."""
+        ``0..t-1`` of sublayer ``sublayer``'s buffer where ``slot``
+        (``prefill_slot``'s) says — a group, or a group and the
+        sequence of it the ``b`` prompts start at; in a format without
+        groups the sequence alone: one bulk write."""
         if self.groups is None:
             at = (slot,)
         else:
             at = slot if isinstance(slot, tuple) else (slot, 0)
-        buf = layer["latent"]
+        key = self.keys[sublayer]
+        buf = layer[key]
         # rows as the buffer holds them: left to itself the compiler may
         # produce them positions-minor and convert the *buffer* around
         # the write (``KVCacheFormat.write_prefix``'s joined rows)
@@ -262,23 +283,33 @@ class LatentCacheFormat(RingRows):
                                       Layout(major_to_minor=(0, 1, 2)))
         if self.groups is not None:
             rows = rows[None]
-        return {"latent": lax.dynamic_update_slice(
-            buf, rows.astype(buf.dtype), at + (0,) * (buf.ndim - len(at)))}
+        return dict(layer, **{key: lax.dynamic_update_slice(
+            buf, rows.astype(buf.dtype), at + (0,) * (buf.ndim - len(at)))})
 
-    def item(self, layer: dict, group=None):
-        """One group's buffer ``[b, L, padded]``: what
+    def item(self, layer: dict, group=None, sublayer: int = 0):
+        """One group's buffer ``[b, L, padded]`` of one sublayer: what
         :func:`attend_einsum` reads."""
-        buf = layer["latent"]
+        buf = layer[self.keys[sublayer]]
         return buf if group is None else _group_slice(buf, group)[0]
 
-    def attend(self, q, layer: dict, pos, group=None):
+    def step(self, q, layer: dict, rows: dict, pos, group=None,
+             sublayer: int = 0):
+        """``RingRows.step`` on one sublayer's buffer: the row written
+        at ``pos``, then ``q`` over that buffer's rows ``<= pos``."""
+        layer = self.write_position(layer, rows, pos, group=group,
+                                    sublayer=sublayer)
+        return self.attend(q, layer, pos, group=group,
+                           sublayer=sublayer), layer
+
+    def attend(self, q, layer: dict, pos, group=None, sublayer: int = 0):
         """Every head's absorbed query ``q`` ``[b, heads * width]`` over
-        ``layer``'s rows (group ``group``'s sequences, where the format
-        has groups), positions ``<= pos`` live — ``pos`` a scalar or
-        [b]; returns the heads' outputs in the latent space, ``[b,
-        heads * latent]``.  :func:`latent_attend`."""
+        the rows of ``layer``'s sublayer ``sublayer`` (group ``group``'s
+        sequences, where the format has groups), positions ``<= pos``
+        live — ``pos`` a scalar or [b]; returns the heads' outputs in
+        the latent space, ``[b, heads * latent]``.
+        :func:`latent_attend`."""
         b = q.shape[0]
-        buf = layer["latent"]
+        buf = layer[self.keys[sublayer]]
         if group is None:
             buf, group = buf[None], 0
         q = self._pad(q.reshape(b, -1, self.width)).astype(buf.dtype)

@@ -331,8 +331,15 @@ class PipelinedDecoder:
             # their quotient is what a live row costs a step to read
             REGISTRY.gauge("decode.cache.latent_bytes").set(held(
                 lambda k, fmt, key: k == "latent_cache"))
+            # (a layer of several sublayers keeps a buffer each: all
+            # are counted)
             REGISTRY.gauge("decode.cache.latent_positions").set(sum(
-                n * math.prod(fmt.buffers(mb)["latent"].shape[:-1])
+                n * math.prod(buf.shape[:-1])
+                for k, fmt in zip(self.memory, self.state_formats)
+                if k == "latent_cache"
+                for buf in fmt.buffers(mb).values()))
+            REGISTRY.gauge("decode.cache.latent_sublayers").set(sum(
+                n * fmt.sublayers
                 for k, fmt in zip(self.memory, self.state_formats)
                 if k == "latent_cache"))
         if "ssm" in kinds:

@@ -64,19 +64,23 @@ LIMIT_GB = 16.0
 CACHE_OVER_NEED = 1.12
 
 
-def main() -> int:
+def check(model, config: str, traffic: str, tag: str) -> int:
+    """Compile the cell of ``config`` / ``traffic`` (file names under
+    ``chipbench/``) for the graph ``model(**model_args)``; the module
+    docstring's checks, one JSON line, the exit code.
+    ``<TAG>_CHECK_DUMP=DIR`` writes both compiled texts as
+    ``<tag>_<program>.txt``.  Any family of latent blocks
+    (``scripts/longcat_tpu_compile_check.py`` calls it too)."""
     jax.config.update("jax_enable_compilation_cache", False)
-    with open(os.path.join(HERE, "..", "chipbench", "configs",
-                           "kimi-k2.7-code-5l-ep32.json")) as f:
+    with open(os.path.join(HERE, "..", "chipbench", "configs", config)) as f:
         args = json.load(f)["model_args"]
-    with open(os.path.join(HERE, "..", "chipbench", "traffic",
-                           "batch32_8192in_4096out_chunk32.json")) as f:
+    with open(os.path.join(HERE, "..", "chipbench", "traffic", traffic)) as f:
         tr = json.load(f)
     mb, plen, max_len, chunk = (tr["batch"], tr["prompt_len"],
                                 tr["max_len"], tr["token_chunk"])
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    graph = kimi_k2(**args)
+    graph = model(**args)
     params = jax.tree.map(lambda s: np.zeros(s.shape, jnp.bfloat16),
                           jax.eval_shape(graph.init, jax.random.key(0)))
     dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=mb,
@@ -111,7 +115,8 @@ def main() -> int:
                 arg((1, mb), jnp.int32, P(None, None)), i32, i32,
                 arg((1, mb, dec.d_model), jnp.float32,
                     P(STAGE_AXIS, None, None)), caches)
-    layers = len(dec.memory)
+    # a row buffer a sublayer a layer, all of one shape
+    layers = sum(fmt.sublayers for fmt in dec.state_formats)
     buffer = shapes["latent"][0].shape
     # the scratch group and row are the ring's, the row's need the
     # configuration's: 576 values of 2 bytes
@@ -122,14 +127,14 @@ def main() -> int:
            "cache_buffer": list(buffer), "cache_need_gb": need / 1e9}
     matrices = [leaf.shape for leaf in jax.tree.leaves(params)
                 if leaf.ndim > 1 and leaf.size > 1 << 22]
-    # a piece of the prefill is 8192 tokens: an activation [tokens,
+    # a piece of the prefill is a prompt's tokens: an activation [tokens,
     # columns] may have the shape of a dense matrix, and a shape that
     # activations share says nothing.  The prefill is held to the
     # matrices no activation resembles, the decode program to all
     tokens = dec._prefill_rows(plen) * plen
     distinct = [shape for shape in matrices if tokens not in shape]
     ok = True
-    out_dir = os.environ.get("KIMI_CHECK_DUMP")
+    out_dir = os.environ.get(f"{tag.upper()}_CHECK_DUMP")
     for name, lowered in (("prefill", prefill), ("decode", decode)):
         try:
             compiled = lowered.compile()
@@ -140,7 +145,7 @@ def main() -> int:
         text = compiled.as_text()
         if out_dir:
             os.makedirs(out_dir, exist_ok=True)
-            with open(os.path.join(out_dir, f"kimi_{name}.txt"), "w") as f:
+            with open(os.path.join(out_dir, f"{tag}_{name}.txt"), "w") as f:
                 f.write(text)
         m = compiled.memory_analysis()
         comps = computations(text)
@@ -169,6 +174,11 @@ def main() -> int:
             and not cache_ops["item_copies"]
     print(json.dumps(row))
     return 0 if ok else 1
+
+
+def main() -> int:
+    return check(kimi_k2, "kimi-k2.7-code-5l-ep32.json",
+                 "batch32_8192in_4096out_chunk32.json", "kimi")
 
 
 if __name__ == "__main__":

@@ -11,7 +11,8 @@ memory in one graph, a period a stage; ``granite_hybrid_tiny``, the
 same with a state of heads and routed experts in every layer;
 ``kimi_k2_tiny``, a latent cache, and at two stages a dense block at
 the place of the other stage's routed one; ``mellum_tiny``, two
-rotations by layer kind) and
+rotations by layer kind; ``longcat_flash_tiny``, two latent caches a
+block and two turns around them a step, two blocks a stage) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -40,7 +41,8 @@ import jax.numpy as jnp
 
 from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
                               granite_hybrid_tiny, gpt_tiny, jamba_tiny,
-                              kimi_k2_tiny, mellum_tiny, olmoe, olmoe_tiny)
+                              kimi_k2_tiny, longcat_flash_tiny, mellum_tiny,
+                              olmoe, olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -73,6 +75,10 @@ def ring_configurations():
     # two rotations by layer kind, ring buffers beside full layers'
     # caches, kernels named by kind: one period a stage
     yield "mellum_tiny", mellum_tiny(), (1, 2), *every
+    # a block of two latent-attention sublayers: two row buffers a
+    # layer, the shortcut's output carried across the second turn; a
+    # stage cut between blocks
+    yield "longcat_flash_tiny", longcat_flash_tiny(), (1, 2), *plain
 
 
 def ring_programs(name, graph, stages, kv_caches, beams):

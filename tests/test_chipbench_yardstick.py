@@ -97,3 +97,49 @@ def test_the_queued_gpt2_cell_is_the_batch_cells_traffic_at_full_depth():
     assert full.end_to_end == half.end_to_end == ("tokens_per_s", "setup_s")
     pairs = [(w["config"], w["traffic"]) for w in m.doc["workloads"]]
     assert len(set(pairs)) == len(pairs)
+
+
+# -- PR 57's two cells: what of their own tests needs no tiny driver -------------------
+
+import test_shortcut_latent_moe_driver as _shortcut  # noqa: E402
+
+real_args = _shortcut.real_args
+test_the_longcat_cell_has_its_files_and_metrics = \
+    _shortcut.test_the_real_manifest_gives_the_cell_its_files_and_metrics
+test_the_longcat_readers_return_nothing_without_their_counters = \
+    _shortcut.test_the_readers_return_nothing_without_their_counters
+test_the_longcat_models_size_against_the_issues_count = \
+    _shortcut.test_the_models_size_against_the_issues_count
+test_the_longcat_decode_step_needs_against_the_issues_count = \
+    _shortcut.test_decode_step_needs_against_the_issues_count
+test_the_longcat_prefill_needs_against_the_issues_count = \
+    _shortcut.test_prefill_needs_against_the_issues_count
+
+
+def test_the_long_prompt_cell_is_the_full_cells_model_on_a_long_prompt():
+    """``gpt2xl_long_prompt`` (queued since PR 32 as B0.5): configuration
+    ``gpt2-xl`` (48 layers), one chip, driver ``batch_decode``, 8 x (896
+    in, 32 out) 4 a call inside GPT-2's 1024 positions; it reports what
+    the full batch cell reports, ``decode_step_roofline`` with it."""
+    from chipbench.manifest import Manifest
+    m = Manifest()
+    cell = m.cell("gpt2xl_long_prompt")
+    full = m.cell("gpt2xl_full_batch_decode")
+    assert cell.config == full.config and cell.chips == 1
+    assert cell.config["model_args"]["num_layers"] == 48
+    assert {k: cell.traffic[k] for k in (
+        "driver", "batch", "prompt_len", "new_tokens", "token_chunk",
+        "max_len", "compute_dtype", "kv_cache", "check_sequences")} == {
+        "driver": "batch_decode", "batch": 8, "prompt_len": 896,
+        "new_tokens": 32, "token_chunk": 4, "max_len": 928,
+        "compute_dtype": "bfloat16", "kv_cache": "buffer",
+        "check_sequences": 2}
+    assert cell.traffic["max_len"] <= cell.config["model_args"]["seq_len"]
+    assert cell.per_layer == full.per_layer
+    assert cell.end_to_end == ("tokens_per_s", "setup_s")
+    # (a later PR may append cells: these two stand behind PR 55's eleven)
+    assert [w["name"] for w in m.doc["workloads"]][11:13] == [
+        "longcatflash_batch_decode", "gpt2xl_long_prompt"]
+    assert sum(w["chips"] == 4 for w in m.doc["workloads"]) == 1
+    pairs = [(w["config"], w["traffic"]) for w in m.doc["workloads"]]
+    assert len(set(pairs)) == len(pairs)

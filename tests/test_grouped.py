@@ -14,7 +14,7 @@ from jax import lax
 import defer_tpu.graph.ops as gops
 from defer_tpu.graph.ops import expert_dispatch_held, grouped_swiglu
 from defer_tpu.models import (cohere_moe_tiny, granite_hybrid_tiny,
-                              mellum_tiny, olmoe_tiny)
+                              longcat_flash_tiny, mellum_tiny, olmoe_tiny)
 from defer_tpu.obs import REGISTRY
 from defer_tpu.ops import grouped
 from defer_tpu.runtime.decode import PipelinedDecoder
@@ -218,8 +218,10 @@ def _ring_programs(graph, plen):
 
 
 @pytest.mark.parametrize("family", [
-    olmoe_tiny, cohere_moe_tiny, granite_hybrid_tiny, mellum_tiny],
-    ids=["olmoe", "command-a-plus", "granite-4.0-h", "mellum2"])
+    olmoe_tiny, cohere_moe_tiny, granite_hybrid_tiny, mellum_tiny,
+    longcat_flash_tiny],
+    ids=["olmoe", "command-a-plus", "granite-4.0-h", "mellum2",
+         "longcat-flash"])
 def test_a_step_takes_the_kernel_and_a_long_prompt_the_tiled_one(family):
     """The path is the product's static shape's: a step's few rows a
     group go to ``grouped_experts``, 512 positions' rows to
