@@ -8,36 +8,46 @@ streaming K/V blocks through VMEM with an online-softmax accumulator —
 neither the score matrix [Tq, Tk] nor the full K/V sequence is ever resident
 on-chip.
 
-Tiling: grid = (batch*heads, Tq/block_q, Tk/block_k) with the K axis
-innermost; Pallas DMAs one [block_k, d] K/V tile per step while the
-(running max, running denominator, rescaled accumulator) state persists in
-VMEM scratch across the sequential K iterations.  Both matmuls per block
-(QK^T and PV) hit the MXU at [block_q, d] x [d, block_k] and
-[block_q, block_k] x [block_k, d].
+Causal calls — every prompt of every decoder family — take
+:func:`_band_kernel`: full heads, grouped queries (``k`` / ``v`` with
+fewer heads than ``q``: query head ``j`` reads KV head ``j // (H / Hkv)``
+*by index*; repeating 8 heads to 128 would be 16x the prompt's keys) and
+a window (``flash_attention(..., window=W)``: row ``t`` attends keys
+``s`` with ``0 <= t - s < W``) alike.  The mask is bottom-right aligned:
+query row i attends key positions <= i + (Tk - Tq), so decode-style
+calls (Tq=1 against a long K/V prefix) attend the whole prefix.  Its
+products take the operands in their own type — bf16 on the matrix unit
+at full rate, ``p`` cast to the value's type before the second product;
+f32 operands stay f32 through both — and accumulate in f32; only the
+triangle's (or the band's) edge blocks pay for masks.  What the call
+can see decides the arithmetic: there is no argument for it.  The
+Pallas call is named by kind, for a device trace's readers:
+``flash_causal`` (full heads, no window: GPT-2's and OLMoE's prompts),
+``flash_grouped``, ``flash_band`` (a window, grouped or not).
 
-Causal masking uses bottom-right alignment: query row i attends to key
-positions <= i + (Tk - Tq), so decode-style calls (Tq=1 against a long K/V
-prefix) attend to the whole prefix.
-
-Grouped queries and a window (``flash_attention(..., window=W)`` or
-``k`` / ``v`` with fewer heads than ``q``) take a second kernel,
-:func:`_band_kernel`: query head ``j`` reads KV head ``j // (H / Hkv)``
-*by index* (repeating 8 heads to 128 would be 16x the prompt's keys),
-row ``t`` attends keys ``s`` with ``0 <= t - s < W``.  Its products take
-the operands in their own type (bf16 on the matrix unit at full rate)
-and accumulate in f32; only a band's edge blocks pay for masks.
+Non-causal calls (the encoder blocks of ``graph/ops.py``; full heads
+only) keep the first kernel, :func:`_attn_kernel`.  Tiling: grid =
+(batch*heads, Tq/block_q, Tk/block_k), the whole rectangle, with the K
+axis innermost; Pallas DMAs one [block_k, d] K/V tile per step while
+the (running max, running denominator, rescaled accumulator) state
+persists in VMEM scratch across the sequential K iterations.  Its
+operands are converted to f32 and its statistics live on one lane of a
+``[block_q, 128]`` scratch, which is why no causal call takes it: at
+896 x 896 on 25 heads of 64 it ran at 2% of the matrix peak where the
+paired kernel runs at 9%, lane padding counted (PERF.md PR 58).
 
 A latent-attention layer's prompt takes a third kernel,
 :func:`flash_latent`: a score is the sum of a head's own product and
 one over a key every head shares, and the value is narrower than the
 key.
 
-**How a grid step of these two finds its pair.**  Their grid is
-``(batch, heads, pairs)``: the last axis walks the (query block, key
-block) pairs that hold an allowed (query, key) — the causal triangle,
-cut by the window where there is one — and no others, so a call's
-steps are its live blocks (136 a head at 8192 / 512, where the
-rectangle over them has 256; 108 under a window of 4096).  The sizes,
+**How a grid step of the two causal kernels finds its pair.**  Their
+grid is ``(batch, heads, pairs)``: the last axis walks the (query
+block, key block) pairs that hold an allowed (query, key) — the causal
+triangle, cut by the window where there is one — and no others, so a
+call's steps are its live blocks (136 a head at 8192 / 512, where the
+rectangle over them has 256; 108 under a window of 4096; 3 at GPT-2's
+896, where the rectangle of 128-row blocks had 49).  The sizes,
 the blocks and the window are static, so :func:`live_pairs` lists the
 pairs on the host, query-block-major, with three bits a pair (the
 query block's first, its last, an edge that needs the mask); the table
@@ -83,8 +93,10 @@ _LANES = 128
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 scale, block_q, block_k, num_kb, t_q, t_k, causal):
-    qi = pl.program_id(1)
+                 scale, block_k, num_kb, t_k):
+    """One (query block, key block) step of the non-causal call: every
+    key is every query's, so the grid is the whole rectangle and the
+    only mask is the key padding's."""
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -93,47 +105,31 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # bottom-right causal alignment: q row r is global position
-    # r + qi*block_q + (t_k - t_q) in key coordinates
-    causal_off = t_k - t_q
-    if causal:
-        # this K block is fully in the future of every query row -> skip
-        live = kb * block_k <= qi * block_q + block_q - 1 + causal_off
-    else:
-        live = True
+    q = q_ref[0].astype(jnp.float32)  # [block_q, d]
+    k = k_ref[0].astype(jnp.float32)  # [block_k, d]
+    v = v_ref[0].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # [bq, bk]
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
+    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mask = k_pos < t_k  # drop key padding
+    s = jnp.where(mask, s, _NEG_INF)
 
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < t_k  # drop key padding
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + causal_off
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_ref[:, :1]  # [bq, 1]
-        l_prev = l_ref[:, :1]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        # rows with no unmasked key yet carry m = -inf; keep them inert
-        safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - safe_m))
-        p = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    m_prev = m_ref[:, :1]  # [bq, 1]
+    l_prev = l_ref[:, :1]
+    m_blk = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_blk)
+    # rows with no unmasked key yet carry m = -inf; keep them inert
+    safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+    alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - safe_m))
+    p = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -331,8 +327,8 @@ def _band_kernel(qi_ref, kb_ref, bits_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
-    """:func:`flash_attention`'s causal path for grouped queries and a
-    window (its docstring)."""
+    """:func:`flash_attention`'s causal path (its docstring): full
+    heads, grouped queries, a window."""
     b, h, t_q, d = q.shape
     hkv, t_k = k.shape[1], k.shape[2]
     if h % hkv:
@@ -347,7 +343,9 @@ def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
         functools.partial(_band_kernel, scale=1.0 / math.sqrt(d), t_q=t_q,
                           t_k=t_k, window=window),
         # by kind, so that a device trace tells a window layer's calls
-        "flash_band" if window is not None else "flash_grouped",
+        # from a grouped layer's and a full-head one's
+        "flash_band" if window is not None else
+        "flash_grouped" if h != hkv else "flash_causal",
         (qp, kp, vp), sizes=(t_q, t_k, block_q, block_k, window),
         in_specs=[pl.BlockSpec((1, 1, block_q, dp), _q_block),
                   pl.BlockSpec((1, 1, block_k, dp), kv_block),
@@ -429,28 +427,30 @@ def flash_attention(q, k, v, *, causal: bool = False,
     (query head ``j`` reads KV head ``j // (H / Hkv)``, by index).  Any
     sizes — inputs are padded to
     MXU-aligned tiles internally and the padding is masked out of the
-    softmax.  ``causal=True`` with Tq != Tk uses bottom-right alignment
-    (decode semantics); with ``window`` a row attends its ``window``
-    newest keys, itself counted, and no key block outside that band is
-    fetched.  ``block_q`` / ``block_k`` default to 128, and to
-    :data:`_BAND_BLOCK` on the grouped or windowed path.
+    softmax.  ``causal=True`` takes the paired kernel (the module's
+    docstring), whose products run in the operands' own type; with Tq !=
+    Tk it uses bottom-right alignment (decode semantics); with
+    ``window`` a row attends its ``window`` newest keys, itself counted,
+    and no key block outside that band is fetched; grouped queries and a
+    window are causal calls only.  ``block_q`` / ``block_k`` default to
+    :data:`_BAND_BLOCK` on the causal path (the fastest of 128, 256 and
+    512 at 512, 896 and 1024 rows on the v5e, PERF.md PR 58) and to 128
+    on the non-causal one, clamped to the sizes.
     ``interpret=None`` auto-selects interpreter mode
     off-TPU so tests exercise the identical kernel on CPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if window is not None or k.shape[1] != q.shape[1]:
-        if not causal:
-            raise ValueError("grouped queries and a window are the causal "
-                             "kernel's: pass causal=True")
+    if causal:
         return _band_attention(
             q, k, v, window=window, block_q=block_q or _BAND_BLOCK,
             block_k=block_k or _BAND_BLOCK, interpret=interpret)
+    if window is not None or k.shape[1] != q.shape[1]:
+        raise ValueError("grouped queries and a window are the causal "
+                         "kernel's: pass causal=True")
     block_q, block_k = block_q or 128, block_k or 128
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    scale = 1.0 / math.sqrt(d)
-    orig_dtype = q.dtype
 
     block_q = min(block_q, max(8, 1 << (t_q - 1).bit_length()))
     block_k = min(block_k, max(8, 1 << (t_k - 1).bit_length()))
@@ -466,8 +466,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     num_qb, num_kb = tqp // block_q, tkp // block_k
 
     kernel = functools.partial(
-        _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        num_kb=num_kb, t_q=t_q, t_k=t_k, causal=causal)
+        _attn_kernel, scale=1.0 / math.sqrt(d), block_k=block_k,
+        num_kb=num_kb, t_k=t_k)
 
     out = pl.pallas_call(
         kernel,
@@ -479,7 +479,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
         ],
         out_specs=pl.BlockSpec((1, block_q, dp),
                                lambda bh, qi, kb: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, tqp, dp), orig_dtype),
+        out_shape=jax.ShapeDtypeStruct((b * h, tqp, dp), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom

@@ -1,12 +1,18 @@
 """The prefill's causal flash kernels alone, timed on the chip:
-``flash_latent``, ``flash_band`` and ``flash_grouped``
+``flash_latent``, ``flash_band``, ``flash_grouped`` and ``flash_causal``
 (``defer_tpu/ops/flash_attention.py``) at the shapes of one call of the
 two long-prompt cells' prefills (Kimi's 64 expanded heads and
 command-a-plus's 128 query heads on 8 KV heads, window 4096 and none,
-one prompt of 8192) and at Jamba's and granite's (prompts of 256 and
-1024, where a head's triangle is one and three pairs).  Chip only.
+one prompt of 8192), at Jamba's and granite's (prompts of 256 and
+1024, where a head's triangle is one and three pairs) and at the two
+full-head families' (GPT-2's 8 prompts of 512 and
+``gpt2xl_long_prompt``'s of 896 on 25 heads of 64, OLMoE's 16 of 1024
+on 16 heads of 128).  Chip only.
 
-    python scripts/flash_kernel_bench.py [OUT.json] [shape ...]
+    python scripts/flash_kernel_bench.py [OUT.json] [shape[:block] ...]
+
+``shape:256`` calls the kernel in blocks of 256 rows and keys in place
+of its own choice (``flash_attention``'s shapes only).
 
 A line a shape: milliseconds a call (``CALLS`` calls behind two
 warm-ups), the call's operations by the benchmark's own functions
@@ -16,7 +22,8 @@ matrix peak, the largest distance from the masked softmax in float32
 over the first heads, the grid's steps and those that work
 (``prefill.flash.grid_steps`` / ``.live_steps``) and microseconds a
 step.  It runs on a tree from before PR 50 too (copy it there), which
-sets no gauges.
+sets no gauges; nor does a full-head call before PR 58 (``_attn_kernel``
+then, whatever the line calls it).
 """
 
 from __future__ import annotations
@@ -51,12 +58,19 @@ SHAPES = {
     "jamba": ("jamba2-3b", "flash_grouped", 64, 256, None),
     "granite": ("granite-4.0-h-small-10l-ep2", "flash_grouped", 8, 1024,
                 None),
+    "gpt2xl": ("gpt2-xl", "flash_causal", 8, 512, None),
+    "gpt2xl_long": ("gpt2-xl", "flash_causal", 8, 896, None),
+    "olmoe": ("olmoe-1b-7b-8l", "flash_causal", 16, 1024, None),
 }
 
 
 def model_args(config: str) -> dict:
+    """The configuration's ``model_args``; a family that names neither
+    has as many KV heads as query heads, of the stream's share."""
     with open(f"chipbench/configs/{config}.json") as f:
-        return json.load(f)["model_args"]
+        a = json.load(f)["model_args"]
+    return {"kv_heads": a["heads"], "head_dim": a["hidden"] // a["heads"],
+            **a}
 
 
 def operands(kernel: str, a: dict, rows: int, t: int):
@@ -97,6 +111,8 @@ def masked_softmax(kernel, ops, window, heads, scale):
 
 
 def run(name: str, peak: float) -> dict:
+    name, _, block = name.partition(":")
+    block = int(block) if block else None
     config, kernel, rows, t, window = SHAPES[name]
     a = model_args(config)
     ops = operands(kernel, a, rows, t)
@@ -113,7 +129,13 @@ def run(name: str, peak: float) -> dict:
             2, a["heads"] // a["kv_heads"])
 
         def call(q, k, v):
-            return flash_attention(q, k, v, causal=True, window=window)
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   block_q=block, block_k=block)
+    # the gauges are set while a call is traced: trace this one anew,
+    # and leave no other shape's reading where a tree sets none
+    jax.clear_caches()
+    for gauge in ("grid_steps", "live_steps"):
+        REGISTRY.gauge(f"prefill.flash.{gauge}").set(0)
     y = call(*ops).block_until_ready()
     call(*ops).block_until_ready()
     t0 = time.perf_counter()
@@ -126,10 +148,11 @@ def run(name: str, peak: float) -> dict:
     steps = REGISTRY.gauge("prefill.flash.grid_steps").value
     live = REGISTRY.gauge("prefill.flash.live_steps").value
     row = {"shape": name, "kernel": kernel, "rows": rows, "prompt_len": t,
-           "window": window, "ms": ms, "flops": flops,
+           "window": window, "block": block, "ms": ms, "flops": flops,
            "peak_share": flops / peak / (ms / 1e3), "grid_steps": steps,
            "live_steps": live, "max_err": err}
-    print(f"{name}: {kernel} {ms:.3f} ms a call, "
+    print(f"{name}{f' in blocks of {block}' if block else ''}: {kernel} "
+          f"{ms:.3f} ms a call, "
           f"{100 * row['peak_share']:.1f}% of the matrix peak, "
           f"err {err:.4f}; "
           # a tree from before PR 50 sets no gauge

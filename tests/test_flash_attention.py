@@ -23,6 +23,9 @@ TOL = 5e-3
     ((1, 2, 100, 100, 24), True),     # non-multiple of block: padding path
     ((2, 2, 37, 53, 8), False),       # Tq != Tk
     ((1, 1, 130, 130, 64), True),     # spills into a second q block
+    ((2, 5, 600, 600, 64), True),     # heads of 64 past a block of 512
+    ((1, 3, 90, 300, 64), True),      # Tq != Tk, bottom-right aligned
+    ((1, 2, 200, 200, 64), False),    # the rectangle keeps its own kernel
 ])
 def test_matches_reference(shape, causal):
     b, h, tq, tk, d = shape
@@ -148,11 +151,10 @@ def _banded_reference(q, k, v, window):
     (64, 1, 16),        # a row sees itself alone
     (512, None, 128),   # 4 x 4 blocks of four lane groups: the sum a lane
     (520, 300, 128),    # the same under a window, ragged
+    (200, None, 64),    # ragged, inner and edge pairs: 4 x 4's triangle
 ], ids=["causal", "under", "at", "over-ragged", "odd-window", "window1",
-        "lanes", "lanes-window"])
+        "lanes", "lanes-window", "ragged-inner-and-edge"])
 def test_band_kernel_matches_the_masked_softmax(h, hkv, t, window, block):
-    if h == hkv and window is None:
-        pytest.skip("the default path: tested above")
     ks = jax.random.split(jax.random.key(t), 3)
     q = jax.random.normal(ks[0], (2, h, t, 16))
     k = jax.random.normal(ks[1], (2, hkv, t, 16))
@@ -176,6 +178,108 @@ def test_band_kernel_decode_alignment_and_default_blocks():
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                atol=3e-2, rtol=3e-2)
+
+
+def _kernel_names(jaxpr) -> list:
+    """The ``pallas_call`` names of a jaxpr, at any depth, in order."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernel_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t_q,t_k", [(200, 200), (72, 200)],
+                         ids=["prompt", "decode-aligned"])
+def test_full_head_causal_takes_the_paired_kernel(dtype, tol, t_q, t_k):
+    """GPT-2's geometry cut small — as many KV heads as query heads, of
+    64, no window — in blocks of 64, so that a head has inner and edge
+    pairs: the causal triangle's pairs and no others, every one live.
+    Operands go to the products in their own type: float32 ones stay
+    far inside what a bfloat16 ``p`` would cost (its 8 bits: ~4e-3)."""
+    from defer_tpu.obs.registry import REGISTRY
+    from defer_tpu.ops.flash_attention import live_pairs
+    ks = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(ks[0], (2, 5, t_q, 64), dtype)
+    k = jax.random.normal(ks[1], (2, 5, t_k, 64), dtype)
+    v = jax.random.normal(ks[2], (2, 5, t_k, 64), dtype)
+    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    assert out.dtype == dtype
+    pairs = live_pairs(t_q, t_k, 64, 64, None).shape[1]
+    assert pairs == {200: 10, 72: 7}[t_q]
+    steps = REGISTRY.gauge("prefill.flash.grid_steps").value
+    assert steps == 2 * 5 * pairs
+    assert REGISTRY.gauge("prefill.flash.live_steps").value == steps
+    ref = _banded_reference(*(a.astype(jnp.float32) for a in (q, k, v)),
+                            None)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("hkv,window,causal,name", [
+    (4, None, True, "flash_causal"),
+    (2, None, True, "flash_grouped"),
+    (4, 8, True, "flash_band"),
+    (2, 8, True, "flash_band"),
+    (4, None, False, None),
+])
+def test_a_trace_tells_the_kernels_apart(hkv, window, causal, name):
+    """A call's Pallas kernel is named by kind (what a device trace's
+    readers select by): a full-head causal call's is its own, and the
+    non-causal rectangle's call carries none."""
+    q = jnp.zeros((1, 4, 40, 16))
+    k = jnp.zeros((1, hkv, 40, 16))
+    jaxpr = jax.make_jaxpr(lambda q, k: flash_attention(
+        q, k, k, causal=causal, window=window))(q, k)
+    assert _kernel_names(jaxpr.jaxpr) == [name]
+
+
+@pytest.mark.parametrize("family", ["gpt_tiny", "olmoe_tiny"])
+def test_a_full_head_family_s_block_is_the_same_under_both_paths(family):
+    """The two families whose prompts are full-head causal calls: a
+    block's prompt form through the paired kernel against plain XLA."""
+    from defer_tpu import models
+    graph = getattr(models, family)(seq_len=48)
+    params = graph.init(jax.random.key(0))
+    op, p = graph.nodes["block_1"].op, params["block_1"]
+    assert op.kv_heads == op.num_heads and op.window is None
+    width = jax.tree.leaves(p["ln1"])[0].size
+    x = jax.random.normal(jax.random.key(2), (2, 40, width))
+    flash = type(op)(**{**vars(op), "attn_impl": "flash"})
+    xla = type(op)(**{**vars(op), "attn_impl": "xla"})
+    np.testing.assert_allclose(np.asarray(flash.apply(p, x)),
+                               np.asarray(xla.apply(p, x)),
+                               atol=2e-5, rtol=2e-5)
+
+
+#: sha256 of ``flash_attention.lower(q, k, k).as_text()`` (non-causal)
+#: on the tree before full-head causal calls left ``_attn_kernel``
+#: (PR 57's): operand shapes and type, the text's hash
+_PARENT_NON_CAUSAL = [
+    ((2, 3, 37, 16), (2, 3, 53, 16), jnp.float32,
+     "f47629e2a1a875084ac654321fa6561087b6ac6929a20680dff5af7c2b9efca2"),
+    ((1, 2, 200, 64), (1, 2, 200, 64), jnp.bfloat16,
+     "fa5e15fdf7ee5f328e3a9e348db5929107dbe1ef374e22ff307f880fea9088b1"),
+]
+
+
+@pytest.mark.parametrize("q_shape,k_shape,dtype,sha", _PARENT_NON_CAUSAL,
+                         ids=["f32-ragged", "bf16-heads-of-64"])
+def test_the_non_causal_call_is_the_parent_s_program(q_shape, k_shape,
+                                                     dtype, sha):
+    """``_attn_kernel`` lost its causal branch and nothing else: the
+    non-causal call lowers to the text it lowered to before, so it
+    returns what it returned, bit for bit."""
+    import hashlib
+    q = jax.ShapeDtypeStruct(q_shape, dtype)
+    k = jax.ShapeDtypeStruct(k_shape, dtype)
+    text = flash_attention.lower(q, k, k).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
 
 
 # (t_q, t_k, block_q, block_k, window) of the pair list's cases
@@ -284,6 +388,12 @@ def test_flash_gauges_count_the_steps_that_work():
                     jnp.zeros((1, 1, 16, 8)), causal=True, block_q=8,
                     block_k=8)
     assert REGISTRY.gauge("prefill.flash.grid_steps").value == 2 * 6
+    assert REGISTRY.gauge("prefill.flash.live_steps").value == 2 * 3
+    # full heads at the path's own blocks: 896 rows are two blocks of
+    # 512, three pairs a head
+    flash_attention(jnp.zeros((1, 2, 896, 8)), jnp.zeros((1, 2, 896, 8)),
+                    jnp.zeros((1, 2, 896, 8)), causal=True)
+    assert REGISTRY.gauge("prefill.flash.grid_steps").value == 2 * 3
     assert REGISTRY.gauge("prefill.flash.live_steps").value == 2 * 3
 
 
