@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.grouped import grouped_gate_up, grouped_product
+from ..ops.routed import expert_dispatch, route_top_k
 from .ir import Op, ShapeSpec
 
 
@@ -725,181 +725,6 @@ class TransformerBlock(Op):
 # ---------------------------------------------------------------------------
 # mixture of experts (expert parallelism rides parallel/expert.py)
 # ---------------------------------------------------------------------------
-
-
-#: the scoring rules :func:`route_top_k` knows
-SCORING_RULES = ("softmax", "sigmoid", "softmax_of_chosen", "noaux_tc",
-                 "softmax_bias")
-
-
-def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
-                scale: float = 1.0):
-    """Scores over the experts in float32, then the ``k`` largest:
-    ``(expert ids [..., k], their weights [..., k])``.  ``scoring`` is
-    the family's rule, named by the block that calls (never a user's
-    flag): ``"softmax"`` — probabilities over all experts, used as the
-    softmax gave them, not renormalised (OLMoE, ``models/olmoe.py``);
-    ``"sigmoid"`` — an independent score an expert, renormalised over
-    the chosen ``k`` (command-a-plus, ``models/cohere_moe.py``);
-    ``"softmax_of_chosen"`` — the ``k`` largest logits, then a softmax
-    over those ``k`` values alone (Granite 4.0-H,
-    ``models/granite_hybrid.py``); ``"noaux_tc"`` — sigmoid scores
-    ``p``, the ``k`` largest of ``p + bias`` (``bias`` [experts], the
-    balancing term: it chooses and never weighs), the chosen ``p``
-    renormalised and multiplied by ``scale`` (Kimi K2 / DeepSeek-V3
-    without a group limit, ``models/kimi_k2.py``); ``"softmax_bias"`` —
-    probabilities ``p`` over *all* columns (a layer's routed experts
-    and, behind them, its zero-compute ones), the ``k`` largest of ``p
-    + bias`` (the bias chooses and never weighs), the chosen ``p``
-    multiplied by ``scale`` and **not renormalised** (LongCat-Flash,
-    ``models/longcat_flash.py``; an id past the routed experts names a
-    zero-compute expert, :func:`zero_expert_pairs`)."""
-    if scoring not in SCORING_RULES:
-        raise ValueError(f"scoring must be one of {SCORING_RULES}, "
-                         f"got {scoring!r}")
-    logits = logits.astype(jnp.float32)
-    if scoring == "softmax":
-        probs = jax.nn.softmax(logits, axis=-1)
-        p, eid = lax.top_k(probs, k)
-        return eid, p
-    if scoring == "softmax_of_chosen":
-        top, eid = lax.top_k(logits, k)
-        return eid, jax.nn.softmax(top, axis=-1)
-    if scoring == "noaux_tc":
-        probs = jax.nn.sigmoid(logits)
-        _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
-        p = jnp.take_along_axis(probs, eid, axis=-1)
-        return eid, scale * p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
-    if scoring == "softmax_bias":
-        probs = jax.nn.softmax(logits, axis=-1)
-        _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
-        return eid, scale * jnp.take_along_axis(probs, eid, axis=-1)
-    p, eid = lax.top_k(jax.nn.sigmoid(logits), k)
-    return eid, p / jnp.sum(p, axis=-1, keepdims=True)
-
-
-def expert_dispatch(x, eid, gate, num_experts: int, expert_fn):
-    """Routed experts on rows grouped by expert: every (row, choice) pair
-    is computed, by its own expert only, and each expert's weights are
-    read once however many rows chose it.  No capacity, nothing dropped.
-
-    ``x`` [T, d]; ``eid``/``gate`` [T, k] (:func:`route_top_k`).
-    ``expert_fn(xs, group_sizes, es)`` maps the [T*k, d] rows sorted by
-    expert (``es`` [T*k] names each row's expert, ``group_sizes`` [E]
-    counts them: the arguments of ``lax.ragged_dot``) to [T*k, d_out].
-    Returns ``(sum_k gate * expert_k(x) [T, d_out] in float32, as it was
-    summed, group_sizes)``."""
-    t, k = eid.shape
-    flat = eid.reshape(t * k)
-    order = jnp.argsort(flat, stable=True)       # slots, grouped by expert
-    sizes = jnp.sum(flat[:, None] == jnp.arange(num_experts)[None, :],
-                    axis=0, dtype=jnp.int32)
-    ys = expert_fn(x[order // k], sizes, flat[order])
-    ys = ys[jnp.argsort(order)].reshape(t, k, -1)       # back to row order
-    y = jnp.sum(ys.astype(jnp.float32)
-                * gate[..., None].astype(jnp.float32), axis=1)
-    return y, sizes
-
-
-def grouped_swiglu(xs, experts, sizes):
-    """The routed experts' SwiGLU on rows sorted by expert: ``xs [rows,
-    d]``, ``experts`` the stacks ``gate`` / ``up [E, d, h]`` and ``down
-    [E, h, d]``, ``sizes [E]`` rows each (they may sum to less than the
-    rows: the ``expert_fn`` of both dispatchers).  ``[rows, d]`` in
-    ``xs``'s type.  Which way a product goes — the kernel that streams
-    the touched matrices once, or the one that tiles a prompt's rows —
-    is its static shape's choice (``ops/grouped.py``)."""
-    a = grouped_gate_up(xs, experts["gate"], experts["up"], sizes)
-    return grouped_product(a, experts["down"], sizes)
-
-
-#: the most (row, choice) pairs one grouped product of
-#: :func:`expert_dispatch_held` takes: a prompt's pairs beyond it are
-#: worked off run by run, as many runs as hold the pairs that fell to
-#: held experts
-_HELD_RUN = 4096
-
-
-def expert_dispatch_held(x, eid, gate, held: tuple[int, int], expert_fn):
-    """:func:`expert_dispatch` for a layer that holds experts
-    ``held[0] .. held[1] - 1`` of those its router chooses among (one
-    chip's share of a layer under expert parallelism): the pairs that
-    fell to a held expert are computed, by that expert; the pairs that
-    fell elsewhere are another chip's, and are **not computed** — they
-    are sorted behind the held ones and no product sees them, not even
-    as zeros.  No capacity, nothing held is dropped.
-
-    ``x`` [T, d]; ``eid``/``gate`` [T, k] over *all* experts
-    (:func:`route_top_k`: the weights stay those of the full choice).
-    ``expert_fn(xs, group_sizes)`` maps rows sorted by held expert
-    (``group_sizes`` [held experts]; they may sum to less than the
-    rows: the rest are no expert's) to [rows, d_out].  Returns ``(the
-    held pairs' weighted sum [T, d_out] in float32, group_sizes)``.
-
-    Up to :data:`_HELD_RUN` pairs are one grouped product.  A prompt
-    has more; its sorted pairs are taken a run at a time, in a loop
-    whose trip count is the number of runs that hold held pairs — with
-    1/8 of the experts held, 1/8 of the runs."""
-    lo, hi = held
-    n_held = hi - lo
-    t, k = eid.shape
-    pairs = t * k
-    flat = eid.reshape(pairs) - lo
-    mine = jnp.logical_and(flat >= 0, flat < n_held)
-    key = jnp.where(mine, flat, n_held)          # absent: sorted last
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
-                    axis=0, dtype=jnp.int32)
-    weight = jnp.where(mine, gate.reshape(pairs).astype(jnp.float32), 0.0)
-    run = min(pairs, _HELD_RUN)
-
-    def one_run(y, start, slots):
-        """The sorted pairs ``start .. start + run - 1`` added to ``y``."""
-        ends = jnp.cumsum(sizes)
-        part = jnp.clip(ends - start, 0, run) \
-            - jnp.clip(ends - sizes - start, 0, run)
-        rows = slots // k
-        ys = expert_fn(x[rows], part).astype(jnp.float32)
-        # rows behind the last group are no expert's: whatever the
-        # product left there must not reach the sum
-        live = (start + jnp.arange(run) < ends[-1])[:, None]
-        return y.at[rows].add(
-            jnp.where(live, ys * weight[slots][:, None], 0.0))
-
-    d_out = jax.eval_shape(
-        expert_fn, jax.ShapeDtypeStruct((run,) + x.shape[1:], x.dtype),
-        jax.ShapeDtypeStruct(sizes.shape, sizes.dtype)).shape[-1]
-    y = jnp.zeros((t, d_out), jnp.float32)
-    if run == pairs:
-        return one_run(y, 0, order), sizes
-    # whole runs only: the tail of the order is padded with pair 0,
-    # which ``live`` masks
-    padded = jnp.concatenate(
-        [order, jnp.zeros((-pairs % run,), order.dtype)])
-
-    def body(i, y):
-        start = i * run
-        return one_run(y, start, lax.dynamic_slice(padded, (start,), (run,)))
-
-    return lax.fori_loop(0, (jnp.sum(sizes) + run - 1) // run, body, y), sizes
-
-
-def zero_expert_pairs(x, eid, gate, num_experts: int):
-    """The third fate of a (row, choice) pair, beside
-    :func:`expert_dispatch_held`'s two (a held expert's: computed;
-    another chip's: left out): a pair whose id is ``>= num_experts``
-    fell to a **zero-compute expert**, the identity — it adds ``weight
-    * x`` and multiplies by no matrix.  Such pairs are never sorted nor
-    dispatched (to the held dispatcher they are no expert's): all of a
-    row's are one multiply of ``x`` by the sum of their weights.
-
-    ``x`` [T, d]; ``eid``/``gate`` [T, k] (:func:`route_top_k` over
-    real and zero columns).  Returns ``(x * sum of the zero pairs'
-    weights [T, d] in float32, the number of zero pairs)``."""
-    zero = eid >= num_experts
-    weight = jnp.sum(jnp.where(zero, gate.astype(jnp.float32), 0.0), axis=-1)
-    return x.astype(jnp.float32) * weight[:, None], \
-        jnp.sum(zero, dtype=jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True, repr=False)

@@ -14,7 +14,7 @@ passes, averaged among themselves; the head tied to the embedding.
 one chip's under expert parallelism).  It still routes over all of
 them — the router's columns, the top ``k`` and the renormalisation are
 the whole layer's — computes the pairs that fell to the experts it
-holds (``graph/ops.py::expert_dispatch_held``) and adds the shared
+holds (``ops/routed.py::expert_dispatch_held``) and adds the shared
 term; the rest of the routed sum is other chips', and nothing here
 stands in for them.
 
@@ -35,8 +35,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import (_cast, expert_dispatch_held, grouped_swiglu,
-                         route_top_k)
+from ..graph.ops import _cast
+from ..ops.routed import held_range, routed_experts
 from .decoder import DecoderBlock
 from .olmoe import OlmoeEmbedding
 
@@ -109,11 +109,7 @@ class CohereMoeBlock(DecoderBlock, Op):
     @property
     def held(self) -> tuple[int, int]:
         """The routed experts this layer holds, ``[lo, hi)``."""
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(f"experts_held {self.experts_held} is no range "
-                             f"of {self.num_experts} experts")
-        return lo, hi
+        return held_range(self.experts_held, self.num_experts)
 
     def init(self, key, in_specs):
         (spec,) = in_specs
@@ -173,29 +169,16 @@ class CohereMoeBlock(DecoderBlock, Op):
         and, from the same normed stream the attention read, the held
         routed experts and the shared ones' mean; all three added to
         the stream in float32, which is rounded once on the way out."""
-        f32, ex = jnp.float32, p["experts"]
+        f32 = jnp.float32
         h = layer_norm(x, p["ln"]["scale"], self.ln_eps)
         attn = jnp.dot(y, p["proj"]["w"], preferred_element_type=f32)
-        # router logits leave the product in float32: rounded, they
-        # would flip the last of the chosen at near-ties
-        eid, gate = route_top_k(
-            jnp.dot(h, p["router"]["w"], preferred_element_type=f32),
-            self.experts_per_tok, scoring="sigmoid")
-
-        routed, sizes = expert_dispatch_held(
-            h, eid, gate, self.held,
-            lambda xs, sizes: grouped_swiglu(xs, ex, sizes))
-        a = jax.nn.silu(h @ p["shared_gate"]["w"]) \
-            * (h @ p["shared_up"]["w"])
-        shared = jnp.dot(a, p["shared_down"]["w"],
-                         preferred_element_type=f32) / self.num_shared
-        if sow is not None:
-            sow["moe.chosen"] = eid             # [T, k]: not a statistic
-            sow["moe.assignments"] = jnp.int32(eid.size)
-            sow["moe.held_assignments"] = jnp.sum(sizes)
-            sow["moe.experts_hit"] = jnp.sum(sizes > 0, dtype=jnp.int32)
-            sow["moe.load_max"] = jnp.max(sizes)
-        return (x.astype(f32) + attn + routed + shared).astype(x.dtype)
+        routed, shared = routed_experts(
+            h, p["router"], p["experts"], k=self.experts_per_tok,
+            scoring="sigmoid", num_experts=self.num_experts, held=self.held,
+            shared=(p["shared_gate"]["w"], p["shared_up"]["w"],
+                    p["shared_down"]["w"]), sow=sow)
+        return (x.astype(f32) + attn + routed
+                + shared / self.num_shared).astype(x.dtype)
 
     # -- full sequence ----------------------------------------------------
 

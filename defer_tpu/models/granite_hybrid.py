@@ -24,7 +24,7 @@ Two kinds of per-sequence memory lie side by side in one graph:
 share of its routed experts** (``experts_held``: one chip's under expert
 parallelism), as ``models/cohere_moe.py``'s may: it routes over all of
 them, keeps the weights of the full choice, computes the pairs that fell
-to the experts it holds (``graph/ops.py::expert_dispatch_held``) and
+to the experts it holds (``ops/routed.py::expert_dispatch_held``) and
 adds the shared expert whole.  Both kinds of block sow one ledger: the
 four ``moe.*`` sums and ``ssm.updates``.
 
@@ -50,9 +50,9 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import (RMSNorm, _cast, expert_dispatch_held,
-                         grouped_swiglu, rms_norm, route_top_k)
+from ..graph.ops import RMSNorm, _cast, rms_norm
 from ..ops import ssm
+from ..ops.routed import held_range, routed_experts
 from .cohere_moe import CohereHead
 from .decoder import DecoderBlock, StateSpaceBlock
 from .olmoe import OlmoeEmbedding
@@ -90,11 +90,7 @@ class _ExpertHalf:
     @property
     def held(self) -> tuple[int, int]:
         """The routed experts this layer holds, ``[lo, hi)``."""
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(f"experts_held {self.experts_held} is no range "
-                             f"of {self.num_experts} experts")
-        return lo, hi
+        return held_range(self.experts_held, self.num_experts)
 
     def _experts_init(self, keys, d: int) -> dict:
         h, sh = self.expert_hidden, self.shared_hidden
@@ -119,27 +115,14 @@ class _ExpertHalf:
         the way out; ``p`` the layer's parameters in ``dtype``.  Fills
         ``sow`` with :data:`_STATS` of this step, ``ssm.updates`` being
         ``updates``."""
-        f32, ex = jnp.float32, p["experts"]
         h = rms_norm(x32, p["ln2"]["scale"], self.rms_eps).astype(dtype)
-        # router logits leave the product in float32: rounded, they
-        # would flip the last of the chosen at near-ties
-        eid, gate = route_top_k(
-            jnp.dot(h, p["router"]["w"], preferred_element_type=f32),
-            self.experts_per_tok, scoring="softmax_of_chosen")
-
-        routed, sizes = expert_dispatch_held(
-            h, eid, gate, self.held,
-            lambda xs, sizes: grouped_swiglu(xs, ex, sizes))
-        a = jax.nn.silu(h @ p["shared_gate"]["w"]) \
-            * (h @ p["shared_up"]["w"])
-        shared = jnp.dot(a, p["shared_down"]["w"],
-                         preferred_element_type=f32)
+        routed, shared = routed_experts(
+            h, p["router"], p["experts"], k=self.experts_per_tok,
+            scoring="softmax_of_chosen", num_experts=self.num_experts,
+            held=self.held,
+            shared=(p["shared_gate"]["w"], p["shared_up"]["w"],
+                    p["shared_down"]["w"]), sow=sow)
         if sow is not None:
-            sow["moe.chosen"] = eid             # [T, k]: not a statistic
-            sow["moe.assignments"] = jnp.int32(eid.size)
-            sow["moe.held_assignments"] = jnp.sum(sizes)
-            sow["moe.experts_hit"] = jnp.sum(sizes > 0, dtype=jnp.int32)
-            sow["moe.load_max"] = jnp.max(sizes)
             sow["ssm.updates"] = jnp.int32(updates)
         return (x32 + self.residual_multiplier * (routed + shared)
                 ).astype(dtype)

@@ -32,7 +32,7 @@ float32 when the graph is built; ``softmax_scale`` is ``(nope + rope)
 ``p`` over all experts, the ``k`` largest of ``p + b`` (``b``, the
 balancing bias, chooses and never weighs), the chosen ``p`` renormalised
 and multiplied by ``routed_scaling_factor``
-(``graph/ops.py::route_top_k``).  A layer may hold a share of its routed
+(``ops/routed.py::route_top_k``).  A layer may hold a share of its routed
 experts (``experts_held``), as ``models/cohere_moe.py``'s do: it routes
 over all of them, computes the pairs that fell to the experts it holds
 and adds the shared expert whole.
@@ -51,8 +51,8 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch_held,
-                         grouped_swiglu, rms_norm, route_top_k)
+from ..graph.ops import Dense, RMSNorm, _cast, rms_norm
+from ..ops.routed import held_range, route, routed_experts
 from .decoder import LatentBlock
 from .latent_attention import LatentAttention, _normal
 from .olmoe import OlmoeEmbedding
@@ -198,15 +198,13 @@ class KimiMoeBlock(_KimiBlock):
 
     _ffn_params = ("router", "experts", "shared_gate", "shared_up",
                    "shared_down")
+    #: the router's rule (``ops/routed.py::route_top_k``)
+    scoring = "noaux_tc"
 
     @property
     def held(self) -> tuple[int, int]:
         """The routed experts this layer holds, ``[lo, hi)``."""
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(f"experts_held {self.experts_held} is no range "
-                             f"of {self.num_experts} experts")
-        return lo, hi
+        return held_range(self.experts_held, self.num_experts)
 
     def init(self, key, in_specs):
         (spec,) = in_specs
@@ -234,30 +232,16 @@ class KimiMoeBlock(_KimiBlock):
         stream ``h`` [T, d] in the type of ``params``: what the layer
         dispatches by."""
         p = _cast(params["router"], params["router"]["w"].dtype)
-        # router logits leave the product in float32: rounded, they
-        # would flip the last of the chosen at near-ties
-        return route_top_k(
-            jnp.dot(h.astype(p["w"].dtype), p["w"],
-                    preferred_element_type=jnp.float32),
-            self.experts_per_tok, scoring="noaux_tc", bias=p["bias"],
-            scale=self.routed_scale)
+        return route(h.astype(p["w"].dtype), p, self.experts_per_tok,
+                     self.scoring, self.routed_scale)
 
     def _ffn(self, p, h, sow=None):
-        f32, ex = jnp.float32, p["experts"]
-        eid, gate = self.route(p, h)
-        routed, sizes = expert_dispatch_held(
-            h, eid, gate, self.held,
-            lambda xs, sizes: grouped_swiglu(xs, ex, sizes))
-        a = jax.nn.silu(h @ p["shared_gate"]["w"]) \
-            * (h @ p["shared_up"]["w"])
-        shared = jnp.dot(a, p["shared_down"]["w"], preferred_element_type=f32)
-        if sow is not None:
-            sow["moe.chosen"] = eid             # [T, k]: not a statistic
-            sow["moe.weights"] = gate           # [T, k]: not one either
-            sow["moe.assignments"] = jnp.int32(eid.size)
-            sow["moe.held_assignments"] = jnp.sum(sizes)
-            sow["moe.experts_hit"] = jnp.sum(sizes > 0, dtype=jnp.int32)
-            sow["moe.load_max"] = jnp.max(sizes)
+        routed, shared = routed_experts(
+            h, p["router"], p["experts"], k=self.experts_per_tok,
+            scoring=self.scoring, num_experts=self.num_experts,
+            held=self.held, scale=self.routed_scale,
+            shared=(p["shared_gate"]["w"], p["shared_up"]["w"],
+                    p["shared_down"]["w"]), sow=sow)
         return routed + shared
 
     def flops(self, in_specs, out_spec):

@@ -26,7 +26,7 @@ crosses a stage cut.
 zero_experts`` columns, ``p = softmax`` over all of them, the
 ``experts_per_tok`` largest of ``p + b`` (the bias chooses and never
 weighs), weights ``routed_scale * p`` of the chosen, **not
-renormalised** (``graph/ops.py::route_top_k``, ``"softmax_bias"``).  A
+renormalised** (``ops/routed.py::route_top_k``, ``"softmax_bias"``).  A
 chosen id below ``num_experts`` is a routed SwiGLU expert; an id from
 there on is a **zero-compute expert**, the identity: it adds ``weight *
 n1`` and multiplies by no matrix (``zero_expert_pairs``).  No shared
@@ -52,9 +52,8 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch_held,
-                         grouped_swiglu, rms_norm, route_top_k,
-                         zero_expert_pairs)
+from ..graph.ops import Dense, RMSNorm, _cast, rms_norm
+from ..ops.routed import held_range, route, routed_experts
 from .decoder import LatentBlock
 from .kimi_k2 import _BIAS_SPREAD
 from .latent_attention import LatentAttention, _normal
@@ -98,6 +97,8 @@ class LongcatFlashBlock(LatentAttention, LatentBlock, Op):
     attn_impl: str = "auto"
 
     sublayers = 2
+    #: the router's rule (``ops/routed.py::route_top_k``)
+    scoring = "softmax_bias"
     decode_stats = ("moe.assignments", "moe.held_assignments",
                     "moe.experts_hit", "moe.load_max",
                     "moe.zero_assignments", "moe.real_assignments")
@@ -105,11 +106,7 @@ class LongcatFlashBlock(LatentAttention, LatentBlock, Op):
     @property
     def held(self) -> tuple[int, int]:
         """The routed experts this layer holds, ``[lo, hi)``."""
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(f"experts_held {self.experts_held} is no range "
-                             f"of {self.num_experts} routed experts")
-        return lo, hi
+        return held_range(self.experts_held, self.num_experts)
 
     def init(self, key, in_specs):
         (spec,) = in_specs
@@ -148,13 +145,8 @@ class LongcatFlashBlock(LatentAttention, LatentBlock, Op):
         k])`` of the normed stream ``h`` [T, d] in the type of
         ``params``: what the layer dispatches by."""
         p = _cast(params["router"], params["router"]["w"].dtype)
-        # router logits leave the product in float32: rounded, they
-        # would flip the last of the chosen at near-ties
-        return route_top_k(
-            jnp.dot(h.astype(p["w"].dtype), p["w"],
-                    preferred_element_type=jnp.float32),
-            self.experts_per_tok, scoring="softmax_bias", bias=p["bias"],
-            scale=self.routed_scale)
+        return route(h.astype(p["w"].dtype), p, self.experts_per_tok,
+                     self.scoring, self.routed_scale)
 
     def shortcut(self, params, h):
         """The shortcut branch alone on a normed stream ``h`` [T, d],
@@ -169,22 +161,11 @@ class LongcatFlashBlock(LatentAttention, LatentBlock, Op):
         """The shortcut branch on the normed stream ``h`` [T, d]: the
         held routed pairs' weighted sum and the zero pairs' ``weight *
         h``, float32."""
-        ex = p["experts"]
-        eid, gate = self.route(p, h)
-        routed, sizes = expert_dispatch_held(
-            h, eid, gate, self.held,
-            lambda xs, sizes: grouped_swiglu(xs, ex, sizes))
-        zero, zeros = zero_expert_pairs(h, eid, gate, self.num_experts)
-        if sow is not None:
-            sow["moe.chosen"] = eid             # [T, k]: not a statistic
-            sow["moe.weights"] = gate           # [T, k]: not one either
-            sow["moe.assignments"] = jnp.int32(eid.size)
-            sow["moe.held_assignments"] = jnp.sum(sizes)
-            sow["moe.experts_hit"] = jnp.sum(sizes > 0, dtype=jnp.int32)
-            sow["moe.load_max"] = jnp.max(sizes)
-            sow["moe.zero_assignments"] = zeros
-            sow["moe.real_assignments"] = jnp.int32(eid.size) - zeros
-        return routed + zero
+        return routed_experts(
+            h, p["router"], p["experts"], k=self.experts_per_tok,
+            scoring=self.scoring, num_experts=self.num_experts,
+            held=self.held, scale=self.routed_scale,
+            zero_experts=self.zero_experts, sow=sow)[0]
 
     def _behind(self, p, x, y, sublayer: int, carry, sow=None):
         """Sublayer ``sublayer`` behind its attention, on the stream

@@ -20,7 +20,7 @@ factor when the graph is built; it knows nothing of the other kind.
 
 **Routing**: a softmax over all experts, the ``k`` largest,
 renormalised over the chosen (``norm_topk_prob``) — which is a softmax
-over the ``k`` chosen logits alone, ``graph/ops.py::route_top_k``'s
+over the ``k`` chosen logits alone, ``ops/routed.py::route_top_k``'s
 ``"softmax_of_chosen"``.  Every layer holds all its experts.
 
 The graph follows the decoder-model contract (``embeddings`` /

@@ -23,8 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.ir import GraphBuilder, LayerGraph, Op
-from ..graph.ops import (Dense, RMSNorm, _cast, expert_dispatch,
-                         grouped_swiglu, rms_norm, route_top_k)
+from ..graph.ops import Dense, RMSNorm, _cast, rms_norm
+from ..ops.routed import routed_experts
 from .decoder import DecoderBlock
 
 
@@ -64,7 +64,7 @@ class OlmoeBlock(DecoderBlock, Op):
     attn_impl: str = "auto"
 
     decode_stats = ("moe.assignments", "moe.experts_hit", "moe.load_max")
-    #: the router's rule (``graph/ops.py::route_top_k``), and the leaves
+    #: the router's rule (``ops/routed.py::route_top_k``), and the leaves
     #: :meth:`_qkv` reads: what a family on this block's routed tail
     #: (``models/mellum.py``) says of itself instead of copying the tail
     scoring = "softmax"
@@ -122,24 +122,13 @@ class OlmoeBlock(DecoderBlock, Op):
         float32; the stream is rounded to its own type once, on the way
         out (rounded after each add, bfloat16 moved near-tied logits
         half again as far: PERF.md, PR 26)."""
-        f32, ex = jnp.float32, p["experts"]
+        f32 = jnp.float32
         x32 = x.astype(f32) + jnp.dot(y, p["proj"]["w"],
                                       preferred_element_type=f32)
         h = rms_norm(x32, p["ln2"]["scale"], self.rms_eps).astype(x.dtype)
-        # router logits leave the product in float32 too: rounded, they
-        # would flip the last of the chosen experts at near-ties
-        eid, gate = route_top_k(
-            jnp.dot(h, p["router"]["w"], preferred_element_type=f32),
-            self.experts_per_tok, scoring=self.scoring)
-
-        out, sizes = expert_dispatch(
-            h, eid, gate, self.num_experts,
-            lambda xs, sizes, _es: grouped_swiglu(xs, ex, sizes))
-        if sow is not None:
-            sow["moe.chosen"] = eid             # [T, k]: not a statistic
-            sow["moe.assignments"] = jnp.sum(sizes)
-            sow["moe.experts_hit"] = jnp.sum(sizes > 0, dtype=jnp.int32)
-            sow["moe.load_max"] = jnp.max(sizes)
+        out, _ = routed_experts(
+            h, p["router"], p["experts"], k=self.experts_per_tok,
+            scoring=self.scoring, num_experts=self.num_experts, sow=sow)
         return (x32 + out).astype(x.dtype)
 
     # -- full sequence ----------------------------------------------------

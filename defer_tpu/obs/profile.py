@@ -137,28 +137,6 @@ DECODE_DISPATCH_PHASES = ("upload", "launch")
 #: the counters
 DECODE_STATS_PHASES = ("moe_stats",)
 
-#: what a ``PipelinedDecoder`` says of the per-sequence memory it holds,
-#: set once when it is built (``runtime/decode.py``): a gauge a kind of
-#: memory (``decode.<kind>.state_bytes``, the kind a block's ``memory``)
-#: and each kind's own parts.  The latent cache's pair is bytes and the
-#: rows those bytes are — their quotient is what a live row costs a step
-#: to read, which ``latent_moe_decode_step_roofline`` holds against the
-#: configuration's 1152 B — and ``latent_sublayers`` the row buffers
-#: they lie in, over the stages (a block of two latent-attention
-#: sublayers keeps two a layer)
-DECODE_MEMORY_GAUGES = (
-    "decode.cache.window_bytes", "decode.cache.full_bytes",
-    "decode.cache.window_positions", "decode.cache.latent_bytes",
-    "decode.cache.latent_positions", "decode.cache.latent_sublayers",
-    "decode.ssm.conv_bytes")
-
-#: the Pallas kernels' names in a device trace, which the benchmark's
-#: kernel readers search for (``chipbench/metrics/*_kernel_roofline.py``)
-KERNEL_NAMES = (
-    "kv_attend", "kv_write_rows", "kv_step", "flash_band", "flash_grouped",
-    "flash_latent", "latent_attend", "retention_step", "ssm_step",
-    "ssm_scan", "ssd_step", "ssd_scan", "grouped_experts", "grouped_rows")
-
 #: the front door's per-request phase on the client's reader thread
 #: (serve/frontdoor.py): prompt frame received -> queued or shed
 DOOR_PHASES = ("admit",)

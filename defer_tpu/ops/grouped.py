@@ -7,7 +7,7 @@ group's — the contract of ``lax.ragged_dot``:
 
     out[r] = xs[r] @ w[g]      for  sum(sizes[:g]) <= r < sum(sizes[:g+1])
 
-``sizes`` may sum to *less* than ``rows`` (``graph/ops.py::
+``sizes`` may sum to *less* than ``rows`` (``ops/routed.py::
 expert_dispatch_held``: the tail is no held expert's); those rows come
 back zero and the caller masks them.
 Operands in one type, bfloat16 or float32; the sum in float32; the
@@ -72,7 +72,7 @@ does: a prompt's rows are read once and no ``[rows, n]`` pair of
 products is written and read back.
 
 * :func:`grouped_product` / :func:`grouped_gate_up` — what the blocks
-  call (through ``graph/ops.py::grouped_swiglu``): the shape rule, then
+  call (through ``ops/routed.py::grouped_swiglu``): the shape rule, then
   one of the two kernels.
 * :func:`grouped_experts` / :func:`grouped_rows` — the Pallas calls,
   whatever the shape.

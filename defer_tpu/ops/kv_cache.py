@@ -917,6 +917,29 @@ class KVCacheFormat(RingRows):
         """The format's entries of a state (any other is its holder's)."""
         return ("k", "v", "ks", "vs") if self.quantized else ("k", "v")
 
+    # -- what a holder posts ---------------------------------------------
+
+    #: a window's rows are a layer's measure: over layers the largest
+    largest = frozenset({"decode.cache.window_positions"})
+
+    def gauges(self, batch: int, stages: int) -> dict[str, int]:
+        """The layer's bytes under its kind — a ring buffer of a
+        window's rows, or a row a position — and the window's rows."""
+        held = stages * self.state_bytes(batch, 1)
+        ring = self.window is not None
+        return {"decode.cache.window_bytes": held if ring else 0,
+                "decode.cache.full_bytes": 0 if ring else held,
+                "decode.cache.window_positions": self.window or 0}
+
+    def rows_read(self, rows: int, positions: int) -> dict[str, int]:
+        """The cached rows the step's attention read, under its kind:
+        ``positions`` of a sequence where every position is kept, the
+        window's at most in a ring buffer."""
+        if self.window is None:
+            return {"decode.cache.full_rows_read": rows * positions}
+        return {"decode.cache.window_rows_read":
+                rows * min(positions, self.window)}
+
     # -- rows ------------------------------------------------------------
 
     def rows(self, k_new, v_new) -> dict:

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -240,6 +241,17 @@ class LatentCacheFormat(RingRows):
             lead + (batch, -(-length // _JOINED_ROWS) * _JOINED_ROWS,
                     self.padded), self.dtype)
         return {key: buf for key in self.keys}
+
+    def gauges(self, batch: int, stages: int) -> dict[str, int]:
+        """The layer's buffers as they are laid out, the rows (of a
+        sequence, a group, a sublayer) those bytes are — their quotient
+        is what a live row costs a step to read — and the buffers."""
+        rows = sum(math.prod(buf.shape[:-1])
+                   for buf in self.buffers(batch).values())
+        return {"decode.cache.latent_bytes":
+                stages * self.state_bytes(batch, 1),
+                "decode.cache.latent_positions": stages * rows,
+                "decode.cache.latent_sublayers": stages * self.sublayers}
 
     def _pad(self, a):
         """``a [..., width]`` with zeros up to the buffer's columns."""

@@ -12,7 +12,10 @@ beside the formats' in the same dict; a format passes them through.
 A format (``ops/kv_cache.py::KVCacheFormat``,
 ``ops/retention.py::RetentionFormat``, ``ops/ssm.py::SsmFormat``) says
 what the buffers are (``buffers(batch)``, ``keys``) and is the one
-place that writes and reads them.
+place that writes and reads them — and the one that says what they are
+to an observer: the gauges of its kind (``gauges``) and what a step
+read of them (``rows_read``), under names of its own.  A holder adds
+up what its layers' formats say (:func:`totals`) and spells no kind.
 """
 
 from __future__ import annotations
@@ -23,9 +26,18 @@ import jax
 import jax.numpy as jnp
 
 
+def nbytes(s) -> int:
+    """Bytes of one buffer (a ``ShapeDtypeStruct`` or an array)."""
+    return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+
+
 class LayeredState:
     """``zeros`` / ``layer`` / ``with_layer`` over a format's own
     ``buffers(batch)`` and ``keys``."""
+
+    #: the names of :meth:`gauges` that measure a layer and are no
+    #: amount: over layers the largest stands, where the others add up
+    largest = frozenset()
 
     def zeros(self, batch: int, layers: int, lead: tuple = ()) -> dict:
         """The empty state of ``layers`` layers: a tuple of buffers under
@@ -62,8 +74,37 @@ class LayeredState:
 
     def state_bytes(self, batch: int, layers: int) -> int:
         """Bytes of ``layers`` layers' buffers for ``batch`` sequences."""
-        return layers * sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-                            for s in self.buffers(batch).values())
+        return layers * sum(map(nbytes, self.buffers(batch).values()))
+
+    # -- what a holder posts of this layer, by gauge name
+
+    def gauges(self, batch: int, stages: int) -> dict[str, int]:
+        """What one layer of this format holds for ``batch`` sequences
+        a group on each of ``stages`` stages, beyond its bytes (the
+        holder's ``decode.<kind>.state_bytes``): the parts and measures
+        its kind of memory has names for.  None, unless a format says."""
+        del batch, stages
+        return {}
+
+    def rows_read(self, rows: int, positions: int) -> dict[str, int]:
+        """What the newest step read of this layer for ``rows``
+        sequences at ``positions`` positions each, where its kind
+        counts that: host integers, from shapes."""
+        del rows, positions
+        return {}
+
+
+def totals(formats, ask) -> dict[str, int]:
+    """What the formats of a holder's layers (``formats``, one a layer)
+    answer to ``ask(fmt)`` — :meth:`LayeredState.gauges` or
+    ``.rows_read`` — added up by name; of a name among a format's
+    ``largest`` the largest."""
+    out: dict[str, int] = {}
+    for fmt in formats:
+        for name, value in ask(fmt).items():
+            out[name] = max(out.get(name, 0), value) \
+                if name in fmt.largest else out.get(name, 0) + value
+    return out
 
 
 def shapes_by_layer(formats, batch: int) -> dict:

@@ -96,7 +96,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
-from .layered import LayeredState
+from .layered import LayeredState, nbytes
 
 #: channels a kernel works on at a time: 16 states x 512 channels of
 #: float32 are 8 vector registers, so a block's ``H`` stays in them
@@ -514,6 +514,11 @@ class _WindowedState(LayeredState):
                 lead + (self.d_conv - 1, batch, self.conv_width), self.dtype),
             "h": jax.ShapeDtypeStruct(
                 lead + (batch, self.states, self.channels), jnp.float32)}
+
+    def gauges(self, batch: int, stages: int) -> dict[str, int]:
+        """The convolution's window, of the layer's bytes."""
+        return {"decode.ssm.conv_bytes":
+                stages * nbytes(self.buffers(batch)["conv"])}
 
     # -- where a ring step's memory goes: a bubble is an identity update
 

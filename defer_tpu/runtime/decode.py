@@ -85,7 +85,7 @@ from ..graph.ir import LayerGraph
 from ..models.decoder import decoder_parts
 from ..obs import REGISTRY, span, spanned_first_call
 from ..ops import quant
-from ..ops.layered import shapes_by_layer, zeros_by_layer
+from ..ops.layered import shapes_by_layer, totals, zeros_by_layer
 from ..parallel.mesh import STAGE_AXIS, pipeline_mesh
 from ..utils.xla_opts import ring_jit_kwargs
 
@@ -290,62 +290,25 @@ class PipelinedDecoder:
                 "hand one sequence's memory to another")
 
         # what the ring holds over its n stages (scratch row and group
-        # included): (kind, format, key, bytes) a buffer a local layer
-        sizes = [(kind, fmt, key, n * math.prod(buf.shape)
-                  * jnp.dtype(buf.dtype).itemsize)
-                 for kind, fmt in zip(self.memory, self.state_formats)
-                 for key, buf in fmt.buffers(mb).items()]
-
-        def held(pick) -> int:
-            return sum(size for kind, fmt, key, size in sizes
-                       if pick(kind, fmt, key))
-
-        # a gauge a kind of memory, and each kind's own parts
+        # included): a gauge a kind of memory, and under names of their
+        # own what the layers' formats say of their kind's parts
         for kind in kinds:
-            REGISTRY.gauge(f"decode.{kind}.state_bytes").set(
-                held(lambda k, fmt, key: k == kind))
-        if "kv_cache" in kinds:
-            # ring buffers of a window's rows, and a row a position
-            REGISTRY.gauge("decode.cache.window_bytes").set(held(
-                lambda k, fmt, key: k == "kv_cache"
-                and fmt.window is not None))
-            REGISTRY.gauge("decode.cache.full_bytes").set(held(
-                lambda k, fmt, key: k == "kv_cache" and fmt.window is None))
-            REGISTRY.gauge("decode.cache.window_positions").set(max(
-                (fmt.window or 0 for k, fmt, _key, _size in sizes
-                 if k == "kv_cache"), default=0))
-        #: where some layer keeps a ring buffer: of every block that
-        #: keeps a KV cache, over all stages, how many rows of a sequence
-        #: its attention reads at most (the window; None: every position
-        #: so far).  Empty where no format has a window: the rows a step
-        #: reads are then one kind's, and no gauge tells kinds apart
-        self._kv_reach = tuple(
-            self.state_formats[l].window
-            for names in self.stage_blocks for l, nm in enumerate(names)
-            if nodes[nm].op.memory == "kv_cache")
-        if all(w is None for w in self._kv_reach):
-            self._kv_reach = ()
-        if "latent_cache" in kinds:
-            # the latent layers' buffers as they are laid out, and the
-            # rows (of a layer, a sequence, a group) those bytes are:
-            # their quotient is what a live row costs a step to read
-            REGISTRY.gauge("decode.cache.latent_bytes").set(held(
-                lambda k, fmt, key: k == "latent_cache"))
-            # (a layer of several sublayers keeps a buffer each: all
-            # are counted)
-            REGISTRY.gauge("decode.cache.latent_positions").set(sum(
-                n * math.prod(buf.shape[:-1])
+            REGISTRY.gauge(f"decode.{kind}.state_bytes").set(sum(
+                n * fmt.state_bytes(mb, 1)
                 for k, fmt in zip(self.memory, self.state_formats)
-                if k == "latent_cache"
-                for buf in fmt.buffers(mb).values()))
-            REGISTRY.gauge("decode.cache.latent_sublayers").set(sum(
-                n * fmt.sublayers
-                for k, fmt in zip(self.memory, self.state_formats)
-                if k == "latent_cache"))
-        if "ssm" in kinds:
-            # the convolutions' windows, of the state-space layers' all
-            REGISTRY.gauge("decode.ssm.conv_bytes").set(held(
-                lambda k, fmt, key: k == "ssm" and key == "conv"))
+                if k == kind))
+        for name, value in totals(self.state_formats,
+                                  lambda fmt: fmt.gauges(mb, n)).items():
+            REGISTRY.gauge(name).set(value)
+        # every block's format, over all stages (a shorter stage keeps
+        # none at its missing places): those whose layers' reads a step
+        # posts (:meth:`_post_rows_read`) — all, where they count them
+        # under several names; where the rows a step reads are one
+        # kind's, no gauge tells kinds apart
+        blocks = tuple(self.state_formats[l] for names in self.stage_blocks
+                       for l in range(len(names)))
+        self._row_readers = blocks if len(totals(
+            blocks, lambda fmt: fmt.rows_read(0, 0))) > 1 else ()
         #: the newest generation's state as it left it (device buffers;
         #: dropped when the next generation begins)
         self.state = None
@@ -1118,20 +1081,15 @@ class PipelinedDecoder:
                 prefill=prefill, on_tokens=on_tokens)
 
     def _post_rows_read(self, rows: int, positions: int) -> None:
-        """Set ``decode.cache.full_rows_read`` / ``.window_rows_read``:
-        the cached rows the attention of the newest step read, over its
-        ``rows`` sequences and the layers of each kind — ``positions``
-        of a sequence in a layer that keeps every position, the window's
-        at most in a ring buffer.  From positions and shapes: host
-        integers, no device work; nothing where no layer has a window."""
-        if not self._kv_reach:
-            return
-        full = sum(w is None for w in self._kv_reach)
-        REGISTRY.gauge("decode.cache.full_rows_read").set(
-            rows * full * positions)
-        REGISTRY.gauge("decode.cache.window_rows_read").set(
-            rows * sum(min(positions, w) for w in self._kv_reach
-                       if w is not None))
+        """Set what the newest step read of the layers' memory for its
+        ``rows`` sequences at ``positions`` positions each, by the
+        names their formats count it under (a KV cache's rows, a
+        window's or a full layer's).  Host integers, no device work;
+        nothing where the formats tell no kinds apart."""
+        for name, value in totals(
+                self._row_readers,
+                lambda fmt: fmt.rows_read(rows, positions)).items():
+            REGISTRY.gauge(name).set(value)
 
     def _post_stats(self, sums: np.ndarray) -> None:
         """Add ``sums``, what the blocks sowed

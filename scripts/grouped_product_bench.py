@@ -112,8 +112,9 @@ def swiglu_gmm(tm, tile_bytes):
 
 #: a prompt's products.  name: (tokens of a piece, choices a token,
 #: experts routed among, held, d, hidden).  Where all are held the
-#: piece's pairs are one product (``expert_dispatch``); else the sorted
-#: held pairs go a run of 4096 at a time (``expert_dispatch_held``) and
+#: piece's pairs are one product (``ops/routed.py::expert_dispatch``);
+#: else the sorted held pairs go a run of 4096 at a time
+#: (``ops/routed.py::expert_dispatch_held``) and
 #: the bench takes the middle run, which holds the few groups it spans
 PREFILL = {"mellum_prefill": (24576, 8, 64, 64, 2304, 896),
            "olmoe_prefill": (16384, 8, 64, 64, 2048, 1024),

@@ -12,11 +12,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from defer_tpu.graph.ops import expert_dispatch_held, route_top_k
 from defer_tpu.models import cohere_moe, cohere_moe_tiny, gpt_tiny
 from defer_tpu.models.cohere_moe import (
     FULL_LAYER, WINDOW_LAYER, CohereMoeBlock, rope_interleaved, tie_head)
 from defer_tpu.obs import REGISTRY
+from defer_tpu.ops.routed import expert_dispatch_held, route_top_k
 from defer_tpu.runtime.decode import PipelinedDecoder
 
 ref = importlib.import_module("chipbench.reference.cohere2_moe")
@@ -211,8 +211,8 @@ def test_held_dispatch_computes_held_pairs_only(monkeypatch, pairs_run):
     """Rows that fell to experts the layer does not hold never reach the
     product — in one run, and in runs of 16 pairs (a loop whose trip
     count is the held pairs')."""
-    import defer_tpu.graph.ops as gops
-    monkeypatch.setattr(gops, "_HELD_RUN", pairs_run)
+    import defer_tpu.ops.routed as routed
+    monkeypatch.setattr(routed, "_HELD_RUN", pairs_run)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(24, 8)), jnp.float32)
     eid = jnp.asarray(np.stack([rng.permutation(16)[:4] for _ in range(24)]))

@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-import defer_tpu.graph.ops as gops
-from defer_tpu.graph.ops import expert_dispatch_held, grouped_swiglu
+import defer_tpu.ops.routed as routed
+from defer_tpu.ops.routed import expert_dispatch_held, grouped_swiglu
 from defer_tpu.models import (cohere_moe_tiny, granite_hybrid_tiny,
                               longcat_flash_tiny, mellum_tiny, olmoe_tiny)
 from defer_tpu.obs import REGISTRY
@@ -166,7 +166,7 @@ def test_the_held_dispatch_on_the_kernel(monkeypatch, pairs_run):
     """``expert_dispatch_held`` end to end with the one SwiGLU under it —
     in one run, whose tail is no held expert's, and in runs of 16
     pairs — against the pairs computed one by one."""
-    monkeypatch.setattr(gops, "_HELD_RUN", pairs_run)
+    monkeypatch.setattr(routed, "_HELD_RUN", pairs_run)
     rng = np.random.default_rng(0)
     f32 = jnp.float32
     x = jnp.asarray(rng.normal(size=(24, 8)), f32)

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 from chipbench.agreement import logit_gaps, rel_err
-from defer_tpu.graph.ops import SCORING_RULES, route_top_k
 from defer_tpu.models import kimi_k2, kimi_k2_tiny
 from defer_tpu.models.decoder import DecoderBlock, LatentBlock, decoder_parts
 from defer_tpu.models.kimi_k2 import (KimiDenseBlock, KimiMoeBlock,
@@ -21,6 +20,7 @@ from defer_tpu.models.kimi_k2 import (KimiDenseBlock, KimiMoeBlock,
 from defer_tpu.obs import REGISTRY
 from defer_tpu.ops import latent_cache
 from defer_tpu.ops.flash_attention import flash_latent
+from defer_tpu.ops.routed import SCORING_RULES, route_top_k
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -503,11 +503,11 @@ def test_the_counters_and_gauges(model, ids):
         == rows * 128 * 4
     assert REGISTRY.gauge("decode.latent_cache.state_bytes").value \
         == rows * 128 * 4
-    # the names are spelled where every program name is
-    from defer_tpu.obs import profile
+    # the names are the format's own: the decoder spells none of them
+    said = dec.state_format.gauges(4, 1)
     assert {"decode.cache.latent_bytes", "decode.cache.latent_positions"} \
-        <= set(profile.DECODE_MEMORY_GAUGES)
-    assert {"latent_attend", "flash_latent"} <= set(profile.KERNEL_NAMES)
+        <= set(said)
+    assert said["decode.cache.latent_positions"] * 5 == rows
     text = dec._get_decode_fn(4, False, None).lower(
         dec._w, jnp.zeros((1, 4, PLEN), jnp.int32), *(jnp.int32(0),) * 3,
         jnp.uint32(0), jnp.float32(0), jnp.zeros((1, 4), jnp.int32),

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from chipbench.agreement import logit_gaps, rel_err
-from defer_tpu.graph.ops import (SCORING_RULES, expert_dispatch_held,
-                                 route_top_k, zero_expert_pairs)
 from defer_tpu.models import kimi_k2_tiny, longcat_flash, longcat_flash_tiny
 from defer_tpu.models.decoder import DecoderBlock, LatentBlock, decoder_parts
 from defer_tpu.models.kimi_k2 import KimiMoeBlock
@@ -26,6 +24,8 @@ from defer_tpu.models.latent_attention import LatentAttention
 from defer_tpu.models.longcat_flash import LongcatFlashBlock
 from defer_tpu.obs import REGISTRY
 from defer_tpu.ops import latent_cache
+from defer_tpu.ops.routed import (SCORING_RULES, expert_dispatch_held,
+                                  route_top_k, zero_expert_pairs)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -519,8 +519,8 @@ def test_the_counters_and_gauges_count_both_sublayers(model, ids):
         == rows * 128 * 4
     assert REGISTRY.gauge("decode.latent_cache.state_bytes").value \
         == rows * 128 * 4
-    from defer_tpu.obs import profile
-    assert "decode.cache.latent_sublayers" in profile.DECODE_MEMORY_GAUGES
+    assert dec.state_format.gauges(4, 1)["decode.cache.latent_sublayers"] \
+        == 2
     text = dec._get_decode_fn(4, False, None).lower(
         dec._w, jnp.zeros((1, 4, PLEN), jnp.int32), *(jnp.int32(0),) * 3,
         jnp.uint32(0), jnp.float32(0), jnp.zeros((1, 4), jnp.int32),

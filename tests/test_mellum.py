@@ -16,11 +16,11 @@ import jax.numpy as jnp
 
 from chipbench.agreement import rel_err
 from defer_tpu import models
-from defer_tpu.graph.ops import route_top_k
 from defer_tpu.models import mellum, mellum_tiny, rotary
 from defer_tpu.models.mellum import FULL_LAYER, WINDOW_LAYER, MellumBlock
 from defer_tpu.models.olmoe import rope
 from defer_tpu.obs import REGISTRY
+from defer_tpu.ops.routed import route_top_k
 from defer_tpu.runtime.decode import PipelinedDecoder
 
 ref = importlib.import_module("chipbench.reference.mellum")
@@ -400,7 +400,7 @@ def test_counters_and_gauges_add_up_over_a_generation(tiny, ids):
     assert REGISTRY.gauge("decode.cache.window_rows_read").value \
         == 4 * 6 * WINDOW
     assert REGISTRY.gauge("decode.cache.window_positions").value == WINDOW
-    assert dec._kv_reach == (8, 8, 8, None) * 2
+    assert [fmt.window for fmt in dec._row_readers] == [8, 8, 8, None] * 2
 
 
 def test_a_family_without_a_window_posts_no_rows_by_kind(ids):
@@ -409,7 +409,7 @@ def test_a_family_without_a_window_posts_no_rows_by_kind(ids):
     graph = models.olmoe_tiny(SEQ, VOCAB)
     dec = PipelinedDecoder(graph, graph.init(jax.random.key(0)),
                            num_stages=1, microbatch=4, max_len=SEQ)
-    assert dec._kv_reach == ()
+    assert dec._row_readers == ()
     for kind in ("full", "window"):
         REGISTRY.gauge(f"decode.cache.{kind}_rows_read").set(-1)
     dec.generate(ids[:, :PLEN], 5, prefill=True, token_chunk=4)

@@ -23,10 +23,11 @@ import jax.numpy as jnp
 import defer_tpu as dt
 from chipbench.agreement import rel_err
 from chipbench.reference import olmoe as ref
-from defer_tpu.graph.ops import MoE, expert_dispatch, route_top_k
+from defer_tpu.graph.ops import MoE
 from defer_tpu.models import gpt_tiny, olmoe, olmoe_tiny
 from defer_tpu.models.decoder import DecoderBlock, decoder_parts
 from defer_tpu.models.olmoe import OlmoeBlock
+from defer_tpu.ops.routed import expert_dispatch, route_top_k
 from defer_tpu.obs import REGISTRY
 from defer_tpu.ops.kv_cache import KVCacheFormat
 from defer_tpu.runtime.decode import PipelinedDecoder
