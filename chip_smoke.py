@@ -448,6 +448,7 @@ def phase_decode(run: Run) -> None:
     import jax.numpy as jnp
 
     from defer_tpu import PipelinedDecoder, pipeline_mesh
+    from defer_tpu.obs import REGISTRY
 
     sz, n = run.sizes, run.n
     graph, params = run.model(sz.lm, 2)
@@ -467,6 +468,9 @@ def phase_decode(run: Run) -> None:
                                microbatch=sz.lm_microbatch,
                                max_len=sz.lm_max_len,
                                mesh=pipeline_mesh(n))
+        # the cut the bytes chose, from the decoder's own gauges
+        ph.note(cut=[int(REGISTRY.gauge(f"decode.cut.blocks.{s}").value)
+                     for s in range(n)])
         t0 = time.perf_counter()
         toks_pre = dec.generate(prompts, new, prefill=True)
         ph.note(first_call_s=round(time.perf_counter() - t0, 3))

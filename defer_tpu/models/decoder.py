@@ -33,6 +33,7 @@ it, whatever its family.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Any
 
@@ -464,7 +465,9 @@ class LatentBlock(DecoderBlock):
 
 
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
-    """Contiguous, balanced block assignment (stage i gets ~L/N blocks)."""
+    """Contiguous, balanced block assignment (stage i gets ~L/N blocks):
+    the even rule, which counts blocks and no ends.  The cut a holder's
+    bytes choose (:func:`balanced_cut`) falls back on it at a tie."""
     bounds = [round(num_blocks * s / num_stages)
               for s in range(num_stages + 1)]
     out = [list(range(bounds[s], bounds[s + 1])) for s in range(num_stages)]
@@ -472,6 +475,96 @@ def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
         raise ValueError(
             f"{num_blocks} blocks cannot fill {num_stages} stages")
     return out
+
+
+def _off_pattern(cut, kinds):
+    """The first layer of ``cut`` (blocks a stage) whose kind of memory
+    is not that of the longest stage's layer at the same place: ``(its
+    stage, its place there, its block, the longest stage's block)``, or
+    None where every stage repeats the longest's ``kinds`` in order."""
+    bounds = list(itertools.accumulate(cut, initial=0))
+    at = bounds[cut.index(max(cut))]        # where the longest stage starts
+    return next(((s, l, b + l, at + l)
+                 for s, (b, count) in enumerate(zip(bounds, cut))
+                 for l in range(count) if kinds[b + l] != kinds[at + l]),
+                None)
+
+
+def check_cut(cut, names, kinds) -> None:
+    """Refuse ``cut`` (blocks a stage, over the blocks ``names`` in
+    order) unless the ring can run it: a block or more on every stage,
+    all of them laid out, and every stage's layers repeating the longest
+    stage's ``kinds`` of memory (one a block: its format) in order."""
+    if sum(cut) != len(names) or min(cut) < 1:
+        raise ValueError(
+            f"cut {list(cut)} does not lay {len(names)} blocks on "
+            f"{len(cut)} stages, one or more a stage")
+    off = _off_pattern(cut, kinds)
+    if off is not None:
+        s, l, mine, theirs = off
+        raise ValueError(
+            f"stage {s}'s layer {l} ({names[mine]}) keeps {kinds[mine]}, "
+            f"{names[theirs]} at the same place of its stage "
+            f"{kinds[theirs]}: the ring shards one buffer a local layer "
+            "over the stages, so every stage's layers must repeat the "
+            "same kinds of memory in the same order (cut the graph at a "
+            "whole period of its layer pattern)")
+
+
+def balanced_cut(costs, num_stages: int, kinds=None, *, first=0,
+                 last=0) -> list[int]:
+    """Blocks a stage: the contiguous cut of ``len(costs)`` blocks whose
+    costliest stage costs least, among the cuts :func:`check_cut` lets
+    pass.  A stage costs its blocks' ``costs`` and, on the first stage,
+    ``first``, on the last ``last`` (the ends it holds besides; whole
+    numbers — a holder's bytes — tie exactly).  Ties: :func:`split_blocks`'
+    cut where it is among the best, else the fewest blocks on the
+    longest stage (the fewest zero leaves), then the bounds nearest the
+    even cut's.  Where no cut passes, the even one, for its refusal."""
+    n = len(costs)
+    kinds = list(kinds) if kinds is not None else [None] * n
+    even = [len(b) for b in split_blocks(n, num_stages)]
+    even_bounds = list(itertools.accumulate(even))
+    cum = list(itertools.accumulate(costs, initial=0))
+    ends = [0] * num_stages
+    ends[0] += first
+    ends[-1] += last
+
+    def rank(cut):
+        bounds = list(itertools.accumulate(cut, initial=0))
+        return (max(cum[b + c] - cum[b] + e
+                    for b, c, e in zip(bounds, cut, ends)),
+                cut != even, max(cut),
+                sum(abs(b - e) for b, e in zip(bounds[1:], even_bounds)))
+
+    best = None     # (its rank, the cut)
+
+    def offer(cut):
+        nonlocal best
+        if _off_pattern(cut, kinds) is None:
+            ranked = rank(cut), cut
+            if best is None or ranked < best:
+                best = ranked
+
+    offer(even)
+
+    def grow(cut, at):
+        stage, left = len(cut), num_stages - len(cut) - 1
+        if not left:
+            offer(cut + [n - at])
+            return
+        for count in range(1, n - at - left + 1):
+            if best is not None:
+                # no stage over the best cut's costliest; and what is
+                # left has to fit under it on the stages that are left
+                if cum[at + count] - cum[at] + ends[stage] > best[0][0]:
+                    break
+                if cum[n] - cum[at + count] + ends[-1] > left * best[0][0]:
+                    continue
+            grow(cut + [count], at + count)
+
+    grow([], 0)
+    return best[1] if best is not None else even
 
 
 @dataclasses.dataclass(frozen=True)
@@ -483,7 +576,7 @@ class DecoderParts:
     d_model: int                #: the stream's width, every block's
     vocab: int
     max_len: int                #: positions a cache is to hold
-    stage_blocks: list          #: per stage, its blocks' names, balanced
+    stage_blocks: list          #: per stage, its blocks' names
     decode_stats: tuple         #: what every block sows each step
     #: per block, the kind of memory it keeps (``DecoderBlock.memory``)
     memory: tuple
@@ -493,10 +586,25 @@ class DecoderParts:
 
 
 def decoder_parts(graph: LayerGraph, num_stages: int,
-                  max_len: int | None = None) -> DecoderParts:
+                  max_len: int | None = None, *, cut=None,
+                  step_bytes=None) -> DecoderParts:
     """Check ``graph`` against the contract (module docstring) and
     return its parts; ``max_len`` defaults to the positions the model
-    declares and may not exceed them."""
+    declares and may not exceed them.
+
+    Which blocks a stage holds (``stage_blocks``): with one stage all
+    of them, and nothing is reckoned.  With more, ``cut`` (blocks a
+    stage, ``[8, 8, 8, 4]``) where a caller hands one in — a planner
+    that timed the stages, a deployment whose layer pattern it knows —
+    refused by :func:`check_cut` unless the ring can run it.  Else,
+    where the holder says what one ring step reads of each node
+    (``step_bytes``: ``{node: bytes}`` over the blocks and the three
+    ends), :func:`balanced_cut` over those: a stage costs what it reads
+    a step, the first the embedding's gathered rows and the last the
+    final norm and the head on top of their blocks, so a large head
+    takes layers off the last stage.  Else the even rule
+    (:func:`split_blocks`), unchecked: the serving engine walks the
+    blocks in order and its stages are the planner's structure alone."""
     nodes = graph.nodes
     for req in ("embeddings", "final_ln", "lm_head"):
         if req not in nodes:
@@ -519,10 +627,31 @@ def decoder_parts(graph: LayerGraph, num_stages: int,
                 "(models/decoder.py): the decode engines need its "
                 "decode / prefill and the halves they are made of")
     # an empty block list is refused with split_blocks' message
-    stage_blocks = [[block_names[i] for i in idxs]
-                    for idxs in split_blocks(len(block_names), num_stages)]
+    even = [len(b) for b in split_blocks(len(block_names), num_stages)]
     first = nodes[block_names[0]]
     d_model = first.out_spec.shape[-1]
+    ops = [nodes[nm].op for nm in block_names]
+    if cut is None and (num_stages == 1 or step_bytes is None):
+        cut = even
+    else:
+        # a layer's kind of memory is its format; the type and the
+        # quantisation are the holder's, alike on every layer, and
+        # stand out of the comparison
+        kinds = [op.memory_format(d_model, max_len, jnp.float32,
+                                  groups=num_stages) for op in ops]
+        if cut is None:
+            cut = balanced_cut(
+                [step_bytes[nm] for nm in block_names], num_stages, kinds,
+                first=step_bytes["embeddings"],
+                last=step_bytes["final_ln"] + step_bytes["lm_head"])
+        if len(cut) != num_stages:
+            raise ValueError(
+                f"cut {list(cut)} names {len(cut)} stages, the ring has "
+                f"{num_stages}")
+        check_cut(list(cut), block_names, kinds)
+    bounds = list(itertools.accumulate(cut, initial=0))
+    stage_blocks = [list(block_names[b:b + count])
+                    for b, count in zip(bounds, cut)]
     stats = tuple(first.op.decode_stats)
     for nm in block_names:
         op = nodes[nm].op
@@ -537,7 +666,6 @@ def decoder_parts(graph: LayerGraph, num_stages: int,
             raise ValueError(
                 f"{nm} sows {op.decode_stats}, block_0 {stats}: one "
                 "ledger serves every block")
-    ops = [nodes[nm].op for nm in block_names]
     return DecoderParts(
         embed_op=embed_op, block_names=block_names, d_model=d_model,
         vocab=nodes["lm_head"].out_spec.shape[-1], max_len=max_len,

@@ -24,6 +24,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def nbytes(s) -> int:
@@ -49,6 +50,20 @@ class LayeredState:
     def layer(self, state: dict, l: int) -> dict:
         """Layer ``l``'s buffers out of a state."""
         return {key: state[key][l] for key in self.keys}
+
+    @staticmethod
+    def idle(layer: dict) -> dict:
+        """``layer``'s buffers as a stage hands them on that holds no
+        such layer (a shorter stage of the ring: the buffers are the
+        longer stages', sharded over all, and dead storage here): each
+        with one element rewritten in place.  A buffer a branch of a
+        conditional only passes through is copied whole on its way out
+        (XLA:TPU, every step: 49 MB a layer in the four-chip cell,
+        0.11 ms of a 1.4 ms ring step); one that every branch writes in
+        place is handed on where it lies."""
+        return {key: lax.dynamic_update_slice(
+            buf, jnp.zeros((1,) * buf.ndim, buf.dtype), (0,) * buf.ndim)
+            for key, buf in layer.items()}
 
     @staticmethod
     def with_layer(state: dict, l: int, layer: dict) -> dict:

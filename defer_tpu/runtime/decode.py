@@ -16,6 +16,11 @@ TPU-native design, one SPMD program:
     ``weight_dtype="int8"``), every leaf a stage-sharded argument of its
     own, in its own shape: local block ``l``'s leaves stacked over the
     stages, the leaves of the nodes outside the blocks by name.
+  * The cut: a ring step lasts as long as its costliest stage, so the
+    stages are cut where the costliest reads the fewest bytes a step —
+    its blocks' leaves and the ends it holds, the head on the last
+    (``models/decoder.py::balanced_cut``; ``split_blocks``' even cut
+    wherever that is as good); ``cut=`` hands another in.
   * Sequence memory: per device, one resident buffer a local block and
     key, held and touched only through the format *that block* names
     (``DecoderBlock.memory_format``: kind, geometry and length are the
@@ -176,6 +181,22 @@ class PipelinedDecoder:
 
     ``prompt_ids`` is [B, prompt_len] with B <= num_stages * microbatch;
     returns [B, prompt_len + max_new_tokens].
+
+    Which blocks a stage holds (``stage_blocks``) is chosen when the
+    decoder is built, from the graph and ``params`` alone: a stage costs
+    the bytes it reads in one ring step — its blocks' leaves as they are
+    placed, ``final_ln`` and ``lm_head`` on the last stage, the
+    embedding's gathered rows on the first — and the cut is the
+    contiguous one whose costliest stage costs least among those the
+    ring can run (every stage repeating the longest stage's kinds of
+    memory in order); the even cut wherever it is among the best
+    (``models/decoder.py::decoder_parts``).  ``cut`` (blocks a stage,
+    ``[8, 8, 8, 4]``) hands one in instead, checked by the same rule:
+    for a planner that timed the stages or a deployment that knows what
+    its routed layers read, not a knob of the default.  With one stage
+    there is nothing to choose.  The gauges ``decode.cut.blocks.<s>``,
+    ``decode.cut.stage_bytes_max`` and ``.stage_bytes_mean`` say what
+    was chosen.
     """
 
     def __init__(
@@ -191,6 +212,7 @@ class PipelinedDecoder:
         kv_cache: str = "buffer",
         weight_dtype: str | None = None,
         beam_width: int = 1,
+        cut=None,
     ):
         self.graph = graph
         self.num_stages = n = num_stages
@@ -220,7 +242,20 @@ class PipelinedDecoder:
                 "microbatch/beam_width sequences x beam_width beams)")
         self.beam_width = beam_width
 
-        parts = decoder_parts(graph, n, max_len)
+        # weights live in the compute dtype (the runtime/spmd.py recipe):
+        # bf16 deployments read 2 bytes/param from HBM per decode step with
+        # no per-step downcast materialization
+        self._wdt = np.dtype(jnp.bfloat16) \
+            if self.compute_dtype == jnp.bfloat16 else np.dtype(np.float32)
+        # what the deployment holds of each node, and what one ring step
+        # reads of it: its leaves whole, but of the embedding's tables
+        # the rows a group gathers — what a stage costs, by which the
+        # stages are cut (with one stage nothing is; a graph that lacks
+        # a node is ``decoder_parts``' to refuse)
+        held = {nm: self._node_bytes(tree) for nm, tree in params.items()}
+        reads = dict(held, embeddings=self._node_bytes(
+            params.get("embeddings"), rows=mb))
+        parts = decoder_parts(graph, n, max_len, cut=cut, step_bytes=reads)
         self.embed_op = parts.embed_op
         self.max_len = max_len = parts.max_len
         self.block_names = list(parts.block_names)
@@ -233,31 +268,36 @@ class PipelinedDecoder:
         self.l_max = max(len(b) for b in self.stage_blocks)
         nodes = graph.nodes
         #: each local block's memory (n groups of mb sequences), in the
-        #: format that block names: one a local layer, the same on
-        #: every stage (asked first: a cut the formats refuse would
-        #: fail later, less clearly, where the stages' leaves are laid
-        #: side by side)
-        self.state_formats = self._layer_formats()
+        #: format that block names: one a local layer, the longest
+        #: stage's, which ``decoder_parts`` saw every other stage repeat
+        #: in order — a buffer is sharded over the stages, so local
+        #: layer ``l`` has one shape on all of them
+        self.state_formats = tuple(
+            nodes[nm].op.memory_format(
+                self.d_model, max_len, self.compute_dtype,
+                quantized=kv_cache == "int8", groups=n)
+            for nm in max(self.stage_blocks, key=len))
 
         #: the nodes outside the blocks, with the one stage that holds
         #: each
         self._ends = {"embeddings": 0, "final_ln": n - 1, "lm_head": n - 1}
-        # weights live in the compute dtype (the runtime/spmd.py recipe):
-        # bf16 deployments read 2 bytes/param from HBM per decode step with
-        # no per-step downcast materialization
-        self._wdt = np.dtype(jnp.bfloat16) \
-            if self.compute_dtype == jnp.bfloat16 else np.dtype(np.float32)
         self._w = self._place_weights(params, init=True)
-        # what the deployment holds: leaves as placed (int8 values and
-        # their scales under W8A16), before the device's tiling pads
-        # them, a shorter stage's zeros and other stages' ends not
-        # counted
-        shapes = [shape for node in self._layout.values()
-                  for shape, _ in node.values()]
+        # what the deployment holds: leaves as placed, before the
+        # device's tiling pads them, a shorter stage's zeros and other
+        # stages' ends not counted
         REGISTRY.gauge("decode.weights.own_bytes").set(
-            sum(math.prod(sh) + 4 * math.prod(sh[-1:]) for sh in shapes)
-            if self.weight_quant
-            else self._wdt.itemsize * sum(map(math.prod, shapes)))
+            sum(held[nm] for nm in self._layout))
+        # the cut: blocks a stage, and what a stage reads in one ring
+        # step (its blocks and the ends it holds) — the largest against
+        # the mean says how far the longest stage holds the others back
+        stage_bytes = []
+        for s, names in enumerate(self.stage_blocks):
+            REGISTRY.gauge(f"decode.cut.blocks.{s}").set(len(names))
+            ends = [nm for nm, at in self._ends.items() if at == s]
+            stage_bytes.append(sum(reads[nm] for nm in (*names, *ends)))
+        REGISTRY.gauge("decode.cut.stage_bytes_max").set(max(stage_bytes))
+        REGISTRY.gauge("decode.cut.stage_bytes_mean").set(
+            sum(stage_bytes) / n)
         # the arrays (one a leaf, stage-sharded) that the device would
         # have laid out otherwise than row-major, and their bytes over
         # the stages: what every dispatch converted while they lay in
@@ -333,32 +373,18 @@ class PipelinedDecoder:
 
     # ------------------------------------------------------------------
 
-    def _layer_formats(self) -> tuple:
-        """The memory format of each local layer, asked of the blocks:
-        the longest stage's, which every other stage's blocks must
-        repeat in order — a buffer is sharded over the stages, so local
-        layer ``l`` has one shape on all of them."""
-        nodes = self.graph.nodes
-
-        def fmt(nm):
-            return nodes[nm].op.memory_format(
-                self.d_model, self.max_len, self.compute_dtype,
-                quantized=self.kv_cache == "int8", groups=self.num_stages)
-
-        longest = max(self.stage_blocks, key=len)
-        formats = tuple(fmt(nm) for nm in longest)
-        for s, names in enumerate(self.stage_blocks):
-            for l, nm in enumerate(names):
-                if fmt(nm) != formats[l]:
-                    raise ValueError(
-                        f"stage {s}'s layer {l} ({nm}) keeps "
-                        f"{fmt(nm)}, {longest[l]} at the same place of "
-                        f"its stage {formats[l]}: the ring shards one "
-                        "buffer a local layer over the stages, so every "
-                        "stage's layers must repeat the same kinds of "
-                        "memory in the same order (cut the graph at a "
-                        "whole period of its layer pattern)")
-        return formats
+    def _node_bytes(self, tree, rows: int | None = None) -> int:
+        """Bytes of a node's leaves as :meth:`_place_weights` lays them
+        (the compute type's; int8 values and a float32 scale a channel
+        under W8A16), from their shapes; with ``rows``, of that many
+        rows of each."""
+        shapes = [np.shape(leaf) if rows is None else
+                  (rows, *np.shape(leaf)[1:])
+                  for leaf in jax.tree.leaves(tree)]
+        if self.weight_quant:
+            return sum(math.prod(sh) + 4 * math.prod(sh[-1:])
+                       for sh in shapes)
+        return self._wdt.itemsize * sum(map(math.prod, shapes))
 
     def _prefill_rows(self, plen: int) -> int:
         """Sequences of a group that cross a stage's prefill at once:
@@ -601,6 +627,7 @@ class PipelinedDecoder:
                     step = jnp.stack([sown[k] for k in stats])
                     caches = dict(caches, stats=caches["stats"] + jnp.where(
                         valid, step.astype(jnp.int32), 0))
+            caches = self._idle_layers(s, caches)
 
             if is_last:
                 h = nodes["final_ln"].op.apply(p["final_ln"], x)
@@ -659,6 +686,15 @@ class PipelinedDecoder:
             return a_out, caches
 
         return branch
+
+    def _idle_layers(self, s: int, caches):
+        """``caches`` as stage ``s`` hands them on: the local layers it
+        lacks (the longest stage's last) touched in place, so that no
+        branch passes a buffer through (``LayeredState.idle``)."""
+        for l in range(len(self.stage_blocks[s]), self.l_max):
+            fmt = self.state_formats[l]
+            caches = fmt.with_layer(caches, l, fmt.idle(fmt.layer(caches, l)))
+        return caches
 
     def _make_prefill_branch(self, s: int, plen: int, sample: bool,
                              top_k: int | None):
@@ -739,6 +775,7 @@ class PipelinedDecoder:
                                        jnp.arange(mb // rows))
                 out = out.reshape((mb,) + out.shape[2:])
 
+            caches = self._idle_layers(s, caches)
             if is_last:         # ``out`` the last position's logits
                 if sample:
                     # key domain disjoint from decode's per-step keys

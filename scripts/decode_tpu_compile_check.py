@@ -134,6 +134,9 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
                rf"%{name}[.\d]* = .*tpu_custom_call", text))
                for name in ("kv_step", "kv_attend", "kv_write_rows")},
            "fused_layers": int(REGISTRY.gauge("decode.kv.fused_layers").value),
+           # blocks a stage, as the bytes cut them (``decode.cut.blocks``)
+           "cut": [int(REGISTRY.gauge(f"decode.cut.blocks.{s}").value)
+                   for s in range(stages)],
            "argument_bytes": mem.argument_size_in_bytes,
            "temp_bytes": mem.temp_size_in_bytes}
     dump = os.environ.get("DECODE_CHECK_DUMP")

@@ -277,7 +277,12 @@ def test_a_stage_whose_kinds_do_not_repeat_is_refused(tiny):
     with pytest.raises(ValueError, match="same kinds of memory in the same "
                                          "order"):
         PipelinedDecoder(graph, params, num_stages=4, microbatch=1,
-                         max_len=32)
+                         max_len=32, cut=[2, 2, 2, 2])
+    # left to the bytes, every stage opens where the pattern does: the
+    # full layer closes a stage of three, a window layer is one alone
+    dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=1,
+                           max_len=32)
+    assert [len(b) for b in dec.stage_blocks] == [1, 3, 1, 3]
     # a graph of one kind splits anywhere
     g = gpt_tiny()
     PipelinedDecoder(g, g.init(jax.random.key(0)), num_stages=2,

@@ -21,7 +21,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from defer_tpu.graph.ir import GraphBuilder
 from defer_tpu.graph.ops import Dense, LayerNorm
 from defer_tpu.models import gpt_stage_cuts, gpt_tiny
-from defer_tpu.models.decoder import decoder_parts, split_blocks
+from defer_tpu.models.decoder import (balanced_cut, decoder_parts,
+                                      split_blocks)
 from defer_tpu.models.gpt import CausalTransformerBlock, GptEmbedding
 from defer_tpu.ops.kv_cache import KVCacheFormat
 from defer_tpu.runtime.decode import (PipelinedDecoder, off_default_layout,
@@ -1081,6 +1082,196 @@ def test_split_blocks():
     assert split_blocks(5, 2) == [[0, 1], [2, 3, 4]]
     with pytest.raises(ValueError):
         split_blocks(2, 4)
+
+
+#: one GPT-2 XL layer's bf16 leaves, and its final norm + head's
+_XL_LAYER, _XL_HEAD = 61_493_600, 160_828_800
+
+
+@pytest.mark.parametrize("costs,stages,kwargs,want", [
+    # the ends are counted: a head worth two blocks takes blocks off the
+    # last stage (2 | 3 | 1 + 2 where the even rule's 2 | 2 | 2 + 2 is 4)
+    pytest.param([1] * 6, 3, {"last": 2}, [2, 3, 1], id="ends_counted"),
+    # a small head: split_blocks' cut, exactly, rounding and all
+    pytest.param([100] * 6, 3, {"last": 2}, [2, 2, 2], id="small_head"),
+    # the even rule's rounding stands where it ties (3 | 2 costs the
+    # same), and falls to the head where it put the odd block beside it
+    pytest.param([100] * 5, 2, {}, [2, 3], id="a_tie_is_the_even_rounding"),
+    pytest.param([100] * 5, 2, {"first": 1, "last": 3}, [3, 2],
+                 id="the_odd_block_leaves_the_heads_stage"),
+    pytest.param([100] * 7, 3, {}, [2, 3, 2], id="no_ends_is_the_even_rule"),
+    pytest.param([5, 1, 7], 1, {"first": 3, "last": 9}, [3], id="one_stage"),
+    # a pattern of 4: only whole periods (and a last stage that is a
+    # prefix of one) repeat the longest stage's kinds; the head goes
+    # with the short stage
+    pytest.param([10] * 28, 4, {"kinds": list("ssas") * 7, "last": 15},
+                 [8, 8, 8, 4], id="pattern_of_4_over_28"),
+    # blocks of unlike cost: the costliest stage decides, not the count
+    pytest.param([4, 1, 1, 1, 1], 2, {}, [1, 4], id="unlike_costs"),
+    # no cut of these kinds repeats a longest stage: the even one, for
+    # the holder to refuse
+    pytest.param([3, 1, 1, 1, 3, 3], 3, {"kinds": list("abcdab")},
+                 [2, 2, 2], id="no_cut_passes_is_the_even_one"),
+    # GPT-2 XL on four chips: 48 layers, the head 2.6 of them, the
+    # first stage's gathered rows next to nothing — but not nothing
+    pytest.param([_XL_LAYER] * 48, 4, {"first": 12_800, "last": _XL_HEAD},
+                 [12, 13, 13, 10], id="gpt2xl_on_four"),
+    pytest.param([_XL_LAYER] * 48, 8, {"first": 12_800, "last": _XL_HEAD},
+                 [6, 6, 6, 6, 6, 7, 7, 4], id="gpt2xl_on_eight"),
+])
+def test_balanced_cut(costs, stages, kwargs, want):
+    """The contiguous cut whose costliest stage costs least, ends
+    counted; ``split_blocks``' own wherever that is among the best."""
+    got = balanced_cut(costs, stages, **kwargs)
+    assert got == want and sum(got) == len(costs)
+
+
+@pytest.mark.parametrize("layers,stages,head", [
+    (28, 4, 15), (28, 4, 0), (16, 3, 25), (8, 4, 15), (24, 5, 40)])
+def test_balanced_cut_never_cuts_inside_a_period(layers, stages, head):
+    """Over a pattern of 4 every stage opens where the pattern does and
+    repeats the longest stage's kinds as far as it goes."""
+    kinds = (list("ssas") * layers)[:layers]
+    got = balanced_cut([10] * layers, stages, kinds, first=1, last=head)
+    assert sum(got) == layers and min(got) >= 1
+    bounds = np.cumsum([0] + got)
+    longest = "".join(kinds[bounds[got.index(max(got))]:][:max(got)])
+    for b, count in zip(bounds, got):
+        assert longest.startswith("".join(kinds[b:b + count]))
+
+
+def test_balanced_cut_ties_fall_to_the_shortest_longest_stage():
+    """Where several cuts have the same costliest stage and the even
+    one is not among them: the fewest blocks on the longest stage (the
+    fewest zero leaves), then the bounds nearest the even cut's."""
+    # 6 blocks on 3 stages, the costliest stage 6 either way
+    costs = [6, 1, 1, 1, 1, 2]
+    assert balanced_cut(costs, 3) == [1, 3, 2]      # not [1, 4, 1]
+    assert balanced_cut(costs, 3, last=1) == [1, 3, 2]
+
+
+def _patterned(layers: int, period: int):
+    """A GPT graph whose every ``period``-th block has one KV head."""
+    from defer_tpu import models
+    graph = models.gpt(layers, 32, 2, 16, vocab=VOCAB)
+    nodes = dict(graph.nodes)
+    for i in range(period - 1, layers, period):
+        nm = f"block_{i}"
+        nodes[nm] = dataclasses.replace(nodes[nm], op=dataclasses.replace(
+            nodes[nm].op, num_kv_heads=1))
+    other = graph.__class__.__new__(graph.__class__)
+    other.__dict__.update(graph.__dict__)
+    other.nodes = nodes
+    return other
+
+
+@pytest.mark.parametrize("cut,words", [
+    ([7, 7, 7, 7], r"stage 1's layer 0 \(block_7\) keeps KVCacheFormat.*"
+     r"block_0 at the same place.*cut the graph at a whole period"),
+    ([8, 8, 8, 8], "does not lay 28 blocks on 4 stages"),
+    ([8, 8, 12, 0], "one or more a stage"),
+    ([12, 8, 8], "names 3 stages, the ring has 4"),
+])
+def test_a_handed_cut_the_ring_cannot_run_is_refused(cut, words):
+    """``cut=`` is checked by the rule the chooser keeps to, in the
+    ring's words: a cut inside a period, a sum that is not the blocks',
+    an empty stage."""
+    graph = _patterned(28, 4)
+    with pytest.raises(ValueError, match=words):
+        decoder_parts(graph, 4, cut=cut)
+    assert [len(b) for b in decoder_parts(
+        graph, 4, cut=[8, 8, 8, 4]).stage_blocks] == [8, 8, 8, 4]
+
+
+def test_decoder_parts_cuts_by_what_a_step_reads():
+    """``step_bytes`` (what the holder's step reads of each node) picks
+    the cut: whole periods of a pattern of 4 where the even rule's 7 a
+    stage is refused, the head with the short stage; without it the
+    even rule, unchecked, as the serving engine takes it; one stage is
+    every block whatever is handed in."""
+    graph = _patterned(28, 4)
+    reads = dict({f"block_{i}": 100 for i in range(28)},
+                 embeddings=1, final_ln=1, lm_head=150)
+    counts = [len(b) for b in decoder_parts(
+        graph, 4, step_bytes=reads).stage_blocks]
+    assert counts == [8, 8, 8, 4]
+    assert [len(b) for b in decoder_parts(graph, 4).stage_blocks] == [7] * 4
+    assert decoder_parts(graph, 1, step_bytes=reads).stage_blocks == [
+        [f"block_{i}" for i in range(28)]]
+    # one period on four stages: the last stage's one layer is of
+    # another kind than the others', whichever way it is cut
+    with pytest.raises(ValueError, match=r"stage 3's layer 0 \(block_3\).*"
+                       "cut the graph at a whole period"):
+        decoder_parts(_patterned(4, 4), 4, step_bytes=reads)
+
+
+def _cut_gauges(stages):
+    from defer_tpu.obs import REGISTRY
+    return ([REGISTRY.gauge(f"decode.cut.blocks.{s}").value
+             for s in range(stages)],
+            REGISTRY.gauge("decode.cut.stage_bytes_max").value,
+            REGISTRY.gauge("decode.cut.stage_bytes_mean").value)
+
+
+@pytest.mark.parametrize("layers,stages,cut,want", [
+    (4, 2, None, [3, 1]), (6, 3, None, [2, 3, 1]), (4, 2, [1, 3], [1, 3]),
+])
+def test_an_uneven_cut_hands_out_the_one_stage_decoders_tokens(
+        prompt, layers, stages, cut, want):
+    """A head worth two and a half blocks: the bytes' cut takes blocks
+    off the last stage (a handed-in cut stands as it is), the gauges
+    say so, and the tokens are the one-stage decoder's, bit for bit —
+    stepwise and through the fused prefill."""
+    from defer_tpu import models
+    graph = models.gpt(layers, 32, 2, MAX_LEN, vocab=1000)
+    params = graph.init(jax.random.key(11))
+    one = PipelinedDecoder(graph, params, num_stages=1, microbatch=8,
+                           max_len=MAX_LEN)
+    blocks, worst, mean = _cut_gauges(1)
+    assert blocks == [layers] and worst == mean > 0
+    ring = PipelinedDecoder(graph, params, num_stages=stages, microbatch=2,
+                            max_len=MAX_LEN, cut=cut)
+    assert [len(b) for b in ring.stage_blocks] == want
+    blocks, worst, mean = _cut_gauges(stages)
+    assert blocks == want and worst > mean > 0
+    if cut is None:     # the even cut's costliest stage reads more
+        PipelinedDecoder(graph, params, num_stages=stages, microbatch=2,
+                         max_len=MAX_LEN, cut=[layers // stages] * stages)
+        assert _cut_gauges(stages)[1] > worst
+    for prefill in (False, True):
+        np.testing.assert_array_equal(
+            ring.generate(prompt, max_new_tokens=7, prefill=prefill),
+            one.generate(prompt, max_new_tokens=7, prefill=prefill))
+
+
+def test_a_stage_touches_the_layers_it_lacks_and_passes_none_through(model):
+    """A shorter stage hands on the buffers of the local layers it
+    lacks with one element rewritten in place (``LayeredState.idle``):
+    a buffer a branch only passes through is copied whole on the chip,
+    every step.  1 | 2 | 1: stages 0 and 2 touch layer 1's two buffers,
+    stage 1 none; the stages of an even cut touch nothing."""
+    from defer_tpu.ops.layered import LayeredState
+    layer = {"k": jnp.ones((2, 3)), "h": jnp.ones((4,), jnp.int8)}
+    idle = LayeredState.idle(layer)
+    assert {key: (buf.shape, buf.dtype) for key, buf in idle.items()} == {
+        key: (buf.shape, buf.dtype) for key, buf in layer.items()}
+    assert int(idle["k"].sum()) == 5 and int(idle["h"].sum()) == 3
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=3, microbatch=2,
+                           max_len=MAX_LEN)
+    assert [len(b) for b in dec.stage_blocks] == [1, 2, 1]
+    _, caches = dec._init_state()
+    local = jax.tree.map(lambda a: a[0], caches)
+
+    def touched(s):
+        jaxpr = jax.make_jaxpr(lambda c: dec._idle_layers(s, c))(local)
+        return sum(eqn.primitive.name == "dynamic_update_slice"
+                   for eqn in jaxpr.eqns)
+
+    assert [touched(s) for s in range(3)] == [2, 0, 2]
+    even = PipelinedDecoder(graph, params, num_stages=2, microbatch=2,
+                            max_len=MAX_LEN)
+    assert even._idle_layers(0, local) is local
 
 
 def test_causal_block_full_vs_decode(model):

@@ -315,12 +315,28 @@ def test_a_prefill_in_pieces_is_the_prefill(model, ids, generated,
 
 def test_a_cut_inside_a_period_is_refused(model):
     """Four stages of two layers: stage 1 opens with the attention
-    layer where stage 0 opens with a state-space layer."""
+    layer where stage 0 opens with a state-space layer.  Left to the
+    bytes, the ring lies on four stages that each open a period."""
     graph, params = model
     with pytest.raises(ValueError, match="stage 1's layer 0 .block_2. keeps "
                        "KVCacheFormat.*cut the graph at a whole period"):
         PipelinedDecoder(graph, params, num_stages=4, microbatch=1,
-                         max_len=SEQ)
+                         max_len=SEQ, cut=[2, 2, 2, 2])
+
+
+@pytest.mark.parametrize("prefill", [True, False])
+def test_left_to_the_bytes_four_stages_each_open_a_period(model, ids,
+                                                          generated, prefill):
+    """The even rule's two layers a stage cut both periods; the cut the
+    bytes choose among those the ring can run is 3 | 1 | 3 | 1 — every
+    stage a prefix of ``ssa`` — and hands out the one-stage tokens."""
+    graph, params = model
+    dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=1,
+                           max_len=SEQ)
+    assert [len(b) for b in dec.stage_blocks] == [3, 1, 3, 1]
+    np.testing.assert_array_equal(
+        dec.generate(ids[:, :PLEN], NEW, prefill=prefill, token_chunk=2),
+        generated[0])
 
 
 @pytest.mark.parametrize("kwargs, words", [

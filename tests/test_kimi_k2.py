@@ -417,15 +417,16 @@ def test_the_ring_leaves_the_rows_the_reference_holds(model, generated):
     (2, None, False)])
 def test_the_tokens_do_not_depend_on_stages_chunks_or_the_prefill(
         model, ids, generated, stages, chunk, prefill):
-    """Two stages cut the graph into (dense, routed | routed x 3): the
-    dense block lies at the place of the other stage's first routed
-    one, each in a tree of its own."""
+    """Two stages cut the graph into (dense, routed x 2 | routed x 2)
+    — the bytes' cut: the even rule's 2 | 3 laid the odd block beside
+    the head: the dense block lies at the place of the other stage's
+    first routed one, each in a tree of its own."""
     graph, params = model
     dec = PipelinedDecoder(graph, params, num_stages=stages,
                            microbatch=4 // stages, max_len=SEQ)
     if stages == 2:
-        assert dec.stage_blocks == [["block_0", "block_1"],
-                                    ["block_2", "block_3", "block_4"]]
+        assert dec.stage_blocks == [["block_0", "block_1", "block_2"],
+                                    ["block_3", "block_4"]]
         assert dec._variant == [[0, 1], None, None]
         dense, routed = dec._w["blocks"][0]
         assert "gate" in dense and "router" in routed

@@ -620,16 +620,21 @@ def test_the_serving_engine_refuses_the_block_by_name(model):
 #: parent commit (f2c0c8e, PR 56): the attention half moved into
 #: ``models/latent_attention.py`` and ``LatentBlock.decode`` became a
 #: round a sublayer, and Kimi's programs lower byte for byte.  A PR that
-#: changes them on purpose records them anew and says so.
+#: changes them on purpose records them anew and says so: PR 60 did the
+#: two-stage pair's — the ring's cut goes by what a stage reads a step,
+#: and Kimi's five blocks lie 3 | 2 where the even rule laid the odd
+#: one beside the head (2 | 3), and the shorter stage touches the layer
+#: it lacks (``LayeredState.idle``); one stage is cut nowhere and lowers
+#: as it did.
 PARENT_KIMI_SHA = {
     "ring.kimi_k2_tiny.buffer.beam1.stages1.decode.greedy":
         "08e62c95a36e92ac",
     "ring.kimi_k2_tiny.buffer.beam1.stages1.prefill.greedy":
         "dee3d5be46af6873",
     "ring.kimi_k2_tiny.buffer.beam1.stages2.decode.greedy":
-        "5ccb5d094ab15fb9",
+        "21b530164ad1d4fa",
     "ring.kimi_k2_tiny.buffer.beam1.stages2.prefill.greedy":
-        "405fa54c5d18faee",
+        "c3bd8c070e586a74",
 }
 
 

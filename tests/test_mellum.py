@@ -422,7 +422,12 @@ def test_a_stage_whose_kinds_do_not_repeat_is_refused(tiny):
     with pytest.raises(ValueError, match="same kinds of memory in the same "
                                          "order"):
         PipelinedDecoder(graph, params, num_stages=4, microbatch=1,
-                         max_len=SEQ)
+                         max_len=SEQ, cut=[2, 2, 2, 2])
+    # left to the bytes, every stage opens where the pattern does: the
+    # full layer closes a stage of three, a window layer is one alone
+    dec = PipelinedDecoder(graph, params, num_stages=4, microbatch=1,
+                           max_len=SEQ)
+    assert [len(b) for b in dec.stage_blocks] == [1, 3, 1, 3]
 
 
 def test_the_serving_engine_refuses_the_block(tiny):
