@@ -20,6 +20,7 @@ from .jamba import jamba, jamba_tiny
 from .granite_hybrid import granite_hybrid, granite_hybrid_tiny
 from .kimi_k2 import kimi_k2, kimi_k2_tiny
 from .longcat_flash import longcat_flash, longcat_flash_tiny
+from .lfm2_moe import lfm2_moe, lfm2_moe_tiny
 from .mellum import mellum, mellum_tiny
 from .inception import (INCEPTION_6STAGE_CUTS, inception, inception_tiny,
                         inception_v3)
@@ -44,4 +45,5 @@ __all__ = [
     "kimi_k2", "kimi_k2_tiny",
     "longcat_flash", "longcat_flash_tiny",
     "mellum", "mellum_tiny",
+    "lfm2_moe", "lfm2_moe_tiny",
 ]

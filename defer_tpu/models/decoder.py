@@ -16,18 +16,18 @@ it, whatever its family.
 * **Blocks**: :class:`DecoderBlock`, whose per-sequence memory is a KV
   cache: it hands key and value *columns* to the cache's format
   (``ops/kv_cache.py``) and takes the attention's output back.  Its
-  sibling :class:`RetentionBlock` keeps a recurrent state of fixed size
-  instead: it hands ``q, k, v`` and a log-decay to the state's format
-  (``ops/retention.py``) and takes the layer's output back.  Its
-  sibling :class:`StateSpaceBlock` keeps a convolution window and a
-  state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's) and has no
-  attention heads at all.  Its sibling :class:`LatentBlock` keeps a
-  latent cache (``ops/latent_cache.py``): one row a position that every
-  head shares, which a step attends over with queries absorbed into the
-  latent space and a prompt over the expanded heads.  None
-  knows an axis order, key or type of what the format holds; which kind
-  a block keeps is the class it is (``memory``), and the holder asks
-  every block for its format (:meth:`DecoderBlock.memory_format`).
+  siblings: :class:`RetentionBlock` keeps a recurrent state of fixed
+  size (``q, k, v`` and a log-decay to ``ops/retention.py``, the
+  layer's output back); :class:`StateSpaceBlock` a convolution window
+  and a state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's), no
+  heads at all; :class:`ConvWindowBlock` such a window *alone*
+  (``ops/conv_window.py``: a gated short convolution, no position);
+  :class:`LatentBlock` a latent cache (``ops/latent_cache.py``): one
+  row a position that every head shares, which a step attends over
+  with queries absorbed into the latent space and a prompt over the
+  expanded heads.  None knows an axis order, key or type of what the
+  format holds; which kind a block keeps is the class it is (``memory``),
+  and the holder asks every block (:meth:`DecoderBlock.memory_format`).
 """
 
 from __future__ import annotations
@@ -462,6 +462,89 @@ class LatentBlock(DecoderBlock):
         for i, r in enumerate(rows if isinstance(rows, tuple) else (rows,)):
             cache = fmt.write_prefix(cache, r, slot, sublayer=i)
         return x, cache
+
+
+class ConvWindowBlock(DecoderBlock):
+    """A decoder block whose per-sequence memory is the window of a
+    short depthwise causal convolution and **nothing else**
+    (``ops/conv_window.py``): the last ``d_conv - 1`` inputs, of fixed
+    size whatever the text's length, moved on by one row a step.  The
+    mixer is *gated on both sides* by projections of the same input —
+    ``[B, C, X] = u W_in``, the convolution runs over ``z = B * X`` and
+    ``C`` multiplies what it gives — and goes on to no selection, no
+    recurrence and no attention: no position is read and there are no
+    heads.  In place of ``apply_with_kv`` / ``decode_qkv`` such a block
+    has
+
+    * ``channels`` (the convolution's columns) and ``d_conv`` (its
+      taps), the window's sizes; ``mixer_width``, the columns of the
+      input projection, the widest activation a token has in the
+      layer;
+    * ``mixer_inputs(params, x [..., d]) -> (z, c_gate)``: the
+      convolution's input ``B * X`` [..., channels] — what the window
+      keeps — and the output gate ``C``, which the block gets back
+      untouched;
+    * ``mixer_conv(params, taps) -> c``: the convolution over its
+      ``d_conv`` taps (``ops/ssm.py::causal_conv`` under the block's
+      weights, without bias or activation);
+    * ``decode_finish(params, x [T, d], c [T, channels], c_gate,
+      sow=None)``: the rest of the block after the convolution (the
+      gate, the output projection, the second half).
+
+    ``decode_stats`` is :class:`DecoderBlock`'s.  No serving engine
+    takes such a block yet (``serve/engine.py`` refuses every block but
+    GPT's).
+    """
+
+    memory = "conv_window"
+
+    def geometry(self, d_model: int):
+        del d_model
+        return None
+
+    def widest(self, d_model: int) -> int:
+        """The input projection's columns."""
+        return max(d_model, self.mixer_width)
+
+    def memory_format(self, d_model: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """A window's size depends neither on the stream's width nor on
+        ``positions``; it is of type ``dtype``."""
+        del d_model, positions
+        if quantized:
+            raise ValueError(
+                "kv_cache='int8' quantizes cached key and value rows; "
+                "these blocks keep a convolution window, which has none")
+        from ..ops import conv_window
+        return conv_window.ConvWindowFormat(self.channels, self.d_conv,
+                                            dtype, groups=groups)
+
+    def decode(self, params, x, state, pos, fmt, slot=True, group=None,
+               sow=None):
+        """One-token step: ``x`` [b, d] against one layer's window;
+        ``slot`` is ``fmt.decode_slot``'s, handed on to the format
+        unread (it says whether the step is real: a bubble leaves the
+        window as it is).  The position is not read."""
+        del pos
+        z, c_gate = self.mixer_inputs(params, x)
+        taps, state = fmt.shift(z, state, group=group, valid=slot)
+        c = self.mixer_conv(params, taps)
+        return self.decode_finish(params, x, c, c_gate, sow=sow), state
+
+    def prefill(self, params, x, state, fmt, slot=(None, True), sow=None):
+        """A whole prompt ``x`` [b, t, d] through the layer from an
+        empty window; the window after its last position is left where
+        ``slot`` (``fmt.prefill_slot``'s, handed on unread) says.  A
+        dict ``sow`` is filled as :meth:`decode` fills it, over all ``b
+        * t`` rows."""
+        b, t, d = x.shape
+        z, c_gate = self.mixer_inputs(params, x)
+        taps, state = fmt.prefill_shift(z, state, slot)
+        c = self.mixer_conv(params, taps)
+        out = self.decode_finish(params, x.reshape(b * t, d),
+                                 c.reshape(b * t, -1),
+                                 c_gate.reshape(b * t, -1), sow=sow)
+        return out.reshape(b, t, d), state
 
 
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:

@@ -1033,14 +1033,14 @@ class KVCacheFormat(RingRows):
             v = v.reshape(shape).transpose(0, 2, 1, 3)
             if pieces:
                 # a piece of a group, written inside the prefill's loop
-                # over pieces: positions-major, as the product made the
-                # rows and as the chip keeps a buffer of few heads (8
-                # heads of 128 are one tile, so its own layout of
-                # ``[.., 8, rows, 128]`` has the rows outside the
-                # heads).  Head-major rows make the loop hold the
-                # *buffer* head-major and convert all of it on the way
-                # in and out: two copies of every buffer a prefill
-                as_made = Layout(major_to_minor=(0, 2, 1, 3))
+                # over pieces, in the order the chip keeps the *buffer*
+                # (else the loop holds it otherwise and converts all of
+                # it in and out, two copies a buffer a prefill): heads
+                # under a lane row keep the positions on the lanes; 8
+                # heads of 128 are one tile, the rows outside the heads
+                order = (0, 1, 3, 2) if _on_lanes(self.head_dim) \
+                    else (0, 2, 1, 3)
+                as_made = Layout(major_to_minor=order)
                 k, v = (with_layout_constraint(a, as_made) for a in (k, v))
         if shift:
             k, v = (jnp.roll(a, shift, axis=1 if self.joined else 2)

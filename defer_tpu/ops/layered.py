@@ -10,7 +10,8 @@ of it (docs/DECODE_CLIFF.md).  A holder may keep entries of its own
 beside the formats' in the same dict; a format passes them through.
 
 A format (``ops/kv_cache.py::KVCacheFormat``,
-``ops/retention.py::RetentionFormat``, ``ops/ssm.py::SsmFormat``) says
+``ops/retention.py::RetentionFormat``, ``ops/ssm.py::SsmFormat``,
+``ops/conv_window.py::ConvWindowFormat``) says
 what the buffers are (``buffers(batch)``, ``keys``) and is the one
 place that writes and reads them — and the one that says what they are
 to an observer: the gauges of its kind (``gauges``) and what a step

@@ -28,7 +28,7 @@ SCORING_RULES = ("softmax", "sigmoid", "softmax_of_chosen", "noaux_tc",
 
 
 def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
-                scale: float = 1.0):
+                scale: float = 1.0, eps: float = 1e-20):
     """Scores over the experts in float32, then the ``k`` largest:
     ``(expert ids [..., k], their weights [..., k])``.  ``scoring`` is
     the family's rule, named by the block that calls (never a user's
@@ -39,10 +39,10 @@ def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
     ``"softmax_of_chosen"`` — the ``k`` largest logits, then a softmax
     over those ``k`` values alone (Granite 4.0-H,
     ``models/granite_hybrid.py``); ``"noaux_tc"`` — sigmoid scores
-    ``p``, the ``k`` largest of ``p + bias`` (``bias`` [experts], the
-    balancing term: it chooses and never weighs), the chosen ``p``
-    renormalised and multiplied by ``scale`` (Kimi K2 / DeepSeek-V3
-    without a group limit, ``models/kimi_k2.py``); ``"softmax_bias"`` —
+    ``p``, the ``k`` largest of ``p + bias`` (``bias`` [experts]: it
+    chooses and never weighs), the chosen ``p`` over their sum plus
+    ``eps`` (the caller's published term) times ``scale`` (Kimi K2,
+    ``models/kimi_k2.py``; ``models/lfm2_moe.py``); ``"softmax_bias"`` —
     probabilities ``p`` over *all* columns (a layer's routed experts
     and, behind them, its zero-compute ones), the ``k`` largest of ``p
     + bias`` (the bias chooses and never weighs), the chosen ``p``
@@ -64,7 +64,7 @@ def route_top_k(logits, k: int, scoring: str = "softmax", *, bias=None,
         probs = jax.nn.sigmoid(logits)
         _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
         p = jnp.take_along_axis(probs, eid, axis=-1)
-        return eid, scale * p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
+        return eid, scale * p / (jnp.sum(p, axis=-1, keepdims=True) + eps)
     if scoring == "softmax_bias":
         probs = jax.nn.softmax(logits, axis=-1)
         _, eid = lax.top_k(probs + bias.astype(jnp.float32), k)
@@ -208,27 +208,27 @@ def held_range(experts_held, num_experts: int) -> tuple[int, int]:
     return lo, hi
 
 
-def route(h, router, k: int, scoring: str, scale: float = 1.0):
-    """``(expert ids [T, k], their weights [T, k])`` of the normed stream
-    ``h`` [T, d] by the router's leaves: ``w`` [d, columns] and, where
-    the rule has one, ``bias`` [columns] (:func:`route_top_k`)."""
+def route(h, router, k: int, scoring: str, scale: float = 1.0,
+          eps: float = 1e-20):
+    """``(ids [T, k], weights [T, k])`` of the normed stream ``h`` [T, d] by
+    the router's ``w`` [d, columns] and ``bias`` (:func:`route_top_k`)."""
     # the logits leave the product in float32: rounded, they would flip
     # the last of the chosen at near-ties
     return route_top_k(
         jnp.dot(h, router["w"], preferred_element_type=jnp.float32),
-        k, scoring=scoring, bias=router.get("bias"), scale=scale)
+        k, scoring=scoring, bias=router.get("bias"), scale=scale, eps=eps)
 
 
 def routed_experts(h, router, experts, *, k: int, scoring: str,
                    num_experts: int, held: tuple[int, int] | None = None,
-                   scale: float = 1.0, zero_experts: int = 0, shared=None,
-                   sow=None):
+                   scale: float = 1.0, eps: float = 1e-20,
+                   zero_experts: int = 0, shared=None, sow=None):
     """The routed experts of one layer on the normed stream ``h`` [T, d]:
     ``(the pairs' weighted sum [T, d], the shared experts' [T, d] or
     None)``, both float32 and neither added to anything — where the
     residual is and what multiplies a branch is the family's.
 
-    ``router`` and ``k`` / ``scoring`` / ``scale`` are :func:`route`'s;
+    ``router``, ``k`` / ``scoring`` / ``scale`` / ``eps`` are :func:`route`'s;
     ``experts`` the stacks :func:`grouped_swiglu` reads.  ``held`` is
     what the layer holds of the ``num_experts`` its router chooses
     among: None — all of them, one grouped product over every pair
@@ -252,7 +252,7 @@ def routed_experts(h, router, experts, *, k: int, scoring: str,
         raise ValueError(f"a router of {router['w'].shape[-1]} columns for "
                          f"{num_experts} routed + {zero_experts} "
                          "zero-compute experts")
-    eid, gate = route(h, router, k, scoring, scale)
+    eid, gate = route(h, router, k, scoring, scale, eps)
 
     def swiglu(xs, sizes, _es=None):
         return grouped_swiglu(xs, experts, sizes)

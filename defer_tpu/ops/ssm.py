@@ -93,10 +93,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.layout import Layout, with_layout_constraint
+# (the window, and its layout constraint, live in ops/conv_window.py)
 from jax.experimental.pallas import tpu as pltpu
 
-from .layered import LayeredState, nbytes
+from .conv_window import Window, dense_window
 
 #: channels a kernel works on at a time: 16 states x 512 channels of
 #: float32 are 8 vector registers, so a block's ``H`` stays in them
@@ -486,23 +486,30 @@ def ssd_scan(dt, dx, b, c, a, *, chunk: int):
 
 # -- the convolution ----------------------------------------------------------
 
-def causal_conv(taps, w, bias):
-    """``silu(bias + sum_j w[j] * taps[j])`` in float32, rounded to the
-    taps' type: ``taps`` a sequence of ``d_conv`` arrays [..., E], oldest
-    first, the newest the position's own input; ``w`` [d_conv, E],
-    ``bias`` [E]."""
-    acc = bias.astype(jnp.float32)
+def causal_conv(taps, w, bias=None, *, activation=jax.nn.silu):
+    """``activation(bias + sum_j w[j] * taps[j])`` in float32, rounded
+    to the taps' type: ``taps`` a sequence of ``d_conv`` arrays [..., E],
+    oldest first, the newest the position's own input; ``w`` [d_conv,
+    E], ``bias`` [E] or None (a convolution without one).  The
+    state-space mixers' is a ``silu``; ``activation`` None leaves the
+    sum as it is (a gated short convolution's,
+    ``models/decoder.py::ConvWindowBlock``)."""
+    acc = None if bias is None else bias.astype(jnp.float32)
     for j, tap in enumerate(taps):
-        acc = acc + w[j].astype(jnp.float32) * tap.astype(jnp.float32)
-    return jax.nn.silu(acc).astype(taps[-1].dtype)
+        term = w[j].astype(jnp.float32) * tap.astype(jnp.float32)
+        acc = term if acc is None else acc + term
+    if activation is not None:
+        acc = activation(acc)
+    return acc.astype(taps[-1].dtype)
 
 
 # -- the format --------------------------------------------------------------------
 
-class _WindowedState(LayeredState):
-    """What both shapes of state-space memory share: the buffers' keys,
-    the bubble as an identity update, the convolution's window (only
-    its width differs) and where a prefill leaves its last state."""
+class _WindowedState(Window):
+    """What both shapes of state-space memory share: the convolution's
+    window (``ops/conv_window.py``'s, with its three calls and the
+    bubble as an identity update; only its width differs) and, beside
+    it, the state ``h``, and where a prefill leaves its last state."""
 
     keys = ("conv", "h")
 
@@ -510,79 +517,13 @@ class _WindowedState(LayeredState):
         """One layer's buffers for ``batch`` sequences (a group), by key."""
         lead = () if self.groups is None else (self.groups,)
         return {
-            "conv": jax.ShapeDtypeStruct(
-                lead + (self.d_conv - 1, batch, self.conv_width), self.dtype),
+            "conv": self.window_buffer(batch),
             "h": jax.ShapeDtypeStruct(
                 lead + (batch, self.states, self.channels), jnp.float32)}
 
     def gauges(self, batch: int, stages: int) -> dict[str, int]:
         """The convolution's window, of the layer's bytes."""
-        return {"decode.ssm.conv_bytes":
-                stages * nbytes(self.buffers(batch)["conv"])}
-
-    # -- where a ring step's memory goes: a bubble is an identity update
-
-    @staticmethod
-    def decode_slot(valid, pos):
-        """What :meth:`shift` and :meth:`step` take as ``valid``; the
-        position is not part of a state's address."""
-        del pos
-        return valid
-
-    @staticmethod
-    def prefill_slot(valid, group, row=None):
-        """What the prefill calls take as ``slot``: the group and
-        whether the call is real; with ``row``, also the sequence of
-        the group from which a piece's prompts lie."""
-        return (group, valid) if row is None else (group, valid, row)
-
-    # -- one token a sequence ------------------------------------------------
-
-    def shift(self, u, layer: dict, group=None, valid=True):
-        """The convolution's taps for one token of every sequence (of
-        group ``group``), and the window moved on by it: ``u`` [b, E]
-        the position's input -> ``(taps, layer)``, ``taps`` the
-        ``d_conv`` inputs ``[b, E]`` the convolution reads, oldest
-        first, ``u`` itself the last.  With ``valid`` false the window
-        is kept as it is."""
-        bufs, group = self._group(layer, group)
-        at = (group[0], 0, 0, 0)
-        win = lax.dynamic_slice(bufs["conv"], at,
-                                (1,) + bufs["conv"].shape[1:])[0]
-        u = u.astype(win.dtype)
-        new = jnp.concatenate([win[1:], u[None]], axis=0)
-        new = jnp.where(valid, new, win)
-        conv = lax.dynamic_update_slice(bufs["conv"], new[None], at)
-        return [win[j] for j in range(self.d_conv - 1)] + [u], \
-            self._ungroup(dict(bufs, conv=conv))
-
-    # -- a whole prompt ---------------------------------------------------------
-
-    def prefill_shift(self, u, layer: dict, slot=(None, True)):
-        """The taps of a whole prompt ``u`` [b, t, E] from an empty
-        window, and the window after its last position left where
-        ``slot`` says: ``(taps, layer)``, ``taps`` the ``d_conv`` arrays
-        ``[b, t, E]``, the input ``d_conv - 1 - j`` positions back under
-        ``j`` (zero before the prompt's start)."""
-        group, valid, row = slot if len(slot) == 3 else (*slot, 0)
-        k = self.d_conv - 1
-        b, t, e = u.shape
-        bufs, group = self._group(layer, group)
-        u = u.astype(bufs["conv"].dtype)
-        padded = jnp.pad(u, ((0, 0), (k, 0), (0, 0)))
-        taps = [lax.slice_in_dim(padded, j, j + t, axis=1)
-                for j in range(k + 1)]
-        at = (group[0], 0, row, 0)
-        old = lax.dynamic_slice(bufs["conv"], at, (1, k, b, e))
-        # taps-major as the buffer holds them: left to itself the
-        # compiler keeps the prompt's last inputs sequence-major (what
-        # the slice before them liked) and, the write needing one layout
-        # on both sides, converts the *buffer* there and back
-        last = with_layout_constraint(padded[:, t:].swapaxes(0, 1),
-                                      Layout(major_to_minor=(0, 1, 2)))
-        new = jnp.where(valid, last[None], old)
-        conv = lax.dynamic_update_slice(bufs["conv"], new, at)
-        return taps, self._ungroup(dict(bufs, conv=conv))
+        return {"decode.ssm.conv_bytes": self.window_bytes(batch, stages)}
 
     def _leave(self, last, layer: dict, slot) -> dict:
         """``layer`` with ``last`` [b, N, E], the state after a prompt's
@@ -712,7 +653,7 @@ def dense(h, conv, heads: int | None = None
     h = np.swapaxes(np.asarray(h), -1, -2)
     if heads is not None:
         h = h.reshape(h.shape[:-2] + (heads, -1, h.shape[-1]))
-    return h, np.swapaxes(np.asarray(conv), 0, 1)
+    return h, dense_window(conv)
 
 
 # -- the oracle -----------------------------------------------------------------

@@ -12,7 +12,10 @@ same with a state of heads and routed experts in every layer;
 ``kimi_k2_tiny``, a latent cache, and at two stages a dense block at
 the place of the other stage's routed one; ``mellum_tiny``, two
 rotations by layer kind; ``longcat_flash_tiny``, two latent caches a
-block and two turns around them a step, two blocks a stage) and
+block and two turns around them a step, two blocks a stage;
+``lfm2_moe_tiny``, layers that keep a convolution window and nothing
+else beside attention layers' caches, a dense block at the place of the
+other stage's routed one) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -41,8 +44,8 @@ import jax.numpy as jnp
 
 from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
                               granite_hybrid_tiny, gpt_tiny, jamba_tiny,
-                              kimi_k2_tiny, longcat_flash_tiny, mellum_tiny,
-                              olmoe, olmoe_tiny)
+                              kimi_k2_tiny, lfm2_moe_tiny, longcat_flash_tiny,
+                              mellum_tiny, olmoe, olmoe_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -79,6 +82,9 @@ def ring_configurations():
     # layer, the shortcut's output carried across the second turn; a
     # stage cut between blocks
     yield "longcat_flash_tiny", longcat_flash_tiny(), (1, 2), *plain
+    # a window-only memory (neither int8 rows nor beams) beside caches,
+    # three kinds of block on one ledger: one period a stage
+    yield "lfm2_moe_tiny", lfm2_moe_tiny(), (1, 2), *plain
 
 
 def ring_programs(name, graph, stages, kv_caches, beams):

@@ -104,8 +104,40 @@ def test_the_queued_gpt2_cell_is_the_batch_cells_traffic_at_full_depth():
 import test_shortcut_latent_moe_driver as _shortcut  # noqa: E402
 
 real_args = _shortcut.real_args
-test_the_longcat_cell_has_its_files_and_metrics = \
+# kept as it is, and expected to fail until a ``benchmark`` PR repairs
+# it: it takes LongCat's configuration and three metrics for the *last*
+# entries of their lists, and PR 61 appended behind them, as the
+# contract asks (the ten setup readers' case above, again; the file is
+# the benchmark's).  ``strict``: the day it passes again this mark has
+# to go.  Every other assertion it makes is held below, against the
+# manifest cut where PR 57 left it.
+_longcat_files = \
     _shortcut.test_the_real_manifest_gives_the_cell_its_files_and_metrics
+
+
+@pytest.mark.xfail(strict=True, raises=AssertionError,
+                   reason="LongCat's entries are no longer the last of "
+                          "configs and per_layer (PR 61 appended)")
+def test_the_longcat_cell_has_its_files_and_metrics():
+    _longcat_files()
+
+
+def test_the_longcat_cell_has_its_files_and_metrics_where_pr_57_left_them(
+        monkeypatch):
+    from chipbench.manifest import Manifest
+
+    class AsPr57LeftIt(Manifest):
+        def __init__(self, root=None):
+            super().__init__(root)
+            for group, last in (("configs", "longcat-flash-chat-4l-ep32"),
+                                ("per_layer", "zero_expert_pair_share")):
+                names = [e["name"] for e in self.doc[group]]
+                del self.doc[group][names.index(last) + 1:]
+
+    monkeypatch.setattr(_shortcut, "Manifest", AsPr57LeftIt)
+    _longcat_files()
+
+
 test_the_longcat_readers_return_nothing_without_their_counters = \
     _shortcut.test_the_readers_return_nothing_without_their_counters
 test_the_longcat_models_size_against_the_issues_count = \
@@ -114,6 +146,27 @@ test_the_longcat_decode_step_needs_against_the_issues_count = \
     _shortcut.test_decode_step_needs_against_the_issues_count
 test_the_longcat_prefill_needs_against_the_issues_count = \
     _shortcut.test_prefill_needs_against_the_issues_count
+
+
+# -- PR 61's cell: what of its own tests needs no tiny driver ------------------------
+
+import test_conv_moe_driver as _conv_moe  # noqa: E402
+
+lfm2_args = _conv_moe.lfm2_args
+test_the_lfm2_cell_has_its_files_and_metrics = \
+    _conv_moe.test_the_real_manifest_gives_the_cell_its_files_and_metrics
+test_the_lfm2_readers_on_a_trace_made_by_hand = \
+    _conv_moe.test_the_readers_on_a_trace_made_by_hand
+test_the_lfm2_readers_on_the_recorded_trace_find_nothing_to_read = \
+    _conv_moe.test_the_readers_on_the_recorded_trace_find_nothing_to_read
+test_the_lfm2_readers_return_nothing_without_their_counters = \
+    _conv_moe.test_the_readers_return_nothing_without_their_counters
+test_the_lfm2_models_size_against_the_issues_count = \
+    _conv_moe.test_the_models_size_against_the_issues_count
+test_the_lfm2_decode_step_needs_against_the_issues_count = \
+    _conv_moe.test_decode_step_needs_against_the_issues_count
+test_the_lfm2_prefill_needs_against_the_issues_count = \
+    _conv_moe.test_prefill_needs_against_the_issues_count
 
 
 def test_the_long_prompt_cell_is_the_full_cells_model_on_a_long_prompt():
