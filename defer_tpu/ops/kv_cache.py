@@ -73,7 +73,13 @@ over a layer's buffers *where they lie* — the Pallas kernel
 :func:`kv_attend`, which takes the group as an index and reads the
 position blocks that hold live rows and no other.  Only the int8 rows
 stay on the plain einsum (:func:`attend_einsum`), which is also the
-oracle the tests hold the kernel to.
+oracle the tests hold the kernel to.  A block is fetched whole, so what
+it holds past ``pos`` is read for nothing: over joined rows
+(:func:`kv_attend_joined`) a block has two extents, sequences and
+positions, and where a position's rows are thin (one KV head of 128:
+256 B) it stops at :data:`_BLOCK_POSITIONS` and holds several sequences
+instead of one sequence's 4096 rows (:func:`joined_block_rows`, from the
+operands' shapes alone).
 
 **A list of live sequences** (:func:`live_slots`), for a holder most of
 whose sequences are idle — the serving engine, 2 of 16 slots live under
@@ -89,6 +95,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 from typing import Any
 
@@ -108,6 +115,9 @@ from .layered import LayeredState
 _LANES = 128
 #: the most one of the attention's blocks (keys or values) may hold
 _BLOCK_BYTES = 1 << 20
+#: the positions of a block of thin rows (:func:`joined_block_rows`):
+#: what a block reads past ``pos`` is at most these, a sequence
+_BLOCK_POSITIONS = 512
 #: joined buffers hold a multiple of this many positions (a sublane
 #: tile of 16-bit rows)
 _JOINED_ROWS = 16
@@ -432,22 +442,33 @@ def _attend_kernel(group_ref, pos_ref, *refs, tl, on_lanes, scale,
                 o_ref[0, j] = out[0].astype(o_ref.dtype)
 
 
+def _block_positions(pos_ref, i, sb: int) -> list:
+    """``pos`` of each of the ``sb`` sequences of block ``i``."""
+    if sb == 1:
+        return [pos_ref[i]]
+    return [pos_ref[i * sb + j] for j in range(sb)]
+
+
 def _attend_joined_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                           m_ref, l_ref, acc_ref, *, tl, kv, scale):
-    """One position block of one sequence, every KV head's whole query
-    group at once: ``q_ref`` / ``o_ref`` ``[1, kv * g, hd]``, ``k_ref``
-    / ``v_ref`` ``[1, 1, tl, kv * hd]`` as a joined buffer lies.  A
-    head's keys are a lane-aligned slice ``[tl, hd]`` of the block, its
-    scores ``[g, hd] x [hd, tl]`` and its output ``[g, tl] x [tl, hd]``
-    on the matrix unit, accumulated in f32 (16 queries over 512 bytes a
-    position are 8192 f32 operations for every 512 bytes: more than the
-    vector unit has at the memory's pace); the online softmax between
-    them runs on ``[g, tl]``.  m_ref / l_ref ``[kv * g, 128]`` (a row's
-    scalar on every lane), acc_ref ``[kv * g, hd]``."""
+    """One position block of a block's sequences, every KV head's whole
+    query group at once: ``q_ref`` / ``o_ref`` ``[sb, kv * g, hd]``,
+    ``k_ref`` / ``v_ref`` ``[1, sb, tl, kv * hd]`` as a joined buffer
+    lies.  A head's keys are a lane-aligned slice ``[tl, hd]`` of a
+    sequence's rows, its scores ``[g, hd] x [hd, tl]`` and its output
+    ``[g, tl] x [tl, hd]`` on the matrix unit, accumulated in f32 (16
+    queries over 512 bytes a position are 8192 f32 operations for every
+    512 bytes: more than the vector unit has at the memory's pace); the
+    online softmax between them runs on ``[g, tl]``.  Each sequence
+    masks by its own ``pos``.  m_ref / l_ref ``[sb * stride, 128]`` (a
+    row's scalar on every lane), acc_ref ``[sb * stride, hd]``: a
+    sequence's heads from row ``j * stride`` on, whole sublane tiles
+    apart."""
     del group_ref                       # the index maps read it
     t = pl.program_id(1)
-    pos = pos_ref[pl.program_id(0)]
-    g, hd = q_ref.shape[1] // kv, q_ref.shape[2]
+    sb, heads, hd = q_ref.shape
+    g, stride = heads // kv, m_ref.shape[0] // sb
+    at = _block_positions(pos_ref, pl.program_id(0), sb)
 
     @pl.when(t == 0)
     def _init():
@@ -456,10 +477,11 @@ def _attend_joined_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def accumulate(ragged):
-        for h in range(kv):
-            rows, cols = pl.ds(h * g, g), pl.ds(h * hd, hd)
-            q, k, v = q_ref[0, rows, :], k_ref[0, 0, :, cols], \
-                v_ref[0, 0, :, cols]
+        for j, h in itertools.product(range(sb), range(kv)):
+            pos = at[j]
+            rows, cols = pl.ds(j * stride + h * g, g), pl.ds(h * hd, hd)
+            q, k, v = q_ref[j, pl.ds(h * g, g), :], k_ref[0, j, :, cols], \
+                v_ref[0, j, :, cols]
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if ragged:
@@ -470,7 +492,9 @@ def _attend_joined_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                 v = jnp.where(t * tl + lax.broadcasted_iota(
                     jnp.int32, v.shape, 0) <= pos, v, jnp.zeros_like(v))
             # block 0 always holds a live position: from the first
-            # block on the running max is finite
+            # block on the running max is finite, and a sequence whose
+            # ``pos`` lies before a block its neighbours still read
+            # adds exp(-inf) = 0 to its sums
             m_prev = m_ref[rows, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -483,20 +507,45 @@ def _attend_joined_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                 preferred_element_type=jnp.float32)
             m_ref[rows, :] = jnp.broadcast_to(m_new, (g, m_ref.shape[1]))
 
-    pl.when((t + 1) * tl - 1 <= pos)(lambda: accumulate(False))
-    pl.when(jnp.logical_and(t * tl <= pos, (t + 1) * tl - 1 > pos))(
+    # a block every sequence fills goes unmasked; one that holds or
+    # lies past some sequence's ``pos`` — and before the furthest —
+    # pays for masks; one past them all is not computed
+    least, most = functools.reduce(jnp.minimum, at), \
+        functools.reduce(jnp.maximum, at)
+    pl.when((t + 1) * tl - 1 <= least)(lambda: accumulate(False))
+    pl.when(jnp.logical_and(t * tl <= most, (t + 1) * tl - 1 > least))(
         lambda: accumulate(True))
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        for j in range(sb):
+            rows = pl.ds(j * stride, heads)
+            o_ref[j] = (acc_ref[rows, :] / l_ref[rows, :1]).astype(o_ref.dtype)
 
 
-def joined_block_rows(kv: int, hd: int, length: int, itemsize: int) -> int:
-    """Positions of one block of :func:`kv_attend_joined`: as many lane
-    rows as :data:`_BLOCK_BYTES` hold of every KV head's keys."""
-    return min(length, max(_LANES, _BLOCK_BYTES // (kv * hd * itemsize)
-                           // _LANES * _LANES))
+def joined_block_rows(kv: int, hd: int, length: int, itemsize: int,
+                      b: int) -> tuple[int, int]:
+    """``(sequences, positions)`` of one block of
+    :func:`kv_attend_joined` over ``b`` sequences' buffers of ``length``
+    positions: for one sequence as many lane rows of positions as
+    :data:`_BLOCK_BYTES` hold of every KV head's keys.  A block is
+    fetched whole, so its positions past ``pos`` are bytes nothing
+    reads (4096 positions of Jamba's 256 B took 0.91 ms a call whatever
+    the position, 512 take 0.23 at 384: PERF.md §6, PR 63): where the
+    rows are thin — :data:`_BLOCK_POSITIONS` of a sequence under half of
+    :data:`_BLOCK_BYTES` — the block stops at those positions and takes,
+    of ``b``'s divisors, as many sequences as :data:`_BLOCK_BYTES` hold
+    (their DMAs in flight together: at full length 78% of the memory
+    peak where a sequence a grid step read 63%).  From the operands'
+    shapes alone: a position of 1 KB and more keeps one sequence a
+    block."""
+    row = kv * hd * itemsize
+    tl = min(length, max(_LANES, _BLOCK_BYTES // row // _LANES * _LANES))
+    if 2 * _BLOCK_POSITIONS * row >= _BLOCK_BYTES:
+        return 1, tl
+    tl = min(tl, _BLOCK_POSITIONS)
+    return max(d for d in range(1, b + 1)
+               if b % d == 0 and d * tl * row <= _BLOCK_BYTES), tl
 
 
 @functools.partial(jax.jit, static_argnames=("kv", "name"))
@@ -506,44 +555,64 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
     hd]`` (:attr:`KVCacheFormat.joined`: a position's rows of all KV
     heads side by side on the lanes), for a query group that fills the
     matrix unit's rows: ``q`` [b, heads * hd], sequence ``i`` over its
-    rows ``<= pos[i]``.  The grid runs (sequence, position block); a
-    block is whole rows as they lie, so its DMA is one contiguous run,
-    a block past ``pos[i]`` is neither fetched nor computed, and each
-    KV head's ``[positions, hd]`` is a lane-aligned slice of it — the
-    operand the products want.  The same name in a device trace as
-    :func:`kv_attend`, and like it ``name`` where a format names its
-    kernels (:attr:`KVCacheFormat.kernel_suffix`)."""
+    rows ``<= pos[i]``.  The grid runs (block of sequences, position
+    block), a block ``[sequences, positions, kv * hd]`` of whole rows as
+    they lie (:func:`joined_block_rows`: one sequence's run of positions
+    where a position's rows are 1 KB and more, several sequences' capped
+    runs where they are thin), so its DMA is a contiguous run a
+    sequence, a block past every ``pos`` of its sequences is neither
+    fetched nor computed, and each KV head's ``[positions, hd]`` is a
+    lane-aligned slice of it — the operand the products want.
+
+    A block of several sequences is fetched as far as the *furthest* of
+    them has come.  The ring's sequences of a group stand at one
+    position, so nothing is fetched twice or in vain; a holder whose
+    sequences stand at unlike positions (the serving engine could hold
+    such a format; no list is walked here) fetches every sequence of a
+    block to the longest one's ``pos``, and masks the others.
+
+    The same name in a device trace as :func:`kv_attend`, and like it
+    ``name`` where a format names its kernels
+    (:attr:`KVCacheFormat.kernel_suffix`)."""
     b, d = q.shape
     groups, _, length, width = k_buf.shape
     hd = width // kv
     heads = d // hd
-    tl = joined_block_rows(kv, hd, length, k_buf.dtype.itemsize)
+    sb, tl = joined_block_rows(kv, hd, length, k_buf.dtype.itemsize, b)
+    blocks = b // sb
+    # a sequence's rows of the softmax's state start on a sublane tile
+    stride = -(-heads // 8) * 8
     pos = jnp.clip(pos.astype(jnp.int32), 0, length - 1)
     group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
 
     def head_block(i, t, group_ref, pos_ref):
         return (i, 0, 0)
 
+    def last_block(i, pos_ref):
+        """The last position block any sequence of block ``i`` reads."""
+        return functools.reduce(
+            jnp.maximum, _block_positions(pos_ref, i, sb)) // tl
+
     def cache_block(i, t, group_ref, pos_ref):
         # as in kv_attend: past the last live block, name the first
-        # block of the grid's next sequence
-        more = jnp.logical_and(t > pos_ref[i] // tl, i + 1 < b)
+        # block of the grid's next block of sequences
+        more = jnp.logical_and(t > last_block(i, pos_ref), i + 1 < blocks)
         i = jnp.where(more, i + 1, i)
-        t = jnp.where(more, 0, jnp.minimum(t, pos_ref[i] // tl))
+        t = jnp.where(more, 0, jnp.minimum(t, last_block(i, pos_ref)))
         return (group_ref[0], i, t, 0)
 
     out = pl.pallas_call(
         functools.partial(_attend_joined_kernel, tl=tl, kv=kv,
                           scale=1.0 / math.sqrt(hd)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, pl.cdiv(length, tl)),
-            in_specs=[pl.BlockSpec((1, heads, hd), head_block),
-                      pl.BlockSpec((1, 1, tl, width), cache_block),
-                      pl.BlockSpec((1, 1, tl, width), cache_block)],
-            out_specs=pl.BlockSpec((1, heads, hd), head_block),
-            scratch_shapes=[pltpu.VMEM((heads, _LANES), jnp.float32),
-                            pltpu.VMEM((heads, _LANES), jnp.float32),
-                            pltpu.VMEM((heads, hd), jnp.float32)]),
+            num_scalar_prefetch=2, grid=(blocks, pl.cdiv(length, tl)),
+            in_specs=[pl.BlockSpec((sb, heads, hd), head_block),
+                      pl.BlockSpec((1, sb, tl, width), cache_block),
+                      pl.BlockSpec((1, sb, tl, width), cache_block)],
+            out_specs=pl.BlockSpec((sb, heads, hd), head_block),
+            scratch_shapes=[pltpu.VMEM((sb * stride, _LANES), jnp.float32),
+                            pltpu.VMEM((sb * stride, _LANES), jnp.float32),
+                            pltpu.VMEM((sb * stride, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, heads, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -919,17 +988,30 @@ class KVCacheFormat(RingRows):
 
     # -- what a holder posts ---------------------------------------------
 
-    #: a window's rows are a layer's measure: over layers the largest
-    largest = frozenset({"decode.cache.window_positions"})
+    #: a window's rows and a block's extents are a layer's measures:
+    #: over layers the largest
+    largest = frozenset({"decode.cache.window_positions",
+                         "decode.cache.block_sequences",
+                         "decode.cache.block_positions"})
 
     def gauges(self, batch: int, stages: int) -> dict[str, int]:
         """The layer's bytes under its kind — a ring buffer of a
-        window's rows, or a row a position — and the window's rows."""
+        window's rows, or a row a position —, the window's rows, and
+        for joined rows the two extents of the attention's block
+        (:func:`joined_block_rows`; 0 and 0 for any other rows)."""
         held = stages * self.state_bytes(batch, 1)
         ring = self.window is not None
+        sequences = positions = 0
+        if self.joined:
+            rows = self.buffers(batch)["k"]
+            sequences, positions = joined_block_rows(
+                self.kv_heads, self.head_dim, rows.shape[-2],
+                jnp.dtype(rows.dtype).itemsize, batch)
         return {"decode.cache.window_bytes": held if ring else 0,
                 "decode.cache.full_bytes": 0 if ring else held,
-                "decode.cache.window_positions": self.window or 0}
+                "decode.cache.window_positions": self.window or 0,
+                "decode.cache.block_sequences": sequences,
+                "decode.cache.block_positions": positions}
 
     def rows_read(self, rows: int, positions: int) -> dict[str, int]:
         """The cached rows the step's attention read, under its kind:

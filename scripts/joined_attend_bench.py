@@ -1,0 +1,122 @@
+"""``kv_attend_joined`` (``defer_tpu/ops/kv_cache.py``) alone, timed on
+the chip: one decode step's attention of one layer over joined bfloat16
+buffers of noise, every sequence at one position as the ring has them.
+Chip only.
+
+    python scripts/joined_attend_bench.py [OUT.json] [case ...]
+
+A case is ``kv:queries:sequences:rows:pos[:cap]`` — KV heads of 128,
+queries a KV head, sequences, the buffers' rows (a multiple of 16) and
+the position; ``cap`` sets ``_BLOCK_POSITIONS`` for that case, in a
+tree that has it.  Without cases: Jamba's call (one KV head, 20
+queries, 256 sequences, 4368 rows) along the cell's window — 384, the
+traced window's middle; 1136, the 40 s window's mean; 2016, 4351 — and
+the same at a cap of 1024; that call over buffers of 512 rows, whose
+block *is* 512 rows whatever the tree (the bytes a block of live rows
+fetches, a sequence a grid step); and Mellum2's and command-a-plus's
+full layers at their windows' ends (4 x 8 queries at 28671 of 28688
+rows, 8 x 16 at 12287 of 12304; 16 sequences), which no tree may move.
+
+A line a case: the block's extents as the tree's ``joined_block_rows``
+gives them (``(1, positions)`` before PR 63, whose function returned
+the positions alone), the grid, milliseconds a call (``CALLS`` calls
+behind two warm-ups), the bytes of the live rows and of the blocks
+fetched over that time as shares of the memory peak, and the largest
+distance from ``attend_einsum`` in float32 over the first two
+sequences.
+"""
+
+from __future__ import annotations
+
+import inspect
+import json
+import sys
+import time
+
+sys.path.insert(0, ".")
+
+import jax                                                  # noqa: E402
+import jax.numpy as jnp                                     # noqa: E402
+
+from chipbench.roofline import peaks_for                    # noqa: E402
+from defer_tpu.ops import kv_cache                          # noqa: E402
+
+CALLS, HD = 50, 128
+
+JAMBA = "1:20:256:4368:"
+DEFAULT = [JAMBA + "384", JAMBA + "1136", JAMBA + "2016", JAMBA + "4351",
+           JAMBA + "384:1024", JAMBA + "1136:1024", JAMBA + "2016:1024",
+           "1:20:256:512:384", "4:8:16:28688:28671", "8:16:16:12304:12287"]
+
+
+def geometry(kv: int, rows: int, b: int) -> tuple[int, int]:
+    """``(sequences, positions)`` of the tree's block."""
+    fn = kv_cache.joined_block_rows
+    if "b" in inspect.signature(fn).parameters:
+        return fn(kv, HD, rows, 2, b)
+    return 1, fn(kv, HD, rows, 2)
+
+
+def run(case: str, peak_bytes_s: float) -> dict:
+    kv, g, b, rows, pos, *cap = map(int, case.split(":"))
+    if cap and not hasattr(kv_cache, "_BLOCK_POSITIONS"):
+        return {"case": case, "skipped": "this tree has no cap to set"}
+    kept = getattr(kv_cache, "_BLOCK_POSITIONS", None)
+    if cap:
+        kv_cache._BLOCK_POSITIONS = cap[0]
+    try:
+        sb, tl = geometry(kv, rows, b)
+        keys = jax.random.split(jax.random.key(rows + pos), 3)
+        k_buf, v_buf = (jax.random.normal(key, (1, b, rows, kv * HD),
+                                          jnp.bfloat16) for key in keys[:2])
+        q = jax.random.normal(keys[2], (b, kv * g * HD), jnp.bfloat16)
+        at, group = jnp.full(b, pos, jnp.int32), jnp.zeros(1, jnp.int32)
+        # a fresh trace a case: the block is sized when the kernel is built
+        call = jax.jit(lambda q, k, v: kv_cache.kv_attend_joined.__wrapped__(
+            q, k, v, at, group, kv=kv))
+        for _ in range(2):
+            out = call(q, k_buf, v_buf).block_until_ready()
+        start = time.perf_counter()
+        for _ in range(CALLS):
+            out = call(q, k_buf, v_buf)
+        out.block_until_ready()
+        seconds = (time.perf_counter() - start) / CALLS
+    finally:
+        if cap:
+            kv_cache._BLOCK_POSITIONS = kept
+    item = {key: buf[0, :2].reshape(2, rows, kv, HD).swapaxes(1, 2)
+            .astype(jnp.float32) for key, buf in (("k", k_buf), ("v", v_buf))}
+    want = kv_cache.attend_einsum(q[:2].astype(jnp.float32), item, pos)
+    row = 2 * kv * HD * 2               # a position's keys and values
+    live = b * (pos + 1) * row
+    fetched = b * min(-(-(pos + 1) // tl) * tl, rows) * row
+    return {"case": case, "block": [sb, tl], "grid": [b // sb, -(-rows // tl)],
+            "ms": seconds * 1e3, "live_mb": live / 1e6,
+            "fetched_mb": fetched / 1e6,
+            "live_share_of_peak": live / seconds / peak_bytes_s,
+            "fetched_share_of_peak": fetched / seconds / peak_bytes_s,
+            "max_err": float(jnp.max(jnp.abs(
+                out[:2].astype(jnp.float32) - want)))}
+
+
+def main(argv: list[str]) -> int:
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(f"joined_attend_bench: a {device.platform} device times "
+              "nothing a chip would", file=sys.stderr)
+        return 1
+    out = argv[0] if argv else None
+    peak = peaks_for(device.device_kind)["hbm_bytes_per_s"]
+    lines = []
+    for case in argv[1:] or DEFAULT:
+        lines.append(run(case, peak))
+        print(json.dumps(lines[-1]), flush=True)
+    if out:
+        with open(out, "w") as f:
+            json.dump({"device_kind": device.device_kind, "calls": CALLS,
+                       "cases": lines}, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -1,0 +1,90 @@
+"""sha256 of ``kv_attend_joined`` (``defer_tpu/ops/kv_cache.py``) as it
+lowers for the chip at the shapes of the three cells that call it, to
+show that a change to the kernel left a cell's call what it was — no
+chip needed, not part of the tests.  ``scripts/lowered_text_hashes.py``
+cannot say: no tiny family's heads are a lane row wide, so none holds
+joined rows.
+
+    env JAX_PLATFORMS=cpu python scripts/joined_lowering_hashes.py [DIR]
+
+Run it in two trees and compare the lines: a call a line — Mellum2's
+and command-a-plus's full and window layers (4 x 8 and 8 x 16 queries on
+heads of 128, 16 sequences) and Jamba's (1 x 20, 256 sequences), over
+bfloat16 buffers as the cells hold them — with the hash of the Mosaic
+kernel's text and of the text around it.  The kernel's body travels as
+bytecode that carries its source lines; it is hashed as text without
+them.  With ``DIR`` both texts are written there for ``diff``.
+"""
+
+import base64
+import functools
+import hashlib
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+from jax._src.interpreters import mlir
+from jax._src.lib import tpu
+from jax._src.lib.mlir import ir
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from defer_tpu.ops import kv_cache
+
+#: name: (KV heads, queries a head, sequences, the buffers' rows)
+CALLS = {
+    "mellum2.full": (4, 8, 16, 28688),
+    "mellum2.window": (4, 8, 16, 1040),
+    "commandaplus.full": (8, 16, 16, 12304),
+    "commandaplus.window": (8, 16, 16, 4112),
+    "jamba2": (1, 20, 256, 4368),
+}
+
+
+def kernel_text(lowered: str) -> tuple[str, str]:
+    """``(the Mosaic module as text without locations, the rest)``."""
+    config = re.search(r'backend_config = "(\{.*?\})"', lowered).group(1)
+    body = json.loads(config.replace("\\22", '"'))["custom_call_config"]["body"]
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True      # the versioned dialect
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body))
+        return (module.operation.get_asm(enable_debug_info=False),
+                lowered.replace(config, ""))
+
+
+def main(out: str | None = None) -> int:
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    # the call asks the backend whether to interpret: lower the kernel
+    jax.default_backend = lambda: "tpu"
+    if out:
+        os.makedirs(out, exist_ok=True)
+    for name, (kv, g, b, rows) in CALLS.items():
+        def arg(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+        buf = arg((2, b, rows, kv * 128))
+        lowered = jax.jit(functools.partial(
+            kv_cache.kv_attend_joined.__wrapped__, kv=kv)).lower(
+                arg((b, kv * g * 128)), buf, buf, arg((b,), jnp.int32),
+                arg((1,), jnp.int32)).as_text(debug_info=False)
+        kernel, around = kernel_text(lowered)
+        print(name, *(hashlib.sha256(t.encode()).hexdigest()
+                      for t in (kernel, around)), len(kernel))
+        if out:
+            for kind, text in (("kernel", kernel), ("around", around)):
+                with open(os.path.join(out, f"{name}.{kind}.txt"), "w") as f:
+                    f.write(text)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(*sys.argv[1:2]))

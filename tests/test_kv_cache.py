@@ -881,9 +881,10 @@ def test_a_ring_buffer_holds_window_rows_and_the_scratch_row(quantized,
 
 @pytest.mark.parametrize("g,hd,dtype", [
     (1, 8, jnp.float32), (4, 8, jnp.float32), (4, 64, jnp.bfloat16),
-    (16, 128, jnp.float32), (16, 128, jnp.bfloat16), (8, 128, jnp.float32)],
+    (16, 128, jnp.float32), (16, 128, jnp.bfloat16), (8, 128, jnp.float32),
+    (20, 128, jnp.bfloat16)],
     ids=["g1", "g4", "g4-hd64-bf16", "g16-joined", "g16-joined-bf16",
-         "g8-joined"])
+         "g8-joined", "g20-joined-bf16"])
 @pytest.mark.parametrize("plen", [3, 6, 7, 17], ids=[
     "under", "at", "over", "wrapped-twice"])
 def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
@@ -891,7 +892,8 @@ def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
     decode steps across the next wraps: every step's attention is the
     dense attention over the ``window`` newest positions — through the
     vector kernel (groups 1 and 4) and through joined rows on the matrix
-    unit (8 and 16 queries a KV head of 128)."""
+    unit (8, 16 and 20 queries a KV head of 128; in bfloat16 the two
+    heads' rows are thin and both sequences share a block)."""
     kv, w, total, b = 2, 6, 30, 2
     fmt = KVCacheFormat(kv, hd, total, dtype, groups=1, window=w,
                         query_group=g)
@@ -949,29 +951,104 @@ def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
                                np.asarray(ys[-1], np.float32), atol=tol)
 
 
-@pytest.mark.parametrize("positions", [[5, 300], [511, 512], [1029, 0]],
-                         ids=["block0", "edge", "three-blocks"])
+#: (KV heads, queries a head, a position a sequence), over 1100
+#: positions of heads of 128: 16 queries on two KV heads — in float32
+#: 1 KB a position, a sequence a block — and Jamba's 20 on one, whose
+#: thin rows share a block of 512 positions among sequences: all at one
+#: position; each at its own, on both sides of a block's edge, at 0 and
+#: at the last row; six and two sequences, which eight a block (four in
+#: float32) do not divide
+_JOINED_CASES = {
+    "block0": (2, 16, [5, 300]),
+    "edge": (2, 16, [511, 512]),
+    "three-blocks": (2, 16, [1029, 0]),
+    "thin-one-position": (1, 20, [700] * 4),
+    "thin-edges": (1, 20, [0, 511, 512, 1099]),
+    "thin-six": (1, 20, [5, 300, 511, 512, 1029, 0]),
+    "thin-two": (1, 20, [1029, 0]),
+}
+
+
+@pytest.mark.parametrize("groups", [None, 2], ids=["slots", "groups"])
+@pytest.mark.parametrize("case", _JOINED_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_joined_attention_at_each_sequences_own_position(positions, dtype):
-    """16 queries a KV head over joined rows, each sequence at its own
-    position, over several position blocks and a length that is no
-    multiple of the block: the einsums over the live rows."""
-    kv, g, hd, length, b = 2, 16, 128, 1100, 2
-    fmt = KVCacheFormat(kv, hd, length, dtype, query_group=g)
+def test_joined_attention_at_each_sequences_own_position(case, dtype, groups):
+    """A matrix's rows of queries a KV head over joined rows, each
+    sequence at its own position, over several position blocks and a
+    length that is no multiple of the block: the einsums over the live
+    rows.  Every row nothing may read is NaN — the tiles' padding, and
+    with groups (the ring's call: the group an index) the scratch row
+    and the scratch group."""
+    kv, g, positions = _JOINED_CASES[case]
+    hd, length, b = 128, 1100, len(positions)
+    fmt = KVCacheFormat(kv, hd, length, dtype, groups=groups, query_group=g)
     assert fmt.joined
+    sequences, rows = kv_cache.joined_block_rows(
+        kv, hd, fmt.buffers(b)["k"].shape[-2], jnp.dtype(dtype).itemsize, b)
+    row = kv * hd * jnp.dtype(dtype).itemsize
+    if row < 1024:
+        # the cap engages, and a block holds as many of the sequences
+        # as a megabyte holds and divide them
+        assert rows == 512 and sequences == max(
+            d for d in range(1, 2048 // row + 1) if b % d == 0) > 1
+    else:
+        assert sequences == 1 and rows == 1024 < length
     rng = np.random.default_rng(7)
     layer = {key: jnp.asarray(rng.standard_normal(s.shape), dtype)
+             .at[..., length:, :].set(jnp.nan)
              for key, s in fmt.buffers(b).items()}
+    group = None
+    if groups is not None:
+        group = jnp.int32(1)
+        layer = {key: buf.at[fmt.scratch_group].set(jnp.nan)
+                 for key, buf in layer.items()}
     q = jnp.asarray(rng.standard_normal((b, kv * g * hd)), dtype)
     pos = jnp.asarray(positions, jnp.int32)
-    got = jax.jit(fmt.attend)(q, layer, pos)
+    got = jax.jit(fmt.attend)(q, layer, pos, group)
     want = attend_einsum(q.astype(jnp.float32), {
-        key: buf.astype(jnp.float32)
-        for key, buf in fmt.head_major(layer).items()}, pos)
+        key: jnp.nan_to_num(buf.astype(jnp.float32)) for key, buf
+        in fmt.head_major({key: buf if groups is None else buf[1]
+                           for key, buf in layer.items()}).items()}, pos)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want),
         atol=2e-5 if dtype == jnp.float32 else 3e-2)
+
+
+#: bfloat16 formats of heads of 128 as three cells hold them — (KV
+#: heads, queries a head, positions, window, sequences a group) — with
+#: their buffers' rows and the attention's block over them
+_CELL_BLOCKS = {
+    "mellum2-full": ((4, 8, 28672, None, 16), 28688, (1, 1024)),
+    "mellum2-window": ((4, 8, 28672, 1024, 16), 1040, (1, 1024)),
+    "commandaplus-full": ((8, 16, 12288, None, 16), 12304, (1, 512)),
+    "commandaplus-window": ((8, 16, 12288, 4096, 16), 4112, (1, 512)),
+    "jamba2": ((1, 20, 4352, None, 256), 4368, (8, 512)),
+    "jamba2-a-group-of-two": ((1, 20, 4352, None, 2), 4368, (2, 512)),
+}
+
+
+@pytest.mark.parametrize("cell", _CELL_BLOCKS)
+def test_a_joined_block_is_sized_from_the_operands_shapes(cell):
+    """A position of 1 KB and more keeps the block it had, one
+    sequence's run of as many positions as a megabyte holds (Mellum2's
+    and command-a-plus's calls lower as they did); Jamba's 256 B a
+    position stop at 512 positions and fill the block with sequences —
+    and the format's gauges say which."""
+    (kv, g, positions, window, b), length, want = _CELL_BLOCKS[cell]
+    fmt = KVCacheFormat(kv, 128, positions, jnp.bfloat16, groups=1,
+                        window=window, query_group=g)
+    assert fmt.joined and fmt.buffers(b)["k"].shape[-2] == length
+    assert kv_cache.joined_block_rows(kv, 128, length, 2, b) == want
+    said = fmt.gauges(b, 1)
+    assert (said["decode.cache.block_sequences"],
+            said["decode.cache.block_positions"]) == want
+    assert {"decode.cache.block_sequences",
+            "decode.cache.block_positions"} <= fmt.largest
+    plain = KVCacheFormat(kv, 128, positions, jnp.bfloat16, groups=1,
+                          window=window).gauges(b, 1)
+    assert plain["decode.cache.block_sequences"] == 0
+    assert plain["decode.cache.block_positions"] == 0
 
 
 def test_a_joined_prefix_of_a_piece_lands_at_its_sequences():

@@ -21,10 +21,14 @@ FAMILIES = ("gpt_tiny", "olmoe_tiny", "brumby_tiny", "cohere_moe_tiny",
 #: every name some format of some family answers with
 NAMES = (
     "decode.cache.window_bytes", "decode.cache.full_bytes",
-    "decode.cache.window_positions", "decode.cache.latent_bytes",
+    "decode.cache.window_positions", "decode.cache.block_sequences",
+    "decode.cache.block_positions", "decode.cache.latent_bytes",
     "decode.cache.latent_positions", "decode.cache.latent_sublayers",
     "decode.ssm.conv_bytes", "decode.cache.full_rows_read",
     "decode.cache.window_rows_read")
+#: a layer's measures, no amounts: over layers the largest stands
+LARGEST = {"decode.cache.window_positions", "decode.cache.block_sequences",
+           "decode.cache.block_positions"}
 KINDS = ("kv_cache", "retention", "ssm", "latent_cache")
 MB, UNSET = 2, -1
 
@@ -44,8 +48,7 @@ def test_a_decoders_gauges_are_what_its_formats_say(family):
     for fmt, layer in zip(dec.state_formats, said):
         for name, value in layer.items():
             want[name] = max(want.get(name, 0), value) \
-                if name == "decode.cache.window_positions" \
-                else want.get(name, 0) + value
+                if name in LARGEST else want.get(name, 0) + value
     assert want == totals(dec.state_formats, lambda fmt: fmt.gauges(MB, n))
     # a step's reads are posted only where the formats tell kinds apart
     reads = totals(dec._row_readers, lambda fmt: fmt.rows_read(MB * n, 7))
