@@ -73,7 +73,13 @@ over a layer's buffers *where they lie* — the Pallas kernel
 :func:`kv_attend`, which takes the group as an index and reads the
 position blocks that hold live rows and no other.  Only the int8 rows
 stay on the plain einsum (:func:`attend_einsum`), which is also the
-oracle the tests hold the kernel to.  A block is fetched whole, so what
+oracle the tests hold the kernel to.  Which of two kernels a float
+format takes is its geometry's: :func:`kv_attend` walks a block once a
+query on the vector unit, which one query a KV head does at the
+memory's pace and two or more do not, so heads of whole lane rows read
+by a group of :data:`_JOINED_GROUP` or more queries hold their buffers
+*joined* (:attr:`KVCacheFormat.joined`) and attend on the matrix unit,
+:func:`kv_attend_joined`.  A block is fetched whole, so what
 it holds past ``pos`` is read for nothing: over joined rows
 (:func:`kv_attend_joined`) a block has two extents, sequences and
 positions, and where a position's rows are thin (one KV head of 128:
@@ -122,10 +128,15 @@ _BLOCK_POSITIONS = 512
 #: tile of 16-bit rows)
 _JOINED_ROWS = 16
 #: from this many queries a KV head on, the group is the rows of a
-#: matrix product a position block (:func:`kv_attend_joined`); under it
-#: the vector unit's multiply-and-reduce a query keeps up with the DMA
-#: (8 rows fill a sublane tile of f32)
-_MXU_GROUP = 8
+#: matrix product a position block (:func:`kv_attend_joined`).  One
+#: query's multiply-and-reduce on the vector unit keeps up with the DMA
+#: (OLMoE's call reads 82% of its bytes' time); a pass a query does not
+#: from the second on — granite's group of 4 took 1.70 ms where its rows
+#: take 0.36, 21% — while the products' cost a block is the tiles of
+#: keys and values they load, whatever the rows pushed through them: the
+#: same call over joined rows takes 0.60 ms, at 4 queries as at 2 or 16
+#: (docs/DECODE_CLIFF.md, "The attention"; PERF.md §6, PR 64)
+_JOINED_GROUP = 2
 
 
 def live_slots(slots, width: int) -> np.ndarray:
@@ -458,12 +469,17 @@ def _attend_joined_kernel(group_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     sequence's rows, its scores ``[g, hd] x [hd, tl]`` and its output
     ``[g, tl] x [tl, hd]`` on the matrix unit, accumulated in f32 (16
     queries over 512 bytes a position are 8192 f32 operations for every
-    512 bytes: more than the vector unit has at the memory's pace); the
-    online softmax between them runs on ``[g, tl]``.  Each sequence
-    masks by its own ``pos``.  m_ref / l_ref ``[sb * stride, 128]`` (a
-    row's scalar on every lane), acc_ref ``[sb * stride, hd]``: a
-    sequence's heads from row ``j * stride`` on, whole sublane tiles
-    apart."""
+    512 bytes: more than the vector unit has at the memory's pace — and
+    so are 4, 2048 of them, which it walks a pass a query); the online
+    softmax between them runs on ``[g, tl]``.  The keys and values are
+    the products' stationary operand: a block costs the tiles it loads,
+    not the ``g`` rows pushed through them, so a group of 2 takes what a
+    group of 16 does, and ``g`` need fill no tile — a head's rows are a
+    slice at ``h * g`` of the queries and of the state, whatever ``g``
+    (2 to 7 lower under Mosaic as 8 and 16 do).  Each sequence masks by
+    its own ``pos``.  m_ref / l_ref ``[sb * stride, 128]`` (a row's
+    scalar on every lane), acc_ref ``[sb * stride, hd]``: a sequence's
+    heads from row ``j * stride`` on, whole sublane tiles apart."""
     del group_ref                       # the index maps read it
     t = pl.program_id(1)
     sb, heads, hd = q_ref.shape
@@ -553,9 +569,9 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
                      name: str = "kv_attend"):
     """:func:`kv_attend` over *joined* buffers ``[groups, b, L, kv *
     hd]`` (:attr:`KVCacheFormat.joined`: a position's rows of all KV
-    heads side by side on the lanes), for a query group that fills the
-    matrix unit's rows: ``q`` [b, heads * hd], sequence ``i`` over its
-    rows ``<= pos[i]``.  The grid runs (block of sequences, position
+    heads side by side on the lanes), for a query group of two or more
+    (:data:`_JOINED_GROUP`): ``q`` [b, heads * hd], sequence ``i`` over
+    its rows ``<= pos[i]``.  The grid runs (block of sequences, position
     block), a block ``[sequences, positions, kv * hd]`` of whole rows as
     they lie (:func:`joined_block_rows`: one sequence's run of positions
     where a position's rows are 1 KB and more, several sequences' capped
@@ -652,9 +668,12 @@ def kv_attend(q, k_buf, v_buf, pos, group, live=None, *,
     hd]``.  Where the bytes lie otherwise, the view is a transpose and
     the compile checks (``scripts/*_tpu_compile_check.py``) say so.
     The query group of a KV head rides along in the block — a few
-    queries; a group of :data:`_MXU_GROUP` or more is a matrix's rows,
-    and a format for such a group holds its buffers joined for
-    :func:`kv_attend_joined` (the same name in a device trace).
+    queries, a pass over the block each; a group of
+    :data:`_JOINED_GROUP` or more over heads of whole lane rows is a
+    matrix's rows, and a format for such a group holds its buffers
+    joined for :func:`kv_attend_joined` (the same name in a device
+    trace): here that leaves one query a KV head, and the groups of
+    heads under a lane row.
 
     With ``live`` (:func:`live_slots`, [b + 1] int32) the grid's first
     axis walks the list as :func:`write_kv_rows`'s does: step ``j``
@@ -937,8 +956,10 @@ class KVCacheFormat(RingRows):
     def joined(self) -> bool:
         """Whether the buffers are ``[batch, positions, kv_heads *
         head_dim]`` (the module docstring): for float rows of whole
-        lane rows read by a group the matrix unit is for."""
-        return (self.query_group >= _MXU_GROUP and not self.quantized
+        lane rows read by a group of queries (:data:`_JOINED_GROUP`:
+        two or more; one query a KV head keeps plain rows and the
+        vector unit)."""
+        return (self.query_group >= _JOINED_GROUP and not self.quantized
                 and self.head_dim % _LANES == 0)
 
     def __post_init__(self):
@@ -1231,6 +1252,7 @@ class KVCacheFormat(RingRows):
             layer, pos, group, q.shape[0])
         name = "kv_attend" + self.kernel_suffix
         if self.joined:
+            REGISTRY.gauge("decode.kv.joined_layers").inc()
             return kv_attend_joined(q, k_buf, v_buf, pos, group,
                                     kv=self.kv_heads, name=name)
         return kv_attend(q, k_buf, v_buf, pos, group, live, name=name)

@@ -609,8 +609,10 @@ class PipelinedDecoder:
                 x = a[:, : self.d_model].astype(cd)
 
             # counted anew a stage's trace: the layers whose format
-            # writes the step's row inside its attention (kv_step)
+            # writes the step's row inside its attention (kv_step), and
+            # those that attend over joined rows (kv_attend_joined)
             REGISTRY.gauge("decode.kv.fused_layers").set(0)
+            REGISTRY.gauge("decode.kv.joined_layers").set(0)
             for l, (nm, op) in enumerate(zip(self.stage_blocks[s],
                                              block_ops)):
                 # the block's step against its layer's buffers where

@@ -21,20 +21,27 @@ What it answers before any chip time is spent:
   of a layer's experts or of a layer's state or cache buffer inside a
   loop (``scripts/hlo_cache_ops.py``);
 * does the decode program hold an ``ssd_step`` call a Mamba layer and
-  the prefill an ``ssd_scan`` call a Mamba layer.
+  the prefill an ``ssd_scan`` call a Mamba layer;
+* does the decode program hold **one** ``kv_attend`` call, over joined
+  rows (``decode.kv.joined_layers`` 1: four queries a KV head of 128,
+  ``ops/kv_cache.py::_JOINED_GROUP``), and neither a copy the size of
+  the cache buffer nor a slice the size of a group's item around it —
+  a view of the joined rows that became a transpose shows here.
 
     env JAX_PLATFORMS=cpu python scripts/granite_tpu_compile_check.py
 
 A few minutes and ~12 GB of host memory (the weights are zeros); one
 JSON line; exit 0 when both programs fit under 16 GB, the state's
-arguments stay within 1.10 of the need and nothing weight-sized or
-state-sized is produced inside a loop.  A process of its own, like the
+arguments stay within 1.10 of the need, nothing weight-sized or
+state-sized is produced inside a loop and the decode program's one
+``kv_attend`` is the joined one.  A process of its own, like the
 other compile checks: the TPU's library is locked machine-wide while
 it runs.
 """
 
 import json
 import os
+import re
 import sys
 from unittest import mock
 
@@ -50,6 +57,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from defer_tpu.models import granite_hybrid
+from defer_tpu.obs.registry import REGISTRY
 from defer_tpu.ops.layered import shapes_by_layer
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
@@ -167,6 +175,10 @@ def main() -> int:
             "state_over_need": state / need,
             "state_ops": state_ops,
             "kernels": text.count('custom_call_target="tpu_custom_call"'),
+            # the cache kernels by name, as a device trace tells them
+            "cache_kernels": {k: len(re.findall(
+                rf"%{k}[.\d]* = .*tpu_custom_call", text))
+                for k in ("kv_attend", "kv_step", "kv_write_rows")},
             # the shape rule (defer_tpu/ops/grouped.py): a step's
             # products on the kernel, the prompt's on the tiled one
             **grouped_products(text), **rule[name].read,
@@ -180,6 +192,14 @@ def main() -> int:
             and all(c["buffer_copies"] <= allowed.get(key, 0)
                     and not c["item_copies"]
                     for key, c in state_ops.items())
+        if name == "decode":
+            # the one attention layer's step: a slice of a position's
+            # rows written, one kernel over the joined rows where they
+            # lie (the gauge was set as the decode program was traced)
+            joined = int(REGISTRY.gauge("decode.kv.joined_layers").value)
+            row[name]["joined_layers"] = joined
+            ok = ok and joined == 1 and row[name]["cache_kernels"] == {
+                "kv_attend": 1, "kv_step": 0, "kv_write_rows": 0}
     print(json.dumps(row))
     return 0 if ok else 1
 

@@ -13,9 +13,14 @@ queries, 256 sequences, 4368 rows) along the cell's window — 384, the
 traced window's middle; 1136, the 40 s window's mean; 2016, 4351 — and
 the same at a cap of 1024; that call over buffers of 512 rows, whose
 block *is* 512 rows whatever the tree (the bytes a block of live rows
-fetches, a sequence a grid step); and Mellum2's and command-a-plus's
+fetches, a sequence a grid step); Mellum2's and command-a-plus's
 full layers at their windows' ends (4 x 8 queries at 28671 of 28688
-rows, 8 x 16 at 12287 of 12304; 16 sequences), which no tree may move.
+rows, 8 x 16 at 12287 of 12304; 16 sequences), which no tree may move;
+and granite's call (8 KV heads, 4 queries, 64 sequences, 3088 rows) at
+the traced window's position, the 40 s window's mean and its end —
+1151, 1839, 2623 — with a group of 2 beside it, the low edge of what
+holds joined rows (``_JOINED_GROUP``).  The function is called as it
+is, whatever ``KVCacheFormat.joined`` says of the group in that tree.
 
 A line a case: the block's extents as the tree's ``joined_block_rows``
 gives them (``(1, positions)`` before PR 63, whose function returned
@@ -43,10 +48,12 @@ from defer_tpu.ops import kv_cache                          # noqa: E402
 
 CALLS, HD = 50, 128
 
-JAMBA = "1:20:256:4368:"
+JAMBA, GRANITE = "1:20:256:4368:", "8:4:64:3088:"
 DEFAULT = [JAMBA + "384", JAMBA + "1136", JAMBA + "2016", JAMBA + "4351",
            JAMBA + "384:1024", JAMBA + "1136:1024", JAMBA + "2016:1024",
-           "1:20:256:512:384", "4:8:16:28688:28671", "8:16:16:12304:12287"]
+           "1:20:256:512:384", "4:8:16:28688:28671", "8:16:16:12304:12287",
+           GRANITE + "1151", GRANITE + "1839", GRANITE + "2623",
+           "8:2:64:3088:1839"]
 
 
 def geometry(kv: int, rows: int, b: int) -> tuple[int, int]:

@@ -1,5 +1,5 @@
 """sha256 of ``kv_attend_joined`` (``defer_tpu/ops/kv_cache.py``) as it
-lowers for the chip at the shapes of the three cells that call it, to
+lowers for the chip at the shapes of the four cells that call it, to
 show that a change to the kernel left a cell's call what it was — no
 chip needed, not part of the tests.  ``scripts/lowered_text_hashes.py``
 cannot say: no tiny family's heads are a lane row wide, so none holds
@@ -9,7 +9,9 @@ joined rows.
 
 Run it in two trees and compare the lines: a call a line — Mellum2's
 and command-a-plus's full and window layers (4 x 8 and 8 x 16 queries on
-heads of 128, 16 sequences) and Jamba's (1 x 20, 256 sequences), over
+heads of 128, 16 sequences), Jamba's (1 x 20, 256 sequences) and, since
+PR 64, granite's (8 x 4, 64 sequences: a tree from before it lowers the
+same call, which its format did not yet make) — over
 bfloat16 buffers as the cells hold them — with the hash of the Mosaic
 kernel's text and of the text around it.  The kernel's body travels as
 bytecode that carries its source lines; it is hashed as text without
@@ -44,6 +46,7 @@ CALLS = {
     "commandaplus.full": (8, 16, 16, 12304),
     "commandaplus.window": (8, 16, 16, 4112),
     "jamba2": (1, 20, 256, 4368),
+    "granite4h": (8, 4, 64, 3088),
 }
 
 

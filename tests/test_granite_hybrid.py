@@ -17,6 +17,11 @@ measure, ``logit_gaps``: in float32 no generated token may sit under
 the reference's best at all.
 """
 
+import json
+import math
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -25,7 +30,7 @@ import jax.numpy as jnp
 
 from chipbench.agreement import logit_gaps, rel_err
 from chipbench.reference import granite_hybrid as ref
-from defer_tpu.models import granite_hybrid_tiny
+from defer_tpu.models import granite_hybrid, granite_hybrid_tiny
 from defer_tpu.models.cohere_moe import tie_head
 from defer_tpu.models.decoder import (DecoderBlock, StateSpaceBlock,
                                       decoder_parts)
@@ -33,6 +38,7 @@ from defer_tpu.models.granite_hybrid import (GraniteAttentionBlock,
                                              GraniteMambaBlock)
 from defer_tpu.obs import REGISTRY
 from defer_tpu.ops import ssm
+from defer_tpu.ops.kv_cache import KVCacheFormat
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -494,7 +500,6 @@ def test_the_blocks_declare_their_memory(model):
 def test_the_four_multipliers_enter_where_the_family_says(model, ids):
     """Each multiplier changed alone moves the reference and the program
     together (the tiny graph's are none of them 1)."""
-    from defer_tpu.models import granite_hybrid
     _, params = model
     base = dict(embedding_multiplier=6.0, residual_multiplier=0.35,
                 attention_multiplier=0.1, logits_scaling=4.0)
@@ -510,3 +515,55 @@ def test_the_four_multipliers_enter_where_the_family_says(model, ids):
         assert rel_err(got, ref.logits(params, ids[:1],
                                        **dict(REF, **args))) < RTOL
         assert rel_err(got, ref.logits(params, ids[:1], **REF)) > 50 * RTOL
+
+
+def test_the_published_geometry_attends_over_joined_rows(ids):
+    """32 query heads on 8 KV heads of 128 are four queries a KV head:
+    a group for the matrix unit (``ops/kv_cache.py::_JOINED_GROUP``),
+    so the cell's attention layer holds its rows joined — 3072
+    positions and the scratch row in whole sublane tiles, 2 KB a
+    position — and a ring step of that geometry (two layers, a narrow
+    stream) names ``kv_attend`` once, beside the gauge that says which
+    of the two kernels of that name it is; its tokens are the
+    reference's, prompt and steps over joined rows."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "chipbench", "configs",
+                           "granite-4.0-h-small-10l-ep2.json")) as f:
+        args = json.load(f)["model_args"]
+    attn = granite_hybrid(**args).nodes["block_5"].op
+    assert attn.geometry(args["hidden"]) == (32, 8, 128)
+    fmt = attn.memory_format(args["hidden"], 3072, jnp.bfloat16, groups=1)
+    assert fmt == KVCacheFormat(8, 128, 3072, jnp.bfloat16, groups=1,
+                                query_group=4)
+    assert fmt.joined and not fmt.writes_in_attention
+    assert fmt.buffers(64)["k"].shape == (2, 64, 3088, 1024)
+
+    graph = granite_hybrid(
+        2, 64, 8, 2, 128, SEQ, VOCAB, ("mamba", "attention"), 8, 16, 16,
+        8, 3, 32, 64, mamba_chunk=8)
+    params = tie_head(graph.init(jax.random.key(0)))
+    dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=2,
+                           max_len=SEQ)
+    a, caches = dec._init_state()
+    assert caches["k"][1].shape == (1, 2, 2, 48, 256)   # stage, groups, ...
+    i32 = jnp.int32(0)
+    for name in ("decode.kv.joined_layers", "decode.kv.fused_layers"):
+        REGISTRY.gauge(name).set(-1)
+    jaxpr = jax.make_jaxpr(dec._get_decode_fn(4, False, None))(
+        dec._w, jnp.zeros((1, 2, 5), jnp.int32), i32, i32, i32,
+        jnp.uint32(0), jnp.float32(0), jnp.zeros((1, 2), jnp.int32),
+        i32, i32, a, caches)
+    # (the joined call's ``jit`` is named ``kv_attend_joined``)
+    calls = re.findall(r"\bname=(kv_attend|kv_step|kv_write_rows)\b",
+                       str(jaxpr))
+    assert calls == ["kv_attend"]
+    assert REGISTRY.gauge("decode.kv.joined_layers").value == 1
+    assert REGISTRY.gauge("decode.kv.fused_layers").value == 0
+    narrow = {"module": REF_CFG["module"], "args": dict(
+        REF, layer_types=("mamba", "attention"), n_head=8, head_dim=128,
+        attention_multiplier=1 / math.sqrt(128), residual_multiplier=1.0,
+        embedding_multiplier=1.0, logits_scaling=1.0)}
+    out = dec.generate(ids[:2, :PLEN], NEW, prefill=True)
+    assert logit_gaps(params, out, PLEN, narrow).max() <= 0
+    np.testing.assert_array_equal(
+        dec.generate(ids[:2, :PLEN], NEW, prefill=False), out)

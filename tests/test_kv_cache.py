@@ -429,13 +429,17 @@ def test_a_ring_step_holds_one_cache_kernel_a_layer(model, num_stages, kv):
     """The lowered GPT ring step: one ``kv_step`` a layer, no
     ``kv_write_rows`` and no ``kv_attend``, and the gauge
     ``decode.kv.fused_layers`` reads a stage's layers; int8 rows take
-    the format's two calls (einsums, no kernel) and the gauge reads 0."""
+    the format's two calls (einsums, no kernel) and the gauge reads 0.
+    No layer attends over joined rows (heads under a lane row, one query
+    a head): ``decode.kv.joined_layers`` is set, to 0."""
     from defer_tpu.obs.registry import REGISTRY
     REGISTRY.gauge("decode.kv.fused_layers").set(-1)
+    REGISTRY.gauge("decode.kv.joined_layers").set(-1)
     dec, jaxpr = _ring_step_jaxpr(model, num_stages, kv_cache=kv)
     fused = dec.l_max if kv == "buffer" else 0
     assert _kernel_names(jaxpr.jaxpr) == ["kv_step"] * (fused * num_stages)
     assert REGISTRY.gauge("decode.kv.fused_layers").value == fused
+    assert REGISTRY.gauge("decode.kv.joined_layers").value == 0
 
 
 @pytest.mark.parametrize("kv,hd,length,itemsize,want", [
@@ -882,22 +886,27 @@ def test_a_ring_buffer_holds_window_rows_and_the_scratch_row(quantized,
 @pytest.mark.parametrize("g,hd,dtype", [
     (1, 8, jnp.float32), (4, 8, jnp.float32), (4, 64, jnp.bfloat16),
     (16, 128, jnp.float32), (16, 128, jnp.bfloat16), (8, 128, jnp.float32),
-    (20, 128, jnp.bfloat16)],
+    (20, 128, jnp.bfloat16), (1, 128, jnp.float32), (2, 128, jnp.float32),
+    (4, 128, jnp.float32), (4, 128, jnp.bfloat16), (5, 128, jnp.bfloat16),
+    (7, 128, jnp.float32)],
     ids=["g1", "g4", "g4-hd64-bf16", "g16-joined", "g16-joined-bf16",
-         "g8-joined", "g20-joined-bf16"])
+         "g8-joined", "g20-joined-bf16", "g1-hd128", "g2-joined",
+         "g4-joined", "g4-joined-bf16", "g5-joined-bf16", "g7-joined"])
 @pytest.mark.parametrize("plen", [3, 6, 7, 17], ids=[
     "under", "at", "over", "wrapped-twice"])
 def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
     """``write_prefix`` of a prompt under, at and over the window, then
     decode steps across the next wraps: every step's attention is the
     dense attention over the ``window`` newest positions — through the
-    vector kernel (groups 1 and 4) and through joined rows on the matrix
-    unit (8, 16 and 20 queries a KV head of 128; in bfloat16 the two
-    heads' rows are thin and both sequences share a block)."""
+    vector kernel (one query a KV head, and groups over heads under a
+    lane row) and through joined rows on the matrix unit (2 to 20
+    queries a KV head of 128, a group that fills a sublane tile or not;
+    in bfloat16 the two heads' rows are thin and both sequences share a
+    block)."""
     kv, w, total, b = 2, 6, 30, 2
     fmt = KVCacheFormat(kv, hd, total, dtype, groups=1, window=w,
                         query_group=g)
-    assert fmt.joined == (g >= 8 and hd == 128)
+    assert fmt.joined == (g >= 2 and hd == 128)
     if fmt.joined:
         # whole sublane tiles of positions: the window, the scratch
         # row, and padding
@@ -957,7 +966,12 @@ def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
 #: thin rows share a block of 512 positions among sequences: all at one
 #: position; each at its own, on both sides of a block's edge, at 0 and
 #: at the last row; six and two sequences, which eight a block (four in
-#: float32) do not divide
+#: float32) do not divide.  And the groups under a sublane tile — 2, 4,
+#: 5 and 7 queries a head: a head's rows of the queries and of the
+#: softmax's state are a slice at ``h * g``, inside a tile or across two
+#: — on one KV head and on granite's eight (2 KB a position in bfloat16,
+#: a block of 512; 4 KB in float32, of 256): inside the first block, on
+#: a block's last row, in a later block
 _JOINED_CASES = {
     "block0": (2, 16, [5, 300]),
     "edge": (2, 16, [511, 512]),
@@ -966,6 +980,8 @@ _JOINED_CASES = {
     "thin-edges": (1, 20, [0, 511, 512, 1099]),
     "thin-six": (1, 20, [5, 300, 511, 512, 1029, 0]),
     "thin-two": (1, 20, [1029, 0]),
+    **{f"g{g}-kv{kv}": (kv, g, [5, 511, 700])
+       for g in (2, 4, 5, 7) for kv in (1, 8)},
 }
 
 
@@ -993,7 +1009,8 @@ def test_joined_attention_at_each_sequences_own_position(case, dtype, groups):
         assert rows == 512 and sequences == max(
             d for d in range(1, 2048 // row + 1) if b % d == 0) > 1
     else:
-        assert sequences == 1 and rows == 1024 < length
+        # a megabyte of a sequence's positions, in whole lane rows
+        assert sequences == 1 and rows == (1 << 20) // row < length
     rng = np.random.default_rng(7)
     layer = {key: jnp.asarray(rng.standard_normal(s.shape), dtype)
              .at[..., length:, :].set(jnp.nan)
@@ -1015,7 +1032,7 @@ def test_joined_attention_at_each_sequences_own_position(case, dtype, groups):
         atol=2e-5 if dtype == jnp.float32 else 3e-2)
 
 
-#: bfloat16 formats of heads of 128 as three cells hold them — (KV
+#: bfloat16 formats of heads of 128 as four cells hold them — (KV
 #: heads, queries a head, positions, window, sequences a group) — with
 #: their buffers' rows and the attention's block over them
 _CELL_BLOCKS = {
@@ -1025,6 +1042,7 @@ _CELL_BLOCKS = {
     "commandaplus-window": ((8, 16, 12288, 4096, 16), 4112, (1, 512)),
     "jamba2": ((1, 20, 4352, None, 256), 4368, (8, 512)),
     "jamba2-a-group-of-two": ((1, 20, 4352, None, 2), 4368, (2, 512)),
+    "granite4h": ((8, 4, 3072, None, 64), 3088, (1, 512)),
 }
 
 
