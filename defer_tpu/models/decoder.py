@@ -22,12 +22,16 @@ it, whatever its family.
   and a state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's), no
   heads at all; :class:`ConvWindowBlock` such a window *alone*
   (``ops/conv_window.py``: a gated short convolution, no position);
+  :class:`DeltaRuleBlock` such a window and a square state a head whose
+  write *reads it* (``ops/delta_rule.py``: ``q, k, v``, a log-decay a
+  channel and the write's strength, the layer's output back);
   :class:`LatentBlock` a latent cache (``ops/latent_cache.py``): one
   row a position that every head shares, which a step attends over
   with queries absorbed into the latent space and a prompt over the
-  expanded heads.  None knows an axis order, key or type of what the
-  format holds; which kind a block keeps is the class it is (``memory``),
-  and the holder asks every block (:meth:`DecoderBlock.memory_format`).
+  expanded heads.  Six kinds of per-sequence memory; none of the
+  blocks knows an axis order, key or type of what its format holds;
+  which kind a block keeps is the class it is (``memory``), and the
+  holder asks every block (:meth:`DecoderBlock.memory_format`).
 """
 
 from __future__ import annotations
@@ -235,7 +239,57 @@ class RetentionBlock(DecoderBlock):
         return out.reshape(b, t, d), state
 
 
-class StateSpaceBlock(DecoderBlock):
+class _WindowedMixerBlock(DecoderBlock):
+    """What the blocks share whose memory is a convolution's window and
+    a recurrent state behind it (:class:`StateSpaceBlock`,
+    :class:`DeltaRuleBlock`): no heads of a cache's kind, the input
+    projection as the widest activation, and the walk of a step and of
+    a prompt — the window's input, the taps, the convolution, what the
+    recurrence takes (``mixer_selection``'s tuple, whatever the
+    format's ``step`` / ``prefill`` take), the recurrence."""
+
+    def geometry(self, d_model: int):
+        del d_model
+        return None
+
+    def widest(self, d_model: int) -> int:
+        """The input projection's columns."""
+        return max(d_model, self.mixer_width)
+
+    def _walk(self, params, x, state, shift, recur):
+        """``(y, selection, rest, state)`` of ``x`` against ``state``:
+        ``shift(u, state)`` the window's call, ``recur(*selection,
+        state)`` the state's."""
+        u, rest = self.mixer_inputs(params, x)
+        taps, state = shift(u, state)
+        sel = self.mixer_selection(params, self.mixer_conv(params, taps),
+                                   rest)
+        y, state = recur(*sel, state)
+        return y, sel, rest, state
+
+    def _step(self, params, x, state, fmt, slot, group):
+        """:meth:`_walk` of one token a sequence."""
+        return self._walk(
+            params, x, state,
+            lambda u, s: fmt.shift(u, s, group=group, valid=slot),
+            lambda *a: fmt.step(*a, group=group, valid=slot))
+
+    def _prompt(self, params, x, state, fmt, slot):
+        """:meth:`_walk` of a whole prompt ``x`` [b, t, d], and ``rows``,
+        which lays a tree's ``[b, t, ..]`` leaves as ``[b * t, ..]``."""
+        b, t = x.shape[:2]
+
+        def rows(tree):
+            return jax.tree.map(
+                lambda v: v.reshape((b * t,) + v.shape[2:]), tree)
+
+        return self._walk(
+            params, x, state,
+            lambda u, s: fmt.prefill_shift(u, s, slot),
+            lambda *a: fmt.prefill(*a, slot)) + (rows,)
+
+
+class StateSpaceBlock(_WindowedMixerBlock):
     """A decoder block whose per-sequence memory is a state-space
     mixer's (``ops/ssm.py``): the last inputs of a causal convolution
     and a recurrent state ``H``, both of fixed size, the state read
@@ -279,14 +333,6 @@ class StateSpaceBlock(DecoderBlock):
 
     memory = "ssm"
 
-    def geometry(self, d_model: int):
-        del d_model
-        return None
-
-    def widest(self, d_model: int) -> int:
-        """The input projection's columns."""
-        return max(d_model, self.mixer_width)
-
     def memory_format(self, d_model: int, positions: int, dtype, *,
                       quantized: bool = False, groups: int | None = None):
         """A state's size depends neither on the stream's width nor on
@@ -313,13 +359,8 @@ class StateSpaceBlock(DecoderBlock):
         unread (it says whether the step is real: a bubble leaves the
         window and ``H`` as they are).  The position is not read."""
         del pos
-        u, rest = self.mixer_inputs(params, x)
-        taps, state = fmt.shift(u, state, group=group, valid=slot)
-        c = self.mixer_conv(params, taps)
-        dt, xs, b, c_read, a = self.mixer_selection(params, c, rest)
-        y, state = fmt.step(dt, xs, b, c_read, a, state, group=group,
-                            valid=slot)
-        return self.decode_finish(params, x, y, xs, rest, sow=sow), state
+        y, sel, rest, state = self._step(params, x, state, fmt, slot, group)
+        return self.decode_finish(params, x, y, sel[1], rest, sow=sow), state
 
     def prefill(self, params, x, state, fmt, slot=(None, True), sow=None):
         """A whole prompt ``x`` [b, t, d] through the layer from an
@@ -327,19 +368,83 @@ class StateSpaceBlock(DecoderBlock):
         are left where ``slot`` (``fmt.prefill_slot``'s, handed on
         unread) says.  A dict ``sow`` is filled as :meth:`decode`
         fills it, over all ``b * t`` rows."""
-        b, t, d = x.shape
-        u, rest = self.mixer_inputs(params, x)
-        taps, state = fmt.prefill_shift(u, state, slot)
-        c = self.mixer_conv(params, taps)
-        dt, xs, b_in, c_read, a = self.mixer_selection(params, c, rest)
-        y, state = fmt.prefill(dt, xs, b_in, c_read, a, state, slot)
+        y, sel, rest, state, rows = self._prompt(params, x, state, fmt, slot)
+        out = self.decode_finish(params, rows(x), rows(y), rows(sel[1]),
+                                 rows(rest), sow=sow)
+        return out.reshape(x.shape), state
 
-        def rows(v):
-            return v.reshape((b * t,) + v.shape[2:])
 
-        out = self.decode_finish(params, rows(x), rows(y), rows(xs),
-                                 jax.tree.map(rows, rest), sow=sow)
-        return out.reshape(b, t, d), state
+class DeltaRuleBlock(_WindowedMixerBlock):
+    """A decoder block whose per-sequence memory is a delta rule's
+    (``ops/delta_rule.py``): the last inputs of short causal
+    convolutions over ``q``, ``k`` and ``v`` side by side, and a square
+    float32 state a head, read *and rewritten whole* every step by a
+    write that reads it — ``S <- (I - beta k k^T) Diag(alpha) S + beta
+    k v^T``, ``alpha`` a decay a key channel.  No position is read.  In
+    place of ``apply_with_kv`` / ``decode_qkv`` such a block has
+
+    * ``heads`` and ``head_dim`` (a head's key and value channels),
+      ``d_conv`` and ``chunk`` (the positions of one chunk of its
+      prefill), the memory's sizes; ``mixer_width``, the columns of the
+      convolutions' input (``3 heads head_dim``), the widest activation
+      a token has in the layer;
+    * ``mixer_inputs(params, x [..., d]) -> (u, rest)``: the
+      convolutions' input ``[q, k, v]`` [..., mixer_width] and whatever
+      else the layer makes of the normed stream (the decay's and the
+      gate's projections, ``beta``), which the block gets back
+      untouched;
+    * ``mixer_conv(params, taps) -> c``: the convolutions over their
+      ``d_conv`` taps (``ops/ssm.py::causal_conv`` under the block's
+      weights), as wide as the window;
+    * ``mixer_selection(params, c, rest) -> (q, k, v, g, beta)``: what
+      the recurrence takes — ``q`` / ``k`` / ``v`` [..., heads *
+      head_dim] final (normed, scaled), the log-decay ``g`` [..., heads
+      * head_dim] and ``beta`` [..., heads], float32;
+    * ``decode_finish(params, x [T, d], y [T, heads * head_dim], rest,
+      sow=None)``: the rest of the block after the recurrence's output
+      ``y`` (the norm a head, the gate, the output projection, the
+      second half).
+
+    ``decode_stats`` is :class:`DecoderBlock`'s.  No serving engine
+    takes such a block yet (``serve/engine.py`` refuses every block but
+    GPT's).
+    """
+
+    memory = "delta_rule"
+
+    def memory_format(self, d_model: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """A state's size depends neither on the stream's width nor on
+        ``positions``; the window is of type ``dtype``, ``S`` float32."""
+        del d_model, positions
+        if quantized:
+            raise ValueError(
+                "kv_cache='int8' quantizes cached key and value rows; "
+                "these blocks keep a delta-rule state, which has none")
+        from ..ops import delta_rule
+        return delta_rule.DeltaFormat(self.heads, self.head_dim, self.d_conv,
+                                      self.chunk, dtype, groups=groups)
+
+    def decode(self, params, x, state, pos, fmt, slot=True, group=None,
+               sow=None):
+        """One-token step: ``x`` [b, d] against one layer's ``state``;
+        ``slot`` is ``fmt.decode_slot``'s, handed on to the format
+        unread (it says whether the step is real: a bubble leaves the
+        window and ``S`` as they are).  The position is not read."""
+        del pos
+        y, _, rest, state = self._step(params, x, state, fmt, slot, group)
+        return self.decode_finish(params, x, y, rest, sow=sow), state
+
+    def prefill(self, params, x, state, fmt, slot=(None, True), sow=None):
+        """A whole prompt ``x`` [b, t, d] through the layer from an
+        empty memory; the window and the state after its last position
+        are left where ``slot`` (``fmt.prefill_slot``'s, handed on
+        unread) says.  A dict ``sow`` is filled as :meth:`decode`
+        fills it, over all ``b * t`` rows."""
+        y, _, rest, state, rows = self._prompt(params, x, state, fmt, slot)
+        out = self.decode_finish(params, rows(x), rows(y), rows(rest),
+                                 sow=sow)
+        return out.reshape(x.shape), state
 
 
 class LatentBlock(DecoderBlock):

@@ -15,7 +15,9 @@ rotations by layer kind; ``longcat_flash_tiny``, two latent caches a
 block and two turns around them a step, two blocks a stage;
 ``lfm2_moe_tiny``, layers that keep a convolution window and nothing
 else beside attention layers' caches, a dense block at the place of the
-other stage's routed one) and
+other stage's routed one; ``solar_open2_tiny``, layers whose state
+their own write reads beside attention layers' caches, a share of the
+experts held) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -45,7 +47,8 @@ import jax.numpy as jnp
 from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
                               granite_hybrid_tiny, gpt_tiny, jamba_tiny,
                               kimi_k2_tiny, lfm2_moe_tiny, longcat_flash_tiny,
-                              mellum_tiny, olmoe, olmoe_tiny)
+                              mellum_tiny, olmoe, olmoe_tiny,
+                              solar_open2_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -85,6 +88,10 @@ def ring_configurations():
     # a window-only memory (neither int8 rows nor beams) beside caches,
     # three kinds of block on one ledger: one period a stage
     yield "lfm2_moe_tiny", lfm2_moe_tiny(), (1, 2), *plain
+    # a delta-rule state and its window (neither int8 rows nor beams)
+    # beside caches, a share of every layer's experts held: one period
+    # a stage
+    yield "solar_open2_tiny", solar_open2_tiny(), (1, 2), *plain
 
 
 def ring_programs(name, graph, stages, kv_caches, beams):

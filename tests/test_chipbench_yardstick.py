@@ -169,6 +169,29 @@ test_the_lfm2_prefill_needs_against_the_issues_count = \
     _conv_moe.test_prefill_needs_against_the_issues_count
 
 
+# -- PR 65's cell: what of its own tests needs no tiny driver ------------------------
+
+import test_delta_moe_driver as _delta_moe  # noqa: E402
+
+solar_args = _delta_moe.solar_args
+test_the_solar_cell_has_its_files_and_metrics = \
+    _delta_moe.test_the_real_manifest_gives_the_cell_its_files_and_metrics
+test_the_solar_readers_on_a_trace_made_by_hand = \
+    _delta_moe.test_the_readers_on_a_trace_made_by_hand
+test_the_solar_readers_on_the_recorded_trace_find_nothing_to_read = \
+    _delta_moe.test_the_readers_on_the_recorded_trace_find_nothing_to_read
+test_the_solar_readers_return_nothing_without_their_counters = \
+    _delta_moe.test_the_readers_return_nothing_without_their_counters
+test_the_solar_models_size_against_the_issues_count = \
+    _delta_moe.test_the_models_size_against_the_issues_count
+test_the_solar_programs_tree_has_the_issues_count = \
+    _delta_moe.test_the_programs_tree_has_the_issues_count
+test_the_solar_decode_step_needs_against_the_issues_count = \
+    _delta_moe.test_decode_step_needs_against_the_issues_count
+test_the_solar_prefill_needs_against_the_issues_count = \
+    _delta_moe.test_prefill_needs_against_the_issues_count
+
+
 def test_the_long_prompt_cell_is_the_full_cells_model_on_a_long_prompt():
     """``gpt2xl_long_prompt`` (queued since PR 32 as B0.5): configuration
     ``gpt2-xl`` (48 layers), one chip, driver ``batch_decode``, 8 x (896
