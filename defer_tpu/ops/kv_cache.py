@@ -79,7 +79,13 @@ query on the vector unit, which one query a KV head does at the
 memory's pace and two or more do not, so heads of whole lane rows read
 by a group of :data:`_JOINED_GROUP` or more queries hold their buffers
 *joined* (:attr:`KVCacheFormat.joined`) and attend on the matrix unit,
-:func:`kv_attend_joined`.  A block is fetched whole, so what
+:func:`kv_attend_joined` — and so do heads of half a lane row that
+pair off (:func:`_lane_heads`: LFM2's 8 KV heads of 64, two a lane row;
+the buffers' bytes are the plain rows'): to the kernel a pair is one
+head of 128 and its two groups that head's group, each query in its own
+head's columns beside zeros.  What is left to :func:`kv_attend`'s pass
+a query is one query a head, and a group over heads that pair into no
+lane row.  A block is fetched whole, so what
 it holds past ``pos`` is read for nothing: over joined rows
 (:func:`kv_attend_joined`) a block has two extents, sequences and
 positions, and where a position's rows are thin (one KV head of 128:
@@ -135,7 +141,12 @@ _JOINED_ROWS = 16
 #: take 0.36, 21% — while the products' cost a block is the tiles of
 #: keys and values they load, whatever the rows pushed through them: the
 #: same call over joined rows takes 0.60 ms, at 4 queries as at 2 or 16
-#: (docs/DECODE_CLIFF.md, "The attention"; PERF.md §6, PR 64)
+#: (docs/DECODE_CLIFF.md, "The attention"; PERF.md §6, PR 64).  The
+#: width of a head is no part of the rule where heads pair into lane
+#: rows (:func:`_lane_heads`): LFM2's group of 4 over heads of 64 took
+#: 0.81 ms in ``kv_step`` where its rows take 0.24, and two heads a lane
+#: row as one head of 128 — eight query rows, half of each zeros — take
+#: 0.47 (PERF.md §6, PR 66)
 _JOINED_GROUP = 2
 
 
@@ -294,6 +305,18 @@ def _on_lanes(hd: int) -> bool:
     (``hd`` 64 would otherwise be padded to twice its size); from 128
     on, a position's ``kv x hd`` rows lie together."""
     return hd < _LANES
+
+
+def _lane_heads(hd: int, kv: int) -> int:
+    """KV heads that lie side by side in one lane row of a joined row —
+    1 for heads of whole lane rows, 2 for heads of half a lane row (64)
+    that pair off, an even number of them — or 0: joined, such heads
+    would be no lane-aligned slices of a row.  (Narrower heads would
+    tile a lane row too, four of 32; no family has them and no chip has
+    timed three quarters of the query rows as zeros.)"""
+    if hd % _LANES == 0:
+        return 1
+    return 2 if 2 * hd == _LANES and kv % 2 == 0 else 0
 
 
 @functools.lru_cache(maxsize=None)
@@ -587,13 +610,30 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
     such a format; no list is walked here) fetches every sequence of a
     block to the longest one's ``pos``, and masks the others.
 
+    Heads of half a lane row (``hd`` 64: :func:`_lane_heads`, two
+    side by side in one) are no lane-aligned slices, and a product over
+    64 columns of a tile costs the tile (LFM2's call by such slices:
+    0.49 ms at 740 positions, 1.27 at 2559; as below 0.47 and 1.00:
+    PERF.md §6, PR 66).  The kernel sees a lane row's heads as one head
+    of 128 and their groups as its group, each query in its own head's
+    columns beside zeros (:func:`_side_by_side`); the scale stays the
+    head's own, and of the output a head's group keeps its own columns
+    (:func:`_own_columns`).  Two small fusions around the call.
+
     The same name in a device trace as :func:`kv_attend`, and like it
     ``name`` where a format names its kernels
     (:attr:`KVCacheFormat.kernel_suffix`)."""
     b, d = q.shape
     groups, _, length, width = k_buf.shape
-    hd = width // kv
-    heads = d // hd
+    hd, g = width // kv, d // width
+    scale = 1.0 / math.sqrt(hd)
+    per = _lane_heads(hd, kv)
+    if per > 1:
+        # heads under a lane row: to the kernel the ``per`` of a lane
+        # row are one head of 128 and their queries its group
+        q = _side_by_side(q.reshape(b, kv // per, per, g, hd))
+        kv, hd = kv // per, _LANES
+    heads = kv * per * g
     sb, tl = joined_block_rows(kv, hd, length, k_buf.dtype.itemsize, b)
     blocks = b // sb
     # a sequence's rows of the softmax's state start on a sublane tile
@@ -618,8 +658,7 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
         return (group_ref[0], i, t, 0)
 
     out = pl.pallas_call(
-        functools.partial(_attend_joined_kernel, tl=tl, kv=kv,
-                          scale=1.0 / math.sqrt(hd)),
+        functools.partial(_attend_joined_kernel, tl=tl, kv=kv, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(blocks, pl.cdiv(length, tl)),
             in_specs=[pl.BlockSpec((sb, heads, hd), head_block),
@@ -635,7 +674,31 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
         interpret=jax.default_backend() != "tpu",
         name=name,
     )(group, pos, q.reshape(b, heads, hd), k_buf, v_buf)
+    if per > 1:
+        out = _own_columns(out.reshape(b, kv, per, g, per, hd // per))
     return out.reshape(b, d)
+
+
+def _side_by_side(q):
+    """The queries ``[b, lane rows, per, g, hd]`` of KV heads that share
+    a lane row of joined rows, as one head's group ``[b, lane rows, per *
+    g, per * hd]``: head ``i``'s queries in its own ``hd`` columns and
+    zeros in its neighbours', so a row's score against the lane row's
+    keys is that query's against its own head's, and of its output
+    (every head's values, weighted its way) its own columns are its
+    own: :func:`_own_columns`.  The zeros ride through the matrix unit
+    beside the queries: a block costs the tiles of keys and values it
+    loads."""
+    b, rows, per, g, hd = q.shape
+    own = jnp.eye(per, dtype=bool)[:, None, :, None]
+    return jnp.where(own, q[:, :, :, :, None, :], 0).reshape(
+        b, rows, per * g, per * hd)
+
+
+def _own_columns(out):
+    """Of ``[b, lane rows, per, g, per, hd]``, head ``i``'s group's
+    columns of head ``i``: ``[b, lane rows, per, g, hd]``."""
+    return jnp.stack([out[:, :, i, :, i] for i in range(out.shape[2])], 2)
 
 
 @functools.partial(jax.jit, static_argnames=("name",))
@@ -672,8 +735,10 @@ def kv_attend(q, k_buf, v_buf, pos, group, live=None, *,
     :data:`_JOINED_GROUP` or more over heads of whole lane rows is a
     matrix's rows, and a format for such a group holds its buffers
     joined for :func:`kv_attend_joined` (the same name in a device
-    trace): here that leaves one query a KV head, and the groups of
-    heads under a lane row.
+    trace), and so does one for a group over heads of half a lane row
+    that pair off (two of 64): here that leaves one query a KV head, and
+    the groups over heads that pair into no lane row (an odd number of
+    heads of 64; the tests' heads of 8, 16 and 32).
 
     With ``live`` (:func:`live_slots`, [b + 1] int32) the grid's first
     axis walks the list as :func:`write_kv_rows`'s does: step ``j``
@@ -956,11 +1021,12 @@ class KVCacheFormat(RingRows):
     def joined(self) -> bool:
         """Whether the buffers are ``[batch, positions, kv_heads *
         head_dim]`` (the module docstring): for float rows of whole
-        lane rows read by a group of queries (:data:`_JOINED_GROUP`:
-        two or more; one query a KV head keeps plain rows and the
-        vector unit)."""
+        lane rows — a head's own, or those that heads of half a lane
+        row pair into (:func:`_lane_heads`: two KV heads of 64) — read by
+        a group of queries (:data:`_JOINED_GROUP`: two or more; one
+        query a KV head keeps plain rows and the vector unit)."""
         return (self.query_group >= _JOINED_GROUP and not self.quantized
-                and self.head_dim % _LANES == 0)
+                and _lane_heads(self.head_dim, self.kv_heads) > 0)
 
     def __post_init__(self):
         if self.window is not None and not 0 < self.window < self.positions:
@@ -1072,7 +1138,7 @@ class KVCacheFormat(RingRows):
         buffer ``pos`` lands at ``pos % window``."""
         if self.window is not None:
             pos = self._row(pos)
-        if not self.quantized and _on_lanes(self.head_dim):
+        if not (self.quantized or self.joined) and _on_lanes(self.head_dim):
             b = rows["k"].shape[0]
             pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
             if group is not None:

@@ -5,10 +5,12 @@ Chip only.
 
     python scripts/joined_attend_bench.py [OUT.json] [case ...]
 
-A case is ``kv:queries:sequences:rows:pos[:cap]`` — KV heads of 128,
-queries a KV head, sequences, the buffers' rows (a multiple of 16) and
-the position; ``cap`` sets ``_BLOCK_POSITIONS`` for that case, in a
-tree that has it.  Without cases: Jamba's call (one KV head, 20
+A case is ``kv[xwidth]:queries:sequences:rows:pos[:cap]`` — KV heads
+(of 128, or of ``width``: ``8x64`` is LFM2's eight heads of 64, two a
+lane row, in a tree whose function takes them: PR 66), queries a KV
+head, sequences, the buffers' rows (a multiple of 16) and the position;
+``cap`` sets ``_BLOCK_POSITIONS`` for that case, in a tree that has it.
+Without cases: Jamba's call (one KV head, 20
 queries, 256 sequences, 4368 rows) along the cell's window — 384, the
 traced window's middle; 1136, the 40 s window's mean; 2016, 4351 — and
 the same at a cap of 1024; that call over buffers of 512 rows, whose
@@ -19,8 +21,11 @@ rows, 8 x 16 at 12287 of 12304; 16 sequences), which no tree may move;
 and granite's call (8 KV heads, 4 queries, 64 sequences, 3088 rows) at
 the traced window's position, the 40 s window's mean and its end —
 1151, 1839, 2623 — with a group of 2 beside it, the low edge of what
-holds joined rows (``_JOINED_GROUP``).  The function is called as it
-is, whatever ``KVCacheFormat.joined`` says of the group in that tree.
+holds joined rows (``_JOINED_GROUP``); and LFM2's call (8 KV heads of
+64, 4 queries, 128 sequences, 2560 rows) at the traced window's middle,
+the generation's mean and its end — 740, 1535, 2559.  The function is
+called as it is, whatever ``KVCacheFormat.joined`` says of the group in
+that tree.
 
 A line a case: the block's extents as the tree's ``joined_block_rows``
 gives them (``(1, positions)`` before PR 63, whose function returned
@@ -48,35 +53,37 @@ from defer_tpu.ops import kv_cache                          # noqa: E402
 
 CALLS, HD = 50, 128
 
-JAMBA, GRANITE = "1:20:256:4368:", "8:4:64:3088:"
+JAMBA, GRANITE, LFM2 = "1:20:256:4368:", "8:4:64:3088:", "8x64:4:128:2560:"
 DEFAULT = [JAMBA + "384", JAMBA + "1136", JAMBA + "2016", JAMBA + "4351",
            JAMBA + "384:1024", JAMBA + "1136:1024", JAMBA + "2016:1024",
            "1:20:256:512:384", "4:8:16:28688:28671", "8:16:16:12304:12287",
            GRANITE + "1151", GRANITE + "1839", GRANITE + "2623",
-           "8:2:64:3088:1839"]
+           "8:2:64:3088:1839", LFM2 + "740", LFM2 + "1535", LFM2 + "2559"]
 
 
-def geometry(kv: int, rows: int, b: int) -> tuple[int, int]:
+def geometry(kv: int, hd: int, rows: int, b: int) -> tuple[int, int]:
     """``(sequences, positions)`` of the tree's block."""
     fn = kv_cache.joined_block_rows
     if "b" in inspect.signature(fn).parameters:
-        return fn(kv, HD, rows, 2, b)
-    return 1, fn(kv, HD, rows, 2)
+        return fn(kv, hd, rows, 2, b)
+    return 1, fn(kv, hd, rows, 2)
 
 
 def run(case: str, peak_bytes_s: float) -> dict:
-    kv, g, b, rows, pos, *cap = map(int, case.split(":"))
+    heads, *rest = case.split(":")
+    kv, hd = map(int, heads.split("x")) if "x" in heads else (int(heads), HD)
+    g, b, rows, pos, *cap = map(int, rest)
     if cap and not hasattr(kv_cache, "_BLOCK_POSITIONS"):
         return {"case": case, "skipped": "this tree has no cap to set"}
     kept = getattr(kv_cache, "_BLOCK_POSITIONS", None)
     if cap:
         kv_cache._BLOCK_POSITIONS = cap[0]
     try:
-        sb, tl = geometry(kv, rows, b)
+        sb, tl = geometry(kv, hd, rows, b)
         keys = jax.random.split(jax.random.key(rows + pos), 3)
-        k_buf, v_buf = (jax.random.normal(key, (1, b, rows, kv * HD),
+        k_buf, v_buf = (jax.random.normal(key, (1, b, rows, kv * hd),
                                           jnp.bfloat16) for key in keys[:2])
-        q = jax.random.normal(keys[2], (b, kv * g * HD), jnp.bfloat16)
+        q = jax.random.normal(keys[2], (b, kv * g * hd), jnp.bfloat16)
         at, group = jnp.full(b, pos, jnp.int32), jnp.zeros(1, jnp.int32)
         # a fresh trace a case: the block is sized when the kernel is built
         call = jax.jit(lambda q, k, v: kv_cache.kv_attend_joined.__wrapped__(
@@ -91,10 +98,10 @@ def run(case: str, peak_bytes_s: float) -> dict:
     finally:
         if cap:
             kv_cache._BLOCK_POSITIONS = kept
-    item = {key: buf[0, :2].reshape(2, rows, kv, HD).swapaxes(1, 2)
+    item = {key: buf[0, :2].reshape(2, rows, kv, hd).swapaxes(1, 2)
             .astype(jnp.float32) for key, buf in (("k", k_buf), ("v", v_buf))}
     want = kv_cache.attend_einsum(q[:2].astype(jnp.float32), item, pos)
-    row = 2 * kv * HD * 2               # a position's keys and values
+    row = 2 * kv * hd * 2               # a position's keys and values
     live = b * (pos + 1) * row
     fetched = b * min(-(-(pos + 1) // tl) * tl, rows) * row
     return {"case": case, "block": [sb, tl], "grid": [b // sb, -(-rows // tl)],

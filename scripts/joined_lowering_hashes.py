@@ -1,5 +1,5 @@
 """sha256 of ``kv_attend_joined`` (``defer_tpu/ops/kv_cache.py``) as it
-lowers for the chip at the shapes of the four cells that call it, to
+lowers for the chip at the shapes of the five cells that call it, to
 show that a change to the kernel left a cell's call what it was — no
 chip needed, not part of the tests.  ``scripts/lowered_text_hashes.py``
 cannot say: no tiny family's heads are a lane row wide, so none holds
@@ -11,8 +11,10 @@ Run it in two trees and compare the lines: a call a line — Mellum2's
 and command-a-plus's full and window layers (4 x 8 and 8 x 16 queries on
 heads of 128, 16 sequences), Jamba's (1 x 20, 256 sequences) and, since
 PR 64, granite's (8 x 4, 64 sequences: a tree from before it lowers the
-same call, which its format did not yet make) — over
-bfloat16 buffers as the cells hold them — with the hash of the Mosaic
+same call, which its format did not yet make) and, since PR 66, LFM2's
+(8 x 4 on heads of 64, two a lane row, 128 sequences: a tree from
+before it lowers another kernel there, slices of 64 columns, which no
+format made) — over bfloat16 buffers as the cells hold them — with the hash of the Mosaic
 kernel's text and of the text around it.  The kernel's body travels as
 bytecode that carries its source lines; it is hashed as text without
 them.  With ``DIR`` both texts are written there for ``diff``.
@@ -39,7 +41,8 @@ from jax.sharding import SingleDeviceSharding
 
 from defer_tpu.ops import kv_cache
 
-#: name: (KV heads, queries a head, sequences, the buffers' rows)
+#: name: (KV heads, queries a head, sequences, the buffers' rows[, a
+#: head's width where it is not 128])
 CALLS = {
     "mellum2.full": (4, 8, 16, 28688),
     "mellum2.window": (4, 8, 16, 1040),
@@ -47,6 +50,7 @@ CALLS = {
     "commandaplus.window": (8, 16, 16, 4112),
     "jamba2": (1, 20, 256, 4368),
     "granite4h": (8, 4, 64, 3088),
+    "lfm2moe": (8, 4, 128, 2560, 64),
 }
 
 
@@ -70,14 +74,16 @@ def main(out: str | None = None) -> int:
     jax.default_backend = lambda: "tpu"
     if out:
         os.makedirs(out, exist_ok=True)
-    for name, (kv, g, b, rows) in CALLS.items():
+    for name, (kv, g, b, rows, *hd) in CALLS.items():
+        hd = hd[0] if hd else 128
+
         def arg(shape, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-        buf = arg((2, b, rows, kv * 128))
+        buf = arg((2, b, rows, kv * hd))
         lowered = jax.jit(functools.partial(
             kv_cache.kv_attend_joined.__wrapped__, kv=kv)).lower(
-                arg((b, kv * g * 128)), buf, buf, arg((b,), jnp.int32),
+                arg((b, kv * g * hd)), buf, buf, arg((b,), jnp.int32),
                 arg((1,), jnp.int32)).as_text(debug_info=False)
         kernel, around = kernel_text(lowered)
         print(name, *(hashlib.sha256(t.encode()).hexdigest()

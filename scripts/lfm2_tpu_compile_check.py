@@ -22,10 +22,16 @@ What it answers before any chip time is spent:
   weight's layout at the head of a dispatch
   (``scripts/hlo_cache_ops.py``; a decode step rewrites a layer's
   window whole, by design, and nothing else);
-* which cache and flash kernels the programs hold, by name — these are
-  the first *grouped* queries (4 a KV head) and the first rotated keys
-  on ``kv_step``'s positions-on-the-lanes path (a head of 64) — and how
-  many ``transpose`` and ``copy`` operations stand in the decode loop;
+* which cache and flash kernels the programs hold, by name, and how
+  many ``transpose`` and ``copy`` operations stand in the decode loop.
+  Since PR 66 the two attention layers' group of 4 queries over 8 KV
+  heads of 64 holds *joined* rows (two heads a lane row, ``bf16[1, 2,
+  128, 2560, 512]`` a buffer): the decode program must hold two
+  ``kv_attend`` calls and no ``kv_step``, with
+  ``decode.kv.joined_layers`` 2, ``decode.kv.fused_layers`` 0 and the
+  attention's block ``(1, 1024)`` (``decode.cache.block_sequences`` /
+  ``.block_positions``), and the cache's arguments no more bytes than
+  the rows themselves;
 * does the decode program hold two ``grouped_experts`` calls a routed
   layer and the prefill two ``grouped_rows`` calls.
 
@@ -34,12 +40,14 @@ What it answers before any chip time is spent:
 A few minutes and ~12 GB of host memory (the weights are zeros); one
 JSON line; exit 0 when both programs fit under 15.3 GB, the windows'
 arguments stay within 1.10 of the need and nothing weight-sized or
-buffer-sized is produced inside a loop.  ``LFM2_CHECK_DUMP=DIR`` writes
+buffer-sized is produced inside a loop, and the decode program's cache
+kernels and gauges are those above.  ``LFM2_CHECK_DUMP=DIR`` writes
 both compiled texts.  A process of its own, like the other compile
 checks: the TPU's library is locked machine-wide while it runs.
 """
 
 import json
+import math
 import os
 import re
 import sys
@@ -57,6 +65,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from defer_tpu.models import lfm2_moe
+from defer_tpu.obs.registry import REGISTRY
 from defer_tpu.ops.layered import shapes_by_layer
 from defer_tpu.parallel.mesh import STAGE_AXIS
 from defer_tpu.runtime.decode import PipelinedDecoder
@@ -195,6 +204,26 @@ def main() -> int:
             and all(c["buffer_copies"] <= allowed.get(key, 0)
                     and not c["item_copies"]
                     for key, c in state_ops.items())
+        if name == "decode":
+            # the two attention layers' steps: a slice of a position's
+            # rows written, one kernel over the joined rows where they
+            # lie (the gauges were set as the decode program was traced)
+            said = {key: int(REGISTRY.gauge("decode." + key).value)
+                    for key in ("kv.joined_layers", "kv.fused_layers")}
+            rows = next(f for f in dec.state_formats if hasattr(f, "joined"))
+            said.update({key: value for key, value
+                         in rows.gauges(mb, 1).items()
+                         if key.startswith("decode.cache.block_")})
+            row[name]["gauges"] = said
+            attention = len(dec.memory) - conv
+            rows_mb = 2 * attention * math.prod(buffers["k"]) * 2 / 1e6
+            ok = ok and said == {
+                "kv.joined_layers": attention, "kv.fused_layers": 0,
+                "decode.cache.block_sequences": 1,
+                "decode.cache.block_positions": 1024} \
+                and row[name]["kernels_by_name"]["kv_attend"] == attention \
+                and not row[name]["kernels_by_name"]["kv_step"] \
+                and row[name]["state_argument_mb"]["k"] <= rows_mb
     print(json.dumps(row))
     return 0 if ok else 1
 

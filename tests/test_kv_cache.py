@@ -4,6 +4,7 @@ layout: both decode engines give the same tokens over a format that
 keeps the same rows in another axis order.
 """
 
+import dataclasses
 import math
 
 import numpy as np
@@ -354,10 +355,13 @@ class _WritesItsOwnWay(KVCacheFormat):
     KVCacheFormat(2, 128, 40, jnp.bfloat16, groups=2, query_group=8),
     KVCacheFormat(2, 64, 40, jnp.float32, groups=2, window=16),
     _WritesItsOwnWay(2, 64, 40, jnp.float32, groups=2),
-], ids=["lane-rows", "int8", "joined", "ring-buffer", "subclass"])
+    KVCacheFormat(4, 64, 40, jnp.bfloat16, groups=2, query_group=4),
+], ids=["lane-rows", "int8", "joined", "ring-buffer", "subclass",
+        "joined-hd64"])
 def test_a_format_off_the_lanes_steps_through_its_two_calls(monkeypatch,
                                                             fmt):
-    """Heads of a lane row, int8 rows, joined rows, a ring buffer: a
+    """Heads of a lane row, int8 rows, joined rows (of heads of 128, or
+    of a group's heads of 64 two a lane row), a ring buffer: a
     position's rows lie together (or land in another block than the
     last live one), there is nothing to fuse, and ``step`` is the write
     and then the attention over what it wrote — the calls the blocks
@@ -883,30 +887,54 @@ def test_a_ring_buffer_holds_window_rows_and_the_scratch_row(quantized,
         fmt.write_slots({}, {}, jnp.zeros(3, jnp.int32))
 
 
-@pytest.mark.parametrize("g,hd,dtype", [
-    (1, 8, jnp.float32), (4, 8, jnp.float32), (4, 64, jnp.bfloat16),
-    (16, 128, jnp.float32), (16, 128, jnp.bfloat16), (8, 128, jnp.float32),
-    (20, 128, jnp.bfloat16), (1, 128, jnp.float32), (2, 128, jnp.float32),
-    (4, 128, jnp.float32), (4, 128, jnp.bfloat16), (5, 128, jnp.bfloat16),
-    (7, 128, jnp.float32)],
-    ids=["g1", "g4", "g4-hd64-bf16", "g16-joined", "g16-joined-bf16",
-         "g8-joined", "g20-joined-bf16", "g1-hd128", "g2-joined",
-         "g4-joined", "g4-joined-bf16", "g5-joined-bf16", "g7-joined"])
+#: (queries a KV head, a head's width, the rows' type, KV heads)
+_WINDOW_CASES = {
+    "g1": (1, 8, jnp.float32, 2), "g4": (4, 8, jnp.float32, 2),
+    "g4-hd64-bf16": (4, 64, jnp.bfloat16, 2),
+    "g16-joined": (16, 128, jnp.float32, 2),
+    "g16-joined-bf16": (16, 128, jnp.bfloat16, 2),
+    "g8-joined": (8, 128, jnp.float32, 2),
+    "g20-joined-bf16": (20, 128, jnp.bfloat16, 2),
+    "g1-hd128": (1, 128, jnp.float32, 2),
+    "g2-joined": (2, 128, jnp.float32, 2),
+    "g4-joined": (4, 128, jnp.float32, 2),
+    "g4-joined-bf16": (4, 128, jnp.bfloat16, 2),
+    "g5-joined-bf16": (5, 128, jnp.bfloat16, 2),
+    "g7-joined": (7, 128, jnp.float32, 2),
+    "g2-hd64": (2, 64, jnp.float32, 2), "g4-hd64": (4, 64, jnp.float32, 2),
+    "g1-hd64": (1, 64, jnp.float32, 2),
+    "g4-hd64-kv3": (4, 64, jnp.float32, 3),
+    "g3-hd32-kv4": (3, 32, jnp.bfloat16, 4),
+}
+
+
+@pytest.mark.parametrize("case", _WINDOW_CASES)
 @pytest.mark.parametrize("plen", [3, 6, 7, 17], ids=[
     "under", "at", "over", "wrapped-twice"])
-def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
+def test_ring_buffer_steps_are_attention_over_the_window(case, plen):
     """``write_prefix`` of a prompt under, at and over the window, then
     decode steps across the next wraps: every step's attention is the
     dense attention over the ``window`` newest positions — through the
-    vector kernel (one query a KV head, and groups over heads under a
-    lane row) and through joined rows on the matrix unit (2 to 20
-    queries a KV head of 128, a group that fills a sublane tile or not;
-    in bfloat16 the two heads' rows are thin and both sequences share a
-    block)."""
-    kv, w, total, b = 2, 6, 30, 2
+    vector kernel (one query a KV head, and a group over heads that
+    pair into no lane row: heads of 8, four of 32, three of 64) and
+    through joined rows on the matrix unit (2 to 20 queries a KV head of
+    128, a group that fills a sublane tile or not; in bfloat16 the two
+    heads' rows are thin and both sequences share a block — and the same
+    over heads of 64, two a lane row: the pair is one head of 128 to the
+    kernel, its queries side by side).  The rule is the geometry's: a
+    group, float rows, heads of whole lane rows or pairs of halves; one
+    query a head of 64 keeps plain rows and, with a row a position, the
+    write inside its attention."""
+    g, hd, dtype, kv = _WINDOW_CASES[case]
+    w, total, b = 6, 30, 2
     fmt = KVCacheFormat(kv, hd, total, dtype, groups=1, window=w,
                         query_group=g)
-    assert fmt.joined == (g >= 2 and hd == 128)
+    tiles = hd == 128 or (hd == 64 and kv % 2 == 0)
+    assert fmt.joined == (g >= 2 and tiles)
+    a_row_a_position = dataclasses.replace(fmt, window=None)
+    assert a_row_a_position.joined == fmt.joined
+    assert a_row_a_position.writes_in_attention == (
+        not fmt.joined and hd < 128)
     if fmt.joined:
         # whole sublane tiles of positions: the window, the scratch
         # row, and padding
@@ -971,7 +999,10 @@ def test_ring_buffer_steps_are_attention_over_the_window(g, hd, dtype, plen):
 #: softmax's state are a slice at ``h * g``, inside a tile or across two
 #: — on one KV head and on granite's eight (2 KB a position in bfloat16,
 #: a block of 512; 4 KB in float32, of 256): inside the first block, on
-#: a block's last row, in a later block
+#: a block's last row, in a later block.  And heads of 64 (a fourth
+#: entry: a head's width), two a lane row: LFM2's eight in groups of 2,
+#: 4 and 7 (1 KB a position in bfloat16, a block of 1024) and two, whose
+#: thin rows share a block
 _JOINED_CASES = {
     "block0": (2, 16, [5, 300]),
     "edge": (2, 16, [511, 512]),
@@ -982,6 +1013,8 @@ _JOINED_CASES = {
     "thin-two": (1, 20, [1029, 0]),
     **{f"g{g}-kv{kv}": (kv, g, [5, 511, 700])
        for g in (2, 4, 5, 7) for kv in (1, 8)},
+    **{f"g{g}-kv{kv}-hd64": (kv, g, [5, 511, 700], 64)
+       for g in (2, 4, 7) for kv in (2, 8)},
 }
 
 
@@ -990,14 +1023,15 @@ _JOINED_CASES = {
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_joined_attention_at_each_sequences_own_position(case, dtype, groups):
-    """A matrix's rows of queries a KV head over joined rows, each
-    sequence at its own position, over several position blocks and a
-    length that is no multiple of the block: the einsums over the live
-    rows.  Every row nothing may read is NaN — the tiles' padding, and
+    """A matrix's rows of queries a KV head over joined rows (the
+    heads of a lane row one head to the kernel, where they are 64
+    wide), each sequence at its own position, over several position
+    blocks and a length that is no multiple of the block: the einsums
+    over the live rows.  Every row nothing may read is NaN — the tiles' padding, and
     with groups (the ring's call: the group an index) the scratch row
     and the scratch group."""
-    kv, g, positions = _JOINED_CASES[case]
-    hd, length, b = 128, 1100, len(positions)
+    kv, g, positions, *hd = _JOINED_CASES[case]
+    hd, length, b = hd[0] if hd else 128, 1100, len(positions)
     fmt = KVCacheFormat(kv, hd, length, dtype, groups=groups, query_group=g)
     assert fmt.joined
     sequences, rows = kv_cache.joined_block_rows(
@@ -1032,9 +1066,10 @@ def test_joined_attention_at_each_sequences_own_position(case, dtype, groups):
         atol=2e-5 if dtype == jnp.float32 else 3e-2)
 
 
-#: bfloat16 formats of heads of 128 as four cells hold them — (KV
-#: heads, queries a head, positions, window, sequences a group) — with
-#: their buffers' rows and the attention's block over them
+#: bfloat16 formats as five cells hold them — (KV heads, queries a
+#: head, positions, window, sequences a group) — with their buffers'
+#: rows, the attention's block over them and a head's width where it is
+#: not 128 (LFM2's two heads a lane row: Mellum2's 1 KB a position)
 _CELL_BLOCKS = {
     "mellum2-full": ((4, 8, 28672, None, 16), 28688, (1, 1024)),
     "mellum2-window": ((4, 8, 28672, 1024, 16), 1040, (1, 1024)),
@@ -1043,6 +1078,7 @@ _CELL_BLOCKS = {
     "jamba2": ((1, 20, 4352, None, 256), 4368, (8, 512)),
     "jamba2-a-group-of-two": ((1, 20, 4352, None, 2), 4368, (2, 512)),
     "granite4h": ((8, 4, 3072, None, 64), 3088, (1, 512)),
+    "lfm2moe": ((8, 4, 2559, None, 128), 2560, (1, 1024), 64),
 }
 
 
@@ -1053,36 +1089,41 @@ def test_a_joined_block_is_sized_from_the_operands_shapes(cell):
     and command-a-plus's calls lower as they did); Jamba's 256 B a
     position stop at 512 positions and fill the block with sequences —
     and the format's gauges say which."""
-    (kv, g, positions, window, b), length, want = _CELL_BLOCKS[cell]
-    fmt = KVCacheFormat(kv, 128, positions, jnp.bfloat16, groups=1,
+    (kv, g, positions, window, b), length, want, *hd = _CELL_BLOCKS[cell]
+    hd = hd[0] if hd else 128
+    fmt = KVCacheFormat(kv, hd, positions, jnp.bfloat16, groups=1,
                         window=window, query_group=g)
-    assert fmt.joined and fmt.buffers(b)["k"].shape[-2] == length
-    assert kv_cache.joined_block_rows(kv, 128, length, 2, b) == want
+    assert fmt.joined and fmt.buffers(b)["k"].shape == (
+        2, b, length, kv * hd)
+    assert kv_cache.joined_block_rows(kv, hd, length, 2, b) == want
     said = fmt.gauges(b, 1)
     assert (said["decode.cache.block_sequences"],
             said["decode.cache.block_positions"]) == want
     assert {"decode.cache.block_sequences",
             "decode.cache.block_positions"} <= fmt.largest
-    plain = KVCacheFormat(kv, 128, positions, jnp.bfloat16, groups=1,
+    plain = KVCacheFormat(kv, hd, positions, jnp.bfloat16, groups=1,
                           window=window).gauges(b, 1)
     assert plain["decode.cache.block_sequences"] == 0
     assert plain["decode.cache.block_positions"] == 0
 
 
-def test_a_joined_prefix_of_a_piece_lands_at_its_sequences():
+@pytest.mark.parametrize("hd", [128, 64])
+def test_a_joined_prefix_of_a_piece_lands_at_its_sequences(hd):
     """``prefill_slot`` with a row: a piece of a group, written from
-    that sequence on, the rest of the group untouched."""
+    that sequence on, the rest of the group untouched — plain rows (one
+    query a head) and joined ones, of heads of 128 and of 64."""
     for g in (1, 16):
-        fmt = KVCacheFormat(2, 128, 12, jnp.float32, groups=2,
+        fmt = KVCacheFormat(2, hd, 12, jnp.float32, groups=2,
                             query_group=g)
+        assert fmt.joined == (g == 16)
         rng = np.random.default_rng(g)
-        k = jnp.asarray(rng.standard_normal((2, 5, 256)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((2, 5, 2 * hd)), jnp.float32)
         layer = fmt.layer(fmt.zeros(4, 1), 0)
         slot = fmt.prefill_slot(True, 1, 2)
         out = jax.jit(lambda layer: fmt.write_prefix(layer, k, k + 1, slot))(
             layer)
         item = fmt.head_major({key: buf[1] for key, buf in out.items()})
-        want = np.asarray(k).reshape(2, 5, 2, 128).transpose(0, 2, 1, 3)
+        want = np.asarray(k).reshape(2, 5, 2, hd).transpose(0, 2, 1, 3)
         np.testing.assert_array_equal(np.asarray(item["k"])[2:, :, :5], want)
         np.testing.assert_array_equal(np.asarray(item["v"])[2:, :, :5],
                                       want + 1)

@@ -17,6 +17,10 @@ measure, ``logit_gaps``: in float32 no generated token may sit under
 the reference's best at all.
 """
 
+import json
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -533,6 +537,62 @@ def test_the_blocks_declare_their_memory(model):
     sown = {}
     attn.apply(params["block_2"], x[None], sow=sown)
     assert int(sown["moe.assignments"]) == 4 and int(sown["conv.updates"]) == 0
+
+
+def test_the_published_geometry_attends_over_joined_rows(ids):
+    """32 query heads on 8 KV heads of 64 are four queries a KV head
+    and two KV heads a lane row: a group for the matrix unit over heads
+    that pair into lane rows (``ops/kv_cache.py::_JOINED_GROUP``,
+    ``_lane_heads``), so the cell's two attention layers hold their rows
+    joined — 2559 positions and the scratch row, 1 KB a position, the
+    bytes the plain rows took — and leave ``kv_step``'s pass a query.  A
+    ring of that kind of geometry (heads of 64 in a group of 4, a narrow
+    stream) names ``kv_attend`` once an attention layer and no
+    ``kv_step``, beside the gauge that says which of the two kernels of
+    that name it is; its tokens are the float32 reference's, prompt and
+    steps over joined rows, fused prefill and teacher-forced."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "chipbench", "configs",
+                           "lfm2-24b-a2b-10l.json")) as f:
+        args = json.load(f)["model_args"]
+    attn = lfm2_moe(**args).nodes["block_2"].op
+    assert attn.geometry(args["hidden"]) == (32, 8, 64)
+    fmt = attn.memory_format(args["hidden"], 2559, jnp.bfloat16, groups=1)
+    assert fmt == kv_cache.KVCacheFormat(8, 64, 2559, jnp.bfloat16,
+                                         groups=1, query_group=4)
+    assert fmt.joined and not fmt.writes_in_attention
+    assert fmt.buffers(128)["k"].shape == (2, 128, 2560, 512)
+    said = fmt.gauges(128, 1)
+    assert (said["decode.cache.block_sequences"],
+            said["decode.cache.block_positions"]) == (1, 1024)
+
+    graph = lfm2_moe(4, 64, 8, 2, 64, 96, SEQ, VOCAB, TYPES, 8, 2, 32)
+    params = tie_head(graph.init(jax.random.key(0)))
+    dec = PipelinedDecoder(graph, params, num_stages=1, microbatch=2,
+                           max_len=SEQ)
+    attention = dec.memory.count("kv_cache")
+    assert attention == 1
+    a, caches = dec._init_state()
+    assert caches["k"][2].shape == (1, 2, 2, 48, 128)   # stage, groups, ...
+    i32 = jnp.int32(0)
+    for name in ("decode.kv.joined_layers", "decode.kv.fused_layers"):
+        REGISTRY.gauge(name).set(-1)
+    jaxpr = jax.make_jaxpr(dec._get_decode_fn(4, False, None))(
+        dec._w, jnp.zeros((1, 2, 5), jnp.int32), i32, i32, i32,
+        jnp.uint32(0), jnp.float32(0), jnp.zeros((1, 2), jnp.int32),
+        i32, i32, a, caches)
+    # (the joined call's ``jit`` is named ``kv_attend_joined``)
+    calls = re.findall(r"\bname=(kv_attend|kv_step|kv_write_rows)\b",
+                       str(jaxpr))
+    assert calls == ["kv_attend"] * attention
+    assert REGISTRY.gauge("decode.kv.joined_layers").value == attention
+    assert REGISTRY.gauge("decode.kv.fused_layers").value == 0
+    narrow = {"module": REF_CFG["module"],
+              "args": dict(REF, n_head=8, head_dim=64)}
+    out = dec.generate(ids[:2, :PLEN], NEW, prefill=True)
+    assert logit_gaps(params, out, PLEN, narrow).max() <= 0
+    np.testing.assert_array_equal(
+        dec.generate(ids[:2, :PLEN], NEW, prefill=False), out)
 
 
 def test_the_contract_reports_kinds_and_geometries_by_layer(model):
