@@ -22,6 +22,7 @@ from .kimi_k2 import kimi_k2, kimi_k2_tiny
 from .longcat_flash import longcat_flash, longcat_flash_tiny
 from .lfm2_moe import lfm2_moe, lfm2_moe_tiny
 from .mellum import mellum, mellum_tiny
+from .nemotron_h import nemotron_h, nemotron_h_tiny
 from .solar_open2 import solar_open2, solar_open2_tiny
 from .inception import (INCEPTION_6STAGE_CUTS, inception, inception_tiny,
                         inception_v3)
@@ -47,5 +48,6 @@ __all__ = [
     "longcat_flash", "longcat_flash_tiny",
     "mellum", "mellum_tiny",
     "lfm2_moe", "lfm2_moe_tiny",
+    "nemotron_h", "nemotron_h_tiny",
     "solar_open2", "solar_open2_tiny",
 ]

@@ -9,8 +9,13 @@ it, whatever its family.
   keeps a sequence, in which geometry and how much of it, is the
   layer's own: a block whose attention has a ``window`` keeps a ring
   buffer of that many rows beside a neighbour that keeps every
-  position, and a state-space block a state of fixed size beside a
-  block that keeps a KV cache), ``final_ln``, ``lm_head``.
+  position, a state-space block a state of fixed size beside a
+  block that keeps a KV cache, and a block that is a feed-forward part
+  alone keeps **nothing**), ``final_ln``, ``lm_head``.  A block is what
+  the published model calls a layer: most families' is a mixer *and* a
+  second half behind two residuals, but a family whose layers are a
+  mixer **or** a feed-forward part alone (``models/nemotron_h.py``)
+  has a block a layer, the mixer blocks without a second half.
   :func:`decoder_parts` checks a graph against this and hands back its
   parts; both engines' constructors call it.
 * **Blocks**: :class:`DecoderBlock`, whose per-sequence memory is a KV
@@ -19,8 +24,10 @@ it, whatever its family.
   siblings: :class:`RetentionBlock` keeps a recurrent state of fixed
   size (``q, k, v`` and a log-decay to ``ops/retention.py``, the
   layer's output back); :class:`StateSpaceBlock` a convolution window
-  and a state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's), no
-  heads at all; :class:`ConvWindowBlock` such a window *alone*
+  and a state-space state (``ops/ssm.py``, Mamba-1's or Mamba-2's —
+  whose channels form heads under a decay each and, where the block
+  names ``bc_groups``, groups of heads that share a ``B`` and a ``C``),
+  no heads of a cache's kind; :class:`ConvWindowBlock` such a window *alone*
   (``ops/conv_window.py``: a gated short convolution, no position);
   :class:`DeltaRuleBlock` such a window and a square state a head whose
   write *reads it* (``ops/delta_rule.py``: ``q, k, v``, a log-decay a
@@ -28,10 +35,15 @@ it, whatever its family.
   :class:`LatentBlock` a latent cache (``ops/latent_cache.py``): one
   row a position that every head shares, which a step attends over
   with queries absorbed into the latent space and a prompt over the
-  expanded heads.  Six kinds of per-sequence memory; none of the
-  blocks knows an axis order, key or type of what its format holds;
-  which kind a block keeps is the class it is (``memory``), and the
-  holder asks every block (:meth:`DecoderBlock.memory_format`).
+  expanded heads.  Six kinds of per-sequence memory, and
+  :class:`MemorylessBlock`, which keeps **none**: ``memory`` is None
+  and its format ``ops/layered.py::NoMemory``, which has no key — that
+  is the one place "no memory" is said, and a holder reads ``memory``
+  alone (it allocates, gauges, idles and re-parents nothing for such a
+  layer).  None of the blocks knows an axis order, key or type of what
+  its format holds; which kind a block keeps is the class it is
+  (``memory``), and the holder asks every block
+  (:meth:`DecoderBlock.memory_format`).
 """
 
 from __future__ import annotations
@@ -305,8 +317,10 @@ class StateSpaceBlock(_WindowedMixerBlock):
       state's sizes; ``heads``: absent or None where every channel and
       state has a decay of its own (``ops/ssm.py::SsmFormat``), else the number
       of heads the channels form, a scalar decay each, over a window
-      of ``E + 2 N`` columns (``SsdFormat``; such a block also names
-      ``chunk``, the positions of one chunk of its prefill);
+      of ``E + 2 G N`` columns (``SsdFormat``; such a block also names
+      ``chunk``, the positions of one chunk of its prefill, and may
+      name ``bc_groups``, ``G``: the groups of consecutive heads that
+      share one ``B`` and one ``C``, 1 where absent);
       ``mixer_width``, the columns of the input projection, the widest
       activation a token has in the layer;
     * ``mixer_inputs(params, x [..., d]) -> (u, rest)``: the
@@ -319,7 +333,8 @@ class StateSpaceBlock(_WindowedMixerBlock):
     * ``mixer_selection(params, c, rest) -> (dt, xs, b, c_read, a)``:
       what the recurrence takes, in the format's shapes — the step
       (``[..., E]``, or ``[..., heads]``), the channels ``xs [..., E]``
-      it is fed, the two projections ``[..., N]``, and ``A`` (``[N,
+      it is fed, the two projections ``[..., N]`` (``[..., G N]``, a
+      group after the other, under ``bc_groups``), and ``A`` (``[N,
       E]``, or ``[heads]``);
     * ``decode_finish(params, x [T, d], y [T, E], xs, rest,
       sow=None)``: the rest of the block after the recurrence's output
@@ -349,8 +364,9 @@ class StateSpaceBlock(_WindowedMixerBlock):
         if heads is None:
             return ssm.SsmFormat(self.channels, self.states, self.d_conv,
                                  dtype, groups=groups)
-        return ssm.SsdFormat(heads, self.channels // heads, self.states, self.d_conv, self.chunk, dtype,
-                             groups=groups)
+        return ssm.SsdFormat(heads, self.channels // heads, self.states,
+                             self.d_conv, self.chunk, dtype, groups=groups,
+                             bc_groups=getattr(self, "bc_groups", 1))
 
     def decode(self, params, x, state, pos, fmt, slot=True, group=None,
                sow=None):
@@ -652,6 +668,68 @@ class ConvWindowBlock(DecoderBlock):
         return out.reshape(b, t, d), state
 
 
+class MemorylessBlock(DecoderBlock):
+    """A decoder block that keeps **nothing** a sequence: a feed-forward
+    part alone behind its one norm and residual — no mixer, no cache,
+    no state, no position (``models/nemotron_h.py``'s ``E`` layers: a
+    published layer there is a mixer *or* a feed-forward part, and a
+    block is a layer).  ``memory`` is None and the format
+    ``ops/layered.py::NoMemory``, which has no key: a holder gives such
+    a layer no buffer, and what it hands :meth:`decode` and
+    :meth:`prefill` as the layer's memory is an empty dict that comes
+    back as it went.  In place of every other half such a block has
+
+    * ``feed_forward(params, x [T, d], sow=None) -> out``: the whole
+      layer on ``T`` rows of the stream, whatever sequence or position
+      each is; it sows :attr:`decode_stats` as the other blocks do;
+    * optionally ``mixer_width``, the columns of its widest activation
+      a token (else the stream's).
+
+    Beams and int8 rows are other layers' business: this one has
+    nothing to re-parent and nothing to quantise, and refuses neither.
+    ``decode_stats`` is :class:`DecoderBlock`'s.  No serving engine
+    takes such a block yet (``serve/engine.py`` refuses every block but
+    GPT's).
+    """
+
+    memory = None
+
+    def geometry(self, d_model: int):
+        del d_model
+        return None
+
+    def widest(self, d_model: int) -> int:
+        return max(d_model, getattr(self, "mixer_width", d_model))
+
+    def memory_format(self, d_model: int, positions: int, dtype, *,
+                      quantized: bool = False, groups: int | None = None):
+        """No memory: the format that has no key."""
+        del d_model, positions, dtype, quantized
+        from ..ops import layered
+        return layered.NoMemory(groups=groups)
+
+    def apply(self, params, x, sow=None):
+        """Full-sequence forward on ``x`` [b, t, d] or [t, d]: every
+        row alike."""
+        return self.feed_forward(
+            params, x.reshape(-1, x.shape[-1]), sow=sow).reshape(x.shape)
+
+    def decode(self, params, x, state, pos, fmt, slot=None, group=None,
+               sow=None):
+        """One-token step: ``x`` [b, d]; ``state`` (an empty dict) is
+        handed back untouched, and position, slot and group are not
+        read — a bubble's rows are computed like any others and dropped
+        by the holder."""
+        del pos, fmt, slot, group
+        return self.feed_forward(params, x, sow=sow), state
+
+    def prefill(self, params, x, state, fmt, slot=None, sow=None):
+        """A whole prompt ``x`` [b, t, d] through the layer: its ``b *
+        t`` rows."""
+        del fmt, slot
+        return self.apply(params, x, sow=sow), state
+
+
 def split_blocks(num_blocks: int, num_stages: int) -> list[list[int]]:
     """Contiguous, balanced block assignment (stage i gets ~L/N blocks):
     the even rule, which counts blocks and no ends.  The cut a holder's
@@ -766,7 +844,8 @@ class DecoderParts:
     max_len: int                #: positions a cache is to hold
     stage_blocks: list          #: per stage, its blocks' names
     decode_stats: tuple         #: what every block sows each step
-    #: per block, the kind of memory it keeps (``DecoderBlock.memory``)
+    #: per block, the kind of memory it keeps (``DecoderBlock.memory``;
+    #: None: none)
     memory: tuple
     #: per block, ``(query heads, KV heads, a head's width)``, or None
     #: for a block without heads (``DecoderBlock.geometry``)

@@ -16,7 +16,10 @@ output in the operands' type.
 **Two paths, both Pallas kernels of this module, chosen by the
 product's static shape alone** (:func:`takes_kernel`).  A decode step's
 product has a handful of rows a group (2 in OLMoE's step, ~9 in
-granite-4.0-h's, ~1 in command-a-plus's, ~6 in Mellum2's): far under the
+granite-4.0-h's, ~1 in command-a-plus's, ~6 in Mellum2's, ~5.5 in
+Nemotron-3-Super's, whose rows are latent rows a quarter as wide as the
+stream and whose expert is two such products with a squared relu
+between them, ``ops/routed.py::grouped_mlp``): far under the
 ~240 rows at which a bfloat16 matrix's operations cost what its bytes do
 on a v5e (197 TFLOP/s over 819 GB/s), so it is bound by the *touched*
 matrices' bytes and takes :func:`grouped_experts`.  A prompt's product

@@ -1238,8 +1238,11 @@ class KVCacheFormat(RingRows):
 
     def reparent(self, state: dict, group, parents) -> dict:
         """Beam search: sequence ``i`` of group ``group`` takes over
-        sequence ``parents[i]``'s rows, in every layer."""
+        sequence ``parents[i]``'s rows, in every layer that keeps rows
+        (a layer without memory has None there)."""
         def one(buf):
+            if buf is None:
+                return None
             grp = jnp.take(_group_slice(buf, group), parents, axis=1)
             return lax.dynamic_update_slice(
                 buf, grp, (group,) + (0,) * (buf.ndim - 1))

@@ -4,7 +4,9 @@ of several layers a dict of tuples, one entry a layer under each key: a
 buffer, or None where that layer's format has no such key (a
 state-space layer's ``conv`` and ``h`` beside an attention layer's ``k``
 and ``v``: layers of unlike formats lie side by side, each reached
-through its own format alone).  The layers are never stacked into one
+through its own format alone; a layer that keeps nothing a sequence —
+a feed-forward part alone — has :class:`NoMemory`, no key at all, and
+its entry in every tuple is None).  The layers are never stacked into one
 array: XLA:TPU wraps a write into a value that large in copies of all
 of it (docs/DECODE_CLIFF.md).  A holder may keep entries of its own
 beside the formats' in the same dict; a format passes them through.
@@ -21,6 +23,7 @@ up what its layers' formats say (:func:`totals`) and spells no kind.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -108,6 +111,37 @@ class LayeredState:
         counts that: host integers, from shapes."""
         del rows, positions
         return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class NoMemory(LayeredState):
+    """The format of a layer that keeps **nothing** a sequence
+    (``models/decoder.py::MemorylessBlock``): no key, no buffer, no
+    byte, no gauge.  ``layer`` hands out an empty dict and
+    ``with_layer`` hands the state back as it was, so a holder walks
+    such a layer as it walks the others and allocates, aliases, idles
+    and posts nothing for it; a bubble has nothing to leave alone, so
+    both slots are None."""
+
+    #: the ring's round-robin groups, as the other formats name them;
+    #: nothing here depends on it
+    groups: int | None = None
+
+    keys = ()
+
+    def buffers(self, batch: int) -> dict:
+        del batch
+        return {}
+
+    @staticmethod
+    def decode_slot(valid, pos):
+        del valid, pos
+        return None
+
+    @staticmethod
+    def prefill_slot(valid, group, row=None):
+        del valid, group, row
+        return None
 
 
 def totals(formats, ask) -> dict[str, int]:

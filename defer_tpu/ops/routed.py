@@ -6,7 +6,14 @@ experts, :func:`expert_dispatch_held` where it holds one chip's share),
 and the weighted sum comes back in float32 with the counters of what
 was routed.  :func:`routed_experts` is that layer; every routed family
 of ``models/`` calls it with its own facts and keeps what is its own
-(which norm feeds the router, where the residual is added), and the
+(which norm feeds the router, where the residual is added).  An expert
+is one of two forms, told by the stacks it is given: SwiGLU (``gate``,
+``up``, ``down``: :func:`grouped_swiglu`) or two matrices with a named
+activation between them (``up``, ``down``: :func:`grouped_mlp`;
+Nemotron-3's squared relu); and the rows the experts multiply may be
+other than the rows the router reads (``rows``: a latent projection of
+the stream, a quarter as wide) — the weighted sum then leaves in the
+experts' width and the family projects it back up, once a token.  The
 2021 switch op (``graph/ops.py::MoE``) takes the two pieces it needs.
 The products the dispatchers end in are ``ops/grouped.py``'s kernels.
 
@@ -106,6 +113,40 @@ def grouped_swiglu(xs, experts, sizes):
     is its static shape's choice (``ops/grouped.py``)."""
     a = grouped_gate_up(xs, experts["gate"], experts["up"], sizes)
     return grouped_product(a, experts["down"], sizes)
+
+
+#: what may stand between a two-matrix expert's ``up`` and ``down``
+#: (:func:`grouped_mlp`), by the name a block gives: float32 in, float32
+#: out
+ACTIVATIONS = {"relu2": lambda a: jnp.square(jax.nn.relu(a))}
+
+
+def _activation(name: str):
+    if name not in ACTIVATIONS:
+        raise ValueError(f"activation must be one of {tuple(ACTIVATIONS)}, "
+                         f"got {name!r}")
+    return ACTIVATIONS[name]
+
+
+def grouped_mlp(xs, experts, sizes, activation: str):
+    """The routed experts without a gate, on rows sorted by expert:
+    ``act(xs up) down`` with ``up [E, k, h]``, ``down [E, h, n]`` — two
+    :func:`~defer_tpu.ops.grouped.grouped_product` calls, the activation
+    between them taken in float32 of the first product as it was
+    rounded to ``xs``'s type (an elementwise pass over ``[rows, h]``,
+    the compiler's to fuse).  ``[rows, n]`` in ``xs``'s type."""
+    a = grouped_product(xs, experts["up"], sizes)
+    a = _activation(activation)(a.astype(jnp.float32)).astype(xs.dtype)
+    return grouped_product(a, experts["down"], sizes)
+
+
+def shared_mlp(h, up, down, activation: str):
+    """A shared expert without a gate on ``h`` [T, d]: ``act(h up)
+    down`` in float32 sums, the activation on the float32 product:
+    ``[T, n]`` float32."""
+    a = jnp.dot(h, up, preferred_element_type=jnp.float32)
+    return jnp.dot(_activation(activation)(a).astype(h.dtype), down,
+                   preferred_element_type=jnp.float32)
 
 
 #: the most (row, choice) pairs one grouped product of
@@ -222,14 +263,23 @@ def route(h, router, k: int, scoring: str, scale: float = 1.0,
 def routed_experts(h, router, experts, *, k: int, scoring: str,
                    num_experts: int, held: tuple[int, int] | None = None,
                    scale: float = 1.0, eps: float = 1e-20,
-                   zero_experts: int = 0, shared=None, sow=None):
+                   zero_experts: int = 0, shared=None, sow=None,
+                   rows=None, activation: str | None = None):
     """The routed experts of one layer on the normed stream ``h`` [T, d]:
     ``(the pairs' weighted sum [T, d], the shared experts' [T, d] or
     None)``, both float32 and neither added to anything — where the
     residual is and what multiplies a branch is the family's.
 
     ``router``, ``k`` / ``scoring`` / ``scale`` / ``eps`` are :func:`route`'s;
-    ``experts`` the stacks :func:`grouped_swiglu` reads.  ``held`` is
+    ``experts`` the stacks of one of the two forms an expert has:
+    ``gate`` / ``up`` / ``down``, what :func:`grouped_swiglu` reads, or
+    ``up`` / ``down`` alone with ``activation`` naming what stands
+    between them (:func:`grouped_mlp`, :data:`ACTIVATIONS`).  ``rows``
+    [T, r], where given, are the rows the experts multiply in place of
+    ``h`` (a latent projection of it; the router and the shared experts
+    still read ``h``): the pairs' sum then comes back ``[T, n]``, as
+    wide as the experts' ``down`` leaves it, and is the caller's to
+    project.  ``held`` is
     what the layer holds of the ``num_experts`` its router chooses
     among: None — all of them, one grouped product over every pair
     (:func:`expert_dispatch`); ``(lo, hi)`` — that share, whose pairs
@@ -238,7 +288,8 @@ def routed_experts(h, router, experts, *, k: int, scoring: str,
     ``num_experts`` are zero-compute experts, whose pairs are added
     here (:func:`zero_expert_pairs`).  ``shared`` — the leaves
     ``(gate, up, down)`` of the shared experts side by side, every
-    token's — is one SwiGLU on ``h``.
+    token's — is one SwiGLU on ``h`` (a family whose shared expert has
+    no gate computes it itself, :func:`shared_mlp`, and hands none in).
 
     A dict ``sow`` takes the choice (``moe.chosen`` / ``moe.weights``
     [T, k]: no statistics) and the step's counters, the names a block
@@ -254,13 +305,18 @@ def routed_experts(h, router, experts, *, k: int, scoring: str,
                          "zero-compute experts")
     eid, gate = route(h, router, k, scoring, scale, eps)
 
-    def swiglu(xs, sizes, _es=None):
-        return grouped_swiglu(xs, experts, sizes)
-
-    if held is None:
-        out, sizes = expert_dispatch(h, eid, gate, num_experts, swiglu)
+    if "gate" in experts:
+        def expert_fn(xs, sizes, _es=None):
+            return grouped_swiglu(xs, experts, sizes)
     else:
-        out, sizes = expert_dispatch_held(h, eid, gate, held, swiglu)
+        def expert_fn(xs, sizes, _es=None):
+            return grouped_mlp(xs, experts, sizes, activation)
+
+    seen = h if rows is None else rows
+    if held is None:
+        out, sizes = expert_dispatch(seen, eid, gate, num_experts, expert_fn)
+    else:
+        out, sizes = expert_dispatch_held(seen, eid, gate, held, expert_fn)
     if zero_experts:
         zero, zeros = zero_expert_pairs(h, eid, gate, num_experts)
     if shared is not None:

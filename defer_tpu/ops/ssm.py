@@ -52,30 +52,42 @@ layers is a tuple of buffers a key, never stacked (``ops/layered.py``).
 **The second shape: a state of heads** (Mamba-2, Dao & Gu,
 arXiv:2405.21060; :class:`SsdFormat`).  The ``E`` channels are ``heads``
 heads of ``head_dim``; the decay is **one scalar a head** (``dt [heads]``
-a position, ``A [heads]``), ``B`` and ``C [N]`` are shared by every head
-(one group), and the convolution runs over the channels, ``B`` and ``C``
-together, so its window is ``E + 2 N`` wide, wider than the state:
+a position, ``A [heads]``), ``B`` and ``C [N]`` are shared by the heads
+of a **group** — ``G`` groups (``bc_groups``) of ``heads / G``
+consecutive heads, head ``h`` in group ``h // (heads / G)``; one group
+in granite-4.0-h, eight in Nemotron-3 — and the convolution runs over
+the channels, every group's ``B`` and every group's ``C`` together, so
+its window is ``E + 2 G N`` wide, wider than the state:
 
-    [x(t), B(t), C(t)] = c(t)                       (the window's output)
-    H(t)[h] = exp(dt(t)[h] A[h]) * H(t-1)[h] + dt(t)[h] * x(t)[h] (x) B(t)
-    y(t)[h] = H(t)[h] C(t)
+    [x(t), B(t)[0..G-1], C(t)[0..G-1]] = c(t)       (the window's output)
+    H(t)[h] = exp(dt(t)[h] A[h]) * H(t-1)[h]
+              + dt(t)[h] * x(t)[h] (x) B(t)[g(h)]
+    y(t)[h] = H(t)[h] C(t)[g(h)]
 
 The buffers are the first shape's — ``h [batch, N, E]`` float32, the
 states on the sublanes and every head's channels side by side on the
-lanes (128 x 8192: whole tiles; ``B`` and ``C`` being shared, a product
-over the states serves all heads at once), ``conv [d_conv - 1, batch, E
-+ 2 N]`` — and so are the window's three calls, the scratch-free
-bubble and the layered state.  What differs is the recurrence:
+lanes (128 x 8192: whole tiles; ``B`` and ``C`` being shared within a
+group, a product over the states serves a group's heads at once),
+``conv [d_conv - 1, batch, E + 2 G N]`` — and so are the window's three
+calls, the scratch-free bubble and the layered state.  ``B`` and ``C``
+pass as ``[..., G N]``, a group after the other as the window holds
+them, and **a kernel's block of channels lies inside one group** (a
+block of 1024 channels is a group's 16 heads of 64 at the published
+sizes): its ``B`` and ``C`` are that group's ``N`` columns, named by
+the block's index map — nothing is gathered or repeated, and at one
+group the calls are what they were before groups, operand for operand.
+What differs from the first shape is the recurrence:
 
 * :meth:`SsdFormat.step` — the aliased Pallas kernel :func:`ssd_step`:
   a grid over blocks of sequences *and* blocks of channels (a
   sequence's 4.2 MB does not cross VMEM whole), the decay a row a
-  sequence (one exponential a head, taken before the call), ``y`` a sum
-  over the sublanes.
+  sequence (one exponential a head, taken before the call), ``B`` and
+  ``C`` the block's group's, ``y`` a sum over the sublanes.
 * :meth:`SsdFormat.prefill` — the Pallas kernel :func:`ssd_scan`: the
   chunked matrix form.  Within a chunk of ``chunk`` positions ``Y =
   (L o C B^T) (dt x)`` with ``L[i, j] = exp(sum_{j<k<=i} dt_k A)`` a
-  head, on the matrix unit; between chunks the ``[N, E]`` state is
+  head and ``C B^T`` a group, on the matrix unit; between chunks the
+  ``[N, E]`` state is
   carried in VMEM; the ``[t, E, N]`` tensor is never made.
 * :func:`ssd_step_reference` / :func:`ssd_prefill_reference` — plain
   ``jnp``, position by position.
@@ -272,6 +284,34 @@ def ssm_scan(dt, dx, b, c, a):
 
 # -- the second shape's step kernel -------------------------------------------
 
+def _group_of_block(e: int, block: int, bc_groups: int):
+    """Which ``B`` / ``C`` group a kernel's ``j``-th block of ``block``
+    channels reads: a function of ``j`` for an index map.  With one
+    group the constant 0 (the call is then what it was before groups);
+    else ``j`` over the blocks a group's ``e / bc_groups`` channels
+    make."""
+    if bc_groups == 1:
+        return lambda j: 0
+    per = e // bc_groups // block
+    return lambda j: j // per
+
+
+def _block_in_group(e: int, bc_groups: int, sizes) -> int:
+    """The first of ``sizes`` (candidate channel blocks, widest first)
+    that divides a group's ``e / bc_groups`` channels; with one group
+    and none, all ``e``."""
+    span = e // bc_groups
+    block = next((k for k in sizes if span % k == 0), None)
+    if block is None:
+        if bc_groups > 1:
+            raise ValueError(
+                f"{bc_groups} B/C groups of {span} channels: a kernel's "
+                "block of channels lies inside one group, and no block of "
+                "whole lane tiles divides a group")
+        block = e
+    return block
+
+
 #: the most bytes of ``H`` one grid step of :func:`ssd_step` takes in
 #: (and as many out): 8 sequences x 128 states x 1024 channels
 _SSD_STEP_BYTES = 4 << 20
@@ -306,9 +346,10 @@ def ssd_step(decay, dx, b, c, state, group):
     state, for a state of heads laid ``[N, E]``.  ``state`` [groups,
     batch, N, E] f32, of which group ``group`` [1] int32; ``decay`` /
     ``dx`` [batch, E] f32 (a head's ``exp(dt A)`` on each of its
-    channels, and the step times the input); ``b`` / ``c`` [batch, N]
-    f32.  Returns ``(y [batch, E] f32, state)``; the state aliases its
-    argument: donate it.
+    channels, and the step times the input); ``b`` / ``c`` [batch, G N]
+    f32, a group after the other (``G`` from the shapes: a block of
+    channels reads its own group's ``N`` columns).  Returns ``(y [batch,
+    E] f32, state)``; the state aliases its argument: donate it.
 
     The grid runs over blocks of sequences and, inside, blocks of
     channels: at 128 states x 8192 channels a sequence is 4.2 MB, so a
@@ -317,13 +358,15 @@ def ssd_step(decay, dx, b, c, state, group):
     exponential in here: a head has one, taken before the call."""
     groups, batch, n, e = state.shape
     bs = _SEQUENCES if batch % _SEQUENCES == 0 else batch
+    bc_groups = b.shape[-1] // n
     fit = max(128, _SSD_STEP_BYTES // (4 * bs * n))
-    cb = next((k for k in range(min(e, fit), 0, -128)
-               if k % 128 == 0 and e % k == 0), e)
+    cb = _block_in_group(e, bc_groups, [
+        k for k in range(min(e, fit), 0, -128) if k % 128 == 0 and e % k == 0])
     block = next((k for k in (_SSD_LANES, 128) if cb % k == 0), cb)
     group = jnp.clip(group.astype(jnp.int32), 0, groups - 1)
+    of = _group_of_block(e, cb, bc_groups)
     rows = pl.BlockSpec((bs, cb), lambda i, j, group_ref: (i, j))
-    cols = pl.BlockSpec((bs, n, 1), lambda i, j, group_ref: (i, 0, 0))
+    cols = pl.BlockSpec((bs, n, 1), lambda i, j, group_ref: (i, of(j), 0))
     big = pl.BlockSpec((1, bs, n, cb),
                        lambda i, j, group_ref: (group_ref[0], i, 0, j))
     y, out = pl.pallas_call(
@@ -412,20 +455,22 @@ def _ssd_scan_kernel(cum_ref, row_ref, col_ref, dx_ref, b_ref, bt_ref,
         last_ref[0] = h
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan(dt, dx, b, c, a, *, chunk: int):
+@functools.partial(jax.jit, static_argnames=("chunk", "bc_groups"))
+def ssd_scan(dt, dx, b, c, a, *, chunk: int, bc_groups: int = 1):
     """The recurrence of :func:`ssd_step` over whole prompts from an
     empty memory, in the chunked matrix form: ``dt`` [batch, t, heads]
     f32, ``dx`` [batch, t, E] f32 (the step times the input, a head's
-    step on each of its channels), ``b`` / ``c`` [batch, t, N] f32,
-    ``a`` [heads] f32 -> ``(y [batch, t, E] f32, H [batch, N, E] f32
-    after the last position)``.
+    step on each of its channels), ``b`` / ``c`` [batch, t, G N] f32 (``G =
+    bc_groups``, a group after the other), ``a`` [heads] f32 -> ``(y
+    [batch, t, E] f32, H [batch, N, E] f32 after the last position)``.
 
     The grid is (sequence, block of channels, chunk), the chunks
     innermost and in order.  Inside a chunk of ``L`` positions a head's
     outputs are ``(decay o C B^T) dx`` with ``decay[i, j] = exp(sum_{j <
     k <= i} dt_k A)`` for ``j <= i`` — ``[L, L]`` products on the matrix
-    unit, ``C B^T`` shared by the heads — plus what the state carried
+    unit, ``C B^T`` shared by a group's heads (a block of channels lies
+    inside one group and reads that group's columns) — plus what the
+    state carried
     in gives, ``(C H) * decay since the chunk began``; the state moves
     on by ``B^T (decay to the chunk's end * dx)``.  Every product takes
     float32 operands whole (``Precision.HIGHEST``).  The running sums
@@ -435,7 +480,7 @@ def ssd_scan(dt, dx, b, c, a, *, chunk: int):
     tile share one: each takes the whole tile's product and keeps its
     own lanes."""
     batch, t, heads = dt.shape
-    e, n = dx.shape[-1], b.shape[-1]
+    e, n = dx.shape[-1], b.shape[-1] // bc_groups
     head_dim = e // heads
     length = min(chunk, -(-t // _TILE) * _TILE)
     pad = -t % length
@@ -446,13 +491,15 @@ def ssd_scan(dt, dx, b, c, a, *, chunk: int):
     chunks = tp // length
     cum = jnp.cumsum((dt * a).reshape(batch, chunks, length, heads), axis=2)
     rows = cum.swapaxes(2, 3)                   # [batch, chunks, heads, L]
-    eb = next((k for k in (_SSD_SCAN_CHANNELS, 512, 256, 128)
-               if e % k == 0 and k % head_dim == 0), e)
+    eb = _block_in_group(e, bc_groups, [
+        k for k in (_SSD_SCAN_CHANNELS, 512, 256, 128)
+        if e % k == 0 and k % head_dim == 0])
+    of = _group_of_block(e, eb, bc_groups)
     tile = next((k for k in (128, head_dim) if eb % k == 0
                  and k % head_dim == 0), eb)
     hb = eb // head_dim
     wide = pl.BlockSpec((1, length, eb), lambda i, j, k: (i, k, j))
-    thin = pl.BlockSpec((1, length, n), lambda i, j, k: (i, k, 0))
+    thin = pl.BlockSpec((1, length, n), lambda i, j, k: (i, k, of(j)))
     y, last = pl.pallas_call(
         functools.partial(_ssd_scan_kernel, head_dim=head_dim, tile=tile),
         grid=(batch, e // eb, chunks),
@@ -462,7 +509,8 @@ def ssd_scan(dt, dx, b, c, a, *, chunk: int):
             pl.BlockSpec((1, 1, hb, length, 1),
                          lambda i, j, k: (i, k, j, 0, 0)),
             wide, thin,
-            pl.BlockSpec((1, 1, n, length), lambda i, j, k: (i, k, 0, 0)),
+            pl.BlockSpec((1, 1, n, length),
+                         lambda i, j, k: (i, k, of(j), 0)),
             thin],
         out_specs=[wide, pl.BlockSpec((1, n, eb), lambda i, j, k: (i, 0, j))],
         out_shape=[jax.ShapeDtypeStruct((batch, tp, e), jnp.float32),
@@ -480,7 +528,7 @@ def ssd_scan(dt, dx, b, c, a, *, chunk: int):
         interpret=jax.default_backend() != "tpu",
         name="ssd_scan",
     )(cum.reshape(batch, tp, heads), rows, rows[..., None], dx, b,
-      b.reshape(batch, chunks, length, n).swapaxes(2, 3), c)
+      b.reshape(batch, chunks, length, bc_groups * n).swapaxes(2, 3), c)
     return (y[:, :t] if pad else y), last
 
 
@@ -588,8 +636,8 @@ class SsmFormat(_WindowedState):
 class SsdFormat(_WindowedState):
     """One layer's Mamba-2 memory, described: a state of ``heads`` heads
     of ``head_dim`` channels under one decay a head, ``B`` and ``C``
-    shared by all heads and convolved with the channels (the module
-    docstring's second shape)."""
+    shared by the heads of each of ``bc_groups`` groups and convolved
+    with the channels (the module docstring's second shape)."""
 
     heads: int
     head_dim: int
@@ -601,6 +649,15 @@ class SsdFormat(_WindowedState):
     dtype: Any
     #: the ring's round-robin groups (a leading axis); None for one batch
     groups: int | None = None
+    #: ``G``: groups of consecutive heads that share a ``B`` and a ``C``
+    bc_groups: int = 1
+
+    largest = frozenset({"decode.ssm.bc_groups"})
+
+    def __post_init__(self):
+        if self.heads % self.bc_groups:
+            raise ValueError(f"{self.heads} heads do not form "
+                             f"{self.bc_groups} B/C groups")
 
     @property
     def channels(self) -> int:
@@ -608,18 +665,24 @@ class SsdFormat(_WindowedState):
 
     @property
     def conv_width(self) -> int:
-        """The channels, ``B`` and ``C``."""
-        return self.channels + 2 * self.states
+        """The channels, every group's ``B`` and every group's ``C``."""
+        return self.channels + 2 * self.bc_groups * self.states
+
+    def gauges(self, batch: int, stages: int) -> dict[str, int]:
+        """The window's bytes, and the ``B`` / ``C`` groups (the most
+        any layer has)."""
+        return dict(super().gauges(batch, stages),
+                    **{"decode.ssm.bc_groups": self.bc_groups})
 
     def step(self, dt, x, b, c, a, layer: dict, group=None, valid=True):
         """One token of every sequence (of group ``group``): ``dt`` [b,
         heads] the step (float32), ``x`` [b, E] the channels of the
-        convolution's output, ``b`` / ``c`` [b, N], ``a`` [heads].
-        Every head's state is decayed by its ``exp(dt a)``, ``(dt x)
-        (x) b`` is added and ``c`` reads the *new* state: returns ``(y
-        [b, E] float32, the layer)``.  With ``valid`` false (a
-        pipeline's bubble) the update is the identity (``dt = 0``) and
-        ``y`` means nothing."""
+        convolution's output, ``b`` / ``c`` [b, G N] (a group after the
+        other), ``a`` [heads].  Every head's state is decayed by its
+        ``exp(dt a)``, ``(dt x) (x) b`` is added and ``c`` reads the
+        *new* state: returns ``(y [b, E] float32, the layer)``.  With
+        ``valid`` false (a pipeline's bubble) the update is the identity
+        (``dt = 0``) and ``y`` means nothing."""
         f32 = jnp.float32
         dt = jnp.where(valid, dt.astype(f32), 0.0)
         bufs, group = self._group(layer, group)
@@ -632,7 +695,7 @@ class SsdFormat(_WindowedState):
     def prefill(self, dt, x, b, c, a, layer: dict, slot=(None, True)):
         """A whole prompt of every sequence (of the group ``slot``
         names) into an *empty* memory: ``dt`` [b, t, heads], ``x`` [b,
-        t, E], ``b`` / ``c`` [b, t, N], ``a`` [heads] -> ``(y [b, t, E]
+        t, E], ``b`` / ``c`` [b, t, G N], ``a`` [heads] -> ``(y [b, t, E]
         float32, the layer)``, the layer holding the state after the
         last position.  Where ``slot`` says the call is a bubble, the
         state is kept."""
@@ -640,7 +703,8 @@ class SsdFormat(_WindowedState):
         dt = dt.astype(f32)
         y, last = ssd_scan(
             dt, jnp.repeat(dt, self.head_dim, axis=-1) * x.astype(f32),
-            b.astype(f32), c.astype(f32), a.astype(f32), chunk=self.chunk)
+            b.astype(f32), c.astype(f32), a.astype(f32), chunk=self.chunk,
+            bc_groups=self.bc_groups)
         return y, self._leave(last, layer, slot)
 
 
@@ -683,24 +747,33 @@ def prefill_reference(dt, x, b, c, a):
 def ssd_step_reference(dt, x, b, c, a, h):
     """:func:`ssd_step` (behind :meth:`SsdFormat.step`'s spreading of a
     head's step) in plain ``jnp`` over one item: ``dt`` [batch, heads],
-    ``x`` [batch, E], ``b`` / ``c`` [batch, N], ``a`` [heads], ``h``
-    [batch, N, E], all float32: ``(y [batch, E], h)``."""
-    p = x.shape[-1] // dt.shape[-1]
+    ``x`` [batch, E], ``b`` / ``c`` [batch, G N] (a group after the
+    other; ``G`` from ``h``'s ``N``), ``a`` [heads], ``h`` [batch, N,
+    E], all float32: ``(y [batch, E], h)``."""
+    e, n = x.shape[-1], h.shape[-2]
+    p = e // dt.shape[-1]
+
+    def spread(v):
+        """A group's ``[N]`` on each of its channels: ``[batch, N, E]``."""
+        g = v.shape[-1] // n
+        return jnp.repeat(v.reshape(-1, g, n), e // g, axis=1).swapaxes(1, 2)
+
     h = jnp.repeat(jnp.exp(dt * a), p, axis=-1)[:, None, :] * h \
-        + (jnp.repeat(dt, p, axis=-1) * x)[:, None, :] * b[:, :, None]
-    return jnp.sum(h * c[:, :, None], axis=1), h
+        + (jnp.repeat(dt, p, axis=-1) * x)[:, None, :] * spread(b)
+    return jnp.sum(h * spread(c), axis=1), h
 
 
-def ssd_prefill_reference(dt, x, b, c, a):
+def ssd_prefill_reference(dt, x, b, c, a, *, bc_groups: int = 1):
     """The second shape's recurrence position by position from an empty
     memory: ``dt`` [batch, t, heads], ``x`` [batch, t, E], ``b`` / ``c``
-    [batch, t, N], ``a`` [heads], all float32 -> ``(y [batch, t, E], h
-    [batch, N, E])``."""
+    [batch, t, G N] (``G = bc_groups``), ``a`` [heads], all float32 ->
+    ``(y [batch, t, E], h [batch, N, E])``."""
     def step(h, xs):
         y, h = ssd_step_reference(*xs, a, h)
         return h, y
 
-    start = jnp.zeros((dt.shape[0], b.shape[-1], x.shape[-1]), jnp.float32)
+    start = jnp.zeros((dt.shape[0], b.shape[-1] // bc_groups, x.shape[-1]),
+                      jnp.float32)
     h, ys = lax.scan(step, start, tuple(
         jnp.swapaxes(v, 0, 1) for v in (dt, x, b, c)))
     return jnp.swapaxes(ys, 0, 1), h

@@ -314,13 +314,21 @@ class PipelinedDecoder:
         #: the first local layer's (every layer's, where they are alike)
         self.state_format = self.state_formats[0]
         #: the kind of memory each local layer keeps
-        #: (``DecoderBlock.memory``)
+        #: (``DecoderBlock.memory``; None where it keeps none: such a
+        #: layer has no buffer, no gauge, no bubble and no parent here)
         self.memory = tuple(nodes[nm].op.memory
                             for nm in max(self.stage_blocks, key=len))
-        kinds = dict.fromkeys(self.memory)
+        kinds = dict.fromkeys(k for k in self.memory if k is not None)
+        REGISTRY.gauge("decode.memoryless_layers").set(sum(
+            nodes[nm].op.memory is None for nm in self.block_names))
         states = [(kind, fmt) for kind, fmt in zip(self.memory,
                                                     self.state_formats)
-                  if kind != "kv_cache"]
+                  if kind not in ("kv_cache", None)]
+        #: the format beams re-parent rows through: the first KV layer's
+        #: (it walks every layer's rows and passes by a layer without)
+        self._rows_format = next(
+            (fmt for kind, fmt in zip(self.memory, self.state_formats)
+             if kind == "kv_cache"), None)
         if beam_width > 1 and states:
             kind, fmt = states[0]
             raise ValueError(
@@ -588,9 +596,11 @@ class PipelinedDecoder:
                     jnp.round(a[:, self.d_model]).astype(jnp.int32),
                     0, mb - 1)
                 applies = jnp.logical_and(valid, safe_pos >= plen)
-                caches = lax.cond(
-                    applies, lambda cs: fmts[0].reparent(cs, g, parents),
-                    lambda cs: cs, caches)
+                if self._rows_format is not None:
+                    caches = lax.cond(
+                        applies,
+                        lambda cs: self._rows_format.reparent(cs, g, parents),
+                        lambda cs: cs, caches)
 
             if is_first:
                 recv_ids = jnp.round(a[:, 0]).astype(jnp.int32)

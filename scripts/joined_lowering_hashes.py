@@ -1,5 +1,5 @@
 """sha256 of ``kv_attend_joined`` (``defer_tpu/ops/kv_cache.py``) as it
-lowers for the chip at the shapes of the five cells that call it, to
+lowers for the chip at the shapes of the six cells that call it, to
 show that a change to the kernel left a cell's call what it was — no
 chip needed, not part of the tests.  ``scripts/lowered_text_hashes.py``
 cannot say: no tiny family's heads are a lane row wide, so none holds
@@ -14,7 +14,9 @@ PR 64, granite's (8 x 4, 64 sequences: a tree from before it lowers the
 same call, which its format did not yet make) and, since PR 66, LFM2's
 (8 x 4 on heads of 64, two a lane row, 128 sequences: a tree from
 before it lowers another kernel there, slices of 64 columns, which no
-format made) — over bfloat16 buffers as the cells hold them — with the hash of the Mosaic
+format made) and, since PR 67, Nemotron-3-Super's (2 x 16 on heads of
+128: rows of 512 B a position, 128 sequences; the kernel is older
+trees' too, no format of theirs made the call) — over bfloat16 buffers as the cells hold them — with the hash of the Mosaic
 kernel's text and of the text around it.  The kernel's body travels as
 bytecode that carries its source lines; it is hashed as text without
 them.  With ``DIR`` both texts are written there for ``diff``.
@@ -51,6 +53,7 @@ CALLS = {
     "jamba2": (1, 20, 256, 4368),
     "granite4h": (8, 4, 64, 3088),
     "lfm2moe": (8, 4, 128, 2560, 64),
+    "nemotron3super": (2, 16, 128, 3600),
 }
 
 

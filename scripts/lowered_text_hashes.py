@@ -17,7 +17,10 @@ block and two turns around them a step, two blocks a stage;
 else beside attention layers' caches, a dense block at the place of the
 other stage's routed one; ``solar_open2_tiny``, layers whose state
 their own write reads beside attention layers' caches, a share of the
-experts held) and
+experts held; ``nemotron_h_tiny``, layers that are a mixer or a
+feed-forward part alone — one kind in three keeping no memory at all —,
+a state of heads in two B/C groups, two-matrix relu² experts on latent
+rows) and
 the engine's step (greedy and sampling) and prefill, lowered on the CPU
 mesh at toy sizes.  Run it in two trees and compare the lines:
 
@@ -47,8 +50,8 @@ import jax.numpy as jnp
 from defer_tpu.models import (brumby_tiny, cohere_moe_tiny,
                               granite_hybrid_tiny, gpt_tiny, jamba_tiny,
                               kimi_k2_tiny, lfm2_moe_tiny, longcat_flash_tiny,
-                              mellum_tiny, olmoe, olmoe_tiny,
-                              solar_open2_tiny)
+                              mellum_tiny, nemotron_h_tiny, olmoe,
+                              olmoe_tiny, solar_open2_tiny)
 from defer_tpu.runtime.decode import PipelinedDecoder
 from defer_tpu.serve.engine import ContinuousBatchEngine
 
@@ -92,6 +95,11 @@ def ring_configurations():
     # beside caches, a share of every layer's experts held: one period
     # a stage
     yield "solar_open2_tiny", solar_open2_tiny(), (1, 2), *plain
+    # a block a published layer, a mixer or a feed-forward part: the
+    # feed-forward blocks keep no memory and the ring holds no buffer
+    # for them (a state beside them: neither int8 rows nor beams); one
+    # period a stage
+    yield "nemotron_h_tiny", nemotron_h_tiny(), (1, 2), *plain
 
 
 def ring_programs(name, graph, stages, kv_caches, beams):

@@ -192,6 +192,29 @@ test_the_solar_prefill_needs_against_the_issues_count = \
     _delta_moe.test_prefill_needs_against_the_issues_count
 
 
+# PR 67's cell (Nemotron-3-Super): the tests of its driver's file that
+# need no tiny driver run — the manifest's entries found by name, the
+# readers on traces made by hand, the roofline's counts against the
+# issue's
+import test_ssd_latent_moe_driver as _ssd_latent_moe  # noqa: E402
+
+nemotron_args = _ssd_latent_moe.nemotron_args
+test_the_nemotron_cell_has_its_files_and_metrics = \
+    _ssd_latent_moe.test_the_real_manifest_gives_the_cell_its_files_and_metrics
+test_the_nemotron_readers_on_a_trace_made_by_hand = \
+    _ssd_latent_moe.test_the_readers_on_a_trace_made_by_hand
+test_the_nemotron_readers_return_nothing_without_their_counters = \
+    _ssd_latent_moe.test_the_readers_return_nothing_without_their_counters
+test_the_nemotron_models_size_against_the_issues_count = \
+    _ssd_latent_moe.test_the_models_size_against_the_issues_count
+test_the_nemotron_programs_tree_has_the_issues_count = \
+    _ssd_latent_moe.test_the_programs_tree_has_the_issues_count
+test_the_nemotron_decode_step_needs_against_the_issues_count = \
+    _ssd_latent_moe.test_decode_step_needs_against_the_issues_count
+test_the_nemotron_prefill_needs_against_the_issues_count = \
+    _ssd_latent_moe.test_prefill_needs_against_the_issues_count
+
+
 def test_the_long_prompt_cell_is_the_full_cells_model_on_a_long_prompt():
     """``gpt2xl_long_prompt`` (queued since PR 32 as B0.5): configuration
     ``gpt2-xl`` (48 layers), one chip, driver ``batch_decode``, 8 x (896

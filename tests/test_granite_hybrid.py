@@ -120,42 +120,68 @@ def test_the_references_recurrence_is_its_explicit_sum(state_dtype, least,
 
 # -- the state's second shape: kernels against the plain oracle ------------------
 
-def _inputs(seed, b, t, nh, p, n):
+def _inputs(seed, b, t, nh, p, n, g=1):
+    """``g`` B/C groups: ``B`` and ``C`` ``[b, t, g * n]``, a group
+    after the other."""
     rng = np.random.default_rng(seed)
     dt = jax.nn.softplus(jnp.asarray(rng.normal(size=(b, t, nh)) - 2.0,
                                      jnp.float32))
     x, bm, cm = (jnp.asarray(rng.normal(size=s), jnp.float32)
-                 for s in ((b, t, nh * p), (b, t, n), (b, t, n)))
+                 for s in ((b, t, nh * p), (b, t, g * n), (b, t, g * n)))
     a = -jnp.asarray(rng.uniform(1.0, 16.0, (nh,)), jnp.float32)
     return dt, x, bm, cm, a
 
 
-@pytest.mark.parametrize("t, nh, p, n", [
-    (3, 8, 16, 16), (19, 8, 16, 16), (40, 8, 16, 16), (24, 2, 128, 8),
-    (20, 4, 64, 128)], ids=["shorter-than-d_conv", "ends-inside-a-chunk",
-                            "five-chunks", "wide-heads", "cells-head"])
-def test_ssd_scan_is_the_recurrence_position_by_position(t, nh, p, n):
+@pytest.mark.parametrize("t, nh, p, n, g", [
+    (3, 8, 16, 16, 1), (19, 8, 16, 16, 1), (40, 8, 16, 16, 1),
+    (24, 2, 128, 8, 1), (20, 4, 64, 128, 1), (19, 8, 32, 16, 2),
+    (16, 8, 32, 16, 2), (40, 16, 32, 16, 4), (12, 128, 64, 128, 8)],
+    ids=["shorter-than-d_conv", "ends-inside-a-chunk", "five-chunks",
+         "wide-heads", "cells-head", "two-groups-ends-inside-a-chunk",
+         "two-groups-ends-at-a-chunk", "four-groups-five-chunks",
+         "eight-groups-published-shape"])
+def test_ssd_scan_is_the_recurrence_position_by_position(t, nh, p, n, g):
     """The prefill kernel (interpret mode, chunks of 8) against the
     plain oracle: a prompt shorter than the convolution, one that is no
     multiple of the chunk, one whose state crosses four chunk
     boundaries in VMEM; heads of a lane tile and more, and heads of 64
-    x 128 states as the cell has them."""
-    dt, x, bm, cm, a = _inputs(t, 2, t, nh, p, n)
-    y, h = ssm.ssd_scan(dt, jnp.repeat(dt, p, -1) * x, bm, cm, a, chunk=8)
-    want_y, want_h = ssm.ssd_prefill_reference(dt, x, bm, cm, a)
-    assert y.shape == (2, t, nh * p) and h.shape == (2, n, nh * p)
+    x 128 states as the cell has them; with B/C groups — a block of
+    channels reading its own group's ``B`` and ``C`` — a prompt that
+    ends inside a chunk and one that ends at a chunk's end, and
+    Nemotron-3-Super's published shape: 128 heads of 64 in 8 groups of
+    1024 channels, 128 states."""
+    b = 1 if g == 8 else 2
+    dt, x, bm, cm, a = _inputs(t, b, t, nh, p, n, g)
+    y, h = ssm.ssd_scan(dt, jnp.repeat(dt, p, -1) * x, bm, cm, a, chunk=8,
+                        bc_groups=g)
+    want_y, want_h = ssm.ssd_prefill_reference(dt, x, bm, cm, a,
+                                               bc_groups=g)
+    assert y.shape == (b, t, nh * p) and h.shape == (b, n, nh * p)
     assert rel_err(y, want_y) < 1e-5 and rel_err(h, want_h) < 1e-5
+    if g > 1:
+        # every head reading the first group's B and C is another
+        # recurrence: the comparison sees the groups
+        other, _ = ssm.ssd_prefill_reference(dt, x, bm[..., :n],
+                                             cm[..., :n], a)
+        assert rel_err(y, other) > 0.1
 
 
-@pytest.mark.parametrize("batch", [2, 8])
-def test_ssd_step_updates_its_group_in_place(batch):
-    """The decode kernel against the plain oracle, on group 1 of 2: the
-    other group's state is not touched."""
-    nh, p, n = 8, 16, 16
-    dt, x, bm, cm, a = _inputs(batch, batch, 1, nh, p, n)
+@pytest.mark.parametrize("batch, nh, p, n, g", [
+    (2, 8, 16, 16, 1), (8, 8, 16, 16, 1), (2, 8, 32, 16, 2),
+    (8, 16, 32, 16, 4), (8, 128, 64, 128, 8)],
+    ids=["2", "8", "two-groups", "four-groups",
+         "eight-groups-published-shape"])
+def test_ssd_step_updates_its_group_in_place(batch, nh, p, n, g):
+    """The decode kernel against the plain oracle, on (ring) group 1 of
+    2: the other group's state is not touched.  With B/C groups a block
+    of channels reads its own group's ``B`` and ``C``: two and four
+    groups of 128 channels, and the published 8 groups of 1024 (a block
+    of 1024 channels a group, 8 sequences a grid step)."""
+    dt, x, bm, cm, a = _inputs(batch, batch, 1, nh, p, n, g)
     rng = np.random.default_rng(1)
     state = jnp.asarray(rng.normal(size=(2, batch, n, nh * p)), jnp.float32)
-    fmt = ssm.SsdFormat(nh, p, n, 4, 8, jnp.float32, groups=2)
+    fmt = ssm.SsdFormat(nh, p, n, 4, 8, jnp.float32, groups=2, bc_groups=g)
+    assert fmt.conv_width == nh * p + 2 * g * n
     layer = {"h": state, "conv": jnp.zeros((2, 3, batch, fmt.conv_width))}
     y, after = fmt.step(dt[:, 0], x[:, 0], bm[:, 0], cm[:, 0], a, layer,
                         group=1)
@@ -166,16 +192,20 @@ def test_ssd_step_updates_its_group_in_place(batch):
     np.testing.assert_array_equal(after["h"][0], state[0])
 
 
-@pytest.mark.parametrize("plen", [2, 11])
-def test_the_format_prefills_then_steps_like_one_long_prefill(plen):
+@pytest.mark.parametrize("plen, p, g", [
+    (2, 16, 1), (11, 16, 1), (2, 32, 2), (11, 32, 2), (8, 32, 2)],
+    ids=["2", "11", "two-groups-2", "two-groups-11",
+         "two-groups-ends-at-a-chunk"])
+def test_the_format_prefills_then_steps_like_one_long_prefill(plen, p, g):
     """A prompt through ``prefill_shift`` / ``prefill`` and the rest a
     token at a time through ``shift`` / ``step``: the taps and outputs
     of one prefill over everything (a prompt shorter than ``d_conv``,
-    and one that crosses a chunk boundary)."""
-    nh, p, n, t = 8, 16, 16, 16
-    dt, x, bm, cm, a = _inputs(7, 2, t, nh, p, n)
+    and one that crosses a chunk boundary); with two B/C groups too,
+    and there a prompt that ends where a chunk ends."""
+    nh, n, t = 8, 16, 16
+    dt, x, bm, cm, a = _inputs(7, 2, t, nh, p, n, g)
     rng = np.random.default_rng(2)
-    fmt = ssm.SsdFormat(nh, p, n, 4, 8, jnp.float32)
+    fmt = ssm.SsdFormat(nh, p, n, 4, 8, jnp.float32, bc_groups=g)
     u = jnp.asarray(rng.normal(size=(2, t, fmt.conv_width)), jnp.float32)
     empty = fmt.layer(fmt.zeros(2, 1), 0)
     all_taps, _ = fmt.prefill_shift(u, empty)
@@ -193,10 +223,12 @@ def test_the_format_prefills_then_steps_like_one_long_prefill(plen):
         assert rel_err(y, all_y[:, pos]) < 1e-5
 
 
-def test_a_bubble_leaves_the_window_and_the_state_bit_for_bit():
-    nh, p, n = 8, 16, 16
-    dt, x, bm, cm, a = _inputs(3, 2, 4, nh, p, n)
-    fmt = ssm.SsdFormat(nh, p, n, 4, 8, jnp.float32, groups=1)
+@pytest.mark.parametrize("p, g", [(16, 1), (32, 2)],
+                         ids=["one-group", "two-groups"])
+def test_a_bubble_leaves_the_window_and_the_state_bit_for_bit(p, g):
+    nh, n = 8, 16
+    dt, x, bm, cm, a = _inputs(3, 2, 4, nh, p, n, g)
+    fmt = ssm.SsdFormat(nh, p, n, 4, 8, jnp.float32, groups=1, bc_groups=g)
     u = jnp.concatenate([x, bm, cm], axis=-1)
     layer = fmt.layer(fmt.zeros(2, 1), 0)
     _, layer = fmt.prefill_shift(u, layer, fmt.prefill_slot(True, 0))
@@ -230,6 +262,32 @@ def test_the_two_shapes_share_their_buffers_and_their_window():
     h, window = ssm.dense(np.zeros((4, 16, 128)), np.zeros((3, 4, 160)),
                           heads=8)
     assert h.shape == (4, 8, 16, 16) and window.shape == (4, 3, 160)
+
+
+def test_one_group_is_the_format_before_groups():
+    """``bc_groups`` 1 is the default and names the format every older
+    family has: the same buffers, the same window, a kernel's block
+    reading columns 0 by a constant (so the calls lower to what they
+    were); more groups widen the window alone, and a group that no lane
+    tile of channels divides is refused by message."""
+    one = ssm.SsdFormat(8, 16, 16, 4, 8, jnp.bfloat16, groups=2)
+    assert one == ssm.SsdFormat(8, 16, 16, 4, 8, jnp.bfloat16, groups=2,
+                                bc_groups=1)
+    assert one.gauges(4, 2)["decode.ssm.bc_groups"] == 1
+    two = ssm.SsdFormat(8, 32, 16, 4, 8, jnp.bfloat16, groups=2, bc_groups=2)
+    assert two.buffers(4)["h"].shape == (2, 4, 16, 256)
+    assert two.buffers(4)["conv"].shape == (2, 3, 4, 256 + 2 * 2 * 16)
+    assert two.gauges(4, 2)["decode.ssm.bc_groups"] == 2
+    assert ssm._group_of_block(8192, 1024, 1)(5) == 0
+    assert [ssm._group_of_block(8192, 512, 8)(j) for j in (0, 1, 2, 15)] \
+        == [0, 0, 1, 7]
+    assert ssm._block_in_group(8192, 8, [1024, 512]) == 1024
+    assert ssm._block_in_group(8192, 16, [1024, 512]) == 512
+    assert ssm._block_in_group(100, 1, []) == 100
+    with pytest.raises(ValueError, match="lies inside one group"):
+        ssm._block_in_group(128, 2, [128])
+    with pytest.raises(ValueError, match="do not form"):
+        ssm.SsdFormat(8, 16, 16, 4, 8, jnp.bfloat16, bc_groups=3)
 
 
 # -- the ring against the reference -----------------------------------------------

@@ -145,6 +145,10 @@ def test_the_tiled_kernel_lists_a_row_tile_once_a_group_in_it():
     (128, 64, 2304, 896, "kernel"),         # Mellum2's step
     (128, 64, 896, 2304, "kernel"),
     (256, 12, 7168, 2048, "kernel"),        # Kimi's step
+    (2816, 128, 1024, 2688, "kernel"),      # Nemotron-3-Super's step: the
+    (2816, 128, 2688, 1024, "kernel"),      # latent rows up, and down
+    (4096, 128, 1024, 2688, "tiled"),       # a run of its held prefill
+    (4096, 128, 2688, 1024, "tiled"),
     (4096, 36, 4096, 768, "tiled"),         # a run of a held prefill
     (4096, 16, 4096, 4096, "tiled"),
     (4096, 12, 7168, 2048, "tiled"),
@@ -159,6 +163,42 @@ def test_the_shape_rule_at_the_cells_shapes(rows, groups, k, n, path):
     after = [REGISTRY.counter(name).value for name in names]
     assert [a - b for a, b in zip(after, before)] == [
         int(path == "kernel"), int(path == "tiled")]
+
+
+@pytest.mark.parametrize("pairs_run", [4096, 64])
+def test_the_held_dispatch_of_two_matrix_experts_on_latent_rows(
+        monkeypatch, pairs_run):
+    """``expert_dispatch_held`` with the two-matrix relu² expert under
+    it (``grouped_mlp``: two grouped products, the squared relu between
+    them) on rows a quarter as wide as the stream — 22 choices of 512
+    experts a token, experts 128-255 held, in one run and in runs of 64
+    pairs — against the pairs computed one by one; the sum comes back
+    in the latent width."""
+    monkeypatch.setattr(routed, "_HELD_RUN", pairs_run)
+    rng = np.random.default_rng(3)
+    f32 = jnp.float32
+    t, k, r, h = 6, 22, 16, 24
+    u = jnp.asarray(rng.normal(size=(t, r)), f32)
+    eid = jnp.asarray(np.stack([rng.permutation(512)[:k] for _ in range(t)]))
+    gate = jnp.asarray(rng.uniform(size=(t, k)), f32)
+    ex = {"up": jnp.asarray(rng.normal(size=(128, r, h)), f32) / 4,
+          "down": jnp.asarray(rng.normal(size=(128, h, r)), f32) / 5}
+    got, sizes = jax.jit(lambda *a: expert_dispatch_held(
+        *a, (128, 256), lambda xs, s: routed.grouped_mlp(
+            xs, ex, s, "relu2")))(u, eid, gate)
+    want = np.zeros((t, r), np.float32)
+    held = 0
+    for i in range(t):
+        for j in range(k):
+            e = int(eid[i, j]) - 128
+            if 0 <= e < 128:
+                held += 1
+                a = jnp.square(jax.nn.relu(u[i] @ ex["up"][e]))
+                want[i] += float(gate[i, j]) * np.asarray(a @ ex["down"][e])
+    assert got.shape == (t, r) and int(sizes.sum()) == held > 0
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-4, rtol=2e-4)
+    with pytest.raises(ValueError, match="activation must be one of"):
+        routed.grouped_mlp(u, ex, sizes, "gelu")
 
 
 @pytest.mark.parametrize("pairs_run", [4096, 16])

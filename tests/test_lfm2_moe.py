@@ -221,7 +221,9 @@ def test_the_window_formats_buffers_gauge_and_bytes():
         assert isinstance(other, conv_window.Window)
         assert other.keys == ("conv", "h")
         assert other.buffers(5)["conv"].shape == (2, 3, 5, other.conv_width)
-        assert list(other.gauges(5, 2)) == ["decode.ssm.conv_bytes"]
+        # (since PR 67 the state of heads also says its B/C groups)
+        assert set(other.gauges(5, 2)) - {"decode.ssm.bc_groups"} \
+            == {"decode.ssm.conv_bytes"}
 
 
 @pytest.mark.parametrize("plen", [1, 2, 9])

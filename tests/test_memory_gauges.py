@@ -17,18 +17,19 @@ from defer_tpu.runtime.decode import PipelinedDecoder
 
 FAMILIES = ("gpt_tiny", "olmoe_tiny", "brumby_tiny", "cohere_moe_tiny",
             "jamba_tiny", "granite_hybrid_tiny", "kimi_k2_tiny",
-            "mellum_tiny", "longcat_flash_tiny")
+            "mellum_tiny", "longcat_flash_tiny", "nemotron_h_tiny")
 #: every name some format of some family answers with
 NAMES = (
     "decode.cache.window_bytes", "decode.cache.full_bytes",
     "decode.cache.window_positions", "decode.cache.block_sequences",
     "decode.cache.block_positions", "decode.cache.latent_bytes",
     "decode.cache.latent_positions", "decode.cache.latent_sublayers",
-    "decode.ssm.conv_bytes", "decode.cache.full_rows_read",
+    "decode.ssm.conv_bytes", "decode.ssm.bc_groups",
+    "decode.cache.full_rows_read",
     "decode.cache.window_rows_read")
 #: a layer's measures, no amounts: over layers the largest stands
 LARGEST = {"decode.cache.window_positions", "decode.cache.block_sequences",
-           "decode.cache.block_positions"}
+           "decode.cache.block_positions", "decode.ssm.bc_groups"}
 KINDS = ("kv_cache", "retention", "ssm", "latent_cache")
 MB, UNSET = 2, -1
 
@@ -57,7 +58,8 @@ def test_a_decoders_gauges_are_what_its_formats_say(family):
     assert bool(reads) == any(w is not None for w in windows)
     dec._post_rows_read(MB * n, 7)
     want.update(reads)
-    for kind in set(dec.memory):
+    # (a layer that keeps no memory has no kind and no gauge)
+    for kind in set(dec.memory) - {None}:
         want[f"decode.{kind}.state_bytes"] = sum(
             n * fmt.state_bytes(MB, 1)
             for k, fmt in zip(dec.memory, dec.state_formats) if k == kind)

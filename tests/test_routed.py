@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from defer_tpu import models
 from defer_tpu.graph.ops import rms_norm
 from defer_tpu.models.cohere_moe import layer_norm
-from defer_tpu.ops.routed import held_range, route, routed_experts
+from defer_tpu.ops.routed import (held_range, route, routed_experts,
+                                  shared_mlp)
 
 T = 12
 CHOICE = {"moe.chosen", "moe.weights"}
@@ -109,6 +110,53 @@ def test_a_blocks_routed_half_is_the_one_layer_with_its_facts(family):
                                   np.asarray(eid))
     np.testing.assert_array_equal(np.asarray(sown["moe.weights"]),
                                   np.asarray(gate))
+
+
+@pytest.mark.parametrize("held", [None, (128, 256)],
+                         ids=["all-held", "a-quarter-held"])
+def test_two_matrix_experts_on_latent_rows_22_of_512(held):
+    """The layer with an expert that is ``(up, down)`` and a named
+    activation, on rows (``rows=u``) other than the rows the router and
+    the shared expert read (``h``): 22 of 512 by ``noaux_tc``, all held
+    or experts 128-255; the pairs' sum leaves in the latent width;
+    against the pairs one by one.  The family's shared expert, two
+    matrices and the same activation on ``h``, is ``shared_mlp``."""
+    rng = np.random.default_rng(7)
+    f32 = jnp.float32
+    t, d, r, w, sh, n, k = 5, 24, 8, 12, 16, 512, 22
+    lo, hi = held or (0, n)
+    h = jnp.asarray(rng.normal(size=(t, d)), f32)
+    u = jnp.asarray(rng.normal(size=(t, r)), f32)
+    router = {"w": jnp.asarray(rng.normal(size=(d, n)), f32) / 5,
+              "bias": jnp.asarray(rng.normal(size=(n,)), f32) * 0.01}
+    ex = {"up": jnp.asarray(rng.normal(size=(hi - lo, r, w)), f32) / 3,
+          "down": jnp.asarray(rng.normal(size=(hi - lo, w, r)), f32) / 3}
+    shared = (jnp.asarray(rng.normal(size=(d, sh)), f32) / 5,
+              jnp.asarray(rng.normal(size=(sh, d)), f32) / 4)
+    sow = {}
+    got, none = jax.jit(lambda h, u: routed_experts(
+        h, router, ex, k=k, scoring="noaux_tc", num_experts=n, held=held,
+        scale=5.0, rows=u, activation="relu2", sow=sow))(h, u)
+    got_shared = shared_mlp(h, *shared, "relu2")
+    assert none is None
+    eid, gate = route(h, router, k, "noaux_tc", 5.0)
+    np.testing.assert_allclose(np.asarray(gate).sum(-1), 5.0, rtol=1e-5)
+    want = np.zeros((t, r), np.float32)
+    for i in range(t):
+        for j in range(k):
+            e = int(eid[i, j]) - lo
+            if 0 <= e < hi - lo:
+                a = jnp.square(jax.nn.relu(u[i] @ ex["up"][e]))
+                want[i] += float(gate[i, j]) * np.asarray(a @ ex["down"][e])
+    assert got.shape == (t, r) and got_shared.shape == (t, d)
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(got_shared),
+        np.asarray(jnp.square(jax.nn.relu(h @ shared[0])) @ shared[1]),
+        atol=1e-4, rtol=1e-4)
+    assert set(sow) >= CHOICE | {"moe.assignments", "moe.experts_hit",
+                                 "moe.load_max"}
+    assert ("moe.held_assignments" in sow) == (held is not None)
 
 
 def test_the_layer_refuses_a_router_of_other_columns():
