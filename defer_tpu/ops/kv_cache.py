@@ -66,7 +66,10 @@ lane row an instant later inside its block.  There a layer's step is one
 kernel, :func:`kv_step`: the attention's grid and blocks, the new rows
 put into the block that holds ``pos`` in fast memory, the lane row stored
 back through an aliased output.  Every other format keeps the two calls:
-a position's rows lie together there, and their write is a slice.
+a position's rows lie together there, and their write is a slice.  (The
+lane row stored for one position is the layout's price, 410 KB a
+sequence a buffer for GPT-2's 3.2 KB of new rows: since PR 68 the ring
+holds heads of 64 joined, below, and ``kv_step`` is narrower heads'.)
 
 **The attention**, :meth:`KVCacheFormat.attend`: one query a sequence
 over a layer's buffers *where they lie* — the Pallas kernel
@@ -75,19 +78,27 @@ position blocks that hold live rows and no other.  Only the int8 rows
 stay on the plain einsum (:func:`attend_einsum`), which is also the
 oracle the tests hold the kernel to.  Which of two kernels a float
 format takes is its geometry's: :func:`kv_attend` walks a block once a
-query on the vector unit, which one query a KV head does at the
-memory's pace and two or more do not, so heads of whole lane rows read
-by a group of :data:`_JOINED_GROUP` or more queries hold their buffers
-*joined* (:attr:`KVCacheFormat.joined`) and attend on the matrix unit,
-:func:`kv_attend_joined` — and so do heads of half a lane row that
-pair off (:func:`_lane_heads`: LFM2's 8 KV heads of 64, two a lane row;
-the buffers' bytes are the plain rows'): to the kernel a pair is one
+query on the vector unit, which one query a KV head of a whole lane row
+does at the memory's pace and two or more do not, so rows of whole lane
+rows that :data:`_JOINED_GROUP` or more query rows read hold their
+buffers *joined* (:attr:`KVCacheFormat.joined`: a position's rows of all
+heads side by side, ``[batch, positions, heads * head_dim]``) and attend
+on the matrix unit, :func:`kv_attend_joined`.  A lane row is a head's
+own, or the one two heads of 64 pair into (:func:`_lane_heads`; an odd
+count pairs off with a phantom head of zeros:
+:attr:`KVCacheFormat.held_heads`); its query rows are a KV head's group
+(LFM2's 8 KV heads of 64 in groups of 4: to the kernel a pair is one
 head of 128 and its two groups that head's group, each query in its own
-head's columns beside zeros.  What is left to :func:`kv_attend`'s pass
-a query is one query a head, and a group over heads that pair into no
-lane row.  A block is fetched whole, so what
-it holds past ``pos`` is read for nothing: over joined rows
-(:func:`kv_attend_joined`) a block has two extents, sequences and
+head's columns beside zeros) and, where the holder writes every sequence
+of a group at one position — the ring —, one query a head of the lane
+row's two (GPT-2's 25 heads: 13 lane rows; there all the row's heads
+side by side are one head to the kernel, 26 query rows over 1664
+columns).  What is left to :func:`kv_attend`'s pass a query is one query
+a head of 128 (OLMoE), heads that pair into no lane row (the tests' 8,
+16 and 32), and the serving engine's slots, whose writes and live list
+(below) plain rows take and joined rows do not.  A block is fetched
+whole, so what it holds past ``pos`` is read for nothing: over joined
+rows (:func:`kv_attend_joined`) a block has two extents, sequences and
 positions, and where a position's rows are thin (one KV head of 128:
 256 B) it stops at :data:`_BLOCK_POSITIONS` and holds several sequences
 instead of one sequence's 4096 rows (:func:`joined_block_rows`, from the
@@ -133,20 +144,28 @@ _BLOCK_POSITIONS = 512
 #: joined buffers hold a multiple of this many positions (a sublane
 #: tile of 16-bit rows)
 _JOINED_ROWS = 16
-#: from this many queries a KV head on, the group is the rows of a
-#: matrix product a position block (:func:`kv_attend_joined`).  One
-#: query's multiply-and-reduce on the vector unit keeps up with the DMA
-#: (OLMoE's call reads 82% of its bytes' time); a pass a query does not
-#: from the second on — granite's group of 4 took 1.70 ms where its rows
-#: take 0.36, 21% — while the products' cost a block is the tiles of
-#: keys and values they load, whatever the rows pushed through them: the
-#: same call over joined rows takes 0.60 ms, at 4 queries as at 2 or 16
-#: (docs/DECODE_CLIFF.md, "The attention"; PERF.md §6, PR 64).  The
-#: width of a head is no part of the rule where heads pair into lane
-#: rows (:func:`_lane_heads`): LFM2's group of 4 over heads of 64 took
-#: 0.81 ms in ``kv_step`` where its rows take 0.24, and two heads a lane
-#: row as one head of 128 — eight query rows, half of each zeros — take
-#: 0.47 (PERF.md §6, PR 66)
+#: from this many query rows a lane row of a joined row on, they are the
+#: rows of a matrix product a position block (:func:`kv_attend_joined`).
+#: One query's multiply-and-reduce on the vector unit keeps up with the
+#: DMA (OLMoE's call, one query a head of 128, reads 82% of its bytes'
+#: time); a pass a query does not from the second on — granite's group
+#: of 4 took 1.70 ms where its rows take 0.36, 21% — while the products'
+#: cost a block is the tiles of keys and values they load, whatever the
+#: rows pushed through them: the same call over joined rows takes 0.60
+#: ms, at 4 queries as at 2 or 16 (docs/DECODE_CLIFF.md, "The
+#: attention"; PERF.md §6, PR 64).  The width of a head is no part of
+#: the rule where heads pair into lane rows (:func:`_lane_heads`):
+#: LFM2's group of 4 over heads of 64 took 0.81 ms in ``kv_step`` where
+#: its rows take 0.24, and two heads a lane row as one head of 128 —
+#: eight query rows, half of each zeros — take 0.47 (PERF.md §6, PR 66).
+#: Nor is the group: a lane row's two heads with one query each are two
+#: query rows, and where a holder writes every sequence at one position
+#: (the ring) GPT-2's 25 heads hold 13 lane rows and a position's write
+#: is a slice, not ``kv_step``'s lane row of 128 positions stored for
+#: one (6.5 MB a layer a step): 81 us a call there, 59 us here and two
+#: slices of 2.8 — with all the row's heads side by side as one head;
+#: lane row by lane row the kernel's 13 passes a block took 93 (PERF.md
+#: §6, PR 68)
 _JOINED_GROUP = 2
 
 
@@ -307,16 +326,43 @@ def _on_lanes(hd: int) -> bool:
     return hd < _LANES
 
 
-def _lane_heads(hd: int, kv: int) -> int:
+def _lane_heads(hd: int) -> int:
     """KV heads that lie side by side in one lane row of a joined row —
     1 for heads of whole lane rows, 2 for heads of half a lane row (64)
-    that pair off, an even number of them — or 0: joined, such heads
-    would be no lane-aligned slices of a row.  (Narrower heads would
-    tile a lane row too, four of 32; no family has them and no chip has
-    timed three quarters of the query rows as zeros.)"""
+    — or 0: joined, such heads would be no lane-aligned slices of a
+    row.  An odd count of halves pairs off with one *phantom* head of
+    zeros in the row's last half lane row
+    (:attr:`KVCacheFormat.held_heads`: GPT-2's
+    25 heads are 13 lane rows; the device tiles 1600 columns as 1664
+    anyway, so the 26th head costs no byte that is not already there).
+    (Narrower heads would tile a lane row too, four of 32; no family has
+    them and no chip has timed three quarters of the query rows as
+    zeros.)"""
     if hd % _LANES == 0:
         return 1
-    return 2 if 2 * hd == _LANES and kv % 2 == 0 else 0
+    return 2 if 2 * hd == _LANES else 0
+
+
+def _side_by_side_heads(hd: int, kv: int, g: int) -> int:
+    """How many of a joined row's ``kv`` heads of ``hd`` go side by side
+    as one head to :func:`kv_attend_joined`'s kernel: a lane row's
+    (:func:`_lane_heads`; 1: every head its own), and with one query a
+    head the whole row's.  Two query rows a lane row leave a pass of the
+    kernel's loop over heads 131 KB of a block of 256 positions, less
+    than the memory moves in the pass's own latency, and the passes run
+    one behind the other (GPT-2's 13 lane rows: 93.7 us a call, 51% of
+    the fetched bytes' time); all the row's heads as one head of the
+    row's width are one pass a block: 59.1 us, 79%.  A group's passes
+    hold enough to hide theirs and the whole row gains nothing — LFM2's
+    kernel takes 443.5 us either way and the two fusions around it 12 us
+    more for queries eight heads wide, 480.3 us a call against the
+    pairs' 469.0; granite's and command-a-plus's heads of 128 side by
+    side read 790.2 against 789.7 and 1114.9 against 1133.6 — so a group
+    keeps the lane row's (``scripts/joined_attend_bench.py
+    --side-by-side=lane|row`` times either form of any case; PERF.md §6,
+    PR 68)."""
+    per = _lane_heads(hd)
+    return kv if per > 1 and g == 1 else per
 
 
 @functools.lru_cache(maxsize=None)
@@ -620,6 +666,14 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
     head's own, and of the output a head's group keeps its own columns
     (:func:`_own_columns`).  Two small fusions around the call.
 
+    With one query a head (GPT-2: ``kv`` 26, the 25 and a phantom) a
+    lane row has two query rows and a pass of the kernel's loop over
+    heads little to do: there all the row's heads go side by side, one
+    head of ``kv * hd`` columns whose group is every head's query, each
+    in its own columns of the row as they lie — a mask, no element
+    moved — and the output's own columns come back under the same mask
+    (:func:`_side_by_side_heads` has the rule and its timings).
+
     The same name in a device trace as :func:`kv_attend`, and like it
     ``name`` where a format names its kernels
     (:attr:`KVCacheFormat.kernel_suffix`)."""
@@ -627,12 +681,12 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
     groups, _, length, width = k_buf.shape
     hd, g = width // kv, d // width
     scale = 1.0 / math.sqrt(hd)
-    per = _lane_heads(hd, kv)
+    per = _side_by_side_heads(hd, kv, g)
     if per > 1:
-        # heads under a lane row: to the kernel the ``per`` of a lane
-        # row are one head of 128 and their queries its group
+        # heads under a lane row: to the kernel the ``per`` side by side
+        # are one head of their joint width and their queries its group
         q = _side_by_side(q.reshape(b, kv // per, per, g, hd))
-        kv, hd = kv // per, _LANES
+        kv, hd = kv // per, per * hd
     heads = kv * per * g
     sb, tl = joined_block_rows(kv, hd, length, k_buf.dtype.itemsize, b)
     blocks = b // sb
@@ -675,30 +729,51 @@ def kv_attend_joined(q, k_buf, v_buf, pos, group, *, kv: int,
         name=name,
     )(group, pos, q.reshape(b, heads, hd), k_buf, v_buf)
     if per > 1:
-        out = _own_columns(out.reshape(b, kv, per, g, per, hd // per))
+        out = _own_columns(out, kv, per)
     return out.reshape(b, d)
 
 
+def _own(per: int, hd: int):
+    """``[per, per * hd]``: whether a column of ``per`` heads side by
+    side is row ``i``'s own head's."""
+    return (jnp.arange(per)[:, None]
+            == jnp.arange(per * hd)[None, :] // hd)
+
+
 def _side_by_side(q):
-    """The queries ``[b, lane rows, per, g, hd]`` of KV heads that share
-    a lane row of joined rows, as one head's group ``[b, lane rows, per *
-    g, per * hd]``: head ``i``'s queries in its own ``hd`` columns and
-    zeros in its neighbours', so a row's score against the lane row's
-    keys is that query's against its own head's, and of its output
-    (every head's values, weighted its way) its own columns are its
-    own: :func:`_own_columns`.  The zeros ride through the matrix unit
+    """The queries ``[b, rows, per, g, hd]`` of KV heads that lie side
+    by side in a joined row (a lane row's two, or with one query a head
+    the whole row's), as one head's group ``[b, rows, per * g, per *
+    hd]``: head ``i``'s queries in its own ``hd`` columns and zeros in
+    its neighbours', so a row's score against the joint keys is that
+    query's against its own head's, and of its output (every head's
+    values, weighted its way) its own columns are its own:
+    :func:`_own_columns`.  The zeros ride through the matrix unit
     beside the queries: a block costs the tiles of keys and values it
-    loads."""
+    loads.  One query a head is its columns of the row as they lie, so
+    there a row of the group is the whole row under a mask and no
+    element moves."""
     b, rows, per, g, hd = q.shape
+    if g == 1:
+        return jnp.where(_own(per, hd), q.reshape(b, rows, 1, per * hd), 0)
     own = jnp.eye(per, dtype=bool)[:, None, :, None]
     return jnp.where(own, q[:, :, :, :, None, :], 0).reshape(
         b, rows, per * g, per * hd)
 
 
-def _own_columns(out):
-    """Of ``[b, lane rows, per, g, per, hd]``, head ``i``'s group's
-    columns of head ``i``: ``[b, lane rows, per, g, hd]``."""
-    return jnp.stack([out[:, :, i, :, i] for i in range(out.shape[2])], 2)
+def _own_columns(out, rows: int, per: int):
+    """Of the output ``[b, rows * per * g, per * hd]`` over ``per``
+    heads side by side, head ``i``'s group's columns of head ``i``:
+    ``[b, rows, per, g, hd]`` (with one query a head ``[b, rows, per *
+    hd]``, the same elements: a column's one row under the mask, summed
+    out of zeros)."""
+    b, heads, width = out.shape
+    g = heads // (rows * per)
+    if g == 1:
+        return jnp.sum(jnp.where(_own(per, width // per),
+                                 out.reshape(b, rows, per, width), 0), axis=2)
+    out = out.reshape(b, rows, per, g, per, width // per)
+    return jnp.stack([out[:, :, i, :, i] for i in range(per)], 2)
 
 
 @functools.partial(jax.jit, static_argnames=("name",))
@@ -1019,14 +1094,47 @@ class KVCacheFormat(RingRows):
 
     @property
     def joined(self) -> bool:
-        """Whether the buffers are ``[batch, positions, kv_heads *
+        """Whether the buffers are ``[batch, positions, held_heads *
         head_dim]`` (the module docstring): for float rows of whole
         lane rows — a head's own, or those that heads of half a lane
-        row pair into (:func:`_lane_heads`: two KV heads of 64) — read by
-        a group of queries (:data:`_JOINED_GROUP`: two or more; one
-        query a KV head keeps plain rows and the vector unit)."""
-        return (self.query_group >= _JOINED_GROUP and not self.quantized
-                and _lane_heads(self.head_dim, self.kv_heads) > 0)
+        row pair into (:func:`_lane_heads`: two KV heads of 64) — that
+        :data:`_JOINED_GROUP` or more query rows read.  A KV head's
+        group are such rows whoever holds the format; the queries of a
+        lane row's *two* heads are too, one a head (GPT-2), where the
+        holder writes every sequence of a group at one position — the
+        ring's formats, ``groups`` set.  A holder of slots (the serving
+        engine) writes a position a sequence and walks a list of live
+        sequences, which plain rows take and joined rows do not
+        (:meth:`write_slots`, :meth:`attend`): one query a head keeps
+        plain rows there, as it does over heads of a whole lane row
+        wherever they are held (OLMoE: the vector unit at the memory's
+        pace)."""
+        per = _lane_heads(self.head_dim)
+        if self.quantized or not per:
+            return False
+        rows = self.query_group * (1 if self.groups is None else per)
+        return rows >= _JOINED_GROUP
+
+    @property
+    def held_heads(self) -> int:
+        """The heads a joined row holds: :attr:`kv_heads`, and where an
+        odd count of halves leaves the row's last lane row half empty,
+        one phantom head of zeros behind them (GPT-2's 25 heads of 64:
+        26, 13 lane rows).  :meth:`zeros` makes the phantom's columns
+        and no write puts anything else there (:meth:`rows`,
+        :meth:`write_prefix` pad with zeros), so its scores are 0, its
+        output 0, and :meth:`attend` drops it."""
+        per = _lane_heads(self.head_dim) or 1
+        return -(-self.kv_heads // per) * per
+
+    def _held(self, a):
+        """``a`` ``[..., kv_heads * n]`` (``n`` columns a KV head: its
+        row, or its group's queries) with the phantom head's behind
+        them, zeros."""
+        phantom = a.shape[-1] // self.kv_heads \
+            * (self.held_heads - self.kv_heads)
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, phantom)]) \
+            if phantom else a
 
     def __post_init__(self):
         if self.window is not None and not 0 < self.window < self.positions:
@@ -1057,7 +1165,7 @@ class KVCacheFormat(RingRows):
             # kernel's layout and back around each dispatch
             rows = jax.ShapeDtypeStruct(
                 lead + (batch, -(-length // _JOINED_ROWS) * _JOINED_ROWS,
-                        self.kv_heads * self.head_dim), self.dtype)
+                        self.held_heads * self.head_dim), self.dtype)
             return {"k": rows, "v": rows}
         scales = lead + (batch, self.kv_heads, length)
         rows = jax.ShapeDtypeStruct(
@@ -1092,7 +1200,7 @@ class KVCacheFormat(RingRows):
         if self.joined:
             rows = self.buffers(batch)["k"]
             sequences, positions = joined_block_rows(
-                self.kv_heads, self.head_dim, rows.shape[-2],
+                self.held_heads, self.head_dim, rows.shape[-2],
                 jnp.dtype(rows.dtype).itemsize, batch)
         return {"decode.cache.window_bytes": held if ring else 0,
                 "decode.cache.full_bytes": 0 if ring else held,
@@ -1116,7 +1224,8 @@ class KVCacheFormat(RingRows):
         one position a sequence, as the writes take them."""
         b = k_new.shape[0]
         if self.joined:
-            return {"k": k_new[:, None], "v": v_new[:, None]}
+            return {"k": self._held(k_new)[:, None],
+                    "v": self._held(v_new)[:, None]}
         rows = {"k": k_new.reshape(b, self.kv_heads, 1, -1),
                 "v": v_new.reshape(b, self.kv_heads, 1, -1)}
         if self.quantized:
@@ -1195,7 +1304,8 @@ class KVCacheFormat(RingRows):
             # the product before them liked) and, the write needing one
             # layout on both sides, convert the *buffer* there and back
             rows_major = Layout(major_to_minor=(0, 1, 2))
-            k, v = (with_layout_constraint(a, rows_major) for a in (k, v))
+            k, v = (with_layout_constraint(self._held(a), rows_major)
+                    for a in (k, v))
         else:
             shape = (b, t, self.kv_heads, self.head_dim)
             k = k.reshape(shape).transpose(0, 2, 1, 3)
@@ -1233,8 +1343,9 @@ class KVCacheFormat(RingRows):
         :func:`attend_einsum` reads."""
         if not self.joined:
             return item
-        return {key: buf.reshape(buf.shape[:2] + (self.kv_heads, -1))
-                .swapaxes(1, 2) for key, buf in item.items()}
+        return {key: buf.reshape(buf.shape[:2] + (self.held_heads, -1))
+                [:, :, :self.kv_heads].swapaxes(1, 2)
+                for key, buf in item.items()}
 
     def reparent(self, state: dict, group, parents) -> dict:
         """Beam search: sequence ``i`` of group ``group`` takes over
@@ -1322,6 +1433,12 @@ class KVCacheFormat(RingRows):
         name = "kv_attend" + self.kernel_suffix
         if self.joined:
             REGISTRY.gauge("decode.kv.joined_layers").inc()
-            return kv_attend_joined(q, k_buf, v_buf, pos, group,
-                                    kv=self.kv_heads, name=name)
+            out = kv_attend_joined(
+                self._held(q), k_buf, v_buf, pos, group, kv=self.held_heads,
+                name=name)
+            # a phantom head's queries ride as zeros behind the others
+            # and its output is dropped: inside the two fusions that put
+            # a lane row's heads side by side and take each head's own
+            # columns back
+            return out[:, :q.shape[1]]
         return kv_attend(q, k_buf, v_buf, pos, group, live, name=name)

@@ -12,17 +12,22 @@ none.  Until PR 29 a step also cut a group's item out of every buffer
 (``slice``), and the compiled loop converted every buffer to a padded
 layout of its own and back, every dispatch (``copy``, and a temporary of
 all of them): with the attention a kernel over the buffers as they lie
-(``ops/kv_cache.py::kv_attend``) there is neither.  Since PR 54 a
-layer's step under a lane row is one kernel that writes while it attends
-(``kv_step``): the line counts the cache kernels by name
-(``cache_kernels``; 24 / 0 / 0 at the batch cell, all four stages'
-branches at the four-chip cell: 48 / 0 / 0) and reads the gauge
-``decode.kv.fused_layers`` (a stage's layers).  Run it before spending
+(``ops/kv_cache.py::kv_attend``) there is neither.  From PR 54 to PR 67
+a layer's step under a lane row was one kernel that wrote while it
+attended (``kv_step``); since PR 68 heads of 64 on the ring hold joined
+rows, a position's write is a slice and the attention the matrix
+unit's (``kv_attend``), and ``kv_step`` is narrower heads': the line
+counts the cache kernels by name (``cache_kernels``: ``kv_attend`` 24,
+``kv_step`` 0 at the batch cell with 48 ``row_writes``; all four
+stages' branches at the four-chip cell: 48 and 104) and reads the gauges
+``decode.kv.fused_layers`` and ``decode.kv.joined_layers`` (0 and a
+stage's layers).  Run it before spending
 chip time on a change to how the ring holds its caches — after one to
 the cache kernels also at a buffer of under 128 positions (``2 768 12 2
-64 4``, seconds: there a block is one partial lane row, and Mosaic
-refused PR 54's first ``kv_step`` for a lane slice it could not see was
-aligned, which only the chip smoke's small decoder showed):
+64 4``, seconds: there a block is the whole buffer, 80 rows; while
+those heads were ``kv_step``'s a block was one partial lane row, and
+Mosaic refused PR 54's first ``kv_step`` for a lane slice it could not
+see was aligned, which only the chip smoke's small decoder showed):
 
     env JAX_PLATFORMS=cpu python scripts/decode_tpu_compile_check.py \\
         [layers d_model heads sequences max_len token_chunk stages]
@@ -134,6 +139,8 @@ def main(layers=12, d_model=768, heads=12, mb=64, max_len=512,
                rf"%{name}[.\d]* = .*tpu_custom_call", text))
                for name in ("kv_step", "kv_attend", "kv_write_rows")},
            "fused_layers": int(REGISTRY.gauge("decode.kv.fused_layers").value),
+           "joined_layers": int(
+               REGISTRY.gauge("decode.kv.joined_layers").value),
            # blocks a stage, as the bytes cut them (``decode.cut.blocks``)
            "cut": [int(REGISTRY.gauge(f"decode.cut.blocks.{s}").value)
                    for s in range(stages)],

@@ -16,7 +16,13 @@ same call, which its format did not yet make) and, since PR 66, LFM2's
 before it lowers another kernel there, slices of 64 columns, which no
 format made) and, since PR 67, Nemotron-3-Super's (2 x 16 on heads of
 128: rows of 512 B a position, 128 sequences; the kernel is older
-trees' too, no format of theirs made the call) — over bfloat16 buffers as the cells hold them — with the hash of the Mosaic
+trees' too, no format of theirs made the call) and, since PR 68, GPT-2's
+(26 x 1 on heads of 64, 25 and a phantom: one query a head, so the whole
+row's heads side by side are one head of 1664 columns and 26 query
+rows; 8 sequences a group, and the four-chip ring's 2; a tree from
+before it lowers 13 lane-row heads of two query rows there, which no
+format made) — over bfloat16 buffers as the cells hold them — with the
+hash of the Mosaic
 kernel's text and of the text around it.  The kernel's body travels as
 bytecode that carries its source lines; it is hashed as text without
 them.  With ``DIR`` both texts are written there for ``diff``.
@@ -54,6 +60,8 @@ CALLS = {
     "granite4h": (8, 4, 64, 3088),
     "lfm2moe": (8, 4, 128, 2560, 64),
     "nemotron3super": (2, 16, 128, 3600),
+    "gpt2xl": (26, 1, 8, 784, 64),
+    "gpt2xl.pipe4": (26, 1, 2, 784, 64),
 }
 
 

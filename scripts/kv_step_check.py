@@ -5,8 +5,11 @@ has — the tests hold the same on the CPU's interpreter
 
     python scripts/kv_step_check.py [kv_heads head_dim sequences positions groups]
 
-(default gpt2-xl's ring in the batch cell: ``25 64 8 768 1``; the
-four-chip cell's is ``25 64 2 768 4``).  A step at every given position
+(default ``50 32 8 768 1``: the bytes a position of gpt2-xl's ring in
+the batch cell at heads of 32 — since PR 68 the ring holds heads of 64
+joined and ``kv_step`` is the kernel of heads that pair into no lane
+row; the format is refused where it does not write in its attention).
+A step at every given position
 of a bfloat16 buffer of noise: ``fmt.step`` and ``fmt.write_position``
 then ``fmt.attend`` from the same buffers, the outputs and both buffers
 compared as bits.  One JSON line; exit 0 when nothing differs.
@@ -26,7 +29,7 @@ import jax.numpy as jnp
 from defer_tpu.ops.kv_cache import KVCacheFormat, attend_blocks
 
 
-def main(kv=25, hd=64, b=8, positions=768, groups=1) -> int:
+def main(kv=50, hd=32, b=8, positions=768, groups=1) -> int:
     fmt = KVCacheFormat(kv, hd, positions, jnp.bfloat16, groups=groups)
     assert fmt.writes_in_attention
     _, tl = attend_blocks(kv, hd, positions + 1, 2)

@@ -154,10 +154,11 @@ def test_attend_takes_each_sequences_own_position(quantized):
 # -- the attention kernel against the einsums it replaces -----------------------
 
 def _attend_case(hd, dtype, g, groups, length, batch=4, kv=2, seed=0):
-    """A format, one layer of noise (NaN wherever nothing may read: the
-    scratch group and the scratch row) and a query."""
+    """A format of plain rows, one layer of noise (NaN wherever nothing
+    may read: the scratch group and the scratch row) and a query."""
     rng = np.random.default_rng(seed)
     fmt = KVCacheFormat(kv, hd, length, dtype, groups=groups)
+    assert not fmt.joined
     layer = {key: jnp.asarray(rng.standard_normal(s.shape), dtype)
              for key, s in fmt.buffers(batch).items()}
     if groups is not None:
@@ -168,25 +169,32 @@ def _attend_case(hd, dtype, g, groups, length, batch=4, kv=2, seed=0):
     return fmt, layer, q
 
 
-def _oracle(q, layer, pos, group=None):
-    """The kept einsums in f32 over the group's clean item."""
+def _oracle(q, layer, pos, group=None, fmt=None):
+    """The kept einsums in f32 over the group's clean item (head-major:
+    ``fmt``'s word where it holds the rows otherwise)."""
     item = {key: jnp.nan_to_num((buf if group is None else buf[group])
                                 .astype(jnp.float32))
             for key, buf in layer.items()}
+    if fmt is not None:
+        item = fmt.head_major(item)
     return np.asarray(attend_einsum(q.astype(jnp.float32), item, pos))
 
 
-@pytest.mark.parametrize("groups", [None, 2], ids=["slots", "groups"])
 @pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("hd,groups", [
+    (64, None), (128, None), (32, 2), (128, 2)],
+    ids=["64-slots", "128-slots", "32-groups", "128-groups"])
 def test_kv_attend_is_the_einsums_over_live_rows(hd, dtype, g, groups):
     """Both block shapes, both row types, one query a KV head and a
     group of them; 300 (301) positions are two blocks, the second
     ragged.  With groups: the ring's call, a scalar position and the
     group an index, and neither the scratch group nor the scratch row
-    (NaN) reaches the output."""
+    (NaN) reaches the output.  (Under a lane row the ring's heads are
+    32 wide here: its heads of 64 hold joined rows since PR 68 and read
+    them through the other kernel —
+    ``test_a_ring_step_over_heads_of_64_is_a_slice_and_the_einsums``.)"""
     fmt, layer, q = _attend_case(hd, dtype, g, groups, 300)
     tol = 2e-6 if dtype == jnp.float32 else 1e-2
     if groups is None:
@@ -226,7 +234,7 @@ def test_kv_attend_at_each_sequences_own_position(monkeypatch, request, hd,
 def test_kv_attend_of_a_bubble_step_is_finite():
     """A bubble writes the scratch row and attends at it: numbers
     nobody reads, but numbers."""
-    fmt, layer, q = _attend_case(64, jnp.bfloat16, 1, 2, 300)
+    fmt, layer, q = _attend_case(32, jnp.bfloat16, 1, 2, 300)
     layer = {key: jnp.nan_to_num(buf) for key, buf in layer.items()}
     got = fmt.attend(q, layer, jnp.int32(fmt.scratch_position),
                      group=jnp.int32(0))
@@ -283,13 +291,15 @@ def _assert_step_is_the_two_calls(fmt, q, layer, rows, pos, group):
     return got, stepped
 
 
-@pytest.mark.parametrize("groups,at", [
-    (groups, at) for groups in (None, 2) for at in _STEP_POSITIONS
+@pytest.mark.parametrize("hd,groups,at", [
+    # slots' heads of 32 and 64; the ring's of 32 and 16 (its heads of
+    # 64 hold joined rows since PR 68 and their write is a slice)
+    (hd, groups, at) for groups, widths in ((None, (32, 64)), (2, (32, 16)))
+    for hd in widths for at in _STEP_POSITIONS
     if groups or at != "scratch"])      # slots alone have no scratch row
 @pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("hd", [32, 64])
 def test_step_is_write_position_then_attend_bit_for_bit(
         monkeypatch, request, hd, dtype, g, groups, at):
     """Under a lane row a step is one kernel (``kv_step``), and it gives
@@ -313,7 +323,7 @@ def test_a_bubble_step_touches_the_scratch_row_of_its_group_only(
         monkeypatch, request):
     """A bubble writes the scratch row and attends at it: numbers nobody
     reads, but numbers, and no other row or group moves."""
-    fmt, layer, q, rows = _step_case(monkeypatch, request, 64, jnp.bfloat16,
+    fmt, layer, q, rows = _step_case(monkeypatch, request, 32, jnp.bfloat16,
                                      1, 2)
     layer = {key: jnp.nan_to_num(buf) for key, buf in layer.items()}
     got, stepped = fmt.step(q, layer, rows, jnp.int32(fmt.scratch_position),
@@ -329,13 +339,14 @@ def test_a_bubble_step_touches_the_scratch_row_of_its_group_only(
 def test_step_over_blocks_of_heads(monkeypatch, request):
     """Two blocks of heads a sequence: each stores its own heads' lane
     row, once."""
-    monkeypatch.setattr(kv_cache, "_BLOCK_BYTES", 64 * 128 * 4)
+    monkeypatch.setattr(kv_cache, "_BLOCK_BYTES", 32 * 128 * 4)
     attend_blocks.cache_clear()         # sizes are reckoned once a shape
     request.addfinalizer(attend_blocks.cache_clear)
-    assert attend_blocks(2, 64, 421, 4) == (1, 128)
-    fmt, layer, q = _attend_case(64, jnp.float32, 2, 2, 420, batch=3)
+    assert attend_blocks(2, 32, 421, 4) == (1, 128)
+    fmt, layer, q = _attend_case(32, jnp.float32, 2, 2, 420, batch=3)
+    assert fmt.writes_in_attention
     rng = np.random.default_rng(5)
-    rows = fmt.rows(*(jnp.asarray(rng.standard_normal((3, 2 * 64)),
+    rows = fmt.rows(*(jnp.asarray(rng.standard_normal((3, 2 * 32)),
                                   jnp.float32) for _ in range(2)))
     for pos in (0, 127, 200, 419, 420):
         _assert_step_is_the_two_calls(fmt, q, layer, rows, jnp.int32(pos),
@@ -354,19 +365,22 @@ class _WritesItsOwnWay(KVCacheFormat):
     KVCacheFormat(2, 64, 40, jnp.float32, quantized=True, groups=2),
     KVCacheFormat(2, 128, 40, jnp.bfloat16, groups=2, query_group=8),
     KVCacheFormat(2, 64, 40, jnp.float32, groups=2, window=16),
-    _WritesItsOwnWay(2, 64, 40, jnp.float32, groups=2),
+    _WritesItsOwnWay(2, 32, 40, jnp.float32, groups=2),
     KVCacheFormat(4, 64, 40, jnp.bfloat16, groups=2, query_group=4),
+    KVCacheFormat(25, 64, 40, jnp.bfloat16, groups=2),
+    KVCacheFormat(4, 64, 40, jnp.float32, groups=2),
 ], ids=["lane-rows", "int8", "joined", "ring-buffer", "subclass",
-        "joined-hd64"])
+        "joined-hd64", "joined-hd64-one-query-25", "joined-hd64-one-query-4"])
 def test_a_format_off_the_lanes_steps_through_its_two_calls(monkeypatch,
                                                             fmt):
     """Heads of a lane row, int8 rows, joined rows (of heads of 128, or
-    of a group's heads of 64 two a lane row), a ring buffer: a
-    position's rows lie together (or land in another block than the
-    last live one), there is nothing to fuse, and ``step`` is the write
-    and then the attention over what it wrote — the calls the blocks
-    made themselves until PR 54.  So is a subclass's whose write is its
-    own."""
+    of heads of 64 two a lane row: a group's, or on the ring one query a
+    head — GPT-2's 25, the last lane row half a phantom head, and an
+    even count), a ring buffer: a position's rows lie together (or land
+    in another block than the last live one), there is nothing to fuse,
+    and ``step`` is the write and then the attention over what it wrote
+    — the calls the blocks made themselves until PR 54.  So is a
+    subclass's whose write is its own."""
     assert fmt.writes_in_attention == isinstance(fmt, _WritesItsOwnWay)
     rng = np.random.default_rng(3)
     layer = {key: jnp.asarray(rng.integers(-9, 9, s.shape), s.dtype)
@@ -905,6 +919,8 @@ _WINDOW_CASES = {
     "g1-hd64": (1, 64, jnp.float32, 2),
     "g4-hd64-kv3": (4, 64, jnp.float32, 3),
     "g3-hd32-kv4": (3, 32, jnp.bfloat16, 4),
+    "g1-hd64-kv25-bf16": (1, 64, jnp.bfloat16, 25),
+    "g1-hd64-kv5": (1, 64, jnp.float32, 5),
 }
 
 
@@ -915,22 +931,24 @@ def test_ring_buffer_steps_are_attention_over_the_window(case, plen):
     """``write_prefix`` of a prompt under, at and over the window, then
     decode steps across the next wraps: every step's attention is the
     dense attention over the ``window`` newest positions — through the
-    vector kernel (one query a KV head, and a group over heads that
-    pair into no lane row: heads of 8, four of 32, three of 64) and
+    vector kernel (one query a KV head of 8 or of 128, and a group over
+    heads that pair into no lane row: heads of 8, four of 32) and
     through joined rows on the matrix unit (2 to 20 queries a KV head of
     128, a group that fills a sublane tile or not; in bfloat16 the two
     heads' rows are thin and both sequences share a block — and the same
     over heads of 64, two a lane row: the pair is one head of 128 to the
-    kernel, its queries side by side).  The rule is the geometry's: a
-    group, float rows, heads of whole lane rows or pairs of halves; one
-    query a head of 64 keeps plain rows and, with a row a position, the
-    write inside its attention."""
+    kernel, its queries side by side; an odd count of them — three,
+    five, GPT-2's 25 — pairs off with a phantom head of zeros; and with
+    one query a head, which this holder's groups make two query rows a
+    lane row, the whole row's heads side by side are one head).  The
+    rule is the geometry's and the holder's: float rows, heads of whole
+    lane rows or pairs of halves, two query rows a lane row."""
     g, hd, dtype, kv = _WINDOW_CASES[case]
     w, total, b = 6, 30, 2
     fmt = KVCacheFormat(kv, hd, total, dtype, groups=1, window=w,
                         query_group=g)
-    tiles = hd == 128 or (hd == 64 and kv % 2 == 0)
-    assert fmt.joined == (g >= 2 and tiles)
+    per = {128: 1, 64: 2}.get(hd, 0)
+    assert fmt.joined == (per * g >= 2)
     a_row_a_position = dataclasses.replace(fmt, window=None)
     assert a_row_a_position.joined == fmt.joined
     assert a_row_a_position.writes_in_attention == (
@@ -938,7 +956,8 @@ def test_ring_buffer_steps_are_attention_over_the_window(case, plen):
     if fmt.joined:
         # whole sublane tiles of positions: the window, the scratch
         # row, and padding
-        assert fmt.buffers(b)["k"].shape == (2, b, 16, kv * hd)
+        assert fmt.buffers(b)["k"].shape == (
+            2, b, 16, -(-kv // per) * per * hd)
     rng = np.random.default_rng(plen)
     q = rng.standard_normal((b, total, kv * g * hd)).astype(np.float32)
     k = rng.standard_normal((b, total, kv * hd)).astype(np.float32)
@@ -1066,10 +1085,12 @@ def test_joined_attention_at_each_sequences_own_position(case, dtype, groups):
         atol=2e-5 if dtype == jnp.float32 else 3e-2)
 
 
-#: bfloat16 formats as five cells hold them — (KV heads, queries a
+#: bfloat16 formats as the cells hold them — (KV heads, queries a
 #: head, positions, window, sequences a group) — with their buffers'
 #: rows, the attention's block over them and a head's width where it is
-#: not 128 (LFM2's two heads a lane row: Mellum2's 1 KB a position)
+#: not 128 (LFM2's two heads a lane row: Mellum2's 1 KB a position;
+#: GPT-2's 25 and a phantom, 3328 B a position: a sequence's 256
+#: positions a block)
 _CELL_BLOCKS = {
     "mellum2-full": ((4, 8, 28672, None, 16), 28688, (1, 1024)),
     "mellum2-window": ((4, 8, 28672, 1024, 16), 1040, (1, 1024)),
@@ -1079,6 +1100,9 @@ _CELL_BLOCKS = {
     "jamba2-a-group-of-two": ((1, 20, 4352, None, 2), 4368, (2, 512)),
     "granite4h": ((8, 4, 3072, None, 64), 3088, (1, 512)),
     "lfm2moe": ((8, 4, 2559, None, 128), 2560, (1, 1024), 64),
+    "gpt2xl": ((25, 1, 768, None, 8), 784, (1, 256), 64),
+    "gpt2xl-long-prompt": ((25, 1, 928, None, 8), 944, (1, 256), 64),
+    "gpt2xl-pipe4": ((25, 1, 768, None, 2), 784, (1, 256), 64),
 }
 
 
@@ -1093,15 +1117,17 @@ def test_a_joined_block_is_sized_from_the_operands_shapes(cell):
     hd = hd[0] if hd else 128
     fmt = KVCacheFormat(kv, hd, positions, jnp.bfloat16, groups=1,
                         window=window, query_group=g)
+    # whole lane rows of columns: GPT-2's 25 heads of 64 hold a 26th
+    held = -(-kv * hd // 128) * 128
     assert fmt.joined and fmt.buffers(b)["k"].shape == (
-        2, b, length, kv * hd)
-    assert kv_cache.joined_block_rows(kv, hd, length, 2, b) == want
+        2, b, length, held)
+    assert kv_cache.joined_block_rows(held // hd, hd, length, 2, b) == want
     said = fmt.gauges(b, 1)
     assert (said["decode.cache.block_sequences"],
             said["decode.cache.block_positions"]) == want
     assert {"decode.cache.block_sequences",
             "decode.cache.block_positions"} <= fmt.largest
-    plain = KVCacheFormat(kv, hd, positions, jnp.bfloat16, groups=1,
+    plain = KVCacheFormat(kv, hd, positions, jnp.bfloat16,
                           window=window).gauges(b, 1)
     assert plain["decode.cache.block_sequences"] == 0
     assert plain["decode.cache.block_positions"] == 0
@@ -1111,11 +1137,13 @@ def test_a_joined_block_is_sized_from_the_operands_shapes(cell):
 def test_a_joined_prefix_of_a_piece_lands_at_its_sequences(hd):
     """``prefill_slot`` with a row: a piece of a group, written from
     that sequence on, the rest of the group untouched — plain rows (one
-    query a head) and joined ones, of heads of 128 and of 64."""
+    query a head of 128) and joined ones, of heads of 128 and of 64
+    (there one query a head too: the ring's two query rows a lane
+    row)."""
     for g in (1, 16):
         fmt = KVCacheFormat(2, hd, 12, jnp.float32, groups=2,
                             query_group=g)
-        assert fmt.joined == (g == 16)
+        assert fmt.joined == (g == 16 or hd == 64)
         rng = np.random.default_rng(g)
         k = jnp.asarray(rng.standard_normal((2, 5, 2 * hd)), jnp.float32)
         layer = fmt.layer(fmt.zeros(4, 1), 0)
@@ -1129,3 +1157,199 @@ def test_a_joined_prefix_of_a_piece_lands_at_its_sequences(hd):
                                       want + 1)
         assert not np.asarray(item["k"])[:2].any()
         assert not np.asarray(out["k"][0]).any()
+
+
+# -- one query a head of 64 on the ring: GPT-2's rows off the lanes ------------
+
+@pytest.mark.parametrize("hd,kv,g,groups,quantized,want", [
+    (64, 25, 1, 2, False, True), (64, 8, 1, 2, False, True),
+    (64, 25, 1, None, False, False), (64, 8, 1, None, False, False),
+    (128, 16, 1, 2, False, False), (128, 16, 2, None, False, True),
+    (64, 8, 4, None, False, True), (64, 3, 2, None, False, True),
+    (64, 25, 1, 2, True, False), (32, 4, 4, 2, False, False),
+], ids=["gpt2-ring", "even-ring", "gpt2-slots", "even-slots", "olmoe-ring",
+        "group-of-128", "lfm2-slots", "odd-group-slots", "int8-ring",
+        "heads-of-32"])
+def test_joined_counts_the_query_rows_of_a_lane_row(hd, kv, g, groups,
+                                                    quantized, want):
+    """Float rows of whole lane rows — a head's, or two heads of 64 —
+    are joined from two query rows a lane row on: a KV head's group
+    whoever holds the format; a lane row's two heads with one query
+    each where the holder writes every sequence of a group at one
+    position (the ring: ``groups``), not in slots, whose writes and
+    live list joined rows refuse; one query a head of 128 nowhere.  An
+    odd count of halves holds one phantom head more."""
+    fmt = KVCacheFormat(kv, hd, 40, jnp.bfloat16, quantized=quantized,
+                        groups=groups, query_group=g)
+    assert fmt.joined == want
+    if not want:
+        assert fmt.buffers(3)["k"].shape[-3:] == (kv, 40 + (groups
+                                                            is not None), hd)
+        return
+    assert fmt.held_heads == kv + (hd == 64 and kv % 2)
+    assert fmt.buffers(3)["k"].shape[-2:] == (48, fmt.held_heads * hd)
+    assert fmt.buffers(3)["k"].shape[-1] % 128 == 0
+    assert not fmt.writes_in_attention
+    with pytest.raises(NotImplementedError):
+        fmt.write_slots({}, {}, jnp.zeros(3, jnp.int32))
+
+
+def _ring_of_64(kv, dtype, length=300, batch=3, seed=0):
+    """The ring's format of ``kv`` heads of 64, one query a head, two
+    groups, and a layer: noise in the heads' columns, zeros in the
+    phantom's — as :meth:`zeros` makes them and every write leaves them
+    — and NaN wherever nothing may read: the scratch group, the scratch
+    row and the padding behind it."""
+    fmt = KVCacheFormat(kv, 64, length, dtype, groups=2)
+    assert fmt.joined
+    rng = np.random.default_rng(seed)
+    layer = {key: jnp.asarray(rng.standard_normal(s.shape), dtype)
+             .at[..., kv * 64:].set(0)
+             .at[fmt.scratch_group].set(jnp.nan)
+             .at[:, :, fmt.scratch_position:].set(jnp.nan)
+             for key, s in fmt.buffers(batch).items()}
+    q = jnp.asarray(rng.standard_normal((batch, kv * 64)), dtype)
+    rows = fmt.rows(*(jnp.asarray(rng.standard_normal((batch, kv * 64)),
+                                  dtype) for _ in range(2)))
+    return fmt, layer, q, rows
+
+
+@pytest.mark.parametrize("at", ["first", "block-last", "block-first", "last",
+                                "scratch"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kv", [25, 4])
+def test_a_ring_step_over_heads_of_64_is_a_slice_and_the_einsums(kv, dtype,
+                                                                 at):
+    """GPT-2's 25 heads (13 lane rows, the last half a phantom; a
+    sequence's 256 positions a block in bfloat16, 128 in float32) and an
+    even count (thin rows: several sequences a block): a step writes the
+    position's row as a slice — the new rows, zeros in the phantom's
+    columns, nothing else of any buffer — and attends over the rows ``<=
+    pos`` as the einsums do, at position 0, a block's last and first
+    row, the last position; a bubble's step writes the scratch row of
+    its group and reads numbers."""
+    fmt, layer, q, rows = _ring_of_64(kv, dtype)
+    scratch = at == "scratch"
+    pos = {"first": 0, "block-last": 255, "block-first": 256, "last": 299,
+           "scratch": fmt.scratch_position}[at]
+    group = 0 if scratch else 1
+    if scratch:
+        layer = {key: jnp.nan_to_num(buf) for key, buf in layer.items()}
+    got, stepped = jax.jit(fmt.step)(q, layer, rows, jnp.int32(pos),
+                                     jnp.int32(group))
+    assert got.dtype == q.dtype and got.shape == q.shape
+    for key, buf in layer.items():
+        want = np.asarray(buf.astype(jnp.float32)).copy()
+        want[group, :, pos] = np.asarray(rows[key].astype(jnp.float32))[:, 0]
+        np.testing.assert_array_equal(
+            np.asarray(stepped[key].astype(jnp.float32)), want)
+        assert not want[group, :, pos, kv * 64:].any()
+    if scratch:
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        return
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), _oracle(q, stepped, pos, group, fmt),
+        atol=2e-5 if dtype == jnp.float32 else 3e-2)
+
+
+@pytest.mark.parametrize("kv", [25, 3])
+def test_the_phantom_head_stays_zeros_and_head_major_drops_it(kv):
+    """A prompt written whole, in pieces of a group and a row at a
+    time leaves one buffer: the rows in the heads' columns, zeros in the
+    phantom head's half lane row — which no write fills with anything
+    else, so its scores are 0 and its output 0 — and ``head_major``
+    hands the heads back without it."""
+    b, t = 4, 7
+    fmt = KVCacheFormat(kv, 64, 12, jnp.float32, groups=2)
+    assert fmt.joined and fmt.held_heads == kv + 1
+    rng = np.random.default_rng(kv)
+    k = jnp.asarray(rng.standard_normal((b, t, kv * 64)), jnp.float32)
+    empty = fmt.layer(fmt.zeros(b, 1), 0)
+    assert empty["k"].shape == (3, b, 16, (kv + 1) * 64)
+    whole = jax.jit(lambda layer: fmt.write_prefix(
+        layer, k, k + 1, fmt.prefill_slot(True, 1)))(empty)
+
+    @jax.jit
+    def pieces(layer):
+        for row in (0, 2):
+            layer = fmt.write_prefix(layer, k[row:row + 2], k[row:row + 2] + 1,
+                                     fmt.prefill_slot(True, 1, row))
+        return layer
+
+    @jax.jit
+    def one_by_one(layer):
+        for pos in range(t):
+            layer = fmt.write_position(
+                layer, fmt.rows(k[:, pos], k[:, pos] + 1), jnp.int32(pos),
+                group=jnp.int32(1))
+        return layer
+
+    for other in (pieces(empty), one_by_one(empty)):
+        for key in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(other[key]),
+                                          np.asarray(whole[key]))
+    for key, add in (("k", 0), ("v", 1)):
+        buf = np.asarray(whole[key])
+        assert not buf[..., kv * 64:].any()            # the phantom
+        assert not buf[0].any() and not buf[2].any()    # the other groups
+        np.testing.assert_array_equal(buf[1, :, :t, :kv * 64],
+                                      np.asarray(k) + add)
+    item = fmt.head_major({key: buf[1] for key, buf in whole.items()})
+    assert item["k"].shape == (b, kv, 16, 64)
+    np.testing.assert_array_equal(
+        np.asarray(item["k"])[:, :, :t],
+        np.asarray(k).reshape(b, t, kv, 64).transpose(0, 2, 1, 3))
+
+
+@pytest.fixture(scope="module")
+def model_of_64():
+    """GPT's blocks at GPT-2's head width: three heads of 64, an odd
+    count as GPT-2's 25 is."""
+    from defer_tpu.models.gpt import gpt
+    graph = gpt(2, 192, 3, 24, vocab=97)
+    return graph, graph.init(jax.random.key(5))
+
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_a_ring_step_over_heads_of_64_attends_over_joined_rows(model_of_64,
+                                                               num_stages):
+    """The lowered ring step of a GPT-2-shaped model: a layer is two
+    slices and one ``kv_attend`` — no ``kv_step``, no ``kv_write_rows``
+    — and the gauges say which kernel ran: ``decode.kv.joined_layers``
+    a stage's layers, ``decode.kv.fused_layers`` 0."""
+    from defer_tpu.obs.registry import REGISTRY
+    REGISTRY.gauge("decode.kv.fused_layers").set(-1)
+    REGISTRY.gauge("decode.kv.joined_layers").set(-1)
+    dec, jaxpr = _ring_step_jaxpr(model_of_64, num_stages)
+    assert dec.state_format.joined and dec.state_format.held_heads == 4
+    assert _kernel_names(jaxpr.jaxpr) == ["kv_attend"] * 2
+    assert REGISTRY.gauge("decode.kv.fused_layers").value == 0
+    assert REGISTRY.gauge("decode.kv.joined_layers").value == dec.l_max
+    assert str(jaxpr).count("dynamic_update_slice") >= 4
+
+
+def test_the_engine_over_heads_of_64_keeps_plain_rows_and_a_live_list(
+        model_of_64):
+    """The serving engine holds slots: a position a sequence, a list of
+    live sequences — what plain rows take and joined rows refuse.  Its
+    format of the same heads is plain, and its step is the row-writer a
+    buffer and ``kv_attend`` a layer, each with the list among its
+    scalar operands (group, positions, list)."""
+    graph, params = model_of_64
+    eng = ContinuousBatchEngine(graph, params, num_stages=1, width=3)
+    assert not eng.kv_format.joined
+    assert eng.kv_format.buffers(3)["k"].shape == (3, 3, 24, 64)
+    jaxpr = jax.make_jaxpr(eng._step_fn(False))(
+        eng.params, eng._caches, eng._prev_ids, *eng._blank_rows())
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield (eqn.params["name"],
+                       eqn.params["grid_mapping"].num_index_operands)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    assert list(calls(jaxpr.jaxpr)) == [
+        ("kv_write_rows", 3), ("kv_write_rows", 3), ("kv_attend", 3)] * 2
