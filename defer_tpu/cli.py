@@ -1326,9 +1326,11 @@ def _render_serve_stats(doc: dict) -> None:
         # attribution buckets (p50 ms per bucket, docs/OBSERVABILITY.md)
         buckets = attrib.get(name)
         if buckets and (buckets.get("e2e") or {}).get("count"):
+            # the block's own buckets, in timeline order: the tensor
+            # path's four, or a decode request's five
             parts = " ".join(
-                f"{k}={((buckets.get(k) or {}).get('p50', 0.0)):.2f}"
-                for k in ("admission", "gather", "chain", "result_edge"))
+                f"{k}={((b or {}).get('p50', 0.0)):.2f}"
+                for k, b in buckets.items() if k != "e2e")
             print(f"{'':>12}   p50ms: {parts} "
                   f"e2e={(buckets['e2e'].get('p50', 0.0)):.2f}")
 

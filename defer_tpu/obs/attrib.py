@@ -32,8 +32,9 @@ tolerance" acceptance predicate the smoke/bench assert.
 :class:`DoorAttribution` is the always-on, trace-free sibling: the
 front door feeds it four timestamps per delivered unit and it keeps
 per-tenant bucket histograms (admission / gather / chain / result
-edge) — the ``attribution`` block of the serve stats reply and the
-``monitor --serve --json`` lines.
+edge; a decode request's five stamps tile its own buckets, admission /
+join / first_token / tokens / result edge) — the ``attribution`` block
+of the serve stats reply and the ``monitor --serve --json`` lines.
 """
 
 from __future__ import annotations
@@ -216,6 +217,12 @@ def attribute_sampled(spans, *, hop_tiers=None) -> list[RequestAttribution]:
 
 #: the door-side (trace-free) bucket names, in timeline order
 DOOR_BUCKETS = ("admission", "gather", "chain", "result_edge")
+#: a decode request's: admitted -> popped by the engine's loop ->
+#: its prompt's pass begins to be launched -> its first generated id in
+#: host memory -> its last -> the answer written to the client
+#: (``serve/engine.py::Waypoints`` between the door's own two ends)
+DECODE_BUCKETS = ("admission", "join", "first_token", "tokens",
+                  "result_edge")
 
 
 class DoorAttribution:
@@ -225,37 +232,46 @@ class DoorAttribution:
     admitted -> popped (``admission``), popped -> submitted
     (``gather``), submitted -> demux receipt (``chain`` — everything
     inside the deployed chain), demux -> client bytes written
-    (``result_edge``).  No tracing required; this is what
-    ``monitor --serve`` renders and the stats reply carries."""
+    (``result_edge``).  A decode request has no frame and no chain: its
+    five stamps tile ``DECODE_BUCKETS`` (:meth:`record_decode`), and a
+    decode tenant's block holds those and no other.  No tracing
+    required; this is what ``monitor --serve`` renders and the stats
+    reply carries."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._tenants: dict[str, dict[str, LatencyHistogram]] = {}
 
-    def _hists(self, tenant: str) -> dict[str, LatencyHistogram]:
+    def _tile(self, tenant: str, buckets: tuple, stamps: tuple) -> None:
+        """One unit's ``len(buckets) + 1`` stamps (``perf_counter``
+        seconds, in timeline order) into the tenant's ``buckets`` and
+        ``e2e``; they sum to it.  Out-of-order stamps clamp to
+        zero-width buckets."""
         with self._lock:
             h = self._tenants.get(tenant)
             if h is None:
                 h = self._tenants[tenant] = {
-                    k: LatencyHistogram()
-                    for k in DOOR_BUCKETS + ("e2e",)}
-            return h
+                    k: LatencyHistogram() for k in buckets + ("e2e",)}
+        start = at = stamps[0]
+        for name, stamp in zip(buckets, stamps[1:]):
+            stamp = max(at, stamp)
+            h[name].record(stamp - at)
+            at = stamp
+        h["e2e"].record(at - start)
 
     def record(self, tenant: str, *, queued: float, popped: float,
                submitted: float, demuxed: float, delivered: float
                ) -> None:
-        """Fold one unit's timestamps (``perf_counter`` seconds) in.
-        Out-of-order stamps clamp to zero-width buckets."""
-        h = self._hists(tenant)
-        popped = max(queued, popped)
-        submitted = max(popped, submitted)
-        demuxed = max(submitted, demuxed)
-        delivered = max(demuxed, delivered)
-        h["admission"].record(popped - queued)
-        h["gather"].record(submitted - popped)
-        h["chain"].record(demuxed - submitted)
-        h["result_edge"].record(delivered - demuxed)
-        h["e2e"].record(delivered - queued)
+        """Fold one tensor unit's timestamps in."""
+        self._tile(tenant, DOOR_BUCKETS,
+                   (queued, popped, submitted, demuxed, delivered))
+
+    def record_decode(self, tenant: str, *, queued: float, popped: float,
+                      prefill: float, first: float, last: float,
+                      delivered: float) -> None:
+        """Fold one decode request's timestamps in."""
+        self._tile(tenant, DECODE_BUCKETS,
+                   (queued, popped, prefill, first, last, delivered))
 
     def summary(self) -> dict:
         """Per-tenant bucket summaries in milliseconds (JSON-ready):

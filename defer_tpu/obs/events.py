@@ -57,6 +57,11 @@ EVENT_KINDS = {
     "client_close": "a tenant connection finished or died (tenant)",
     "decode_join": "a decode request claimed an engine slot (rid)",
     "decode_cancel": "a decode request's slot was reclaimed (rid)",
+    "decode_done": "a decode request's answer was written to its client: "
+                   "its life in milliseconds from admitted (rid, tenant, "
+                   "prompt, new_tokens, popped_ms, prefill_ms, first_ms, "
+                   "last_ms, delivered_ms, forced_steps, pass_rounds, "
+                   "worst_gap_ms, first_step, last_step)",
     "model_drift": "a stage's measured service drifted from the cost "
                    "model's prediction (stage, rel_err)",
     "redial": "a connect_retry attempt failed and backed off "
@@ -78,7 +83,8 @@ EVENT_KINDS = {
     "mem_pressure": "live device-array bytes crossed the configured "
                     "threshold (bytes, threshold, live_arrays)",
     "host_pause": "a program phase took far longer than it usually does "
-                  "(layer, phase, round, wall_ms, typical_ms, cpu_ms, "
+                  "(layer, phase, round, passes where the phase keeps a "
+                  "typical time a count, wall_ms, typical_ms, cpu_ms, "
                   "proc_cpu_ms; since the thread's baseline: since_ms, "
                   "vol_switches, invol_switches, major_faults, "
                   "runq_wait_ms, steal_ms, gc_collections)",

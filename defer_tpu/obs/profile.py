@@ -56,7 +56,7 @@ import time
 
 from .events import emit as emit_event
 from .registry import REGISTRY
-from .trace import ANNOTATION_PREFIX, ROUND_ARGS
+from .trace import ANNOTATION_PREFIX, PAUSE_BEHIND_KEY, ROUND_ARGS
 
 #: the named phases of one frame through a stage node's compute loop,
 #: in wall order.  ``dispatch`` + ``queue`` + ``device`` + ``host_sync``
@@ -81,7 +81,10 @@ NODE_PHASES = ("dispatch", "queue", "device", "host_sync")
 #: launches it.  A busy period's first call holds the first two alone
 #: (nothing was in flight), its last the last three with no root (nothing
 #: is left to launch).  ``sync`` carries ``{"ahead": 0|1}``: whether a
-#: later step was running on the device under the wait.
+#: later step was running on the device under the wait; ``device``
+#: ``{"passes": k, "step": n}``: the joined prompts' passes launched in
+#: front of the step it waits for (the pause watch keeps the wait's
+#: typical time a count: ``_PhaseWatch.behind``) and that step's number.
 ENGINE_PHASES = ("gather", "dispatch", "device", "sync", "delivery")
 
 #: inside the engine's ``dispatch``, in wall order: ``upload`` is the
@@ -207,6 +210,10 @@ PAUSE_TIMES = 3.0
 #: one judged is the one after (a warm-up's compiling dispatch is the
 #: phase's first, and the median of these leaves it out)
 PAUSE_UNJUDGED_FIRST = 7
+#: a phase keeps a typical time for each count of what rode in front of
+#: it (``obs/trace.py::PAUSE_BEHIND_KEY``: the engine's ``device`` behind
+#: 0, 1, or this many and more of joined prompts' passes)
+PAUSE_BEHIND_MOST = 2
 
 #: the jax.monitoring duration event around ``compile_or_get_cached``
 #: (jax 0.9.0, ``pxla.py``): it fires once a program jax builds — never
@@ -649,6 +656,18 @@ class _PhaseWatch:
         self._first: list[float] = []
         self._streak = 0        # occurrences over PAUSE_TIMES x, in a row
 
+    def behind(self, count: int) -> "_PhaseWatch":
+        """The phase's watch for occurrences that waited behind ``count``
+        passes (at most ``PAUSE_BEHIND_MOST`` are told apart): itself for
+        none, else a watch of its own in the owner's table, so that a
+        wait behind a pass is compared with waits behind a pass — neither
+        a pause for being longer than a plain one, nor hiding one by
+        lifting the plain waits' typical time."""
+        if not count:
+            return self
+        return self._owner.phase(self.layer, self.phase,
+                                 min(count, PAUSE_BEHIND_MOST))
+
     def feed(self, sp, dur: float) -> None:
         """One occurrence's wall time, from the span ``sp`` at its exit.
         The estimate follows the recent occurrences (an eighth of the
@@ -705,17 +724,31 @@ class PauseWatcher:
         self._last_line = float("-inf")
         self._lacks: set = set()    # count sources this platform lacks
 
-    def phase(self, layer: str, phase: str) -> _PhaseWatch | None:
-        """The watch of ``<layer>.<phase>``; ``None`` for a phase that
-        is never judged (``PAUSE_UNJUDGED``, and every root)."""
+    def phase(self, layer: str, phase: str,
+              behind: int = 0) -> _PhaseWatch | None:
+        """The watch of ``<layer>.<phase>`` for occurrences ``behind``
+        that many passes (``_PhaseWatch.behind``; 0: the phase's plain
+        ones); ``None`` for a phase that is never judged
+        (``PAUSE_UNJUDGED``, and every root)."""
         root = SPAN_LAYERS[layer][1]
         if phase == root or phase in PAUSE_UNJUDGED.get(layer, ()):
             return None
+        key = (layer, phase, behind) if behind else (layer, phase)
         with self._lock:
-            w = self._watches.get((layer, phase))
+            w = self._watches.get(key)
             if w is None:
-                w = self._watches[layer, phase] = \
-                    _PhaseWatch(self, layer, phase)
+                w = self._watches[key] = _PhaseWatch(self, layer, phase)
+                if behind > 1:
+                    # rounds behind two passes are a handful a run, too
+                    # few to find a typical time among: it starts at the
+                    # waits behind one and a pass (what those take over
+                    # the plain waits) for each more, and is judged from
+                    # its first occurrence
+                    one = self._watches.get((layer, phase, 1))
+                    none = self._watches.get((layer, phase))
+                    if one and none and one.typ and none.typ:
+                        w.typ = one.typ + (behind - 1) * max(
+                            one.typ - none.typ, 0.0)
             return w
 
     def _thread_counts(self) -> dict:
@@ -789,6 +822,9 @@ class PauseWatcher:
         rnd = ROUND_ARGS.get(layer, {}).get(PAUSE_ROUND_KEY.get(layer))
         if rnd is not None:
             data["round"] = rnd
+        if sp.args and PAUSE_BEHIND_KEY in sp.args:
+            # the count whose typical time the occurrence was held to
+            data[PAUSE_BEHIND_KEY] = sp.args[PAUSE_BEHIND_KEY]
         base = self._bases().get(layer)
         if base is not None:
             t_base, then = base

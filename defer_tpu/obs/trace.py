@@ -387,6 +387,14 @@ _PROGRAM_SPANS: dict = {}
 #: any: the round a ``host_pause`` event names
 ROUND_ARGS: dict = {}
 
+#: the key of a span's ``args`` that counts what the device was given to
+#: run in front of what the span waits for (the engine's ``device``
+#: behind a joined prompt's pass): the pause watch keeps the phase's
+#: typical time a count, so such a wait is compared with its like
+#: (``obs/profile.py::_PhaseWatch.behind``).  A span that carries no
+#: ``args`` is not asked
+PAUSE_BEHIND_KEY = "passes"
+
 #: ``.at``: (perf_counter, thread CPU, process CPU) as the thread last
 #: read them.  The two CPU clocks are a system call each (0.35 us here,
 #: 6 us on the TPU host's sandboxed kernel: PERF.md section 6, PR 36), so
@@ -560,6 +568,8 @@ def span(layer: str, phase: str, args: dict | None = None) -> _ProgramSpan:
     which the profiler records only while a profiler session is live
     (it reads its own clock, the device trace's, right after each of the
     pair's reads).
+    ``args`` that hold ``PAUSE_BEHIND_KEY`` send the occurrence to the
+    phase's watch for that count.
     With the tracer off and no session this costs the two clock reads,
     one histogram record, one comparison, one inert annotation and,
     where the thread's last CPU reading is over 5 ms old (a long wait's
@@ -569,6 +579,8 @@ def span(layer: str, phase: str, args: dict | None = None) -> _ProgramSpan:
         or _program_span_entry(layer, phase)
     if args is not None:
         ROUND_ARGS[layer] = args
+        if watch is not None and PAUSE_BEHIND_KEY in args:
+            watch = watch.behind(args[PAUSE_BEHIND_KEY])
     return cls(name, ann_name, hist, watch, args)
 
 

@@ -12,6 +12,7 @@ the number closed-loop benchmarking structurally cannot see.
 
 from __future__ import annotations
 
+import socket
 import threading
 import time
 
@@ -97,7 +98,14 @@ class ServeClient:
         return dict(self.results)
 
     def abort(self) -> None:
-        """Cut the connection without an END (the disconnect tests)."""
+        """Cut the connection without an END (the disconnect tests).
+        Shut down first: a ``close`` alone sends nothing while this
+        client's own reader thread still waits on the socket, and the
+        door would decode the request to its end."""
+        try:
+            self._sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass        # the door hung up first
         self._sock.close()
 
     def stream(self, samples) -> list:

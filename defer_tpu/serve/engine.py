@@ -131,6 +131,10 @@ class DecodeRequest:
     #: set by the front door when the client disconnects while this
     #: request is still queued — the engine loop must not join it
     cancelled: bool = False
+    #: where the request's time in the engine went (:class:`Waypoints`):
+    #: set when its last token is delivered, before ``on_done``; ``None``
+    #: until then, and for ever on a cancelled request
+    waypoints: "Waypoints | None" = None
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)
@@ -140,8 +144,48 @@ class DecodeRequest:
             raise ValueError("max_new_tokens must be >= 1")
 
 
+class Waypoints:
+    """A finished request's life in the engine, on ``time.perf_counter``,
+    each stamp a clock read the loop makes anyway, taken where the thing
+    happens.  The door lays its own ends around them (``queued_pc``,
+    ``popped_at``, its ``done`` behind the socket write) and the six
+    tile the request's timeline (``obs/attrib.py::DECODE_BUCKETS``)."""
+
+    __slots__ = ("prefill_at", "first_at", "last_at", "rounds",
+                 "pass_rounds", "worst_gap", "first_step", "last_step",
+                 "forced_steps")
+
+    def __init__(self):
+        #: the ``engine.prefill`` span's ``t0``: the prompt's pass begins
+        #: to be launched (for a prompt of one token, which has no pass,
+        #: the ``engine.launch`` of the step that first takes the slot in)
+        self.prefill_at: float | None = None
+        #: the ``engine.sync`` span's ``t1`` of the step whose delivery
+        #: appended the first generated id, and of the one that delivered
+        #: the last: the instant the ids reached host memory
+        self.first_at: float | None = None
+        self.last_at: float | None = None
+        #: delivering steps from the first id to the last: the answer's
+        #: length
+        self.rounds = 0
+        #: of the ``rounds - 1`` behind the first id — the gaps between
+        #: two of the request's tokens — those whose flight had another
+        #: slot's pass launched in front of it (its own pass rides in
+        #: front of a round that is no gap), and the longest of them in
+        #: seconds (what ``step_s`` recorded for that step)
+        self.pass_rounds = 0
+        self.worst_gap = 0.0
+        #: ``engine.steps`` as the first and the last id were read: the
+        #: ``step`` of the ``engine.step`` span that launched each
+        self.first_step = self.last_step = -1
+        #: steps that fed the slot a prompt token and produced no
+        #: generated id: the tail of a prompt over ``PREFILL_POSITIONS``
+        #: + 1.  They lie between ``prefill_at`` and ``first_at``
+        self.forced_steps = 0
+
+
 class _Slot:
-    __slots__ = ("req", "pos", "prefill", "out", "cancelled")
+    __slots__ = ("req", "pos", "prefill", "out", "cancelled", "way")
 
     def __init__(self, req: DecodeRequest, prefill: int = 0):
         self.req = req
@@ -153,6 +197,7 @@ class _Slot:
         self.prefill = prefill
         self.out: list[int] = []   #: generated ids, as they are read
         self.cancelled = False
+        self.way = Waypoints()
 
     def steps_left(self) -> bool:
         """Whether a step is still to be launched for this slot: the
@@ -173,6 +218,10 @@ class _Flight:
     #: launched onto an empty queue; the step before's ids reaching the
     #: host, for one launched ahead (set when they do)
     since: float
+    #: prompts' passes launched in front of this step since the launch
+    #: before it: the device runs them first, so the round is that much
+    #: longer and the wait for its ids is no pause of the host
+    passes: int = 0
 
 
 class ContinuousBatchEngine:
@@ -268,6 +317,13 @@ class ContinuousBatchEngine:
             "serve.decode.prompt_tokens_prefilled")
         self._forced_count = REGISTRY.counter(
             "serve.decode.prompt_tokens_forced")
+        #: passes launched, and how many since the last step's launch;
+        #: the rounds whose flight held one, beside ``step_s``, which
+        #: keeps every round
+        self._pass_count = REGISTRY.counter("serve.decode.passes")
+        self._passes_ahead = 0
+        self._pass_round_hist = REGISTRY.histogram(
+            "serve.decode.pass_round_s")
 
     # -- state -------------------------------------------------------------
 
@@ -425,7 +481,7 @@ class ContinuousBatchEngine:
         fmt = self.kv_format
         embed, blocks_prefill = self._prefill_calls
         with span("engine", "prefill", {"step": self.steps, "slot": i,
-                                        "positions": n}):
+                                        "positions": n}) as launched:
             ids = np.zeros(self.prefill_len, np.int32)
             ids[:n] = s.req.prompt[:n]
             slot = jnp.int32(i)
@@ -438,7 +494,10 @@ class ContinuousBatchEngine:
                      for j in range(len(ops))], slot)
                 for l, layer in enumerate(layers, l0):
                     self._caches = fmt.with_layer(self._caches, l, layer)
+        s.way.prefill_at = launched.t0
         self._prefilled_count.n += n
+        self._pass_count.n += 1
+        self._passes_ahead += 1
 
     # -- one decode step ---------------------------------------------------
 
@@ -505,10 +564,13 @@ class ContinuousBatchEngine:
                 [i for i, _ in rows])
             sample = False
             fed = []
+            passless = []   # slots this step takes in without a pass
             for i, s in rows:
                 if s.pos < s.req.prompt.size:
                     host_ids[i] = s.req.prompt[s.pos]
                     self._forced_count.n += 1
+                    if s.way.prefill_at is None:
+                        passless.append(s.way)
                 else:
                     # the step before this one sampled it: every launch
                     # since the slot's first has held the slot
@@ -526,11 +588,15 @@ class ContinuousBatchEngine:
             with span("engine", "upload"):
                 up = jax.device_put((host_ids, from_host, pos, seeds, temps,
                                      live))
-            with span("engine", "launch"):
+            with span("engine", "launch") as launched:
                 self._prev_ids, self._caches = self._step_fn(sample)(
                     self.params, self._caches, self._prev_ids, *up)
             del up      # while the device runs the step, not at the next
-        self._flight = _Flight(self._prev_ids, fed, dispatched.t0)
+        for way in passless:
+            way.prefill_at = launched.t0
+        self._flight = _Flight(self._prev_ids, fed, dispatched.t0,
+                               self._passes_ahead)
+        self._passes_ahead = 0
 
     def _deliver(self, flight: _Flight
                  ) -> list[tuple[DecodeRequest, np.ndarray]]:
@@ -542,28 +608,51 @@ class ContinuousBatchEngine:
         (per-slot bookkeeping + on_done).  ``step_s`` records the round:
         from the step before's ids reaching the host to this step's —
         what a token costs a live slot — and launch to ids for a step
-        launched onto an empty queue."""
+        launched onto an empty queue; ``pass_round_s`` the rounds whose
+        flight had a prompt's pass in front of it.  The instant the ids
+        reached the host (``sync``'s end) is every live row's stamp for
+        this step (:class:`Waypoints`): no clock is read a row."""
         later = self._flight    # the step launched since, if any
-        with span("engine", "device"):
+        step, passes = self.steps, flight.passes
+        # the wait behind a pass is kept apart from the plain waits by
+        # the pause watch: a join is no pause of the host
+        with span("engine", "device", {"passes": passes, "step": step}):
             flight.ids.block_until_ready()
         with span("engine", "sync", {"ahead": int(later is not None)}) \
                 as synced:
             next_ids = np.asarray(flight.ids)
-        self._step_hist.record(synced.t1 - flight.since)
+        at = synced.t1
+        gap = at - flight.since
+        self._step_hist.record(gap)
+        if passes:
+            self._pass_round_hist.record(gap)
         if later is not None:
-            later.since = synced.t1
+            later.since = at
         self.steps += 1
         done: list[tuple[DecodeRequest, np.ndarray]] = []
         with span("engine", "delivery"):
             for i, s, fed in flight.rows:
                 if s.cancelled:     # left while the step ran
                     continue
+                way = s.way
                 # the step consumed position ``fed``; the token it
                 # produced sits behind it, generated iff past the prompt
                 if fed + 1 >= s.req.prompt.size:
                     s.out.append(int(next_ids[i]))
                     self._tok_count.n += 1
+                    if way.rounds:      # a gap between two of its tokens
+                        if passes:
+                            way.pass_rounds += 1
+                        if gap > way.worst_gap:
+                            way.worst_gap = gap
+                    else:
+                        way.first_at, way.first_step = at, step
+                    way.rounds += 1
+                else:
+                    way.forced_steps += 1
                 if len(s.out) >= s.req.max_new_tokens:
+                    way.last_at, way.last_step = at, step
+                    s.req.waypoints = way
                     result = np.concatenate(
                         [s.req.prompt.astype(np.int64),
                          np.asarray(s.out, np.int64)])

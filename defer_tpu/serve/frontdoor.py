@@ -339,8 +339,10 @@ class ServeFrontDoor:
                                   gather_s=gather_s)
         self.decode_defaults = dict(decode_defaults or {})
         #: always-on per-tenant latency-attribution buckets (admission /
-        #: gather / chain / result edge — docs/OBSERVABILITY.md); rides
-        #: the stats reply for ``monitor --serve``
+        #: gather / chain / result edge; a decode request's admission /
+        #: join / first_token / tokens / result edge —
+        #: docs/OBSERVABILITY.md); rides the stats reply for
+        #: ``monitor --serve``
         self.attrib = DoorAttribution()
         self._clients: list[_Client] = []
         self._lock = threading.Lock()
@@ -674,19 +676,41 @@ class ServeFrontDoor:
             except OSError as e:
                 self._disconnect(client, e)
                 return
-            done = time.perf_counter()
-            queued_pc = getattr(req, "queued_pc", done)
-            popped = getattr(req, "popped_at", None)
-            if popped is None:
-                popped = done
-            # decode buckets: admission = queue wait, chain = the
-            # engine's whole-request residency (its pipeline stages are
-            # in-process; no per-stage frame path to decompose)
-            self.admission.record_slo(client.tenant, done - queued_pc)
-            self.attrib.record(client.tenant, queued=queued_pc,
-                               popped=popped, submitted=popped,
-                               demuxed=done, delivered=done)
+            self._record_decode(client.tenant, req, time.perf_counter())
         self._maybe_drained(client)
+
+    def _record_decode(self, tenant: str, req: DecodeRequest,
+                       done: float) -> None:
+        """A delivered decode request's timeline, ``done`` behind its
+        answer's write: the engine's waypoints between the door's own
+        ends tile the tenant's decode buckets (``obs/attrib.py``), and
+        one ``decode_done`` event keeps the request's own numbers."""
+        # both set where the request was made (_make_decode_request);
+        # the engine's loop stamps the second as it pops
+        queued = req.queued_pc
+        popped = req.popped_at if req.popped_at is not None else queued
+        way = req.waypoints
+        self.admission.record_slo(tenant, done - queued)
+        self.attrib.record_decode(
+            tenant, queued=queued, popped=popped, prefill=way.prefill_at,
+            first=way.first_at, last=way.last_at, delivered=done)
+        # admitted -> first generated id in host memory: the one number
+        # no single bucket holds (admission + join + first_token)
+        REGISTRY.histogram("serve.decode.first_token_s").record(
+            way.first_at - queued)
+
+        def ms(at):
+            return round((at - queued) * 1e3, 4)
+
+        emit_event(
+            "decode_done", rid=req.request_id, tenant=tenant,
+            prompt=int(req.prompt.size), new_tokens=req.max_new_tokens,
+            popped_ms=ms(popped), prefill_ms=ms(way.prefill_at),
+            first_ms=ms(way.first_at), last_ms=ms(way.last_at),
+            delivered_ms=ms(done), forced_steps=way.forced_steps,
+            pass_rounds=way.pass_rounds,
+            worst_gap_ms=round(way.worst_gap * 1e3, 4),
+            first_step=way.first_step, last_step=way.last_step)
 
     def _maybe_drained(self, client: _Client) -> None:
         with client.state:
@@ -866,10 +890,11 @@ class ServeFrontDoor:
                 **{name: REGISTRY.counter(f"serve.decode.{name}").value
                    for name in ("tokens", "prompt_tokens_prefilled",
                                 "prompt_tokens_forced", "ahead.launched",
-                                "rows.launched")},
+                                "rows.launched", "passes")},
                 **{f"{name}_s": REGISTRY.histogram(
                     f"serve.decode.{name}_s").summary()
-                   for name in ("step",) + ENGINE_LOOP_PHASES},
+                   for name in ("step", "pass_round") + ENGINE_LOOP_PHASES
+                   + ("first_token",)},
             }
         setup = setup_breakdown()
         if setup is not None:

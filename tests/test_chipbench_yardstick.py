@@ -22,6 +22,7 @@ from test_idle import *  # noqa: E402,F401,F403
 from test_manifest import *  # noqa: E402,F401,F403
 from test_pipe_readers import *  # noqa: E402,F401,F403
 from test_readings import *  # noqa: E402,F401,F403
+from test_request_readers import *  # noqa: E402,F401,F403
 from test_setup_readers import *  # noqa: E402,F401,F403
 from test_spans import *  # noqa: E402,F401,F403
 from test_steady import *  # noqa: E402,F401,F403
