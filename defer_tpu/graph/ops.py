@@ -545,6 +545,23 @@ class TransformerBlock(Op):
         att = jax.nn.softmax(att, axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", att, v)
 
+    def _attend_columns(self, q, k, v):
+        """Attention on the projection's own columns — ``q`` [b, t,
+        nh*hd], ``k`` / ``v`` [b, t, kv*hd] — with the heads merged
+        again, [b, t, nh*hd]: the heads split out, :meth:`_attend`."""
+        b, t, d = q.shape
+        nh, kvh = self.num_heads, self._kv_head_count()
+        hd = d // nh
+        qh = q.reshape(b, t, nh, hd).transpose(0, 2, 1, 3)
+        kh = k.reshape(b, t, kvh, hd).transpose(0, 2, 1, 3)
+        vh = v.reshape(b, t, kvh, hd).transpose(0, 2, 1, 3)
+        if kvh != nh:
+            # broadcast each KV head over its query group (exact GQA)
+            kh = jnp.repeat(kh, nh // kvh, axis=1)
+            vh = jnp.repeat(vh, nh // kvh, axis=1)
+        y = self._attend(qh, kh, vh)
+        return y.transpose(0, 2, 1, 3).reshape(b, t, d)
+
     def _split_qkv(self, qkv):
         """q/k/v column split of the fused projection (subclass hook)."""
         return jnp.split(qkv, 3, axis=-1)
@@ -566,25 +583,13 @@ class TransformerBlock(Op):
         narrows them).
         """
         p = _cast(params, x.dtype)
-        b, t, d = x.shape
-        nh = self.num_heads
-        hd = d // nh
-        kvh = self._kv_head_count()
         eps = self.ln_eps
         post = self.norm == "post"  # validated in __post_init__
 
         y = x if post else self._ln(p["ln1"], x, eps)
         qkv = y @ p["qkv"]["w"] + p["qkv"]["b"]
         q, k, v = self._split_qkv(qkv)
-        qh = q.reshape(b, t, nh, hd).transpose(0, 2, 1, 3)
-        kh = k.reshape(b, t, kvh, hd).transpose(0, 2, 1, 3)
-        vh = v.reshape(b, t, kvh, hd).transpose(0, 2, 1, 3)
-        if kvh != nh:
-            # broadcast each KV head over its query group (exact GQA)
-            kh = jnp.repeat(kh, nh // kvh, axis=1)
-            vh = jnp.repeat(vh, nh // kvh, axis=1)
-        y = self._attend(qh, kh, vh)
-        y = y.transpose(0, 2, 1, 3).reshape(b, t, d)
+        y = self._attend_columns(q, k, v)
         y = y @ p["proj"]["w"] + p["proj"]["b"]
         x = self._ln(p["ln1"], x + y, eps) if post else x + y
 

@@ -82,6 +82,19 @@ class CausalTransformerBlock(DecoderBlock, TransformerBlock):
     def _kv_head_count(self) -> int:
         return self.kv_heads
 
+    def _attend_columns(self, q, k, v):
+        """Under ``"flash"`` the kernels take the projection's columns
+        as they lie, token-major (``ops/flash_attention.py::
+        flash_causal_columns``: heads under a lane row wide are read
+        side by side, nothing transposed or padded around the call;
+        any other geometry is laid head-major there); plain XLA splits
+        the heads out (the base's)."""
+        if self._attention_impl() == "flash":
+            from ..ops.flash_attention import flash_causal_columns
+            return flash_causal_columns(q, k, v, heads=self.num_heads,
+                                        kv_heads=self.kv_heads)
+        return super()._attend_columns(q, k, v)
+
     def flops(self, in_specs, out_spec):
         # base formula assumes a 3d-wide qkv projection; GQA narrows it
         (spec,) = in_specs
@@ -92,7 +105,7 @@ class CausalTransformerBlock(DecoderBlock, TransformerBlock):
 
     # apply/apply_with_kv are inherited: the base TransformerBlock forward
     # (graph/ops.py) is the single implementation, made causal here purely
-    # through DecoderBlock's _attend.  apply_with_kv's K/V columns
+    # through _attend_columns and DecoderBlock's _attend.  apply_with_kv's K/V columns
     # match what decode_qkv hands over row by row (pre-head-split qkv
     # projections), so pipelined prefill bulk-writes cache rows 0..t-1
     # and decoding continues at t.

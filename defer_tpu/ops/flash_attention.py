@@ -25,6 +25,36 @@ Pallas call is named by kind, for a device trace's readers:
 ``flash_causal`` (full heads, no window: GPT-2's and OLMoE's prompts),
 ``flash_grouped``, ``flash_band`` (a window, grouped or not).
 
+**Which calls are padded, and which read the operand as it lies.**
+``flash_attention`` takes heads laid head-major, ``[b, h, t, d]``, and
+pads them in HBM: rows to a whole block, a head's width to the 128
+lanes (zeros add nothing to a score, the output's padding is sliced
+off).  At heads of 128 on prompts of whole blocks that is nothing; at
+GPT-2's 25 heads of 64 on 896 rows it was 2.3x the bytes, three pads,
+four transposes and a slice a layer around a kernel that fetched the
+zeros (PERF.md PR 58 / PR 70).  :func:`flash_causal_columns` is the
+entry for a projection's own columns, ``[b, t, heads * d]``: full,
+unwindowed heads that are a whole fraction of a lane row wide (``d <
+128``, ``128 % d == 0``) go to :func:`_lane_row_kernel` *token-major,
+unpadded* — grid ``(batch, lane rows, pairs)``, a block ``[block, 128]``
+columns of the operand, ``128 // d`` heads side by side, each with its
+own statistics (their queries stacked into one ``[heads * block_q,
+128]`` operand, the other heads' columns zeroed: one product a pair,
+the contraction a padded head costs and no more), the output written
+with the heads already merged.  Every other geometry is laid head-major
+by that entry and takes the padded path: one algorithm whose block holds
+``128 // d`` heads, 1 for heads of 128.  The gauge
+``prefill.flash.heads_a_block`` says which.  **The out-of-bounds rule
+an edge keeps:** what an unpadded operand's block holds beyond the
+array — the last lane row of an odd head count (25 heads are 12 lane
+rows and a half), the rows past the prompt's end in its last block — is
+undefined and may be NaN, and ``0 * NaN`` is NaN.  So masking scores is
+not enough: the keys' dead columns are zeroed before the contraction
+(every pair of the last lane row), the values' dead rows before the
+second product (an edge pair), and a dead query row or column only ever
+reaches output rows and columns that lie beyond the array and are not
+written back.
+
 Non-causal calls (the encoder blocks of ``graph/ops.py``; full heads
 only) keep the first kernel, :func:`_attn_kernel`.  Tiling: grid =
 (batch*heads, Tq/block_q, Tk/block_k), the whole rectangle, with the K
@@ -41,8 +71,9 @@ A latent-attention layer's prompt takes a third kernel,
 one over a key every head shares, and the value is narrower than the
 key.
 
-**How a grid step of the two causal kernels finds its pair.**  Their
-grid is ``(batch, heads, pairs)``: the last axis walks the (query
+**How a grid step of the causal kernels finds its pair.**  Their
+grid is ``(batch, heads, pairs)`` (``(batch, lane rows, pairs)``
+token-major): the last axis walks the (query
 block, key block) pairs that hold an allowed (query, key) — the causal
 triangle, cut by the window where there is one — and no others, so a
 call's steps are its live blocks (136 a head at 8192 / 512, where the
@@ -54,12 +85,13 @@ query block's first, its last, an edge that needs the mask); the table
 goes in as three scalar-prefetched int32 rows, the index maps read
 ``qi[step]`` / ``kb[step]`` to name the blocks to fetch, and the kernel
 reads the same column: it starts the statistics on a first pair and
-writes the output on a last.  Both kernels form and mask their own
+writes the output on a last.  The kernels form and mask their own
 scores and hand them to one :func:`_block_update`, whose statistics
 stay a whole lane tile wide (a row's max on every lane, the sum a lane:
 one cross-lane reduction a block, none of a single lane's broadcasts).
-Two gauges, ``prefill.flash.grid_steps`` and ``.live_steps``, hold the
-newest traced call's steps and those of them that work.
+Three gauges, ``prefill.flash.grid_steps``, ``.live_steps`` and
+``.heads_a_block``, hold the newest traced call's steps, those of them
+that work and the heads one block holds.
 
 On non-TPU backends (CPU tests) the same kernel runs in interpreter mode, so
 there is exactly one implementation of the math.  On a TPU backend it is
@@ -188,17 +220,20 @@ def live_pairs(t_q: int, t_k: int, block_q: int, block_k: int,
     return np.asarray(cols, np.int32).T
 
 
-def _paired_call(kernel, name, operands, *, sizes, in_specs, out_spec,
-                 out_shape, interpret):
+def _paired_call(kernel, name, operands, *, sizes, lead, dv, in_specs,
+                 out_spec, out_shape, interpret, heads=1):
     """A causal kernel called over the live pairs of ``sizes`` =
-    ``(t_q, t_k, block_q, block_k, window)``: grid ``(batch, heads,
-    pairs)``, :func:`live_pairs`' table handed over as three
-    scalar-prefetched rows, which every index map (``(bi, hi, step, qi,
-    kb, bits)``) and the kernel read at column ``step``; sets the two
-    gauges of the newest traced call."""
+    ``(t_q, t_k, block_q, block_k, window)``: grid ``lead + (pairs,)``,
+    ``lead`` the call's ``(batch, heads)`` — of a token-major call
+    ``(batch, lane rows)``, ``heads`` heads a block, their statistics
+    and their sums of ``dv`` columns stacked in the scratch —,
+    :func:`live_pairs`' table handed over as three scalar-prefetched
+    rows, which every index map (``(bi, hi, step, qi, kb, bits)``) and
+    the kernel read at column ``step``; sets the three gauges of the
+    newest traced call."""
     t_q, t_k, block_q, block_k, _ = sizes
     pairs = live_pairs(*sizes)
-    (b, h), n = out_shape.shape[:2], pairs.shape[1]
+    (b, h), n = lead, pairs.shape[1]
     # :func:`_block_update`'s statistics: rows of a lane tile, or of a
     # whole key block where that is narrower or no multiple of one
     w = _LANES if block_k % _LANES == 0 else block_k
@@ -206,15 +241,17 @@ def _paired_call(kernel, name, operands, *, sizes, in_specs, out_spec,
     # a query block ahead of every key is listed and holds nothing
     REGISTRY.gauge("prefill.flash.live_steps").set(
         b * h * (n - max(t_q - t_k, 0) // block_q))
+    REGISTRY.gauge("prefill.flash.heads_a_block").set(heads)
+    rows = heads * block_q
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b, h, n), in_specs=in_specs,
             out_specs=out_spec,
             scratch_shapes=[
-                pltpu.VMEM((block_q, w), jnp.float32),   # running max
-                pltpu.VMEM((block_q, w), jnp.float32),   # running sum a lane
-                pltpu.VMEM((block_q, out_shape.shape[-1]), jnp.float32)]),
+                pltpu.VMEM((rows, w), jnp.float32),   # running max
+                pltpu.VMEM((rows, w), jnp.float32),   # running sum a lane
+                pltpu.VMEM((rows, dv), jnp.float32)]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary")),
@@ -241,7 +278,7 @@ def _block_update(s, v, m_ref, l_ref, acc_ref):
     a multiple of it: ``m_ref [block_q, w]`` holds a row's running max
     on every lane, ``l_ref [block_q, w]`` the running sum *a lane* —
     lane ``j`` sums the keys ``j, w + j, ...`` of every block, rescaled
-    with the rest, and the lanes are added once, in :func:`_finish` —,
+    with the rest, and the lanes are added once, in :func:`_normed` —,
     ``acc_ref [block_q, dv]`` the values' sum.  So a block pays one
     cross-lane reduction (its max, after the column groups are folded
     elementwise) and nothing is broadcast from a single lane."""
@@ -277,38 +314,56 @@ def _start(m_ref, l_ref, acc_ref):
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def _finish(o_ref, l_ref, acc_ref):
+def _normed(l_ref, acc_ref):
+    """The values' sum over the rows' sums: a query block's output."""
     l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
-    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+    return acc_ref[...] / jnp.maximum(l, 1e-20)
 
 
-def _masked(s, qi, kb, t_q: int, t_k: int, window):
+def _masked(s, qi, kb, t_q: int, t_k: int, window, heads: int = 1):
     """An edge pair's scores ``s`` (query block ``qi``, key block
-    ``kb``) with ``-inf`` where :func:`live_pairs`' rule forbids the
-    (query, key) or the key is padding."""
-    k_pos = kb * s.shape[1] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    q_pos = qi * s.shape[0] + (t_k - t_q) + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 0)
+    ``kb``; ``heads`` heads' rows stacked) with ``-inf`` where
+    :func:`live_pairs`' rule forbids the (query, key) or the key is
+    padding, or lies beyond the operand."""
+    shape = (s.shape[0] // heads, s.shape[1])
+    k_pos = kb * shape[1] + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    q_pos = qi * shape[0] + (t_k - t_q) + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0)
     mask = jnp.logical_and(k_pos < t_k, q_pos >= k_pos)
     if window is not None:
         mask = jnp.logical_and(mask, q_pos - k_pos < window)
+    if heads > 1:
+        mask = jnp.concatenate([mask] * heads, axis=0)
     return jnp.where(mask, s, _NEG_INF)
 
 
-def _pair_step(table, scores, v_ref, o_ref, scratch, *, t_q, t_k, window):
+def _pair_step(table, scores, values, write, scratch, *, t_q, t_k, window,
+               heads=1):
     """One grid step of a causal kernel: the step's pair from the
     ``table`` (the three prefetched rows), the statistics started on a
     query block's first pair, the kernel's own ``scores()`` (f32,
-    ``[block_q, block_k]``), masked on an edge, through
-    :func:`_block_update`, the output written on the last pair."""
+    ``[heads * block_q, block_k]``), masked on an edge, through
+    :func:`_block_update` with its ``values(kb)`` (``kb`` None, or on
+    an edge the key block: an operand that is not padded leaves what it
+    likes in a block's rows beyond ``t_k``, and the kernel zeroes
+    them), the query block's output handed to ``write`` on the last
+    pair."""
     qi, kb, bits = (row[pl.program_id(2)] for row in table)
     pl.when(bits & _FIRST != 0)(lambda: _start(*scratch))
     pl.when(bits & _EDGE == 0)(
-        lambda: _block_update(scores(), v_ref[0, 0], *scratch))
+        lambda: _block_update(scores(), values(None), *scratch))
     pl.when(bits & _EDGE != 0)(
-        lambda: _block_update(_masked(scores(), qi, kb, t_q, t_k, window),
-                              v_ref[0, 0], *scratch))
-    pl.when(bits & _LAST != 0)(lambda: _finish(o_ref, *scratch[1:]))
+        lambda: _block_update(
+            _masked(scores(), qi, kb, t_q, t_k, window, heads),
+            values(kb), *scratch))
+    pl.when(bits & _LAST != 0)(lambda: write(_normed(*scratch[1:])))
+
+
+def _store(o_ref):
+    """``write`` of a kernel whose output block is one head's."""
+    def write(y):
+        o_ref[0, 0] = y.astype(o_ref.dtype)
+    return write
 
 
 def _band_kernel(qi_ref, kb_ref, bits_ref, q_ref, k_ref, v_ref, o_ref,
@@ -322,8 +377,13 @@ def _band_kernel(qi_ref, kb_ref, bits_ref, q_ref, k_ref, v_ref, o_ref,
             q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [bq, bk]
 
-    _pair_step((qi_ref, kb_ref, bits_ref), scores, v_ref, o_ref, scratch,
-               **band)
+    _pair_step((qi_ref, kb_ref, bits_ref), scores, lambda kb: v_ref[0, 0],
+               _store(o_ref), scratch, **band)
+
+
+def _clamped(block: int, t: int) -> int:
+    """A block of ``block`` rows, or the power of two that holds ``t``."""
+    return min(block, max(8, 1 << (t - 1).bit_length()))
 
 
 def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
@@ -333,8 +393,7 @@ def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
     hkv, t_k = k.shape[1], k.shape[2]
     if h % hkv:
         raise ValueError(f"{hkv} KV heads do not divide {h} query heads")
-    block_q = min(block_q, max(8, 1 << (t_q - 1).bit_length()))
-    block_k = min(block_k, max(8, 1 << (t_k - 1).bit_length()))
+    block_q, block_k = _clamped(block_q, t_q), _clamped(block_k, t_k)
     qp = _pad_to(_pad_to(q, 2, block_q), 3, _LANES)
     kp = _pad_to(_pad_to(k, 2, block_k), 3, _LANES)
     vp = _pad_to(_pad_to(v, 2, block_k), 3, _LANES)
@@ -347,6 +406,7 @@ def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
         "flash_band" if window is not None else
         "flash_grouped" if h != hkv else "flash_causal",
         (qp, kp, vp), sizes=(t_q, t_k, block_q, block_k, window),
+        lead=(b, h), dv=dp,
         in_specs=[pl.BlockSpec((1, 1, block_q, dp), _q_block),
                   pl.BlockSpec((1, 1, block_k, dp), kv_block),
                   pl.BlockSpec((1, 1, block_k, dp), kv_block)],
@@ -354,6 +414,146 @@ def _band_attention(q, k, v, *, window, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         interpret=interpret)
     return out[:, :, :t_q, :d]
+
+
+#: rows of a token-major block's scores, its heads' stacked: twice
+#: :data:`_BAND_BLOCK` (two heads of 64 a lane row), so that narrower
+#: heads' blocks hold fewer queries and no more fast memory
+_STACK_ROWS = 2 * _BAND_BLOCK
+
+
+def _lane_row_kernel(qi_ref, kb_ref, bits_ref, q_ref, k_ref, v_ref, o_ref,
+                     *scratch, scale, d, cols, t_q, t_k):
+    """One (query block, key block) pair of one *lane row* of a
+    token-major call: ``128 // d`` heads side by side in the 128 columns
+    of q_ref / o_ref ``[1, block_q, 128]`` and k_ref / v_ref ``[1,
+    block_k, 128]``, blocks of operands ``[b, t, cols]`` that nothing
+    pads — what a block holds beyond ``cols`` columns (the last lane row
+    of an odd head count) or beyond ``t`` rows is undefined, may be NaN,
+    and ``0 * NaN`` is NaN: the keys' dead columns and the values' dead
+    rows are zeroed, not only their scores masked.  A head's queries
+    are the block's with the other heads' columns zeroed, so its scores
+    are one contraction over the lane row (what a head of 64 padded to
+    128 costs the matrix unit, no more); the heads' queries are stacked
+    into one ``[heads * block_q, 128]`` operand, so a pair is one
+    product, one :func:`_block_update` over the stacked rows and one
+    product with the values, whose ``[heads * block_q, 128]`` sum holds
+    head ``j``'s output in rows ``j`` and columns ``j``."""
+    heads, (block_q, block_k) = _LANES // d, (q_ref.shape[1], k_ref.shape[1])
+    ragged = cols % _LANES != 0
+    # the lane row's real columns: all 128 but for the last's
+    live = cols - pl.program_id(1) * _LANES
+
+    def lane(rows):
+        return jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+
+    def scores():
+        q, k, at = q_ref[0], k_ref[0], lane(block_q)
+        if ragged:
+            k = jnp.where(lane(block_k) < live, k, jnp.zeros_like(k))
+        stack = []      # head j: its own columns, the others' zero
+        for j in range(heads):
+            own = jnp.logical_and(at >= j * d, at < (j + 1) * d)
+            if ragged:
+                own = jnp.logical_and(own, at < live)
+            stack.append(jnp.where(own, q, jnp.zeros_like(q)))
+        return jax.lax.dot_general(
+            jnp.concatenate(stack, axis=0), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [heads * bq, bk]
+
+    def values(kb):
+        v = v_ref[0]
+        if kb is None:
+            return v
+        row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        return jnp.where(row < t_k - kb * block_k, v, jnp.zeros_like(v))
+
+    def write(y):
+        at, out = lane(block_q), y[:block_q]
+        for j in range(1, heads):
+            out = jnp.where(at >= j * d, y[j * block_q:(j + 1) * block_q],
+                            out)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+    _pair_step((qi_ref, kb_ref, bits_ref), scores, values, write, scratch,
+               t_q=t_q, t_k=t_k, window=None, heads=heads)
+
+
+def _lane_row_attention(q, k, v, *, d, t_q, t_k, cols, block_q, block_k,
+                        interpret):
+    """:func:`flash_causal_columns`' own path: ``q`` ``[b, >= t_q, >=
+    cols]``, ``k`` / ``v`` ``[b, >= t_k, >= cols]`` token-major,
+    ``cols`` columns of heads of ``d``; what an operand holds beyond
+    the sizes named is never read into a result."""
+    heads = _LANES // d
+    # the fewest query blocks that hold the prompt, all alike (896 rows:
+    # two of 448, where two of 512 work on 128 rows that are not there;
+    # 0.71 against 0.80 ms a call on the v5e, PERF.md PR 70), in whole
+    # bf16 tiles; a key block's rows are the scores' lanes, and stay a
+    # power of two
+    block_q = min(block_q, _STACK_ROWS // heads)
+    block_q = -(-t_q // (16 * -(-t_q // block_q))) * 16
+    block_k = _clamped(block_k, t_k)
+    b, lane_rows = q.shape[0], -(-cols // _LANES)
+
+    def q_block(bi, li, step, qi, kb, bits):
+        return (bi, qi[step], li)
+
+    def k_block(bi, li, step, qi, kb, bits):
+        return (bi, kb[step], li)
+
+    return _paired_call(
+        functools.partial(_lane_row_kernel, scale=1.0 / math.sqrt(d), d=d,
+                          cols=cols, t_q=t_q, t_k=t_k),
+        "flash_causal", (q, k, v),
+        sizes=(t_q, t_k, block_q, block_k, None), lead=(b, lane_rows),
+        dv=_LANES, heads=heads,
+        in_specs=[pl.BlockSpec((1, block_q, _LANES), q_block),
+                  pl.BlockSpec((1, block_k, _LANES), k_block),
+                  pl.BlockSpec((1, block_k, _LANES), k_block)],
+        out_spec=pl.BlockSpec((1, block_q, _LANES), q_block),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "kv_heads", "window", "block_q", "block_k", "interpret"))
+def flash_causal_columns(q, k, v, *, heads: int, kv_heads: int | None = None,
+                         window: int | None = None,
+                         block_q: int | None = None,
+                         block_k: int | None = None,
+                         interpret: bool | None = None):
+    """Causal attention on a projection's own columns, token-major:
+    ``q`` ``[b, t_q, heads * d]``, ``k`` / ``v`` ``[b, t_k, kv_heads *
+    d]``, returns ``[b, t_q, heads * d]``, the heads merged — what
+    :func:`flash_attention` ``(causal=True)`` returns for the same
+    heads laid head-major, bottom-right aligned where ``t_q != t_k``.
+
+    Where the heads are full, unwindowed and a whole fraction of a lane
+    row wide (``d < 128``, ``128 % d == 0``: GPT-2's 64), the kernel
+    reads the columns as they lie: a block is a lane row, ``128 // d``
+    heads side by side (:func:`_lane_row_kernel`), and no head-major
+    copy, no lane or row pad and no slice of a padded output exists.
+    Every other call — heads of 128, grouped queries, a window — is
+    laid head-major and takes :func:`flash_attention`'s path, a head a
+    block.  The shapes decide, nothing else."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    kv_heads = kv_heads or heads
+    (b, t_q, cols), t_k = q.shape, k.shape[1]
+    d = cols // heads
+    block_q, block_k = block_q or _BAND_BLOCK, block_k or _BAND_BLOCK
+    if heads == kv_heads and window is None and d < _LANES \
+            and _LANES % d == 0:
+        return _lane_row_attention(
+            q, k, v, d=d, t_q=t_q, t_k=t_k, cols=cols, block_q=block_q,
+            block_k=block_k, interpret=interpret)
+    qh = q.reshape(b, t_q, heads, d).transpose(0, 2, 1, 3)
+    kh, vh = (a.reshape(b, t_k, kv_heads, d).transpose(0, 2, 1, 3)
+              for a in (k, v))
+    y = _band_attention(qh, kh, vh, window=window, block_q=block_q,
+                        block_k=block_k, interpret=interpret)
+    return y.transpose(0, 2, 1, 3).reshape(b, t_q, cols)
 
 
 def _latent_kernel(qi_ref, kb_ref, bits_ref, qn_ref, qr_ref, kn_ref, kr_ref,
@@ -374,8 +574,8 @@ def _latent_kernel(qi_ref, kb_ref, bits_ref, qn_ref, qr_ref, kn_ref, kr_ref,
                                       preferred_element_type=jnp.float32)
                 ) * scale                                 # [block, block]
 
-    _pair_step((qi_ref, kb_ref, bits_ref), scores, v_ref, o_ref, scratch,
-               t_q=t, t_k=t, window=None)
+    _pair_step((qi_ref, kb_ref, bits_ref), scores, lambda kb: v_ref[0, 0],
+               _store(o_ref), scratch, t_q=t, t_k=t, window=None)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
@@ -397,13 +597,14 @@ def flash_latent(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
         interpret = jax.default_backend() != "tpu"
     b, h, t, dn = q_nope.shape
     dr, dv = q_rope.shape[-1], v.shape[-1]
-    block = min(block or _BAND_BLOCK, max(8, 1 << (t - 1).bit_length()))
+    block = _clamped(block or _BAND_BLOCK, t)
     qn, qr, kn, kr, vp = (_pad_to(a, 2, block)
                           for a in (q_nope, q_rope, k_nope, k_rope, v))
     own, shared = _k_block(1), _k_block(h)    # the one rotated key: head 0
     out = _paired_call(
         functools.partial(_latent_kernel, scale=scale, t=t), "flash_latent",
         (qn, qr, kn, kr, vp), sizes=(t, t, block, block, None),
+        lead=(b, h), dv=dv,
         in_specs=[pl.BlockSpec((1, 1, block, dn), _q_block),
                   pl.BlockSpec((1, 1, block, dr), _q_block),
                   pl.BlockSpec((1, 1, block, dn), own),
@@ -425,9 +626,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     q: [B, H, Tq, D]; k, v: [B, Hkv, Tk, D], ``Hkv`` dividing ``H``
     (query head ``j`` reads KV head ``j // (H / Hkv)``, by index).  Any
-    sizes — inputs are padded to
-    MXU-aligned tiles internally and the padding is masked out of the
-    softmax.  ``causal=True`` takes the paired kernel (the module's
+    sizes — these head-major inputs are padded in HBM, rows to whole
+    blocks and a head's width to the 128 lanes, and the padding is
+    masked out of the softmax (a projection's own columns of heads
+    under a lane row wide go to :func:`flash_causal_columns`, which
+    pads nothing: the module's docstring).  ``causal=True`` takes the paired kernel (the module's
     docstring), whose products run in the operands' own type; with Tq !=
     Tk it uses bottom-right alignment (decode semantics); with
     ``window`` a row attends its ``window`` newest keys, itself counted,
@@ -452,8 +655,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
 
-    block_q = min(block_q, max(8, 1 << (t_q - 1).bit_length()))
-    block_k = min(block_k, max(8, 1 << (t_k - 1).bit_length()))
+    block_q, block_k = _clamped(block_q, t_q), _clamped(block_k, t_k)
 
     qp = _pad_to(q.reshape(b * h, t_q, d), 1, block_q)
     kp = _pad_to(k.reshape(b * h, t_k, d), 1, block_k)

@@ -7,7 +7,13 @@ one prompt of 8192), at Jamba's and granite's (prompts of 256 and
 1024, where a head's triangle is one and three pairs) and at the two
 full-head families' (GPT-2's 8 prompts of 512 and
 ``gpt2xl_long_prompt``'s of 896 on 25 heads of 64, OLMoE's 16 of 1024
-on 16 heads of 128).  Chip only.
+on 16 heads of 128).  The two GPT-2 shapes are timed through both
+entries, on the same token-major operands ``[prompts, t, 1600]`` laid
+row-major as the projection leaves them: ``head-major`` is
+``flash_attention(causal=True)`` with the head split, its pads, its
+slice and the merge inside the timed call (what a layer paid until
+PR 70), ``token-major`` is ``flash_causal_columns`` with nothing around
+it.  Chip only.
 
     python scripts/flash_kernel_bench.py [OUT.json] [shape[:block] ...]
 
@@ -20,8 +26,8 @@ warm-ups), the call's operations by the benchmark's own functions
 ``roofline_window_moe.py::band_flops``) over that as a share of the
 matrix peak, the largest distance from the masked softmax in float32
 over the first heads, the grid's steps and those that work
-(``prefill.flash.grid_steps`` / ``.live_steps``) and microseconds a
-step.  It runs on a tree from before PR 50 too (copy it there), which
+(``prefill.flash.grid_steps`` / ``.live_steps``), the heads a block
+holds (``.heads_a_block``) and microseconds a step.  It runs on a tree from before PR 50 too (copy it there), which
 sets no gauges; nor does a full-head call before PR 58 (``_attn_kernel``
 then, whatever the line calls it).
 """
@@ -43,8 +49,15 @@ from chipbench import roofline_latent_moe as rl             # noqa: E402
 from chipbench import roofline_window_moe as rw             # noqa: E402
 from chipbench.roofline import peaks_for                    # noqa: E402
 from defer_tpu.obs.registry import REGISTRY                 # noqa: E402
+from jax.experimental.layout import Format, Layout          # noqa: E402
+
 from defer_tpu.ops.flash_attention import (                 # noqa: E402
     flash_attention, flash_latent)
+
+try:
+    from defer_tpu.ops.flash_attention import flash_causal_columns
+except ImportError:         # a tree from before PR 70: one entry
+    flash_causal_columns = None
 
 CALLS = 8
 
@@ -110,7 +123,39 @@ def masked_softmax(kernel, ops, window, heads, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def run(name: str, peak: float) -> dict:
+def head_major(x, heads: int):
+    """``[b, t, heads * d]`` as ``[b, heads, t, d]``."""
+    b, t, cols = x.shape
+    return x.reshape(b, t, heads, cols // heads).transpose(0, 2, 1, 3)
+
+
+def token_major(x):
+    """``[b, heads, t, d]`` as ``[b, t, heads * d]``."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def timed(call, ops) -> tuple:
+    """``call(*ops)`` traced anew: its result, milliseconds a call
+    behind two warm-ups and the gauges its trace set."""
+    # the gauges are set while a call is traced: trace this one anew,
+    # and leave no other shape's reading where a tree sets none
+    jax.clear_caches()
+    gauges = ("grid_steps", "live_steps", "heads_a_block")
+    for gauge in gauges:
+        REGISTRY.gauge(f"prefill.flash.{gauge}").set(0)
+    y = call(*ops).block_until_ready()
+    call(*ops).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(CALLS):
+        y = call(*ops)
+    y.block_until_ready()
+    ms = (time.perf_counter() - t0) / CALLS * 1e3
+    return y, ms, {g: REGISTRY.gauge(f"prefill.flash.{g}").value
+                   for g in gauges}
+
+
+def run(name: str, peak: float) -> list:
     name, _, block = name.partition(":")
     block = int(block) if block else None
     config, kernel, rows, t, window = SHAPES[name]
@@ -131,35 +176,46 @@ def run(name: str, peak: float) -> dict:
         def call(q, k, v):
             return flash_attention(q, k, v, causal=True, window=window,
                                    block_q=block, block_k=block)
-    # the gauges are set while a call is traced: trace this one anew,
-    # and leave no other shape's reading where a tree sets none
-    jax.clear_caches()
-    for gauge in ("grid_steps", "live_steps"):
-        REGISTRY.gauge(f"prefill.flash.{gauge}").set(0)
-    y = call(*ops).block_until_ready()
-    call(*ops).block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(CALLS):
-        y = call(*ops)
-    y.block_until_ready()
-    ms = (time.perf_counter() - t0) / CALLS * 1e3
+    entries = {kernel: (call, ops, lambda y: y)}
+    if kernel == "flash_causal" and a["head_dim"] < 128 \
+            and flash_causal_columns:
+        # full heads under a lane row: the same operands token-major,
+        # row-major on the device as a projection's columns are (the
+        # chip's own choice for [8, 896, 1600] has the rows innermost)
+        rowmajor = Format(Layout(major_to_minor=(0, 1, 2)),
+                          ops[0].sharding)
+        cols = tuple(jax.device_put(token_major(o), rowmajor) for o in ops)
+        h = a["heads"]
+        entries = {
+            "head-major": (jax.jit(lambda q, k, v: token_major(call(
+                *(head_major(o, h) for o in (q, k, v)))),
+                out_shardings=rowmajor), cols, lambda y: head_major(y, h)),
+            "token-major": (jax.jit(lambda q, k, v: flash_causal_columns(
+                q, k, v, heads=h, block_q=block, block_k=block),
+                out_shardings=rowmajor), cols, lambda y: head_major(y, h))}
     ref = masked_softmax(kernel, ops, window, heads, scale)
-    err = float(jnp.abs(y[:1, :heads].astype(jnp.float32) - ref).max())
-    steps = REGISTRY.gauge("prefill.flash.grid_steps").value
-    live = REGISTRY.gauge("prefill.flash.live_steps").value
-    row = {"shape": name, "kernel": kernel, "rows": rows, "prompt_len": t,
-           "window": window, "block": block, "ms": ms, "flops": flops,
-           "peak_share": flops / peak / (ms / 1e3), "grid_steps": steps,
-           "live_steps": live, "max_err": err}
-    print(f"{name}{f' in blocks of {block}' if block else ''}: {kernel} "
-          f"{ms:.3f} ms a call, "
-          f"{100 * row['peak_share']:.1f}% of the matrix peak, "
-          f"err {err:.4f}; "
-          # a tree from before PR 50 sets no gauge
-          + (f"{live:.0f} of {steps:.0f} steps work, "
-             f"{ms * 1e3 / steps:.3f} us a step" if steps else
-             "no step gauges"), flush=True)
-    return row
+    out = []
+    for entry, (fn, args, as_heads) in entries.items():
+        y, ms, gauges = timed(fn, args)
+        err = float(jnp.abs(
+            as_heads(y)[:1, :heads].astype(jnp.float32) - ref).max())
+        steps, live = gauges["grid_steps"], gauges["live_steps"]
+        row = {"shape": name, "kernel": kernel, "entry": entry,
+               "rows": rows, "prompt_len": t, "window": window,
+               "block": block, "ms": ms, "flops": flops,
+               "peak_share": flops / peak / (ms / 1e3), "max_err": err,
+               **gauges}
+        print(f"{name}{f' in blocks of {block}' if block else ''}: {entry} "
+              f"{ms:.3f} ms a call, "
+              f"{100 * row['peak_share']:.1f}% of the matrix peak, "
+              f"err {err:.4f}; "
+              # a tree from before PR 50 sets no gauge
+              + (f"{live:.0f} of {steps:.0f} steps work, "
+                 f"{gauges['heads_a_block']:.0f} heads a block, "
+                 f"{ms * 1e3 / steps:.3f} us a step" if steps else
+                 "no step gauges"), flush=True)
+        out.append(row)
+    return out
 
 
 def main() -> int:
@@ -171,7 +227,7 @@ def main() -> int:
               file=sys.stderr)
         return 1
     peak = peaks_for(dev.device_kind)["bf16_flops_per_s"]
-    rows = [run(name, peak) for name in (args or SHAPES)]
+    rows = [row for name in (args or SHAPES) for row in run(name, peak)]
     if out:
         with open(out, "w") as f:
             json.dump({"device": dev.device_kind, "rows": rows}, f, indent=1)

@@ -230,13 +230,20 @@ def test_full_head_causal_takes_the_paired_kernel(dtype, tol, t_q, t_k):
 ])
 def test_a_trace_tells_the_kernels_apart(hkv, window, causal, name):
     """A call's Pallas kernel is named by kind (what a device trace's
-    readers select by): a full-head causal call's is its own, and the
-    non-causal rectangle's call carries none."""
+    readers select by): a full-head causal call's is its own — head-major
+    and token-major alike, so a ``breakdown`` compares it under one name
+    across PR 70 —, and the non-causal rectangle's call carries none."""
+    from defer_tpu.ops.flash_attention import flash_causal_columns
     q = jnp.zeros((1, 4, 40, 16))
     k = jnp.zeros((1, hkv, 40, 16))
     jaxpr = jax.make_jaxpr(lambda q, k: flash_attention(
         q, k, k, causal=causal, window=window))(q, k)
     assert _kernel_names(jaxpr.jaxpr) == [name]
+    if causal:
+        jaxpr = jax.make_jaxpr(lambda q, k: flash_causal_columns(
+            _columns(q), _columns(k), _columns(k), heads=4, kv_heads=hkv,
+            window=window))(q, k)
+        assert _kernel_names(jaxpr.jaxpr) == [name]
 
 
 @pytest.mark.parametrize("family", ["gpt_tiny", "olmoe_tiny"])
@@ -255,6 +262,143 @@ def test_a_full_head_family_s_block_is_the_same_under_both_paths(family):
     np.testing.assert_allclose(np.asarray(flash.apply(p, x)),
                                np.asarray(xla.apply(p, x)),
                                atol=2e-5, rtol=2e-5)
+
+
+def _columns(x):
+    """``[b, h, t, d]`` token-major: ``[b, t, h * d]``."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def _token_major_case(h, d, t_q, t_k, dtype, batch=1):
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (batch, h, t_q, d), dtype)
+    k = jax.random.normal(ks[1], (batch, h, t_k, d), dtype)
+    v = jax.random.normal(ks[2], (batch, h, t_k, d), dtype)
+    return q, k, v
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,d,t_q,t_k,block", [
+    (25, 64, 896, 896, None),   # gpt2xl_long_prompt: 12 lane rows and a
+                                # half, two blocks of 512 over 896 rows
+    (4, 64, 512, 512, None),    # the batch cells' prompt: one pair
+    (3, 32, 200, 200, 64),      # four heads a lane row, 96 columns of 128
+    (2, 64, 8, 40, 16),         # 8 queries on 40 keys, bottom-right
+], ids=["25x64x896", "4x64x512", "3x32x200", "2x64x8on40"])
+def test_token_major_matches_the_masked_softmax(h, d, t_q, t_k, block,
+                                                dtype, tol):
+    """``flash_causal_columns`` on the projection's own columns against
+    the masked softmax in float32.  The interpreter fills what a block
+    holds beyond the operand with NaN, as the chip may: the last lane
+    row of an odd head count and the rows past the prompt's end."""
+    from defer_tpu.ops.flash_attention import flash_causal_columns
+    q, k, v = _token_major_case(h, d, t_q, t_k, dtype)
+    out = flash_causal_columns(_columns(q), _columns(k), _columns(v),
+                               heads=h, block_q=block, block_k=block)
+    assert out.shape == (1, t_q, h * d) and out.dtype == dtype
+    ref = _banded_reference(*(a.astype(jnp.float32) for a in (q, k, v)),
+                            None)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(_columns(ref)), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,d,t_q,t_k", [(3, 64, 40, 40), (5, 32, 24, 56)],
+                         ids=["3x64", "5x32-decode-aligned"])
+def test_token_major_reads_nothing_beyond_its_sizes(h, d, t_q, t_k, dtype):
+    """The odd head count's last lane row, and the last blocks' rows,
+    with NaN planted in everything beyond the real columns and rows: the
+    output's real part is finite and what the exact operands give.  (A
+    zeroed query times a NaN key is NaN, and so is a masked score's zero
+    weight times a NaN value: the kernel zeroes the keys' dead columns
+    and the values' dead rows.)"""
+    from defer_tpu.ops.flash_attention import (_lane_row_attention,
+                                               flash_causal_columns)
+    q, k, v = (_columns(a) for a in _token_major_case(h, d, t_q, t_k, dtype,
+                                                      batch=2))
+    cols = h * d
+
+    def planted(x):
+        big = jnp.full((2, 64, 256), jnp.nan, dtype)
+        return big.at[:, :x.shape[1], :cols].set(x)
+
+    exact = flash_causal_columns(q, k, v, heads=h, block_q=16, block_k=16)
+    out = _lane_row_attention(planted(q), planted(k), planted(v), d=d,
+                              t_q=t_q, t_k=t_k, cols=cols, block_q=16,
+                              block_k=16, interpret=True)[:, :t_q, :cols]
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(exact, np.float32))
+
+
+@pytest.mark.parametrize("h,hkv,d,window,heads_a_block,name", [
+    (4, 4, 64, None, 2, "flash_causal"),    # GPT-2's: two a lane row
+    (3, 3, 32, None, 4, "flash_causal"),
+    (2, 2, 128, None, 1, "flash_causal"),   # OLMoE's: a head a block
+    (4, 2, 64, None, 1, "flash_grouped"),   # LFM2's: out of scope, kept
+    (4, 4, 64, 24, 1, "flash_band"),
+    (2, 2, 96, None, 1, "flash_causal"),    # no whole fraction of 128
+], ids=["64", "32", "128", "grouped-64", "window-64", "96"])
+def test_one_entry_takes_the_block_its_shapes_name(h, hkv, d, window,
+                                                   heads_a_block, name):
+    """No argument chooses the path: the same public call holds a lane
+    row of heads a block where full, unwindowed heads are a whole
+    fraction of 128 wide, and a head a block everywhere else — and is
+    then ``flash_attention(causal=True)`` on the same operands laid
+    head-major.  The Pallas call keeps its name by kind."""
+    from defer_tpu.obs.registry import REGISTRY
+    from defer_tpu.ops.flash_attention import flash_causal_columns
+    ks = jax.random.split(jax.random.key(13), 3)
+    q = jax.random.normal(ks[0], (2, h, 72, d))
+    k = jax.random.normal(ks[1], (2, hkv, 72, d))
+    v = jax.random.normal(ks[2], (2, hkv, 72, d))
+
+    def call(q, k, v):
+        return flash_causal_columns(q, k, v, heads=h, kv_heads=hkv,
+                                    window=window, block_q=32, block_k=32)
+    cols = tuple(_columns(a) for a in (q, k, v))
+    assert _kernel_names(jax.make_jaxpr(call)(*cols).jaxpr) == [name]
+    out = call(*cols)
+    assert REGISTRY.gauge("prefill.flash.heads_a_block").value \
+        == heads_a_block
+    pairs = REGISTRY.gauge("prefill.flash.grid_steps").value
+    head_major = flash_attention(q, k, v, causal=True, window=window,
+                                 block_q=32, block_k=32)
+    assert REGISTRY.gauge("prefill.flash.heads_a_block").value == 1
+    # a lane row's block is its heads' blocks: that many fewer steps
+    assert REGISTRY.gauge("prefill.flash.grid_steps").value \
+        == pairs * h // -(-h // heads_a_block)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_columns(head_major)),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads,hidden", [(2, 32), (3, 192), (4, 256)],
+                         ids=["gpt_tiny", "3-heads-of-64", "4-heads-of-64"])
+def test_a_gpt_block_hands_its_columns_over_as_they_lie(heads, hidden):
+    """``apply_with_kv`` of a GPT block under ``"flash"`` — the
+    projection's columns token-major into ``flash_causal``, no head
+    split in the program — against ``"xla"``: the same output, and the
+    K and V columns it returns to the cache bit for bit."""
+    from defer_tpu.graph.ir import ShapeSpec
+    from defer_tpu.models.gpt import CausalTransformerBlock
+    flash = CausalTransformerBlock(heads, attn_impl="flash")
+    xla = CausalTransformerBlock(heads, attn_impl="xla")
+    p = flash.init(jax.random.key(0), (ShapeSpec((40, hidden)),))
+    x = jax.random.normal(jax.random.key(2), (2, 40, hidden))
+    jaxpr = jax.make_jaxpr(flash.apply_with_kv)(p, x)
+    assert _kernel_names(jaxpr.jaxpr) == ["flash_causal"]
+    assert "transpose" not in {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    (y, k, v), (y_ref, k_ref, v_ref) = (
+        op.apply_with_kv(p, x) for op in (flash, xla))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(k), np.asarray(k_ref))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(v_ref))
 
 
 #: sha256 of ``flash_attention.lower(q, k, k).as_text()`` (non-causal)
@@ -395,6 +539,15 @@ def test_flash_gauges_count_the_steps_that_work():
                     jnp.zeros((1, 2, 896, 8)), causal=True)
     assert REGISTRY.gauge("prefill.flash.grid_steps").value == 2 * 3
     assert REGISTRY.gauge("prefill.flash.live_steps").value == 2 * 3
+    assert REGISTRY.gauge("prefill.flash.heads_a_block").value == 1
+    # the same prompt token-major on GPT-2's 25 heads of 64: 13 lane
+    # rows of two heads, two query blocks of 448 on key blocks of 512
+    from defer_tpu.ops.flash_attention import flash_causal_columns
+    cols = jnp.zeros((1, 896, 1600))
+    flash_causal_columns(cols, cols, cols, heads=25)
+    assert REGISTRY.gauge("prefill.flash.grid_steps").value == 13 * 3
+    assert REGISTRY.gauge("prefill.flash.live_steps").value == 13 * 3
+    assert REGISTRY.gauge("prefill.flash.heads_a_block").value == 2
 
 
 def test_band_kernel_refuses_what_it_is_not():
